@@ -14,6 +14,7 @@
 // round-trips float32 exactly; parsing uses strtof/strtoull.
 
 #include <cctype>
+#include <cerrno>
 #include <cstdint>
 #include <cstdio>
 #include <cstdlib>
@@ -110,7 +111,9 @@ void smtpu_libsvm_free(SmtpuLibsvm* d) { delete d; }
 
 // Write n_rows lines "key\tfield0\tfield1..." where field j is dims[j]
 // space-joined %.9g floats read from fields[j] (row-major (n_rows, dims[j])).
-// Returns rows written, or -1 on open failure.
+// Returns rows written, or -1 with errno set when the file cannot be
+// opened or a write fails (a full disk must not leave a short file that
+// looks saved).
 int64_t smtpu_dump_rows(const char* path, const uint64_t* keys,
                         int64_t n_rows, int64_t n_fields,
                         const float* const* fields, const int64_t* dims) {
@@ -118,7 +121,7 @@ int64_t smtpu_dump_rows(const char* path, const uint64_t* keys,
   if (!f) return -1;
   std::vector<char> buf(1 << 20);
   setvbuf(f, buf.data(), _IOFBF, buf.size());
-  for (int64_t r = 0; r < n_rows; r++) {
+  for (int64_t r = 0; r < n_rows && !ferror(f); r++) {
     fprintf(f, "%llu", (unsigned long long)keys[r]);
     for (int64_t j = 0; j < n_fields; j++) {
       fputc('\t', f);
@@ -130,7 +133,17 @@ int64_t smtpu_dump_rows(const char* path, const uint64_t* keys,
     }
     fputc('\n', f);
   }
-  fclose(f);
+  // stdio keeps the failed write's errno only until the next call
+  bool failed = ferror(f) != 0;
+  int err = errno;
+  if (fclose(f) != 0 && !failed) {
+    failed = true;
+    err = errno;
+  }
+  if (failed) {
+    errno = err;
+    return -1;
+  }
   return n_rows;
 }
 

@@ -3,8 +3,8 @@
 
 The window-coalesced push exists to cut wire traffic; this script makes
 that a *checked* property instead of a one-time measurement.  It reads
-two bench result files (the ``.bench_cache/tpu_*.json`` shape:
-``{"ts": ..., "result": {cell: {metric: value}}}``), lines up every
+two bench result files (``{"ts": ..., "result": {cell: {metric:
+value}}}`` around bench.py's ``BENCH_CHILD`` cells), lines up every
 cell present in both, and fails when a traffic metric regressed beyond
 tolerance:
 

@@ -957,86 +957,6 @@ def _print_trace_report(rep: dict) -> None:
                   f"touches, {_fmt_qty(h.get('bytes'), 'B')}")
 
 
-# -- history mode (smtpu-bench-history/1 trend tables) --------------------
-HISTORY_SCHEMA_PREFIX = "smtpu-bench-history/"
-
-
-def load_history(path: str) -> List[dict]:
-    """Load bench.py's append-only ``runs/bench_history.jsonl``; rows
-    with a foreign schema are dropped (the file is append-only across
-    versions).  SystemExit(2) on unreadable/empty/no-valid-rows."""
-    try:
-        with open(path) as f:
-            lines = [ln for ln in f if ln.strip()]
-    except OSError as e:
-        print(f"telemetry_report: cannot read {path}: {e}",
-              file=sys.stderr)
-        raise SystemExit(2)
-    rows = []
-    for ln in lines:
-        try:
-            rec = json.loads(ln)
-        except ValueError:
-            continue
-        if isinstance(rec, dict) and str(rec.get("schema", "")).startswith(
-                HISTORY_SCHEMA_PREFIX):
-            rows.append(rec)
-    if not rows:
-        print(f"telemetry_report: {path} has no "
-              f"{HISTORY_SCHEMA_PREFIX}* rows", file=sys.stderr)
-        raise SystemExit(2)
-    rows.sort(key=lambda r: r.get("ts", 0.0))
-    return rows
-
-
-def history_report(rows: List[dict]) -> dict:
-    """Trend table per cell: chronological (ts, git_sha, stack_key,
-    value) points plus first->last delta so a regression names the
-    commit range it arrived in."""
-    cells: Dict[str, List[dict]] = {}
-    for r in rows:
-        cells.setdefault(str(r.get("cell", "?")), []).append(r)
-    out = {}
-    for cell, rs in sorted(cells.items()):
-        field = "value" if any("value" in r for r in rs) else None
-        if field is None:
-            # secondary cells carry their metric under tpu/cpu keys
-            for cand in ("tpu", "cpu", "tpu_cached"):
-                if any(isinstance(r.get(cand), (int, float))
-                       for r in rs):
-                    field = cand
-                    break
-        points = [{"ts": r.get("ts"), "git_sha": r.get("git_sha"),
-                   "stack_key": r.get("stack_key"),
-                   "value": r.get(field) if field else None}
-                  for r in rs]
-        numeric = [p["value"] for p in points
-                   if isinstance(p["value"], (int, float))]
-        entry = {"field": field, "points": points, "runs": len(points)}
-        if len(numeric) >= 2 and numeric[0]:
-            entry["delta_pct"] = 100.0 * (numeric[-1] - numeric[0]) \
-                / abs(numeric[0])
-        out[cell] = entry
-    return out
-
-
-def _print_history_report(rep: dict) -> None:
-    import time as _time
-    print("bench history trends:")
-    for cell, e in rep.items():
-        delta = (f"  ({e['delta_pct']:+.1f}% first->last)"
-                 if "delta_pct" in e else "")
-        print(f"  {cell} [{e.get('field')}] — {e['runs']} run(s){delta}")
-        for p in e["points"]:
-            day = (_time.strftime("%Y-%m-%d %H:%M",
-                                  _time.localtime(p["ts"]))
-                   if p.get("ts") else "?")
-            v = p.get("value")
-            v_s = f"{v:,.2f}" if isinstance(v, (int, float)) else "-"
-            print(f"    {day}  {str(p.get('git_sha')):>10}  "
-                  f"{v_s:>14}  {p.get('stack_key')}")
-
-
 # -- rendering ------------------------------------------------------------
 def _print_numerics(num: dict) -> None:
     print()
@@ -1256,10 +1176,6 @@ def main(argv=None) -> int:
                     help="treat path as an smtpu-trace/1 flight-"
                     "recorder dump (obs/trace.py): per-window wire "
                     "decisions with priced alternatives, hot keys")
-    ap.add_argument("--history", action="store_true",
-                    help="treat path as a smtpu-bench-history/1 "
-                    "runs/bench_history.jsonl: per-cell trend tables "
-                    "stamped with git SHA + stack key")
     args = ap.parse_args(argv)
 
     if args.trace:
@@ -1269,14 +1185,6 @@ def main(argv=None) -> int:
             print()
         else:
             _print_trace_report(rep)
-        return 0
-    if args.history:
-        rep = history_report(load_history(args.path))
-        if args.json:
-            json.dump(rep, sys.stdout, indent=2)
-            print()
-        else:
-            _print_history_report(rep)
         return 0
     if args.fleet:
         rep = fleet_report(load_fleet(args.path))

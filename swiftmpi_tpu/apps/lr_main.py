@@ -16,6 +16,7 @@ import numpy as np
 from swiftmpi_tpu.models.logistic import LogisticRegression
 from swiftmpi_tpu.utils import CMDLine, global_config
 from swiftmpi_tpu.utils.logger import get_logger
+from swiftmpi_tpu.utils.xla_env import ensure_compile_cache
 
 log = get_logger("apps.lr")
 
@@ -35,6 +36,7 @@ def main(argv=None) -> int:
         cmd.print_help()
         return 0
 
+    ensure_compile_cache()
     if cmd.hasParameter("config"):
         global_config().load_conf(cmd.getValue("config")).parse()
     mode = cmd.getValue("mode")

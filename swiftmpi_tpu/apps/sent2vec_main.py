@@ -12,6 +12,7 @@ import sys
 from swiftmpi_tpu.models.sent2vec import Sent2Vec, build_word_model_from_dump
 from swiftmpi_tpu.utils import CMDLine, global_config
 from swiftmpi_tpu.utils.logger import get_logger
+from swiftmpi_tpu.utils.xla_env import ensure_compile_cache
 
 log = get_logger("apps.sent2vec")
 
@@ -39,6 +40,7 @@ def _main(argv=None) -> int:
         cmd.print_help()
         return 0
 
+    ensure_compile_cache()
     if cmd.hasParameter("config"):
         global_config().load_conf(cmd.getValue("config")).parse()
     word_model = build_word_model_from_dump(

@@ -16,6 +16,7 @@ from swiftmpi_tpu.data.text import load_corpus
 from swiftmpi_tpu.models.word2vec import Word2Vec
 from swiftmpi_tpu.utils import CMDLine, global_config
 from swiftmpi_tpu.utils.logger import get_logger
+from swiftmpi_tpu.utils.xla_env import ensure_compile_cache
 
 log = get_logger("apps.w2v")
 
@@ -49,6 +50,7 @@ def _main(argv=None) -> int:
         cmd.print_help()
         return 0
 
+    ensure_compile_cache()
     if cmd.hasParameter("config"):
         global_config().load_conf(cmd.getValue("config")).parse()
     variant = cmd.getValue("variant", "sync")

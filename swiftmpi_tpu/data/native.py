@@ -7,7 +7,8 @@ pure-Python pipeline (data/text.py) when the shared library cannot be
 built; call ``available()`` to check.
 
 The .so is built on demand with g++ from the repo's ``native/`` directory
-and cached next to the source.
+and cached next to the source (gitignored: every checkout compiles its
+own from ``loader.cpp`` + ``io.cpp``).
 """
 
 from __future__ import annotations
@@ -54,13 +55,15 @@ def _load_lib():
                 subprocess.run(
                     ["g++", "-O3", "-std=c++17", "-Wall", "-shared",
                      "-fPIC", *srcs, "-o", _SO_PATH],
-                    check=True, capture_output=True, timeout=120)
+                    check=True, capture_output=True, text=True,
+                    timeout=120)
             except (subprocess.SubprocessError, FileNotFoundError) as e:
-                log.warning("native loader build failed (%s); "
-                            "using python pipeline", e)
+                log.warning("native loader build failed (%s); using "
+                            "python pipeline\n%s", e,
+                            getattr(e, "stderr", None) or "")
                 _build_failed = True
                 return None
-        lib = ctypes.CDLL(_SO_PATH)
+        lib = ctypes.CDLL(_SO_PATH, use_errno=True)
         c = ctypes
         lib.smtpu_vocab_build.restype = c.c_void_p
         lib.smtpu_vocab_build.argtypes = [c.c_char_p, c.c_int, c.c_int64,
@@ -333,7 +336,8 @@ def dump_rows_native(path: str, keys: np.ndarray, fields) -> int:
     n = lib.smtpu_dump_rows(path.encode(), keys.ctypes.data, len(keys),
                             len(arrs), ptrs, dims.ctypes.data)
     if n < 0:
-        raise OSError(f"cannot write {path}")
+        err = ctypes.get_errno()
+        raise OSError(err, os.strerror(err), path)
     return int(n)
 
 

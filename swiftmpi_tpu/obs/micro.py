@@ -1,7 +1,7 @@
 """Telemetry emission for the kernel microbench scripts.
 
 ``scripts/gather_micro.py`` / ``scripts/scatter_micro.py`` print their
-cells as free text — fine for a human in a tunnel window, invisible to
+cells as free text — fine for a human reader, invisible to
 the diff tooling.  :class:`MicroTelemetry` gives those scripts the same
 schema-versioned JSONL (``smtpu-telemetry/1``) every other producer
 emits, so ``scripts/telemetry_report.py`` renders a microbench run's

@@ -56,8 +56,7 @@ ENV_PROFILE_STEPS = "SMTPU_PROFILE_STEPS"
 #: already emitted across the codebase.  Substring match: XLA embeds
 #: scope names inside fused-kernel labels.
 KNOWN_PHASES = (
-    "window_dedup", "wire_exchange", "apply", "pallas_gather_stencil",
-    "serve/topk", "render", "h2d", "input_wait", "dispatch",
+    "window_dedup", "wire_exchange", "apply", "serve/topk", "render", "h2d", "input_wait", "dispatch",
     "checkpoint_save",
 )
 

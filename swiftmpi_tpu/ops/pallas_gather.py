@@ -11,12 +11,12 @@ staged on-chip once per kernel and gathered at VMEM latency.
 This module is the honest experiment VERDICT round 1 asked for ("weak:
 Pallas surface — with zero chip measurements nobody knows whether XLA
 falls short"): ``vmem_gather(table, idx)`` stages the whole table into
-VMEM via the BlockSpec pipeline and gathers index blocks with
-``jnp.take`` inside the kernel (Mosaic's dynamic-gather path).  The
-A/B against XLA's native gather runs as the final cell of
-``scripts/gather_micro.py``; wiring into ``XlaTransfer.pull`` is gated
-on that A/B showing a real win on hardware — on CPU the kernel runs in
-interpret mode and is for correctness only.
+VMEM via the BlockSpec pipeline and copies the indexed rows one by one,
+addressed by SMEM scalars.  The A/B against XLA's native gather runs as
+the final cell of ``scripts/gather_micro.py``; wiring into
+``XlaTransfer.pull`` is gated on that A/B showing a real win on
+hardware — on CPU the kernel runs in interpret mode and is for
+correctness only.
 
 Reference context: the gather this replaces is the pull half of
 ``MiniBatch::pull`` (/root/reference/src/apps/word2vec/word2vec.h:303-311);
@@ -36,66 +36,17 @@ from jax.experimental.pallas import tpu as pltpu
 from swiftmpi_tpu.ops import calibration
 
 _DEF_IDX_BLOCK = 4096
-_TAA_IDX_BLOCK = 1024
 
 
 def _gather_kernel(table_ref, idx_ref, out_ref):
-    """One grid step: gather ``idx_block`` rows from the VMEM-resident
-    table.  ``jnp.take`` on a VMEM value lowers to Mosaic's dynamic
-    gather; clip keeps OOB/padding indices defined (callers mask).
-
-    Round-3 chip A/B: Mosaic REJECTS this form ("Shape mismatch in
-    input, indices and output") — its TC gather lowering
-    (jax/_src/pallas/mosaic/lowering.py _gather_lowering_rule) supports
-    only the equal-shape take_along_axis pattern.  Kept for the record
-    and for generations whose Mosaic may accept it; the `taa` variant
-    below is the form that lowers today."""
-    idx = jnp.clip(idx_ref[...], 0, table_ref.shape[0] - 1)
-    out_ref[...] = jnp.take(table_ref[...], idx, axis=0)
-
-
-def _gather_taa_kernel(table_ref, idx_ref, out_ref):
-    """Equal-shape ``take_along_axis`` form: ``tpu.dynamic_gather``
-    requires input, indices and output to share one 2D shape, so the
-    kernel walks the VMEM-resident table in ``idx_block``-row chunks
-    (static unroll) and accumulates the masked equal-shape gather of
-    each chunk.  Round-3 chipless-AOT finding: Mosaic STILL rejects it
-    ("Multiple source vregs along gather dimension") — the primitive is
-    a within-vreg shuffle (8 sublanes for f32), not a table gather, so
-    any chunk big enough to be useful spans many vregs.  Kept for the
-    A/B record and for future Mosaic versions; ``loop`` is the variant
-    that lowers today.
-
-        out[i, :] = sum_c  [c*B <= idx[i] < (c+1)*B] * chunk_c[idx[i] - c*B, :]
-
-    Vector work is N * (table_rows/idx_block) lane-gathers — all VMEM
-    register traffic, no HBM transactions, which is the entire point
-    vs XLA's transaction-bound 400B-row fetches."""
-    n_blk = out_ref.shape[0]
-    d = out_ref.shape[1]
-    rows = table_ref.shape[0]
-    idx = jnp.clip(idx_ref[...], 0, rows - 1)
-    # masks are born 2D from 32-bit values: reshaping a 1-bit vector
-    # 1D->2D ("insertion of minor dim") is rejected by Mosaic
-    idx2 = jnp.broadcast_to(idx[:, None], (n_blk, d))
-    acc = jnp.zeros((n_blk, d), table_ref.dtype)
-    for c in range(rows // n_blk):
-        chunk = table_ref[c * n_blk:(c + 1) * n_blk, :]
-        li2 = idx2 - c * n_blk
-        inb2 = (li2 >= 0) & (li2 < n_blk)
-        g = jnp.take_along_axis(chunk, jnp.where(inb2, li2, 0), axis=0,
-                                mode="promise_in_bounds")
-        acc = acc + jnp.where(inb2, g, jnp.zeros((), table_ref.dtype))
-    out_ref[...] = acc
-
-
-def _gather_loop_kernel(table_ref, idx_ref, out_ref):
-    """Fallback form: sequential per-row copies, indices read as SMEM
-    scalars.  The round-2 rendering extracted ``idx[j]`` from a vector
-    value — that lowers to ``dynamic_slice``, which Mosaic TC rejects
-    (round-3 chip A/B); scalar reads from an SMEM ref are the supported
-    addressing path, and the row copies are ref dynamic slices (DMA-
-    addressable), not vector-value slices."""
+    """Sequential per-row copies, indices read as SMEM scalars.  This is
+    the one addressing form Mosaic lowers (checked on libtpu 0.0.34,
+    TPU v5e): a vectorized ``jnp.take`` on the VMEM table is refused
+    ("Shape mismatch in input, indices and output"), the equal-shape
+    ``take_along_axis`` form is refused ("Multiple source vregs along
+    gather dimension"), and extracting ``idx[j]`` from a vector value
+    lowers to ``dynamic_slice``, which is not implemented.  The row
+    copies are ref dynamic slices, not vector-value slices."""
 
     unroll = 8
 
@@ -111,65 +62,31 @@ def _gather_loop_kernel(table_ref, idx_ref, out_ref):
     jax.lax.fori_loop(0, out_ref.shape[0] // unroll, body, 0)
 
 
-_METHODS = ("taa", "take", "loop")
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("idx_block", "interpret", "method"))
+@functools.partial(jax.jit, static_argnames=("idx_block", "interpret"))
 def vmem_gather(table: jax.Array, idx: jax.Array,
-                idx_block: int | None = None,
-                interpret: bool | None = None,
-                method: str = "taa") -> jax.Array:
+                idx_block: int = _DEF_IDX_BLOCK,
+                interpret: bool | None = None) -> jax.Array:
     """``table[idx]`` with the table staged in VMEM.
 
     ``idx`` length must be a multiple of ``idx_block`` (pad with any
     in-range value and discard).  Requires the table (plus one index and
     one output block) to fit the ~16MB VMEM budget — callers check
-    ``fits_vmem(table)`` first.  ``method``: ``taa`` (chunked
-    equal-shape take_along_axis — the form Mosaic TC lowers, see
-    kernel docstrings), ``take`` (whole-table vectorized gather;
-    rejected by today's Mosaic, kept for the A/B), or ``loop``
-    (per-row ref slices addressed by SMEM scalars)."""
+    ``fits_vmem(table)`` first."""
     n = idx.shape[0]
-    if idx_block is None:
-        idx_block = _TAA_IDX_BLOCK if method == "taa" else _DEF_IDX_BLOCK
     if n % idx_block:
         raise ValueError(f"idx length {n} not a multiple of {idx_block}")
-    if method not in _METHODS:
-        # a stale/hand-edited calibration file must fail loudly, not
-        # silently select the slow loop kernel on the production path
-        raise ValueError(f"unknown vmem_gather method {method!r}")
     if interpret is None:
         interpret = not calibration.on_tpu()
-    if method == "taa":
-        # the equal-shape gather walks the table in idx_block-row
-        # chunks, so the resident copy is padded to a chunk multiple
-        pad_rows = (-table.shape[0]) % idx_block
-        if pad_rows:
-            table = jnp.concatenate(
-                [table, jnp.zeros((pad_rows, table.shape[1]),
-                                  table.dtype)])
-    grid = (n // idx_block,)
-    kernel = {"taa": _gather_taa_kernel,
-              "take": _gather_kernel,
-              "loop": _gather_loop_kernel}[method]
-    if method == "loop":
-        # indices as SMEM scalars: vector-value extraction lowers to
-        # dynamic_slice, which Mosaic TC rejects; SMEM scalar reads
-        # are the supported per-row addressing path
-        idx_spec = pl.BlockSpec((idx_block,), lambda i: (i,),
-                                memory_space=pltpu.SMEM)
-    else:
-        idx_spec = pl.BlockSpec((idx_block,), lambda i: (i,))
     return pl.pallas_call(
-        kernel,
-        grid=grid,
+        _gather_kernel,
+        grid=(n // idx_block,),
         in_specs=[
             # whole table every step: the pipeline loads it once and the
             # revisiting steps reuse the resident copy
             pl.BlockSpec(table.shape, lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
-            idx_spec,
+            pl.BlockSpec((idx_block,), lambda i: (i,),
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((idx_block, table.shape[1]),
                                lambda i: (i, 0)),
@@ -204,21 +121,11 @@ def use_vmem_gather(table: jax.Array) -> bool:
                              fits_vmem(table))
 
 
-def gather_method() -> str:
-    """The kernel variant the recorded verdict crowned for this device
-    kind (``taa`` when no verdict names one or names an unknown)."""
+def gather_idx_block() -> int:
+    """The index-block size the recorded verdict crowned for this
+    device kind (the default when none names one)."""
     v = calibration.lookup("vmem_gather", calibration.device_key())
-    m = (v or {}).get("method", "taa")
-    return m if m in _METHODS else "taa"
-
-
-def gather_idx_block() -> int | None:
-    """The index-block size the verdict crowned (None = the method's
-    default) — taa's chunk redundancy scales with table_rows/idx_block,
-    so the A/B measures more than one block size."""
-    v = calibration.lookup("vmem_gather", calibration.device_key())
-    b = (v or {}).get("idx_block")
-    return int(b) if b else None
+    return int((v or {}).get("idx_block") or _DEF_IDX_BLOCK)
 
 
 def masked_vmem_gather(table: jax.Array, slots: jax.Array,
@@ -228,13 +135,11 @@ def masked_vmem_gather(table: jax.Array, slots: jax.Array,
     zeroes invalid rows — identical semantics to
     ``transfer.xla._masked_gather`` (clip keeps padding defined)."""
     n = slots.shape[0]
-    method = gather_method()
-    blk = gather_idx_block() or (
-        _TAA_IDX_BLOCK if method == "taa" else _DEF_IDX_BLOCK)
+    blk = gather_idx_block()
     safe = jnp.where(valid, slots, 0)
     pad = (-n) % blk
     if pad:
         safe = jnp.concatenate(
             [safe, jnp.zeros((pad,), slots.dtype)])
-    rows = vmem_gather(table, safe, idx_block=blk, method=method)[:n]
+    rows = vmem_gather(table, safe, idx_block=blk)[:n]
     return jnp.where(valid[:, None], rows, 0)

@@ -12,8 +12,8 @@ collective.
 ``ring_exchange(x, axis, n)`` is a drop-in for
 ``jax.lax.all_to_all(x, axis, 0, 0, tiled=True)`` on a (n, C, ...)
 operand inside ``shard_map``: block j of the result is the block this
-device received from device j.  Schedule: the local block is copied
-VMEM-locally; then, at ring step s = 1..n-1, this device RDMA-sends
+device received from device j.  Schedule: the local block is copied by
+an on-chip DMA; then, at ring step s = 1..n-1, this device RDMA-sends
 block ``(my_id + s) % n`` of its operand into slot ``my_id`` of the
 receiver's output — every device sends to distance-s neighbor at step
 s, so each step is a pure ring shift and the n-1 steps saturate both
@@ -24,7 +24,7 @@ Device addressing uses scalar ``DeviceIdType.LOGICAL`` ids — the mesh
 must be 1-D over ``axis`` (``use_ring_push`` refuses otherwise), which
 keeps the logical id equal to the axis index on chip and is the only
 form the interpret-mode discharge rule supports, so the 8-device CPU
-parity tests exercise the identical kernel.
+parity tests exercise the same DMA schedule.
 
 Routing: ``use_ring_push`` resolves the ``[cluster] data_plane:`` knob
 via ``calibration.data_plane_gated`` (kernel name ``ring_push``, env
@@ -35,6 +35,7 @@ multi-chip mesh, the ``all_to_all`` wire exchange stays.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -43,11 +44,30 @@ from jax.experimental.pallas import tpu as pltpu
 
 from swiftmpi_tpu.ops import calibration
 
+_LANES = 128
+#: elements in one (32, 128) tile — whole tiles for every dtype down to
+#: 8 bits
+_TILE_ELEMS = 32 * _LANES
 
-def _ring_kernel(n: int, my_id_ref, x_ref, out_ref, send_sem, recv_sem):
+
+def _ring_kernel(n: int, barrier: bool, my_id_ref, x_ref, out_ref,
+                 local_sem, send_sem, recv_sem):
     my_id = my_id_ref[0]
-    # local block: straight VMEM copy, no wire
-    out_ref[pl.ds(my_id, 1)] = x_ref[pl.ds(my_id, 1)]
+    if barrier:
+        # on chip every peer must have entered the kernel before
+        # anything is written into its output buffer (the interpreter
+        # runs the devices in lockstep and has no barrier semaphore)
+        sem = pltpu.get_barrier_semaphore()
+        for s in range(1, n):
+            pltpu.semaphore_signal(
+                sem, inc=1, device_id=jax.lax.rem(my_id + s, n),
+                device_id_type=pltpu.DeviceIdType.LOGICAL)
+        pltpu.semaphore_wait(sem, n - 1)
+    # local block: an on-chip DMA, no wire (x/out live in HBM, which
+    # Mosaic cannot load from or store to directly)
+    local = pltpu.make_async_copy(x_ref.at[pl.ds(my_id, 1)],
+                                  out_ref.at[pl.ds(my_id, 1)], local_sem)
+    local.start()
 
     def step(s):
         dst = jax.lax.rem(my_id + s, n)
@@ -64,6 +84,7 @@ def _ring_kernel(n: int, my_id_ref, x_ref, out_ref, send_sem, recv_sem):
     # transfers; per-step semaphores keep each send/recv pair exact
     for s in range(1, n):
         step(s).start()
+    local.wait()
     for s in range(1, n):
         step(s).wait()
 
@@ -83,17 +104,29 @@ def ring_exchange(x: jax.Array, axis: str, n: int,
     if interpret is None:
         interpret = not calibration.on_tpu()
     my_id = jax.lax.axis_index(axis).reshape((1,)).astype(jnp.int32)
-    return pl.pallas_call(
-        functools.partial(_ring_kernel, n),
+    # Mosaic can only slice a block off an UNTILED leading dim of whole
+    # (sublane, lane) tiles: a (n, C) int32 bucket tiles its first dim,
+    # a (n, C, 101) grad bucket has a ragged lane dim.  Exchange the
+    # bucket as (n, R, 128) with each block zero-padded to whole tiles.
+    shape, block_len = x.shape, math.prod(x.shape[1:])
+    flat = x.reshape(n, block_len)
+    pad = (-block_len) % _TILE_ELEMS
+    if pad:
+        flat = jnp.pad(flat, ((0, 0), (0, pad)))
+    x = flat.reshape(n, -1, _LANES)
+    out = pl.pallas_call(
+        functools.partial(_ring_kernel, n, not interpret),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        scratch_shapes=[pltpu.SemaphoreType.DMA((max(n - 1, 1),)),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(()),
+                        pltpu.SemaphoreType.DMA((max(n - 1, 1),)),
                         pltpu.SemaphoreType.DMA((max(n - 1, 1),))],
-        compiler_params=pltpu.TPUCompilerParams(collective_id=0),
+        compiler_params=pltpu.CompilerParams(collective_id=0),
         interpret=interpret,
     )(my_id, x)
+    return out.reshape(n, -1)[:, :block_len].reshape(shape)
 
 
 def use_ring_push(n: int, single_axis: bool, mode: str = "auto") -> bool:
@@ -107,29 +140,3 @@ def use_ring_push(n: int, single_axis: bool, mode: str = "auto") -> bool:
     fits = n > 1 and single_axis
     return calibration.data_plane_gated(
         mode, "ring_push", "SMTPU_RING_PUSH", fits, manual=True)
-
-
-def ring_supported(mesh, axis: str) -> bool:
-    """Capability probe: can the ring kernel actually run on this
-    mesh/backend (interpret discharge on CPU, Mosaic on chip)?  Runs a
-    tiny exchange under ``shard_map`` and reports success — the parity
-    tests and call sites use this to skip rather than crash on
-    environments whose pallas build lacks remote-DMA support."""
-    try:
-        from swiftmpi_tpu.utils import jax_compat  # noqa: F401  (shim)
-        n = mesh.shape[axis]
-        if n < 2:
-            return False
-        from jax.sharding import PartitionSpec as P
-
-        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(axis),
-                           out_specs=P(axis), check_vma=False)
-        def tiny(x):
-            return ring_exchange(x[0], axis, n)[None]
-
-        x = jnp.arange(n * n * 8, dtype=jnp.float32).reshape(n, n, 8)
-        want = jax.jit(tiny)(x)
-        ref = x.reshape(n, n, 8).transpose(1, 0, 2)
-        return bool(jnp.allclose(want.reshape(n, n, 8), ref))
-    except Exception:
-        return False

@@ -46,15 +46,15 @@ def _scatter_kernel(idx_ref, g_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    idx = idx_ref[...]
-
     def body(j, _):
-        row = idx[j]
+        # index read as an SMEM scalar: extracting it from a vector
+        # value lowers to dynamic_slice, which Mosaic does not implement
+        row = idx_ref[j]
         g = g_ref[pl.ds(j, 1), :]
         out_ref[pl.ds(row, 1), :] = out_ref[pl.ds(row, 1), :] + g
         return 0
 
-    jax.lax.fori_loop(0, idx.shape[0], body, 0)
+    jax.lax.fori_loop(0, idx_ref.shape[0], body, 0)
 
 
 @functools.partial(jax.jit,
@@ -78,7 +78,8 @@ def vmem_scatter_add(idx: jax.Array, grads: jax.Array, capacity: int,
         _scatter_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((idx_block,), lambda i: (i,)),
+            pl.BlockSpec((idx_block,), lambda i: (i,),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((idx_block, W), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((capacity + 1, W), lambda i: (0, 0),

@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from swiftmpi_tpu.utils import jax_compat  # noqa: F401  (lax.axis_size alias)
-
 
 def psum(x, axis: str):
     """Dense gradient combine (the reference's server-side add across

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from swiftmpi_tpu.utils import jax_compat  # noqa: F401  (jax.shard_map alias)
 from swiftmpi_tpu.parallel.collectives import ring_permute
 
 STAGE_AXIS = "stage"

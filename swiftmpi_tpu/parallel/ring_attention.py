@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from swiftmpi_tpu.utils import jax_compat  # noqa: F401  (jax.shard_map alias)
 from swiftmpi_tpu.parallel.collectives import all_to_all, ring_permute
 
 SEQ_AXIS = "seq"

@@ -38,7 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from swiftmpi_tpu.utils import jax_compat  # noqa: F401  (jax.shard_map alias)
 from swiftmpi_tpu.cluster.mesh import SHARD_AXIS
 from swiftmpi_tpu.parameter.sparse_table import (base_field, hot_name,
                                                  is_hot_field)

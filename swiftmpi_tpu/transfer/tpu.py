@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from swiftmpi_tpu.utils import jax_compat  # noqa: F401  (jax.shard_map alias)
 from swiftmpi_tpu.cluster.mesh import DATA_AXIS, SHARD_AXIS
 from swiftmpi_tpu.obs import costs as obs_costs
 from swiftmpi_tpu.ops import (calibration, pallas_gather, pallas_ring,
@@ -267,8 +266,10 @@ class TpuTransfer(Transfer):
         routing, not of the wire format."""
         fields = tuple(fields)
         slots = jnp.asarray(slots, jnp.int32)
+        n_req = slots.shape[0]
         if self.count_traffic:
             self._record_routed(jnp.sum(slots >= 0))
+        (slots,) = self._pad_batch(slots)
         sig = self._signature(state, slots) + (fields,)
         fn = self._pull_cache.get(sig)
         if fn is None:
@@ -276,9 +277,12 @@ class TpuTransfer(Transfer):
                 sig, obs_costs.track("tpu_pull", jax.jit(
                     self._build_pull(state, fields))))
         if self.bucket_capacity is None:
-            return fn(state, slots)
-        out, ovf = fn(state, slots)
-        self._record_overflow("pull", ovf)
+            out = fn(state, slots)
+        else:
+            out, ovf = fn(state, slots)
+            self._record_overflow("pull", ovf)
+        if slots.shape[0] != n_req:
+            out = {f: v[:n_req] for f, v in out.items()}
         return out
 
     def _batch_spec(self):
@@ -286,6 +290,25 @@ class TpuTransfer(Transfer):
         groups each carry their own slice of the global batch)."""
         return P((self.dp_axis, self.axis)) if self.dp_axis \
             else P(self.axis)
+
+    def _pad_batch(self, slots, *rows):
+        """shard_map splits the request axis evenly over every device,
+        so a batch whose length is not a multiple of the device count
+        (625 centers x 21 targets on 4 chips) is padded with ``-1``
+        slots — padding by the transfer contract — and zero rows.
+        ``rows`` are pytrees of per-request arrays."""
+        pad = (-slots.shape[0]) % self.mesh.size
+        if not pad:
+            return (slots, *rows)
+
+        def pad_rows(a):
+            a = jnp.asarray(a)
+            return jnp.concatenate(
+                [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)])
+
+        slots = jnp.concatenate(
+            [slots, jnp.full((pad,), -1, slots.dtype)])
+        return (slots, *jax.tree.map(pad_rows, rows))
 
     def _build_pull(self, state, fields):
         capacity = next(iter(state.values())).shape[0]
@@ -360,6 +383,7 @@ class TpuTransfer(Transfer):
             grads = dict(grads)
             grads["__counts__"] = jnp.asarray(
                 counts, jnp.float32).reshape(-1, 1)
+        slots, grads = self._pad_batch(slots, grads)
         sig = self._signature(state, slots, grads) + (mean, with_counts)
         fn = self._push_cache.get(sig)
         if fn is None:
@@ -414,6 +438,7 @@ class TpuTransfer(Transfer):
         representative row."""
         counts_in = fcounts if fcounts is not None else jnp.ones(
             flat.shape, jnp.float32)
+        flat, fgrads, counts_in = self._pad_batch(flat, fgrads, counts_in)
         sig = (capacity, tuple(flat.shape),
                tuple(sorted((f, tuple(v.shape), str(v.dtype))
                             for f, v in fgrads.items())))
@@ -462,6 +487,7 @@ class TpuTransfer(Transfer):
         with_counts = fcounts is not None
         counts_in = fcounts if with_counts else jnp.ones(
             flat.shape, jnp.float32)
+        flat, fgrads, counts_in = self._pad_batch(flat, fgrads, counts_in)
         sig = self._signature(state, flat, fgrads) + (
             mean, with_counts, "window_dense")
         fn = self._window_dense_cache.get(sig)

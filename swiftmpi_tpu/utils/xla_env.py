@@ -1,68 +1,52 @@
-"""XLA environment setup for the emulated multi-device CPU platform.
+"""XLA / JAX environment setup for entry points.
 
-Must run BEFORE the first jax import in the process (env-var flags are
-read at backend init).  Importing this module is side-effect free and
-jax-free, so test conftests and entry scripts can call it first thing.
+Importing this module is side-effect free and jax-free, so test
+conftests and entry scripts can import it first thing:
+``ensure_cpu_mesh_flags`` must run BEFORE the first jax import in the
+process (env-var flags are read at backend init);
+``ensure_compile_cache`` imports jax itself and must run before the
+first compile.
 """
 
 from __future__ import annotations
 
-import glob
-import importlib.util
-import mmap
 import os
 
-_FLAG_SUPPORT_CACHE: dict = {}
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+ENV_COMPILE_CACHE = "JAX_COMPILATION_CACHE_DIR"
 
 
-def _xla_extension_path():
-    """Locate jaxlib's xla_extension shared object WITHOUT importing
-    jaxlib (find_spec only reads metadata — this module must stay
-    import-side-effect free and jax-free, see test_utils.py)."""
-    try:
-        spec = importlib.util.find_spec("jaxlib")
-    except (ImportError, ValueError):
-        return None
-    if spec is None or not spec.submodule_search_locations:
-        return None
-    for d in spec.submodule_search_locations:
-        # the binary only — the same prefix also matches the .pyi stub
-        # package dir, whose bytes say nothing about registered flags
-        hits = sorted(glob.glob(os.path.join(d, "xla_extension*.so"))
-                      + glob.glob(os.path.join(d, "xla_extension*.pyd")))
-        if hits:
-            return hits[0]
-    return None
+def ensure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns the directory
+    in use.  Every entry point that compiles calls this once.
 
-
-def xla_flag_supported(flag: str) -> bool:
-    """True if the installed jaxlib's XLA recognises ``flag``.
-
-    XLA calls ``abort()`` on ANY unknown name in XLA_FLAGS
-    (parse_flags_from_env.cc) — on jaxlib 0.4.x that kills the process at
-    backend init with "Fatal Python error: Aborted", so every
-    version-dependent flag must be probed before it is appended.  Probe:
-    registered flag names are embedded verbatim in the xla_extension
-    binary (they come from the DebugOptions proto descriptor), so a
-    substring scan of the .so decides without spawning a subprocess or
-    initialising a backend.  Unknown/unprobeable → False: not appending
-    a flag is always safe, appending an unknown one never is.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, nothing is touched — JAX
+    reads it itself and no other directory is named in code, so whoever
+    runs the program decides where compiled programs survive.  Where it
+    is not, the cache goes to ``<checkout>/.jax_cache`` (gitignored): a
+    FIXED path, because the directory is part of what a later process
+    must agree on to hit — never a tempdir, a pid or a timestamp.
     """
-    name = flag.lstrip("-").split("=", 1)[0]
-    cached = _FLAG_SUPPORT_CACHE.get(name)
-    if cached is not None:
-        return cached
-    ok = False
-    path = _xla_extension_path()
-    if path:
-        try:
-            with open(path, "rb") as f, \
-                    mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ) as m:
-                ok = m.find(name.encode()) != -1
-        except (OSError, ValueError):
-            ok = False
-    _FLAG_SUPPORT_CACHE[name] = ok
-    return ok
+    env = os.environ.get(ENV_COMPILE_CACHE)
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def compile_cache_entries(path: str) -> int:
+    """Number of compiled programs stored under ``path`` (0 when the
+    directory does not exist yet).  JAX keeps one ``<key>-cache`` file
+    per program next to its ``-atime`` bookkeeping file."""
+    try:
+        return sum(1 for f in os.listdir(path) if not f.endswith("-atime"))
+    except FileNotFoundError:
+        return 0
 
 
 def ensure_cpu_mesh_flags(n_devices: int | None = None,
@@ -93,17 +77,9 @@ def ensure_cpu_mesh_flags(n_devices: int | None = None,
             or "--xla_force_host_platform_device_count" not in flags):
         flags += f" --xla_force_host_platform_device_count={n_devices}"
     # each timeout flag guarded on ITS OWN substring: a caller who set
-    # only one of the pair keeps their value (last-occurrence-wins would
-    # otherwise silently override it — round-2 advisor finding).  Both
-    # are probed against the installed jaxlib: older XLAs (e.g. jaxlib
-    # 0.4.36) don't know them and abort() the whole process at backend
-    # init on any unknown XLA_FLAGS entry.
-    if ("--xla_cpu_collective_call_warn_stuck_timeout_seconds"
-            not in flags and xla_flag_supported(
-                "xla_cpu_collective_call_warn_stuck_timeout_seconds")):
+    # only one of the pair keeps their value
+    if "--xla_cpu_collective_call_warn_stuck_timeout_seconds" not in flags:
         flags += " --xla_cpu_collective_call_warn_stuck_timeout_seconds=60"
-    if ("--xla_cpu_collective_call_terminate_timeout_seconds"
-            not in flags and xla_flag_supported(
-                "xla_cpu_collective_call_terminate_timeout_seconds")):
+    if "--xla_cpu_collective_call_terminate_timeout_seconds" not in flags:
         flags += " --xla_cpu_collective_call_terminate_timeout_seconds=600"
     os.environ["XLA_FLAGS"] = flags

@@ -285,3 +285,17 @@ def test_native_dump_empty_table(tmp_path, devices8):
     path = str(tmp_path / "empty.txt")
     assert dump_table_text(t, path, fields=("val",)) == 0
     assert open(path).read() == ""
+
+
+def test_native_dump_reports_a_failed_write():
+    """A write the OS refuses (here: /dev/full, ENOSPC) raises — it must
+    not return the row count over a short file."""
+    import errno
+    import os
+    if not os.path.exists("/dev/full"):
+        pytest.skip("no /dev/full on this host")
+    keys = np.arange(1, 5001, dtype=np.uint64)
+    rows = np.ones((5000, 64), np.float32) / 3
+    with pytest.raises(OSError) as e:
+        native.dump_rows_native("/dev/full", keys, [rows])
+    assert e.value.errno == errno.ENOSPC

@@ -281,22 +281,9 @@ def test_w2v_step_with_pallas_scatter_matches_xla(monkeypatch, devices8):
         np.testing.assert_allclose(st1[f], st0[f], rtol=1e-5, atol=1e-6)
 
 
-def test_vmem_gather_loop_variant_matches_take(devices8):
-    """The per-row loop fallback kernel must produce exactly what the
-    vectorized take kernel does (interpret mode)."""
-    from swiftmpi_tpu.ops.pallas_gather import vmem_gather
-
-    rng = np.random.default_rng(11)
-    table = jnp.asarray(rng.standard_normal((301, 24)), jnp.float32)
-    idx = jnp.asarray(rng.integers(-1, 301, 512), jnp.int32)
-    a = vmem_gather(table, idx, idx_block=128, method="take")
-    b = vmem_gather(table, idx, idx_block=128, method="loop")
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
 def test_calibration_clear_removes_only_named_kernel(monkeypatch,
                                                      tmp_path):
-    """The rollback path (chip_session verdict_rollback): clearing one
+    """The rollback path: clearing one
     kernel's verdicts must not touch other kernels' entries."""
     from swiftmpi_tpu.ops import calibration
 
@@ -418,7 +405,7 @@ def test_calibration_stack_stamp_and_staleness(monkeypatch, tmp_path,
 
     # externally-written file: one pre-stamp entry, one foreign-stack
     # entry, one current-stack entry
-    raw["stencil_fused:TPU v4"] = {
+    raw["vmem_scatter:TPU v4"] = {
         "win": True, "pallas_ms": 1.0, "xla_ms": 2.0}
     raw["vmem_gather:TPU v4"] = {
         "win": True, "pallas_ms": 1.0, "xla_ms": 2.0,
@@ -426,12 +413,12 @@ def test_calibration_stack_stamp_and_staleness(monkeypatch, tmp_path,
     path.write_text(json.dumps(raw))
     calibration.reset_cache()
 
-    assert calibration.lookup("stencil_fused", "TPU v4") is None
+    assert calibration.lookup("vmem_scatter", "TPU v4") is None
     err = capsys.readouterr().err
-    assert "RE-CALIBRATE" in err and "stencil_fused:TPU v4" in err
+    assert "RE-CALIBRATE" in err and "vmem_scatter:TPU v4" in err
     assert "pre-stamp" in err
     # the warning fires once per key, not per lookup
-    assert calibration.lookup("stencil_fused", "TPU v4") is None
+    assert calibration.lookup("vmem_scatter", "TPU v4") is None
     assert "RE-CALIBRATE" not in capsys.readouterr().err
 
     assert calibration.lookup("vmem_gather", "TPU v4") is None
@@ -443,7 +430,7 @@ def test_calibration_stack_stamp_and_staleness(monkeypatch, tmp_path,
     assert calibration.lookup("ring_push", "TPU v5 lite")["win"]
 
     stale = dict(calibration.stale_keys())
-    assert set(stale) == {"stencil_fused:TPU v4", "vmem_gather:TPU v4"}
+    assert set(stale) == {"vmem_scatter:TPU v4", "vmem_gather:TPU v4"}
     calibration.reset_cache()
 
 
@@ -469,11 +456,11 @@ def test_calibration_stale_check_cli(monkeypatch, tmp_path, capsys):
     assert "match the current stack" in capsys.readouterr().out
 
     raw = json.loads(path.read_text())
-    raw["stencil_fused:TPU v4"] = {"win": True}
+    raw["vmem_scatter:TPU v4"] = {"win": True}
     path.write_text(json.dumps(raw))
     calibration.reset_cache()
     assert calibration.main(["--stale-check"]) == 0
     out = capsys.readouterr().out
     assert "ADVISORY" in out and "1/2" in out
-    assert "stencil_fused:TPU v4" in out
+    assert "vmem_scatter:TPU v4" in out
     calibration.reset_cache()

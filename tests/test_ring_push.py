@@ -2,9 +2,8 @@
 8-device CPU mesh): ring_exchange parity vs ``lax.all_to_all`` on float
 and int operands, the knob/mesh routing gate, and end-to-end TpuTransfer
 push / push_span / push_window parity with the ring forced on — the
-on-chip A/B lives in ``scripts/scatter_micro.py --ring-ab``.  Every
-kernel-running test is capability-probed (``ring_supported``) and skips
-rather than fails on pallas builds without remote-DMA interpret support.
+on-chip A/B lives in ``scripts/scatter_micro.py --ring-ab``.  A kernel
+that cannot run here is a failure, not a skip.
 """
 
 import numpy as np
@@ -17,20 +16,15 @@ from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 from swiftmpi_tpu.cluster import SHARD_AXIS, ps_mesh  # noqa: E402
 from swiftmpi_tpu.ops import calibration  # noqa: E402
 from swiftmpi_tpu.ops.pallas_ring import (ring_exchange,  # noqa: E402
-                                          ring_supported, use_ring_push)
+                                          use_ring_push)
 from swiftmpi_tpu.parameter import KeyIndex, SparseTable  # noqa: E402
 from swiftmpi_tpu.parameter import w2v_access  # noqa: E402
 from swiftmpi_tpu.transfer.tpu import TpuTransfer  # noqa: E402
-from swiftmpi_tpu.utils import jax_compat  # noqa: F401,E402
 
 
 @pytest.fixture
 def ring_mesh(devices8):
-    mesh = Mesh(np.asarray(devices8), ("x",))
-    if not ring_supported(mesh, "x"):
-        pytest.skip("pallas remote-DMA interpret discharge unsupported "
-                    "on this jax build")
-    return mesh
+    return Mesh(np.asarray(devices8), ("x",))
 
 
 def _wrap(mesh, f):
@@ -113,10 +107,7 @@ def _arm(monkeypatch, mesh, flag):
     # fresh transfer per arm: the push program cache is per-instance and
     # the ring/all_to_all choice is resolved at build time
     monkeypatch.setenv("SMTPU_RING_PUSH", flag)
-    t = TpuTransfer(mesh)
-    if flag == "1" and not ring_supported(mesh, t.axis):
-        pytest.skip("pallas remote-DMA interpret discharge unsupported")
-    return t
+    return TpuTransfer(mesh)
 
 
 @pytest.mark.parametrize("mean", [False, True])

@@ -124,6 +124,28 @@ def test_push_replica_scatter_gate_matches_plain(w2v_setup, monkeypatch):
     assert xla_mod._replica_R(1 << 20, 128) == 0
 
 
+def test_tpu_backend_batch_not_a_multiple_of_devices(w2v_setup):
+    """625 centers x 21 targets is not a multiple of 4 chips: the routed
+    backend pads the request axis itself (found on the chip, PR 21 — the
+    demo.conf batch raised in shard_map) and matches the oracle."""
+    mesh, access, table, slots, grads, state_np = w2v_setup
+    slots = slots[:61]
+    grads = {f: g[:61] for f, g in grads.items()}
+    local, tpu = LocalTransfer(), TpuTransfer(mesh)
+    want = local.pull(state_np, slots, access)
+    got = tpu.pull(table.state, slots, access)
+    for f in access.pull_fields:
+        assert got[f].shape == want[f].shape
+        np.testing.assert_allclose(want[f], np.asarray(got[f]),
+                                   rtol=1e-6, atol=1e-7, err_msg=f)
+    for mean in (False, True):
+        want = local.push(state_np, slots, grads, access, mean=mean)
+        got = tpu.push(table.state, slots, grads, access, mean=mean)
+        for f in access.fields:
+            np.testing.assert_allclose(want[f], np.asarray(got[f]),
+                                       rtol=1e-5, atol=1e-6, err_msg=f)
+
+
 def test_push_sums_duplicate_slots(devices8):
     # Two pushes of the same slot in one batch must combine by SUM before a
     # single AdaGrad application (api.py semantics).

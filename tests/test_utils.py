@@ -1,5 +1,7 @@
 """Unit tests for the utils layer (config, cmdline, hashing, rng, buffers)."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -223,3 +225,57 @@ def test_xla_env_import_is_jax_free():
          "assert 'jax' not in sys.modules, 'xla_env import pulled in jax'"],
         capture_output=True, text=True, timeout=120)
     assert p.returncode == 0, p.stderr
+
+
+def test_cpu_mesh_flags_append_both_collective_timeouts(monkeypatch):
+    """The installed jaxlib knows both rendezvous-timeout flags, so they
+    are appended unprobed; a caller's own value for either one wins."""
+    from swiftmpi_tpu.utils.xla_env import ensure_cpu_mesh_flags
+
+    warn = "--xla_cpu_collective_call_warn_stuck_timeout_seconds"
+    term = "--xla_cpu_collective_call_terminate_timeout_seconds"
+    monkeypatch.setenv("XLA_FLAGS", "")
+    ensure_cpu_mesh_flags()
+    assert f"{warn}=60" in os.environ["XLA_FLAGS"]
+    assert f"{term}=600" in os.environ["XLA_FLAGS"]
+    monkeypatch.setenv("XLA_FLAGS", f"{term}=5")
+    ensure_cpu_mesh_flags()
+    assert os.environ["XLA_FLAGS"].count(term) == 1
+    assert f"{warn}=60" in os.environ["XLA_FLAGS"]
+
+
+def _cache_dir_in_child(env_value):
+    """(helper's return value, jax's configured directory) in a fresh
+    interpreter — the helper updates process-global jax config."""
+    import subprocess
+    import sys
+
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_value is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_value
+    p = subprocess.run(
+        [sys.executable, "-c",
+         "from swiftmpi_tpu.utils.xla_env import ensure_compile_cache; "
+         "import jax; print(ensure_compile_cache()); "
+         "print(jax.config.jax_compilation_cache_dir)"],
+        capture_output=True, text=True, timeout=120, env=env)
+    assert p.returncode == 0, p.stderr
+    return p.stdout.split()
+
+
+def test_compile_cache_env_is_left_alone(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX reads it itself and the helper
+    names no other directory."""
+    placed = str(tmp_path / "placed")
+    assert _cache_dir_in_child(placed) == [placed, placed]
+
+
+def test_compile_cache_defaults_to_checkout():
+    """Unset: <checkout>/.jax_cache, a fixed path."""
+    from swiftmpi_tpu.utils.xla_env import REPO_ROOT
+
+    want = os.path.join(REPO_ROOT, ".jax_cache")
+    assert REPO_ROOT == os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))
+    assert _cache_dir_in_child(None) == [want, want]
