@@ -1,0 +1,25 @@
+#!/usr/bin/env python3
+"""Look at a trace by hand: planes, lines, event counts and the first events
+of each line of an ``.xplane.pb``.
+
+    python3 benchmark/tools/dump_trace.py <file.xplane.pb> [events-per-line]
+"""
+
+import sys
+
+from jax.profiler import ProfileData
+
+
+def main(path: str, n: int = 5) -> None:
+    for plane in ProfileData.from_file(path).planes:
+        print(f"PLANE {plane.name!r}")
+        for line in plane.lines:
+            events = list(line.events)
+            print(f"  LINE {line.name!r}: {len(events)} events")
+            for e in events[:n]:
+                print(f"    {e.name[:90]!r} start_ns={e.start_ns:.0f} "
+                      f"duration_ns={e.duration_ns:.0f}")
+
+
+if __name__ == "__main__":
+    main(sys.argv[1], int(sys.argv[2]) if len(sys.argv) > 2 else 5)
