@@ -154,8 +154,10 @@ class PrefetchIterator:
         if self._stop.is_set():
             raise StopIteration
         t0 = time.monotonic()
-        with obs.span("input_wait"):
+        with obs.span("input_wait") as wait:
             item = self._q.get()
+            if item is _DONE or item is _CLOSED:
+                wait.drop()     # one `input_wait` sample per item
         self._stall_s += time.monotonic() - t0
         if item is _DONE or item is _CLOSED:
             # drain-order guarantee: _DONE lands after every real item

@@ -156,20 +156,21 @@ def _cbow_targets(slot_of_vocab, alias_prob, alias_idx, centers,
     both the gather and dense renderings — their identical sampling
     stream (the basis of the dense mode's parity guarantee) is
     identical by construction, not by parallel maintenance."""
-    B = centers.shape[0]
-    # fused draw: negatives and their table slots from ONE packed row
-    # gather (sampling was ~6.5ms of the 17.7ms chip step as separate
-    # scalar gathers — see ops/sampling.sample_alias_slots)
-    negs, neg_slots = sample_alias_slots(
-        key, alias_prob, alias_idx, slot_of_vocab, (B, K))
-    t_slots = jnp.concatenate(
-        [slot_of_vocab[centers][:, None], neg_slots], axis=1)  # (B, K+1)
-    ctx_slots = jnp.where(ctx_mask, slot_of_vocab[contexts], -1)
-    row_valid = ctx_mask.any(axis=1)
-    # negative == center is skipped (word2vec.h:584-586)
-    t_valid = jnp.concatenate(
-        [jnp.ones((B, 1), bool), negs != centers[:, None]], axis=1)
-    t_valid = t_valid & row_valid[:, None]
+    with obs.named_scope("sample"):
+        B = centers.shape[0]
+        # fused draw: negatives and their table slots from ONE packed row
+        # gather (sampling was ~6.5ms of the 17.7ms chip step as separate
+        # scalar gathers — see ops/sampling.sample_alias_slots)
+        negs, neg_slots = sample_alias_slots(
+            key, alias_prob, alias_idx, slot_of_vocab, (B, K))
+        t_slots = jnp.concatenate(
+            [slot_of_vocab[centers][:, None], neg_slots], axis=1)  # (B, K+1)
+        ctx_slots = jnp.where(ctx_mask, slot_of_vocab[contexts], -1)
+        row_valid = ctx_mask.any(axis=1)
+        # negative == center is skipped (word2vec.h:584-586)
+        t_valid = jnp.concatenate(
+            [jnp.ones((B, 1), bool), negs != centers[:, None]], axis=1)
+        t_valid = t_valid & row_valid[:, None]
     return t_slots, ctx_slots, t_valid
 
 
@@ -393,6 +394,9 @@ class Word2Vec:
         self._step = None
         self._fused_cache = {}
         self._tail_fuse_frozen = False
+        #: train steps dispatched by this model, over all train() calls:
+        #: the `dispatch` span's step= (a fused group carries steps=L)
+        self._steps_dispatched = 0
         self._key = jax.random.key(seed ^ 0x5EED)
         # per-train() observability: hogwild tail-skip count, hybrid
         # transfer traffic counters — refreshed by every train() call
@@ -993,37 +997,39 @@ class Word2Vec:
             t_slots, ctx_slots, t_valid = _cbow_targets(
                 slot_of_vocab, alias_prob, alias_idx, centers, contexts,
                 ctx_mask, key, K)
-            t_slots = jnp.where(t_valid, t_slots, -1)
+            with obs.named_scope("sample"):
+                t_slots = jnp.where(t_valid, t_slots, -1)
 
-            # split pulls: targets need only h, contexts only v —
-            # pulling both fields for the union of slots would gather
-            # twice the bytes and discard half (fp32 upcast restores
-            # precision when the table stores bf16)
-            h_t = transfer.pull(
-                state, t_slots.reshape(-1), access, fields=("h",)
-            )["h"].reshape(B, K + 1, d).astype(jnp.float32)
-            v_ctx = transfer.pull(
-                state, ctx_slots.reshape(-1), access, fields=("v",)
-            )["v"].reshape(B, W2, d).astype(jnp.float32)
+            with obs.named_scope("math"):
+                # split pulls: targets need only h, contexts only v —
+                # pulling both fields for the union of slots would gather
+                # twice the bytes and discard half (fp32 upcast restores
+                # precision when the table stores bf16)
+                h_t = transfer.pull(
+                    state, t_slots.reshape(-1), access, fields=("h",)
+                )["h"].reshape(B, K + 1, d).astype(jnp.float32)
+                v_ctx = transfer.pull(
+                    state, ctx_slots.reshape(-1), access, fields=("v",)
+                )["v"].reshape(B, W2, d).astype(jnp.float32)
 
-            neu1 = jnp.sum(v_ctx * ctx_mask[..., None], axis=1)   # (B, d)
-            f = jnp.einsum("bd,bkd->bk", neu1, h_t)
-            labels = jnp.concatenate(
-                [jnp.ones((B, 1)), jnp.zeros((B, K))], axis=1)
-            g = (labels - sigmoid_clipped(f)) * alpha
-            g = jnp.where(t_valid, g, 0.0)                        # (B, K+1)
+                neu1 = jnp.sum(v_ctx * ctx_mask[..., None], axis=1)   # (B, d)
+                f = jnp.einsum("bd,bkd->bk", neu1, h_t)
+                labels = jnp.concatenate(
+                    [jnp.ones((B, 1)), jnp.zeros((B, K))], axis=1)
+                g = (labels - sigmoid_clipped(f)) * alpha
+                g = jnp.where(t_valid, g, 0.0)  # (B, K+1)
 
-            h_contrib = g[..., None] * neu1[:, None, :]           # (B,K+1,d)
-            neu1e = jnp.einsum("bk,bkd->bd", g, h_t)              # (B, d)
-            v_contrib = jnp.where(ctx_mask[..., None],
-                                  neu1e[:, None, :], 0.0)         # (B,2W,d)
+                h_contrib = g[..., None] * neu1[:, None, :]  # (B,K+1,d)
+                neu1e = jnp.einsum("bk,bkd->bd", g, h_t)              # (B, d)
+                v_contrib = jnp.where(ctx_mask[..., None],
+                                      neu1e[:, None, :], 0.0)  # (B,2W,d)
 
-            pushes = _assemble_push(
-                t_slots.reshape(-1), ctx_slots.reshape(-1),
-                h_contrib.reshape(-1, d), v_contrib.reshape(-1, d))
+                pushes = _assemble_push(
+                    t_slots.reshape(-1), ctx_slots.reshape(-1),
+                    h_contrib.reshape(-1, d), v_contrib.reshape(-1, d))
 
-            err_sum = jnp.sum(1e4 * g * g)          # word2vec.h:593
-            err_cnt = t_valid.sum()
+                err_sum = jnp.sum(1e4 * g * g)          # word2vec.h:593
+                err_cnt = t_valid.sum()
             return pushes, err_sum, err_cnt
 
         return grads_fn
@@ -1074,42 +1080,45 @@ class Word2Vec:
             t_slots, ctx_slots, t_valid = _cbow_targets(
                 slot_of_vocab, alias_prob, alias_idx, centers, contexts,
                 ctx_mask, key, K)
-            safe_t = jnp.clip(jnp.where(t_valid, t_slots, 0), 0, cap - 1)
+            with obs.named_scope("sample"):
+                safe_t = jnp.clip(jnp.where(t_valid, t_slots, 0), 0,
+                                  cap - 1)
 
-            v_ctx = transfer.pull(
-                state, ctx_slots.reshape(-1), access, fields=("v",)
-            )["v"].reshape(B, W2, d).astype(jnp.float32)
-            neu1 = jnp.sum(v_ctx * ctx_mask[..., None], axis=1)  # (B, d)
+            with obs.named_scope("math"):
+                v_ctx = transfer.pull(
+                    state, ctx_slots.reshape(-1), access, fields=("v",)
+                )["v"].reshape(B, W2, d).astype(jnp.float32)
+                neu1 = jnp.sum(v_ctx * ctx_mask[..., None], axis=1)  # (B, d)
 
-            h_all = state["h"].astype(jnp.float32)        # (cap, d)
-            F = neu1 @ h_all.T                            # (B, cap) MXU
-            f = jnp.take_along_axis(F, safe_t, axis=1)    # (B, K+1)
-            labels = jnp.concatenate(
-                [jnp.ones((B, 1)), jnp.zeros((B, K))], axis=1)
-            g = (labels - sigmoid_clipped(f)) * alpha
-            g = jnp.where(t_valid, g, 0.0)
+                h_all = state["h"].astype(jnp.float32)        # (cap, d)
+                F = neu1 @ h_all.T                            # (B, cap) MXU
+                f = jnp.take_along_axis(F, safe_t, axis=1)    # (B, K+1)
+                labels = jnp.concatenate(
+                    [jnp.ones((B, 1)), jnp.zeros((B, K))], axis=1)
+                g = (labels - sigmoid_clipped(f)) * alpha
+                g = jnp.where(t_valid, g, 0.0)
 
-            rows = jnp.arange(B)[:, None]
-            G = jnp.zeros((B, cap), jnp.float32).at[rows, safe_t].add(g)
-            # counts scatter straight to (cap,): 344K scalar adds are
-            # noise next to the three O(B*cap) matmuls, and a (B, cap)
-            # count plane would cost another ~1.1GB buffer at bench
-            # shape just to be row-summed away
-            counts = jnp.zeros((cap,), jnp.float32).at[
-                safe_t.reshape(-1)].add(
-                t_valid.reshape(-1).astype(jnp.float32), mode="drop")
-            h_grad = (G.T @ neu1) / jnp.maximum(counts, 1.0)[:, None]
-            neu1e = G @ h_all                             # (B, d)
-            v_contrib = jnp.where(ctx_mask[..., None],
-                                  neu1e[:, None, :], 0.0)
+                rows = jnp.arange(B)[:, None]
+                G = jnp.zeros((B, cap), jnp.float32).at[rows, safe_t].add(g)
+                # counts scatter straight to (cap,): 344K scalar adds are
+                # noise next to the three O(B*cap) matmuls, and a (B, cap)
+                # count plane would cost another ~1.1GB buffer at bench
+                # shape just to be row-summed away
+                counts = jnp.zeros((cap,), jnp.float32).at[
+                    safe_t.reshape(-1)].add(
+                    t_valid.reshape(-1).astype(jnp.float32), mode="drop")
+                h_grad = (G.T @ neu1) / jnp.maximum(counts, 1.0)[:, None]
+                neu1e = G @ h_all                             # (B, d)
+                v_contrib = jnp.where(ctx_mask[..., None],
+                                      neu1e[:, None, :], 0.0)
 
-            pushes = (PushSpec(None, {"h": h_grad}, dense=True),
-                      PushSpec(ctx_slots.reshape(-1),
-                               {"v": v_contrib.reshape(-1, d)},
-                               mean=True))
+                pushes = (PushSpec(None, {"h": h_grad}, dense=True),
+                          PushSpec(ctx_slots.reshape(-1),
+                                   {"v": v_contrib.reshape(-1, d)},
+                                   mean=True))
 
-            err_sum = jnp.sum(1e4 * g * g)
-            err_cnt = t_valid.sum()
+                err_sum = jnp.sum(1e4 * g * g)
+                err_cnt = t_valid.sum()
             return pushes, err_sum, err_cnt
 
         return grads_fn
@@ -1146,72 +1155,74 @@ class Word2Vec:
         def grads_fn(state, slot_of_vocab, alias_prob, alias_idx,
                      centers, contexts, ctx_mask, key):
             B, W2 = contexts.shape
-            negs = sample_alias(key, alias_prob, alias_idx, (K,))
-            c_slots = slot_of_vocab[centers]                  # (B,)
-            n_slots = slot_of_vocab[negs]                     # (K,)
-            ctx_slots = jnp.where(ctx_mask, slot_of_vocab[contexts], -1)
-            row_valid = ctx_mask.any(axis=1)
+            with obs.named_scope("sample"):
+                negs = sample_alias(key, alias_prob, alias_idx, (K,))
+                c_slots = slot_of_vocab[centers]                  # (B,)
+                n_slots = slot_of_vocab[negs]                     # (K,)
+                ctx_slots = jnp.where(ctx_mask, slot_of_vocab[contexts], -1)
+                row_valid = ctx_mask.any(axis=1)
 
-            pulled_h = transfer.pull(
-                state, jnp.concatenate([c_slots, n_slots]), access,
-                fields=("h",))["h"].astype(jnp.float32)
-            h_pos = pulled_h[:B]                              # (B, d)
-            h_neg = pulled_h[B:B + K]                         # (K, d)
-            v_ctx = transfer.pull(
-                state, ctx_slots.reshape(-1), access, fields=("v",)
-            )["v"].reshape(B, W2, d).astype(jnp.float32)
+            with obs.named_scope("math"):
+                pulled_h = transfer.pull(
+                    state, jnp.concatenate([c_slots, n_slots]), access,
+                    fields=("h",))["h"].astype(jnp.float32)
+                h_pos = pulled_h[:B]                              # (B, d)
+                h_neg = pulled_h[B:B + K]                         # (K, d)
+                v_ctx = transfer.pull(
+                    state, ctx_slots.reshape(-1), access, fields=("v",)
+                )["v"].reshape(B, W2, d).astype(jnp.float32)
 
-            neu1 = jnp.sum(v_ctx * ctx_mask[..., None], axis=1)
-            f_pos = jnp.einsum("bd,bd->b", neu1, h_pos)       # (B,)
-            f_neg = neu1 @ h_neg.T                            # (B, K) MXU
-            g_pos = (1.0 - sigmoid_clipped(f_pos)) * alpha
-            g_pos = jnp.where(row_valid, g_pos, 0.0)
-            # negative == center skipped (word2vec.h:584-586)
-            n_valid = (negs[None, :] != centers[:, None]) \
-                & row_valid[:, None]
-            g_neg = jnp.where(n_valid,
-                              (0.0 - sigmoid_clipped(f_neg)) * alpha, 0.0)
-            # keep the objective's positive/negative balance at the
-            # configured `negative` draws per center: the pool evaluates
-            # K pairs per center, so each carries weight negative/K
-            gw = g_neg * (self.negative / K)
+                neu1 = jnp.sum(v_ctx * ctx_mask[..., None], axis=1)
+                f_pos = jnp.einsum("bd,bd->b", neu1, h_pos)       # (B,)
+                f_neg = neu1 @ h_neg.T                            # (B, K) MXU
+                g_pos = (1.0 - sigmoid_clipped(f_pos)) * alpha
+                g_pos = jnp.where(row_valid, g_pos, 0.0)
+                # negative == center skipped (word2vec.h:584-586)
+                n_valid = (negs[None, :] != centers[:, None]) \
+                    & row_valid[:, None]
+                g_neg = jnp.where(n_valid,
+                                  (0.0 - sigmoid_clipped(f_neg)) * alpha, 0.0)
+                # keep the objective's positive/negative balance at the
+                # configured `negative` draws per center: the pool evaluates
+                # K pairs per center, so each carries weight negative/K
+                gw = g_neg * (self.negative / K)
 
-            gh_pos = g_pos[:, None] * neu1                    # (B, d)
-            gh_neg = gw.T @ neu1                              # (K, d) MXU
-            neu1e = g_pos[:, None] * h_pos + gw @ h_neg       # (B, d) MXU
-            v_contrib = jnp.where(ctx_mask[..., None],
-                                  neu1e[:, None, :], 0.0)
+                gh_pos = g_pos[:, None] * neu1                    # (B, d)
+                gh_neg = gw.T @ neu1                              # (K, d) MXU
+                neu1e = g_pos[:, None] * h_pos + gw @ h_neg       # (B, d) MXU
+                v_contrib = jnp.where(ctx_mask[..., None],
+                                      neu1e[:, None, :], 0.0)
 
-            # Three push families.  Positives and contexts keep the
-            # reference's per-key mean normalization.  The pool rows are
-            # pushed as their OWN family with SUM semantics: each row
-            # already carries the sum of its ~B per-pair contributions —
-            # the exact gradient of the pairwise NS objective — and it
-            # must NOT share a count vector with the centers, or a
-            # frequent word appearing hundreds of times as a center in
-            # the same batch would have its one summed negative row
-            # divided by that count (~100-1000x attenuation at bench
-            # shapes: exactly the 'negatives stop training' collapse
-            # documented above, smuggled back in through normalization).
-            # Duplicate pool draws of one key sum too — each draw is a
-            # sample, as in the reference's per-center draws.
-            pos_slots = jnp.where(row_valid, c_slots, -1)
-            neg_slots = jnp.where(n_valid.any(axis=0), n_slots, -1)
-            cslots_flat = ctx_slots.reshape(-1)
-            v_flat = v_contrib.reshape(-1, d)
-            pushes = (PushSpec(pos_slots, {"h": gh_pos}, mean=True),
-                      PushSpec(neg_slots, {"h": gh_neg}),
-                      PushSpec(cslots_flat, {"v": v_flat}, mean=True))
+                # Three push families.  Positives and contexts keep the
+                # reference's per-key mean normalization.  The pool rows are
+                # pushed as their OWN family with SUM semantics: each row
+                # already carries the sum of its ~B per-pair contributions —
+                # the exact gradient of the pairwise NS objective — and it
+                # must NOT share a count vector with the centers, or a
+                # frequent word appearing hundreds of times as a center in
+                # the same batch would have its one summed negative row
+                # divided by that count (~100-1000x attenuation at bench
+                # shapes: exactly the 'negatives stop training' collapse
+                # documented above, smuggled back in through normalization).
+                # Duplicate pool draws of one key sum too — each draw is a
+                # sample, as in the reference's per-center draws.
+                pos_slots = jnp.where(row_valid, c_slots, -1)
+                neg_slots = jnp.where(n_valid.any(axis=0), n_slots, -1)
+                cslots_flat = ctx_slots.reshape(-1)
+                v_flat = v_contrib.reshape(-1, d)
+                pushes = (PushSpec(pos_slots, {"h": gh_pos}, mean=True),
+                          PushSpec(neg_slots, {"h": gh_neg}),
+                          PushSpec(cslots_flat, {"v": v_flat}, mean=True))
 
-            # loss terms carry the same negative/K weighting as the
-            # gradients (advisor r04, both shared-pool variants): a
-            # center contributes ~1 positive + ~`negative` weighted pool
-            # terms, keeping the reported loss scale-comparable with the
-            # per-center parity CBOW rendering
-            ratio = self.negative / K
-            err_sum = jnp.sum(1e4 * g_pos * g_pos) \
-                + ratio * jnp.sum(1e4 * g_neg * g_neg)
-            err_cnt = row_valid.sum() + ratio * n_valid.sum()
+                # loss terms carry the same negative/K weighting as the
+                # gradients (advisor r04, both shared-pool variants): a
+                # center contributes ~1 positive + ~`negative` weighted pool
+                # terms, keeping the reported loss scale-comparable with the
+                # per-center parity CBOW rendering
+                ratio = self.negative / K
+                err_sum = jnp.sum(1e4 * g_pos * g_pos) \
+                    + ratio * jnp.sum(1e4 * g_neg * g_neg)
+                err_cnt = row_valid.sum() + ratio * n_valid.sum()
             return pushes, err_sum, err_cnt
 
         return grads_fn
@@ -1267,39 +1278,42 @@ class Word2Vec:
                           center_pos, half):
             S = tokens.shape[0]
             B = center_pos.shape[0]
-            span_valid = sent_id >= 0
-            span_slots = jnp.where(span_valid, slot_of_vocab[tokens], -1)
-            row_valid = center_pos >= 0
-            cp = jnp.clip(center_pos, 0, S - 1)
-            centers = tokens[cp]                             # (B,) vocab
-            c_slots = jnp.where(row_valid, span_slots[cp], -1)
-            ctx_idx = cp[:, None] + offsets[None, :]         # (B, 2W)
-            ci = jnp.clip(ctx_idx, 0, S - 1)
-            ctx_mask = ((ctx_idx >= 0) & (ctx_idx < S)
-                        & (sent_id[ci] == sent_id[cp][:, None])
-                        & (jnp.abs(offsets)[None, :] <= half[:, None])
-                        & row_valid[:, None])
-            # THE gather this rendering exists for: ≤ B + 2W unique rows
-            v_span = transfer.pull(
-                state, span_slots, access, fields=("v",)
-            )["v"].astype(jnp.float32)                       # (S, d)
-            v_ctx = v_span[ci]        # span-local gather, not HBM rows
-            neu1 = jnp.sum(v_ctx * ctx_mask[..., None], axis=1)
+            with obs.named_scope("sample"):
+                span_valid = sent_id >= 0
+                span_slots = jnp.where(span_valid, slot_of_vocab[tokens], -1)
+                row_valid = center_pos >= 0
+                cp = jnp.clip(center_pos, 0, S - 1)
+                centers = tokens[cp]                             # (B,) vocab
+                c_slots = jnp.where(row_valid, span_slots[cp], -1)
+                ctx_idx = cp[:, None] + offsets[None, :]         # (B, 2W)
+                ci = jnp.clip(ctx_idx, 0, S - 1)
+                ctx_mask = ((ctx_idx >= 0) & (ctx_idx < S)
+                            & (sent_id[ci] == sent_id[cp][:, None])
+                            & (jnp.abs(offsets)[None, :] <= half[:, None])
+                            & row_valid[:, None])
+            with obs.named_scope("math"):
+                # THE gather this rendering exists for: ≤ B + 2W unique rows
+                v_span = transfer.pull(
+                    state, span_slots, access, fields=("v",)
+                )["v"].astype(jnp.float32)                       # (S, d)
+                v_ctx = v_span[ci]        # span-local gather, not HBM rows
+                neu1 = jnp.sum(v_ctx * ctx_mask[..., None], axis=1)
             return span_slots, centers, c_slots, ci, ctx_mask, neu1
 
         def v_push(span_slots, ci, ctx_mask, neu1e, S):
-            # invert the stencil: per-pair context grads land on SPAN
-            # positions (dense batch-local indices, not a capacity
-            # scatter); contribution counts ride along so push_span's
-            # mean normalization divides by the true pair count
-            contrib = jnp.where(ctx_mask[..., None],
-                                neu1e[:, None, :], 0.0)
-            vg = jnp.zeros((S, d), jnp.float32).at[
-                ci.reshape(-1)].add(contrib.reshape(-1, d))
-            vc = jnp.zeros((S,), jnp.float32).at[
-                ci.reshape(-1)].add(
-                ctx_mask.reshape(-1).astype(jnp.float32))
-            return PushSpec(span_slots, {"v": vg}, mean=True, counts=vc)
+            with obs.named_scope("math"):
+                # invert the stencil: per-pair context grads land on SPAN
+                # positions (dense batch-local indices, not a capacity
+                # scatter); contribution counts ride along so push_span's
+                # mean normalization divides by the true pair count
+                contrib = jnp.where(ctx_mask[..., None],
+                                    neu1e[:, None, :], 0.0)
+                vg = jnp.zeros((S, d), jnp.float32).at[
+                    ci.reshape(-1)].add(contrib.reshape(-1, d))
+                vc = jnp.zeros((S,), jnp.float32).at[
+                    ci.reshape(-1)].add(
+                    ctx_mask.reshape(-1).astype(jnp.float32))
+                return PushSpec(span_slots, {"v": vg}, mean=True, counts=vc)
 
         def grads_fn(state, slot_of_vocab, alias_prob, alias_idx,
                      tokens, sent_id, center_pos, half, key):
@@ -1310,65 +1324,69 @@ class Word2Vec:
                                    sent_id, center_pos, half)
             row_valid = center_pos >= 0
             if shared:
-                negs = sample_alias(key, alias_prob, alias_idx, (K,))
-                n_slots = slot_of_vocab[negs]                # (K,)
-                pulled_h = transfer.pull(
-                    state, jnp.concatenate([c_slots, n_slots]), access,
-                    fields=("h",))["h"].astype(jnp.float32)
-                h_pos = pulled_h[:B]
-                h_neg = pulled_h[B:B + K]
-                f_pos = jnp.einsum("bd,bd->b", neu1, h_pos)
-                f_neg = neu1 @ h_neg.T                       # (B, K) MXU
-                g_pos = jnp.where(
-                    row_valid, (1.0 - sigmoid_clipped(f_pos)) * alpha,
-                    0.0)
-                # negative == center skipped (word2vec.h:584-586)
-                n_valid = (negs[None, :] != centers[:, None]) \
-                    & row_valid[:, None]
-                g_neg = jnp.where(
-                    n_valid, (0.0 - sigmoid_clipped(f_neg)) * alpha, 0.0)
-                gw = g_neg * (self.negative / K)
-                gh_pos = g_pos[:, None] * neu1
-                gh_neg = gw.T @ neu1                         # (K, d) MXU
-                neu1e = g_pos[:, None] * h_pos + gw @ h_neg
-                neg_slots = jnp.where(n_valid.any(axis=0), n_slots, -1)
-                # pool rows push as their own SUM family; see the
-                # normalization-collapse note in _build_grads_shared
-                pushes = (PushSpec(c_slots, {"h": gh_pos}, mean=True),
-                          PushSpec(neg_slots, {"h": gh_neg}),
-                          v_push(span_slots, ci, ctx_mask, neu1e, S))
-                ratio = self.negative / K
-                err_sum = jnp.sum(1e4 * g_pos * g_pos) \
-                    + ratio * jnp.sum(1e4 * g_neg * g_neg)
-                err_cnt = row_valid.sum() + ratio * n_valid.sum()
+                with obs.named_scope("sample"):
+                    negs = sample_alias(key, alias_prob, alias_idx, (K,))
+                    n_slots = slot_of_vocab[negs]                # (K,)
+                with obs.named_scope("math"):
+                    pulled_h = transfer.pull(
+                        state, jnp.concatenate([c_slots, n_slots]), access,
+                        fields=("h",))["h"].astype(jnp.float32)
+                    h_pos = pulled_h[:B]
+                    h_neg = pulled_h[B:B + K]
+                    f_pos = jnp.einsum("bd,bd->b", neu1, h_pos)
+                    f_neg = neu1 @ h_neg.T                       # (B, K) MXU
+                    g_pos = jnp.where(
+                        row_valid, (1.0 - sigmoid_clipped(f_pos)) * alpha,
+                        0.0)
+                    # negative == center skipped (word2vec.h:584-586)
+                    n_valid = (negs[None, :] != centers[:, None]) \
+                        & row_valid[:, None]
+                    g_neg = jnp.where(
+                        n_valid, (0.0 - sigmoid_clipped(f_neg)) * alpha, 0.0)
+                    gw = g_neg * (self.negative / K)
+                    gh_pos = g_pos[:, None] * neu1
+                    gh_neg = gw.T @ neu1                         # (K, d) MXU
+                    neu1e = g_pos[:, None] * h_pos + gw @ h_neg
+                    neg_slots = jnp.where(n_valid.any(axis=0), n_slots, -1)
+                    # pool rows push as their own SUM family; see the
+                    # normalization-collapse note in _build_grads_shared
+                    pushes = (PushSpec(c_slots, {"h": gh_pos}, mean=True),
+                              PushSpec(neg_slots, {"h": gh_neg}),
+                              v_push(span_slots, ci, ctx_mask, neu1e, S))
+                    ratio = self.negative / K
+                    err_sum = jnp.sum(1e4 * g_pos * g_pos) \
+                        + ratio * jnp.sum(1e4 * g_neg * g_neg)
+                    err_cnt = row_valid.sum() + ratio * n_valid.sum()
                 return pushes, err_sum, err_cnt
-            # parity negatives: per-center draws from the SAME sampling
-            # stream as _cbow_targets — the oracle test's anchor
-            negs, neg_slots = sample_alias_slots(
-                key, alias_prob, alias_idx, slot_of_vocab, (B, K))
-            t_slots = jnp.concatenate(
-                [c_slots[:, None], neg_slots], axis=1)       # (B, K+1)
-            t_valid = jnp.concatenate(
-                [jnp.ones((B, 1), bool), negs != centers[:, None]],
-                axis=1)
-            t_valid = t_valid & row_valid[:, None]
-            t_slots = jnp.where(t_valid, t_slots, -1)
-            h_t = transfer.pull(
-                state, t_slots.reshape(-1), access, fields=("h",)
-            )["h"].reshape(B, K + 1, d).astype(jnp.float32)
-            f = jnp.einsum("bd,bkd->bk", neu1, h_t)
-            labels = jnp.concatenate(
-                [jnp.ones((B, 1)), jnp.zeros((B, K))], axis=1)
-            g = (labels - sigmoid_clipped(f)) * alpha
-            g = jnp.where(t_valid, g, 0.0)                   # (B, K+1)
-            h_contrib = g[..., None] * neu1[:, None, :]      # (B,K+1,d)
-            neu1e = jnp.einsum("bk,bkd->bd", g, h_t)         # (B, d)
-            pushes = (PushSpec(t_slots.reshape(-1),
-                               {"h": h_contrib.reshape(-1, d)},
-                               mean=True),
-                      v_push(span_slots, ci, ctx_mask, neu1e, S))
-            err_sum = jnp.sum(1e4 * g * g)          # word2vec.h:593
-            err_cnt = t_valid.sum()
+            with obs.named_scope("sample"):
+                # parity negatives: per-center draws from the SAME sampling
+                # stream as _cbow_targets — the oracle test's anchor
+                negs, neg_slots = sample_alias_slots(
+                    key, alias_prob, alias_idx, slot_of_vocab, (B, K))
+                t_slots = jnp.concatenate(
+                    [c_slots[:, None], neg_slots], axis=1)       # (B, K+1)
+                t_valid = jnp.concatenate(
+                    [jnp.ones((B, 1), bool), negs != centers[:, None]],
+                    axis=1)
+                t_valid = t_valid & row_valid[:, None]
+                t_slots = jnp.where(t_valid, t_slots, -1)
+            with obs.named_scope("math"):
+                h_t = transfer.pull(
+                    state, t_slots.reshape(-1), access, fields=("h",)
+                )["h"].reshape(B, K + 1, d).astype(jnp.float32)
+                f = jnp.einsum("bd,bkd->bk", neu1, h_t)
+                labels = jnp.concatenate(
+                    [jnp.ones((B, 1)), jnp.zeros((B, K))], axis=1)
+                g = (labels - sigmoid_clipped(f)) * alpha
+                g = jnp.where(t_valid, g, 0.0)                   # (B, K+1)
+                h_contrib = g[..., None] * neu1[:, None, :]      # (B,K+1,d)
+                neu1e = jnp.einsum("bk,bkd->bd", g, h_t)         # (B, d)
+                pushes = (PushSpec(t_slots.reshape(-1),
+                                   {"h": h_contrib.reshape(-1, d)},
+                                   mean=True),
+                          v_push(span_slots, ci, ctx_mask, neu1e, S))
+                err_sum = jnp.sum(1e4 * g * g)          # word2vec.h:593
+                err_cnt = t_valid.sum()
             return pushes, err_sum, err_cnt
 
         return grads_fn
@@ -1388,43 +1406,45 @@ class Word2Vec:
         def grads_fn(state, slot_of_vocab, alias_prob, alias_idx,
                      centers, contexts, ctx_mask, key):
             B, W2 = contexts.shape
-            negs, neg_slots = sample_alias_slots(
-                key, alias_prob, alias_idx, slot_of_vocab, (B, W2, K))
-            # negative == center is skipped (word2vec.h:584-586); padding
-            # pairs are fully dead.
-            t_valid = jnp.concatenate(
-                [jnp.ones((B, W2, 1), bool),
-                 negs != centers[:, None, None]], axis=2)
-            t_valid = t_valid & ctx_mask[..., None]
-            c_slots = jnp.broadcast_to(
-                slot_of_vocab[centers][:, None, None], (B, W2, 1))
-            t_slots = jnp.where(
-                t_valid, jnp.concatenate([c_slots, neg_slots], axis=2), -1)
-            ctx_slots = jnp.where(ctx_mask, slot_of_vocab[contexts], -1)
+            with obs.named_scope("sample"):
+                negs, neg_slots = sample_alias_slots(
+                    key, alias_prob, alias_idx, slot_of_vocab, (B, W2, K))
+                # negative == center is skipped (word2vec.h:584-586); padding
+                # pairs are fully dead.
+                t_valid = jnp.concatenate(
+                    [jnp.ones((B, W2, 1), bool),
+                     negs != centers[:, None, None]], axis=2)
+                t_valid = t_valid & ctx_mask[..., None]
+                c_slots = jnp.broadcast_to(
+                    slot_of_vocab[centers][:, None, None], (B, W2, 1))
+                t_slots = jnp.where(
+                    t_valid, jnp.concatenate([c_slots, neg_slots], axis=2), -1)
+                ctx_slots = jnp.where(ctx_mask, slot_of_vocab[contexts], -1)
 
-            h_t = transfer.pull(
-                state, t_slots.reshape(-1), access, fields=("h",)
-            )["h"].reshape(B, W2, K + 1, d).astype(jnp.float32)
-            v_in = transfer.pull(
-                state, ctx_slots.reshape(-1), access, fields=("v",)
-            )["v"].reshape(B, W2, d).astype(jnp.float32)
+            with obs.named_scope("math"):
+                h_t = transfer.pull(
+                    state, t_slots.reshape(-1), access, fields=("h",)
+                )["h"].reshape(B, W2, K + 1, d).astype(jnp.float32)
+                v_in = transfer.pull(
+                    state, ctx_slots.reshape(-1), access, fields=("v",)
+                )["v"].reshape(B, W2, d).astype(jnp.float32)
 
-            f = jnp.einsum("bwd,bwkd->bwk", v_in, h_t)
-            labels = jnp.concatenate(
-                [jnp.ones((B, W2, 1)), jnp.zeros((B, W2, K))], axis=2)
-            g = (labels - sigmoid_clipped(f)) * alpha
-            g = jnp.where(t_valid, g, 0.0)                    # (B, W2, K+1)
+                f = jnp.einsum("bwd,bwkd->bwk", v_in, h_t)
+                labels = jnp.concatenate(
+                    [jnp.ones((B, W2, 1)), jnp.zeros((B, W2, K))], axis=2)
+                g = (labels - sigmoid_clipped(f)) * alpha
+                g = jnp.where(t_valid, g, 0.0)  # (B, W2, K+1)
 
-            h_contrib = g[..., None] * v_in[:, :, None, :]    # (B,W2,K+1,d)
-            v_contrib = jnp.einsum("bwk,bwkd->bwd", g, h_t)   # (B, W2, d)
-            v_contrib = jnp.where(ctx_mask[..., None], v_contrib, 0.0)
+                h_contrib = g[..., None] * v_in[:, :, None, :]  # (B,W2,K+1,d)
+                v_contrib = jnp.einsum("bwk,bwkd->bwd", g, h_t)   # (B, W2, d)
+                v_contrib = jnp.where(ctx_mask[..., None], v_contrib, 0.0)
 
-            pushes = _assemble_push(
-                t_slots.reshape(-1), ctx_slots.reshape(-1),
-                h_contrib.reshape(-1, d), v_contrib.reshape(-1, d))
+                pushes = _assemble_push(
+                    t_slots.reshape(-1), ctx_slots.reshape(-1),
+                    h_contrib.reshape(-1, d), v_contrib.reshape(-1, d))
 
-            err_sum = jnp.sum(1e4 * g * g)          # word2vec.h:593
-            err_cnt = t_valid.sum()
+                err_sum = jnp.sum(1e4 * g * g)          # word2vec.h:593
+                err_cnt = t_valid.sum()
             return pushes, err_sum, err_cnt
 
         return grads_fn
@@ -1464,62 +1484,64 @@ class Word2Vec:
         def grads_fn(state, slot_of_vocab, alias_prob, alias_idx,
                      centers, contexts, ctx_mask, key):
             B, W2 = contexts.shape
-            negs = sample_alias(key, alias_prob, alias_idx, (K,))
-            c_slots = slot_of_vocab[centers]                  # (B,)
-            n_slots = slot_of_vocab[negs]                     # (K,)
-            ctx_slots = jnp.where(ctx_mask, slot_of_vocab[contexts], -1)
+            with obs.named_scope("sample"):
+                negs = sample_alias(key, alias_prob, alias_idx, (K,))
+                c_slots = slot_of_vocab[centers]                  # (B,)
+                n_slots = slot_of_vocab[negs]                     # (K,)
+                ctx_slots = jnp.where(ctx_mask, slot_of_vocab[contexts], -1)
 
-            pulled_h = transfer.pull(
-                state, jnp.concatenate([c_slots, n_slots]), access,
-                fields=("h",))["h"].astype(jnp.float32)
-            h_pos = pulled_h[:B]                              # (B, d)
-            h_neg = pulled_h[B:B + K]                         # (K, d)
-            v_in = transfer.pull(
-                state, ctx_slots.reshape(-1), access, fields=("v",)
-            )["v"].reshape(B, W2, d).astype(jnp.float32)
+            with obs.named_scope("math"):
+                pulled_h = transfer.pull(
+                    state, jnp.concatenate([c_slots, n_slots]), access,
+                    fields=("h",))["h"].astype(jnp.float32)
+                h_pos = pulled_h[:B]                              # (B, d)
+                h_neg = pulled_h[B:B + K]                         # (K, d)
+                v_in = transfer.pull(
+                    state, ctx_slots.reshape(-1), access, fields=("v",)
+                )["v"].reshape(B, W2, d).astype(jnp.float32)
 
-            # positive pair (b, w): v[context] . h[center_b]
-            f_pos = jnp.einsum("bwd,bd->bw", v_in, h_pos)     # (B, W2)
-            g_pos = (1.0 - sigmoid_clipped(f_pos)) * alpha
-            g_pos = jnp.where(ctx_mask, g_pos, 0.0)
+                # positive pair (b, w): v[context] . h[center_b]
+                f_pos = jnp.einsum("bwd,bd->bw", v_in, h_pos)     # (B, W2)
+                g_pos = (1.0 - sigmoid_clipped(f_pos)) * alpha
+                g_pos = jnp.where(ctx_mask, g_pos, 0.0)
 
-            f_neg = jnp.einsum("bwd,kd->bwk", v_in, h_neg)    # MXU
-            # negative == center skipped (word2vec.h:584-586); padding
-            # pairs are fully dead
-            n_valid = (negs[None, None, :] != centers[:, None, None]) \
-                & ctx_mask[..., None]
-            g_neg = jnp.where(n_valid,
-                              (0.0 - sigmoid_clipped(f_neg)) * alpha, 0.0)
-            # keep the objective's positive/negative balance at the
-            # configured `negative` draws per pair
-            gw = g_neg * (self.negative / K)                  # (B, W2, K)
+                f_neg = jnp.einsum("bwd,kd->bwk", v_in, h_neg)    # MXU
+                # negative == center skipped (word2vec.h:584-586); padding
+                # pairs are fully dead
+                n_valid = (negs[None, None, :] != centers[:, None, None]) \
+                    & ctx_mask[..., None]
+                g_neg = jnp.where(n_valid,
+                                  (0.0 - sigmoid_clipped(f_neg)) * alpha, 0.0)
+                # keep the objective's positive/negative balance at the
+                # configured `negative` draws per pair
+                gw = g_neg * (self.negative / K)                  # (B, W2, K)
 
-            # per-pair positive grads -> h[center], per-key mean (same
-            # normalization the parity sg push applies per pair)
-            gh_pos = g_pos[..., None] * v_in                  # (B, W2, d)
-            gh_neg = jnp.einsum("bwk,bwd->kd", gw, v_in)      # (K, d) MXU
-            v_contrib = g_pos[..., None] * h_pos[:, None, :] \
-                + gw @ h_neg                                  # (B, W2, d)
-            v_contrib = jnp.where(ctx_mask[..., None], v_contrib, 0.0)
+                # per-pair positive grads -> h[center], per-key mean (same
+                # normalization the parity sg push applies per pair)
+                gh_pos = g_pos[..., None] * v_in                  # (B, W2, d)
+                gh_neg = jnp.einsum("bwk,bwd->kd", gw, v_in)      # (K, d) MXU
+                v_contrib = g_pos[..., None] * h_pos[:, None, :] \
+                    + gw @ h_neg                                  # (B, W2, d)
+                v_contrib = jnp.where(ctx_mask[..., None], v_contrib, 0.0)
 
-            pos_slots = jnp.where(
-                ctx_mask, jnp.broadcast_to(c_slots[:, None], (B, W2)), -1)
-            neg_slots = jnp.where(n_valid.any(axis=(0, 1)), n_slots, -1)
-            pushes = (PushSpec(pos_slots.reshape(-1),
-                               {"h": gh_pos.reshape(-1, d)}, mean=True),
-                      PushSpec(neg_slots, {"h": gh_neg}),
-                      PushSpec(ctx_slots.reshape(-1),
-                               {"v": v_contrib.reshape(-1, d)}, mean=True))
+                pos_slots = jnp.where(
+                    ctx_mask, jnp.broadcast_to(c_slots[:, None], (B, W2)), -1)
+                neg_slots = jnp.where(n_valid.any(axis=(0, 1)), n_slots, -1)
+                pushes = (PushSpec(pos_slots.reshape(-1),
+                                   {"h": gh_pos.reshape(-1, d)}, mean=True),
+                          PushSpec(neg_slots, {"h": gh_neg}),
+                          PushSpec(ctx_slots.reshape(-1),
+                                   {"v": v_contrib.reshape(-1, d)}, mean=True))
 
-            # loss terms carry the SAME negative/K weighting as the
-            # gradients (advisor r04): a pair contributes ~1 positive +
-            # ~`negative` weighted pool terms, so the reported loss is
-            # scale-comparable with the per-pair parity sg rendering
-            # instead of ~K/negative times off
-            ratio = self.negative / K
-            err_sum = jnp.sum(1e4 * g_pos * g_pos) \
-                + ratio * jnp.sum(1e4 * g_neg * g_neg)
-            err_cnt = ctx_mask.sum() + ratio * n_valid.sum()
+                # loss terms carry the SAME negative/K weighting as the
+                # gradients (advisor r04): a pair contributes ~1 positive +
+                # ~`negative` weighted pool terms, so the reported loss is
+                # scale-comparable with the per-pair parity sg rendering
+                # instead of ~K/negative times off
+                ratio = self.negative / K
+                err_sum = jnp.sum(1e4 * g_pos * g_pos) \
+                    + ratio * jnp.sum(1e4 * g_neg * g_neg)
+                err_cnt = ctx_mask.sum() + ratio * n_valid.sum()
             return pushes, err_sum, err_cnt
 
         return grads_fn
@@ -1534,7 +1556,8 @@ class Word2Vec:
                     # capacity-shaped, pre-normalized grads (dense-logits
                     # mode): apply the access rule directly — untouched
                     # rows carry exact zero and are no-ops
-                    new_fields = access.apply_push(state, spec.grads)
+                    with obs.named_scope("apply"):
+                        new_fields = access.apply_push(state, spec.grads)
                     state = dict(state)
                     state.update(new_fields)
                 elif getattr(spec, "counts", None) is not None:
@@ -1632,6 +1655,11 @@ class Word2Vec:
         iterator (e.g. the native C++ ``NativeCBOWBatcher``); its vocab
         indexing must match this model's vocab (both pipelines sort by
         (count desc, key asc), so python- and native-built vocabs agree)."""
+        # host spans of the whole call (obs.catalog.HOST_SPANS):
+        # train_setup runs from here to the first next(items)
+        setup_span = obs.span("train_setup")
+        setup_span.__enter__()
+        setup_seen = obs.get_registry().enabled
         if batcher is None:
             if isinstance(data, str):
                 data = load_corpus(data, min_sentence_length=max(
@@ -1715,6 +1743,11 @@ class Word2Vec:
         owns_rec = tel_rec is None
         if owns_rec:
             tel_rec = obs.configure(self.config, run="word2vec")
+            if not setup_seen and obs.get_registry().enabled:
+                # the process's first call: configure armed the plane just
+                # now, so this call's span can only open after it
+                setup_span = obs.span("train_setup")
+                setup_span.__enter__()
         if tel_rec is not None:
             def _tel_sample(reg, _m=meter):
                 reg.counter("train/host_stall_ms_total").set_total(
@@ -1785,6 +1818,18 @@ class Word2Vec:
             pipe_stats = {"produced": 0, "consumed": 0,
                           "peak_queue_depth": 0, "stall_s": 0.0,
                           "transfer_s": 0.0}
+        def end_setup():
+            nonlocal setup_span
+            if setup_span is not None:
+                setup_span.__exit__(None, None, None)
+                setup_span = None
+
+        def to_device(fields):
+            if pipelined:      # the producer thread put them: its `h2d`
+                return tuple(_dev(f) for f in fields)
+            with obs.span("h2d"):
+                return tuple(_dev(f) for f in fields)
+
         for it in range(niters):
             # global step: cumulative across resumed runs, so a fault
             # plan's crash-at-step-k means "after k completed steps"
@@ -1794,6 +1839,7 @@ class Word2Vec:
                 state = self._poison_row(state)
                 frozen = state
             if hogwild:
+                end_setup()
                 err_sum, err_cnt, it_dropped = self._hogwild_epoch(
                     batcher, batch_size, meter)
                 hogwild_dropped += it_dropped
@@ -1815,10 +1861,10 @@ class Word2Vec:
                     nonlocal state, frozen, step_i
                     self._key, sub = jax.random.split(self._key)
                     args = (self._slot_of_vocab, self._alias_prob,
-                            self._alias_idx,
-                            *(_dev(f) for f in fields), sub)
+                            self._alias_idx, *to_device(fields), sub)
                     if sync:
-                        with obs.span("dispatch"):
+                        with obs.span("dispatch", steps=1,
+                                      step=self._steps_dispatched):
                             state, es, ec = self._step(state, *args)
                         # the step donates (deletes) the input state
                         # buffers; repoint the table at the live ones
@@ -1832,7 +1878,8 @@ class Word2Vec:
                         # immediately; snapshot refreshes every
                         # local_steps batches => bounded staleness.
                         grads_fn, apply_fn = self._step
-                        with obs.span("dispatch"):
+                        with obs.span("dispatch", steps=1,
+                                      step=self._steps_dispatched):
                             pushes, es, ec = grads_fn(frozen, *args)
                             state = apply_fn(state, pushes)
                         self.table.state = state
@@ -1841,6 +1888,7 @@ class Word2Vec:
                             frozen = state
                     es_q.add(es)
                     ec_q.add(ec)
+                    self._steps_dispatched += 1
                     meter.record(n_words)
                     obs.record_step(1)
                     self._serve_on_steps(1)
@@ -1875,14 +1923,16 @@ class Word2Vec:
                                        n_words[i])
                         return
                     self._key, sub = jax.random.split(self._key)
-                    with obs.span("dispatch"):
+                    fields = to_device(fields)
+                    with obs.span("dispatch", steps=L,
+                                  step=self._steps_dispatched):
                         state, es, ec = fused(
                             state, self._slot_of_vocab, self._alias_prob,
-                            self._alias_idx,
-                            *(_dev(f) for f in fields), sub)
+                            self._alias_idx, *fields, sub)
                     self.table.state = state
                     es_q.add(es)
                     ec_q.add(ec)
+                    self._steps_dispatched += L
                     # a fused group is ONE dispatch but L train steps;
                     # stall_ms_per_step stays per-step across fuse modes
                     meter.record(sum(n_words), steps=L)
@@ -1901,14 +1951,24 @@ class Word2Vec:
                     items = pipe
                 try:
                     items = iter(items)
+                    end_setup()
                     while True:
                         # the stall clock covers exactly the input
                         # wait: inline it times rendering + stacking,
                         # pipelined it times empty-queue waits — one
                         # meter for both, so host_stall_ms is directly
-                        # comparable across the two modes
+                        # comparable across the two modes.  The
+                        # `input_wait` span likewise: the pipelined path
+                        # has it inside PrefetchIterator.__next__, one
+                        # sample per item in either mode
                         with meter.stalling():
-                            nxt = next(items, None)
+                            if pipelined:
+                                nxt = next(items, None)
+                            else:
+                                with obs.span("input_wait") as wait:
+                                    nxt = next(items, None)
+                                    if nxt is None:
+                                        wait.drop()
                         if nxt is None:
                             break
                         kind, fields, n_words = nxt
@@ -1924,8 +1984,11 @@ class Word2Vec:
                                 pipe_stats[k] = max(pipe_stats[k], v)
                             elif k != "depth":
                                 pipe_stats[k] += v
-                err_sum = es_q.total()
-                err_cnt = int(round(ec_q.total()))
+                # the epoch's one blocking fetch: waits for every queued
+                # step, then reads two scalars
+                with obs.span("loss_fetch"):
+                    err_sum = es_q.total()
+                    err_cnt = int(round(ec_q.total()))
             loss = err_sum / max(err_cnt, 1)
             losses.append(loss)
             log.info("iter %d: error %.5f  (%.0f words/s)",
@@ -1952,54 +2015,57 @@ class Word2Vec:
                 log.info("checkpoint @ iter %d -> %s", start_iter + it + 1,
                          checkpoint_path)
                 faults.checkpoint_event(npz_path(checkpoint_path))
-        self.table.state = state
-        # final publish: readers see the trained state no matter where
-        # the every-K cadence landed
-        self._serve_publish()
-        # observability surface (returned data, not just logs): the
-        # hogwild drop bound is testable and the hybrid backend's
-        # traffic counters ride along for bench detail fields
-        self.train_metrics = {
-            "hogwild_skipped_tail_words": hogwild_dropped,
-            # host-stall vs device-time split (utils.timers.Throughput):
-            # which side of the step loop is the bottleneck
-            "host_stall_ms": meter.host_stall_ms(),
-            "device_ms": meter.device_ms(),
-            "stall_ms_per_step": meter.stall_ms_per_step(),
-            "words_per_sec": meter.rate(),
-            "pipeline_depth": self.pipeline_depth if pipelined else 0}
-        if pipe_stats is not None:
-            self.train_metrics["pipeline"] = dict(pipe_stats)
-        if self.controller is not None:
-            self.train_metrics["control"] = {
-                **self.controller.summary(),
-                "recompiles": self._control_recompiles}
-        if hasattr(self.transfer, "traffic"):
-            # traffic() drains queued eager counts through _accum_wire,
-            # so the registry mirror is exact before the summary lands
-            self.train_metrics["transfer_traffic"] = \
-                self.transfer.traffic()
-        if self._numerics is not None:
-            # drain in-flight bundle callbacks (safe point: dispatches
-            # retired), then disarm the process-global quant tap — a
-            # numerics-off model training next in this process must
-            # trace (and book) nothing
-            from swiftmpi_tpu.transfer import api as transfer_api
-            self._numerics.sync()
-            transfer_api.clear_numerics_tap()
-            det = self._numerics.detector
-            self.train_metrics["numerics"] = {
-                "bundles": self._numerics.bundles,
-                "anomalies": det.anomalies_emitted if det else 0}
+        end_setup()       # niters == 0: no epoch closed it
         prof = obs.get_profiler()
         if prof is not None:
-            # training ended inside a capture window: stop the trace
-            # and land the summary artifact anyway (short runs,
-            # profile_at near the end)
+            # training ended inside a capture window: stop the trace and
+            # land the summary artifact anyway (short runs, profile_at
+            # near the end) — before the recorder that takes its event
+            # closes, and outside train_finish: it compiles phase maps
             prof.close()
-        if owns_rec and tel_rec is not None:
-            tel_rec.close()
-            obs.uninstall_recorder()
+        with obs.span("train_finish"):
+            self.table.state = state
+            # final publish: readers see the trained state no matter where
+            # the every-K cadence landed
+            self._serve_publish()
+            # observability surface (returned data, not just logs): the
+            # hogwild drop bound is testable and the hybrid backend's
+            # traffic counters ride along for bench detail fields
+            self.train_metrics = {
+                "hogwild_skipped_tail_words": hogwild_dropped,
+                # host-stall vs device-time split (utils.timers.Throughput):
+                # which side of the step loop is the bottleneck
+                "host_stall_ms": meter.host_stall_ms(),
+                "device_ms": meter.device_ms(),
+                "stall_ms_per_step": meter.stall_ms_per_step(),
+                "words_per_sec": meter.rate(),
+                "pipeline_depth": self.pipeline_depth if pipelined else 0}
+            if pipe_stats is not None:
+                self.train_metrics["pipeline"] = dict(pipe_stats)
+            if self.controller is not None:
+                self.train_metrics["control"] = {
+                    **self.controller.summary(),
+                    "recompiles": self._control_recompiles}
+            if hasattr(self.transfer, "traffic"):
+                # traffic() drains queued eager counts through _accum_wire,
+                # so the registry mirror is exact before the summary lands
+                self.train_metrics["transfer_traffic"] = \
+                    self.transfer.traffic()
+            if self._numerics is not None:
+                # drain in-flight bundle callbacks (safe point: dispatches
+                # retired), then disarm the process-global quant tap — a
+                # numerics-off model training next in this process must
+                # trace (and book) nothing
+                from swiftmpi_tpu.transfer import api as transfer_api
+                self._numerics.sync()
+                transfer_api.clear_numerics_tap()
+                det = self._numerics.detector
+                self.train_metrics["numerics"] = {
+                    "bundles": self._numerics.bundles,
+                    "anomalies": det.anomalies_emitted if det else 0}
+            if owns_rec and tel_rec is not None:
+                tel_rec.close()
+                obs.uninstall_recorder()
         return losses
 
     def _hogwild_epoch(self, batcher, batch_size: int, meter) -> tuple:

@@ -81,6 +81,9 @@ def get_registry() -> MetricsRegistry:
 
 def set_enabled(on: bool) -> MetricsRegistry:
     _REGISTRY.enabled = bool(on)
+    # telemetry on: tracked jits remember their abstract call signature,
+    # which is all obs.costs.phase_map needs after the window
+    costs.get_catalog().signatures = bool(on)
     return _REGISTRY
 
 
@@ -112,6 +115,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def drop(self):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -121,9 +127,9 @@ class _Span:
 
     __slots__ = ("_hist", "_ann", "_t0")
 
-    def __init__(self, hist, name: str):
+    def __init__(self, hist, name: str, attrs: dict):
         self._hist = hist
-        self._ann = _host_profiler.annotate(name)
+        self._ann = _host_profiler.annotate(name, **attrs)
 
     def __enter__(self):
         self._ann.__enter__()
@@ -133,23 +139,33 @@ class _Span:
     def __exit__(self, *exc):
         dt_ms = (time.perf_counter() - self._t0) * 1e3
         self._ann.__exit__(*exc)
-        self._hist.observe(dt_ms)
+        if self._hist is not None:
+            self._hist.observe(dt_ms)
         return False
 
+    def drop(self):
+        """Leave no ``phase_ms`` sample for this span (an end-of-stream
+        ``input_wait`` fetched no item: one sample per item)."""
+        self._hist = None
 
-def span(name: str):
+
+def span(name: str, **attrs):
     """Named host-phase span: ``with obs.span("render"): ...``.
 
     Telemetry off -> a shared no-op context (one branch, no allocation).
     On -> a ``jax.profiler.TraceAnnotation`` plus a sample in the
     ``phase_ms{phase=<name>}`` histogram, so the trace viewer and
     ``telemetry_report.py`` see the same phase under the same name.
+    ``attrs`` go to the annotation alone (``dispatch`` carries ``step=``
+    and ``steps=``, so a fused group and its device programs match by
+    number); the histogram's labels stay ``phase=<name>``.
+    Names are declared in ``obs.catalog.HOST_SPANS``.
     Only meaningful OUTSIDE jit — use :func:`named_scope` inside.
     """
     reg = _REGISTRY
     if not reg.enabled:
         return _NULL_SPAN
-    return _Span(reg.histogram("phase_ms", phase=name), name)
+    return _Span(reg.histogram("phase_ms", phase=name), name, attrs)
 
 
 # -- recorder install point -------------------------------------------------

@@ -123,6 +123,39 @@ PREFIXES = (
 )
 
 
+# -- phase names (ONE list: named scopes, host spans, what they count as) ----
+
+#: ``obs.named_scope`` names entered inside compiled code, each with the
+#: phase its device time is booked under — the phase map of a compiled
+#: program (``obs.costs.phase_map``), the profiler-window reduction
+#: (``obs.profiler``) and the benchmark's ``trace_scope`` reader all read
+#: this dict and nothing else.  The tpu/hybrid backends' window dedup is
+#: the same phase as the xla backend's sort/segment-sum dedup, and the
+#: Pallas ring push is one rendering of the wire exchange.
+DEVICE_SCOPES = {
+    "sample": "sample",            # negative draw + slot lookups + masks
+    "pull": "pull",                # Transfer.pull: the row gather
+    "math": "math",                # forward, gradient, error norm
+    "dedup": "dedup",              # sort / segment sums / mean normalise
+    "window_dedup": "dedup",       # transfer/tpu.py, hybrid.py
+    "apply": "apply",              # row read-modify-write, AdaGrad
+    "wire_exchange": "wire_exchange",
+    "pallas_ring_push": "wire_exchange",
+}
+
+#: device time inside a tracked program but under none of these scopes
+#: is reported under this name, never guessed from a shape.
+UNSCOPED = "unscoped"
+
+#: ``obs.span`` names (host side: a TraceAnnotation on the profiler's
+#: clock + a ``phase_ms{phase=}`` sample).  ``render`` runs on the
+#: pipeline's producer thread; the rest on the thread that calls train().
+HOST_SPANS = (
+    "train_setup", "input_wait", "render", "h2d", "dispatch",
+    "loss_fetch", "train_finish", "checkpoint_save", "checkpoint_restore",
+)
+
+
 def declared(name: str) -> bool:
     """True when ``name`` is a declared series (exact or prefix)."""
     return name in SERIES or any(name.startswith(p) for p in PREFIXES)

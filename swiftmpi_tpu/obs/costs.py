@@ -25,6 +25,19 @@ jit's own trace cache — is recorded three ways:
   measured numbers against the hand byte/FLOP models
   (:func:`CostCatalog.note_hand_model`).
 
+Phase map (ISSUE 23).  The device trace names an event by its HLO
+instruction (``fusion.24``, ``copy.141.remat3``); the compiled module's
+text carries ``metadata={op_name="jit(step)/.../pull/gather"}`` for the
+same names.  While telemetry is on (``[obs] costs`` need not be) a
+handle remembers, on its first call, the *abstract* signature it was
+called with — shapes, dtypes and committed shardings, never the arrays:
+the state is donated and fills the chip — and :func:`phase_map` lowers
+and compiles from that signature **on demand, after the window** (the
+executable comes from the compile cache), parses the text once per
+handle and returns ``{module, phase: {instruction: phase}, ...}`` over
+the scope names of :data:`obs.catalog.DEVICE_SCOPES`.  Nothing on the
+call path lowers, compiles or reads text.
+
 Retrace semantics are **per handle**, matching the retrace-guard test:
 one name may cover many jit objects (the w2v fused cache holds one per
 group length, the tpu backend one per push signature) and each handle's
@@ -38,9 +51,16 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import threading
 import time
-from typing import Any, Dict, Optional
+import weakref
+from typing import Any, Dict, List, Optional
+
+from swiftmpi_tpu.obs.catalog import DEVICE_SCOPES, UNSCOPED
+from swiftmpi_tpu.utils.logger import get_logger
+
+log = get_logger(__name__)
 
 #: catalog artifact schema tag (``runs/compile_catalog.json``).
 COSTS_SCHEMA = "smtpu-costs/1"
@@ -61,6 +81,12 @@ class CostCatalog:
                  path: Optional[str] = None,
                  memory: bool = True, analyze_max: int = 1,
                  run: str = "run"):
+        #: the ONE attribute ``TrackedFn.__call__`` checks when nothing
+        #: is armed: true unless ``enabled`` (compile events) or
+        #: ``signatures`` (telemetry on: handles remember their abstract
+        #: signature for :func:`phase_map`) is set
+        self.idle = True
+        self._enabled = self._signatures = False
         self.enabled = enabled
         self.path = path
         #: run memory_analysis (one extra backend compile per analyzed
@@ -73,6 +99,64 @@ class CostCatalog:
         self._lock = threading.Lock()
         self._fns: Dict[str, dict] = {}     # guarded-by: _lock
         self._analyzed: Dict[str, int] = {}  # guarded-by: _lock
+        #: handles that remembered a signature, by catalog name, oldest
+        #: first (weak: a rebuilt step drops its old handle)
+        self._handles: Dict[str, List[weakref.ref]] = {}  # guarded-by: _lock
+
+    @property
+    def enabled(self) -> bool:
+        return self._enabled
+
+    @enabled.setter
+    def enabled(self, on: bool) -> None:
+        self._enabled = bool(on)
+        self.idle = not (self._enabled or self._signatures)
+
+    @property
+    def signatures(self) -> bool:
+        return self._signatures
+
+    @signatures.setter
+    def signatures(self, on: bool) -> None:
+        self._signatures = bool(on)
+        self.idle = not (self._enabled or self._signatures)
+
+    # -- abstract signatures + phase maps ----------------------------------
+    def remember(self, handle: "TrackedFn", args, kwargs) -> None:
+        """First call of ``handle`` while signatures are wanted: keep the
+        call's abstract signature on it, and the handle findable by name."""
+        sig = _abstract_signature(args, kwargs)
+        if sig is None:     # called under a trace: inlined, not a program
+            return
+        handle._sig = sig
+        with self._lock:
+            live = [r for r in self._handles.get(handle.name, ())
+                    if r() is not None]     # rebuilt steps leave dead refs
+            self._handles[handle.name] = live + [weakref.ref(handle)]
+
+    def handle(self, name: str) -> Optional["TrackedFn"]:
+        """The newest live handle of ``name`` that remembered a signature
+        (one name may cover several: fused tail lengths, rebuilt steps —
+        the newest is the one the last window ran)."""
+        with self._lock:
+            refs = list(self._handles.get(name, ()))
+        for ref in reversed(refs):
+            h = ref()
+            if h is not None:
+                return h
+        return None
+
+    def phase_map(self, name: str) -> Optional[dict]:
+        h = self.handle(name)
+        return h.phase_map() if h is not None else None
+
+    def phase_maps(self) -> Dict[str, dict]:
+        """``{hlo module name: phase map}`` of every tracked name that has
+        one — what a trace reduction joins device events with."""
+        with self._lock:
+            names = list(self._handles)
+        maps = (self.phase_map(name) for name in names)
+        return {m["module"]: m for m in maps if m is not None}
 
     # -- the compile event -------------------------------------------------
     def on_compile(self, name: str, fn, args, kwargs, dt_ms: float,
@@ -248,17 +332,23 @@ class TrackedFn:
     ``_cache_size()`` callers don't need to know about the wrapper.
     """
 
-    __slots__ = ("_fn", "name", "steps_per_call", "_compiles",
-                 "__weakref__")
+    __slots__ = ("_fn", "name", "steps_per_call", "_compiles", "_sig",
+                 "_phase_map", "__weakref__")
 
     def __init__(self, name: str, fn, steps_per_call: int = 1):
         self._fn = fn
         self.name = name
         self.steps_per_call = max(int(steps_per_call), 1)
         self._compiles = 0
+        self._sig = None          # (args, kwargs) of ShapeDtypeStructs
+        self._phase_map = _NOT_COMPUTED
 
     def __call__(self, *args, **kwargs):
         cat = _CATALOG
+        if cat.idle:
+            return self._fn(*args, **kwargs)
+        if self._sig is None:
+            cat.remember(self, args, kwargs)
         if not cat.enabled:
             return self._fn(*args, **kwargs)
         before = self._cache_size()
@@ -280,11 +370,184 @@ class TrackedFn:
         except Exception:
             return -1
 
+    def phase_map(self) -> Optional[dict]:
+        """See :func:`phase_map`; computed once per handle."""
+        if self._phase_map is _NOT_COMPUTED:
+            self._phase_map = _compile_phase_map(self.name, self._fn,
+                                                 self._sig)
+        return self._phase_map
+
     def __getattr__(self, attr):
         return getattr(self._fn, attr)
 
     def __repr__(self) -> str:
         return f"TrackedFn({self.name!r}, {self._fn!r})"
+
+
+# -- phase map ----------------------------------------------------------------
+
+_NOT_COMPUTED = object()
+
+
+def _abstract_signature(args, kwargs):
+    """``(args, kwargs)`` with every array leaf replaced by its
+    ``ShapeDtypeStruct`` (committed arrays keep their sharding, so the
+    lowering is the executed one); other leaves (Python scalars, static
+    values) stay.  Holds no device buffer.  ``None`` for a call made
+    under a trace (a tracked jit nested in another is inlined there)."""
+    import jax
+    import numpy as np
+
+    leaves = jax.tree_util.tree_leaves((args, kwargs))
+    if any(isinstance(x, jax.core.Tracer) for x in leaves):
+        return None
+
+    def abstract(x):
+        if isinstance(x, jax.Array):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype,
+                sharding=x.sharding if x.committed else None)
+        if isinstance(x, np.ndarray):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return x
+
+    return jax.tree_util.tree_map(abstract, (tuple(args), dict(kwargs)))
+
+
+_HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?([\w.\-]+) = (.*)$")
+_HLO_OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_SCOPE_PART = re.compile(r"^(?:\w+\()*([\w.\-]+)\)*$")
+#: opcodes that run nothing of their own: counted neither as
+#: instructions nor as unscoped
+_HLO_NO_WORK = frozenset({"parameter", "constant", "tuple",
+                          "get-tuple-element", "bitcast"})
+
+
+def phase_of(op_name: str) -> Optional[str]:
+    """The innermost known phase in an ``op_name`` path
+    (``jit(step)/jit(main)/apply/dedup/sort`` -> ``dedup``); a transform
+    wraps the scope it was applied under (``vmap(pull)``)."""
+    for part in reversed(op_name.split("/")):
+        m = _SCOPE_PART.match(part)
+        if m and m.group(1) in DEVICE_SCOPES:
+            return DEVICE_SCOPES[m.group(1)]
+    return None
+
+
+def parse_hlo_phases(text: str) -> dict:
+    """Phase of every instruction of a compiled module's text.
+
+    An instruction's phase is :func:`phase_of` its own ``op_name``; a
+    ``fusion`` takes the phase most of its fused instructions carry (ties:
+    the root's, else the first seen), since the trace shows the fusion and
+    never its parts.  Instructions under no phase map to ``"unscoped"`` —
+    none is guessed from a shape.  ``scoped`` counts the instructions,
+    fused ones included, that carry a phase at all."""
+    module = None
+    # computation -> [(name, is root, opcode, own phase, called computation)]
+    comps: Dict[str, list] = {}
+    cur = None
+    for line in text.splitlines():
+        if module is None:
+            m = _HLO_MODULE.match(line)
+            if m:
+                module = m.group(1)
+            continue
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+            continue
+        if cur is None:
+            continue
+        if line.startswith("}"):
+            cur = None
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            rhs = m.group(3)
+            op = _HLO_OPCODE.search(rhs)
+            on = _HLO_OP_NAME.search(rhs)
+            calls = _HLO_CALLS.search(rhs)
+            cur.append((m.group(2), bool(m.group(1)),
+                        op.group(1) if op else "",
+                        phase_of(on.group(1)) if on else None,
+                        calls.group(1) if calls else None))
+
+    def fused_phase(comp):
+        votes: Dict[str, int] = {}
+        root = None
+        for _name, is_root, _op, ph, _calls in comps.get(comp, ()):
+            if ph is not None:
+                votes[ph] = votes.get(ph, 0) + 1
+                if is_root:
+                    root = ph
+        if not votes:
+            return None
+        best = max(votes.values())
+        tied = [ph for ph, n in votes.items() if n == best]
+        return root if root in tied else tied[0]
+
+    fused = {calls for body in comps.values()
+             for _n, _r, op, _p, calls in body if op == "fusion" and calls}
+    phase: Dict[str, str] = {}
+    n_instr = n_unscoped = n_scoped = 0
+    for comp, body in comps.items():
+        for name, _root, op, ph, calls in body:
+            n_scoped += ph is not None
+            if comp in fused:
+                continue          # never a trace event of its own
+            if op == "fusion" and calls:
+                ph = fused_phase(calls) or ph
+            phase[name] = ph or UNSCOPED
+            if op not in _HLO_NO_WORK:
+                n_instr += 1
+                n_unscoped += ph is None
+    return {"module": module, "phase": phase, "instructions": n_instr,
+            "unscoped": n_unscoped, "scoped": n_scoped}
+
+
+def _compile_phase_map(name: str, fn, sig) -> Optional[dict]:
+    """Lower + compile ``fn`` from its remembered signature (a compile-
+    cache read when the program ran) and parse the text.  ``None`` when
+    there is nothing to read — and when the text carries no known phase,
+    which is either a program that enters no scope or the stale-cache
+    hazard: JAX's persistent-cache key ignores metadata, so an executable
+    compiled by a build without scopes is served with its old op_names."""
+    lower = getattr(fn, "lower", None)
+    if lower is None or sig is None:
+        return None
+    try:
+        lowered = lower(*sig[0], **sig[1])
+        text = lowered.compile().as_text()
+    except Exception as e:     # noqa: BLE001 — observability must not raise
+        log.warning("phase_map(%s): cannot compile from the remembered "
+                    "signature: %r", name, e)
+        return None
+    out = parse_hlo_phases(text or "")
+    if not out["module"]:
+        return None
+    if not out.pop("scoped"):
+        try:
+            traced = lowered.as_text(debug_info=True)
+        except Exception:      # noqa: BLE001
+            traced = ""
+        if any(f"/{s}/" in traced or f"/{s}\"" in traced
+               for s in DEVICE_SCOPES):
+            log.warning(
+                "phase_map(%s): this build enters named scopes but the "
+                "compiled text of %s carries none: the executable came "
+                "from a compile cache written by a build without them "
+                "(the cache key ignores metadata) — clear the cache "
+                "directory once; no phase map", name, out["module"])
+        else:
+            log.info("phase_map(%s): %s enters no known named scope; no "
+                     "phase map", name, out["module"])
+        return None
+    return out
 
 
 def track(name: str, fn, steps_per_call: int = 1) -> TrackedFn:
@@ -303,6 +566,25 @@ _CATALOG = CostCatalog()
 def get_catalog() -> CostCatalog:
     """The process-global catalog (disarmed unless configured)."""
     return _CATALOG
+
+
+def phase_map(name: str) -> Optional[dict]:
+    """Phase of every HLO instruction of the tracked program ``name``
+    (e.g. ``"w2v_step"``): ``{"module": <hlo module name as the trace
+    prints it, e.g. jit_step>, "phase": {instruction name: phase or
+    "unscoped"}, "instructions": n, "unscoped": n}`` (the counts leave
+    out parameters, constants, tuples and bitcasts), or ``None`` — no
+    handle of that name ran while telemetry was on, it cannot be lowered,
+    or its text carries no known phase (see ``_compile_phase_map``).
+
+    Lowers and compiles once per handle: call it AFTER a measured or
+    traced window, never inside one."""
+    return _CATALOG.phase_map(name)
+
+
+def phase_maps() -> Dict[str, dict]:
+    """:func:`phase_map` of every tracked name, by HLO module name."""
+    return _CATALOG.phase_maps()
 
 
 def reset_for_tests() -> CostCatalog:

@@ -16,14 +16,19 @@ three triggers fires:
   (``[obs] profile_on_anomaly``).
 
 Artifacts land under ``runs/profiles/profile_step<N>_r<rank>/``: the
-raw TensorBoard/perfetto trace plus a ``profile_summary.json`` from
-:func:`parse_trace_dir` — a best-effort chrome-trace parse that splits
-device- from host-side events (the ``process_name`` metadata) and
-attributes duration to the existing ``named_scope``/``span`` phase
-names, reporting per-phase device-vs-host skew.  The same attribution
-lands in the registry as ``profile/{device_ms,host_ms,skew_ms}{phase=}``
-gauges and ``profile/{sessions,steps}`` counters, so the capture is
-visible in the telemetry stream it explains.
+raw capture (``.xplane.pb`` + the perfetto twin) plus a
+``profile_summary.json`` from :func:`parse_trace_dir` — the capture's
+``.xplane.pb`` reduced through ``jax.profiler.ProfileData``: device
+*self* time (the innermost event owns the instant, so a ``while`` is
+not counted over its body) by phase, through the phase map of every
+tracked program (``obs.costs.phase_maps``: instruction name -> the
+``obs.named_scope`` it was traced under), device time under no phase as
+``"unscoped"``, and host time by ``obs.span`` name from the host plane.
+``device_ms`` therefore sums to the capture's device busy time.  The
+same numbers land in the registry as
+``profile/{device_ms,host_ms,skew_ms}{phase=}`` gauges and
+``profile/{sessions,steps}`` counters, so the capture is visible in the
+telemetry stream it explains.
 
 No session installed (the default) means ``record_step`` never touches
 this module — trajectories stay bit-identical.
@@ -31,34 +36,38 @@ this module — trajectories stay bit-identical.
 
 from __future__ import annotations
 
+import bisect
 import glob
-import gzip
 import json
 import os
+import re
 import time
 from typing import Dict, List, Optional
 
+from swiftmpi_tpu.obs.catalog import HOST_SPANS, UNSCOPED
 from swiftmpi_tpu.obs.identity import process_rank
 
 #: fleet-dir trigger file: ``{"id": n, "steps": k}``; ids increase so a
 #: session replays each request exactly once.
 TRIGGER_FILENAME = "profile_trigger.json"
 
-#: per-capture summary schema (``profile_summary.json``).
-PROFILE_SCHEMA = "smtpu-profile/1"
+#: per-capture summary schema (``profile_summary.json``); /2: reduced
+#: from the ``.xplane.pb`` by self time through the compiled programs'
+#: phase maps (was: chrome-trace events credited by substring).
+PROFILE_SCHEMA = "smtpu-profile/2"
 
 #: env pre-arm (set by ``launch.py -profile-at`` for every rank).
 ENV_PROFILE_AT = "SMTPU_PROFILE_AT"
 ENV_PROFILE_STEPS = "SMTPU_PROFILE_STEPS"
 
-#: phase names the trace parser attributes duration to — the union of
-#: the host ``obs.span`` names and the in-jit ``obs.named_scope`` names
-#: already emitted across the codebase.  Substring match: XLA embeds
-#: scope names inside fused-kernel labels.
-KNOWN_PHASES = (
-    "window_dedup", "wire_exchange", "apply", "serve/topk", "render", "h2d", "input_wait", "dispatch",
-    "checkpoint_save",
-)
+#: trace planes and lines (TPU/GPU runtime names)
+_DEVICE_PLANE = re.compile(r"^/device:\w+:\d+$")
+_HOST_PLANE = re.compile(r"^/host:")
+_OP_LINE, _MODULE_LINE = "XLA Ops", "XLA Modules"
+#: ``%fusion.24 = f32[...] fusion(...`` (the device names an op event by
+#: its HLO text) or a bare instruction name
+_INSTRUCTION = re.compile(r"^%?([\w.\-]+)")
+_MODULE_RUN = re.compile(r"^(.*?)(\(\d+\))?$")
 
 
 def request_profile(fleet_dir: str, steps: int = 5) -> dict:
@@ -81,72 +90,150 @@ def request_profile(fleet_dir: str, steps: int = 5) -> dict:
     return req
 
 
-# -- trace parsing ----------------------------------------------------------
+# -- trace reduction --------------------------------------------------------
 
-def parse_trace_dir(root: str,
-                    phases: Optional[tuple] = None) -> dict:
-    """Best-effort phase attribution over every chrome-format trace
-    (``*.trace.json.gz`` and the perfetto twin) under ``root``.
+def self_times(events) -> list:
+    """``[name, self_ns, start_ns]`` per event of ONE trace line: an
+    event's duration minus what its direct children cover, so the
+    values sum to the union of the events (nested time counted once).
+    ``events``: ``(start_ns, end_ns, name)``, properly nested or
+    disjoint, as a device's op line is."""
+    out: List[list] = []
+    stack: List[tuple] = []          # (index into out, end_ns)
+    for s, e, name in sorted(events, key=lambda ev: (ev[0], -ev[1])):
+        while stack and stack[-1][1] <= s:
+            stack.pop()
+        if stack:
+            e = min(e, stack[-1][1])          # a child ends with its parent
+            out[stack[-1][0]][1] -= e - s
+        if e > s:
+            out.append([name, e - s, s])
+            stack.append((len(out) - 1, e))
+    return out
 
-    Complete events (``ph == "X"``) are split device/host by their
-    process's ``process_name`` metadata (``/device:...`` vs host) and
-    their duration is credited to the first KNOWN phase whose name is a
-    substring of the event name — nested events under a scope repeat
-    the scope in their names, so this over-counts nesting rather than
-    attributing to the wrong phase; the numbers are for *ranking*
-    phases, not summing to wall clock.  Events matching no phase
-    aggregate under ``"other"``."""
-    phases = phases or KNOWN_PHASES
-    device_ms: Dict[str, float] = {}
-    host_ms: Dict[str, float] = {}
-    files = sorted(
-        set(glob.glob(os.path.join(root, "**", "*.trace.json.gz"),
-                      recursive=True))
-        | set(glob.glob(os.path.join(root, "**",
-                                     "perfetto_trace.json.gz"),
-                        recursive=True)))
-    # the per-host trace and the perfetto export carry the same events;
-    # parse only one of each basename flavor to avoid double counting
-    if any(p.endswith(".trace.json.gz")
-           and not p.endswith("perfetto_trace.json.gz") for p in files):
-        files = [p for p in files
-                 if not p.endswith("perfetto_trace.json.gz")]
-    n_events = 0
+
+def _line_events(line) -> list:
+    return [(float(ev.start_ns), float(ev.start_ns) + float(ev.duration_ns),
+             str(ev.name)) for ev in line.events]
+
+
+def reduce_profile(data, maps: Optional[Dict[str, dict]] = None) -> dict:
+    """One capture (a ``jax.profiler.ProfileData``, or anything shaped
+    like it: ``planes[].lines[].events[]`` with ``name`` / ``start_ns``
+    / ``duration_ns``) -> ``{device_ms, host_ms, modules_ms, module_runs,
+    busy_ms, devices, events, unmatched}``.
+
+    Device side: self time of every op event, booked under the phase
+    the compiled program's map (``maps``: hlo module name ->
+    ``obs.costs.phase_map`` result) gives its instruction; an op of a
+    program with no map, or under no scope, is ``"unscoped"``.  Which
+    program an op belongs to comes from the device's module line, so
+    ``fusion.16`` of two programs stay apart.  Values are milliseconds,
+    the mean over the capture's device planes; ``module_runs`` counts each
+    program's executions the same way (dispatch runs ahead of the device,
+    so a window of N consumed steps need not hold N executions: divide
+    by this, not by ``steps``, for time an execution).  Host side: summed
+    duration of the ``obs.span`` events (``catalog.HOST_SPANS``) over all
+    host threads."""
+    maps = maps or {}
+    device_ns: Dict[str, float] = {}
+    modules_ns: Dict[str, float] = {}
+    module_runs: Dict[str, float] = {}
+    host_ns: Dict[str, float] = {}
+    n_dev = n_events = unmatched = 0
+
+    def add(acc, key, value):
+        acc[key] = acc.get(key, 0.0) + value
+
+    for plane in data.planes:
+        lines = {}
+        for line in plane.lines:
+            lines.setdefault(line.name, []).append(_line_events(line))
+        if _HOST_PLANE.match(plane.name):
+            for s, e, name in (ev for line in lines.values()
+                               for evs in line for ev in evs):
+                if name in HOST_SPANS:
+                    n_events += 1
+                    add(host_ns, name, e - s)
+            continue
+        if not _DEVICE_PLANE.match(plane.name) or _OP_LINE not in lines:
+            continue
+        n_dev += 1
+        runs = sorted(ev for evs in lines.get(_MODULE_LINE, ())
+                      for ev in evs)
+        starts = [r[0] for r in runs]
+        programs = [_MODULE_RUN.match(r[2]).group(1) for r in runs]
+        for program in programs:
+            add(module_runs, program, 1)
+        for ops in lines[_OP_LINE]:
+            n_events += len(ops)
+            for text, ns, at in self_times(ops):
+                i = bisect.bisect_right(starts, at) - 1
+                program = programs[i] if i >= 0 and at < runs[i][1] \
+                    else "(no program)"
+                phase = UNSCOPED
+                pm = maps.get(program)
+                if pm is not None:
+                    name = _INSTRUCTION.match(text)
+                    phase = pm["phase"].get(name.group(1) if name else text)
+                    if phase is None:
+                        unmatched += 1
+                        phase = UNSCOPED
+                add(device_ns, phase, ns)
+                add(modules_ns, program, ns)
+    per_dev = 1e6 * max(n_dev, 1)
+    device_ms = {k: v / per_dev for k, v in device_ns.items()}
+    return {"device_ms": device_ms,
+            "host_ms": {k: v / 1e6 for k, v in host_ns.items()},
+            "modules_ms": {k: v / per_dev for k, v in modules_ns.items()},
+            "module_runs": {k: v / max(n_dev, 1)
+                            for k, v in module_runs.items()},
+            "busy_ms": sum(device_ms.values()), "devices": n_dev,
+            "events": n_events, "unmatched": unmatched}
+
+
+def _xplane_files(root: str) -> List[str]:
+    return sorted(glob.glob(os.path.join(root, "**", "*.xplane.pb"),
+                            recursive=True))
+
+
+def parse_trace_dir(root: str, maps: Optional[Dict[str, dict]] = None,
+                    skip=()) -> dict:
+    """Reduce every ``.xplane.pb`` under ``root`` (:func:`reduce_profile`;
+    but those in ``skip``: an earlier capture's, left in the same
+    directory) into the ``smtpu-profile/2`` summary.  ``maps`` defaults to
+    the phase maps of every program tracked in this process — computing
+    them lowers and compiles (a compile-cache read), which is why this
+    runs after the capture has stopped, never inside it.  ``device_ms`` by
+    phase (``"unscoped"`` included) sums to ``busy_ms``, the capture's
+    device busy time; ``skew_ms`` is host minus device time under one
+    name."""
+    from jax.profiler import ProfileData
+
+    if maps is None:
+        from swiftmpi_tpu.obs import costs
+        maps = costs.phase_maps()
+    files = [p for p in _xplane_files(root) if p not in skip]
+    out = {"device_ms": {}, "host_ms": {}, "modules_ms": {},
+           "module_runs": {}, "busy_ms": 0.0, "devices": 0, "events": 0,
+           "unmatched": 0}
     for path in files:
         try:
-            with gzip.open(path, "rt") as f:
-                doc = json.load(f)
-        except (OSError, ValueError):
+            part = reduce_profile(ProfileData.from_file(path), maps)
+        except Exception:     # noqa: BLE001 — a torn capture file
             continue
-        events = doc.get("traceEvents") or []
-        procs: Dict[int, str] = {}
-        for ev in events:
-            if ev.get("ph") == "M" and ev.get("name") == "process_name":
-                procs[ev.get("pid")] = str(
-                    (ev.get("args") or {}).get("name", ""))
-        for ev in events:
-            if ev.get("ph") != "X":
-                continue
-            dur_ms = float(ev.get("dur", 0.0)) / 1e3   # trace dur is µs
-            if dur_ms <= 0:
-                continue
-            name = str(ev.get("name", ""))
-            if name.startswith("$"):        # python frame-trace noise
-                continue
-            n_events += 1
-            side = device_ms if "/device:" in procs.get(
-                ev.get("pid"), "") else host_ms
-            for ph in phases:
-                if ph in name:
-                    side[ph] = side.get(ph, 0.0) + dur_ms
-                    break
+        for k, v in part.items():
+            if isinstance(v, dict):
+                for name, ms in v.items():
+                    out[k][name] = out[k].get(name, 0.0) + ms
             else:
-                side["other"] = side.get("other", 0.0) + dur_ms
-    skew_ms = {ph: host_ms.get(ph, 0.0) - device_ms.get(ph, 0.0)
-               for ph in set(device_ms) | set(host_ms)}
-    return {"schema": PROFILE_SCHEMA, "files": len(files),
-            "events": n_events, "device_ms": device_ms,
-            "host_ms": host_ms, "skew_ms": skew_ms}
+                out[k] += v
+    device_ms, host_ms = out["device_ms"], out["host_ms"]
+    out["skew_ms"] = {ph: host_ms.get(ph, 0.0) - device_ms.get(ph, 0.0)
+                      for ph in set(device_ms) | set(host_ms)}
+    out.update(schema=PROFILE_SCHEMA, files=len(files),
+               programs=sorted(maps))
+    return out
 
 
 # -- the session ------------------------------------------------------------
@@ -230,6 +317,19 @@ class ProfileSession:
             self._stop()
 
     # -- capture lifecycle -------------------------------------------------
+    @staticmethod
+    def _drain() -> None:
+        """Wait until every program already dispatched has run.  Dispatch
+        runs ahead of the device (by seconds, where a step takes 200 ms),
+        so without this a window of N consumed steps holds whatever the
+        device happened to run meanwhile, cut at both ends; drained at
+        start and stop it holds exactly those N steps' executions.  A
+        device runs its programs in order: one more, tiny, is done when
+        all before it are."""
+        import jax
+        jax.block_until_ready([jax.device_put(0, d) + 1
+                               for d in jax.local_devices()])
+
     def _start(self, steps: int, reason: str) -> None:
         import jax
         out = os.path.join(
@@ -237,12 +337,15 @@ class ProfileSession:
             f"profile_step{self._consumed}_r{process_rank() or 0}")
         try:
             os.makedirs(out, exist_ok=True)
+            before = set(_xplane_files(out))
+            self._drain()
             jax.profiler.start_trace(out, create_perfetto_trace=True)
         except Exception:
             return   # a second profiler on the host must not kill train
         self._active = {"dir": out, "start_step": self._consumed,
                         "steps": steps, "remaining": steps,
-                        "reason": reason, "t0": time.perf_counter()}
+                        "reason": reason, "t0": time.perf_counter(),
+                        "before": before}
         from swiftmpi_tpu import obs
         obs.get_registry().counter("profile/sessions").inc()
 
@@ -250,11 +353,12 @@ class ProfileSession:
         import jax
         act, self._active = self._active, None
         try:
+            self._drain()
             jax.profiler.stop_trace()
         except Exception:
             pass
         captured = act["steps"] - max(act["remaining"], 0)
-        summary = parse_trace_dir(act["dir"])
+        summary = parse_trace_dir(act["dir"], skip=act["before"])
         summary.update(
             run_dir=act["dir"], reason=act["reason"],
             start_step=act["start_step"],

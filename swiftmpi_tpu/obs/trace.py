@@ -73,6 +73,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from swiftmpi_tpu.obs.catalog import DEVICE_SCOPES
 from swiftmpi_tpu.obs.identity import process_ident, process_rank
 
 TRACE_SCHEMA = "smtpu-trace/1"
@@ -315,8 +316,10 @@ class WindowTracer:
             h = snap["hists"].get(series_key("phase_ms", {"phase": ph}))
             if h is not None and h["count"]:
                 out[ph] = h["sum"]
-            dev = snap["gauges"].get(
-                series_key("profile/device_ms", {"phase": ph}))
+            # the capture books a scope under its phase
+            # (window_dedup -> dedup: obs.catalog.DEVICE_SCOPES)
+            dev = snap["gauges"].get(series_key(
+                "profile/device_ms", {"phase": DEVICE_SCOPES.get(ph, ph)}))
             if dev:
                 out[ph + "_device"] = dev
         return out

@@ -1008,10 +1008,13 @@ class Transfer:
         from swiftmpi_tpu.transfer.plan import pull_route
         fields = tuple(fields or access.pull_fields)
         route = pull_route(self.name)
-        if route.placement == "hot_split":
-            return self._interpret_pull_hot_split(state, slots, access,
-                                                  fields)
-        return self._interpret_pull_flat(state, slots, fields)
+        # the one dispatch point, so every backend's gather (and whatever
+        # XLA attaches to it) is booked under the `pull` phase
+        with obs.named_scope("pull"):
+            if route.placement == "hot_split":
+                return self._interpret_pull_hot_split(state, slots, access,
+                                                      fields)
+            return self._interpret_pull_flat(state, slots, fields)
 
     def _prim_pull(self, state: TableState, slots, fields) -> TableState:
         """Backend pull primitive: masked row gather of ``fields`` at

@@ -639,64 +639,68 @@ class TpuTransfer(Transfer):
             if sparse_dcn:
                 rows_g = jax.lax.all_gather(
                     safe_rows, self.dp_axis).reshape(-1)
-            inv = None
-            if mean and not with_counts:
-                # contribution counts accumulate at the owning shard from
-                # the received requests themselves — no extra collective
-                if sparse_dcn:
-                    counts = jnp.zeros((cap_per_shard,), jnp.float32).at[
-                        rows_g].add(
-                        (rows_g < cap_per_shard).astype(jnp.float32),
-                        mode="drop")
-                else:
-                    counts = jnp.zeros((cap_per_shard,), jnp.float32).at[
-                        safe_rows].add(ok.reshape(-1).astype(jnp.float32),
-                                       mode="drop")
-                    if self.dp_axis:
-                        counts = jax.lax.psum(counts, self.dp_axis)
-                inv = (1.0 / jnp.maximum(counts, 1.0))[:, None]
-            dense = {}
-            for f in grad_fields:
-                g = jnp.asarray(grads_l[f])
-                width = g.shape[1]
-                # forward my buckets' grads in the same (n, C) layout
-                bucket = jnp.zeros((self.n, C, width), g.dtype)
-                row_idx = jnp.where((so < self.n) & (idx < C), so, self.n)
-                col_idx = jnp.clip(idx, 0, C - 1)
-                bucket = bucket.at[row_idx, col_idx].set(
-                    g[order], mode="drop")
-                # the width-1 counts bucket always rides all_to_all: its
-                # bytes are noise next to the d-wide grad buckets, and
-                # inv-scaling ring-fed grad sums by a ring-fed counts
-                # column trips an XLA reshape CHECK during the interpret
-                # discharge (jaxlib 0.4.x, array.h new_num_elements)
-                recv = _wire_exchange(
-                    bucket, ring=use_ring and f != "__counts__")
-                if sparse_dcn:
-                    # batch-proportional DCN traffic: every group's
-                    # received pairs, applied by everyone identically
-                    recv_g = jax.lax.all_gather(
-                        recv.reshape(-1, width), self.dp_axis)
-                    acc = jnp.zeros((cap_per_shard, width), g.dtype)
-                    acc = acc.at[rows_g].add(
-                        recv_g.reshape(-1, width), mode="drop")
-                else:
-                    acc = jnp.zeros((cap_per_shard, width), g.dtype)
-                    acc = acc.at[safe_rows].add(
-                        recv.reshape(-1, width), mode="drop")
-                    if self.dp_axis:
-                        # capacity-sized psum: the right call only at
-                        # batch ~ table scale (see strategy note above)
-                        acc = jax.lax.psum(acc, self.dp_axis)
-                dense[f] = acc
-            if with_counts:
-                # span families: per-row DATA counts rode along as the
-                # synthetic field and summed at the owner like any grad
-                csum = dense.pop("__counts__")
+            # owner-side duplicate reduction: received pairs summed per
+            # row (+ counts, mean) — the `dedup` phase of this backend;
+            # the exchanges nested in it stay `wire_exchange`
+            with jax.named_scope("dedup"):
+                inv = None
+                if mean and not with_counts:
+                    # contribution counts accumulate at the owning shard from
+                    # the received requests themselves — no extra collective
+                    if sparse_dcn:
+                        counts = jnp.zeros((cap_per_shard,), jnp.float32).at[
+                            rows_g].add(
+                            (rows_g < cap_per_shard).astype(jnp.float32),
+                            mode="drop")
+                    else:
+                        counts = jnp.zeros((cap_per_shard,), jnp.float32).at[
+                            safe_rows].add(ok.reshape(-1).astype(jnp.float32),
+                                           mode="drop")
+                        if self.dp_axis:
+                            counts = jax.lax.psum(counts, self.dp_axis)
+                    inv = (1.0 / jnp.maximum(counts, 1.0))[:, None]
+                dense = {}
+                for f in grad_fields:
+                    g = jnp.asarray(grads_l[f])
+                    width = g.shape[1]
+                    # forward my buckets' grads in the same (n, C) layout
+                    bucket = jnp.zeros((self.n, C, width), g.dtype)
+                    row_idx = jnp.where((so < self.n) & (idx < C), so, self.n)
+                    col_idx = jnp.clip(idx, 0, C - 1)
+                    bucket = bucket.at[row_idx, col_idx].set(
+                        g[order], mode="drop")
+                    # the width-1 counts bucket always rides all_to_all: its
+                    # bytes are noise next to the d-wide grad buckets, and
+                    # inv-scaling ring-fed grad sums by a ring-fed counts
+                    # column trips an XLA reshape CHECK during the interpret
+                    # discharge (jaxlib 0.4.x, array.h new_num_elements)
+                    recv = _wire_exchange(
+                        bucket, ring=use_ring and f != "__counts__")
+                    if sparse_dcn:
+                        # batch-proportional DCN traffic: every group's
+                        # received pairs, applied by everyone identically
+                        recv_g = jax.lax.all_gather(
+                            recv.reshape(-1, width), self.dp_axis)
+                        acc = jnp.zeros((cap_per_shard, width), g.dtype)
+                        acc = acc.at[rows_g].add(
+                            recv_g.reshape(-1, width), mode="drop")
+                    else:
+                        acc = jnp.zeros((cap_per_shard, width), g.dtype)
+                        acc = acc.at[safe_rows].add(
+                            recv.reshape(-1, width), mode="drop")
+                        if self.dp_axis:
+                            # capacity-sized psum: the right call only at
+                            # batch ~ table scale (see strategy note above)
+                            acc = jax.lax.psum(acc, self.dp_axis)
+                    dense[f] = acc
+                if with_counts:
+                    # span families: per-row DATA counts rode along as the
+                    # synthetic field and summed at the owner like any grad
+                    csum = dense.pop("__counts__")
+                    if mean:
+                        inv = 1.0 / jnp.maximum(csum[:, :1], 1.0)
                 if mean:
-                    inv = 1.0 / jnp.maximum(csum[:, :1], 1.0)
-            if mean:
-                dense = {f: a * inv for f, a in dense.items()}
+                    dense = {f: a * inv for f, a in dense.items()}
             with jax.named_scope("apply"):
                 new_fields = access.apply_push(state_l, dense)
             out = dict(state_l)

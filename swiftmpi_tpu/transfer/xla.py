@@ -24,6 +24,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from swiftmpi_tpu import obs
 from swiftmpi_tpu.ops import calibration, pallas_gather, pallas_scatter
 from swiftmpi_tpu.transfer.api import (Transfer, bump_row_versions,
                                        grad_row_bytes)
@@ -118,20 +119,7 @@ class XlaTransfer(Transfer):
         valid = slots >= 0
         # OOB scatter indices are dropped by XLA; route padding there.
         safe = jnp.where(valid, slots, capacity)
-        inv = None
-        fuse_count = False
-        if mean:
-            # Single fp32 grad family: fold the contribution counts into
-            # the grads scatter as one extra column — one scatter pass
-            # over the batch instead of two.  (fp32 only: a bf16 count
-            # column goes inexact past 256 occurrences of one key.)
-            gs = list(grads.values())
-            fuse_count = (len(gs) == 1
-                          and jnp.asarray(gs[0]).dtype == jnp.float32)
-            if not fuse_count:
-                counts = jnp.zeros((capacity,), jnp.float32).at[safe].add(
-                    1.0, mode="drop")
-                inv = (1.0 / jnp.maximum(counts, 1.0))[:, None]
+
         def _scatter(g, width):
             # VMEM-resident Pallas scatter when the on-chip A/B verdict
             # says it beats XLA's (ops/pallas_scatter.py; never taken
@@ -154,23 +142,40 @@ class XlaTransfer(Transfer):
             acc = jnp.zeros((capacity, width), g.dtype)
             return acc.at[safe].add(g, mode="drop")
 
-        dense_grads = {}
-        for f in grads:
-            g = jnp.asarray(grads[f])
-            width = state[f].shape[1]
-            if fuse_count:
-                g1 = jnp.concatenate(
-                    [g, jnp.ones((g.shape[0], 1), g.dtype)], axis=1)
-                acc = _scatter(g1, width + 1)
-                dense_grads[f] = acc[:, :width] / jnp.maximum(
-                    acc[:, width:], 1.0)
-            else:
-                acc = _scatter(g, width)
-                dense_grads[f] = acc * inv if mean else acc
-        new_fields = access.apply_push(state, dense_grads)
-        out = dict(state)
-        out.update(new_fields)
-        return bump_row_versions(out, state, safe)
+        with obs.named_scope("dedup"):
+            inv = None
+            fuse_count = False
+            if mean:
+                # Single fp32 grad family: fold the contribution counts
+                # into the grads scatter as one extra column — one scatter
+                # pass over the batch instead of two.  (fp32 only: a bf16
+                # count column goes inexact past 256 occurrences of one
+                # key.)
+                gs = list(grads.values())
+                fuse_count = (len(gs) == 1
+                              and jnp.asarray(gs[0]).dtype == jnp.float32)
+                if not fuse_count:
+                    counts = jnp.zeros((capacity,), jnp.float32).at[
+                        safe].add(1.0, mode="drop")
+                    inv = (1.0 / jnp.maximum(counts, 1.0))[:, None]
+            dense_grads = {}
+            for f in grads:
+                g = jnp.asarray(grads[f])
+                width = state[f].shape[1]
+                if fuse_count:
+                    g1 = jnp.concatenate(
+                        [g, jnp.ones((g.shape[0], 1), g.dtype)], axis=1)
+                    acc = _scatter(g1, width + 1)
+                    dense_grads[f] = acc[:, :width] / jnp.maximum(
+                        acc[:, width:], 1.0)
+                else:
+                    acc = _scatter(g, width)
+                    dense_grads[f] = acc * inv if mean else acc
+        with obs.named_scope("apply"):
+            new_fields = access.apply_push(state, dense_grads)
+            out = dict(state)
+            out.update(new_fields)
+            return bump_row_versions(out, state, safe)
 
     # -- span push (stencil rendering; see models/word2vec.py) -------------
     def push_span(self, state, slots, grads, counts, access, mean=False,
@@ -212,39 +217,41 @@ class XlaTransfer(Transfer):
         else:
             self._record_exchange(jnp.sum(valid),
                                   grad_row_bytes(grads, with_counts=True))
-        safe = jnp.where(valid, slots, 0)
-        pos = jnp.arange(S, dtype=jnp.int32)
-        rep = jnp.full((capacity,), S, jnp.int32).at[safe].min(
-            jnp.where(valid, pos, S))
-        owner = jnp.where(valid, rep[safe], S)           # (S,) in [0, S]
-        inv = None
-        if mean:
-            cnt = jnp.zeros((S,), jnp.float32).at[owner].add(
-                jnp.asarray(counts, jnp.float32), mode="drop")
-            inv = (1.0 / jnp.maximum(cnt, 1.0))[:, None]
-        combined = {}
-        for f in grads:
-            g = jnp.asarray(grads[f])
-            acc = jnp.zeros((S, g.shape[1]), g.dtype).at[owner].add(
-                g, mode="drop")
-            combined[f] = acc * inv if mean else acc
-        is_owner = valid & (owner == pos)
-        touched = access.touched_fields(grads)
-        safe_own = jnp.where(is_owner, slots, 0)
-        current = {f: jnp.take(state[f], safe_own, axis=0)
-                   for f in touched}
-        updated = access.apply_push(current, combined)
-        out = dict(state)
-        tgt = jnp.where(is_owner, slots, capacity)
-        for f in updated:
-            # owner rows hold distinct slots by construction (one owner
-            # per table row); non-owners route OOB and drop.  The span
-            # is position-ordered, not slot-ordered, so no
-            # indices_are_sorted hint — uniqueness alone removes the
-            # scatter's collision machinery.
-            out[f] = state[f].at[tgt].set(
-                updated[f], mode="drop", unique_indices=True)
-        return bump_row_versions(out, state, tgt)
+        with obs.named_scope("dedup"):
+            safe = jnp.where(valid, slots, 0)
+            pos = jnp.arange(S, dtype=jnp.int32)
+            rep = jnp.full((capacity,), S, jnp.int32).at[safe].min(
+                jnp.where(valid, pos, S))
+            owner = jnp.where(valid, rep[safe], S)       # (S,) in [0, S]
+            inv = None
+            if mean:
+                cnt = jnp.zeros((S,), jnp.float32).at[owner].add(
+                    jnp.asarray(counts, jnp.float32), mode="drop")
+                inv = (1.0 / jnp.maximum(cnt, 1.0))[:, None]
+            combined = {}
+            for f in grads:
+                g = jnp.asarray(grads[f])
+                acc = jnp.zeros((S, g.shape[1]), g.dtype).at[owner].add(
+                    g, mode="drop")
+                combined[f] = acc * inv if mean else acc
+            is_owner = valid & (owner == pos)
+        with obs.named_scope("apply"):
+            touched = access.touched_fields(grads)
+            safe_own = jnp.where(is_owner, slots, 0)
+            current = {f: jnp.take(state[f], safe_own, axis=0)
+                       for f in touched}
+            updated = access.apply_push(current, combined)
+            out = dict(state)
+            tgt = jnp.where(is_owner, slots, capacity)
+            for f in updated:
+                # owner rows hold distinct slots by construction (one
+                # owner per table row); non-owners route OOB and drop.
+                # The span is position-ordered, not slot-ordered, so no
+                # indices_are_sorted hint — uniqueness alone removes the
+                # scatter's collision machinery.
+                out[f] = state[f].at[tgt].set(
+                    updated[f], mode="drop", unique_indices=True)
+            return bump_row_versions(out, state, tgt)
 
     # -- window-coalesced push ---------------------------------------------
     # No override: the base-class TrafficPlan interpreter
@@ -264,55 +271,61 @@ class XlaTransfer(Transfer):
         if B == 0:
             return dict(state)
         valid = slots >= 0
-        # Sort so duplicates are adjacent; padding (-1 -> capacity) sorts
-        # last and is dropped by OOB scatter below.
-        sort_keys = jnp.where(valid, slots, capacity)
-        order = jnp.argsort(sort_keys)
-        sorted_slots = sort_keys[order]
-        # Batch-local segment ids: bump at each new slot value.
-        new_seg = jnp.concatenate([
-            jnp.ones((1,), jnp.int32),
-            (sorted_slots[1:] != sorted_slots[:-1]).astype(jnp.int32)])
-        seg_ids = jnp.cumsum(new_seg) - 1  # (B,), in [0, B)
-        # One representative slot per segment; unused segments -> capacity.
-        rep_slots = jnp.full((B,), capacity, jnp.int32).at[seg_ids].set(
-            sorted_slots, mode="drop")
-        rep_valid = rep_slots < capacity
-        safe_rep = jnp.where(rep_valid, rep_slots, 0)
+        with obs.named_scope("dedup"):
+            # Sort so duplicates are adjacent; padding (-1 -> capacity)
+            # sorts last and is dropped by OOB scatter below.
+            sort_keys = jnp.where(valid, slots, capacity)
+            order = jnp.argsort(sort_keys)
+            sorted_slots = sort_keys[order]
+            # Batch-local segment ids: bump at each new slot value.
+            new_seg = jnp.concatenate([
+                jnp.ones((1,), jnp.int32),
+                (sorted_slots[1:] != sorted_slots[:-1]).astype(jnp.int32)])
+            seg_ids = jnp.cumsum(new_seg) - 1  # (B,), in [0, B)
+            # One representative slot per segment; unused segments ->
+            # capacity.
+            rep_slots = jnp.full((B,), capacity, jnp.int32).at[
+                seg_ids].set(sorted_slots, mode="drop")
+            rep_valid = rep_slots < capacity
+            safe_rep = jnp.where(rep_valid, rep_slots, 0)
 
-        inv = None
-        if mean:
-            # seg_ids ascend (cumsum of non-negatives): tell XLA so the
-            # scatter lowering can skip the general collision machinery
-            seg_counts = jnp.zeros((B,), jnp.float32).at[seg_ids].add(
-                valid[order].astype(jnp.float32), mode="drop",
-                indices_are_sorted=True)
-            inv = (1.0 / jnp.maximum(seg_counts, 1.0))[:, None]
-        combined = {}
-        for f in grads:
-            g = jnp.asarray(grads[f])[order]
-            width = g.shape[1]
-            acc = jnp.zeros((B, width), g.dtype)
-            acc = acc.at[seg_ids].add(g, mode="drop",
-                                      indices_are_sorted=True)
-            combined[f] = acc * inv if mean else acc
+            inv = None
+            if mean:
+                # seg_ids ascend (cumsum of non-negatives): tell XLA so
+                # the scatter lowering can skip the general collision
+                # machinery
+                seg_counts = jnp.zeros((B,), jnp.float32).at[seg_ids].add(
+                    valid[order].astype(jnp.float32), mode="drop",
+                    indices_are_sorted=True)
+                inv = (1.0 / jnp.maximum(seg_counts, 1.0))[:, None]
+            combined = {}
+            for f in grads:
+                g = jnp.asarray(grads[f])[order]
+                width = g.shape[1]
+                acc = jnp.zeros((B, width), g.dtype)
+                acc = acc.at[seg_ids].add(g, mode="drop",
+                                          indices_are_sorted=True)
+                combined[f] = acc * inv if mean else acc
 
-        # only the fields this push's grad families actually update are
-        # gathered and re-scattered (a partial push must not round-trip
-        # the untouched fields' rows through HBM for nothing)
-        touched = access.touched_fields(grads)
-        current = {f: jnp.take(state[f], safe_rep, axis=0) for f in touched}
-        updated = access.apply_push(current, combined)
+        with obs.named_scope("apply"):
+            # only the fields this push's grad families actually update
+            # are gathered and re-scattered (a partial push must not
+            # round-trip the untouched fields' rows through HBM for
+            # nothing)
+            touched = access.touched_fields(grads)
+            current = {f: jnp.take(state[f], safe_rep, axis=0)
+                       for f in touched}
+            updated = access.apply_push(current, combined)
 
-        out = dict(state)
-        for f in updated:
-            # Unused segments' representatives stay == capacity: OOB,
-            # dropped.  rep_slots are ascending AND one-per-segment by
-            # construction (duplicates exist only among the dropped
-            # capacity-fill tail), so the scatter-set needs no collision
-            # handling — the hints cut the large-capacity scatter cost
-            # (the 1M-vocab step's measured bound).
-            out[f] = state[f].at[rep_slots].set(
-                updated[f], mode="drop", indices_are_sorted=True,
-                unique_indices=True)
-        return bump_row_versions(out, state, rep_slots)
+            out = dict(state)
+            for f in updated:
+                # Unused segments' representatives stay == capacity: OOB,
+                # dropped.  rep_slots are ascending AND one-per-segment by
+                # construction (duplicates exist only among the dropped
+                # capacity-fill tail), so the scatter-set needs no
+                # collision handling — the hints cut the large-capacity
+                # scatter cost (the 1M-vocab step's measured bound).
+                out[f] = state[f].at[rep_slots].set(
+                    updated[f], mode="drop", indices_are_sorted=True,
+                    unique_indices=True)
+            return bump_row_versions(out, state, rep_slots)

@@ -28,9 +28,10 @@ def trace(logdir: str) -> Iterator[None]:
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named span context (TraceAnnotation) for host-side phases."""
-    return jax.profiler.TraceAnnotation(name)
+def annotate(name: str, **attrs):
+    """Named span context (TraceAnnotation) for host-side phases;
+    ``attrs`` become the trace event's arguments."""
+    return jax.profiler.TraceAnnotation(name, **attrs)
 
 
 class StepTimer:

@@ -4,13 +4,12 @@ real jit cache, hand-model drift math, the w2v cost-catalog golden on
 CPU (compile/* series in the JSONL + a valid smtpu-costs/1 artifact +
 the --compile report rendering it), the shape-churn -> retrace-counter
 -> budget-gate acceptance path, triggered profiler windows (profile_at
-knob artifacts, the fleet trigger file, chrome-trace phase attribution),
+knob artifacts, the fleet trigger file, the xplane phase reduction),
 and the off-by-default bit-identity contract across the jit-stepped
 transfer backends.
 """
 
 import glob
-import gzip
 import json
 import os
 import sys
@@ -290,48 +289,96 @@ def test_fleet_trigger_file_drives_a_capture(tmp_path):
     assert len(sess.captures) == 1
 
 
-def _gz_trace(path, events):
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    with gzip.open(path, "wt") as f:
-        json.dump({"traceEvents": events}, f)
+# (plane, line, [(name, start_ns, end_ns), ...])
+_TIMELINE = [
+    ("/device:TPU:0", "XLA Modules", [
+        ("jit_step(704314026086775491)", 100, 500),
+        ("jit_other(9)", 600, 700)]),
+    ("/device:TPU:0", "XLA Ops", [
+        # the device names an op by its HLO text
+        ("%while.1 = (s32[], f32[4,4]{1,0}) while(%fusion.25), cond", 100,
+         500),                               # self: 400 - 100 - 100 - 50
+        ("%fusion.2 = f32[4,4]{1,0} fusion(%copy.1), kind=kLoop", 120, 220),
+        ("%sort.3 = f32[4,4]{1,0} sort(%gte.1)", 250, 350),
+        ("%copy.5 = f32[8,4]{0,1} copy(%state)", 400, 450),  # not in the map
+        # the same instruction name in another program: not step's pull
+        ("%fusion.2 = u32[2]{0} fusion(%key)", 600, 700)]),
+    ("/host:CPU", "main", [
+        ("train_setup", 0, 50), ("PjitFunction(step)", 55, 95),
+        ("dispatch", 60, 90), ("dispatch", 510, 530)]),
+    ("/host:CPU", "producer", [("render", 0, 800)]),
+]
+_STEP_MAP = {"jit_step": {
+    "module": "jit_step", "instructions": 3, "unscoped": 0,
+    "phase": {"while.1": "dedup", "fusion.2": "pull", "sort.3": "dedup"}}}
+
+
+def _xspace_text(timeline):
+    names = sorted({e[0] for _, _, evs in timeline for e in evs})
+    ids = {n: i + 1 for i, n in enumerate(names)}
+    planes = {}
+    for plane, line, evs in timeline:
+        planes.setdefault(plane, []).append((line, evs))
+    text = ""
+    for p, (plane, lines) in enumerate(planes.items()):
+        text += f'planes {{ id: {p} name: "{plane}"\n'
+        for i, (line, evs) in enumerate(lines):
+            text += f'  lines {{ id: {i} name: "{line}" timestamp_ns: 0\n'
+            for name, s, e in evs:
+                text += (f"    events {{ metadata_id: {ids[name]} "
+                         f"offset_ps: {s * 1000} "
+                         f"duration_ps: {(e - s) * 1000} }}\n")
+            text += "  }\n"
+        for name, i in ids.items():
+            text += (f"  event_metadata {{ key: {i} value {{ id: {i} "
+                     f'name: "{name}" }} }}\n')
+        text += "}\n"
+    return text
 
 
 def test_parse_trace_dir_attributes_phases(tmp_path):
-    root = str(tmp_path / "trace")
-    events = [
-        {"ph": "M", "pid": 1, "name": "process_name",
-         "args": {"name": "/device:TPU:0 (pid 1)"}},
-        {"ph": "M", "pid": 2, "name": "process_name",
-         "args": {"name": "python (host)"}},
-        # device event carrying a named_scope inside a fused label
-        {"ph": "X", "pid": 1, "name": "fusion.3/apply/add",
-         "dur": 2000.0},
-        # host span
-        {"ph": "X", "pid": 2, "name": "render", "dur": 1000.0},
-        {"ph": "X", "pid": 2, "name": "apply", "dur": 500.0},
-        # python frame-trace noise: skipped
-        {"ph": "X", "pid": 2, "name": "$noise.py:1", "dur": 9999.0},
-        # unmatched name: aggregates under "other"
-        {"ph": "X", "pid": 1, "name": "memcpy", "dur": 100.0},
-        # non-complete events: ignored
-        {"ph": "B", "pid": 1, "name": "apply"},
-    ]
-    _gz_trace(os.path.join(root, "host.trace.json.gz"), events)
-    # the perfetto twin carries the same events — must NOT double count
-    _gz_trace(os.path.join(root, "perfetto_trace.json.gz"), events)
+    """The capture's .xplane.pb reduced by self time through the phase
+    map: nested time is counted once, an instruction name is looked up
+    in the program that ran it, and the phases sum to busy time."""
+    from jax.profiler import ProfileData
+    assert obs_profiler.self_times(
+        [(0, 10, "a"), (2, 5, "b"), (3, 4, "c"), (6, 8, "d")]) == [
+        ["a", 5, 0], ["b", 2, 2], ["c", 1, 3], ["d", 2, 6]]
 
-    s = obs_profiler.parse_trace_dir(root)
-    assert s["files"] == 1 and s["events"] == 4
-    assert s["device_ms"]["apply"] == pytest.approx(2.0)
-    assert s["device_ms"]["other"] == pytest.approx(0.1)
-    assert s["host_ms"]["render"] == pytest.approx(1.0)
-    assert s["host_ms"]["apply"] == pytest.approx(0.5)
-    # per-phase host-vs-device skew
-    assert s["skew_ms"]["apply"] == pytest.approx(0.5 - 2.0)
-    # a perfetto-only dir still parses (no chrome twin to prefer)
-    root2 = str(tmp_path / "trace2")
-    _gz_trace(os.path.join(root2, "perfetto_trace.json.gz"), events)
-    assert obs_profiler.parse_trace_dir(root2)["events"] == 4
+    text = _xspace_text(_TIMELINE)
+    part = obs_profiler.reduce_profile(ProfileData.from_text_proto(text),
+                                       _STEP_MAP)
+    assert part["devices"] == 1 and part["unmatched"] == 1
+
+    root = tmp_path / "cap" / "plugins" / "profile" / "t0"
+    root.mkdir(parents=True)
+    (root / "host.xplane.pb").write_bytes(
+        ProfileData.text_proto_to_serialized_xspace(text))
+    s = obs_profiler.parse_trace_dir(str(tmp_path / "cap"), _STEP_MAP)
+    assert s["schema"] == "smtpu-profile/2"
+    assert s["files"] == 1 and s["events"] == 5 + 4
+    ms = 1e-6                               # the timeline is in ns
+    assert s["device_ms"]["dedup"] == pytest.approx((150 + 100) * ms)
+    assert s["device_ms"]["pull"] == pytest.approx(100 * ms)
+    assert s["device_ms"]["unscoped"] == pytest.approx((50 + 100) * ms)
+    assert "other" not in s["device_ms"]
+    # every device instant lands in exactly one phase
+    assert sum(s["device_ms"].values()) == pytest.approx(500 * ms)
+    assert s["busy_ms"] == pytest.approx(500 * ms)
+    assert s["modules_ms"] == pytest.approx(
+        {"jit_step": 400 * ms, "jit_other": 100 * ms})
+    assert s["module_runs"] == {"jit_step": 1, "jit_other": 1}
+    # host spans by name, over every host thread; other events ignored
+    assert s["host_ms"] == pytest.approx(
+        {"train_setup": 50 * ms, "dispatch": 50 * ms, "render": 800 * ms})
+    assert s["skew_ms"]["dispatch"] == pytest.approx(50 * ms)
+    assert s["skew_ms"]["pull"] == pytest.approx(-100 * ms)
+    # no map: the same busy time, all of it under no scope
+    bare = obs_profiler.parse_trace_dir(str(tmp_path / "cap"), {})
+    assert bare["device_ms"] == pytest.approx({"unscoped": 500 * ms})
+    # an empty directory reduces to an empty summary
+    empty = obs_profiler.parse_trace_dir(str(tmp_path / "none"), {})
+    assert empty["files"] == 0 and empty["device_ms"] == {}
 
 
 # -- the contract the default rides on -------------------------------------
