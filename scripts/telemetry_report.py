@@ -458,9 +458,11 @@ def traffic_summary(doc: dict) -> dict:
     """Cumulative counters (prefer the summary line's authoritative
     totals; fall back to summing step deltas for a crashed run) grouped
     as transfer-per-backend / train / everything-else."""
+    gauges = {}
     if doc["summary"] is not None:
         totals = dict(doc["summary"].get("counters") or {})
         steps = int(doc["summary"].get("steps", 0))
+        gauges = doc["summary"].get("gauges") or {}
     else:
         totals = {}
         steps = 0
@@ -468,8 +470,13 @@ def traffic_summary(doc: dict) -> dict:
             steps += int(rec.get("steps", 1))
             for key, delta in (rec.get("counters") or {}).items():
                 totals[key] = totals.get(key, 0.0) + delta
+            gauges = rec.get("gauges") or gauges
     transfer: Dict[str, dict] = {}
-    train, other = {}, {}
+    # the train/ gauges' last values ride with the train/ totals, label
+    # kept: sampler_slot_lookups{mode=per_draw}
+    train = {key[len("train/"):]: v for key, v in gauges.items()
+             if key.startswith("train/")}
+    other = {}
     for key, total in sorted(totals.items()):
         name, labels = parse_series_key(key)
         if name.startswith("transfer/"):

@@ -51,7 +51,8 @@ from swiftmpi_tpu.cluster.cluster import Cluster
 from swiftmpi_tpu.data.text import (CBOWBatcher, Vocab, build_vocab,
                                     load_corpus)  # noqa: F401 (Vocab: API)
 from swiftmpi_tpu.io.checkpoint import dump_table_text, load_table_text
-from swiftmpi_tpu.ops.sampling import (build_unigram_alias, sample_alias,
+from swiftmpi_tpu.ops.sampling import (alias_slot_lookups,
+                                       build_unigram_alias, sample_alias,
                                        sample_alias_slots)
 from swiftmpi_tpu.ops.sigmoid import sigmoid_clipped
 from swiftmpi_tpu.parameter import w2v_access
@@ -149,6 +150,19 @@ def _stack_group_stencil(batches):
                  for f in _stack_group_host_stencil(batches))
 
 
+def _negative_slots(key, alias_prob, alias_idx, slot_of_vocab, shape):
+    """``sample_alias_slots`` for a train step.  While the step is
+    traced (Python time: nothing enters the program) the gauge
+    ``train/sampler_slot_lookups{mode=}`` takes the scalar slot lookups
+    the program makes for its negatives, under the branch it took."""
+    reg = obs.get_registry()
+    if reg.enabled:
+        mode, lookups = alias_slot_lookups(alias_prob.shape[0], shape)
+        reg.gauge("train/sampler_slot_lookups", mode=mode).set(lookups)
+    return sample_alias_slots(key, alias_prob, alias_idx, slot_of_vocab,
+                              shape)
+
+
 def _cbow_targets(slot_of_vocab, alias_prob, alias_idx, centers,
                   contexts, ctx_mask, key, K):
     """Shared CBOW batch layout: draw the negatives and build the
@@ -159,9 +173,8 @@ def _cbow_targets(slot_of_vocab, alias_prob, alias_idx, centers,
     with obs.named_scope("sample"):
         B = centers.shape[0]
         # fused draw: negatives and their table slots from ONE packed row
-        # gather (sampling was ~6.5ms of the 17.7ms chip step as separate
-        # scalar gathers — see ops/sampling.sample_alias_slots)
-        negs, neg_slots = sample_alias_slots(
+        # gather a draw (see ops/sampling.sample_alias_slots)
+        negs, neg_slots = _negative_slots(
             key, alias_prob, alias_idx, slot_of_vocab, (B, K))
         t_slots = jnp.concatenate(
             [slot_of_vocab[centers][:, None], neg_slots], axis=1)  # (B, K+1)
@@ -1361,7 +1374,7 @@ class Word2Vec:
             with obs.named_scope("sample"):
                 # parity negatives: per-center draws from the SAME sampling
                 # stream as _cbow_targets — the oracle test's anchor
-                negs, neg_slots = sample_alias_slots(
+                negs, neg_slots = _negative_slots(
                     key, alias_prob, alias_idx, slot_of_vocab, (B, K))
                 t_slots = jnp.concatenate(
                     [c_slots[:, None], neg_slots], axis=1)       # (B, K+1)
@@ -1407,7 +1420,7 @@ class Word2Vec:
                      centers, contexts, ctx_mask, key):
             B, W2 = contexts.shape
             with obs.named_scope("sample"):
-                negs, neg_slots = sample_alias_slots(
+                negs, neg_slots = _negative_slots(
                     key, alias_prob, alias_idx, slot_of_vocab, (B, W2, K))
                 # negative == center is skipped (word2vec.h:584-586); padding
                 # pairs are fully dead.

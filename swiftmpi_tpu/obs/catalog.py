@@ -56,6 +56,9 @@ SERIES = frozenset({
     # training loops (word2vec/glove via Throughput sampler bridge)
     "train/host_stall_ms_total", "train/device_ms_total",
     "train/words_per_sec",
+    # scalar slot lookups the traced step makes for its negatives,
+    # mode=per_draw|per_vocab (ops/sampling.alias_slot_lookups)
+    "train/sampler_slot_lookups",
     # checkpoints (io/checkpoint.py)
     "checkpoint/saves", "checkpoint/restores",
     # health probes (utils/health.py)

@@ -20,6 +20,7 @@ their neighbors' context windows; the batcher enforces this.
 
 from __future__ import annotations
 
+import math
 from typing import Tuple
 
 import jax
@@ -58,14 +59,15 @@ def build_unigram_alias(counts: np.ndarray, power: float = 0.75
 def _alias_draw_packed(key, prob, extra_cols, shape):
     """Shared draw core: packs ``(prob_bits, *extra_cols)`` into one
     (V, 1+len(extra_cols)) int32 table and resolves each draw with ONE
-    row gather.  The round-3 chip profile showed scalar gathers are
-    transaction-bound (~10ns each regardless of width), so collapsing
-    the per-draw lookups (prob, alias, and optionally the vocab->slot
-    map) into a single row halves-to-quarters the sampling phase.  One
-    copy of the (j, u, accept) sequence keeps every caller's draw
-    stream bit-identical by construction — the parity tests reproduce
-    training negatives through ``sample_alias`` while training itself
-    uses ``sample_alias_slots``.
+    row gather.  A scalar gather on the chip is transaction-bound: 6-7
+    ns a lookup whatever the width (ledger, PR 23: the fusion that
+    gathers V scalars takes 11.93 ms at V = 1.8 M, 19.9 ms at 3.0 M),
+    so one packed row per draw beats one lookup per column per draw.
+    The pack itself is elementwise over V, under 0.1 ms.  One copy of
+    the (j, u, accept) sequence keeps every caller's draw stream
+    bit-identical by construction — the parity tests reproduce training
+    negatives through ``sample_alias`` while training itself uses
+    ``sample_alias_slots``.
 
     Returns ``(j, accept, rows)``: bucket draws, acceptance mask, and
     the gathered packed rows (prob bits in column 0)."""
@@ -91,25 +93,55 @@ def sample_alias(key: jax.Array, prob: jax.Array, alias: jax.Array,
     return jnp.where(accept, j, rows[..., 1]).astype(jnp.int32)
 
 
+def alias_slot_lookups(vocab_size: int, shape: Tuple[int, ...]
+                       ) -> Tuple[str, int]:
+    """``(mode, lookups)``: how ``sample_alias_slots`` finds the slot of
+    a rejected draw's alias word for a draw of ``shape`` over
+    ``vocab_size`` words, and the scalar slot lookups that costs the
+    program.  ``per_draw`` (one lookup a draw) when the call draws fewer
+    negatives than the vocabulary has words, ``per_vocab`` (one a word,
+    into the pack) otherwise.  Both numbers are static at trace time."""
+    draws = math.prod(shape)
+    if draws < vocab_size:
+        return "per_draw", draws
+    return "per_vocab", vocab_size
+
+
 def sample_alias_slots(key: jax.Array, prob: jax.Array, alias: jax.Array,
                        slot_of_vocab: jax.Array, shape: Tuple[int, ...]
                        ) -> Tuple[jax.Array, jax.Array]:
     """Alias draws fused with the vocab->slot mapping: returns
-    ``(negs, neg_slots)`` with ``neg_slots == slot_of_vocab[negs]``.
+    ``(negs, neg_slots)`` with ``neg_slots == slot_of_vocab[negs]``, the
+    draw stream bit-identical to ``sample_alias`` + that lookup.
 
-    One (V, 4) row — ``(prob_bits, alias, slot, slot_of_alias)`` — per
-    vocab id turns what was FOUR transaction-bound scalar gathers per
-    draw (prob, alias, then slot_of_vocab on the result) into one row
-    gather.  The pack itself is (V, 4) work, loop-invariant, and
-    hoisted out of inner-step scans by XLA; draw stream is bit-identical
-    to ``sample_alias`` + ``slot_of_vocab[negs]``."""
+    The packed row ``(prob_bits, alias, slot)`` answers an accepted draw
+    with one row gather.  A rejected draw also needs the slot of its
+    alias word, and there are two places to look it up
+    (``alias_slot_lookups`` picks the cheaper from the two shapes):
+
+    * ``per_vocab``: a fourth column ``slot_of_vocab[alias]`` in the
+      pack.  That is V scalar lookups a *program*: XLA hoists the pack
+      out of a ``lax.scan``, but a step launched on its own rebuilds it
+      every step (11.93 of a 14.18 ms sample phase at V = 1.8 M against
+      163,840 draws; ledger, PR 23).  Right when the call draws at
+      least V negatives (a 30 K-word vocabulary under a 16 K batch).
+    * ``per_draw``: ``slot_of_vocab[alias[j]]`` after the row gather,
+      ``prod(shape)`` lookups, and no V-sized gather in the program.
+
+    The rule does not weigh a scan's trip count: this function cannot
+    see it, and ``per_draw`` costs a trip at most what an unhoisted
+    ``per_vocab`` pack would."""
     V = prob.shape[0]
-    j, accept, rows = _alias_draw_packed(
-        key, prob, [alias, slot_of_vocab[:V], slot_of_vocab[alias]],
-        shape)
-    negs = jnp.where(accept, j, rows[..., 1]).astype(jnp.int32)
+    per_vocab = alias_slot_lookups(V, shape)[0] == "per_vocab"
+    cols = [alias, slot_of_vocab[:V]]
+    if per_vocab:
+        cols.append(slot_of_vocab[alias])
+    j, accept, rows = _alias_draw_packed(key, prob, cols, shape)
+    alias_j = rows[..., 1]
+    alias_slots = rows[..., 3] if per_vocab else slot_of_vocab[alias_j]
+    negs = jnp.where(accept, j, alias_j).astype(jnp.int32)
     neg_slots = jnp.where(accept, rows[..., 2],
-                          rows[..., 3]).astype(jnp.int32)
+                          alias_slots).astype(jnp.int32)
     return negs, neg_slots
 
 

@@ -273,3 +273,53 @@ def test_named_scopes_leave_the_step_bit_identical(monkeypatch):
     assert l_scoped == l_plain
     for f in t_scoped:
         np.testing.assert_array_equal(t_scoped[f], t_plain[f])
+
+
+# -- the negative sampler's slot lookups (ISSUE 25) --------------------------
+
+def _sample_gather_rows(jaxpr):
+    """Index rows of every gather under the ``sample`` scope, read off a
+    jaxpr and the jaxprs nested in it."""
+    rows = []
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "gather" and "sample"
+                in str(eqn.source_info.name_stack).split("/")):
+            rows.append(int(np.prod(eqn.invars[1].aval.shape[:-1])))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            rows += _sample_gather_rows(sub)
+    return rows
+
+
+def _trained_at(batch_size):
+    m = Word2Vec(config=_cfg())
+    m.train(synthetic_corpus(60, vocab_size=400, length=14, seed=8),
+            niters=1, batch_size=batch_size)
+    return m, m._alias_prob.shape[0], batch_size * m.negative
+
+
+@pytest.mark.parametrize("batch_size,mode", [(16, "per_draw"),
+                                             (128, "per_vocab")])
+def test_sample_scope_gathers_over_the_vocabulary_only_from_V_draws_up(
+        batch_size, mode):
+    """The step of a batch that draws fewer negatives than the vocabulary
+    has words holds no gather of V index rows under ``sample``
+    (``slot_of_vocab[alias]``); one that draws more still builds it."""
+    m, V, draws = _trained_at(batch_size)
+    assert (draws < V) == (mode == "per_draw")
+    args, kwargs = m._step._sig
+    rows = _sample_gather_rows(
+        jax.make_jaxpr(m._step._fn)(*args, **kwargs).jaxpr)
+    assert draws in rows                    # the packed row of each draw
+    assert (V in rows) == (mode == "per_vocab"), (V, rows)
+    # per draw: the alias words' slots, one lookup a draw, in its place
+    assert rows.count(draws) == (2 if mode == "per_draw" else 1)
+
+
+@pytest.mark.parametrize("batch_size,mode", [(16, "per_draw"),
+                                             (128, "per_vocab")])
+def test_sampler_gauge_names_the_branch_the_step_took(batch_size, mode):
+    _, V, draws = _trained_at(batch_size)
+    gauges = {k: v for k, v in obs.get_registry().snapshot()["gauges"].items()
+              if k.startswith("train/sampler_slot_lookups")}
+    assert gauges == {f"train/sampler_slot_lookups{{mode={mode}}}":
+                      draws if mode == "per_draw" else V}

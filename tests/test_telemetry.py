@@ -546,6 +546,25 @@ def test_traffic_summary_folds_window_fmt_labels():
     assert tpu["window_sparse"] == 3.0      # legacy series intact
 
 
+@pytest.mark.parametrize("finished", [True, False],
+                         ids=["summary_line", "crashed_run"])
+def test_traffic_summary_carries_train_gauges(finished):
+    """The ``train/`` gauges' last values print with the ``train/``
+    totals, label kept (which slot-lookup branch the step took)."""
+    _scripts_on_path()
+    import telemetry_report
+    key = "train/sampler_slot_lookups{mode=per_draw}"
+    doc = _fmt_doc()
+    doc["steps"][0]["gauges"] = {key: 80.0, "pipeline/queue_depth": 2.0}
+    if finished:
+        doc["summary"] = {"steps": 2, "gauges": {key: 80.0}, "counters": {
+            "train/host_stall_ms_total": 3.0}}
+    t = telemetry_report.traffic_summary(doc)
+    assert t["train"]["sampler_slot_lookups{mode=per_draw}"] == 80.0
+    assert ("host_stall_ms_total" in t["train"]) == finished
+    assert "queue_depth" not in str(t["train"])
+
+
 def test_wire_timeline_prefers_fmt_labels():
     """Steps carrying the fmt-labeled series are labeled by the actual
     4-way decision, not 'mixed' with the coarser legacy counter."""

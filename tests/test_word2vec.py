@@ -26,26 +26,45 @@ def test_alias_sampler_matches_unigram_075():
     np.testing.assert_allclose(freq, expect, atol=0.01)
 
 
-def test_sample_alias_slots_is_fused_sample_plus_lookup():
+@pytest.mark.parametrize("shape,mode", [
+    ((5,), "per_draw"),
+    ((776,), "per_draw"),
+    ((777,), "per_vocab"),          # as many draws as words: the pack
+    ((1000,), "per_vocab"),
+    ((16, 20), "per_draw"),
+    ((64, 20), "per_vocab"),
+    ((8, 4, 5), "per_draw"),
+    ((4, 40, 5), "per_vocab"),
+])
+def test_sample_alias_slots_is_fused_sample_plus_lookup(shape, mode):
     """The fused sampler must stay draw-stream BIT-IDENTICAL to
     sample_alias + slot_of_vocab[negs] — training uses the fused form
     while the oracle-parity tests reproduce negatives via sample_alias,
-    so any drift would silently unpin the golden checks."""
-    import numpy as np
+    so any drift would silently unpin the golden checks.  Both forms of
+    the rejected draw's slot lookup (per draw below V draws, per
+    vocabulary word from V up), a slot map longer than the vocabulary
+    and unplaced words (slot -1)."""
+    from swiftmpi_tpu.ops.sampling import (alias_slot_lookups,
+                                           sample_alias_slots)
+    V = 777
     rng = np.random.default_rng(5)
-    counts = rng.integers(1, 500, 777)
-    prob, alias = build_unigram_alias(counts)
+    prob, alias = build_unigram_alias(rng.integers(1, 500, V))
     prob_d, alias_d = jnp.asarray(prob), jnp.asarray(alias)
-    sov = jnp.asarray(rng.permutation(2048)[:777].astype(np.int32))
-    from swiftmpi_tpu.ops.sampling import sample_alias_slots
-    for shape in ((64, 20), (8, 4, 5)):
-        key = jax.random.key(11)
-        negs, neg_slots = sample_alias_slots(
-            key, prob_d, alias_d, sov, shape)
-        want = sample_alias(key, prob_d, alias_d, shape)
-        np.testing.assert_array_equal(np.asarray(negs), np.asarray(want))
-        np.testing.assert_array_equal(
-            np.asarray(neg_slots), np.asarray(sov)[np.asarray(negs)])
+    sov = rng.permutation(2048)[:900].astype(np.int32)
+    sov[rng.choice(V, 60, replace=False)] = -1
+    assert alias_slot_lookups(V, shape) == (
+        mode, V if mode == "per_vocab" else int(np.prod(shape)))
+    key = jax.random.key(11)
+    negs, neg_slots = sample_alias_slots(
+        key, prob_d, alias_d, jnp.asarray(sov), shape)
+    want = sample_alias(key, prob_d, alias_d, shape)
+    assert negs.shape == neg_slots.shape == shape
+    assert negs.dtype == neg_slots.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(negs), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(neg_slots),
+                                  sov[np.asarray(negs)])
+    if negs.size >= 160:
+        assert (np.asarray(neg_slots) == -1).any()
 
 
 def test_subsample_keep_prob_rule():
