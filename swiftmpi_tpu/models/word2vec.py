@@ -121,6 +121,39 @@ class _LossAccum:
         return float(jnp.stack(self._q).sum())
 
 
+class _PairCount:
+    """Valid (center, context) pairs against the ``(B, 2W)`` pair grid a
+    step gathers and pushes, counted from the host batches as they are
+    rendered (``_epoch_items``: before ``h2d``, no device work).  Exists
+    only with telemetry on; feeds ``train/pairs{kind=valid|grid}`` and
+    ``train_metrics``' ``pairs_per_step`` / ``pair_fill_share``.  For
+    CBOW a pair is one summed context.  Stencil batches carry no mask
+    and multi-process batches are already-placed global arrays: neither
+    is counted, and a run of only such batches exports no ``train/pairs``
+    series at all (the counters are made on the first counted batch), so
+    a series that reads 0 means no valid pair, never "not counted"."""
+
+    def __init__(self, reg):
+        self._reg = reg
+        self._valid = self._grid = None
+        self.valid = self.grid = self.steps = 0
+
+    def observe(self, ctx_mask) -> None:
+        """``ctx_mask``: ``(B, 2W)`` of one step or ``(L, B, 2W)`` of a
+        fused group."""
+        if not isinstance(ctx_mask, np.ndarray):
+            return
+        if self._valid is None:
+            self._valid = self._reg.counter("train/pairs", kind="valid")
+            self._grid = self._reg.counter("train/pairs", kind="grid")
+        valid = int(np.count_nonzero(ctx_mask))
+        self.valid += valid
+        self.grid += ctx_mask.size
+        self.steps += 1 if ctx_mask.ndim == 2 else ctx_mask.shape[0]
+        self._valid.inc(valid)
+        self._grid.inc(ctx_mask.size)
+
+
 def _stack_group_host(batches):
     """Stack a group of same-shape batches host-side (one contiguous H2D
     transfer per field, not one per batch).  Pure numpy — this is the
@@ -1469,8 +1502,12 @@ class Word2Vec:
 
         The parity sg phase draws K negatives per PAIR
         (word2vec.h:550-615 semantics), a B*2W*(K+1)-row random target
-        gather — measured 96.5ms/step vs CBOW's 11.68ms on v5e, ~8x,
-        entirely gather-bound (round-3 verdict Weak #6).  Sharing one
+        gather.  Measured at 300 wide on a table that fills a v5e chip
+        (benchmark cell ``sg2m-b2k``, 2,048 centers a step; PERF.md
+        section 5, chip runs of PR 26): that per-pair work is 14.5 ms of
+        a 175.5 ms step (``dedup`` 7.16, ``pull`` 3.07, ``math`` 2.73,
+        ``sample`` 1.58); the rest follows the table's size, not the
+        batch, in every rendering.  Sharing one
         K-negative pool across every pair in the batch keeps the same
         expected negative-term gradient (each pool pair weighted
         negative/K, the `_build_grads_shared` argument) and collapses
@@ -1589,7 +1626,7 @@ class Word2Vec:
 
     # -- training (word2vec.h:475-547) -------------------------------------
     def _epoch_items(self, batcher, batch_size: int, stencil: bool,
-                     fuse: bool):
+                     fuse: bool, pairs: Optional[_PairCount] = None):
         """Render one epoch into a stream of work items: ``('group',
         host-stacked fields, [n_words...])`` for fuse groups and
         ``('single', fields, n_words)`` otherwise.  Pure host-side
@@ -1612,6 +1649,8 @@ class Word2Vec:
                       else _stack_group_host(group))
             if sketch is not None:
                 sketch.observe(fields[0])
+            if pairs is not None and not stencil:
+                pairs.observe(fields[2])
             return ("group", fields, n_words)
 
         epoch_iter = (batcher.epoch_stencil(batch_size) if stencil
@@ -1638,6 +1677,8 @@ class Word2Vec:
                           batch.ctx_mask)
             if sketch is not None:
                 sketch.observe(fields[0])
+            if pairs is not None and not stencil:
+                pairs.observe(fields[2])
             yield ("single", fields, batch.n_words)
         if group:                  # leftover partial group
             yield group_item()
@@ -1769,6 +1810,10 @@ class Word2Vec:
                     _m.device_ms())
                 reg.gauge("train/words_per_sec").set(_m.rate())
             tel_rec.add_sampler(_tel_sample)
+        # pair counters: host sums over the batches as rendered, only
+        # with telemetry on (no sum is taken otherwise)
+        pairs = _PairCount(obs.get_registry()) \
+            if obs.get_registry().enabled else None
         if self.numerics_on and tel_rec is not None:
             self._arm_numerics(tel_rec)
         # wire tracer hot-key attribution ([obs] trace): the control
@@ -1955,7 +2000,7 @@ class Word2Vec:
                         state = self.table.state
 
                 items = self._epoch_items(batcher, batch_size, stencil,
-                                          fuse)
+                                          fuse, pairs)
                 pipe = None
                 if pipelined:
                     pipe = PrefetchIterator(
@@ -2053,6 +2098,11 @@ class Word2Vec:
                 "stall_ms_per_step": meter.stall_ms_per_step(),
                 "words_per_sec": meter.rate(),
                 "pipeline_depth": self.pipeline_depth if pipelined else 0}
+            if pairs is not None and pairs.steps:
+                self.train_metrics["pairs_per_step"] = \
+                    pairs.valid / pairs.steps
+                self.train_metrics["pair_fill_share"] = \
+                    100.0 * pairs.valid / pairs.grid
             if pipe_stats is not None:
                 self.train_metrics["pipeline"] = dict(pipe_stats)
             if self.controller is not None:

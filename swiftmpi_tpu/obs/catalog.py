@@ -59,6 +59,10 @@ SERIES = frozenset({
     # scalar slot lookups the traced step makes for its negatives,
     # mode=per_draw|per_vocab (ops/sampling.alias_slot_lookups)
     "train/sampler_slot_lookups",
+    # (center, context) pairs of the host batches fed to the step,
+    # kind=valid|grid: the batch's ctx_mask.sum() against its padded
+    # (B, 2W) pair grid (models/word2vec._PairCount)
+    "train/pairs",
     # checkpoints (io/checkpoint.py)
     "checkpoint/saves", "checkpoint/restores",
     # health probes (utils/health.py)

@@ -657,3 +657,106 @@ def test_budget_gate_aggregates_fmt_cells(tmp_path):
     assert cells["fmtrun"]["window_fmt_q"] == 2.0
     assert cells["fmtrun"]["window_fmt_bitmap"] == 1.0
     assert cells["fmtrun"]["window_sparse"] == 3.0
+
+
+# -- pair counters (ISSUE 26) ----------------------------------------------
+
+class _RecordingBatcher:
+    """Forwards a CBOWBatcher's batches and keeps what was fed."""
+
+    def __init__(self, inner):
+        self.inner, self.vocab = inner, inner.vocab
+        self.valid, self.grid = [], 0
+
+    def epoch(self, batch_size):
+        for batch in self.inner.epoch(batch_size):
+            self.valid.append(int(np.asarray(batch.ctx_mask).sum()))
+            self.grid += batch.ctx_mask.size
+            yield batch
+
+    def epoch_stencil(self, batch_size):
+        return self.inner.epoch_stencil(batch_size)
+
+
+def _pairs_run(sg, worker, telemetry, tmp_path, extra=None):
+    from swiftmpi_tpu.data.text import (CBOWBatcher, build_vocab,
+                                        synthetic_corpus)
+    from swiftmpi_tpu.models.word2vec import Word2Vec
+    from swiftmpi_tpu.utils import ConfigParser
+
+    cfg = ConfigParser().update({
+        "cluster": {"transfer": "xla"},
+        "word2vec": {"len_vec": 8, "window": 3, "negative": 2, "sg": sg,
+                     "sample": -1, "learning_rate": 0.05},
+        "server": {"initial_learning_rate": 0.3},
+        "worker": {"minibatch": 512, "telemetry": telemetry,
+                   "telemetry_path": str(tmp_path / "telemetry.jsonl"),
+                   **worker},
+    })
+    cfg.update(extra or {})
+    corpus = synthetic_corpus(30, vocab_size=50, length=12, seed=4)
+    model = Word2Vec(config=cfg)
+    model.build_from_vocab(build_vocab(corpus))
+    batcher = _RecordingBatcher(CBOWBatcher(corpus, model.vocab,
+                                            model.window))
+    model.train(batcher=batcher, niters=2, batch_size=32)
+    return model, batcher
+
+
+@pytest.mark.parametrize("worker", [{}, {"inner_steps": 3},
+                                    {"pipeline": 2}],
+                         ids=["single", "fused", "pipelined"])
+@pytest.mark.parametrize("sg", [0, 1], ids=["cbow", "sg"])
+def test_pair_counters_equal_the_batches_fed(sg, worker, tmp_path,
+                                             devices8):
+    """``pairs_per_step`` / ``pair_fill_share`` and ``train/pairs`` are
+    the host batches' ``ctx_mask.sum()`` against their ``(B, 2W)`` grid,
+    whichever way the loop feeds the step."""
+    model, fed = _pairs_run(sg, worker, 1, tmp_path)
+    m = model.train_metrics
+    assert len(fed.valid) > 4 and 0 < sum(fed.valid) < fed.grid
+    assert m["pairs_per_step"] == pytest.approx(
+        sum(fed.valid) / len(fed.valid), rel=1e-12)
+    assert m["pair_fill_share"] == pytest.approx(
+        100.0 * sum(fed.valid) / fed.grid, rel=1e-12)
+    reg = obs.get_registry()
+    assert reg.counter("train/pairs", kind="valid").value == sum(fed.valid)
+    assert reg.counter("train/pairs", kind="grid").value == fed.grid
+
+
+@pytest.mark.parametrize("sg", [0, 1], ids=["cbow", "sg"])
+def test_pair_counters_absent_with_telemetry_off(sg, monkeypatch, tmp_path,
+                                                 devices8):
+    from swiftmpi_tpu.models import word2vec
+
+    def no_sum(self, ctx_mask):
+        raise AssertionError("a pair sum was taken with telemetry off")
+
+    monkeypatch.setattr(word2vec._PairCount, "observe", no_sum)
+    model, fed = _pairs_run(sg, {}, 0, tmp_path)
+    assert not list(tmp_path.iterdir())
+    assert fed.valid and not obs.get_registry().enabled
+    assert "pairs_per_step" not in model.train_metrics
+    assert "pair_fill_share" not in model.train_metrics
+
+
+def test_uncounted_batches_export_no_pair_series(tmp_path, devices8):
+    """Stencil batches carry no mask: nothing is counted, and neither the
+    ``train/pairs`` series nor the ``train_metrics`` keys exist, so a
+    series at 0 cannot be read as "no pairs"."""
+    cfg_stencil = {"word2vec": {"len_vec": 8, "window": 3, "negative": 2,
+                                "sg": 0, "stencil": 1, "sample": -1,
+                                "learning_rate": 0.05}}
+    model, _fed = _pairs_run(0, {}, 1, tmp_path, extra=cfg_stencil)
+    assert model.resolved_rendering.startswith("stencil")
+    assert obs.get_registry().enabled
+    assert not [k for k in obs.get_registry().series_keys()
+                if k.startswith("train/pairs")]
+    assert "pairs_per_step" not in model.train_metrics
+    assert "pair_fill_share" not in model.train_metrics
+
+
+def test_pair_series_is_declared():
+    from swiftmpi_tpu.obs import catalog
+    assert "train/pairs" in catalog.SERIES
+    assert catalog.declared("train/pairs")
