@@ -44,7 +44,7 @@ ZIPF_TOKENS = 1_600_000
 # cover a truncated stream, as tests/_scale_child.py does.
 TRAIN_TOKENS = 100_000       # 160 steps an iteration, three iterations
 FUSED_TOKENS = 25_000        # 40 steps an iteration = 10 scan groups of 4
-KERNEL_BATCH = 16_384        # bench.py's center count per step
+KERNEL_BATCH = 16_384        # cbow2m-b16k's center count per step
 
 
 def log(msg: str) -> None:

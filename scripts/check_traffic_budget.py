@@ -4,7 +4,8 @@
 The window-coalesced push exists to cut wire traffic; this script makes
 that a *checked* property instead of a one-time measurement.  It reads
 two bench result files (``{"ts": ..., "result": {cell: {metric:
-value}}}`` around bench.py's ``BENCH_CHILD`` cells), lines up every
+value}}}``: the cell records of the yardstick PR 29 deleted; nothing in
+the tree writes that format any more, ROADMAP D6), lines up every
 cell present in both, and fails when a traffic metric regressed beyond
 tolerance:
 

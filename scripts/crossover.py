@@ -4,9 +4,9 @@ push/pull cost across table capacity x push-batch size (SURVEY §7 hard
 part (a); VERDICT round-1 'next' #7).
 
 Times one pull + one push (w2v access, d=100) per (backend, capacity, B)
-cell on the current default platform, using the same D2H fence as
-bench.py.  Emits one JSON line per cell plus a summary table and the
-measured sparse->dense crossover ratio per capacity; the numbers behind
+cell on the current default platform, fenced by a D2H read.  Emits
+one JSON line per cell plus a summary table and the measured
+sparse->dense crossover ratio per capacity; the numbers behind
 docs/ARCHITECTURE.md's "push backend selection" section and
 XlaTransfer's auto heuristic.
 

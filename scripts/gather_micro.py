@@ -1,9 +1,9 @@
 #!/usr/bin/env python
 """Microbench: XLA row-gather / scatter-add throughput on the live chip.
 
-The w2v step is gather/scatter bound (profile_step.py: the fused
-gather+math phase dominates at ~12ms for ~475K row accesses).  This asks
-what the hardware path can actually sustain under layouts we control:
+The w2v step's batch work is gathers and scatters (PERF.md section
+5).  This asks what the hardware path can actually sustain under
+layouts we control:
 
   * row width 100 (demo.conf len_vec) vs 128 (lane-aligned)
   * fp32 vs bf16 rows

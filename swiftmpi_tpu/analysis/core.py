@@ -148,21 +148,18 @@ class LintContext:
 
 # -- file collection --------------------------------------------------------
 
-_DEFAULT_SCOPES = ("swiftmpi_tpu", "scripts", "bench.py")
+_DEFAULT_SCOPES = ("swiftmpi_tpu", "scripts")
 _EXCLUDE_DIRS = {"__pycache__", ".git", "runs"}
 
 
 def default_paths(root: str) -> List[str]:
-    """The repo lint scope: the package, scripts/, and bench.py.
+    """The repo lint scope: the package and scripts/.
     tests/ is deliberately out — fixtures there reproduce violations
     on purpose."""
     out: List[str] = []
     for scope in _DEFAULT_SCOPES:
-        p = os.path.join(root, scope)
-        if os.path.isfile(p):
-            out.append(p)
-            continue
-        for dirpath, dirnames, filenames in os.walk(p):
+        for dirpath, dirnames, filenames in os.walk(
+                os.path.join(root, scope)):
             dirnames[:] = [d for d in dirnames if d not in _EXCLUDE_DIRS]
             for fn in sorted(filenames):
                 if fn.endswith(".py"):

@@ -24,7 +24,7 @@ def build_parser() -> argparse.ArgumentParser:
                     "docs/ARCHITECTURE.md 'Invariant catalog')")
     p.add_argument("paths", nargs="*",
                    help="files to lint (default: repo lint scope — the "
-                        "package, scripts/, bench.py)")
+                        "package and scripts/)")
     p.add_argument("--root", default=None,
                    help="repo root (default: auto-detected from the "
                         "package location)")

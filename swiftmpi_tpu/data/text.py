@@ -389,15 +389,3 @@ def synthetic_corpus_bulk(n_sentences: int, vocab_size: int,
         base[:, ::3] = (topics * 7 + base[:, ::3] // 5) % vocab_size
         out[i:i + n] = base + 1                  # keys are 1-based ints
     return out
-
-
-def write_tokens_file(arr: np.ndarray, path: str,
-                      chunk_rows: int = 4096) -> None:
-    """Write an (n_sentences, length) key array as the loader's text
-    format (one space-separated sentence per line), chunked so a 100M-
-    token corpus streams through a bounded buffer."""
-    with open(path, "w") as f:
-        for i in range(0, arr.shape[0], chunk_rows):
-            chunk = arr[i:i + chunk_rows]
-            f.write("\n".join(
-                " ".join(map(str, row)) for row in chunk) + "\n")

@@ -241,7 +241,7 @@ class GloVe:
                            1.0).astype(np.float32).reshape(inner, B))
 
     def stage(self, sel: np.ndarray, inner: int, B: int):
-        """Device-side ``stage_host`` (kept as the bench cell's API)."""
+        """Device-side ``stage_host``."""
         return tuple(jnp.asarray(f)
                      for f in self.stage_host(sel, inner, B))
 

@@ -439,7 +439,6 @@ class Word2Vec:
         self.vocab: Optional[Vocab] = None
         self._step = None
         self._fused_cache = {}
-        self._tail_fuse_frozen = False
         #: train steps dispatched by this model, over all train() calls:
         #: the `dispatch` span's step= (a fused group carries steps=L)
         self._steps_dispatched = 0
@@ -635,14 +634,9 @@ class Word2Vec:
         native.py's seed+epoch_i) shifts the full-batch count between
         epochs, so a multi-epoch run may compile a few tail lengths as
         it encounters them — amortized across the run and persisted by
-        the JAX compilation cache.  Timing harnesses that must NEVER
-        compile inside a timed region set ``_tail_fuse_frozen`` after
-        their warm epoch: frozen, an uncached length reports None and
-        the caller falls back to the already-compiled single step."""
+        the JAX compilation cache."""
         fn = self._fused_cache.get(n_inner)
         if fn is None:
-            if self._tail_fuse_frozen and n_inner != self.inner_steps:
-                return None
             # cost-catalog funnel (ISSUE 14): one name covers every
             # fused length — each length is its own handle, so a new
             # tail length books a compile, never a retrace
@@ -1969,17 +1963,13 @@ class Word2Vec:
                     # compiled single step.
                     nonlocal state
                     L = len(n_words)
-                    fused = self._fused_for(L) if L > 1 else None
-                    if fused is None:
-                        # lone batch, or an uncached tail length while
-                        # tail-fuse compiles are frozen (timed regions):
-                        # peel the stacked fields back into singles —
-                        # the producer never needs to know compile-cache
-                        # state, so the item stream stays deterministic
-                        for i in range(L):
-                            run_single(tuple(f[i] for f in fields),
-                                       n_words[i])
+                    if L == 1:
+                        # lone batch: peel the stacked fields back
+                        # into a single
+                        run_single(tuple(f[0] for f in fields),
+                                   n_words[0])
                         return
+                    fused = self._fused_for(L)
                     self._key, sub = jax.random.split(self._key)
                     fields = to_device(fields)
                     with obs.span("dispatch", steps=L,

@@ -66,14 +66,13 @@ log = get_logger(__name__)
 COSTS_SCHEMA = "smtpu-costs/1"
 COSTS_SCHEMA_PREFIX = "smtpu-costs/"
 
-#: env override that arms the catalog without a config edit — the bench
-#: harness sets it in child processes so rooflines get measured numbers.
+#: env override that arms the catalog without a config edit.
 ENV_COSTS = "SMTPU_COSTS"
 
 
 class CostCatalog:
     """Per-process compile-event ledger.  Created disarmed; armed by
-    :func:`configure_costs` (or programmatically by the bench child).
+    :func:`configure_costs`.
     Writers go through :func:`get_catalog` each call — the instance is
     swapped by :func:`reset_for_tests`, like the metrics registry."""
 

@@ -4,8 +4,7 @@
 Thin wrappers over ``jax.profiler``: ``trace(logdir)`` captures a
 TensorBoard-loadable device trace around a code block; ``annotate(name)``
 labels host spans so steps show up named in the trace; ``StepTimer``
-measures steady-state step latency with device sync, the number the
-benchmarks report.
+measures steady-state step latency with device sync.
 """
 
 from __future__ import annotations
@@ -37,10 +36,8 @@ def annotate(name: str, **attrs):
 class StepTimer:
     """Wall-clock per-step stats with an explicit device barrier.
 
-    Keeps every sample (bench loops are a few hundred steps at most),
-    so percentiles are exact order statistics, not bucket
-    interpolations — this is the ground truth the registry histogram's
-    interpolated quantiles are validated against in tests."""
+    Keeps every sample, so percentiles are exact order statistics,
+    not bucket interpolations."""
 
     def __init__(self):
         self._times: List[float] = []
@@ -80,31 +77,3 @@ class StepTimer:
     @property
     def p50(self) -> float:
         return self.percentile(0.50)
-
-    @property
-    def p95(self) -> float:
-        return self.percentile(0.95)
-
-    @property
-    def p99(self) -> float:
-        return self.percentile(0.99)
-
-    def summary_ms(self) -> dict:
-        """Mean/p50/p95/p99 in milliseconds — the bench-cell latency
-        fields (mean-only latency hides tail regressions; the p99 is
-        what a serving SLO would gate on)."""
-        return {"mean": self.mean * 1e3, "p50": self.p50 * 1e3,
-                "p95": self.p95 * 1e3, "p99": self.p99 * 1e3}
-
-    def publish(self, name: str = "step_ms", **labels) -> None:
-        """Feed every recorded sample into the telemetry registry's
-        ``<name>{labels}`` histogram (no-op when telemetry is off), so
-        bench latency distributions land in the same sink as training
-        phase timings."""
-        from swiftmpi_tpu import obs
-        reg = obs.get_registry()
-        if not reg.enabled:
-            return
-        h = reg.histogram(name, **labels)
-        for dt in self._times:
-            h.observe(dt * 1e3)

@@ -47,9 +47,7 @@ def sweep(cluster, nprocs):
     """Staleness-envelope mode (round-4 verdict Next #8): train the
     same corpus at ``local_steps`` ∈ SMTPU_ASYNC_SWEEP across ALL
     launched processes, recording final loss + wall per setting.
-    Rank 0 prints one ``MP_SWEEP_JSON {...}`` line the caller archives
-    (scripts/async_envelope.py renders the loss-vs-staleness /
-    wall-vs-staleness table from it).
+    Rank 0 prints one ``MP_SWEEP_JSON {...}`` line for the caller.
 
     The LOSS column is the algorithmic envelope
     (staleness-vs-convergence is host-independent).  The recorded rate

@@ -1,0 +1,393 @@
+"""Tier-1's share of the yardstick: every cell of ``BENCHMARK.json``
+rehearsed on the CPU, and the program names the harness reads.
+
+``benchmark/run.py`` is what the driver measures every PR with, on the
+chip; nothing else under ``tests/`` runs it.  Two guards:
+
+* ``test_cell_rehearses_on_cpu[<cell>]`` — one case per ``workloads``
+  entry, read from ``BENCHMARK.json`` (a later cell gets its case with
+  no edit): the cell's own path at its toy size through
+  ``--rehearse-cpu``, first step held to the plain reference, on
+  ``chips`` virtual devices.  Counts and correctness only: a CPU run
+  prints no time, rate, peak or share (``benchmark/README.md`` "A CPU
+  rehearsal").
+* ``test_harness_surface[<name>]`` — one case per line of
+  ``benchmark/README.md`` "What the harness touches in the program".
+  That README section is this test's specification; the test imports
+  nothing from ``benchmark/`` and reads every name from the program, on
+  toy models built by the harness's own call sequence (conf file ->
+  ``global_config().load_conf().parse()`` -> ``Word2Vec(seed=)`` ->
+  ``build_from_vocab`` -> ``train(batcher=, niters=1)``).  A PR that
+  renames one of these learns it here, not from the driver's chip run.
+"""
+
+import inspect
+import json
+import math
+import os
+import subprocess
+import sys
+from types import SimpleNamespace
+
+import jax
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RUN = [sys.executable, os.path.join(REPO, "benchmark", "run.py")]
+
+with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
+    BENCHMARK = json.load(_f)
+CELLS = {w["name"]: w for w in BENCHMARK["workloads"]}
+
+
+ARGS = ["--seed", "11", "--seconds", "2", "--trace", "0"]
+
+
+def run_child(argv, tmp_path):
+    """A process of its own on the CPU backend, without the suite's
+    XLA_FLAGS: the harness makes ``chips`` virtual devices only when
+    XLA_FLAGS names no count, and conftest.py names eight."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("XLA_FLAGS", None)
+    return subprocess.run(argv, capture_output=True, text=True, timeout=170,
+                          env=env, cwd=str(tmp_path))
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_cell_rehearses_on_cpu(cell, tmp_path):
+    res = run_child(RUN + ["--workload", cell, *ARGS, "--rehearse-cpu"],
+                    tmp_path)
+    assert res.returncode == 0, res.stderr[-3000:]
+    result = json.loads(res.stdout.strip().splitlines()[-1])
+    assert result["correct"] is True, res.stdout[-3000:]
+    assert result["failed"] == 0 and result["attempted"] > 0
+    assert result["device"]["platform"] == "cpu"
+    assert result["device"]["count"] == CELLS[cell]["chips"]
+    # a CPU run may say what the loss is, never how fast or how full
+    assert set(result["metrics"]) == {"train_loss_fixed"}
+    assert math.isfinite(result["metrics"]["train_loss_fixed"]["value"])
+
+
+def test_run_without_tpu_prints_no_result(tmp_path):
+    """No TPU and no ``--rehearse-cpu``: non-zero exit, no result line,
+    and no child process started (the chip belongs to one process —
+    nothing on the measurement path may spawn another)."""
+    marker = tmp_path / "spawned"
+    prog = (
+        "import runpy, subprocess, sys\n"
+        "def boom(*a, **k):\n"
+        f"    open({str(marker)!r}, 'w').close()\n"
+        "    raise AssertionError('benchmark/run.py started a process')\n"
+        "subprocess.Popen = boom\n"
+        f"sys.argv = {[RUN[1], '--workload', sorted(CELLS)[0], *ARGS]!r}\n"
+        f"runpy.run_path({RUN[1]!r}, run_name='__main__')\n")
+    res = run_child([sys.executable, "-c", prog], tmp_path)
+    assert res.returncode != 0
+    assert "no TPU" in res.stderr
+    assert not any(line.lstrip().startswith("{")
+                   for line in res.stdout.splitlines()), res.stdout
+    assert not marker.exists()
+
+
+# -- what the harness touches in the program -----------------------------------
+
+V, WINDOW, NEGATIVE, LEN_VEC, MINIBATCH = 300, 3, 4, 16, 4096
+FIELDS = {"h", "v", "h2sum", "v2sum"}
+STEP_PARAMS = ("state", "slot_of_vocab", "alias_prob", "alias_idx",
+               "centers", "contexts", "ctx_mask", "key")
+
+
+class TwoBatches:
+    """The batcher ``train()`` is handed: the native batcher's first two
+    full batches of an epoch, and a note of every size asked for."""
+
+    def __init__(self, inner):
+        self.inner, self.vocab = inner, inner.vocab
+        self.asked, self.batches = [], []
+
+    def epoch(self, batch_size):
+        self.asked.append(batch_size)
+        gen = self.inner.epoch(batch_size)
+        try:
+            for batch in gen:
+                if batch.n_words == batch_size:
+                    self.batches.append(batch)
+                    yield batch
+                if len(self.batches) == 2:
+                    return
+        finally:
+            gen.close()           # stops the native prefetch thread
+
+
+def build_toy(sg, workdir):
+    """A toy model by the harness's own call sequence, trained one call
+    with telemetry on (the traced run's conf)."""
+    from swiftmpi_tpu import obs
+    from swiftmpi_tpu.data import native
+    from swiftmpi_tpu.data.text import Vocab
+    from swiftmpi_tpu.models.word2vec import Word2Vec
+    from swiftmpi_tpu.utils import global_config, reset_global_config
+
+    conf = os.path.join(workdir, f"cell_sg{sg}.conf")
+    with open(conf, "w") as f:
+        f.write(f"[word2vec]\nlen_vec: {LEN_VEC}\nwindow: {WINDOW}\n"
+                f"negative: {NEGATIVE}\nsg: {sg}\nlearning_rate: 0.05\n"
+                "sample: 0.0001\n[server]\ninitial_learning_rate: 0.7\n"
+                f"[worker]\nminibatch: {MINIBATCH}\ntelemetry: 1\n"
+                "telemetry_path: "
+                + os.path.join(workdir, f"telemetry_sg{sg}.jsonl") + "\n")
+    reset_global_config()
+    global_config().load_conf(conf).parse()
+    model = Word2Vec(seed=5)
+
+    rng = np.random.default_rng(29)
+    keys = rng.permutation(10 * V)[:V].astype(np.uint64) + np.uint64(1)
+    tokens = rng.integers(0, V, 60_000).astype(np.int32)
+    counts = np.bincount(tokens, minlength=V).astype(np.int64)
+    order = np.lexsort((keys, -counts))      # count desc, key asc
+    index_of = np.empty(V, np.int32)
+    index_of[order] = np.arange(V, dtype=np.int32)
+    vocab = Vocab(keys[order], counts[order],
+                  dict(zip(keys[order].tolist(), range(V))))
+    offsets = np.arange(0, len(tokens) + 1, 40, dtype=np.int64)
+    model.build_from_vocab(vocab)
+
+    assert native.available(), "the native loader did not build"
+    batcher = TwoBatches(native.PrefetchingCBOWBatcher(
+        index_of[tokens], offsets, vocab, model.window, model.sample,
+        seed=2013))
+    key_before = np.asarray(jax.random.key_data(model._key))
+
+    def span_counts():
+        hists = obs.get_registry().snapshot()["hists"]
+        return {k[len("phase_ms{phase="):-1]: h["count"]
+                for k, h in hists.items() if k.startswith("phase_ms{phase=")}
+
+    before = span_counts()        # the other toy's, if one test built both
+    losses = model.train(batcher=batcher, niters=1)
+    jax.block_until_ready(model.table.state)
+    spans = {k: n - before.get(k, 0) for k, n in span_counts().items()}
+    return SimpleNamespace(model=model, vocab=vocab, batcher=batcher,
+                           key_before=key_before, losses=losses,
+                           spans=spans)
+
+
+@pytest.fixture(scope="module")
+def toy(tmp_path_factory):
+    """``toy(sg)``: the CBOW or the skip-gram toy, built once a module."""
+    workdir, built = str(tmp_path_factory.mktemp("surface")), {}
+
+    def get(sg=0):
+        if sg not in built:
+            built[sg] = build_toy(sg, workdir)
+        return built[sg]
+    return get
+
+
+SURFACE = {}
+
+
+def surface(case):
+    SURFACE[case.__name__] = case
+    return case
+
+
+@surface
+def conf_sequence(toy):
+    """``global_config()`` / ``reset_global_config()`` /
+    ``load_conf().parse()``, the conf keys written, and
+    ``ensure_compile_cache()``."""
+    from swiftmpi_tpu.utils import ConfigParser, global_config
+    from swiftmpi_tpu.utils.xla_env import ensure_compile_cache
+
+    assert isinstance(global_config(), ConfigParser)
+    m = toy().model
+    assert (m.len_vec, m.window, m.negative, m.sg) == \
+        (LEN_VEC, WINDOW, NEGATIVE, 0)
+    assert (m.alpha, m.sample, m.minibatch) == (0.05, 0.0001, MINIBATCH)
+    assert m.config.get("server", "initial_learning_rate").to_float() == 0.7
+    assert m.config.get("worker", "telemetry").to_bool()
+    assert toy(1).model.sg == 1
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        assert ensure_compile_cache() == "/somewhere/else"
+
+
+@surface
+def vocab_and_batcher(toy):
+    """``Vocab(keys, counts, index)``, ``native.available()``,
+    ``PrefetchingCBOWBatcher(tokens, offsets, vocab, window, sample,
+    seed=)`` with ``.epoch`` / ``.epoch_stencil`` / ``.vocab``, and the
+    batch fields."""
+    from swiftmpi_tpu.data import native
+    from swiftmpi_tpu.data.text import Vocab
+
+    assert [f.name for f in Vocab.__dataclass_fields__.values()] == \
+        ["keys", "counts", "index"]
+    params = list(inspect.signature(
+        native.NativeCBOWBatcher.__init__).parameters)
+    assert params == ["self", "tokens", "offsets", "vocab", "window",
+                      "sample", "seed"]
+    t = toy()
+    inner = t.batcher.inner
+    assert isinstance(inner, native.PrefetchingCBOWBatcher)
+    assert inner.vocab is t.vocab
+    assert callable(inner.epoch) and callable(inner.epoch_stencil)
+    batch = t.batcher.batches[0]
+    B = t.batcher.asked[0]
+    assert batch.centers.shape == (B,)
+    assert batch.contexts.shape == batch.ctx_mask.shape == (B, 2 * WINDOW)
+    assert batch.n_words == B
+
+
+@surface
+def train_call(toy):
+    """``train(batcher=, niters=1)``: one loss an iteration, the batch
+    size it asks the batcher for, and ``.window .sample .stencil``."""
+    t = toy()
+    assert len(t.losses) == 1 and math.isfinite(float(t.losses[0]))
+    assert t.batcher.asked == [max(256, MINIBATCH // (2 * WINDOW))]
+    assert len(t.batcher.batches) == 2
+    m = t.model
+    assert (m.window, m.sample, m.stencil) == (WINDOW, 0.0001, 0)
+
+
+@surface
+def table_state(toy):
+    """``.table.state``: a dict of plain ``jax.Array``s by field, each of
+    the logical shape ``(table.capacity, len_vec)``."""
+    for sg in (0, 1):
+        table = toy(sg).model.table
+        assert type(table.state) is dict
+        assert set(table.state) == FIELDS
+        for field, a in table.state.items():
+            assert isinstance(a, jax.Array), field
+            assert a.shape == (table.capacity, LEN_VEC), field
+            assert a.dtype == np.float32, field
+        assert table.capacity >= V
+
+
+@surface
+def key_index_lookup(toy):
+    """``.table.key_index.lookup(keys)``: one slot a key, inside the
+    table, and the rows train() moved are among them."""
+    t = toy()
+    table = t.model.table
+    slots = np.asarray(table.key_index.lookup(t.vocab.keys))
+    assert slots.shape == (V,) and len(set(slots.tolist())) == V
+    assert slots.min() >= 0 and slots.max() < table.capacity
+    free = np.ones(table.capacity, bool)
+    free[slots] = False
+    v2sum = np.asarray(table.state["v2sum"])
+    trained = t.batcher.batches[0].contexts[t.batcher.batches[0].ctx_mask]
+    assert (v2sum[slots[trained]] != v2sum[np.flatnonzero(free)[0]]).any()
+
+
+@surface
+def cluster_mesh(toy):
+    """``.cluster.mesh`` and ``.cluster.table_axis``: the rows of every
+    field split evenly over that axis."""
+    m = toy().model
+    shards = int(m.cluster.mesh.shape[m.cluster.table_axis])
+    assert shards == len(jax.devices())
+    for a in m.table.state.values():
+        assert {s.data.shape[0] for s in a.addressable_shards} == \
+            {a.shape[0] // shards}
+
+
+@surface
+def sampler_privates(toy):
+    """``_key`` (``train()`` keeps ``split(key)[0]`` and hands the step
+    ``split(key)[1]``), ``_alias_prob`` / ``_alias_idx`` (unigram^0.75 of
+    the counts) and ``ops.sampling.sample_alias``."""
+    from swiftmpi_tpu.ops.sampling import sample_alias
+
+    t = toy()
+    m = t.model
+    key = jax.random.wrap_key_data(t.key_before)
+    for _ in t.batcher.batches:              # one split a step
+        key = jax.random.split(key)[0]
+    assert np.array_equal(jax.random.key_data(m._key),
+                          jax.random.key_data(key))
+    prob = np.asarray(m._alias_prob, np.float64)
+    alias = np.asarray(m._alias_idx)
+    assert prob.shape == alias.shape == (V,)
+    p = (prob + np.bincount(alias, 1.0 - prob, minlength=V)) / V
+    want = t.vocab.counts.astype(np.float64) ** 0.75
+    assert np.abs(p - want / want.sum()).max() < 1e-6
+    draws = np.asarray(sample_alias(jax.random.split(m._key)[1],
+                                    m._alias_prob, m._alias_idx,
+                                    (8, NEGATIVE)))
+    assert draws.shape == (8, NEGATIVE)
+    assert draws.min() >= 0 and draws.max() < V
+
+
+@surface
+def build_step_signature(toy):
+    """``_build_step()``: the positional parameters the harness's
+    ``tools/compile_real_size.py`` lowers by, and ``.lower``."""
+    for sg in (0, 1):
+        step = toy(sg).model._build_step()
+        assert tuple(inspect.signature(step).parameters) == STEP_PARAMS
+        assert callable(step.lower)
+
+
+@surface
+def resolved_rendering(toy):
+    """``resolved_rendering`` under the two configurations' keys: the
+    per-center CBOW step and the per-pair skip-gram step."""
+    assert toy(0).model.resolved_rendering == "gather"
+    assert toy(1).model.resolved_rendering == "sg"
+
+
+@surface
+def train_metrics(toy):
+    """The ``train_metrics`` keys the ``train_metrics`` reader takes."""
+    for sg in (0, 1):
+        t = toy(sg)
+        metrics = t.model.train_metrics
+        for key in ("stall_ms_per_step", "pairs_per_step",
+                    "pair_fill_share"):
+            assert isinstance(metrics[key], (int, float)), (sg, key)
+            assert math.isfinite(metrics[key]), (sg, key)
+        valid = [b.ctx_mask.sum() for b in t.batcher.batches]
+        assert metrics["pairs_per_step"] == pytest.approx(np.mean(valid))
+        assert 0 < metrics["pair_fill_share"] <= 100
+
+
+@surface
+def host_spans(toy):
+    """The span names the harness credits device idle gaps to: declared,
+    and (``render`` is the input pipeline's, off in every cell) opened
+    once a step by the ``train()`` call the cells make."""
+    from swiftmpi_tpu.obs.catalog import HOST_SPANS
+
+    assert {"dispatch", "render", "h2d", "input_wait"} <= set(HOST_SPANS)
+    t = toy()
+    steps = len(t.batcher.batches)
+    assert t.spans["dispatch"] == t.spans["h2d"] == steps
+    assert t.spans["input_wait"] >= steps
+
+
+@surface
+def compile_tool(toy):
+    """``tools/compile_real_size.py`` only: ``Cluster(config, devices=)``,
+    ``Cluster.create_table`` and ``SparseTable._init_state``."""
+    from swiftmpi_tpu.cluster.cluster import Cluster
+    from swiftmpi_tpu.parameter.sparse_table import SparseTable
+
+    assert list(inspect.signature(Cluster.__init__).parameters)[:3] == \
+        ["self", "config", "devices"]
+    assert list(inspect.signature(Cluster.create_table).parameters)[:4] \
+        == ["self", "name", "access", "capacity_per_shard"]
+    assert list(inspect.signature(SparseTable._init_state).parameters) == \
+        ["self"]
+    m = toy().model
+    assert set(m.access.fields) == FIELDS
+    assert m.table.key_index.capacity == m.table.capacity
+
+
+@pytest.mark.parametrize("name", sorted(SURFACE))
+def test_harness_surface(name, toy):
+    SURFACE[name](toy)

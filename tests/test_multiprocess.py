@@ -103,10 +103,7 @@ def test_eight_process_async_staleness():
     """The reference envelope's full width (round-4 verdict Weak #5 /
     Next #8): 8 real jax.distributed processes — cluster_run.sh:2's
     ``mpirun -np 8`` shape — training with cross-process bounded
-    staleness.  One sweep setting here keeps the suite bounded; the
-    full local_steps ∈ {1,4,16} envelope is scripts/async_envelope.py
-    (archived in .bench_cache/async_envelope.json, table in
-    docs/ARCHITECTURE.md)."""
+    staleness.  One sweep setting here keeps the suite bounded."""
     require_cross_process_collectives()
     res = run_launch("-np", "8", "-cpu", "2", "--",
                      sys.executable, os.path.join(REPO, "tests",

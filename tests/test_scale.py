@@ -37,8 +37,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # (timeout 870 in the ROADMAP verify command) can no longer hold it
 # alongside the grown suite; run explicitly via
 # `pytest -m slow tests/test_scale.py`.  The 1M-scale host path stays
-# tier-1-guarded by the lookup-throughput sanity below and the tiny
-# 1M-shape bench-cell drives (tests/test_bench_cells.py).
+# tier-1-guarded by the lookup-throughput sanity below.
 def test_million_word_vocab_end_to_end(tmp_path):
     sys.path.insert(0, os.path.join(REPO, "tests"))
     try:

@@ -812,19 +812,6 @@ def test_w2v_partial_tail_group_fuses(devices8):
     base_losses = base.train(corpus, niters=3, batch_size=64)
     for a, b in zip(losses, base_losses):
         assert abs(a - b) / b < 0.25, (losses, base_losses)
-    # frozen (timed regions): an UNSEEN tail length must fall back to
-    # the compiled single step, never compile mid-epoch (review
-    # finding: per-epoch subsampling shifts the tail length, and a
-    # fresh multi-second compile inside a timed epoch corrupts the
-    # epoch-wall cell)
-    model._fused_cache.clear()
-    model._tail_fuse_frozen = True
-    try:
-        frozen_losses = model.train(corpus, niters=1, batch_size=64)
-        assert not model._fused_cache          # nothing compiled
-        assert np.isfinite(frozen_losses[0])
-    finally:
-        model._tail_fuse_frozen = False
 
 
 def test_w2v_cli_hogwild_variant(tmp_path, devices8):
