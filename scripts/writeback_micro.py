@@ -1,0 +1,294 @@
+"""Micro A/B on the chip: forms of the sparse push's row write-back.
+
+``transfer/xla.py::_push_sparse`` gathers the current rows of a field at
+the batch's representative slots, applies the access method and writes the
+rows back.  This script times that read-modify-write alone, on one donated
+``f32[2340001, 300]`` field (``cbow2m-demo``'s table, default layout), for
+each candidate form of the write-back and the issue's two batch sizes (plus
+three half-padded ones for the forms that were candidates), and reads the
+times from a device trace (ISSUE 30; the table is in PERF.md section 6).
+
+    python scripts/writeback_micro.py                # on the chip
+    JAX_PLATFORMS=cpu python scripts/writeback_micro.py --compile-only DIR
+        # no chip: compile every form for a described v5e, write the HLO
+
+Writes ``chiprun_out/writeback_micro.json`` and prints the table.
+"""
+
+import argparse
+import glob
+import json
+import os
+import re
+import shutil
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+from jax import lax  # noqa: E402
+
+CAP, D = 2340001, 300
+#: (valid rows, batch length): the tail is ``capacity`` padding, as the
+#: push's ``rep_slots`` has after its dedup
+SIZES = {"5k": (5000, 5500), "100k": (100000, 110000),
+         "2.7k_of_5.5k": (2750, 5500), "50k_of_110k": (50000, 110000),
+         "80k_of_180k": (80000, 180224)}
+ISSUE_SIZES = ("5k", "100k")
+RUNS = 4
+CHUNK = 2048
+
+
+def _rmw(x, rep):
+    valid = rep < CAP
+    cur = jnp.take(x, jnp.where(valid, rep, 0), axis=0)
+    return cur, valid
+
+
+def _apply(cur, g):
+    # stand-in for access.apply_push: elementwise on the gathered rows
+    return cur - 0.05 * g * lax.rsqrt(cur * cur + 1.0)
+
+
+def _set(x, rep, upd, mode="drop", **hints):
+    return x.at[rep].set(upd, mode=mode, **hints)
+
+
+def _route_pad(rep, valid, upd, cur, to_last):
+    """Padding rewrites a real row with that row's own new value (the
+    last valid representative keeps the indices ascending; the first
+    does not), so no index is out of bounds."""
+    n = jnp.sum(valid)
+    at = jnp.maximum(n - 1, 0) if to_last else 0
+    tgt_pad = jnp.where(n > 0, rep[at], 0)
+    val_pad = jnp.where(n > 0, upd[at], cur[at])
+    return (jnp.where(valid, rep, tgt_pad),
+            jnp.where(valid[:, None], upd, val_pad[None, :]))
+
+
+def form_a(x, rep, g):          # today's call
+    cur, _ = _rmw(x, rep)
+    return _set(x, rep, _apply(cur, g), indices_are_sorted=True,
+                unique_indices=True)
+
+
+def form_b(x, rep, g):          # no hints
+    cur, _ = _rmw(x, rep)
+    return _set(x, rep, _apply(cur, g))
+
+
+def form_c(x, rep, g):          # unique only
+    cur, _ = _rmw(x, rep)
+    return _set(x, rep, _apply(cur, g), unique_indices=True)
+
+
+def form_d(x, rep, g):          # sorted only
+    cur, _ = _rmw(x, rep)
+    return _set(x, rep, _apply(cur, g), indices_are_sorted=True)
+
+
+def form_e(x, rep, g):          # today's, gather kept out of the fusion
+    cur, _ = _rmw(x, rep)
+    upd = lax.optimization_barrier(_apply(cur, g))
+    return _set(x, rep, upd, indices_are_sorted=True, unique_indices=True)
+
+
+def form_f(x, rep, g):          # padding -> last valid row, in bounds
+    cur, valid = _rmw(x, rep)
+    tgt, vals = _route_pad(rep, valid, _apply(cur, g), cur, True)
+    return _set(x, tgt, vals, mode="promise_in_bounds")
+
+
+def form_f_s(x, rep, g):        # ... + sorted (still true)
+    cur, valid = _rmw(x, rep)
+    tgt, vals = _route_pad(rep, valid, _apply(cur, g), cur, True)
+    return _set(x, tgt, vals, mode="promise_in_bounds",
+                indices_are_sorted=True)
+
+
+def form_g(x, rep, g):          # a loop of row dynamic_update_slices
+    cur, valid = _rmw(x, rep)
+    tgt, vals = _route_pad(rep, valid, _apply(cur, g), cur, False)
+
+    def body(i, acc):
+        return lax.dynamic_update_slice(
+            acc, lax.dynamic_slice_in_dim(vals, i, 1), (tgt[i], 0))
+    return lax.fori_loop(0, rep.shape[0], body, x)
+
+
+def _chunk_loop(x, rep, upd, valid):
+    """Write the rows a chunk at a time, only as many chunks as hold a
+    valid row: the valid representatives are the head of ``rep``."""
+    B = rep.shape[0]
+    pad = -B % CHUNK
+    rep = jnp.concatenate([rep, jnp.full((pad,), CAP, rep.dtype)])
+    upd = jnp.concatenate([upd, jnp.zeros((pad, D), upd.dtype)])
+    n_chunks = (jnp.sum(valid, dtype=jnp.int32) + CHUNK - 1) // CHUNK
+
+    def body(i, acc):
+        at = i * CHUNK
+        return acc.at[lax.dynamic_slice_in_dim(rep, at, CHUNK)].set(
+            lax.dynamic_slice_in_dim(upd, at, CHUNK), mode="drop",
+            unique_indices=True)
+    return lax.fori_loop(0, n_chunks, body, x)
+
+
+def form_h(x, rep, g):          # per-row chunks, trip count = rows written
+    cur, valid = _rmw(x, rep)
+    return _chunk_loop(x, rep, _apply(cur, g), valid)
+
+
+def form_i(x, rep, g):          # h below the crossover, today's sweep above
+    cur, valid = _rmw(x, rep)
+    upd = _apply(cur, g)
+    few = jnp.sum(valid, dtype=jnp.int32) * 19 < CAP
+    return lax.cond(
+        few, lambda x: _chunk_loop(x, rep, upd, valid),
+        lambda x: _set(x, rep, upd, indices_are_sorted=True,
+                       unique_indices=True), x)
+
+
+def form_gather_only(x, rep, g):  # the floor: the read half, no write
+    cur, _ = _rmw(x, rep)
+    return _apply(cur, g)
+
+
+FORMS = {"a": form_a, "b": form_b, "c": form_c, "d": form_d, "e": form_e,
+         "f": form_f, "f_s": form_f_s, "g": form_g, "h": form_h, "i": form_i,
+         "gather_only": form_gather_only}
+
+
+def _jitted(form, size):
+    fn = FORMS[form]
+
+    def wb(x, rep, g):
+        return fn(x, rep, g)
+    wb.__name__ = f"wb_{form}_{size}"
+    donate = () if form == "gather_only" else (0,)
+    return jax.jit(wb, donate_argnums=donate)
+
+
+def _cases():
+    for size in SIZES:
+        for form in FORMS:
+            if form == "g" and size != "5k":
+                continue
+            if size not in ISSUE_SIZES and form not in ("a", "b", "h", "i"):
+                continue
+            yield form, size
+
+
+def compile_only(out_dir):
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    dev = SingleDeviceSharding(topo.devices[0])
+    os.makedirs(out_dir, exist_ok=True)
+    for form, size in _cases():
+        B = SIZES[size][1]
+        c = _jitted(form, size).lower(
+            jax.ShapeDtypeStruct((CAP, D), jnp.float32, sharding=dev),
+            jax.ShapeDtypeStruct((B,), jnp.int32, sharding=dev),
+            jax.ShapeDtypeStruct((B, D), jnp.float32, sharding=dev)).compile()
+        mem = c.memory_analysis()
+        text = c.as_text()
+        with open(os.path.join(out_dir, f"wb_{form}_{size}.hlo"), "w") as f:
+            f.write(text)
+        print(f"{form:12s}{size:14s}temp {mem.temp_size_in_bytes / 2**30:5.2f}"
+              f" GiB  alias {mem.alias_size_in_bytes / 2**30:5.2f} GiB  "
+              f"whole-field copies "
+              f"{len(re.findall(rf'= f32.{CAP},{D}.[^ ]* copy', text))}",
+              flush=True)
+
+
+def _reduce(trace_dir):
+    """``(ms a run, {op: ms a run})`` of the one program the capture
+    holds ``RUNS`` executions of, from the first device plane."""
+    from swiftmpi_tpu.obs.profiler import _line_events, self_times
+    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    data = jax.profiler.ProfileData.from_file(path)
+    plane = next(p for p in data.planes if p.name.startswith("/device:TPU:0"))
+    lines = {ln.name: _line_events(ln) for ln in plane.lines}
+    runs = lines["XLA Modules"]
+    assert len(runs) == RUNS, [r[2] for r in runs]
+    ops = {}
+    for text, ns, _ in self_times(lines["XLA Ops"]):
+        op = text.split(" = ")[0].lstrip("%")
+        ops[op] = ops.get(op, 0.0) + ns / 1e6 / RUNS
+    ops = {k: round(v, 4) for k, v in sorted(
+        ops.items(), key=lambda kv: -kv[1]) if v > 0.02}
+    return sum(e - s for s, e, _ in runs) / 1e6 / RUNS, ops
+
+
+def measure(only=None):
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(f"needs the chip, found {dev.platform}")
+    init = jax.jit(lambda k: jax.random.normal(k, (CAP, D), jnp.float32))
+
+    @jax.jit
+    def digest(x, rep):
+        rows = jnp.take(x, jnp.where(rep < CAP, rep, 0), axis=0)
+        return jnp.sum(rows), jnp.sum(x)
+
+    rng = np.random.default_rng(0)
+    inputs = {}
+    for size, (n, B) in SIZES.items():
+        rep = np.full((B,), CAP, np.int32)
+        rep[:n] = np.sort(rng.choice(CAP, n, replace=False))
+        inputs[size] = (jnp.asarray(rep), jax.random.normal(
+            jax.random.key(1), (B, D), jnp.float32))
+    result = {"device": dev.device_kind, "capacity": CAP, "width": D,
+              "sizes": SIZES, "runs": RUNS, "chunk": CHUNK, "cases": {}}
+    trace_dir = os.path.join("chiprun_out", "writeback_trace")
+    digests = {}
+    for form, size in _cases():
+        if only is not None and form not in only:
+            continue
+        fn = _jitted(form, size)
+        rep, g = inputs[size]
+        x = init(jax.random.key(0))
+        out = fn(x, rep, g)                 # compiles; the digest's run
+        keep = form != "gather_only"
+        if keep:
+            digests[form, size] = [float(v) for v in digest(out, rep)]
+            x = out
+        jax.block_until_ready((x, out))
+        # a capture of its own: programs that compile to one executable
+        # share a name in a common capture
+        jax.profiler.start_trace(trace_dir)
+        for _ in range(RUNS):
+            out = fn(x, rep, g)
+            if keep:
+                x = out
+        jax.block_until_ready((x, out))
+        jax.profiler.stop_trace()
+        ms, ops = _reduce(trace_dir)
+        shutil.rmtree(trace_dir)            # too big to bring back
+        del x, out
+        same = (digests[form, size] == digests["a", size]) if keep else None
+        result["cases"][f"{form}.{size}"] = {
+            "ms_per_run": ms, "ops": ops, "same_as_a": same}
+        top = ", ".join(f"{k} {v:.3f}" for k, v in list(ops.items())[:6])
+        print(f"{form:12s}{size:14s}{ms:9.3f} ms a run  same_as_a={same}  "
+              f"[{top}]", flush=True)
+    with open(os.path.join("chiprun_out", "writeback_micro.json"), "w") as f:
+        json.dump(result, f, indent=1)
+
+
+if __name__ == "__main__":
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--compile-only", metavar="DIR", default=None)
+    ap.add_argument("--only", nargs="*", default=None)
+    args = ap.parse_args()
+    if args.compile_only:
+        compile_only(args.compile_only)
+    else:
+        os.makedirs("chiprun_out", exist_ok=True)
+        measure(args.only)

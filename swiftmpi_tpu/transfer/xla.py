@@ -33,6 +33,19 @@ from swiftmpi_tpu.transfer.api import (Transfer, bump_row_versions,
 # measured-win gate can never OOM a large table's push
 _REPLICA_BUDGET_BYTES = 256 << 20
 
+# Row write-back of the sparse push (`_set_rows`): XLA's TPU scatter has
+# two costs, and the `indices_are_sorted` hint alone picks between them
+# (v5e micro A/B on one donated f32[2340001, 300] field, PERF.md section
+# 6, PR 30; `unique_indices`, `mode`, in-bounds padding and an
+# optimization barrier around the values change nothing).  With the hint
+# the scatter streams the WHOLE field through the chip, 11.2 ms there
+# (3.97 ps a byte of field) plus ~7 ns a row; without it the rows are
+# written one by one, 96 ns a row, dropped padding included, and nothing
+# is fixed.  One row written alone therefore costs what sweeping this
+# many bytes of field costs, and the cheaper form follows from the
+# shapes: 117,000 rows are the crossover on that field.
+_ROW_WRITE_AS_SWEPT_BYTES = 24_000
+
 
 def _replica_R(capacity: int, width: int) -> int:
     """Recorded replica factor for this device kind, bounded by the
@@ -60,18 +73,43 @@ def _masked_gather(arr: jax.Array, slots: jax.Array,
     return jnp.where(valid[:, None], rows, 0)
 
 
+def _set_rows(field: jax.Array, rows: jax.Array, values: jax.Array,
+              sweep: bool) -> jax.Array:
+    """``field`` with ``values[i]`` at row ``rows[i]``: distinct in-range
+    rows, and ``capacity`` padding, which drops.  ``sweep`` (ascending
+    ``rows`` only) is `XlaTransfer.write_back_form`'s choice of cost; the
+    rows and values written are the same."""
+    return field.at[rows].set(values, mode="drop", unique_indices=True,
+                              indices_are_sorted=sweep)
+
+
+def _after(x, done):
+    """``x``, not to be touched before ``done`` exists (``None``: no
+    wait): orders two uses of whole fields that share no data."""
+    return x if done is None else jax.lax.optimization_barrier((done, x))[1]
+
+
 class XlaTransfer(Transfer):
     name = "xla"
 
-    def __init__(self, dense_apply: bool | None = None):
+    def __init__(self, dense_apply: bool | None = None, shards: int = 1):
         """``dense_apply``: True forces the dense full-table push, False
         forces the sort-based sparse push, None (default) picks per call —
         dense when the push batch is at least half the table capacity.
         At that point the sparse path's sort + per-row gather/scatter
         irregularity costs more than sweeping the table once (the
         crossover is measured in docs/ARCHITECTURE.md; word2vec-scale
-        batches over demo-conf-scale tables land far on the dense side)."""
+        batches over demo-conf-scale tables land far on the dense side).
+
+        ``shards``: how many devices the table's rows are split over
+        (``Cluster`` passes its server count).  A row-sharded scatter
+        runs on every shard, with the whole batch against ``capacity /
+        shards`` rows, so `write_back_form` weighs a shard's rows."""
         self.dense_apply = dense_apply
+        self.shards = max(1, int(shards))
+        #: field -> ``"per_row"`` | ``"sweep"``: the form the write-back
+        #: of that field's last traced sparse push took
+        self.resolved_write_back: dict = {}
         # wire ledger (api.py): XLA chooses the actual collectives, so
         # wire_bytes counts the representation-level payload — sparse:
         # valid rows x (index + grad row); dense: capacity x grad row
@@ -246,11 +284,9 @@ class XlaTransfer(Transfer):
             for f in updated:
                 # owner rows hold distinct slots by construction (one
                 # owner per table row); non-owners route OOB and drop.
-                # The span is position-ordered, not slot-ordered, so no
-                # indices_are_sorted hint — uniqueness alone removes the
-                # scatter's collision machinery.
-                out[f] = state[f].at[tgt].set(
-                    updated[f], mode="drop", unique_indices=True)
+                # The span is position-ordered, not slot-ordered: no
+                # sweep, which needs ascending rows.
+                out[f] = _set_rows(state[f], tgt, updated[f], sweep=False)
             return bump_row_versions(out, state, tgt)
 
     # -- window-coalesced push ---------------------------------------------
@@ -307,25 +343,56 @@ class XlaTransfer(Transfer):
                                           indices_are_sorted=True)
                 combined[f] = acc * inv if mean else acc
 
+        # only the fields this push's grad families actually update are
+        # gathered and re-scattered (a partial push must not round-trip
+        # the untouched fields' rows through HBM for nothing)
+        touched = access.touched_fields(grads)
+        form = self.write_back_form(B, [state[f] for f in touched])
+        self.resolved_write_back.update(dict.fromkeys(touched, form))
+        # Unused segments' representatives stay == capacity: OOB, dropped.
+        # rep_slots are ascending AND one-per-segment by construction
+        # (duplicates exist only among the dropped capacity-fill tail), so
+        # either form of the write-back may take them.
+        out = dict(state)
+        if form == "sweep":
+            with obs.named_scope("apply"):
+                current = {f: jnp.take(state[f], safe_rep, axis=0)
+                           for f in touched}
+                updated = access.apply_push(current, combined)
+                for f in updated:
+                    out[f] = _set_rows(state[f], rep_slots, updated[f],
+                                       sweep=True)
+                return bump_row_versions(out, state, rep_slots)
+        # Per row.  XLA reads and writes a field's rows in a row-major
+        # copy of the whole field and, unlike the sweep, does not fuse the
+        # read into the write.  One field after the other then, so that
+        # no two such copies need be alive at once (left to itself the
+        # sg2m-b2k step does not fit the chip): the reads first, the last
+        # field read is written from the same copy, then the others in
+        # turn.  `_after` stands outside the scope: the layout copies it
+        # orders are not apply's work and stay under no phase.
+        src, current, done = {}, {}, None
+        for f in touched:
+            src[f] = _after(state[f], done)
+            with obs.named_scope("apply"):
+                current[f] = done = jnp.take(src[f], safe_rep, axis=0)
         with obs.named_scope("apply"):
-            # only the fields this push's grad families actually update
-            # are gathered and re-scattered (a partial push must not
-            # round-trip the untouched fields' rows through HBM for
-            # nothing)
-            touched = access.touched_fields(grads)
-            current = {f: jnp.take(state[f], safe_rep, axis=0)
-                       for f in touched}
             updated = access.apply_push(current, combined)
-
-            out = dict(state)
-            for f in updated:
-                # Unused segments' representatives stay == capacity: OOB,
-                # dropped.  rep_slots are ascending AND one-per-segment by
-                # construction (duplicates exist only among the dropped
-                # capacity-fill tail), so the scatter-set needs no
-                # collision handling — the hints cut the large-capacity
-                # scatter cost (the 1M-vocab step's measured bound).
-                out[f] = state[f].at[rep_slots].set(
-                    updated[f], mode="drop", indices_are_sorted=True,
-                    unique_indices=True)
+        for f in reversed(touched):
+            if f not in updated:
+                continue
+            field = src[f] if f == touched[-1] else _after(state[f], done)
+            with obs.named_scope("apply"):
+                out[f] = done = _set_rows(field, rep_slots, updated[f],
+                                          sweep=False)
+        with obs.named_scope("apply"):
             return bump_row_versions(out, state, rep_slots)
+
+    def write_back_form(self, n: int, fields) -> str:
+        """``"per_row"`` or ``"sweep"``: the cheaper way to write ``n``
+        ascending rows back into each of ``fields``, from static shapes
+        alone (`_ROW_WRITE_AS_SWEPT_BYTES` has the measurement)."""
+        swept = sum((f.shape[0] // self.shards) * f.shape[1]
+                    * f.dtype.itemsize for f in fields)
+        return ("per_row" if n * len(fields) * _ROW_WRITE_AS_SWEPT_BYTES
+                <= swept else "sweep")

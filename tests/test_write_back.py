@@ -1,0 +1,152 @@
+"""The sparse push's row write-back (``transfer/xla.py``): both forms
+write the ``local`` oracle's rows, bit for bit.
+
+``XlaTransfer.write_back_form`` chooses, from static shapes, between
+writing a push's rows one by one (``per_row``) and one sweep of the whole
+field (``sweep``): a choice of device time (PERF.md section 6, PR 30),
+never of values.  Every case here runs the push in BOTH forms, twice:
+op by op, where the access rule's arithmetic is the oracle's own sequence
+of primitives and every field must equal the numpy oracle's bit for bit
+(``assert_array_equal``); and under ``jit``, as a train step runs it,
+where the two forms must equal each other bit for bit and stay within
+one rounding of the oracle (XLA fuses the rule's arithmetic there).  The
+gradients are multiples of 1/64 and every slot repeats 1, 2 or 4 times,
+so the oracle's ``sum / count`` and the backend's ``sum * (1 / count)``
+are the same float.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from swiftmpi_tpu.cluster import SHARD_AXIS, ps_mesh
+from swiftmpi_tpu.parameter import KeyIndex, SparseTable, lr_access, w2v_access
+from swiftmpi_tpu.transfer.local import LocalTransfer
+from swiftmpi_tpu.transfer.xla import XlaTransfer
+
+FORMS = ("per_row", "sweep")
+SHARDS = 4
+CAP_PER_SHARD = 24
+
+
+def _batch(case, table, width, seed):
+    """(slots, grads) of one push family over ``table``'s rows."""
+    rng = np.random.default_rng(seed)
+    rows = rng.permutation(table.capacity)[:12].astype(np.int32)
+    if case == "duplicates":          # each row 1, 2 or 4 times, shuffled
+        slots = np.concatenate([rows[:4], np.repeat(rows[4:8], 2),
+                                np.repeat(rows[8:], 4)])
+    elif case == "padding_mixed":     # the same, padding interleaved
+        slots = np.concatenate([rows[:4], np.repeat(rows[4:8], 2),
+                                np.repeat(rows[8:], 4)])
+        slots = np.insert(slots, np.arange(0, len(slots), 2), -1)
+    elif case == "all_padding":
+        slots = np.full(16, -1, np.int32)
+    else:
+        assert case == "empty"
+        slots = np.zeros(0, np.int32)
+    slots = rng.permutation(slots).astype(np.int32)
+    grad = (rng.integers(-64, 65, size=(len(slots), width)) / 64.0
+            ).astype(np.float32)
+    return slots, grad
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one_device", "row_sharded_x4"])
+@pytest.mark.parametrize("mean", [False, True], ids=["sum", "mean"])
+@pytest.mark.parametrize("width", [1, 300])
+@pytest.mark.parametrize("case", ["duplicates", "padding_mixed",
+                                  "all_padding", "empty"])
+def test_write_back_forms_match_oracle_bitwise(case, width, mean, sharded,
+                                               monkeypatch):
+    if sharded and len(jax.devices()) < SHARDS:
+        pytest.skip(f"needs {SHARDS} virtual devices")
+    mesh = ps_mesh(n=SHARDS) if sharded else None
+    # d = 1: the logistic table; 300 wide: word2vec's, pushing the ``h``
+    # family alone, so ``v`` / ``v2sum`` must come back untouched
+    access = lr_access(0.3) if width == 1 else w2v_access(0.3, width)
+    family = "val" if width == 1 else "h"
+    ki = KeyIndex(num_shards=SHARDS if sharded else 1,
+                  capacity_per_shard=CAP_PER_SHARD)
+    table = SparseTable(access, ki, mesh=mesh,
+                        axis=SHARD_AXIS if sharded else "model")
+    slots, grad = _batch(case, table, width, seed=width + 7 * mean)
+    state_np = {f: np.asarray(v) for f, v in table.state.items()}
+    want = LocalTransfer().push(state_np, slots, {family: grad}, access,
+                                mean=mean)
+
+    def push(backend, state, slots, grad):
+        return backend.push(state, slots, {family: grad}, access, mean=mean)
+
+    eager, jitted = {}, {}
+    for form in FORMS:
+        backend = XlaTransfer(dense_apply=False,
+                              shards=SHARDS if sharded else 1)
+        monkeypatch.setattr(backend, "write_back_form",
+                            lambda n, fields, form=form: form)
+        out = push(backend, table.state, jnp.asarray(slots),
+                   jnp.asarray(grad))
+        eager[form] = {f: np.asarray(v) for f, v in out.items()}
+        if len(slots):
+            touched = access.touched_fields([family])
+            assert backend.resolved_write_back == dict.fromkeys(touched, form)
+        out = jax.jit(push, static_argnums=0)(
+            backend, table.state, jnp.asarray(slots), jnp.asarray(grad))
+        if sharded:
+            assert out[family].sharding.is_equivalent_to(
+                table.state[family].sharding, 2)
+        jitted[form] = {f: np.asarray(v) for f, v in out.items()}
+        for f in access.fields:
+            np.testing.assert_array_equal(want[f], eager[form][f],
+                                          err_msg=f"{form}:{f}")
+            np.testing.assert_allclose(want[f], jitted[form][f], rtol=1e-6,
+                                       atol=1e-7, err_msg=f"jit {form}:{f}")
+    for f in access.fields:
+        np.testing.assert_array_equal(jitted["per_row"][f],
+                                      jitted["sweep"][f], err_msg=f)
+    if case in ("all_padding", "empty"):
+        for f in access.fields:
+            np.testing.assert_array_equal(state_np[f], jitted["sweep"][f])
+
+
+# rows of a push, the field it writes, the table's shards -> the form.
+# The first five are the benchmark cells' own pushes (PERF.md section 4).
+@pytest.mark.parametrize("n, rows, width, shards, form", [
+    (5_000, 2_340_001, 300, 1, "per_row"),       # cbow2m-demo, contexts
+    (5_500, 2_340_001, 300, 1, "per_row"),       # cbow2m-demo, targets
+    (20_480, 2_340_001, 300, 1, "per_row"),      # sg2m-b2k, inputs
+    (122_880, 2_340_001, 300, 1, "sweep"),       # sg2m-b2k, targets
+    (163_840, 2_340_001, 300, 1, "sweep"),       # cbow2m-b16k, contexts
+    (655_360, 3_900_004, 300, 4, "sweep"),       # gnews3m-x4-b64k
+    (100_000, 3_900_004, 300, 1, "per_row"),     # the same rows, unsharded
+    (100_000, 3_900_004, 300, 4, "sweep"),       # ... a shard is a quarter
+    (1_000, 1 << 20, 1, 1, "sweep"),             # d = 1: a cheap sweep
+    (100, 1 << 20, 1, 1, "per_row"),
+])
+def test_write_back_form_follows_the_shapes(n, rows, width, shards, form):
+    fields = [jax.ShapeDtypeStruct((rows, width), jnp.float32)] * 2
+    assert XlaTransfer(shards=shards).write_back_form(n, fields) == form
+
+
+def test_span_push_writes_row_by_row(monkeypatch):
+    """``push_span``'s owner rows are position-ordered, never ascending:
+    it shares the helper and always takes the per-row form."""
+    from swiftmpi_tpu.transfer import xla
+    seen = []
+    real = xla._set_rows
+    monkeypatch.setattr(xla, "_set_rows", lambda field, rows, values, sweep:
+                        seen.append(sweep) or real(field, rows, values,
+                                                   sweep))
+    access = lr_access(0.3)
+    table = SparseTable(access, KeyIndex(num_shards=1,
+                                         capacity_per_shard=CAP_PER_SHARD))
+    slots = np.array([5, 3, 5, -1, 9, 3], np.int32)
+    grads = {"val": np.arange(6, dtype=np.float32)[:, None] / 8}
+    counts = np.ones(6, np.float32)
+    state_np = {f: np.asarray(v) for f, v in table.state.items()}
+    want = LocalTransfer().push_span(state_np, slots, grads, counts, access)
+    got = XlaTransfer().push_span(table.state, slots, grads, counts, access)
+    assert seen == [False, False]     # val, grad2sum
+    for f in access.fields:
+        np.testing.assert_array_equal(want[f], np.asarray(got[f]), err_msg=f)
