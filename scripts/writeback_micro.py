@@ -3,10 +3,14 @@
 ``transfer/xla.py::_push_sparse`` gathers the current rows of a field at
 the batch's representative slots, applies the access method and writes the
 rows back.  This script times that read-modify-write alone, on one donated
-``f32[2340001, 300]`` field (``cbow2m-demo``'s table, default layout), for
-each candidate form of the write-back and the issue's two batch sizes (plus
-three half-padded ones for the forms that were candidates), and reads the
-times from a device trace (ISSUE 30; the table is in PERF.md section 6).
+``f32[2340001, 300]`` field (``cbow2m-demo``'s table), for each candidate
+form of the write-back and the issue's two batch sizes (plus three
+half-padded ones for the forms that were candidates), and reads the times
+from a device trace (ISSUE 30; the table is in PERF.md section 6).  The
+field is stored as the table stores it, in the compiler's column-major
+default; ``--layout row_major`` pins it row-major in and out of every
+program instead (PR 32's reading: what the write-back costs once no step
+copies a whole field, ROADMAP S1 / D0 / D10).
 
     python scripts/writeback_micro.py                # on the chip
     JAX_PLATFORMS=cpu python scripts/writeback_micro.py --compile-only DIR
@@ -37,10 +41,16 @@ CAP, D = 2340001, 300
 #: push's ``rep_slots`` has after its dedup
 SIZES = {"5k": (5000, 5500), "100k": (100000, 110000),
          "2.7k_of_5.5k": (2750, 5500), "50k_of_110k": (50000, 110000),
-         "80k_of_180k": (80000, 180224)}
+         "80k_of_180k": (80000, 180224),
+         # the cells' other pushes at their 56.5 % fill (PR 32)
+         "12k_of_20k": (11600, 20480), "70k_of_123k": (69500, 122880),
+         "93k_of_164k": (92600, 163840)}
 ISSUE_SIZES = ("5k", "100k")
 RUNS = 4
 CHUNK = 2048
+#: ``layout.Format`` of the field in and out of every program, or ``None``
+#: for the compiler's default (set from ``--layout``)
+FIELD_FORMAT = None
 
 
 def _rmw(x, rep):
@@ -168,8 +178,20 @@ def _jitted(form, size):
     def wb(x, rep, g):
         return fn(x, rep, g)
     wb.__name__ = f"wb_{form}_{size}"
-    donate = () if form == "gather_only" else (0,)
-    return jax.jit(wb, donate_argnums=donate)
+    if form == "gather_only":
+        return jax.jit(wb)
+    return jax.jit(wb, donate_argnums=0, out_shardings=FIELD_FORMAT)
+
+
+def _set_layout(layout, sharding):
+    global FIELD_FORMAT
+    if layout == "row_major":
+        from jax.experimental.layout import Format, Layout
+        FIELD_FORMAT = Format(Layout(major_to_minor=(0, 1)), sharding)
+        # an executable read back from the persistent cache hands out
+        # arrays that claim the default layout (jaxlib 0.9.0;
+        # scripts/layout_cache_probe.py): compile everything here
+        jax.config.update("jax_enable_compilation_cache", False)
 
 
 def _cases():
@@ -182,17 +204,19 @@ def _cases():
             yield form, size
 
 
-def compile_only(out_dir):
+def compile_only(out_dir, layout):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     dev = SingleDeviceSharding(topo.devices[0])
+    _set_layout(layout, dev)
     os.makedirs(out_dir, exist_ok=True)
     for form, size in _cases():
         B = SIZES[size][1]
         c = _jitted(form, size).lower(
-            jax.ShapeDtypeStruct((CAP, D), jnp.float32, sharding=dev),
+            jax.ShapeDtypeStruct((CAP, D), jnp.float32,
+                                 sharding=FIELD_FORMAT or dev),
             jax.ShapeDtypeStruct((B,), jnp.int32, sharding=dev),
             jax.ShapeDtypeStruct((B, D), jnp.float32, sharding=dev)).compile()
         mem = c.memory_analysis()
@@ -226,11 +250,13 @@ def _reduce(trace_dir):
     return sum(e - s for s, e, _ in runs) / 1e6 / RUNS, ops
 
 
-def measure(only=None):
+def measure(only, layout):
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         raise SystemExit(f"needs the chip, found {dev.platform}")
-    init = jax.jit(lambda k: jax.random.normal(k, (CAP, D), jnp.float32))
+    _set_layout(layout, jax.sharding.SingleDeviceSharding(dev))
+    init = jax.jit(lambda k: jax.random.normal(k, (CAP, D), jnp.float32),
+                   out_shardings=FIELD_FORMAT)
 
     @jax.jit
     def digest(x, rep):
@@ -244,7 +270,8 @@ def measure(only=None):
         rep[:n] = np.sort(rng.choice(CAP, n, replace=False))
         inputs[size] = (jnp.asarray(rep), jax.random.normal(
             jax.random.key(1), (B, D), jnp.float32))
-    result = {"device": dev.device_kind, "capacity": CAP, "width": D,
+    result = {"device": dev.device_kind, "layout": layout,
+              "capacity": CAP, "width": D,
               "sizes": SIZES, "runs": RUNS, "chunk": CHUNK, "cases": {}}
     trace_dir = os.path.join("chiprun_out", "writeback_trace")
     digests = {}
@@ -286,9 +313,13 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--compile-only", metavar="DIR", default=None)
     ap.add_argument("--only", nargs="*", default=None)
+    ap.add_argument("--layout", choices=("row_major", "default"),
+                    default="default",
+                    help="how the field is stored (default: as the table "
+                         "stores it, the compiler's choice)")
     args = ap.parse_args()
     if args.compile_only:
-        compile_only(args.compile_only)
+        compile_only(args.compile_only, args.layout)
     else:
         os.makedirs("chiprun_out", exist_ok=True)
-        measure(args.only)
+        measure(args.only, args.layout)

@@ -128,8 +128,8 @@ def load_table_text(table: SparseTable, path: str,
     if parser is None:
         from swiftmpi_tpu.data import native
         if native.available():
-            dims = [int(np.prod(
-                np.atleast_1d(table.access.fields[f].dim))) for f in fields]
+            # the text rows are the logical width, not the stored one
+            dims = [table.access.fields[f].logical for f in fields]
             key_arr, arrs = native.load_rows_native(path, dims)
             if not len(key_arr):
                 return 0
@@ -194,13 +194,16 @@ def _scatter_unified(table: SparseTable, state: dict, fname: str,
     is collective — every process reaches this line)."""
     n_hot = table.n_hot
     tail_sel = idx >= n_hot
+    # text rows carry the logical width; the stored row may be wider
+    # (access.stored_width) and its remaining lanes stay zero
+    width = block.shape[1]
     arr = host_array(state[fname]).copy()
-    arr[idx[tail_sel] - n_hot] = block[tail_sel]
+    arr[idx[tail_sel] - n_hot, :width] = block[tail_sel]
     state[fname] = _replace(table, fname, arr)
     if n_hot and not tail_sel.all():
         hn = hot_name(fname)
         harr = host_array(state[hn]).copy()
-        harr[idx[~tail_sel]] = block[~tail_sel]
+        harr[idx[~tail_sel], :width] = block[~tail_sel]
         state[hn] = _replace(table, hn, harr)
 
 
@@ -535,6 +538,10 @@ def _load_checkpoint(table: SparseTable, path: str,
                 # bf16 fields were saved upcast to fp32 (npz has no
                 # bfloat16); restore the table's storage dtype exactly
                 arr = arr.astype(fs.dtype)
+            if arr.shape[1] < fs.dim:
+                # written before rows were stored wider than they are
+                # (access.stored_width): the new lanes are zero
+                arr = np.pad(arr, ((0, 0), (0, fs.dim - arr.shape[1])))
             state[name] = _replace(table, name, arr)
         had_rowver = ROWVER_KEY in table.state
         table.state = state

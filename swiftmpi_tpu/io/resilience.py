@@ -74,7 +74,8 @@ def load_checkpoint_elastic(table: SparseTable, path: str,
             # host_array, not np.asarray: state may be a non-fully-
             # addressable global array in multi-process runs
             arr = host_array(state[name]).copy()
-            arr[new_slots] = z[f"field__{name}"][old_slots]
+            saved = z[f"field__{name}"]       # may predate the wider
+            arr[new_slots, :saved.shape[1]] = saved[old_slots]  # stored row
             state[name] = _replace(table, name, arr)
         table.state = state
         log.info("elastic restore: %d rows re-keyed from %d-shard "

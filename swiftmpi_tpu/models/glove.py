@@ -40,8 +40,8 @@ from swiftmpi_tpu.cluster.cluster import Cluster
 from swiftmpi_tpu.data.text import Vocab, build_vocab
 from swiftmpi_tpu.io.checkpoint import dump_table_text
 from swiftmpi_tpu.parameter.access import (AdaGradAccess, AdaGradRule,
-                                           FieldSpec, vec_rand_init,
-                                           zeros_init)
+                                           FieldSpec, row_field,
+                                           vec_rand_init, zeros_init)
 from swiftmpi_tpu.utils.config import ConfigParser, global_config
 from swiftmpi_tpu.utils.logger import get_logger
 
@@ -58,12 +58,12 @@ def glove_access(learning_rate: float, len_vec: int) -> AdaGradAccess:
                AdaGradRule("wt", "wt2sum", "wt"),
                AdaGradRule("b", "b2sum", "b"),
                AdaGradRule("bt", "bt2sum", "bt")),
-        fields={"w": FieldSpec(len_vec, vec_rand_init),
-                "wt": FieldSpec(len_vec, vec_rand_init),
+        fields={"w": row_field(len_vec, vec_rand_init),
+                "wt": row_field(len_vec, vec_rand_init),
                 "b": FieldSpec(1, zeros_init),
                 "bt": FieldSpec(1, zeros_init),
-                "w2sum": FieldSpec(len_vec, zeros_init),
-                "wt2sum": FieldSpec(len_vec, zeros_init),
+                "w2sum": row_field(len_vec),
+                "wt2sum": row_field(len_vec),
                 "b2sum": FieldSpec(1, zeros_init),
                 "bt2sum": FieldSpec(1, zeros_init)},
         pull_fields=("w", "wt", "b", "bt"),
@@ -380,8 +380,8 @@ class GloVe:
         if self.vocab is None:
             raise RuntimeError("build() first")
         slots = np.asarray(self._slot_of_vocab)
-        return (np.asarray(self.table.state["w"])[slots]
-                + np.asarray(self.table.state["wt"])[slots])
+        return (self.table.unified_rows_host("w")[slots]
+                + self.table.unified_rows_host("wt")[slots])
 
     def embedding_index(self):
         """Cosine index over the standard w + wt embedding sum."""

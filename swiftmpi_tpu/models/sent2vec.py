@@ -82,7 +82,10 @@ class Sent2Vec:
             """word_slots: (S, L) table slots; vocab_of_pos: (S, L) vocab
             ids (for neg==center masking); returns (S, d) sentence vecs."""
             S, L = word_slots.shape
-            V_all = jnp.take(v_table, jnp.maximum(word_slots, 0), axis=0)
+            # table rows may be stored wider than the vector
+            # (access.stored_width): the sentence vector is d wide
+            V_all = jnp.take(v_table, jnp.maximum(word_slots, 0),
+                             axis=0)[..., :d]
             V_all = V_all * word_mask[..., None]            # (S, L, d)
             k_init, key = jax.random.split(key)
             # Vec::random init, (U(0,1)-0.5)/len  (vec1.h:229-232)
@@ -112,7 +115,7 @@ class Sent2Vec:
                     t_slots = jnp.concatenate(
                         [center_slot[:, None], neg_slots[:, p, :]], axis=1)
                     h_t = jnp.take(h_table, jnp.maximum(t_slots, 0),
-                                   axis=0)                   # (S, K+1, d)
+                                   axis=0)[..., :d]          # (S, K+1, d)
                     f = jnp.einsum("sd,skd->sk", neu1, h_t)
                     labels = jnp.concatenate(
                         [jnp.ones((S, 1)), jnp.zeros((S, K))], axis=1)

@@ -433,6 +433,10 @@ class Word2Vec:
         self.cluster = cluster or Cluster(self.config).initialize()
         self.access = w2v_access(server_lr, self.len_vec,
                                  param_dtype=self.param_dtype)
+        #: lanes of a stored row (`access.stored_width`): what the step
+        #: pulls, multiplies and pushes; the first `len_vec` are the
+        #: vector, the rest stay zero
+        self.row_width = self.access.fields["h"].dim
         self._capacity_per_shard = capacity_per_shard
         self.table = None
         self.transfer = self.cluster.transfer
@@ -1029,7 +1033,7 @@ class Word2Vec:
         transfer = self.transfer
         K = self.negative
         alpha = self.alpha
-        d = self.len_vec
+        d = self.row_width
 
         def grads_fn(state, slot_of_vocab, alias_prob, alias_idx,
                      centers, contexts, ctx_mask, key):
@@ -1111,7 +1115,7 @@ class Word2Vec:
         transfer = self.transfer
         K = self.negative
         alpha = self.alpha
-        d = self.len_vec
+        d = self.row_width
 
         def grads_fn(state, slot_of_vocab, alias_prob, alias_idx,
                      centers, contexts, ctx_mask, key):
@@ -1190,7 +1194,7 @@ class Word2Vec:
         transfer = self.transfer
         K = self.shared_pool
         alpha = self.alpha
-        d = self.len_vec
+        d = self.row_width
 
         def grads_fn(state, slot_of_vocab, alias_prob, alias_idx,
                      centers, contexts, ctx_mask, key):
@@ -1308,7 +1312,7 @@ class Word2Vec:
         transfer = self.transfer
         W = self.window
         alpha = self.alpha
-        d = self.len_vec
+        d = self.row_width
         K = self.shared_pool if shared else self.negative
 
         offsets = jnp.concatenate(
@@ -1441,7 +1445,7 @@ class Word2Vec:
         transfer = self.transfer
         K = self.negative
         alpha = self.alpha
-        d = self.len_vec
+        d = self.row_width
 
         def grads_fn(state, slot_of_vocab, alias_prob, alias_idx,
                      centers, contexts, ctx_mask, key):
@@ -1523,7 +1527,7 @@ class Word2Vec:
         transfer = self.transfer
         K = self.shared_pool
         alpha = self.alpha
-        d = self.len_vec
+        d = self.row_width
 
         def grads_fn(state, slot_of_vocab, alias_prob, alias_idx,
                      centers, contexts, ctx_mask, key):
@@ -2256,9 +2260,10 @@ class Word2Vec:
         n_hot = self.table.n_hot
         if slot < n_hot:            # replicated hot head (hybrid)
             from swiftmpi_tpu.parameter.sparse_table import hot_name
-            return np.asarray(self.table.state[hot_name("v")][slot])
-        return np.asarray(
-            self.table.state["v"][slot - n_hot])  # one-row transfer
+            row = self.table.state[hot_name("v")][slot]
+        else:
+            row = self.table.state["v"][slot - n_hot]  # one-row transfer
+        return np.asarray(row)[:self.len_vec]
 
     def serving_publisher(self):
         """The model's :class:`~swiftmpi_tpu.serve.snapshot
@@ -2507,7 +2512,7 @@ class Word2Vec:
         from swiftmpi_tpu.parameter.key_index import window_wire_format
         new = expected_unique_rows(
             counts, self.push_window_size * self.minibatch)
-        d = self.len_vec
+        d = self.row_width
         row_bytes = 4 + 4 * d + 4          # i32 index + f32 row + counts
         qrb = 4 + (d + 4 if self.wire_quant == "int8" else 2 * d) + 4 \
             if self.wire_quant != "off" else None

@@ -5,7 +5,8 @@ TPU-native equivalent of `/root/reference/src/parameter/` (SURVEY.md §2.4).
 
 from swiftmpi_tpu.parameter.access import (AccessMethod, AdaGradAccess,
                                            AdaGradRule, FieldSpec, SGDAccess,
-                                           lr_access, uniform01_init,
+                                           lr_access, row_field,
+                                           stored_width, uniform01_init,
                                            vec_rand_init, w2v_access,
                                            zeros_init)
 from swiftmpi_tpu.parameter.cache import LocalParamCache
@@ -14,7 +15,7 @@ from swiftmpi_tpu.parameter.sparse_table import SparseTable, TableState
 
 __all__ = [
     "AccessMethod", "AdaGradAccess", "AdaGradRule", "FieldSpec", "SGDAccess",
-    "lr_access", "uniform01_init", "vec_rand_init", "w2v_access",
-    "zeros_init", "LocalParamCache", "CapacityError", "KeyIndex",
+    "lr_access", "row_field", "stored_width", "uniform01_init",
+    "vec_rand_init", "w2v_access", "zeros_init", "LocalParamCache", "CapacityError", "KeyIndex",
     "SparseTable", "TableState",
 ]

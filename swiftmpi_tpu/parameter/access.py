@@ -49,11 +49,61 @@ def vec_rand_init(key: jax.Array, shape: Tuple[int, ...]) -> jax.Array:
     return (jax.random.uniform(key, shape, jnp.float32) - 0.5) / dim
 
 
+#: lanes of a TPU vector register: the minor dimension of a tiled array
+#: occupies a whole number of these
+_LANES = 128
+
+
+def stored_width(width: int) -> int:
+    """Lanes a table keeps for a row of ``width`` values: ``width``
+    rounded up to whole 128-lane tiles where that costs at most a third
+    more memory (300 -> 384, 100 -> 128), else ``width`` itself (a
+    one-wide row would pad 128 x; a multiple of 128 pads nothing).
+
+    Why a row is stored wider than it is.  Every pull gathers rows and
+    every sparse push scatters them, and the TPU's gather / scatter work
+    on a row-major operand.  The compiler's default layout of a tall
+    ``(rows, w)`` array is row-major exactly when ``w`` is a multiple of
+    128; otherwise it is column-major, and every train step copies the
+    WHOLE field into a padded row-major buffer and back (11 copies of
+    2.8 GB, ~108 ms a step at 2,340,001 x 300).  Asking for a row-major
+    layout instead (``jax.experimental.layout``) does not survive JAX's
+    persistent compile cache on jaxlib 0.9.0; a width whose DEFAULT
+    layout is the wanted one does, and occupies the same 384 lanes.
+    PERF.md section 6, PR 32; ``tests/test_compile_v5e.py``."""
+    padded = -(-width // _LANES) * _LANES
+    return padded if 3 * padded <= 4 * width else width
+
+
 @dataclass(frozen=True)
 class FieldSpec:
+    """One table field.  ``dim`` is the STORED row width; ``width`` the
+    number of leading lanes that mean something (default: all of them).
+    The lanes beyond ``width`` are zero when the table is built and stay
+    zero under any rule that maps a zero gradient to a zero update, so
+    rows can be pulled, multiplied and pushed at their stored width."""
     dim: int
     init: Initializer = zeros_init
     dtype: jnp.dtype = jnp.float32
+    width: Optional[int] = None
+
+    @property
+    def logical(self) -> int:
+        return self.dim if self.width is None else self.width
+
+    def draw(self, key: jax.Array, rows: int) -> jax.Array:
+        """``(rows, dim)`` initial values: ``init`` over the logical
+        width (so the draw does not depend on the padding), zeros beyond."""
+        out = self.init(key, (rows, self.logical)).astype(self.dtype)
+        pad = self.dim - self.logical
+        return jnp.pad(out, ((0, 0), (0, pad))) if pad else out
+
+
+def row_field(width: int, init: Initializer = zeros_init,
+              dtype: jnp.dtype = jnp.float32) -> FieldSpec:
+    """A field of ``width``-wide rows, stored as :func:`stored_width`
+    says a row of that width should be."""
+    return FieldSpec(stored_width(width), init, dtype, width)
 
 
 class AccessMethod:
@@ -192,10 +242,10 @@ def w2v_access(learning_rate: float, len_vec: int,
         learning_rate,
         rules=(AdaGradRule("h", "h2sum", "h"),
                AdaGradRule("v", "v2sum", "v")),
-        fields={"h": FieldSpec(len_vec, vec_rand_init, param_dtype),
-                "v": FieldSpec(len_vec, vec_rand_init, param_dtype),
-                "h2sum": FieldSpec(len_vec, zeros_init),
-                "v2sum": FieldSpec(len_vec, zeros_init)},
+        fields={"h": row_field(len_vec, vec_rand_init, param_dtype),
+                "v": row_field(len_vec, vec_rand_init, param_dtype),
+                "h2sum": row_field(len_vec),
+                "v2sum": row_field(len_vec)},
         pull_fields=("h", "v"),
     )
 

@@ -18,7 +18,13 @@ the host to materialize a row.
 
 The table *state* is a plain ``{field: jax.Array}`` dict — a pytree that
 training steps close over, donate, and return updated; the ``SparseTable``
-object is the host-side handle (spec, mesh placement, key index).
+object is the host-side handle (spec, mesh placement, key index).  Each
+array is ``(capacity, FieldSpec.dim)``: the STORED row, which may be wider
+than the field's logical row (``access.stored_width``: a 300-wide row is
+kept on 384 lanes, zeros beyond, so that the compiler's default layout is
+the row-major one and no step copies a whole field).  Rows are pulled,
+multiplied and pushed at their stored width; ``unified_rows_host``, text
+dumps and embedding exports cut them to the logical one.
 
 Window-coalesced updates and the AdaGrad accumulator: with ``[cluster]
 push_window: W`` the transfer layer sums a window's W per-step gradient
@@ -151,6 +157,10 @@ class SparseTable:
                 else self.row_sharding())
 
     def _init_state(self) -> TableState:
+        return self._init_program()(jax.random.key(self.seed))
+
+    def _init_program(self):
+        """The jitted ``key -> state`` that draws every row."""
         cap = self.key_index.capacity
         n_hot = self.n_hot
         fields = self.access.fields
@@ -159,26 +169,24 @@ class SparseTable:
             out = {}
             for name, fs in sorted(fields.items()):
                 key, sub = jax.random.split(key)
-                out[name] = fs.init(sub, (cap, fs.dim)).astype(fs.dtype)
+                out[name] = fs.draw(sub, cap)
             # hot arrays draw from the same stream AFTER the tail fields,
             # so a table with n_hot=0 is bit-identical to the pre-hybrid
             # layout
             for name, fs in sorted(fields.items()):
                 if n_hot:
                     key, sub = jax.random.split(key)
-                    out[hot_name(name)] = fs.init(
-                        sub, (n_hot, fs.dim)).astype(fs.dtype)
+                    out[hot_name(name)] = fs.draw(sub, n_hot)
             return out
 
         sharding = self.row_sharding()
         if sharding is None:
-            return jax.jit(init_all)(jax.random.key(self.seed))
+            return jax.jit(init_all)
         shardings = {name: sharding for name in fields}
         if n_hot:
             rep = self.replicated_sharding()
             shardings.update({hot_name(name): rep for name in fields})
-        return jax.jit(init_all, out_shardings=shardings)(
-            jax.random.key(self.seed))
+        return jax.jit(init_all, out_shardings=shardings)
 
     def ensure_ef(self, grad_fields) -> None:
         """Arm error-feedback residual planes for ``grad_fields``: one
@@ -259,7 +267,7 @@ class SparseTable:
             out = {}
             for name, fs in sorted(fields.items()):
                 key, sub = jax.random.split(key)
-                arr = fs.init(sub, (new_cap, fs.dim)).astype(fs.dtype)
+                arr = fs.draw(sub, new_cap)
                 if len(items):
                     arr = arr.at[new_rows].set(
                         old_state[name][old_rows])
@@ -350,7 +358,7 @@ class SparseTable:
                 if not new_n_hot:
                     continue
                 key, sub = jax.random.split(key)
-                hot = fs.init(sub, (new_n_hot, fs.dim)).astype(fs.dtype)
+                hot = fs.draw(sub, new_n_hot)
                 if p["hot_from_hot_src"].shape[0]:
                     hot = hot.at[p["hot_from_hot_dst"]].set(
                         jnp.take(state[hot_name(name)],
@@ -452,15 +460,19 @@ class SparseTable:
     def unified_rows_host(self, field: str) -> np.ndarray:
         """Host copy of ``field`` indexed by UNIFIED slot: rows
         ``[0, n_hot)`` are the replicated hot head, rows ``[n_hot, ...)``
-        the sharded tail.  This is the view checkpoint text dumps and
-        embedding exports index with KeyIndex slots."""
+        the sharded tail, cut to the field's logical width (the stored
+        row may be wider: `access.stored_width`).  This is the view
+        checkpoint text dumps and embedding exports index with KeyIndex
+        slots."""
         from swiftmpi_tpu.cluster.bootstrap import host_array
 
-        tail = host_array(self.state[field])
+        width = self.access.fields[field].logical
+        tail = host_array(self.state[field])[:, :width]
         if not self.n_hot:
             return tail
         return np.concatenate(
-            [host_array(self.state[hot_name(field)]), tail], axis=0)
+            [host_array(self.state[hot_name(field)])[:, :width], tail],
+            axis=0)
 
     def __repr__(self) -> str:  # pragma: no cover
         return (f"SparseTable(fields={list(self.access.fields)}, "

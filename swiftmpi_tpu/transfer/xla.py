@@ -37,14 +37,18 @@ _REPLICA_BUDGET_BYTES = 256 << 20
 # two costs, and the `indices_are_sorted` hint alone picks between them
 # (v5e micro A/B on one donated f32[2340001, 300] field, PERF.md section
 # 6, PR 30; `unique_indices`, `mode`, in-bounds padding and an
-# optimization barrier around the values change nothing).  With the hint
-# the scatter streams the WHOLE field through the chip, 11.2 ms there
-# (3.97 ps a byte of field) plus ~7 ns a row; without it the rows are
-# written one by one, 96 ns a row, dropped padding included, and nothing
-# is fixed.  One row written alone therefore costs what sweeping this
-# many bytes of field costs, and the cheaper form follows from the
-# shapes: 117,000 rows are the crossover on that field.
-_ROW_WRITE_AS_SWEPT_BYTES = 24_000
+# optimization barrier around the values change nothing; re-taken in PR
+# 32 on the row-major field a 300-wide row is now stored as, 384 lanes
+# and 3.59 GB: `scripts/writeback_micro.py --layout row_major`).  With
+# the hint the scatter streams the WHOLE field through the chip, 11.5 ms
+# there (3.2 ps a byte of stored field) plus ~22 ns a slot; without it
+# the rows are written one by one, ~120 ns a slot, dropped padding
+# included, and nothing is fixed.  One row written alone therefore costs
+# what sweeping this many bytes of field costs, and the cheaper form
+# follows from the shapes: ~116,000 slots are the crossover on that field
+# (measured: 13.98 against 14.90 ms at 122,880 slots, 12.84 against
+# 14.18 at 110,000).
+_ROW_WRITE_AS_SWEPT_BYTES = 31_000
 
 
 def _replica_R(capacity: int, width: int) -> int:
@@ -363,11 +367,14 @@ class XlaTransfer(Transfer):
                     out[f] = _set_rows(state[f], rep_slots, updated[f],
                                        sweep=True)
                 return bump_row_versions(out, state, rep_slots)
-        # Per row.  XLA reads and writes a field's rows in a row-major
-        # copy of the whole field and, unlike the sweep, does not fuse the
-        # read into the write.  One field after the other then, so that
-        # no two such copies need be alive at once (left to itself the
-        # sg2m-b2k step does not fit the chip): the reads first, the last
+        # Per row.  Where a field is column-major in HBM (a tall array
+        # whose stored width is no multiple of 128: `access.stored_width`
+        # widens the rows that can afford it, and then there is nothing
+        # to order) XLA reads and writes its rows in a row-major copy of
+        # the whole field and, unlike the sweep, does not fuse the read
+        # into the write.  One field after the other then, so that no two
+        # such copies need be alive at once (left to itself the 300-wide
+        # sg2m-b2k step did not fit the chip): the reads first, the last
         # field read is written from the same copy, then the others in
         # turn.  `_after` stands outside the scope: the layout copies it
         # orders are not apply's work and stay under no phase.

@@ -55,43 +55,70 @@ def _cell(name):
             load("benchmark", "traffic", cell["traffic"] + ".json"))
 
 
-def test_cbow2m_demo_step_writes_rows_back_in_place(topo, no_compile_cache,
-                                                    tmp_path, monkeypatch):
-    """The ``cbow2m-demo`` train step at 2,340,001 x 300 on one v5e chip:
-    the four fields are updated in place, the push's write-back adds no
-    whole-field temporary and no layout copy to the parent's (PR 29's
-    step: 3.37 GiB of temporaries = one padded row-major field, 11
-    copies), and its ~5,000-row pushes take the per-row form."""
+def _shapes_only(self):
+    """``SparseTable._init_state`` for a chip that is not there: no array
+    can be placed on it, so shapes with the table's own shardings (and,
+    passing none, the compiler's default layout, which is what the table's
+    arrays have on the chip)."""
+    return {n: jax.ShapeDtypeStruct((self.key_index.capacity, fs.dim),
+                                    fs.dtype, sharding=self.field_sharding(n))
+            for n, fs in self.access.fields.items()}
+
+
+def _load_conf(path, config, minibatch):
+    """The conf the benchmark's families write: the configuration's
+    ``[word2vec]`` / ``[server]`` keys and the traffic's minibatch, every
+    mechanism of the program at its default."""
+    from swiftmpi_tpu.utils import global_config, reset_global_config
+
+    path.write_text("\n".join(
+        ["[word2vec]", *(f"{k}: {v}" for k, v in config["word2vec"].items()),
+         "[server]", *(f"{k}: {v}" for k, v in config["server"].items()),
+         "[worker]", f"minibatch: {minibatch}"]) + "\n")
+    reset_global_config()
+    return global_config().load_conf(str(path)).parse()
+
+
+SWEEP, PER_ROW = "sweep", "per_row"
+
+
+@pytest.mark.parametrize("cell, forms, temp_gib", [
+    # ~5,000-row pushes: every field written row by row
+    ("cbow2m-demo", dict(h=PER_ROW, h2sum=PER_ROW, v=PER_ROW,
+                         v2sum=PER_ROW), 0.01),
+    # 180,224 / 163,840 slots: every field swept
+    ("cbow2m-b16k", dict(h=SWEEP, h2sum=SWEEP, v=SWEEP, v2sum=SWEEP), 1.0),
+    # 122,880 target slots swept, 20,480 input slots row by row
+    ("sg2m-b2k", dict(h=SWEEP, h2sum=SWEEP, v=PER_ROW, v2sum=PER_ROW), 0.7),
+])
+def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
+                                  monkeypatch, cell, forms, temp_gib):
+    """A word2vec cell's train step at 2,340,001 rows on one v5e chip, the
+    model built by the calls the benchmark's families make.  The 300-wide
+    rows are stored on 384 lanes (`access.stored_width`), so with no
+    layout asked for the four fields come in and go out row-major
+    (``{1,0:T(8,128)}``, the compiler's default at that width) and
+    aliased, NO whole field is copied (the 300-wide table: 11 copies a
+    step, 3.37 GiB of temporaries = one padded row-major field, ~108 ms),
+    the step fits the chip, and each push takes the write-back form its
+    size asks for."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from swiftmpi_tpu.cluster.cluster import Cluster
     from swiftmpi_tpu.models.word2vec import Word2Vec
     from swiftmpi_tpu.parameter import sparse_table
-    from swiftmpi_tpu.utils import global_config
 
-    config, traffic = _cell("cbow2m-demo")
+    config, traffic = _cell(cell)
     w2v = config["word2vec"]
-    vocab, width, window = (int(config["vocab_size"]), int(w2v["len_vec"]),
-                            int(w2v["window"]))
-    minibatch = int(traffic["minibatch"])
+    vocab, window = int(config["vocab_size"]), int(w2v["window"])
+    minibatch = int(traffic.get("minibatch")
+                    or traffic["centers_per_step"] * 2 * window)
     centers = max(256, minibatch // (2 * window))
-    conf = tmp_path / "cell.conf"
-    conf.write_text("\n".join(
-        ["[word2vec]", *(f"{k}: {v}" for k, v in w2v.items()),
-         "[server]", *(f"{k}: {v}" for k, v in config["server"].items()),
-         "[worker]", f"minibatch: {minibatch}"]) + "\n")
-    global_config().load_conf(str(conf)).parse()
+    conf = _load_conf(tmp_path / "cell.conf", config, minibatch)
 
-    # no array can be placed on a chip that is not there: shapes only
-    def shapes_only(self):
-        return {n: jax.ShapeDtypeStruct((self.key_index.capacity, fs.dim),
-                                        fs.dtype,
-                                        sharding=self.row_sharding())
-                for n, fs in self.access.fields.items()}
-
-    monkeypatch.setattr(sparse_table.SparseTable, "_init_state", shapes_only)
-    cluster = Cluster(global_config(),
-                      devices=list(topo.devices)[:1]).initialize()
+    monkeypatch.setattr(sparse_table.SparseTable, "_init_state",
+                        _shapes_only)
+    cluster = Cluster(conf, devices=list(topo.devices)[:1]).initialize()
     model = Word2Vec(cluster=cluster)
     # Word2Vec.build_from_vocab's capacity rule
     capacity = max(64, int(vocab * 1.3 / cluster.n_servers) + 1)
@@ -111,18 +138,96 @@ def test_cbow2m_demo_step_writes_rows_back_in_place(topo, no_compile_cache,
         jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=rep)).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
 
-    assert (capacity, width) == (2_340_001, 300)
+    assert (capacity, model.len_vec, model.row_width) == (2_340_001, 300, 384)
+    field = rf"f32\[{capacity},384\]"
+    # the module's first line: aliasing and the entry's layouts
+    params, results = re.search(r"entry_computation_layout=\{(.*)",
+                                text).group(1).split(")->(")
+    row_major = field + r"\{1,0:T\(8,128\)\}"
+    assert len(re.findall(row_major, params)) == 4
+    assert len(re.findall(row_major, results)) == 4
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
     assert {(int(o), int(i)) for o, i in re.findall(
         r"\{(\d+)\}: \((\d+), \{\}", aliased)} >= {(i, i) for i in range(4)}
-    assert mem.temp_size_in_bytes <= 3.37 * GIB * 1.05
-    field = rf"f32\[{capacity},{width}\]"
-    assert len(re.findall(rf"= {field}\S* copy\(", text)) <= 11
-    assert cluster.transfer.resolved_write_back == dict.fromkeys(
-        ("h", "h2sum", "v", "v2sum"), "per_row")
+    assert not re.findall(rf"= {field}\S* copy\(", text)
+    assert f"[{capacity},300]" not in text
+    # a field is 3.35 GiB: the temporaries are a fraction of one
+    assert mem.temp_size_in_bytes <= temp_gib * GIB
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total <= 15.75 * GIB, f"{total / GIB:.2f} GiB"
+    assert cluster.transfer.resolved_write_back == forms
     scatters = re.findall(rf"= {field}\S* scatter\(.*apply/scatter", text)
     assert len(scatters) == 4
-    assert not any("indices_are_sorted=true" in s for s in scatters)
+    swept = sum("indices_are_sorted=true" in s for s in scatters)
+    assert swept == list(forms.values()).count(SWEEP)
+
+
+def test_table_is_built_within_its_own_size(topo, no_compile_cache,
+                                            monkeypatch):
+    """The program that draws word2vec's table, compiled for one v5e chip
+    at 2,340,001 rows: the four 300-wide fields come out on 384 lanes,
+    row-major by the compiler's own default, and building them needs next
+    to nothing beside them (13.41 of 15.75 GiB: the draw is laid out and
+    padded through buffers the zero fields will occupy)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from swiftmpi_tpu.cluster.mesh import MODEL_AXIS, MeshSpec, build_mesh
+    from swiftmpi_tpu.parameter import sparse_table, w2v_access
+    from swiftmpi_tpu.parameter.key_index import KeyIndex
+
+    rows = 2_340_001
+    mesh = build_mesh(MeshSpec.from_dict({"data": -1, "model": 1}),
+                      devices=list(topo.devices)[:1])
+    monkeypatch.setattr(sparse_table.SparseTable, "_init_state",
+                        _shapes_only)
+    table = sparse_table.SparseTable(w2v_access(0.7, 300), KeyIndex(1, rows),
+                                     mesh=mesh, axis=MODEL_AXIS)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = table._init_program().lower(jax.ShapeDtypeStruct(
+        key.shape, key.dtype, sharding=NamedSharding(mesh, P()))).compile()
+    results = re.search(r"entry_computation_layout=\{(.*)",
+                        compiled.as_text()).group(1).split(")->(")[1]
+    assert results.count(f"f32[{rows},384]{{1,0:T(8,128)}}") == 4
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 0.1 * GIB
+    assert (mem.output_size_in_bytes + mem.temp_size_in_bytes
+            <= 13.5 * GIB)
+
+
+@pytest.mark.parametrize("width, row_major", [(300, False), (100, False),
+                                              (384, True), (128, True)])
+def test_default_layout_of_a_tall_field(topo, no_compile_cache, width,
+                                        row_major):
+    """What the chip's compiler stores a tall ``(rows, width)`` f32 array
+    as when nobody says: column-major unless the width is a whole number
+    of 128-lane tiles.  A read-modify-write of rows then copies the whole
+    array into a row-major buffer and back (300, 100 wide: 2 copies, a
+    temporary of the padded array) or touches the rows alone (384, 128
+    wide: none).  This is the fact S1 rests on: a field whose STORED row is
+    a multiple of 128 needs no layout of its own (ROADMAP D0)."""
+    from jax.sharding import SingleDeviceSharding
+
+    rows, batch = 2_340_001, 5_500
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def rmw(x, idx, g):
+        return x.at[idx].set(jnp.take(x, idx, axis=0) + g, mode="drop",
+                             unique_indices=True)
+
+    compiled = jax.jit(rmw, donate_argnums=0).lower(
+        jax.ShapeDtypeStruct((rows, width), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((batch, width), jnp.float32,
+                             sharding=one)).compile()
+    text = compiled.as_text()
+    order = "1,0" if row_major else "0,1"
+    assert f"(f32[{rows},{width}]{{{order}:T(8,128)}}" in text.split("\n")[0]
+    copies = re.findall(rf"= f32\[{rows},{width}\]\S* copy\(", text)
+    assert len(copies) == (0 if row_major else 2)
+    padded = rows * (-(-width // 128) * 128) * 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert (temp < 0.01 * GIB) if row_major else (temp >= padded)
 
 
 def test_lfm2_ep4_trainer_step_fits_one_chip(topo, no_compile_cache):

@@ -71,7 +71,11 @@ def test_write_back_forms_match_oracle_bitwise(case, width, mean, sharded,
                   capacity_per_shard=CAP_PER_SHARD)
     table = SparseTable(access, ki, mesh=mesh,
                         axis=SHARD_AXIS if sharded else "model")
-    slots, grad = _batch(case, table, width, seed=width + 7 * mean)
+    # rows are pushed at their STORED width (300 -> 384 lanes, zeros
+    # beyond the vector: `access.stored_width`)
+    stored = table.state[family].shape[1]
+    assert stored == (1 if width == 1 else 384)
+    slots, grad = _batch(case, table, stored, seed=width + 7 * mean)
     state_np = {f: np.asarray(v) for f, v in table.state.items()}
     want = LocalTransfer().push(state_np, slots, {family: grad}, access,
                                 mean=mean)
@@ -110,17 +114,18 @@ def test_write_back_forms_match_oracle_bitwise(case, width, mean, sharded,
             np.testing.assert_array_equal(state_np[f], jitted["sweep"][f])
 
 
-# rows of a push, the field it writes, the table's shards -> the form.
-# The first five are the benchmark cells' own pushes (PERF.md section 4).
+# rows of a push, the field it writes (a 300-wide row is stored on 384
+# lanes), the table's shards -> the form.  The first six are the
+# benchmark cells' own pushes (PERF.md section 4).
 @pytest.mark.parametrize("n, rows, width, shards, form", [
-    (5_000, 2_340_001, 300, 1, "per_row"),       # cbow2m-demo, contexts
-    (5_500, 2_340_001, 300, 1, "per_row"),       # cbow2m-demo, targets
-    (20_480, 2_340_001, 300, 1, "per_row"),      # sg2m-b2k, inputs
-    (122_880, 2_340_001, 300, 1, "sweep"),       # sg2m-b2k, targets
-    (163_840, 2_340_001, 300, 1, "sweep"),       # cbow2m-b16k, contexts
-    (655_360, 3_900_004, 300, 4, "sweep"),       # gnews3m-x4-b64k
-    (100_000, 3_900_004, 300, 1, "per_row"),     # the same rows, unsharded
-    (100_000, 3_900_004, 300, 4, "sweep"),       # ... a shard is a quarter
+    (5_000, 2_340_001, 384, 1, "per_row"),       # cbow2m-demo, contexts
+    (5_500, 2_340_001, 384, 1, "per_row"),       # cbow2m-demo, targets
+    (20_480, 2_340_001, 384, 1, "per_row"),      # sg2m-b2k, inputs
+    (122_880, 2_340_001, 384, 1, "sweep"),       # sg2m-b2k, targets
+    (163_840, 2_340_001, 384, 1, "sweep"),       # cbow2m-b16k, contexts
+    (655_360, 3_900_004, 384, 4, "sweep"),       # gnews3m-x4-b64k
+    (100_000, 3_900_004, 384, 1, "per_row"),     # the same rows, unsharded
+    (100_000, 3_900_004, 384, 4, "sweep"),       # ... a shard is a quarter
     (1_000, 1 << 20, 1, 1, "sweep"),             # d = 1: a cheap sweep
     (100, 1 << 20, 1, 1, "per_row"),
 ])
