@@ -194,16 +194,29 @@ class Trainer:
         self._step_fn = None
 
     # -- the step ---------------------------------------------------------
+    def noise_key(self, step):
+        """The key of step ``step``'s noise draw (objective
+        ``block_diffusion``: ``models/diffusion.py::block_noise``), from
+        the step count alone, so the draw of any step can be made again
+        outside the step."""
+        return jax.random.fold_in(jax.random.key(0), step)
+
     def _build_step(self):
         cfg, mesh, opt = self.cfg, self.mesh, self.optimizer
         aux_w = self.aux_weight
         num = self._numerics
+        noised = cfg.objective == "block_diffusion"
 
         @partial(jax.jit, donate_argnums=(0, 1, 2))
         def train_step(params, opt_state, step, tokens):
+            noise_key = None
+            if noised:
+                with obs.named_scope("noise"):
+                    noise_key = self.noise_key(step)
             (loss, stats), grads = jax.value_and_grad(
                 lm_loss_and_stats, has_aux=True)(
-                    params, tokens, cfg, mesh, aux_weight=aux_w)
+                    params, tokens, cfg, mesh, aux_weight=aux_w,
+                    noise_key=noise_key)
             with obs.named_scope("optimizer"):
                 updates, opt_state = opt.update(grads, opt_state, params)
                 # neither a gradient nor weight decay moves a frozen leaf

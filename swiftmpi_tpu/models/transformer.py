@@ -19,14 +19,21 @@ the framework would compose them:
 * **ep**   — the FFN can be a routed mixture-of-experts over an ``expert``
   axis (parallel/moe.py).
 
-Architecture: pre-RMSNorm, weight-tied output head, and a stack whose
-layers need not be alike: each layer has an *operator* (causal attention
-with RoPE, grouped KV heads and optional per-head QK norm, or a gated
-short convolution) and an *FFN* (dense SiLU-gated, or the routed expert
-layer of parallel/moe.py).  ``layer_ops`` / ``layer_ffns`` name them per
-layer; runs of equal layers are stacked on a leading axis and scanned, so
-a stack compiles one body per run (left empty, every layer is attention +
+Architecture: pre-RMSNorm, an output head that is the embedding's transpose
+(``tied_head``) or a matrix of its own, and a stack whose layers need not
+be alike: each layer has an *operator* (attention with RoPE, grouped KV
+heads of any ``d_head`` and optional per-head QK norm, or a gated short
+convolution) and an *FFN* (dense SiLU-gated, or the routed expert layer of
+parallel/moe.py).  ``layer_ops`` / ``layer_ffns`` name them per layer; runs
+of equal layers are stacked on a leading axis and scanned, so a stack
+compiles one body per run (left empty, every layer is attention +
 ``n_experts``'s FFN: the homogeneous stack ``pipeline_apply`` wants).
+
+Objective (``objective``): ``next_token`` — causal attention, cross entropy
+of the next token — or ``block_diffusion`` (models/diffusion.py): the trunk
+runs ``[x_t ; x_0]``, a noised copy of each sequence before the clean one,
+under the block-diffusion attention mask, and the loss is the 1/t-weighted
+cross entropy of the masked positions' own tokens.
 
 Precision: parameters, residual stream, norms, softmax, router and loss
 are ``dtype`` (f32); ``matmul_dtype`` (bf16 in a deployment) is what the
@@ -45,11 +52,13 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from swiftmpi_tpu import obs
+from swiftmpi_tpu.models import diffusion
 from swiftmpi_tpu.parallel.moe import (MoEParams, MoEStats, expert_layer,
                                        init_moe_params, moe_ffn)
 from swiftmpi_tpu.parallel.pipeline import (pipeline_apply,
                                             stack_stage_params)
-from swiftmpi_tpu.parallel.ring_attention import (blockwise_attention,
+from swiftmpi_tpu.parallel.ring_attention import (CAUSAL,
+                                                  blockwise_attention,
                                                   full_attention,
                                                   ring_attention,
                                                   ulysses_attention)
@@ -57,6 +66,7 @@ from swiftmpi_tpu.parallel.ring_attention import (blockwise_attention,
 
 OPS = ("attention", "conv")
 FFNS = ("dense", "moe")
+OBJECTIVES = ("next_token", "block_diffusion")
 
 
 @dataclass(frozen=True)
@@ -99,9 +109,28 @@ class TransformerConfig:
     matmul_dtype: Any = None         # operands of the large products
     attn_block: int = 512            # blockwise attention's block
     loss_chunk: int = 0              # tokens a head + loss chunk; 0 = all
+    d_head: int = 0                  # a head's width; 0 => d_model / n_heads
+    tied_head: bool = True           # the head is the embedding's transpose
+    # -- the objective ------------------------------------------------------
+    objective: str = "next_token"    # of OBJECTIVES
+    diffusion_block: int = 4         # block_diffusion: positions a block
+    mask_token: int = 0              # ... the id a noised position reads
+    noise_eps: float = 1e-3          # ... a block's mask rate t in [eps, 1]
+
+    def __post_init__(self):
+        if self.objective not in OBJECTIVES:
+            raise ValueError(f"unknown objective {self.objective!r}; have "
+                             f"{OBJECTIVES}")
+        if self.objective == "block_diffusion" \
+                and self.attention != "blockwise":
+            raise ValueError("objective 'block_diffusion' needs attention "
+                             f"'blockwise', not {self.attention!r}: the "
+                             "other variants are causal")
 
     @property
     def head_dim(self) -> int:
+        if self.d_head:
+            return self.d_head
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
 
@@ -189,13 +218,25 @@ def init_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
         stack_stage_params([_init_block(next(keys), cfg, *kind)
                             for _ in range(n)])
         for kind, n in cfg.layer_groups())
-    return {
-        "embed": jax.random.normal(
-            k_emb, (cfg.vocab_size, cfg.d_model), cfg.dtype)
-        * (cfg.init_std or 1.0 / math.sqrt(cfg.d_model)),
+
+    def vocab_matrix(k):
+        return jax.random.normal(k, (cfg.vocab_size, cfg.d_model), cfg.dtype) \
+            * (cfg.init_std or 1.0 / math.sqrt(cfg.d_model))
+
+    params = {
+        "embed": vocab_matrix(k_emb),
         "blocks": groups if cfg.heterogeneous else groups[0],
         "ln_f": jnp.ones((cfg.d_model,), cfg.dtype),
     }
+    if not cfg.tied_head:      # (V, d) like the embedding; a key of its own
+        params["head"] = vocab_matrix(jax.random.fold_in(k_emb, 1))
+    return params
+
+
+def head_matrix(params, cfg: TransformerConfig):
+    """The output head as a (V, d) matrix, ``logits = x @ head.T``: the
+    embedding when ``cfg.tied_head``, else ``params["head"]``."""
+    return params["embed"] if cfg.tied_head else params["head"]
 
 
 def is_buffer(path) -> bool:
@@ -238,7 +279,7 @@ def param_shardings(params, cfg: TransformerConfig, mesh: Mesh,
             return P(None, None, None, model_axis)   # (L, E, d, dff)
         if path == "w_out":
             return P(None, None, model_axis, None)   # (L, E, dff, d)
-        if path == "embed":
+        if path in ("embed", "head"):
             return P(model_axis, None)
         return P()
 
@@ -259,7 +300,7 @@ def param_shardings(params, cfg: TransformerConfig, mesh: Mesh,
 
 # -- forward ---------------------------------------------------------------
 
-def _rms_norm(x, g, eps=1e-6):
+def _rms_norm(x, g, eps):
     x32 = x.astype(jnp.float32)
     r = jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
     return (x32 * r).astype(x.dtype) * g
@@ -274,12 +315,12 @@ def _mm(a, w, cfg: TransformerConfig):
                    preferred_element_type=jnp.float32).astype(a.dtype)
 
 
-def _rope(x, base: float):
-    """(B, S, H, D) rotary position embedding (rotate-half pairing)."""
-    B, S, H, D = x.shape
-    half = D // 2
+def _rope(x, base: float, positions):
+    """(B, S, H, D) rotary position embedding (rotate-half pairing) at the
+    position ids ``positions`` (S,) f32."""
+    half = x.shape[-1] // 2
     freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * freqs[None]  # (S, h)
+    ang = positions[:, None] * freqs[None]                     # (S, h)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = x[..., :half], x[..., half:]
     rot1 = x1 * cos[None, :, None] - x2 * sin[None, :, None]
@@ -288,9 +329,17 @@ def _rope(x, base: float):
 
 
 def _attention(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
-               seq_axis: str):
+               seq_axis: str, positions=None, mask=CAUSAL):
+    """Attention at the position ids ``positions`` ((S,) f32; default
+    ``0..S-1``) under ``mask`` (``blockwise_attention``'s contract;
+    default causal).  The layer does not know the objective: whoever
+    builds another input than a plain sequence says where its positions
+    stand and who sees whom (``diffusion.attention_inputs``)."""
     B, S, d = x.shape
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    if mask is not CAUSAL and cfg.attention != "blockwise":
+        raise ValueError(f"attention {cfg.attention!r} is causal; a mask "
+                         "needs 'blockwise'")
     h = _rms_norm(x, blk["ln1"], cfg.norm_eps)
     q = _mm(h, blk["wq"], cfg).reshape(B, S, H, Dh)
     k = _mm(h, blk["wk"], cfg).reshape(B, S, Hkv, Dh)
@@ -298,14 +347,17 @@ def _attention(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
     if cfg.qk_norm:
         q = _rms_norm(q, blk["q_norm"], cfg.norm_eps)
         k = _rms_norm(k, blk["k_norm"], cfg.norm_eps)
-    q, k = _rope(q, cfg.rope_base), _rope(k, cfg.rope_base)
+    if positions is None:
+        positions = jnp.arange(S, dtype=jnp.float32)
+    q = _rope(q, cfg.rope_base, positions)
+    k = _rope(k, cfg.rope_base, positions)
     if cfg.matmul_dtype is not None:
         q, k, v = (t.astype(cfg.matmul_dtype) for t in (q, k, v))
     # like _ffn: the collective variants need their axis on the mesh;
     # otherwise fall back to the numerically identical local computation
     has_seq = mesh is not None and seq_axis in mesh.axis_names
     if cfg.attention == "blockwise":
-        o = blockwise_attention(q, k, v, block=cfg.attn_block)
+        o = blockwise_attention(q, k, v, block=cfg.attn_block, mask=mask)
     else:
         if Hkv != H:      # the golden and the ring take one K/V per head
             k, v = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
@@ -374,21 +426,22 @@ def _remat_policy(cfg: TransformerConfig):
 
 
 def _operator(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
-              seq_axis: str, op: str):
+              seq_axis: str, op: str, **attn):
     if op == "attention":
         with obs.named_scope("attention"):
-            return _attention(blk, x, cfg, mesh, seq_axis)
+            return _attention(blk, x, cfg, mesh, seq_axis, **attn)
     with obs.named_scope("conv"):
         return _short_conv(blk, x, cfg)
 
 
 def block_apply(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh] = None,
                 *, seq_axis: str = "seq", expert_axis: str = "expert",
-                kind: Optional[Tuple[str, str]] = None):
+                kind: Optional[Tuple[str, str]] = None, **attn):
     """One layer of ``kind`` = (operator, FFN) (default: layer 0's)
-    -> (x, aux loss, MoEStats)."""
+    -> (x, aux loss, MoEStats).  ``attn``: :func:`_attention`'s
+    ``positions`` and ``mask``."""
     op, ffn = kind or cfg.layer_kinds()[0]
-    x = _operator(blk, x, cfg, mesh, seq_axis, op)
+    x = _operator(blk, x, cfg, mesh, seq_axis, op, **attn)
     return _ffn(blk, x, cfg, mesh, expert_axis, ffn)
 
 
@@ -401,9 +454,12 @@ def _groups(params, cfg: TransformerConfig):
 
 def trunk(params, tokens, cfg: TransformerConfig,
           mesh: Optional[Mesh] = None, *, seq_axis: str = "seq",
-          expert_axis: str = "expert"):
+          expert_axis: str = "expert", **attn):
     """tokens (B, S) int32 -> (hidden (B, S, d) after the final norm, aux
-    loss, MoEStats summed over the expert layers)."""
+    loss, MoEStats summed over the expert layers).  A plain sequence
+    under causal attention unless ``attn`` (:func:`_attention`'s
+    ``positions`` and ``mask``) says otherwise: the ``block_diffusion``
+    loss runs ``[x_t ; x_0]`` (B, 2S) with ``diffusion.attention_inputs``."""
     with obs.named_scope("embed"):
         x = params["embed"][tokens]
     carry = (x, jnp.float32(0.0), _no_stats())
@@ -414,7 +470,8 @@ def trunk(params, tokens, cfg: TransformerConfig,
         def body(carry, blk, kind=kind):
             x, aux, stats = carry
             x, a, st = block_apply(blk, x, cfg, mesh, seq_axis=seq_axis,
-                                   expert_axis=expert_axis, kind=kind)
+                                   expert_axis=expert_axis, kind=kind,
+                                   **attn)
             return (x, aux + a, MoEStats(*(s + t for s, t in
                                            zip(stats, st)))), None
 
@@ -430,25 +487,26 @@ def trunk(params, tokens, cfg: TransformerConfig,
 def forward(params, tokens, cfg: TransformerConfig,
             mesh: Optional[Mesh] = None, *, seq_axis: str = "seq",
             expert_axis: str = "expert"):
-    """tokens (B, S) int32 -> (logits (B, S, V), aux_loss)."""
+    """tokens (B, S) int32 -> (logits (B, S, V), aux_loss), through the
+    head :func:`head_matrix` names."""
     x, aux, _ = trunk(params, tokens, cfg, mesh, seq_axis=seq_axis,
                       expert_axis=expert_axis)
     with obs.named_scope("head"):
-        return _mm(x, params["embed"].T, cfg), aux
+        return _mm(x, head_matrix(params, cfg).T, cfg), aux
 
 
-def hidden_states(params, tokens, cfg: TransformerConfig):
+def hidden_states(params, tokens, cfg: TransformerConfig, **attn):
     """The residual stream as ``trunk`` computes it on one device, at every
     half layer: ``[x_0, m_0, x_1, m_1, ..., x_L]`` with ``x_i`` the input of
     layer ``i``'s operator, ``m_i`` the input of its FFN and ``x_L`` the
     last layer's output (for holding each operator and FFN to a reference
-    on the program's own input)."""
+    on the program's own input).  ``attn`` as :func:`trunk` takes it."""
     x = params["embed"][tokens]
     out = [x]
     for (op, ffn), n, stacked in _groups(params, cfg):
         for i in range(n):
             blk = jax.tree.map(lambda a: a[i], stacked)
-            mid = _operator(blk, x, cfg, None, "seq", op)
+            mid = _operator(blk, x, cfg, None, "seq", op, **attn)
             x = _ffn(blk, mid, cfg, None, "expert", ffn)[0]
             out += [mid, x]
     return out
@@ -462,6 +520,9 @@ def forward_pipelined(params, tokens, cfg: TransformerConfig, mesh: Mesh,
     head stay outside the pipelined trunk (homogeneous-activation rule).
     Dense-FFN, local attention — the pipeline composes with dp, not with
     the collective attention variants (one shard_map at a time)."""
+    if cfg.objective != "next_token":
+        raise ValueError("pipelined trunk runs objective 'next_token' only, "
+                         f"not {cfg.objective!r}: its attention is causal")
     if cfg.n_experts or cfg.attention != "full" or cfg.heterogeneous:
         raise ValueError("pipelined trunk requires full attention and "
                          "dense FFN (nested shard_map is not supported)")
@@ -475,19 +536,19 @@ def forward_pipelined(params, tokens, cfg: TransformerConfig, mesh: Mesh,
 
     x = pipeline_apply(stage_fn, params["blocks"], x, mesh,
                        axis=stage_axis, num_microbatches=num_microbatches)
-    x = _rms_norm(x, params["ln_f"])
-    return x @ params["embed"].T, jnp.float32(0.0)
+    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+    return x @ head_matrix(params, cfg).T, jnp.float32(0.0)
 
 
 # -- training --------------------------------------------------------------
 
-def _token_nll(x, embed, targets, cfg: TransformerConfig):
-    """Per-token next-token negative log likelihood, f32: x (N, d) hidden,
-    targets (N,) -> (N,).  The tied head's logits and their log-sum-exp
-    exist for ``loss_chunk`` tokens at a time and are recomputed in the
-    backward pass, so (N, V) f32 is never held."""
+def _token_nll(x, head, targets, cfg: TransformerConfig):
+    """Per-token negative log likelihood of ``targets``, f32: x (N, d)
+    hidden, head (V, d) (:func:`head_matrix`), targets (N,) -> (N,).  The
+    logits and their log-sum-exp exist for ``loss_chunk`` tokens at a time
+    and are recomputed in the backward pass, so (N, V) f32 is never held."""
     def nll(xc, tc):
-        logits = _mm(xc, embed.T, cfg).astype(jnp.float32)
+        logits = _mm(xc, head.T, cfg).astype(jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
         return lse - jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
 
@@ -504,25 +565,42 @@ def _token_nll(x, embed, targets, cfg: TransformerConfig):
 
 def lm_loss_and_stats(params, tokens, cfg: TransformerConfig,
                       mesh: Optional[Mesh] = None, aux_weight: float = 0.01,
-                      **fwd_kwargs):
-    """(next-token cross entropy + weighted MoE aux, MoEStats summed over
-    the expert layers).  The whole sequence runs through the trunk (so a
-    packed sequence keeps its length); a sequence's last position has no
-    target and carries no loss."""
+                      noise_key=None, **fwd_kwargs):
+    """(``cfg.objective``'s loss + weighted MoE aux, MoEStats summed over
+    the expert layers).
+
+    ``next_token``: the whole sequence runs through the trunk (so a packed
+    sequence keeps its length); a sequence's last position has no target
+    and carries no loss.  ``block_diffusion``: ``noise_key`` draws the
+    step's noise (:func:`diffusion.block_noise`), the trunk runs
+    ``[x_t ; x_0]``, and head and loss run over the noised half alone: a
+    masked position predicts its own token, weighted 1/t."""
     B, S = tokens.shape
-    x, aux, stats = trunk(params, tokens, cfg, mesh, **fwd_kwargs)
+    diffuse = cfg.objective == "block_diffusion"
+    inputs, attn = tokens, {}
+    if diffuse:
+        if noise_key is None:
+            raise ValueError("objective 'block_diffusion' needs a noise_key")
+        with obs.named_scope("noise"):
+            noisy, weights = diffusion.block_noise(noise_key, tokens, cfg)
+            inputs = diffusion.trunk_input(noisy, tokens)
+            attn = diffusion.attention_inputs(S, cfg)
+    x, aux, stats = trunk(params, inputs, cfg, mesh, **attn, **fwd_kwargs)
+    if diffuse:
+        x = x[:, :S]
     with obs.named_scope("head"):
-        targets = jnp.roll(tokens, -1, axis=1)
-        nll = _token_nll(x.reshape(B * S, -1), params["embed"],
+        targets = tokens if diffuse else jnp.roll(tokens, -1, axis=1)
+        nll = _token_nll(x.reshape(B * S, -1), head_matrix(params, cfg),
                          targets.reshape(B * S), cfg).reshape(B, S)
-        loss = nll[:, :-1].mean()
+        loss = diffusion.weighted_loss(nll, weights) if diffuse \
+            else nll[:, :-1].mean()
     return loss + aux_weight * aux, stats
 
 
 def lm_loss(params, tokens, cfg: TransformerConfig,
             mesh: Optional[Mesh] = None, aux_weight: float = 0.01,
             **fwd_kwargs):
-    """Next-token cross entropy (+ weighted MoE aux)."""
+    """``cfg.objective``'s loss (+ weighted MoE aux)."""
     return lm_loss_and_stats(params, tokens, cfg, mesh, aux_weight,
                              **fwd_kwargs)[0]
 

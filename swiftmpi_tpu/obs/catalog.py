@@ -151,6 +151,7 @@ DEVICE_SCOPES = {
     # the autodiff LM step (models/transformer.py, models/trainer.py): a
     # backward instruction carries its forward scope inside the
     # transform's name (transpose(jvp(experts))) and books there too
+    "noise": "noise",              # block diffusion: the step's mask draw
     "embed": "embed",              # the embedding rows' gather
     "conv": "conv",                # gated short convolution operator
     "attention": "attention",      # QKV, QK norm, RoPE, softmax(QK)V, out
@@ -162,7 +163,7 @@ DEVICE_SCOPES = {
     "ragged-dot-none": "experts",
     "ragged-dot-metadata": "experts",
     "dense_ffn": "dense_ffn",      # the dense SwiGLU FFN
-    "head": "head",                # final norm, tied head, cross entropy
+    "head": "head",                # final norm, head, cross entropy
     "optimizer": "optimizer",      # clip + AdamW + apply
 }
 

@@ -14,7 +14,8 @@ mesh-native primitives:
   become head-sharded, full-sequence attention runs locally per head group,
   then all_to_all back.  Two collectives total; requires heads % n == 0.
 
-* ``blockwise_attention`` — one device, causal, grouped KV heads: the
+* ``blockwise_attention`` — one device, grouped KV heads, causal or under
+  any mask that names its tiles (``CausalMask`` is the contract): the
   same online-softmax recurrence walked over key/value blocks, so no
   ``(S, S)`` matrix of a head ever exists in HBM, forward or backward (the
   backward pass recomputes a block's probabilities from the saved
@@ -27,6 +28,7 @@ Layout convention: ``(batch, seq, heads, head_dim)``.
 from __future__ import annotations
 
 import math
+from dataclasses import dataclass
 from functools import partial
 from typing import Optional
 
@@ -62,37 +64,77 @@ def full_attention(q, k, v, causal: bool = False):
                       ).astype(v.dtype)
 
 
-# -- blockwise causal attention on one device --------------------------------
+# -- blockwise masked attention on one device ---------------------------------
+
+@dataclass(frozen=True)
+class CausalMask:
+    """What ``blockwise_attention`` asks of a mask, in its causal case.
+    The sequence is cut into ``n`` tiles of ``size`` positions; tile
+    indices may be traced values.  A mask is hashable (a static argument)
+    and gives
+
+    * ``key_tiles(i, n, size) -> (lo, hi, tile)``: query tile ``i`` folds
+      the key tiles ``tile(t)`` for ``t`` in ``[lo, hi)`` — every tile that
+      holds a key one of its queries sees, each once.  Tiles outside the
+      list are never multiplied;
+    * ``query_tiles(j, n, size)``: the same list transposed — the query
+      tiles that fold key tile ``j`` (the backward pass walks these);
+    * ``visible(qa, kc)``: the element predicate, from absolute query
+      positions ``(size, 1)`` and key positions ``(1, size)``;
+    * ``tile(block, S) -> size``: the tile its lists are written for, at
+      most ``block`` positions of the ``S`` (it must divide ``S``).
+
+    Every query must see a key in at least one tile of its list."""
+
+    def tile(self, block, S):
+        return min(block, S)
+
+    def key_tiles(self, i, n, size):
+        return 0, i + 1, lambda t: t
+
+    def query_tiles(self, j, n, size):
+        return j, n, lambda t: t
+
+    def visible(self, qa, kc):
+        return qa >= kc
+
+
+CAUSAL = CausalMask()
+
 
 def _block(x, i, size):
     """Block ``i`` of ``size`` positions along axis 1."""
     return lax.dynamic_slice_in_dim(x, i * size, size, axis=1)
 
 
-def _block_scores(qi, kj, i, j, size, scale):
+def _block_scores(qi, kj, i, j, size, scale, mask):
     """f32 scores (B, Hkv, G, bq, bk) of query block ``i`` against key
-    block ``j``, future positions at ``_NEG``."""
+    block ``j``, positions the mask hides at ``_NEG``."""
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qi, kj,
                    preferred_element_type=jnp.float32) * scale
     pos = jnp.arange(size)
-    visible = (i * size + pos)[:, None] >= (j * size + pos)[None, :]
+    visible = mask.visible((i * size + pos)[:, None],
+                           (j * size + pos)[None, :])
     return jnp.where(visible, s, _NEG)
 
 
-def _blockwise_fwd(q, k, v, size):
+def _blockwise_fwd(q, k, v, size, mask):
     """q (B, S, Hkv, G, D), k / v (B, S, Hkv, D) -> (o like q, lse
     (B, S, Hkv, G) f32).  Query blocks in turn; each folds the key
-    blocks up to its own (later ones are entirely in the future and are
-    never multiplied)."""
+    blocks its mask lists (causal: those up to its own; later ones are
+    entirely in the future and are never multiplied)."""
     B, S, Hkv, G, D = q.shape
     scale = 1.0 / math.sqrt(D)
 
     def q_block(i):
         qi = _block(q, i, size)
+        lo, hi, tile = mask.key_tiles(i, S // size, size)
 
-        def fold(j, carry):
+        def fold(t, carry):
             m, l, o = carry
-            s = _block_scores(qi, _block(k, j, size), i, j, size, scale)
+            j = tile(t)
+            s = _block_scores(qi, _block(k, j, size), i, j, size, scale,
+                              mask)
             m_new = jnp.maximum(m, s.max(axis=-1))
             p = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m - m_new)
@@ -103,7 +145,7 @@ def _blockwise_fwd(q, k, v, size):
 
         m0 = jnp.full((B, Hkv, G, size), _NEG, jnp.float32)
         m, l, o = lax.fori_loop(
-            0, i + 1, fold,
+            lo, hi, fold,
             (m0, jnp.zeros_like(m0), jnp.zeros((B, Hkv, G, size, D),
                                                jnp.float32)))
         o = (o / l[..., None]).astype(q.dtype)
@@ -116,17 +158,17 @@ def _blockwise_fwd(q, k, v, size):
             jnp.moveaxis(lse, 0, 1).reshape(B, S, Hkv, G))
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _blockwise(q, k, v, size):
-    return _blockwise_fwd(q, k, v, size)[0]
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _blockwise(q, k, v, size, mask):
+    return _blockwise_fwd(q, k, v, size, mask)[0]
 
 
-def _blockwise_vjp_fwd(q, k, v, size):
-    o, lse = _blockwise_fwd(q, k, v, size)
+def _blockwise_vjp_fwd(q, k, v, size, mask):
+    o, lse = _blockwise_fwd(q, k, v, size, mask)
     return o, (q, k, v, o, lse)
 
 
-def _blockwise_vjp_bwd(size, res, do):
+def _blockwise_vjp_bwd(size, mask, res, do):
     q, k, v, o, lse = res
     B, S, Hkv, G, D = q.shape
     scale = 1.0 / math.sqrt(D)
@@ -135,13 +177,15 @@ def _blockwise_vjp_bwd(size, res, do):
 
     def kv_block(dq, j):
         kj, vj = _block(k, j, size), _block(v, j, size)
+        lo, hi, tile = mask.query_tiles(j, n, size)
 
-        def fold(i, carry):
+        def fold(t, carry):
             dq, dk, dv = carry
+            i = tile(t)
             qi, doi = _block(q, i, size), _block(do, i, size)
-            s = _block_scores(qi, kj, i, j, size, scale)
+            s = _block_scores(qi, kj, i, j, size, scale, mask)
             lse_i = jnp.einsum("bqhg->bhgq", _block(lse, i, size))
-            p = jnp.exp(s - lse_i[..., None])        # future: exp(-1e30)
+            p = jnp.exp(s - lse_i[..., None])        # hidden: exp(-1e30)
             dv = dv + jnp.einsum("bhgqk,bqhgd->bkhd", p.astype(do.dtype),
                                  doi, preferred_element_type=jnp.float32)
             dp = jnp.einsum("bqhgd,bkhd->bhgqk", doi, vj,
@@ -157,7 +201,7 @@ def _blockwise_vjp_bwd(size, res, do):
             return dq, dk, dv
 
         zeros = jnp.zeros(kj.shape, jnp.float32)
-        dq, dk, dv = lax.fori_loop(j, n, fold, (dq, zeros, zeros))
+        dq, dk, dv = lax.fori_loop(lo, hi, fold, (dq, zeros, zeros))
         return dq, (dk, dv)
 
     dq, (dk, dv) = lax.scan(kv_block, jnp.zeros(q.shape, jnp.float32),
@@ -170,8 +214,9 @@ def _blockwise_vjp_bwd(size, res, do):
 _blockwise.defvjp(_blockwise_vjp_fwd, _blockwise_vjp_bwd)
 
 
-def blockwise_attention(q, k, v, block: int = 512):
-    """Causal attention on one device without an ``(S, S)`` matrix.
+def blockwise_attention(q, k, v, block: int = 512, mask=CAUSAL):
+    """Masked attention on one device without an ``(S, S)`` matrix; causal
+    unless ``mask`` says otherwise (:class:`CausalMask` is the contract).
 
     ``q``: (B, S, H, D); ``k``, ``v``: (B, S, Hkv, D) with ``H`` a
     multiple of ``Hkv`` (each KV head serves ``H / Hkv`` query heads in
@@ -182,10 +227,10 @@ def blockwise_attention(q, k, v, block: int = 512):
     Hkv = k.shape[2]
     if H % Hkv:
         raise ValueError(f"{H} query heads over {Hkv} KV heads")
-    size = min(block, S)
+    size = mask.tile(block, S)
     if S % size:
         raise ValueError(f"sequence {S} is no multiple of block {size}")
-    o = _blockwise(q.reshape(B, S, Hkv, H // Hkv, D), k, v, size)
+    o = _blockwise(q.reshape(B, S, Hkv, H // Hkv, D), k, v, size, mask)
     return o.reshape(B, S, H, D)
 
 

@@ -508,6 +508,134 @@ def lm_phase_map(toy):
     assert want <= set(pm["phase"].values())
 
 
+# -- the block-diffusion family's surface (benchmark/families/bdlm.py's head) ----
+
+_BD = {}
+
+
+def bd_toy():
+    """A toy block stack trained by block diffusion, one ``Trainer.run`` of
+    two host batches with telemetry on, by the family's call sequence."""
+    if _BD:
+        return _BD["toy"]
+    from swiftmpi_tpu import obs
+    from swiftmpi_tpu.models.trainer import Trainer
+    from swiftmpi_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=8, n_kv_heads=1,
+        d_head=8, d_expert=16, max_seq=32, attention="blockwise",
+        attn_block=8, loss_chunk=16, remat=True, remat_policy="full",
+        n_experts=128, moe_top_k=8, experts_held=(16, 32), router="softmax",
+        expert_gated=True, qk_norm=True, layer_ops=("attention",) * 2,
+        layer_ffns=("moe",) * 2, norm_eps=1e-6, rope_base=1e6, init_std=0.3,
+        tied_head=False, objective="block_diffusion", diffusion_block=4,
+        mask_token=63, noise_eps=1e-3)
+    was_on = obs.get_registry().enabled
+    obs.set_enabled(True)
+    trainer = Trainer(cfg, optimizer="adamw", aux_weight=0.0,
+                      learning_rate=3e-4, warmup_steps=2, decay_steps=100,
+                      weight_decay=0.1, grad_clip=1.0, b1=0.9, b2=0.95)
+    state0 = trainer.init_state(jax.random.key(3))
+    rng = np.random.default_rng(37)
+    batches = [rng.integers(0, 63, (2, 16)).astype(np.int32)
+               for _ in range(2)]
+    state, losses = trainer.run(state0, iter(batches))
+    phase_map = obs.costs.phase_map("trainer_step")
+    obs.set_enabled(was_on)
+    _BD["toy"] = SimpleNamespace(cfg=cfg, trainer=trainer, state=state,
+                                 losses=losses, batches=batches,
+                                 phase_map=phase_map)
+    return _BD["toy"]
+
+
+@surface
+def bdlm_config_and_tree(toy):
+    """The ``TransformerConfig`` fields the family sets beyond the LM
+    family's, ``params["head"]`` beside ``embed``, and AdamW's first moment
+    shaped like them."""
+    from swiftmpi_tpu.models.transformer import TransformerConfig
+
+    fields = TransformerConfig.__dataclass_fields__
+    for name, default in [("d_head", 0), ("tied_head", True),
+                          ("objective", "next_token"),
+                          ("diffusion_block", 4), ("mask_token", 0),
+                          ("noise_eps", 1e-3)]:
+        assert fields[name].default == default, name
+    t = bd_toy()
+    assert t.cfg.head_dim == 8 != t.cfg.d_model // t.cfg.n_heads
+    params = t.state.params
+    assert set(params) == {"embed", "head", "blocks", "ln_f"}
+    assert params["head"].shape == params["embed"].shape == (64, 32)
+    assert len(params["blocks"]) == 1            # one run of equal layers
+    assert params["blocks"][0]["wq"].shape == (2, 32, 64)
+    mu = t.state.opt_state[1][0].mu
+    assert jax.tree.structure(mu) == jax.tree.structure(params)
+    assert len(t.losses) == 2
+    assert all(math.isfinite(float(x)) for x in t.losses)
+
+
+@surface
+def bdlm_noise_drawn_again(toy):
+    """``Trainer.noise_key(step)`` + ``diffusion.block_noise(key, tokens,
+    cfg)``: the step's noise from outside the step, and ``trunk_input`` /
+    ``attention_inputs`` / ``hidden_states`` on ``[noisy ; tokens]``."""
+    from swiftmpi_tpu.models import diffusion
+    from swiftmpi_tpu.models.transformer import hidden_states, lm_loss
+
+    t = bd_toy()
+    assert list(inspect.signature(diffusion.block_noise).parameters) == \
+        ["key", "tokens", "cfg"]
+    assert list(inspect.signature(t.trainer.noise_key).parameters) == ["step"]
+    # the second step's loss, made again from its key on the state before it
+    tr = type(t.trainer)(t.cfg, optimizer="adamw", aux_weight=0.0,
+                         learning_rate=3e-4, warmup_steps=2, decay_steps=100,
+                         weight_decay=0.1, grad_clip=1.0, b1=0.9, b2=0.95)
+    state = tr.init_state(jax.random.key(3))
+    state, _ = tr.step(state, t.batches[0])
+    assert int(state.step) == 1
+    again = lm_loss(state.params, t.batches[1], t.cfg, aux_weight=0.0,
+                    noise_key=tr.noise_key(1))
+    assert float(again) == pytest.approx(float(t.losses[1]), rel=1e-5)
+    noisy, weights = diffusion.block_noise(tr.noise_key(1), t.batches[1],
+                                           t.cfg)
+    assert noisy.shape == weights.shape == (2, 16)
+    assert ((np.asarray(noisy) == 63) == (np.asarray(weights) > 0)).all()
+    z = diffusion.trunk_input(noisy, t.batches[1])
+    assert z.shape == (2, 32)
+    attn = diffusion.attention_inputs(16, t.cfg)
+    assert sorted(attn) == ["mask", "positions"]
+    assert list(np.asarray(attn["positions"])) == list(range(16)) * 2
+    hs = hidden_states(state.params, z, t.cfg, **attn)
+    assert len(hs) == 2 * t.cfg.n_layers + 1 and hs[0].shape == (2, 32, 32)
+    # the layer does not know the objective: a plain call is a causal pass
+    causal = hidden_states(state.params, z, t.cfg)
+    assert not np.allclose(np.asarray(causal[-1]), np.asarray(hs[-1]))
+
+
+@surface
+def bdlm_phase_map_and_counters(toy):
+    """The device scope ``noise`` beside the LM step's, and the expert
+    layers' counters of a run whose share is not the first range."""
+    from swiftmpi_tpu.obs.catalog import DEVICE_SCOPES
+
+    t = bd_toy()
+    want = {"noise", "embed", "attention", "route", "experts", "head",
+            "optimizer"}
+    assert want <= set(DEVICE_SCOPES.values())
+    assert want <= set(t.phase_map["phase"].values())
+    assert t.phase_map["module"] == "jit_train_step"
+    m = t.trainer.train_metrics
+    assert m["steps"] == 2 and m["dropped_picks_per_step"] == 0.0
+    assert 0.0 < m["held_pick_share"] < 100.0
+    assert m["expert_load_max_over_mean"] >= 1.0
+    # a share's router is left alone
+    router0 = t.trainer.init_state(jax.random.key(3)).params["blocks"][0][
+        "moe"].router
+    assert np.array_equal(np.asarray(router0), np.asarray(
+        t.state.params["blocks"][0]["moe"].router))
+
+
 @pytest.mark.parametrize("name", sorted(SURFACE))
 def test_harness_surface(name, toy):
     SURFACE[name](toy)

@@ -272,3 +272,58 @@ def test_lfm2_ep4_trainer_step_fits_one_chip(topo, no_compile_cache):
     for dims in set(re.findall(r"= \w+\[([\d,]+)\]", text)):
         big = [int(d) for d in dims.split(",") if int(d) >= S]
         assert len(big) < 2, f"[{dims}]"
+
+
+def test_sdar_ep8_trainer_step_fits_one_chip(topo, no_compile_cache):
+    """The real ``trainer_step`` of ``sdar-ep8-8k-t16k`` — 4 layers at the
+    published widths (32 heads of 128 over a 2,048 residual), 16 of 128
+    experts, an untied head, 2 packed sequences of 8,192 = 32,768 trunk
+    positions of ``[x_t ; x_0]`` — on one v5e chip: the compiler's memory
+    report fits 15.75 GiB, the grouped products are the compiler's one
+    ``ragged-dot`` kernel family, and no buffer has the size of a head's
+    scores over a whole sequence, noised + clean or either half."""
+    import sys
+    sys.path.insert(0, REPO)
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.families import bdlm as family
+    from swiftmpi_tpu.models.trainer import Trainer
+    from swiftmpi_tpu.obs.catalog import DEVICE_SCOPES
+
+    config, traffic = _cell("sdar-ep8-8k-t16k")
+    cfg = family.transformer_config(config, traffic)
+    assert (cfg.objective, cfg.head_dim, cfg.tied_head) == \
+        ("block_diffusion", 128, False)
+    one = SingleDeviceSharding(topo.devices[0])
+    trainer = Trainer(cfg, **family.trainer_kwargs(config))
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda k: trainer.init_state(k).tree(),
+                       jax.random.key(0)))
+    seqs, S = int(traffic["sequences_per_step"]), cfg.max_seq // 2
+    tokens = jax.ShapeDtypeStruct((seqs, S), jnp.int32, sharding=one)
+    n_params = sum(a.size for a in jax.tree.leaves(state["params"]))
+    assert n_params == 456_346_624            # ISSUE 33's count, 7.30 GB x 16 B
+
+    compiled = trainer._build_step().lower(
+        state["params"], state["opt_state"], state["step"], tokens).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total <= 15.75 * GIB, f"{total / GIB:.2f} GiB"
+    assert mem.alias_size_in_bytes >= 0.99 * mem.output_size_in_bytes
+
+    text = compiled.as_text()
+    # every kernel the compiler brings is a ragged-dot one, under the names
+    # the catalog books as `experts` and `^ragged-dot` matches: 3 products
+    # forward and 9 backward in the one scanned layer body
+    kernels = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                         r'op_name="([^"]*)"', text)
+    assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
+    assert set(kernels) <= set(DEVICE_SCOPES)
+    assert kernels.count("ragged-dot-none") == 12
+    # (2S, 2S) or (S, S) scores of a head would be an array with two dims
+    # of at least S; the largest things here have one (positions x a width)
+    for dims in set(re.findall(r"= \w+\[([\d,]+)\]", text)):
+        big = [int(d) for d in dims.split(",") if int(d) >= S]
+        assert len(big) < 2, f"[{dims}]"
