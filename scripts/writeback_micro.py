@@ -4,13 +4,19 @@
 the batch's representative slots, applies the access method and writes the
 rows back.  This script times that read-modify-write alone, on one donated
 ``f32[2340001, 300]`` field (``cbow2m-demo``'s table), for each candidate
-form of the write-back and the issue's two batch sizes (plus three
-half-padded ones for the forms that were candidates), and reads the times
+form of the write-back and the issue's two batch sizes (plus half-padded
+ones for the two forms the program chooses between), and reads the times
 from a device trace (ISSUE 30; the table is in PERF.md section 6).  The
 field is stored as the table stores it, in the compiler's column-major
 default; ``--layout row_major`` pins it row-major in and out of every
 program instead (PR 32's reading: what the write-back costs once no step
-copies a whole field, ROADMAP S1 / D0 / D10).
+copies a whole field, ROADMAP S1 / D0 / D10).  ``--width 384 --cases
+head`` (PR 34) times the field as it is stored since PR 32, 384 lanes and
+row-major by default, at the cells' own pushes with their distinct valid
+rows at the head: the sweep and the per-row write beside the program's own
+``transfer/xla.py::_rmw_head_rows`` (which reads and updates the head alone
+too), without (``head``) and with its run-time choice of the sweep
+(``head_or_sweep``), and the read half for the floor.
 
     python scripts/writeback_micro.py                # on the chip
     JAX_PLATFORMS=cpu python scripts/writeback_micro.py --compile-only DIR
@@ -36,6 +42,8 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax import lax  # noqa: E402
 
+from swiftmpi_tpu.transfer import xla as transfer_xla  # noqa: E402
+
 CAP, D = 2340001, 300
 #: (valid rows, batch length): the tail is ``capacity`` padding, as the
 #: push's ``rep_slots`` has after its dedup
@@ -44,13 +52,23 @@ SIZES = {"5k": (5000, 5500), "100k": (100000, 110000),
          "80k_of_180k": (80000, 180224),
          # the cells' other pushes at their 56.5 % fill (PR 32)
          "12k_of_20k": (11600, 20480), "70k_of_123k": (69500, 122880),
-         "93k_of_164k": (92600, 163840)}
+         "93k_of_164k": (92600, 163840),
+         # the cells' pushes by their DISTINCT valid rows (ISSUE 34):
+         # cbow2m-demo; sg2m-b2k inputs, targets; cbow2m-b16k contexts,
+         # targets; the worst push that shape allows
+         "3.5k_of_5.5k": (3500, 5500), "2k_of_20k": (2000, 20480),
+         "60k_of_123k": (60000, 122880), "63k_of_164k": (63000, 163840),
+         "146k_of_180k": (146000, 180224), "180k_of_180k": (180224, 180224)}
 ISSUE_SIZES = ("5k", "100k")
+HEAD_SIZES = ("3.5k_of_5.5k", "2k_of_20k", "60k_of_123k", "63k_of_164k",
+              "146k_of_180k", "180k_of_180k")
+HEAD_FORMS = ("a", "b", "head", "head_or_sweep", "gather_only")
 RUNS = 4
-CHUNK = 2048
 #: ``layout.Format`` of the field in and out of every program, or ``None``
 #: for the compiler's default (set from ``--layout``)
 FIELD_FORMAT = None
+#: suffix of the result file (set from ``--head-chunk``)
+TAG = ""
 
 
 def _rmw(x, rep):
@@ -130,36 +148,22 @@ def form_g(x, rep, g):          # a loop of row dynamic_update_slices
     return lax.fori_loop(0, rep.shape[0], body, x)
 
 
-def _chunk_loop(x, rep, upd, valid):
-    """Write the rows a chunk at a time, only as many chunks as hold a
-    valid row: the valid representatives are the head of ``rep``."""
-    B = rep.shape[0]
-    pad = -B % CHUNK
-    rep = jnp.concatenate([rep, jnp.full((pad,), CAP, rep.dtype)])
-    upd = jnp.concatenate([upd, jnp.zeros((pad, D), upd.dtype)])
-    n_chunks = (jnp.sum(valid, dtype=jnp.int32) + CHUNK - 1) // CHUNK
+class _Access:
+    """`_apply` as an access method of the one field ``x``."""
 
-    def body(i, acc):
-        at = i * CHUNK
-        return acc.at[lax.dynamic_slice_in_dim(rep, at, CHUNK)].set(
-            lax.dynamic_slice_in_dim(upd, at, CHUNK), mode="drop",
-            unique_indices=True)
-    return lax.fori_loop(0, n_chunks, body, x)
+    @staticmethod
+    def apply_push(current, grads):
+        return {"x": _apply(current["x"], grads["x"])}
 
 
-def form_h(x, rep, g):          # per-row chunks, trip count = rows written
-    cur, valid = _rmw(x, rep)
-    return _chunk_loop(x, rep, _apply(cur, g), valid)
+def form_head(x, rep, g, may_sweep=False):  # the program's loop over the head
+    return transfer_xla._rmw_head_rows(
+        {"x": x}, rep, {"x": g}, _Access,
+        jnp.sum(rep < CAP, dtype=jnp.int32), may_sweep)["x"]
 
 
-def form_i(x, rep, g):          # h below the crossover, today's sweep above
-    cur, valid = _rmw(x, rep)
-    upd = _apply(cur, g)
-    few = jnp.sum(valid, dtype=jnp.int32) * 19 < CAP
-    return lax.cond(
-        few, lambda x: _chunk_loop(x, rep, upd, valid),
-        lambda x: _set(x, rep, upd, indices_are_sorted=True,
-                       unique_indices=True), x)
+def form_head_or_sweep(x, rep, g):  # ... or one sweep, chosen at run time
+    return form_head(x, rep, g, may_sweep=True)
 
 
 def form_gather_only(x, rep, g):  # the floor: the read half, no write
@@ -168,7 +172,8 @@ def form_gather_only(x, rep, g):  # the floor: the read half, no write
 
 
 FORMS = {"a": form_a, "b": form_b, "c": form_c, "d": form_d, "e": form_e,
-         "f": form_f, "f_s": form_f_s, "g": form_g, "h": form_h, "i": form_i,
+         "f": form_f, "f_s": form_f_s, "g": form_g,
+         "head": form_head, "head_or_sweep": form_head_or_sweep,
          "gather_only": form_gather_only}
 
 
@@ -194,17 +199,23 @@ def _set_layout(layout, sharding):
         jax.config.update("jax_enable_compilation_cache", False)
 
 
-def _cases():
+def _cases(which):
+    if which == "head":
+        yield from ((form, size) for size in HEAD_SIZES
+                    for form in HEAD_FORMS)
+        return
     for size in SIZES:
         for form in FORMS:
+            if size in HEAD_SIZES or form.startswith("head"):
+                continue
             if form == "g" and size != "5k":
                 continue
-            if size not in ISSUE_SIZES and form not in ("a", "b", "h", "i"):
+            if size not in ISSUE_SIZES and form not in ("a", "b"):
                 continue
             yield form, size
 
 
-def compile_only(out_dir, layout):
+def compile_only(out_dir, layout, which):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     topo = topologies.get_topology_desc(platform="tpu",
@@ -212,7 +223,7 @@ def compile_only(out_dir, layout):
     dev = SingleDeviceSharding(topo.devices[0])
     _set_layout(layout, dev)
     os.makedirs(out_dir, exist_ok=True)
-    for form, size in _cases():
+    for form, size in _cases(which):
         B = SIZES[size][1]
         c = _jitted(form, size).lower(
             jax.ShapeDtypeStruct((CAP, D), jnp.float32,
@@ -250,7 +261,7 @@ def _reduce(trace_dir):
     return sum(e - s for s, e, _ in runs) / 1e6 / RUNS, ops
 
 
-def measure(only, layout):
+def measure(only, layout, which):
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         raise SystemExit(f"needs the chip, found {dev.platform}")
@@ -272,10 +283,11 @@ def measure(only, layout):
             jax.random.key(1), (B, D), jnp.float32))
     result = {"device": dev.device_kind, "layout": layout,
               "capacity": CAP, "width": D,
-              "sizes": SIZES, "runs": RUNS, "chunk": CHUNK, "cases": {}}
+              "sizes": SIZES, "runs": RUNS,
+              "head_chunk": transfer_xla._HEAD_CHUNK, "cases": {}}
     trace_dir = os.path.join("chiprun_out", "writeback_trace")
     digests = {}
-    for form, size in _cases():
+    for form, size in _cases(which):
         if only is not None and form not in only:
             continue
         fn = _jitted(form, size)
@@ -299,13 +311,15 @@ def measure(only, layout):
         ms, ops = _reduce(trace_dir)
         shutil.rmtree(trace_dir)            # too big to bring back
         del x, out
-        same = (digests[form, size] == digests["a", size]) if keep else None
+        same = (digests[form, size] == digests.get(("a", size))
+                if keep else None)
         result["cases"][f"{form}.{size}"] = {
             "ms_per_run": ms, "ops": ops, "same_as_a": same}
         top = ", ".join(f"{k} {v:.3f}" for k, v in list(ops.items())[:6])
         print(f"{form:12s}{size:14s}{ms:9.3f} ms a run  same_as_a={same}  "
               f"[{top}]", flush=True)
-    with open(os.path.join("chiprun_out", "writeback_micro.json"), "w") as f:
+    with open(os.path.join("chiprun_out", f"writeback_micro{TAG}.json"),
+              "w") as f:
         json.dump(result, f, indent=1)
 
 
@@ -317,9 +331,22 @@ if __name__ == "__main__":
                     default="default",
                     help="how the field is stored (default: as the table "
                          "stores it, the compiler's choice)")
+    ap.add_argument("--width", type=int, default=D,
+                    help="lanes of a stored row (384: the table's since "
+                         "PR 32, row-major by default)")
+    ap.add_argument("--cases", choices=("forms", "head"), default="forms",
+                    help="forms: PR 30's candidates at its sizes; head: "
+                         "the cells' pushes, distinct rows at the head")
+    ap.add_argument("--head-chunk", type=int, default=None,
+                    help="slots a chunk of `_rmw_head_rows` (default: "
+                         "the program's)")
     args = ap.parse_args()
+    D = args.width
+    if args.head_chunk:
+        transfer_xla._HEAD_CHUNK = args.head_chunk
+        TAG = f"_chunk{args.head_chunk}"
     if args.compile_only:
-        compile_only(args.compile_only, args.layout)
+        compile_only(args.compile_only, args.layout, args.cases)
     else:
         os.makedirs("chiprun_out", exist_ok=True)
-        measure(args.only, args.layout)
+        measure(args.only, args.layout, args.cases)

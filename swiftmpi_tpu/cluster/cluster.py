@@ -110,7 +110,9 @@ class Cluster:
                 f"{calibration.DATA_PLANE_MODES}, got {self.data_plane!r}")
         kwargs = ({"mesh": self.mesh, "data_plane": self.data_plane}
                   if backend in ("tpu", "hybrid")
-                  else {"shards": n_servers} if backend == "xla" else {})
+                  else {"shards": n_servers,
+                        "platform": devices[0].platform}
+                  if backend == "xla" else {})
         self.transfer = get_transfer(backend, **kwargs)
         self._initialized = True
         log.info("cluster up: %s transfer=%s", mesh_info(self.mesh), backend)

@@ -100,11 +100,13 @@ class _LossAccum:
             raise ValueError(f"_LossAccum fold must be >= 2, got {fold}")
         self._q = []
         self._fold = fold
+        self.count = 0            # scalars added
         self.peak_queued = 0
         self._window = DispatchWindow(bound)
 
     def add(self, x) -> None:
         x = jnp.asarray(x, jnp.float32)
+        self.count += 1
         self._q.append(x)
         self._window.push(x)
         self.peak_queued = max(self.peak_queued, len(self._q))
@@ -231,9 +233,18 @@ def _assemble_push(tf, cf, h_flat, v_flat):
     capacity scatter + batch gather + (B, d) multiply per family, ~25%
     of the measured step — both folded away here.)  Per-family pushes
     carry only real contributions; apply_push handles partial grad
-    dicts."""
-    return (PushSpec(tf, {"h": h_flat}, mean=True),
-            PushSpec(cf, {"v": v_flat}, mean=True))
+    dicts.
+
+    The two pushes write disjoint fields, so their order moves no value;
+    it moves the step's peak memory (PERF.md section 6, PR 34).  The
+    v-grads sum over a center's targets, the last thing the math
+    computes: pushed first, each push's batch work follows the math push
+    by push.  With h first the compiler sorts and sums the h batch while
+    the contexts' rows are still to be pulled, one ``(B, width)`` buffer
+    more at the peak (0.25 GB on the chip in ``cbow2m-b16k``;
+    ``tests/test_compile_v5e.py`` bounds the temporaries)."""
+    return (PushSpec(cf, {"v": v_flat}, mean=True),
+            PushSpec(tf, {"h": h_flat}, mean=True))
 
 
 def w2v_formatter(row: Dict[str, np.ndarray]) -> str:
@@ -591,6 +602,19 @@ class Word2Vec:
         num = self._numerics
         n_hot = self.table.n_hot
         gfields = tuple(self.access.grad_fields)
+        # row-write counter (telemetry on only: a step built without it
+        # returns no count, so the timed program carries none): the
+        # step's sparse pushes' distinct valid rows x fields, a fourth
+        # result, fetched with the loss
+        count_rows = getattr(self.transfer, "count_rows_written", None) \
+            if obs.get_registry().enabled else None
+
+        def apply_counted(state, pushes):
+            if count_rows is None:
+                return apply_fn(state, pushes), ()
+            with count_rows() as tape:
+                out = apply_fn(state, pushes)
+            return out, (sum(tape, jnp.int32(0)),)
 
         if self.stencil:
             @partial(jax.jit, donate_argnums=0)
@@ -599,13 +623,13 @@ class Word2Vec:
                 pushes, es, ec = grads_fn(
                     state, slot_of_vocab, alias_prob, alias_idx,
                     tokens, sent_id, center_pos, half, key)
-                out = apply_fn(state, pushes)
+                out, rows = apply_counted(state, pushes)
                 if num is not None:
                     obs_numerics.stage_step(
                         num, state, out,
                         obs_numerics.spec_stats(pushes, n_hot),
                         es, ec, gfields)
-                return out, es, ec
+                return (out, es, ec, *rows)
 
             return obs.costs.track("w2v_step", step_st)
 
@@ -615,13 +639,13 @@ class Word2Vec:
             pushes, es, ec = grads_fn(
                 state, slot_of_vocab, alias_prob, alias_idx,
                 centers, contexts, ctx_mask, key)
-            out = apply_fn(state, pushes)
+            out, rows = apply_counted(state, pushes)
             if num is not None:
                 obs_numerics.stage_step(
                     num, state, out,
                     obs_numerics.spec_stats(pushes, n_hot),
                     es, ec, gfields)
-            return out, es, ec
+            return (out, es, ec, *rows)
 
         return obs.costs.track("w2v_step", step)
 
@@ -1786,6 +1810,7 @@ class Word2Vec:
         meter = Throughput()
         step_i = 0
         hogwild_dropped = 0
+        rows_written, rows_steps = 0.0, 0   # the sync step's row writes
         # telemetry plane ([worker] telemetry, obs/): reuse an outer
         # recorder (bench harness, trainer) or own one for this call.
         # The Throughput meter and transfer ledger keep their own
@@ -1912,6 +1937,7 @@ class Word2Vec:
                 # target pairs, i.e. exactly the corpus sizes this
                 # optimization targets.
                 es_q, ec_q = _LossAccum(dispatch_bound), _LossAccum(None)
+                rows_q = _LossAccum(None)
 
                 def run_single(fields, n_words):
                     nonlocal state, frozen, step_i
@@ -1921,7 +1947,9 @@ class Word2Vec:
                     if sync:
                         with obs.span("dispatch", steps=1,
                                       step=self._steps_dispatched):
-                            state, es, ec = self._step(state, *args)
+                            state, es, ec, *rows = self._step(state, *args)
+                        if rows:
+                            rows_q.add(rows[0])
                         # the step donates (deletes) the input state
                         # buffers; repoint the table at the live ones
                         # immediately so an abnormal exit (raise, Ctrl-C)
@@ -2041,6 +2069,8 @@ class Word2Vec:
                 with obs.span("loss_fetch"):
                     err_sum = es_q.total()
                     err_cnt = int(round(ec_q.total()))
+                    rows_written += rows_q.total()
+                    rows_steps += rows_q.count
             loss = err_sum / max(err_cnt, 1)
             losses.append(loss)
             log.info("iter %d: error %.5f  (%.0f words/s)",
@@ -2092,6 +2122,9 @@ class Word2Vec:
                 "stall_ms_per_step": meter.stall_ms_per_step(),
                 "words_per_sec": meter.rate(),
                 "pipeline_depth": self.pipeline_depth if pipelined else 0}
+            if rows_steps:
+                self.train_metrics["rows_written_per_step"] = \
+                    rows_written / rows_steps
             if pairs is not None and pairs.steps:
                 self.train_metrics["pairs_per_step"] = \
                     pairs.valid / pairs.steps

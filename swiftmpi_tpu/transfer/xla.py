@@ -21,6 +21,8 @@ irregularity and can win for small tables.
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
@@ -48,7 +50,23 @@ _REPLICA_BUDGET_BYTES = 256 << 20
 # follows from the shapes: ~116,000 slots are the crossover on that field
 # (measured: 13.98 against 14.90 ms at 122,880 slots, 12.84 against
 # 14.18 at 110,000).
+#
+# What the constant still decides (ROADMAP D10).  From static shapes, the
+# form of every push `write_back_form` does not answer ``head_rows`` for:
+# one-wide logistic rows, widths that keep the column-major default, a
+# row-sharded mesh, the CPU.  Where it does (PR 34) the rows written are
+# counted at run time, a `_HEAD_CHUNK` at a time, and the same constant
+# weighs them against one sweep (`_rmw_head_rows`; there a row's whole
+# read-modify-write costs ~107 ns and the crossover lies at ~132,000
+# rows, so the constant sweeps a little early).
 _ROW_WRITE_AS_SWEPT_BYTES = 31_000
+
+# `_rmw_head_rows` updates the valid head of a push this many slots at a
+# time: a chunk is one gather, one unsorted scatter a field, so a push
+# costs the per-row price of its distinct rows rounded up to a chunk, not
+# of its slots (v5e micro, PERF.md section 6, PR 34: ~92 ns a slot written
+# at 2,048 as at 4,096, which rounds a push of 2,000 rows up to twice it)
+_HEAD_CHUNK = 2048
 
 
 def _replica_R(capacity: int, width: int) -> int:
@@ -87,6 +105,65 @@ def _set_rows(field: jax.Array, rows: jax.Array, values: jax.Array,
                               indices_are_sorted=sweep)
 
 
+def _rmw_rows(fields: dict, rows: jax.Array, grads: dict, access,
+              sweep: bool, inv=None) -> dict:
+    """The push's read-modify-write at ``rows`` (distinct in-range rows,
+    and ``capacity`` padding, which reads row 0 and drops): gather the
+    touched ``fields``' rows, `access.apply_push` them with ``grads``
+    (times ``inv``, a mean push's ``1 / count`` a row, where the caller
+    left that to be fused in here), write the updated ones back
+    (`_set_rows`)."""
+    capacity = next(iter(fields.values())).shape[0]
+    safe = jnp.where(rows < capacity, rows, 0)
+    current = {f: jnp.take(x, safe, axis=0) for f, x in fields.items()}
+    if inv is not None:
+        grads = {f: g * inv for f, g in grads.items()}
+    updated = access.apply_push(current, grads)
+    return {f: _set_rows(x, rows, updated[f], sweep) if f in updated else x
+            for f, x in fields.items()}
+
+
+def _rmw_head_rows(fields: dict, rows: jax.Array, grads: dict, access,
+                   n: jax.Array, may_sweep: bool, inv=None) -> dict:
+    """`_rmw_rows` for ascending ``rows`` whose ``n`` valid ones stand at
+    the head, ``capacity`` behind them: the head alone is read, updated
+    and written, row by row, a `_HEAD_CHUNK` of slots at a time, so the
+    cost follows the distinct rows of the push and not its slots.  The
+    rows of two chunks are distinct, so a chunk reads what no other
+    wrote.  ``may_sweep``: the shapes allow a head so long that one sweep
+    of the fields is cheaper (`_ROW_WRITE_AS_SWEPT_BYTES`), and which it
+    is shows only at run time.  Same rows, same values either way."""
+    B = rows.shape[0]
+    capacity = next(iter(fields.values())).shape[0]
+    chunk = min(_HEAD_CHUNK, B)
+    n_chunks = (n + chunk - 1) // chunk
+
+    def by_chunks(fields):
+        def body(i, fields):
+            # the last chunk of a ragged batch starts early: the slots
+            # it shares with the chunk before are that chunk's
+            at = jnp.minimum(i * chunk, B - chunk)
+
+            def cut(x):
+                return jax.lax.dynamic_slice_in_dim(x, at, chunk)
+            mine = at + jnp.arange(chunk, dtype=jnp.int32) >= i * chunk
+            return _rmw_rows(fields, jnp.where(mine, cut(rows), capacity),
+                             {f: cut(g) for f, g in grads.items()}, access,
+                             sweep=False,
+                             inv=None if inv is None else cut(inv))
+        return jax.lax.fori_loop(0, n_chunks, body, fields)
+
+    if not may_sweep:
+        return by_chunks(fields)
+    swept = max(x.shape[0] * x.shape[1] * x.dtype.itemsize
+                for x in fields.values())
+    return jax.lax.cond(
+        n_chunks <= swept // (chunk * _ROW_WRITE_AS_SWEPT_BYTES), by_chunks,
+        lambda fields: _rmw_rows(fields, rows, grads, access, sweep=True,
+                                 inv=inv),
+        fields)
+
+
 def _after(x, done):
     """``x``, not to be touched before ``done`` exists (``None``: no
     wait): orders two uses of whole fields that share no data."""
@@ -96,7 +173,8 @@ def _after(x, done):
 class XlaTransfer(Transfer):
     name = "xla"
 
-    def __init__(self, dense_apply: bool | None = None, shards: int = 1):
+    def __init__(self, dense_apply: bool | None = None, shards: int = 1,
+                 platform: str | None = None):
         """``dense_apply``: True forces the dense full-table push, False
         forces the sort-based sparse push, None (default) picks per call —
         dense when the push batch is at least half the table capacity.
@@ -108,16 +186,42 @@ class XlaTransfer(Transfer):
         ``shards``: how many devices the table's rows are split over
         (``Cluster`` passes its server count).  A row-sharded scatter
         runs on every shard, with the whole batch against ``capacity /
-        shards`` rows, so `write_back_form` weighs a shard's rows."""
+        shards`` rows, so `write_back_form` weighs a shard's rows.
+
+        ``platform``: of the devices the table lives on (``Cluster``
+        passes its devices'; default: this process's first device's).
+        The write-back's costs were measured on TPUs, so what they
+        select is selected there alone."""
         self.dense_apply = dense_apply
         self.shards = max(1, int(shards))
-        #: field -> ``"per_row"`` | ``"sweep"``: the form the write-back
-        #: of that field's last traced sparse push took
+        self.platform = platform or jax.devices()[0].platform
+        #: field -> ``"per_row"`` | ``"sweep"`` | ``"head_rows"``: the
+        #: form the write-back of that field's last traced sparse push took
         self.resolved_write_back: dict = {}
+        #: while a list (`count_rows_written`), every push traced appends
+        #: its row writes: distinct valid rows x fields touched
+        self.rows_written: list | None = None
         # wire ledger (api.py): XLA chooses the actual collectives, so
         # wire_bytes counts the representation-level payload — sparse:
         # valid rows x (index + grad row); dense: capacity x grad row
         self.count_traffic = False
+
+    @contextlib.contextmanager
+    def count_rows_written(self):
+        """The list every push traced inside the block appends its row
+        writes to (a traced int32 each): what a step built with telemetry
+        on returns beside its loss."""
+        self.rows_written = tape = []
+        try:
+            yield tape
+        finally:
+            self.rows_written = None
+
+    def _count_rows_written(self, rows, touched) -> None:
+        """A push's row writes onto the tape, if one is held: ``rows()``,
+        its distinct valid rows, times the fields it touches."""
+        if self.rows_written is not None:
+            self.rows_written.append(rows() * len(touched))
 
     def _membership_changed(self) -> None:
         """Elastic membership (api.py): XLA keeps no compiled caches
@@ -213,6 +317,10 @@ class XlaTransfer(Transfer):
                 else:
                     acc = _scatter(g, width)
                     dense_grads[f] = acc * inv if mean else acc
+        self._count_rows_written(
+            lambda: jnp.sum(jnp.zeros((capacity,), jnp.bool_).at[safe].set(
+                True, mode="drop"), dtype=jnp.int32),
+            access.touched_fields(grads))
         with obs.named_scope("apply"):
             new_fields = access.apply_push(state, dense_grads)
             out = dict(state)
@@ -277,8 +385,10 @@ class XlaTransfer(Transfer):
                     g, mode="drop")
                 combined[f] = acc * inv if mean else acc
             is_owner = valid & (owner == pos)
+        touched = access.touched_fields(grads)
+        self._count_rows_written(
+            lambda: jnp.sum(is_owner, dtype=jnp.int32), touched)
         with obs.named_scope("apply"):
-            touched = access.touched_fields(grads)
             safe_own = jnp.where(is_owner, slots, 0)
             current = {f: jnp.take(state[f], safe_own, axis=0)
                        for f in touched}
@@ -311,6 +421,26 @@ class XlaTransfer(Transfer):
         if B == 0:
             return dict(state)
         valid = slots >= 0
+        # only the fields this push's grad families actually update are
+        # gathered and re-scattered (a partial push must not round-trip
+        # the untouched fields' rows through HBM for nothing)
+        touched = access.touched_fields(grads)
+        written = [state[f] for f in touched]
+        form = self.write_back_form(B, written)
+        self.resolved_write_back.update(dict.fromkeys(touched, form))
+        # the shapes say whether a head can be so long that one sweep is
+        # cheaper; whether it is, the count at run time
+        may_sweep = self._static_form(B, written) == "sweep"
+        if form == "head_rows" and may_sweep:
+            # A push that large sums its batch after the state it is given
+            # exists, i.e. after the push before it, whichever fields that
+            # wrote: a loop and a conditional bind the scheduler less than
+            # the sweep's one fusion did, and left to itself it sorts and
+            # sums one push's batch before another's gradients are
+            # computed, one (B, width) buffer more at the step's peak
+            # (0.25 GB on the chip in cbow2m-b16k, PERF.md section 6).
+            state, grads = jax.lax.optimization_barrier(
+                (dict(state), dict(grads)))
         with obs.named_scope("dedup"):
             # Sort so duplicates are adjacent; padding (-1 -> capacity)
             # sorts last and is dropped by OOB scatter below.
@@ -345,27 +475,29 @@ class XlaTransfer(Transfer):
                 acc = jnp.zeros((B, width), g.dtype)
                 acc = acc.at[seg_ids].add(g, mode="drop",
                                           indices_are_sorted=True)
-                combined[f] = acc * inv if mean else acc
+                # `head_rows` multiplies where it reads the rows: as an
+                # operand of its loop and conditional the product would
+                # be a (B, width) buffer of its own
+                combined[f] = (acc * inv if mean and form != "head_rows"
+                               else acc)
 
-        # only the fields this push's grad families actually update are
-        # gathered and re-scattered (a partial push must not round-trip
-        # the untouched fields' rows through HBM for nothing)
-        touched = access.touched_fields(grads)
-        form = self.write_back_form(B, [state[f] for f in touched])
-        self.resolved_write_back.update(dict.fromkeys(touched, form))
         # Unused segments' representatives stay == capacity: OOB, dropped.
         # rep_slots are ascending AND one-per-segment by construction
         # (duplicates exist only among the dropped capacity-fill tail), so
-        # either form of the write-back may take them.
+        # any form of the write-back may take them.
         out = dict(state)
-        if form == "sweep":
+        n_rows = jnp.sum(rep_valid, dtype=jnp.int32)
+        self._count_rows_written(lambda: n_rows, touched)
+        if form != "per_row":
+            fields = {f: state[f] for f in touched}
             with obs.named_scope("apply"):
-                current = {f: jnp.take(state[f], safe_rep, axis=0)
-                           for f in touched}
-                updated = access.apply_push(current, combined)
-                for f in updated:
-                    out[f] = _set_rows(state[f], rep_slots, updated[f],
-                                       sweep=True)
+                if form == "sweep":
+                    out.update(_rmw_rows(fields, rep_slots, combined, access,
+                                         sweep=True))
+                else:
+                    out.update(_rmw_head_rows(
+                        fields, rep_slots, combined, access, n_rows,
+                        may_sweep, inv=inv))
                 return bump_row_versions(out, state, rep_slots)
         # Per row.  Where a field is column-major in HBM (a tall array
         # whose stored width is no multiple of 128: `access.stored_width`
@@ -396,9 +528,21 @@ class XlaTransfer(Transfer):
             return bump_row_versions(out, state, rep_slots)
 
     def write_back_form(self, n: int, fields) -> str:
-        """``"per_row"`` or ``"sweep"``: the cheaper way to write ``n``
-        ascending rows back into each of ``fields``, from static shapes
+        """How to write ``n`` ascending slots, the distinct valid rows at
+        their head, back into each of ``fields``: ``"head_rows"``
+        (`_rmw_head_rows`: the cost of the rows, counted at run time)
+        where every field is one it takes — f32 rows of whole 128-lane
+        tiles (row-major by the compiler's default, so a loop around the
+        scatter copies no field), local to one TPU; else the cheaper of
+        ``"per_row"`` and ``"sweep"`` for ``n`` slots, from static shapes
         alone (`_ROW_WRITE_AS_SWEPT_BYTES` has the measurement)."""
+        if (self.shards == 1 and self.platform == "tpu"
+                and all(f.shape[1] % 128 == 0 and f.dtype == jnp.float32
+                        for f in fields)):
+            return "head_rows"
+        return self._static_form(n, fields)
+
+    def _static_form(self, n: int, fields) -> str:
         swept = sum((f.shape[0] // self.shards) * f.shape[1]
                     * f.dtype.itemsize for f in fields)
         return ("per_row" if n * len(fields) * _ROW_WRITE_AS_SWEPT_BYTES

@@ -348,9 +348,15 @@ def train_metrics(toy):
         t = toy(sg)
         metrics = t.model.train_metrics
         for key in ("stall_ms_per_step", "pairs_per_step",
-                    "pair_fill_share"):
+                    "pair_fill_share", "rows_written_per_step"):
             assert isinstance(metrics[key], (int, float)), (sg, key)
             assert math.isfinite(metrics[key]), (sg, key)
+        # at most every slot of the step's two pushes a new row, each
+        # written to a parameter and its accumulator
+        slots = [b.ctx_mask.size + b.centers.size * (1 + t.model.negative)
+                 for b in t.batcher.batches]
+        assert 0 < metrics["rows_written_per_step"] <= 2 * max(slots) * (
+            2 * t.model.window if sg else 1)
         valid = [b.ctx_mask.sum() for b in t.batcher.batches]
         assert metrics["pairs_per_step"] == pytest.approx(np.mean(valid))
         assert 0 < metrics["pair_fill_share"] <= 100

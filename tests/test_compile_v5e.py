@@ -79,29 +79,13 @@ def _load_conf(path, config, minibatch):
     return global_config().load_conf(str(path)).parse()
 
 
-SWEEP, PER_ROW = "sweep", "per_row"
+HEAD_ROWS = dict.fromkeys(("h", "h2sum", "v", "v2sum"), "head_rows")
 
 
-@pytest.mark.parametrize("cell, forms, temp_gib", [
-    # ~5,000-row pushes: every field written row by row
-    ("cbow2m-demo", dict(h=PER_ROW, h2sum=PER_ROW, v=PER_ROW,
-                         v2sum=PER_ROW), 0.01),
-    # 180,224 / 163,840 slots: every field swept
-    ("cbow2m-b16k", dict(h=SWEEP, h2sum=SWEEP, v=SWEEP, v2sum=SWEEP), 1.0),
-    # 122,880 target slots swept, 20,480 input slots row by row
-    ("sg2m-b2k", dict(h=SWEEP, h2sum=SWEEP, v=PER_ROW, v2sum=PER_ROW), 0.7),
-])
-def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
-                                  monkeypatch, cell, forms, temp_gib):
-    """A word2vec cell's train step at 2,340,001 rows on one v5e chip, the
-    model built by the calls the benchmark's families make.  The 300-wide
-    rows are stored on 384 lanes (`access.stored_width`), so with no
-    layout asked for the four fields come in and go out row-major
-    (``{1,0:T(8,128)}``, the compiler's default at that width) and
-    aliased, NO whole field is copied (the 300-wide table: 11 copies a
-    step, 3.37 GiB of temporaries = one padded row-major field, ~108 ms),
-    the step fits the chip, and each push takes the write-back form its
-    size asks for."""
+def _w2v_step(topo, tmp_path, monkeypatch, cell, chips=1):
+    """(cluster, model, compiled train step) of a word2vec cell on
+    ``chips`` described v5e chips, the model built by the calls the
+    benchmark's families make."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from swiftmpi_tpu.cluster.cluster import Cluster
@@ -118,7 +102,7 @@ def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
 
     monkeypatch.setattr(sparse_table.SparseTable, "_init_state",
                         _shapes_only)
-    cluster = Cluster(conf, devices=list(topo.devices)[:1]).initialize()
+    cluster = Cluster(conf, devices=list(topo.devices)[:chips]).initialize()
     model = Word2Vec(cluster=cluster)
     # Word2Vec.build_from_vocab's capacity rule
     capacity = max(64, int(vocab * 1.3 / cluster.n_servers) + 1)
@@ -136,7 +120,34 @@ def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
         shape((centers,), jnp.int32), shape((centers, 2 * window), jnp.int32),
         shape((centers, 2 * window), jnp.bool_),
         jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=rep)).compile()
+    return cluster, model, compiled
+
+
+@pytest.mark.parametrize("cell, sweeps, temp_gib", [
+    # ~5,000-slot pushes: the shapes rule the sweep out, the head's chunks
+    # are all there is
+    ("cbow2m-demo", 0, 0.01),
+    # 180,224 / 163,840 slots: chunks or one sweep, by the count
+    ("cbow2m-b16k", 4, 1.0),
+    # 122,880 target slots: either; 20,480 input slots: chunks alone
+    ("sg2m-b2k", 2, 0.7),
+])
+def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
+                                  monkeypatch, cell, sweeps, temp_gib):
+    """A word2vec cell's train step at 2,340,001 rows on one v5e chip.
+    The 300-wide rows are stored on 384 lanes (`access.stored_width`), so
+    with no layout asked for the four fields come in and go out row-major
+    (``{1,0:T(8,128)}``, the compiler's default at that width) and
+    aliased, NO whole field is copied (the 300-wide table: 11 copies a
+    step, 3.37 GiB of temporaries = one padded row-major field, ~108 ms)
+    — not around the loop over the head's chunks and not around the
+    conditional either (PR 34) —, the step fits the chip, and every push
+    writes back the rows at its head (`XlaTransfer.write_back_form`):
+    row by row inside the loop, swept only in the branch the shapes
+    allow."""
+    cluster, model, compiled = _w2v_step(topo, tmp_path, monkeypatch, cell)
     text, mem = compiled.as_text(), compiled.memory_analysis()
+    capacity = model.table.capacity
 
     assert (capacity, model.len_vec, model.row_width) == (2_340_001, 300, 384)
     field = rf"f32\[{capacity},384\]"
@@ -156,11 +167,30 @@ def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert total <= 15.75 * GIB, f"{total / GIB:.2f} GiB"
-    assert cluster.transfer.resolved_write_back == forms
-    scatters = re.findall(rf"= {field}\S* scatter\(.*apply/scatter", text)
-    assert len(scatters) == 4
+    assert cluster.transfer.resolved_write_back == HEAD_ROWS
+    # a scatter of each field in a loop body, row by row, and where the
+    # shapes allow it one more in the conditional's other branch, swept
+    scatters = re.findall(rf"= {field}\S* scatter\(.*apply/.*scatter", text)
     swept = sum("indices_are_sorted=true" in s for s in scatters)
-    assert swept == list(forms.values()).count(SWEEP)
+    assert (len(scatters) - swept, swept) == (4, sweeps)
+    assert len(re.findall(r" while\(.*apply/", text)) == 2
+    assert len(re.findall(r" conditional\(.*apply/", text)) == sweeps // 2
+
+
+def test_w2v_x4_step_keeps_the_sweep(topo, no_compile_cache, tmp_path,
+                                     monkeypatch):
+    """``gnews3m-x4-b64k``'s table is row-sharded over four chips: its
+    pushes keep the form the shapes choose, one sweep a field, no loop."""
+    cluster, model, compiled = _w2v_step(topo, tmp_path, monkeypatch,
+                                         "gnews3m-x4-b64k", chips=4)
+    assert cluster.transfer.shards == 4
+    assert cluster.transfer.resolved_write_back == dict.fromkeys(
+        HEAD_ROWS, "sweep")
+    text = compiled.as_text()
+    assert " while(" not in text and " conditional(" not in text
+    scatters = re.findall(r"= f32\[\d+,384\]\S* scatter\(.*apply/", text)
+    assert len(scatters) == 4
+    assert all("indices_are_sorted=true" in s for s in scatters)
 
 
 def test_table_is_built_within_its_own_size(topo, no_compile_cache,

@@ -725,6 +725,38 @@ def test_pair_counters_equal_the_batches_fed(sg, worker, tmp_path,
 
 
 @pytest.mark.parametrize("sg", [0, 1], ids=["cbow", "sg"])
+def test_rows_written_counts_each_push_s_distinct_rows(sg, monkeypatch,
+                                                       tmp_path, devices8):
+    """``rows_written_per_step``: every push of a step adds its
+    distinct valid slots times the fields it touches (a parameter and its
+    AdaGrad accumulator), counted on the device and fetched with the loss;
+    here against the slots each push was handed, taken out by a callback."""
+    import jax
+
+    from swiftmpi_tpu.transfer.xla import XlaTransfer
+
+    seen = []
+    real = XlaTransfer.push
+
+    def spying(self, state, slots, grads, access, mean=False):
+        fields = len(access.touched_fields(grads))
+        jax.debug.callback(lambda s: seen.append(
+            fields * np.unique(s[s >= 0]).size), slots)
+        return real(self, state, slots, grads, access, mean)
+
+    monkeypatch.setattr(XlaTransfer, "push", spying)
+    model, fed = _pairs_run(sg, {}, 1, tmp_path)
+    jax.effects_barrier()
+    steps = len(fed.valid)
+    # two pushes a step, sparse or (at this toy capacity, skip-gram's
+    # target push) dense
+    assert steps > 4 and len(seen) == 2 * steps
+    assert model.train_metrics["rows_written_per_step"] == pytest.approx(
+        sum(seen) / steps, rel=1e-6)
+    assert min(seen) > 0
+
+
+@pytest.mark.parametrize("sg", [0, 1], ids=["cbow", "sg"])
 def test_pair_counters_absent_with_telemetry_off(sg, monkeypatch, tmp_path,
                                                  devices8):
     from swiftmpi_tpu.models import word2vec
@@ -738,6 +770,8 @@ def test_pair_counters_absent_with_telemetry_off(sg, monkeypatch, tmp_path,
     assert fed.valid and not obs.get_registry().enabled
     assert "pairs_per_step" not in model.train_metrics
     assert "pair_fill_share" not in model.train_metrics
+    # ... and the step was built without the row-write counter
+    assert "rows_written_per_step" not in model.train_metrics
 
 
 def test_uncounted_batches_export_no_pair_series(tmp_path, devices8):

@@ -226,7 +226,11 @@ def test_w2v_skipgram_grads_match_numpy():
         model._alias_idx, jnp.asarray(batch.centers),
         jnp.asarray(batch.contexts), jnp.asarray(batch.ctx_mask), key)
     es, ec = float(es), int(ec)
-    (tslots_flat, hgrads, hmean), (cslots_flat, vgrads, vmean) = pushes
+    # one push per gradient family, whichever comes first
+    by_family = {next(iter(grads)): (slots, grads, mean)
+                 for slots, grads, mean in pushes}
+    (tslots_flat, hgrads, hmean), (cslots_flat, vgrads, vmean) = \
+        by_family["h"], by_family["v"]
     assert hmean and vmean     # families carry raw sums + mean-norm flag
     tslots_flat, cslots_flat = np.asarray(tslots_flat), np.asarray(cslots_flat)
     gh, gv = np.asarray(hgrads["h"]), np.asarray(vgrads["v"])

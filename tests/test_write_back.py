@@ -1,15 +1,20 @@
-"""The sparse push's row write-back (``transfer/xla.py``): both forms
-write the ``local`` oracle's rows, bit for bit.
+"""The sparse push's row write-back (``transfer/xla.py``): every form
+writes the ``local`` oracle's rows, bit for bit.
 
 ``XlaTransfer.write_back_form`` chooses, from static shapes, between
 writing a push's rows one by one (``per_row``) and one sweep of the whole
-field (``sweep``): a choice of device time (PERF.md section 6, PR 30),
-never of values.  Every case here runs the push in BOTH forms, twice:
-op by op, where the access rule's arithmetic is the oracle's own sequence
-of primitives and every field must equal the numpy oracle's bit for bit
-(``assert_array_equal``); and under ``jit``, as a train step runs it,
-where the two forms must equal each other bit for bit and stay within
-one rounding of the oracle (XLA fuses the rule's arithmetic there).  The
+field (``sweep``) and, on the fields it can (PR 34: f32 rows of whole
+128-lane tiles, one TPU), writing the distinct rows at the head of the
+push alone, a chunk at a time, or sweeping, as their count says at run
+time (``head_rows``): a choice of device time (PERF.md section 6, PR 30,
+PR 34), never of values.  Every case here runs the push in EVERY form,
+twice: op by op, where the access rule's arithmetic is the oracle's own
+sequence of primitives and every field must equal the numpy oracle's bit
+for bit (``assert_array_equal``); and under ``jit``, as a train step runs
+it, where the forms must equal each other bit for bit and stay within
+one rounding of the oracle (XLA fuses the rule's arithmetic there; so it
+does op by op in ``head_rows``, whose loop body is one compiled program:
+held there as the jitted ones are).  The
 gradients are multiples of 1/64 and every slot repeats 1, 2 or 4 times,
 so the oracle's ``sum / count`` and the backend's ``sum * (1 / count)``
 are the same float.
@@ -26,8 +31,16 @@ from swiftmpi_tpu.transfer.local import LocalTransfer
 from swiftmpi_tpu.transfer.xla import XlaTransfer
 
 FORMS = ("per_row", "sweep")
+#: widths whose stored row is whole 128-lane tiles also take
+#: ``head_rows``: bare where the shapes rule the sweep out (the loop over
+#: the head's chunks alone), ``+`` / ``-`` where they do not and the count
+#: at run time picks the chunks / the sweep
+HEAD_FORMS = ("head_rows", "head_rows+", "head_rows-")
 SHARDS = 4
 CAP_PER_SHARD = 24
+#: slots a chunk of `_rmw_head_rows` here: the 12 distinct rows of a
+#: batch are two chunks, the second one ragged
+HEAD_CHUNK = 8
 
 
 def _batch(case, table, width, seed):
@@ -55,15 +68,18 @@ def _batch(case, table, width, seed):
 @pytest.mark.parametrize("sharded", [False, True],
                          ids=["one_device", "row_sharded_x4"])
 @pytest.mark.parametrize("mean", [False, True], ids=["sum", "mean"])
-@pytest.mark.parametrize("width", [1, 300])
+@pytest.mark.parametrize("width", [1, 300, 128, 384])
 @pytest.mark.parametrize("case", ["duplicates", "padding_mixed",
                                   "all_padding", "empty"])
 def test_write_back_forms_match_oracle_bitwise(case, width, mean, sharded,
                                                monkeypatch):
+    from swiftmpi_tpu.transfer import xla
+
     if sharded and len(jax.devices()) < SHARDS:
         pytest.skip(f"needs {SHARDS} virtual devices")
     mesh = ps_mesh(n=SHARDS) if sharded else None
-    # d = 1: the logistic table; 300 wide: word2vec's, pushing the ``h``
+    monkeypatch.setattr(xla, "_HEAD_CHUNK", HEAD_CHUNK)
+    # d = 1: the logistic table; else word2vec's, pushing the ``h``
     # family alone, so ``v`` / ``v2sum`` must come back untouched
     access = lr_access(0.3) if width == 1 else w2v_access(0.3, width)
     family = "val" if width == 1 else "h"
@@ -74,7 +90,8 @@ def test_write_back_forms_match_oracle_bitwise(case, width, mean, sharded,
     # rows are pushed at their STORED width (300 -> 384 lanes, zeros
     # beyond the vector: `access.stored_width`)
     stored = table.state[family].shape[1]
-    assert stored == (1 if width == 1 else 384)
+    assert stored == {1: 1, 300: 384, 128: 128, 384: 384}[width]
+    forms = FORMS + (HEAD_FORMS if stored % 128 == 0 else ())
     slots, grad = _batch(case, table, stored, seed=width + 7 * mean)
     state_np = {f: np.asarray(v) for f, v in table.state.items()}
     want = LocalTransfer().push(state_np, slots, {family: grad}, access,
@@ -84,14 +101,23 @@ def test_write_back_forms_match_oracle_bitwise(case, width, mean, sharded,
         return backend.push(state, slots, {family: grad}, access, mean=mean)
 
     eager, jitted = {}, {}
-    for form in FORMS:
+    for form_case in forms:
+        form = form_case.rstrip("+-")
         backend = XlaTransfer(dense_apply=False,
                               shards=SHARDS if sharded else 1)
         monkeypatch.setattr(backend, "write_back_form",
                             lambda n, fields, form=form: form)
+        if form == "head_rows":
+            # what the shapes say of the sweep, and what a row written
+            # weighs against it: here, the case's
+            monkeypatch.setattr(
+                backend, "_static_form", lambda n, fields, may=form_case
+                != form: "sweep" if may else "per_row")
+            monkeypatch.setattr(xla, "_ROW_WRITE_AS_SWEPT_BYTES",
+                                1 << 24 if form_case.endswith("-") else 1)
         out = push(backend, table.state, jnp.asarray(slots),
                    jnp.asarray(grad))
-        eager[form] = {f: np.asarray(v) for f, v in out.items()}
+        eager[form_case] = {f: np.asarray(v) for f, v in out.items()}
         if len(slots):
             touched = access.touched_fields([family])
             assert backend.resolved_write_back == dict.fromkeys(touched, form)
@@ -100,38 +126,75 @@ def test_write_back_forms_match_oracle_bitwise(case, width, mean, sharded,
         if sharded:
             assert out[family].sharding.is_equivalent_to(
                 table.state[family].sharding, 2)
-        jitted[form] = {f: np.asarray(v) for f, v in out.items()}
+        jitted[form_case] = {f: np.asarray(v) for f, v in out.items()}
         for f in access.fields:
-            np.testing.assert_array_equal(want[f], eager[form][f],
-                                          err_msg=f"{form}:{f}")
-            np.testing.assert_allclose(want[f], jitted[form][f], rtol=1e-6,
-                                       atol=1e-7, err_msg=f"jit {form}:{f}")
-    for f in access.fields:
-        np.testing.assert_array_equal(jitted["per_row"][f],
-                                      jitted["sweep"][f], err_msg=f)
+            if form == "head_rows":
+                np.testing.assert_array_equal(jitted[form_case][f],
+                                              eager[form_case][f],
+                                              err_msg=f"{form_case}:{f}")
+            else:
+                np.testing.assert_array_equal(want[f], eager[form_case][f],
+                                              err_msg=f"{form_case}:{f}")
+            np.testing.assert_allclose(want[f], jitted[form_case][f],
+                                       rtol=1e-6, atol=1e-7,
+                                       err_msg=f"jit {form_case}:{f}")
+    for form_case in forms:
+        for f in access.fields:
+            np.testing.assert_array_equal(jitted["sweep"][f],
+                                          jitted[form_case][f],
+                                          err_msg=f"{form_case}:{f}")
     if case in ("all_padding", "empty"):
         for f in access.fields:
             np.testing.assert_array_equal(state_np[f], jitted["sweep"][f])
 
 
-# rows of a push, the field it writes (a 300-wide row is stored on 384
-# lanes), the table's shards -> the form.  The first six are the
-# benchmark cells' own pushes (PERF.md section 4).
-@pytest.mark.parametrize("n, rows, width, shards, form", [
+# slots of a push, the field it writes (a 300-wide row is stored on 384
+# lanes), the table's shards, the devices' platform -> the form.  The
+# first six are the benchmark cells' own pushes (PERF.md section 4): on
+# the CPU the two forms the shapes choose between, on a TPU the head's
+# rows wherever the field is one `write_back_form` gives them for.
+CELLS = [
     (5_000, 2_340_001, 384, 1, "per_row"),       # cbow2m-demo, contexts
     (5_500, 2_340_001, 384, 1, "per_row"),       # cbow2m-demo, targets
     (20_480, 2_340_001, 384, 1, "per_row"),      # sg2m-b2k, inputs
     (122_880, 2_340_001, 384, 1, "sweep"),       # sg2m-b2k, targets
     (163_840, 2_340_001, 384, 1, "sweep"),       # cbow2m-b16k, contexts
     (655_360, 3_900_004, 384, 4, "sweep"),       # gnews3m-x4-b64k
-    (100_000, 3_900_004, 384, 1, "per_row"),     # the same rows, unsharded
-    (100_000, 3_900_004, 384, 4, "sweep"),       # ... a shard is a quarter
-    (1_000, 1 << 20, 1, 1, "sweep"),             # d = 1: a cheap sweep
-    (100, 1 << 20, 1, 1, "per_row"),
+]
+
+
+@pytest.mark.parametrize("n, rows, width, shards, platform, form", [
+    *((*c[:4], "cpu", c[4]) for c in CELLS),
+    (100_000, 3_900_004, 384, 1, "cpu", "per_row"),  # the same rows, unsharded
+    (100_000, 3_900_004, 384, 4, "cpu", "sweep"),    # ... a shard is a quarter
+    (1_000, 1 << 20, 1, 1, "cpu", "sweep"),          # d = 1: a cheap sweep
+    (100, 1 << 20, 1, 1, "cpu", "per_row"),
+    # one TPU, rows of whole 128-lane tiles: the head's rows, any size
+    *((*c[:4], "tpu", "head_rows") for c in CELLS[:5]),
+    (100_000, 3_900_004, 128, 1, "tpu", "head_rows"),
+    # a TPU, and everything else keeps the shapes' answer: four shards,
+    # 300 wide (column-major by default), one wide
+    (655_360, 3_900_004, 384, 4, "tpu", "sweep"),
+    (100_000, 3_900_004, 384, 4, "tpu", "sweep"),
+    (20_480, 2_340_001, 300, 1, "tpu", "per_row"),
+    (163_840, 2_340_001, 300, 1, "tpu", "sweep"),
+    (1_000, 1 << 20, 1, 1, "tpu", "sweep"),
+    (100, 1 << 20, 1, 1, "tpu", "per_row"),
 ])
-def test_write_back_form_follows_the_shapes(n, rows, width, shards, form):
+def test_write_back_form_follows_the_shapes(n, rows, width, shards, platform,
+                                            form):
     fields = [jax.ShapeDtypeStruct((rows, width), jnp.float32)] * 2
-    assert XlaTransfer(shards=shards).write_back_form(n, fields) == form
+    backend = XlaTransfer(shards=shards, platform=platform)
+    assert backend.write_back_form(n, fields) == form
+
+
+def test_write_back_form_wants_f32_rows_and_names_its_platform():
+    """A bf16 field keeps the shapes' answer on a TPU too, and a backend
+    nobody told takes the platform of this process's devices."""
+    rows = [jax.ShapeDtypeStruct((2_340_001, 384), jnp.bfloat16)] * 2
+    assert XlaTransfer(platform="tpu").write_back_form(5_000, rows) \
+        == "per_row"
+    assert XlaTransfer().platform == jax.devices()[0].platform
 
 
 def test_span_push_writes_row_by_row(monkeypatch):
