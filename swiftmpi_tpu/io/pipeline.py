@@ -25,7 +25,7 @@ loop shares:
 * time the consumer spends blocked on an empty queue is recorded as
   **host stall** (``stats().stall_s``) — the quantity the pipeline
   exists to drive to zero.  ``utils.timers.Throughput`` reports it as
-  ``host_stall_ms`` next to ``device_ms``.
+  ``host_stall_ms``.
 
 Bounding the *output* side (in-flight dispatches the consumer issues
 against prefetched inputs) is the consumer's job — see

@@ -270,8 +270,6 @@ class GloVe:
             def _tel_sample(reg, _m=meter):
                 reg.counter("train/host_stall_ms_total").set_total(
                     _m.host_stall_ms())
-                reg.counter("train/device_ms_total").set_total(
-                    _m.device_ms())
             tel_rec.add_sampler(_tel_sample)
         if self.numerics_on and tel_rec is not None:
             self._arm_numerics(tel_rec)
@@ -341,7 +339,6 @@ class GloVe:
             log.info("glove iter %d: %d cells  loss %.6f", it, n, mean_loss)
         self.train_metrics = {
             "host_stall_ms": meter.host_stall_ms(),
-            "device_ms": meter.device_ms(),
             "stall_ms_per_step": meter.stall_ms_per_step(),
             "pipeline_depth": self.pipeline_depth}
         if self._numerics is not None:

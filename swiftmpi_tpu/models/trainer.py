@@ -234,45 +234,55 @@ class Trainer:
 
         return obs.costs.track("trainer_step", train_step)
 
-    def step(self, state: TrainState, tokens) -> Tuple[TrainState,
-                                                       jax.Array]:
-        faults.step_event(self._host_steps)
-        self._host_steps += 1
-        if self._step_fn is None:
-            self._step_fn = self._build_step()
-        if self.mesh is not None:
-            want = NamedSharding(self.mesh, P("data", None))
-            if not (isinstance(tokens, jax.Array)
-                    and tokens.sharding == want):
-                # reshard whatever we got so dp is never silently
-                # dropped; booked as HOST STALL — this is the H2D
-                # transfer run() hides by pre-transferring on the
-                # producer thread (pre-transferred tokens skip this
-                # branch entirely).  Multi-process: host tokens are
-                # this process's LOCAL rows of the global batch
-                # (device_put would wrongly assume the same full value
-                # on every host)
-                with self.meter.stalling():
-                    if jax.process_count() > 1:
-                        tokens = jax.make_array_from_process_local_data(
-                            want, np.asarray(tokens))
-                    else:
-                        tokens = jax.device_put(jnp.asarray(tokens), want)
-        elif not isinstance(tokens, jax.Array):
+    def step(self, state: TrainState, tokens, _book=None
+             ) -> Tuple[TrainState, jax.Array]:
+        """One train step.  Host spans, siblings in this order:
+        ``step_prep`` (the step function and sharding checks), ``h2d``
+        (host tokens only), ``dispatch``, ``step_book`` (counters, meter,
+        recorder, publisher, controller, and ``run``'s own ``_book``)."""
+        n = self._host_steps
+        reshard = False
+        with obs.span("step_prep", step=n):
+            faults.step_event(n)
+            self._host_steps += 1
+            if self._step_fn is None:
+                self._step_fn = self._build_step()
+            if self.mesh is not None:
+                want = NamedSharding(self.mesh, P("data", None))
+                reshard = not (isinstance(tokens, jax.Array)
+                               and tokens.sharding == want)
+        if reshard:
+            # reshard whatever we got so dp is never silently dropped;
+            # booked as HOST STALL — this is the H2D transfer run() hides
+            # by pre-transferring on the producer thread (pre-transferred
+            # tokens skip this branch entirely).  Multi-process: host
+            # tokens are this process's LOCAL rows of the global batch
+            # (device_put would wrongly assume the same full value on
+            # every host)
+            with self.meter.stalling(), obs.span("h2d"):
+                if jax.process_count() > 1:
+                    tokens = jax.make_array_from_process_local_data(
+                        want, np.asarray(tokens))
+                else:
+                    tokens = jax.device_put(jnp.asarray(tokens), want)
+        elif self.mesh is None and not isinstance(tokens, jax.Array):
             with obs.span("h2d"):
                 tokens = jnp.asarray(tokens)
-        with obs.span("dispatch"):
+        with obs.span("dispatch", step=n):
             params, opt_state, step, loss, stats = self._step_fn(
                 state.params, state.opt_state, state.step, tokens)
-        if self.cfg.n_experts and obs.get_registry().enabled:
-            self._stats.append(stats)
-        self.meter.record(int(np.prod(tokens.shape)))
-        obs.record_step(1)
-        out = TrainState(params, opt_state, step)
-        if self.serve_publisher is not None:
-            self.serve_publisher.on_steps(out.params, n=1)
-        if self.controller is not None:
-            self.controller.on_steps(1)
+        with obs.span("step_book", step=n):
+            if self.cfg.n_experts and obs.get_registry().enabled:
+                self._stats.append(stats)
+            self.meter.record(int(np.prod(tokens.shape)))
+            obs.record_step(1)
+            out = TrainState(params, opt_state, step)
+            if self.serve_publisher is not None:
+                self.serve_publisher.on_steps(out.params, n=1)
+            if self.controller is not None:
+                self.controller.on_steps(1)
+            if _book is not None:
+                _book(loss)
         return out, loss
 
     def run(self, state: TrainState, batches, pipeline: int = 0,
@@ -293,8 +303,9 @@ class Trainer:
         Host spans as ``Word2Vec.train`` gives them
         (``obs.catalog.HOST_SPANS``): ``train_setup`` from entry to the
         first ``next``, ``input_wait`` around every ``next``, ``h2d`` and
-        ``dispatch`` in ``step``, ``loss_fetch`` for the call's one
-        blocking fetch (the last loss, and with telemetry on the expert
+        ``step_prep``, ``dispatch`` and ``step_book`` in ``step``,
+        ``loss_fetch`` for the call's one blocking fetch (``loss_wait``
+        for the last loss, then with telemetry on the read of the expert
         layers' counters), ``train_finish`` from there to the return.
         ``self.train_metrics`` holds what the call counted.
         """
@@ -316,6 +327,11 @@ class Trainer:
                                     transfer=device_put_transfer(want))
             it = pipe
         losses = []
+
+        def book(loss):           # the loop's part of step_book
+            losses.append(loss)
+            window.push(loss)
+
         try:
             it = iter(it)
             while True:
@@ -332,9 +348,7 @@ class Trainer:
                     setup_span = None
                 if tokens is None:
                     break
-                state, loss = self.step(state, tokens)
-                losses.append(loss)
-                window.push(loss)
+                state, _ = self.step(state, tokens, book)
         finally:
             if setup_span is not None:
                 setup_span.__exit__(None, None, None)
@@ -342,9 +356,11 @@ class Trainer:
                 pipe.close()
                 self.pipeline_stats = pipe.stats()
         with obs.span("loss_fetch"):
+            # the wait first, so that it is the wait and the read a read
+            with obs.span("loss_wait"):
+                if losses:
+                    jax.block_until_ready(losses[-1])
             stats, self._stats = jax.device_get(self._stats), []
-            if losses:
-                jax.block_until_ready(losses[-1])
         with obs.span("train_finish"):
             steps = self._host_steps - steps0
             stall = self.meter.host_stall_ms() - stall0

@@ -113,14 +113,23 @@ class _LossAccum:
         if len(self._q) >= self._fold:
             self._q = [jnp.stack(self._q).sum()]
 
+    def wait(self) -> None:
+        """Block until the newest scalar is there: its completion implies
+        every queued step ran (the epoch's ``loss_wait``)."""
+        if self._q:
+            jax.block_until_ready(self._q[-1])
+
     def total(self) -> float:
         if not self._q:
             return 0.0
-        # drain the dispatch pipeline before issuing the stack program:
-        # the newest scalar's completion implies every queued step ran
-        jax.block_until_ready(self._q[-1])
+        # drain the dispatch pipeline before issuing the stack program
+        self.wait()
         self._window.clear()
-        return float(jnp.stack(self._q).sum())
+        out = float(jnp.stack(self._q).sum())
+        # read: let go of the scalars here, inside the epoch's fetch, and
+        # not when the caller's frame is torn down outside every span
+        self._q = []
+        return out
 
 
 class _PairCount:
@@ -1829,8 +1838,6 @@ class Word2Vec:
             def _tel_sample(reg, _m=meter):
                 reg.counter("train/host_stall_ms_total").set_total(
                     _m.host_stall_ms())
-                reg.counter("train/device_ms_total").set_total(
-                    _m.device_ms())
                 reg.gauge("train/words_per_sec").set(_m.rate())
             tel_rec.add_sampler(_tel_sample)
         # pair counters: host sums over the batches as rendered, only
@@ -1905,6 +1912,12 @@ class Word2Vec:
                 setup_span.__exit__(None, None, None)
                 setup_span = None
 
+        def close_epoch(it, err_sum, err_cnt):
+            loss = err_sum / max(err_cnt, 1)
+            losses.append(loss)
+            log.info("iter %d: error %.5f  (%.0f words/s)",
+                     it, loss, meter.rate())
+
         def to_device(fields):
             if pipelined:      # the producer thread put them: its `h2d`
                 return tuple(_dev(f) for f in fields)
@@ -1928,6 +1941,7 @@ class Word2Vec:
                 # hogwild groups its own dispatches; publish at epoch
                 # granularity (the mode's natural consistency point)
                 self._serve_on_steps(1)
+                close_epoch(it, err_sum, err_cnt)
             else:
                 # Per-batch loss scalars are QUEUED as device arrays
                 # and fetched once at epoch end: a float(es) per batch
@@ -1939,50 +1953,58 @@ class Word2Vec:
                 es_q, ec_q = _LossAccum(dispatch_bound), _LossAccum(None)
                 rows_q = _LossAccum(None)
 
+                # an item of the loop is input_wait, then four siblings:
+                # step_prep, h2d (to_device), dispatch, step_book
+
                 def run_single(fields, n_words):
                     nonlocal state, frozen, step_i
-                    self._key, sub = jax.random.split(self._key)
+                    n = self._steps_dispatched
+                    with obs.span("step_prep", step=n):
+                        self._key, sub = jax.random.split(self._key)
                     args = (self._slot_of_vocab, self._alias_prob,
                             self._alias_idx, *to_device(fields), sub)
-                    if sync:
-                        with obs.span("dispatch", steps=1,
-                                      step=self._steps_dispatched):
+                    rows = ()
+                    with obs.span("dispatch", steps=1, step=n):
+                        if sync:
                             state, es, ec, *rows = self._step(state, *args)
-                        if rows:
-                            rows_q.add(rows[0])
+                        else:
+                            # async/global variant, bounded-staleness
+                            # flavor (word2vec_global.h:577-651): grads
+                            # computed against a stale snapshot, pushes
+                            # land immediately; snapshot refreshes every
+                            # local_steps batches => bounded staleness.
+                            grads_fn, apply_fn = self._step
+                            pushes, es, ec = grads_fn(frozen, *args)
+                            state = apply_fn(state, pushes)
+                    with obs.span("step_book", step=n):
                         # the step donates (deletes) the input state
                         # buffers; repoint the table at the live ones
                         # immediately so an abnormal exit (raise, Ctrl-C)
                         # never strands the model with deleted arrays
                         self.table.state = state
-                    else:
-                        # async/global variant, bounded-staleness flavor
-                        # (word2vec_global.h:577-651): grads computed
-                        # against a stale snapshot, pushes land
-                        # immediately; snapshot refreshes every
-                        # local_steps batches => bounded staleness.
-                        grads_fn, apply_fn = self._step
-                        with obs.span("dispatch", steps=1,
-                                      step=self._steps_dispatched):
-                            pushes, es, ec = grads_fn(frozen, *args)
-                            state = apply_fn(state, pushes)
-                        self.table.state = state
-                        step_i += 1
-                        if step_i % self.local_steps == 0:
+                        if rows:
+                            rows_q.add(rows[0])
+                        if not sync:
+                            step_i += 1
+                            if step_i % self.local_steps == 0:
+                                frozen = state
+                        es_q.add(es)
+                        ec_q.add(ec)
+                        self._steps_dispatched += 1
+                        meter.record(n_words)
+                        obs.record_step(1)
+                        self._serve_on_steps(1)
+                        if self._control_on_steps(1):
+                            # an applied decision re-laid out the table
+                            # (or rebuilt the step): repoint the
+                            # loop-local state — and the async snapshot,
+                            # whose rows sit at pre-repartition slots —
+                            # at the remapped one
+                            state = self.table.state
                             frozen = state
-                    es_q.add(es)
-                    ec_q.add(ec)
-                    self._steps_dispatched += 1
-                    meter.record(n_words)
-                    obs.record_step(1)
-                    self._serve_on_steps(1)
-                    if self._control_on_steps(1):
-                        # an applied decision re-laid out the table (or
-                        # rebuilt the step): repoint the loop-local
-                        # state — and the async snapshot, whose rows sit
-                        # at pre-repartition slots — at the remapped one
-                        state = self.table.state
-                        frozen = state
+                        # the step's inputs are let go inside the span,
+                        # not with this frame after it
+                        del args, sub
 
                 def run_group(fields, n_words):
                     # update ORDER is preserved either way: a group runs
@@ -2001,25 +2023,27 @@ class Word2Vec:
                         run_single(tuple(f[0] for f in fields),
                                    n_words[0])
                         return
-                    fused = self._fused_for(L)
-                    self._key, sub = jax.random.split(self._key)
+                    n = self._steps_dispatched
+                    with obs.span("step_prep", step=n):
+                        fused = self._fused_for(L)
+                        self._key, sub = jax.random.split(self._key)
                     fields = to_device(fields)
-                    with obs.span("dispatch", steps=L,
-                                  step=self._steps_dispatched):
+                    with obs.span("dispatch", steps=L, step=n):
                         state, es, ec = fused(
                             state, self._slot_of_vocab, self._alias_prob,
                             self._alias_idx, *fields, sub)
-                    self.table.state = state
-                    es_q.add(es)
-                    ec_q.add(ec)
-                    self._steps_dispatched += L
-                    # a fused group is ONE dispatch but L train steps;
-                    # stall_ms_per_step stays per-step across fuse modes
-                    meter.record(sum(n_words), steps=L)
-                    obs.record_step(L)
-                    self._serve_on_steps(L)
-                    if self._control_on_steps(L):
-                        state = self.table.state
+                    with obs.span("step_book", step=n):
+                        self.table.state = state
+                        es_q.add(es)
+                        ec_q.add(ec)
+                        self._steps_dispatched += L
+                        # a fused group is ONE dispatch but L train steps;
+                        # stall_ms_per_step stays per-step across fuse modes
+                        meter.record(sum(n_words), steps=L)
+                        obs.record_step(L)
+                        self._serve_on_steps(L)
+                        if self._control_on_steps(L):
+                            state = self.table.state
 
                 items = self._epoch_items(batcher, batch_size, stencil,
                                           fuse, pairs)
@@ -2064,17 +2088,17 @@ class Word2Vec:
                                 pipe_stats[k] = max(pipe_stats[k], v)
                             elif k != "depth":
                                 pipe_stats[k] += v
-                # the epoch's one blocking fetch: waits for every queued
-                # step, then reads two scalars
+                # the epoch's one blocking fetch: loss_wait for every
+                # queued step, then the reads of the scalars and the
+                # epoch's line in the log
                 with obs.span("loss_fetch"):
+                    with obs.span("loss_wait"):
+                        es_q.wait()
                     err_sum = es_q.total()
                     err_cnt = int(round(ec_q.total()))
                     rows_written += rows_q.total()
                     rows_steps += rows_q.count
-            loss = err_sum / max(err_cnt, 1)
-            losses.append(loss)
-            log.info("iter %d: error %.5f  (%.0f words/s)",
-                     it, loss, meter.rate())
+                    close_epoch(it, err_sum, err_cnt)
             if checkpoint_path and (it + 1) % checkpoint_every == 0:
                 self.table.state = state
                 from swiftmpi_tpu.io.checkpoint import (npz_path,
@@ -2115,10 +2139,9 @@ class Word2Vec:
             # traffic counters ride along for bench detail fields
             self.train_metrics = {
                 "hogwild_skipped_tail_words": hogwild_dropped,
-                # host-stall vs device-time split (utils.timers.Throughput):
-                # which side of the step loop is the bottleneck
+                # time the loop spent waiting on input
+                # (utils.timers.Throughput)
                 "host_stall_ms": meter.host_stall_ms(),
-                "device_ms": meter.device_ms(),
                 "stall_ms_per_step": meter.stall_ms_per_step(),
                 "words_per_sec": meter.rate(),
                 "pipeline_depth": self.pipeline_depth if pipelined else 0}
@@ -2156,7 +2179,20 @@ class Word2Vec:
             if owns_rec and tel_rec is not None:
                 tel_rec.close()
                 obs.uninstall_recorder()
+                # its ring of step records goes here, inside the span,
+                # not with the frame after it
+                tel_rec = None
         return losses
+
+    def sampling_state(self):
+        """``(step_key, alias_prob, alias_idx)``: the key the next train
+        step will draw its negatives with (what ``train()`` splits off
+        ``_key`` for it; the call leaves the stream where it is) and the
+        sampler's alias tables over the vocabulary, so a caller can make
+        the step's draw again (``ops.sampling.sample_alias``) without
+        reaching for the private names."""
+        return (jax.random.split(self._key)[1], self._alias_prob,
+                self._alias_idx)
 
     def _hogwild_epoch(self, batcher, batch_size: int, meter) -> tuple:
         """One epoch in hogwild mode: group ``n_workers * local_steps``

@@ -123,24 +123,29 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Host span = TraceAnnotation + ``phase_ms{phase=<name>}`` sample."""
+    """Host span = TraceAnnotation + ``phase_ms{phase=<name>}`` sample.
+    The bookkeeping (the histogram's lookup and its sample) runs inside
+    the annotation, so two spans one after the other leave between them
+    only the calls that make them."""
 
-    __slots__ = ("_hist", "_ann", "_t0")
+    __slots__ = ("_reg", "_name", "_hist", "_ann", "_t0")
 
-    def __init__(self, hist, name: str, attrs: dict):
-        self._hist = hist
+    def __init__(self, reg, name: str, attrs: dict):
+        self._reg = reg
+        self._name = name
         self._ann = _host_profiler.annotate(name, **attrs)
 
     def __enter__(self):
         self._ann.__enter__()
+        self._hist = self._reg.histogram("phase_ms", phase=self._name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt_ms = (time.perf_counter() - self._t0) * 1e3
-        self._ann.__exit__(*exc)
         if self._hist is not None:
             self._hist.observe(dt_ms)
+        self._ann.__exit__(*exc)
         return False
 
     def drop(self):
@@ -165,7 +170,7 @@ def span(name: str, **attrs):
     reg = _REGISTRY
     if not reg.enabled:
         return _NULL_SPAN
-    return _Span(reg.histogram("phase_ms", phase=name), name, attrs)
+    return _Span(reg, name, attrs)
 
 
 # -- recorder install point -------------------------------------------------

@@ -54,8 +54,7 @@ SERIES = frozenset({
     # input pipeline (io/pipeline.py)
     "pipeline/produced", "pipeline/consumed", "pipeline/queue_depth",
     # training loops (word2vec/glove via Throughput sampler bridge)
-    "train/host_stall_ms_total", "train/device_ms_total",
-    "train/words_per_sec",
+    "train/host_stall_ms_total", "train/words_per_sec",
     # scalar slot lookups the traced step makes for its negatives,
     # mode=per_draw|per_vocab (ops/sampling.alias_slot_lookups)
     "train/sampler_slot_lookups",
@@ -174,9 +173,17 @@ UNSCOPED = "unscoped"
 #: ``obs.span`` names (host side: a TraceAnnotation on the profiler's
 #: clock + a ``phase_ms{phase=}`` sample).  ``render`` runs on the
 #: pipeline's producer thread; the rest on the thread that calls train().
+#: Inside one ``Word2Vec.train`` / ``Trainer.run`` call that thread is at
+#: any time in ``train_setup``, in an item of the loop (``input_wait``,
+#: then the siblings ``step_prep``, ``h2d``, ``dispatch``, ``step_book``),
+#: in ``loss_fetch`` (``loss_wait`` is its child: the one block on the
+#: queued steps) or in ``train_finish``.  The benchmark's ``trace_host``
+#: reader takes its span list from here, so a span declared here is
+#: credited there without an edit.
 HOST_SPANS = (
     "train_setup", "input_wait", "render", "h2d", "dispatch",
     "loss_fetch", "train_finish", "checkpoint_save", "checkpoint_restore",
+    "step_prep", "step_book", "loss_wait",
 )
 
 

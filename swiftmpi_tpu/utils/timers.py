@@ -57,12 +57,12 @@ class Throughput:
     """Cumulative items/sec meter since construction or last reset().
 
     A single wall-clock rate hides WHICH side of the step loop is the
-    bottleneck, so the meter also splits elapsed time into **host
-    stall** (time the consumer spent waiting on input — rendering, H2D
-    transfer, an empty prefetch queue; reported via ``add_stall`` /
-    the ``stalling()`` context manager) and **device time** (everything
-    else: dispatch + on-device compute).  ``stats()`` packages the
-    split for train metrics and bench detail fields.
+    bottleneck, so the meter also keeps the **host stall**: time the
+    consumer spent waiting on input — rendering, H2D transfer, an empty
+    prefetch queue; reported via ``add_stall`` / the ``stalling()``
+    context manager.  ``stats()`` packages it for train metrics.  (What
+    the device did in the rest of the time only a trace can say: the
+    ``loss_wait`` span and the benchmark's ``step.device_busy_ms``.)
     """
 
     def __init__(self):
@@ -94,10 +94,6 @@ class Throughput:
     def host_stall_ms(self) -> float:
         return self._stall_s * 1e3
 
-    def device_ms(self) -> float:
-        """Elapsed wall-clock minus host stall, in ms (clamped at 0)."""
-        return max(0.0, self._timer.elapsed() - self._stall_s) * 1e3
-
     def stall_ms_per_step(self) -> float:
         return self.host_stall_ms() / self._steps if self._steps else 0.0
 
@@ -106,7 +102,6 @@ class Throughput:
                 "steps": float(self._steps),
                 "rate": self.rate(),
                 "host_stall_ms": self.host_stall_ms(),
-                "device_ms": self.device_ms(),
                 "stall_ms_per_step": self.stall_ms_per_step()}
 
     def reset(self) -> None:

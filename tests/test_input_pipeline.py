@@ -183,10 +183,10 @@ def test_throughput_stall_split():
         time.sleep(0.02)
     assert m.host_stall_ms() >= 60.0
     assert m.stall_ms_per_step() == pytest.approx(m.host_stall_ms() / 3)
-    assert m.device_ms() >= 0.0
+    assert m.rate() > 0.0
     s = m.stats()
     assert set(s) == {"items", "steps", "rate", "host_stall_ms",
-                      "device_ms", "stall_ms_per_step"}
+                      "stall_ms_per_step"}
     assert s["items"] == 150.0 and s["steps"] == 3.0
     m.reset()
     assert m.host_stall_ms() == 0.0 and m.stall_ms_per_step() == 0.0
@@ -285,7 +285,7 @@ def test_pipeline_bit_identical_to_off(transfer, stencil, devices8):
     for m in (m_off, m_on):
         tm = m.train_metrics
         assert tm["host_stall_ms"] >= 0.0
-        assert tm["device_ms"] >= 0.0
+        assert tm["words_per_sec"] >= 0.0
         assert tm["stall_ms_per_step"] >= 0.0
 
 

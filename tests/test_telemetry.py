@@ -438,7 +438,7 @@ def test_w2v_run_emits_valid_telemetry(tmp_path, devices8):
     assert any("phase_ms{phase=dispatch}" in (r.get("hists") or {})
                for r in steps)
     # train samplers publish the throughput meter's split
-    assert "train/device_ms_total" in lines[-1]["counters"]
+    assert "train/host_stall_ms_total" in lines[-1]["counters"]
 
     # the run analyzer parses it and finds the dispatch phase
     _scripts_on_path()
