@@ -295,9 +295,12 @@ void smtpu_batcher_free(SmtpuBatcher* b) { delete b; }
 // ---- positional-stencil batcher -------------------------------------------
 //
 // Emits stream spans instead of per-pair rows: `tokens`/`sent_id` hold a
-// contiguous slice of the shuffled sentence stream (capacity S = batch_size
-// + 2*window — the unique gather working set), `center_pos`/`half` index
-// into it.  Expansion semantics match data/text.py's stencil_to_cbow; the
+// contiguous slice of the shuffled sentence stream (capacity S = `span`
+// positions, sized by the caller to hold batch_size centers under the
+// center gate: data/text.py span_positions; at least batch_size +
+// 2*window), `center_pos`/`half` index into it.  A batch closes at
+// batch_size centers; one that fills the span first closes short.
+// Expansion semantics match data/text.py's stencil_to_cbow; the
 // rng is consumed in exactly smtpu_batcher_next's per-position order (keep
 // coin, then shrink only if kept), so the expanded pair stream for a seed
 // equals the per-pair epoch's.  Do not interleave per-pair and stencil
@@ -306,11 +309,12 @@ void smtpu_batcher_free(SmtpuBatcher* b) { delete b; }
 // Output buffers: tokens (S,) int32, sent_id (S,) int32 (-1 = padding),
 // center_pos (batch_size,) int32 (-1 = padding), half (batch_size,) int32.
 // Returns admitted center count; 0 = epoch exhausted.
-int64_t smtpu_batcher_next_stencil(SmtpuBatcher* b, int64_t batch_size,
-                                   int32_t* tokens, int32_t* sent_id,
-                                   int32_t* center_pos, int32_t* half) {
+int64_t smtpu_batcher_next_span(SmtpuBatcher* b, int64_t batch_size,
+                                int64_t span, int32_t* tokens,
+                                int32_t* sent_id, int32_t* center_pos,
+                                int32_t* half) {
   const int W = b->window;
-  const int64_t S = batch_size + 2 * W;
+  const int64_t S = span;
   std::uniform_real_distribution<float> unif(0.0f, 1.0f);
   for (int64_t i = 0; i < S; i++) { tokens[i] = 0; sent_id[i] = -1; }
   for (int64_t i = 0; i < batch_size; i++) {

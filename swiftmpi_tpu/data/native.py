@@ -21,7 +21,9 @@ from typing import Iterator, List, Optional, Sequence
 
 import numpy as np
 
-from swiftmpi_tpu.data.text import CBOWBatch, StencilBatch, Vocab
+from swiftmpi_tpu.data.text import (CBOWBatch, StencilBatch, Vocab,
+                                     center_keep_mean, span_positions,
+                                     unpack_span)
 from swiftmpi_tpu.utils.logger import get_logger
 
 log = get_logger(__name__)
@@ -88,10 +90,10 @@ def _load_lib():
         lib.smtpu_batcher_next.restype = c.c_int64
         lib.smtpu_batcher_next.argtypes = [c.c_void_p, c.c_int64, c.c_void_p,
                                            c.c_void_p, c.c_void_p]
-        lib.smtpu_batcher_next_stencil.restype = c.c_int64
-        lib.smtpu_batcher_next_stencil.argtypes = [
-            c.c_void_p, c.c_int64, c.c_void_p, c.c_void_p, c.c_void_p,
-            c.c_void_p]
+        lib.smtpu_batcher_next_span.restype = c.c_int64
+        lib.smtpu_batcher_next_span.argtypes = [
+            c.c_void_p, c.c_int64, c.c_int64, c.c_void_p, c.c_void_p,
+            c.c_void_p, c.c_void_p]
         lib.smtpu_batcher_free.argtypes = [c.c_void_p]
         lib.smtpu_prefetcher_new.restype = c.c_void_p
         lib.smtpu_prefetcher_new.argtypes = [c.c_void_p, c.c_int64,
@@ -189,9 +191,11 @@ class NativeCBOWBatcher:
             self._keep = np.ascontiguousarray(
                 subsample_keep_prob(vocab.counts, sample), np.float32)
             keep_ptr = self._keep.ctypes.data
+            self.keep_mean = center_keep_mean(vocab.counts, self._keep)
         else:
             self._keep = None
             keep_ptr = None
+            self.keep_mean = 1.0
         self._seed = seed
         self._epoch_i = 0
         self._h = lib.smtpu_batcher_new(
@@ -225,24 +229,23 @@ class NativeCBOWBatcher:
 
     def epoch_stencil(self, batch_size: int) -> Iterator[StencilBatch]:
         """Stream-span epoch (same wire format as
-        ``CBOWBatcher.epoch_stencil``): spans of ``batch_size + 2W``
-        unique tokens with per-center positions, assembled in C++."""
+        ``CBOWBatcher.epoch_stencil``): spans of ``span_positions``
+        stream positions — what holds ``batch_size`` centers under this
+        batcher's own center gate — with per-center positions, assembled
+        in C++ into one buffer (``StencilBatch.packed``)."""
         lib = self._lib
-        W = self.window
-        S = batch_size + 2 * W
+        S = span_positions(batch_size, self.window, self.keep_mean)
         self._epoch_i += 1
         lib.smtpu_batcher_reset(self._h, self._seed + self._epoch_i)
         while True:
-            tokens = np.zeros(S, np.int32)
-            sids = np.zeros(S, np.int32)
-            cpos = np.zeros(batch_size, np.int32)
-            half = np.zeros(batch_size, np.int32)
-            n = lib.smtpu_batcher_next_stencil(
-                self._h, batch_size, tokens.ctypes.data, sids.ctypes.data,
-                cpos.ctypes.data, half.ctypes.data)
+            packed = np.empty(2 * S + 2 * batch_size, np.int32)
+            tokens, sids, cpos, half = unpack_span(packed, batch_size)
+            n = lib.smtpu_batcher_next_span(
+                self._h, batch_size, S, tokens.ctypes.data,
+                sids.ctypes.data, cpos.ctypes.data, half.ctypes.data)
             if n == 0:
                 return
-            yield StencilBatch(tokens, sids, cpos, half, int(n))
+            yield StencilBatch(tokens, sids, cpos, half, int(n), packed)
 
     def __del__(self):
         try:
@@ -279,11 +282,16 @@ class PrefetchingCBOWBatcher(NativeCBOWBatcher):
         """The C++ prefetch executor covers only the per-pair wire
         format; the stencil epoch gets the same overlap through the
         Python-thread pipeline (io/pipeline.py) over the synchronous
-        native iterator — wire format and batch order unchanged."""
+        native iterator (the C++ call releases the interpreter lock) —
+        wire format and batch order unchanged.  It is this batcher's own
+        read-ahead, as the C++ executor is ``epoch()``'s, not the train
+        loop's ``[worker] pipeline``: it tells no phase of its own, the
+        loop's ``input_wait`` covers the wait for it."""
         from swiftmpi_tpu.io.pipeline import PrefetchIterator
         return PrefetchIterator(super().epoch_stencil(batch_size),
                                 depth=self.depth,
-                                name="native-stencil-prefetch")
+                                name="native-stencil-prefetch",
+                                observed=False)
 
 
 # ---- libSVM (io.cpp) ------------------------------------------------------

@@ -110,11 +110,41 @@ class CBOWBatch:
         return len(self.centers)
 
 
+#: span lengths are whole lane tiles of the chip
+_SPAN_LANES = 128
+
+
+def center_keep_mean(counts, keep_prob) -> float:
+    """Share of stream positions that pass the center gate: ``k = sum
+    f(w) keep(w)`` over the vocabulary, ``f`` a word's share of the
+    stream.  1.0 without subsampling."""
+    counts = np.asarray(counts, np.float64)
+    total = counts.sum()
+    if total <= 0:
+        return 1.0
+    return float(np.dot(counts / total, np.asarray(keep_prob, np.float64)))
+
+
+def span_positions(batch_size: int, window: int,
+                   keep_mean: float = 1.0) -> int:
+    """Positions a span needs to hold ``batch_size`` centers when a
+    position passes the center gate with probability ``keep_mean``: the
+    mean ``B / k``, four standard deviations of the negative binomial
+    (``sqrt(B (1 - k)) / k``), the ``2W`` a resumed left tail and the last
+    window's right edge take, rounded up to whole lane tiles.  A batch
+    that fills its span first closes short, which stays legal."""
+    k = min(max(float(keep_mean), 1e-3), 1.0)
+    need = (batch_size / k + 4.0 * np.sqrt(batch_size * (1.0 - k)) / k
+            + 2 * window)
+    return -(-int(np.ceil(need)) // _SPAN_LANES) * _SPAN_LANES
+
+
 @dataclass
 class StencilBatch:
     """Positional-stencil wire format: the batch is a *stream span* of
-    unique tokens plus per-center positions into it, so the device pulls
-    at most ``B + 2W`` rows instead of ``B * 2W`` context gathers.
+    ``S`` positions (``span_positions``: enough to hold ``B`` centers
+    under the center gate) plus per-center positions into it, so the
+    device pulls ``S`` rows instead of ``B * 2W`` context gathers.
 
     Expansion semantics (see :func:`stencil_to_cbow`): center row ``i``
     with ``p = center_pos[i]`` and ``h = half[i]`` has center token
@@ -129,6 +159,10 @@ class StencilBatch:
     center_pos: np.ndarray  # (B,) int32 span index per center; -1 pad
     half: np.ndarray        # (B,) int32 effective half-window; 0 pad
     n_words: int            # real (unpadded) center count
+    #: the four fields in one int32 buffer, ``[tokens | sent_id |
+    #: center_pos | half]``, where the batcher laid them out so (the
+    #: fields are then views of it): what goes to the device, in one put
+    packed: Optional[np.ndarray] = None
 
     def __len__(self) -> int:
         return len(self.center_pos)
@@ -136,6 +170,24 @@ class StencilBatch:
     @property
     def span(self) -> int:
         return len(self.tokens)
+
+    def pack(self) -> np.ndarray:
+        """``(2S + 2B,)`` int32: ``packed``, or the fields joined."""
+        if self.packed is not None:
+            return self.packed
+        return np.concatenate([
+            np.asarray(f, np.int32) for f in
+            (self.tokens, self.sent_id, self.center_pos, self.half)])
+
+
+def unpack_span(packed, centers: int):
+    """``(tokens, sent_id, center_pos, half)`` of a packed span batch of
+    ``centers`` centers (`StencilBatch.pack`); works on host and traced
+    arrays alike."""
+    S = (packed.shape[-1] - 2 * centers) // 2
+    return (packed[..., :S], packed[..., S:2 * S],
+            packed[..., 2 * S:2 * S + centers],
+            packed[..., 2 * S + centers:])
 
 
 def stencil_to_cbow(batch: StencilBatch, window: int) -> CBOWBatch:
@@ -171,6 +223,7 @@ class CBOWBatcher:
         self.sample = float(sample)
         self.rng = np.random.default_rng(seed)
         self.keep_prob = subsample_keep_prob(vocab.counts, sample)
+        self.keep_mean = center_keep_mean(vocab.counts, self.keep_prob)
         # pre-map sentences to vocab indices, dropping OOV
         self._sents: List[np.ndarray] = []
         for sent in sentences:
@@ -243,15 +296,16 @@ class CBOWBatcher:
         per-pair epoch — the CPU parity tests pin this.
 
         Invariants (by construction, not by dedup):
-        * span capacity is fixed at ``S = batch_size + 2W`` — the unique
-          gather working set per batch;
+        * span capacity is fixed at ``S = span_positions(batch_size, W,
+          keep_mean)`` — what holds a full batch of centers under the
+          center gate (``batch_size + 2W`` rounded up without one);
         * every admitted center's full (sentence-clipped) window is
           resident in the span, so expansion never loses a context;
         * a sentence split across batches replays its last ``W`` tokens
           into the new span so left contexts survive the split.
         """
         W = self.window
-        S = batch_size + 2 * W
+        S = span_positions(batch_size, W, self.keep_mean)
         tokens = np.zeros(S, np.int32)
         sids = np.full(S, -1, np.int32)
         cpos = np.full(batch_size, -1, np.int32)

@@ -61,6 +61,24 @@ class PipelineError(RuntimeError):
     original exception chained (``__cause__``)."""
 
 
+class _Unobserved:
+    """What an ``observed=False`` iterator opens in a span's place."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def drop(self):
+        pass
+
+
+_UNOBSERVED = _Unobserved()
+
+
 class PrefetchIterator:
     """Iterate ``source`` through a ``depth``-bounded background queue.
 
@@ -69,14 +87,21 @@ class PrefetchIterator:
     transferred items the producer may run ahead; the queue slot the
     producer is rendering *into* is not yet visible to the consumer, so
     peak host memory is ``depth + 1`` items.
+
+    ``observed=False`` is for a read-ahead that is not the training
+    loop's input pipeline (a batcher's own, consumed inside the loop's
+    ``input_wait``): it opens no span and feeds no ``pipeline/*`` series,
+    so the loop's phases are told once.
     """
 
     def __init__(self, source: Iterable, depth: int = 2,
                  transfer: Optional[Callable[[Any], Any]] = None,
-                 name: str = "input-pipeline"):
+                 name: str = "input-pipeline", observed: bool = True):
         if depth < 1:
             raise ValueError(f"pipeline depth must be >= 1, got {depth}")
         self.depth = int(depth)
+        self._span = obs.span if observed else (lambda _name: _UNOBSERVED)
+        self._observed = bool(observed)
         self._source = iter(source)
         self._transfer = transfer
         self._q: queue.Queue = queue.Queue(maxsize=self.depth)
@@ -105,14 +130,14 @@ class PrefetchIterator:
                 # happen, so the telemetry phases are measured here (the
                 # concurrent-write side of the registry's thread-safety
                 # contract)
-                with obs.span("render"):
+                with self._span("render"):
                     try:
                         item = next(src)
                     except StopIteration:
                         break
                 if self._transfer is not None:
                     t0 = time.monotonic()
-                    with obs.span("h2d"):
+                    with self._span("h2d"):
                         item = self._transfer(item)
                     self._transfer_s += time.monotonic() - t0
                 # bounded put that stays responsive to close(): a plain
@@ -125,7 +150,7 @@ class PrefetchIterator:
                         self._peak_depth = max(self._peak_depth,
                                                self._q.qsize())
                         reg = obs.get_registry()
-                        if reg.enabled:
+                        if reg.enabled and self._observed:
                             reg.counter("pipeline/produced").inc()
                             reg.gauge("pipeline/queue_depth").set(
                                 self._q.qsize())
@@ -154,7 +179,7 @@ class PrefetchIterator:
         if self._stop.is_set():
             raise StopIteration
         t0 = time.monotonic()
-        with obs.span("input_wait") as wait:
+        with self._span("input_wait") as wait:
             item = self._q.get()
             if item is _DONE or item is _CLOSED:
                 wait.drop()     # one `input_wait` sample per item
@@ -170,7 +195,7 @@ class PrefetchIterator:
             raise StopIteration
         self._consumed += 1
         reg = obs.get_registry()
-        if reg.enabled:
+        if reg.enabled and self._observed:
             reg.counter("pipeline/consumed").inc()
         return item
 

@@ -49,7 +49,8 @@ import numpy as np
 from swiftmpi_tpu import obs
 from swiftmpi_tpu.cluster.cluster import Cluster
 from swiftmpi_tpu.data.text import (CBOWBatcher, Vocab, build_vocab,
-                                    load_corpus)  # noqa: F401 (Vocab: API)
+                                    load_corpus,  # noqa: F401 (Vocab: API)
+                                    unpack_span)
 from swiftmpi_tpu.io.checkpoint import dump_table_text, load_table_text
 from swiftmpi_tpu.ops.sampling import (alias_slot_lookups,
                                        build_unigram_alias, sample_alias,
@@ -138,31 +139,62 @@ class _PairCount:
     rendered (``_epoch_items``: before ``h2d``, no device work).  Exists
     only with telemetry on; feeds ``train/pairs{kind=valid|grid}`` and
     ``train_metrics``' ``pairs_per_step`` / ``pair_fill_share``.  For
-    CBOW a pair is one summed context.  Stencil batches carry no mask
-    and multi-process batches are already-placed global arrays: neither
-    is counted, and a run of only such batches exports no ``train/pairs``
+    CBOW a pair is one summed context.  A span batch is counted from its
+    positions (``observe_span``): the same valid pairs against the same
+    ``(B, 2W)`` grid, which the span step never builds, and the span's
+    positions, which it pulls and pushes instead (``span_rows``).
+    Multi-process batches are already-placed global arrays and are not
+    counted; a run of only such batches exports no ``train/pairs``
     series at all (the counters are made on the first counted batch), so
     a series that reads 0 means no valid pair, never "not counted"."""
 
     def __init__(self, reg):
         self._reg = reg
         self._valid = self._grid = None
-        self.valid = self.grid = self.steps = 0
+        self.valid = self.grid = self.steps = self.span_rows = 0
+
+    def _count(self, valid: int, grid: int, steps: int) -> None:
+        if self._valid is None:
+            self._valid = self._reg.counter("train/pairs", kind="valid")
+            self._grid = self._reg.counter("train/pairs", kind="grid")
+        self.valid += valid
+        self.grid += grid
+        self.steps += steps
+        self._valid.inc(valid)
+        self._grid.inc(grid)
 
     def observe(self, ctx_mask) -> None:
         """``ctx_mask``: ``(B, 2W)`` of one step or ``(L, B, 2W)`` of a
         fused group."""
         if not isinstance(ctx_mask, np.ndarray):
             return
-        if self._valid is None:
-            self._valid = self._reg.counter("train/pairs", kind="valid")
-            self._grid = self._reg.counter("train/pairs", kind="grid")
-        valid = int(np.count_nonzero(ctx_mask))
-        self.valid += valid
-        self.grid += ctx_mask.size
-        self.steps += 1 if ctx_mask.ndim == 2 else ctx_mask.shape[0]
-        self._valid.inc(valid)
-        self._grid.inc(ctx_mask.size)
+        self._count(int(np.count_nonzero(ctx_mask)), ctx_mask.size,
+                    1 if ctx_mask.ndim == 2 else ctx_mask.shape[0])
+
+    def observe_span(self, packed, centers: int, window: int) -> None:
+        """``packed``: ``(2S + 2B,)`` of one step or ``(L, 2S + 2B)`` of
+        a fused group (`StencilBatch.pack`).  A center's valid pairs are
+        the positions within its half window, in the span and of its
+        sentence — `stencil_to_cbow`'s expansion, counted not built: a
+        sentence is one run of equal ids in the span (the wire format's
+        contiguous slice of the stream), so a window reaches
+        ``min(half, distance to the run's end)`` on either side."""
+        if not isinstance(packed, np.ndarray):
+            return
+        packed = packed.reshape(-1, packed.shape[-1])
+        valid = 0
+        for tokens, sent_id, center_pos, half in (
+                unpack_span(row, centers) for row in packed):
+            S = len(tokens)
+            starts = np.flatnonzero(np.diff(sent_id, prepend=-2))
+            ends = np.append(starts[1:], S)       # one past each run
+            live = center_pos >= 0
+            cp, hf = center_pos[live], half[live]
+            run = np.searchsorted(starts, cp, side="right") - 1
+            valid += int(np.minimum(hf, cp - starts[run]).sum()
+                         + np.minimum(hf, ends[run] - 1 - cp).sum())
+            self.span_rows += S
+        self._count(valid, len(packed) * centers * 2 * window, len(packed))
 
 
 def _stack_group_host(batches):
@@ -176,22 +208,15 @@ def _stack_group_host(batches):
 
 
 def _stack_group_host_stencil(batches):
-    """StencilBatch variant of ``_stack_group_host``.  Every stencil
-    batch is fixed-shape (span and center arrays are padded, only
-    ``n_words`` varies), so even epoch tails stack and fuse."""
-    return (np.stack([np.asarray(b.tokens) for b in batches]),
-            np.stack([np.asarray(b.sent_id) for b in batches]),
-            np.stack([np.asarray(b.center_pos) for b in batches]),
-            np.stack([np.asarray(b.half) for b in batches]))
+    """StencilBatch variant of ``_stack_group_host``: the packed spans,
+    one row a batch.  Every stencil batch is fixed-shape (span and
+    center arrays are padded, only ``n_words`` varies), so even epoch
+    tails stack and fuse."""
+    return (np.stack([b.pack() for b in batches]),)
 
 
 def _stack_group(batches):
     return tuple(jnp.asarray(f) for f in _stack_group_host(batches))
-
-
-def _stack_group_stencil(batches):
-    return tuple(jnp.asarray(f)
-                 for f in _stack_group_host_stencil(batches))
 
 
 def _negative_slots(key, alias_prob, alias_idx, slot_of_vocab, shape):
@@ -289,14 +314,19 @@ class Word2Vec:
         self.shared_negatives = g(
             "word2vec", "shared_negatives", 0).to_int32()
         self.shared_pool = g("word2vec", "shared_pool", 1024).to_int32()
-        # TPU-first opt-in: positional-stencil rendering — the batcher
-        # emits stream POSITIONS over a span of B + 2W tokens and the
-        # step gathers only the span's unique rows (≤ B + 2W instead of
-        # B·2W context rows), computing context sums as a fixed-offset
-        # sliding window with sentence-boundary masks.  Composes with
-        # shared_negatives for the pool-negative h side.  See
-        # _build_grads_stencil.
+        # Positional-stencil rendering — the batcher emits stream
+        # POSITIONS over a span that holds the batch's centers and the
+        # step pulls, window-sums and pushes each position once (S rows
+        # instead of B·2W context rows), by statically shifted sums with
+        # sentence-boundary masks.  Composes with shared_negatives for
+        # the pool-negative h side.  See _build_grads_stencil.  1 asks
+        # for it; left at 0 a CBOW model that can take spans resolves to
+        # it at build time (_resolve_stencil) and train() keeps it where
+        # the batcher renders spans.
         self.stencil = g("word2vec", "stencil", 0).to_int32()
+        #: the rendering was resolved from what the model observes, not
+        #: asked for: train() may still fall back to per-pair batches
+        self._stencil_auto = False
         # TPU-first opt-in with PARITY semantics: compute the NS phase
         # through full (B, capacity) logits on the MXU instead of
         # random row gathers (see _build_grads_dense) — same sampling
@@ -569,11 +599,45 @@ class Word2Vec:
         prob, alias = build_unigram_alias(self.vocab.counts)
         self._alias_prob = jnp.asarray(prob)
         self._alias_idx = jnp.asarray(alias)
+        self._resolve_stencil()
         if self.control_settings.enabled:
             self._arm_control()
         log.info("vocab: %d words, %d tokens; table capacity %d",
                  V, self.vocab.total_words, self.table.capacity)
         return self
+
+    def _resolve_stencil(self) -> None:
+        """The context side's rendering, from what the model observes at
+        build time: a CBOW model in one process, not hogwild, on a
+        backend that pushes counted rows renders its contexts by span
+        position (``stencil`` reads 1 from here on, visibly) — one
+        algorithm, the context sum, rendered by position when the input
+        carries positions.  Skip-gram is per-pair by nature, multi-
+        process and hogwild batches are per-pair by construction, and
+        ``dense_logits: 1`` asks for another rendering.  ``train()``
+        settles it against the batcher it is handed (`_settle_stencil`)."""
+        if self.stencil:
+            return
+        self._stencil_auto = bool(
+            not self.sg and self.dense_logits != 1
+            and self.async_mode != "hogwild"
+            and jax.process_count() == 1
+            and getattr(self.transfer, "name", "") in ("xla", "hybrid"))
+        self.stencil = int(self._stencil_auto)
+
+    def _settle_stencil(self, batcher) -> bool:
+        """Whether this ``train()`` call renders spans.  A rendering that
+        was asked for stands (and raises where it cannot run); one the
+        model resolved itself needs a batcher that renders spans
+        (``epoch_stencil``) — ``None`` is the Python batcher train()
+        makes for in-memory sentences, which keeps the per-pair stream.
+        A step compiled for the other rendering is dropped."""
+        if self._stencil_auto:
+            want = int(hasattr(batcher, "epoch_stencil"))
+            if want != self.stencil:
+                self.stencil = want
+                self._step, self._fused_cache = None, {}
+        return bool(self.stencil)
 
     def _seed_hot_touched_fraction(self):
         """Expected fraction of the replicated hot head touched by ONE
@@ -625,29 +689,8 @@ class Word2Vec:
                 out = apply_fn(state, pushes)
             return out, (sum(tape, jnp.int32(0)),)
 
-        if self.stencil:
-            @partial(jax.jit, donate_argnums=0)
-            def step_st(state, slot_of_vocab, alias_prob, alias_idx,
-                        tokens, sent_id, center_pos, half, key):
-                pushes, es, ec = grads_fn(
-                    state, slot_of_vocab, alias_prob, alias_idx,
-                    tokens, sent_id, center_pos, half, key)
-                out, rows = apply_counted(state, pushes)
-                if num is not None:
-                    obs_numerics.stage_step(
-                        num, state, out,
-                        obs_numerics.spec_stats(pushes, n_hot),
-                        es, ec, gfields)
-                return (out, es, ec, *rows)
-
-            return obs.costs.track("w2v_step", step_st)
-
-        @partial(jax.jit, donate_argnums=0)
-        def step(state, slot_of_vocab, alias_prob, alias_idx,
-                 centers, contexts, ctx_mask, key):
-            pushes, es, ec = grads_fn(
-                state, slot_of_vocab, alias_prob, alias_idx,
-                centers, contexts, ctx_mask, key)
+        def run(state, statics, batch, key, **shape):
+            pushes, es, ec = grads_fn(state, *statics, *batch, key, **shape)
             out, rows = apply_counted(state, pushes)
             if num is not None:
                 obs_numerics.stage_step(
@@ -655,6 +698,23 @@ class Word2Vec:
                     obs_numerics.spec_stats(pushes, n_hot),
                     es, ec, gfields)
             return (out, es, ec, *rows)
+
+        if self.stencil:
+            # the batch is one packed buffer (StencilBatch.pack), cut
+            # into its four fields inside the program: one put a step
+            @partial(jax.jit, donate_argnums=0, static_argnames="centers")
+            def step(state, slot_of_vocab, alias_prob, alias_idx,
+                     span, key, *, centers):
+                return run(state, (slot_of_vocab, alias_prob, alias_idx),
+                           (span,), key, centers=centers)
+
+            return obs.costs.track("w2v_step", step)
+
+        @partial(jax.jit, donate_argnums=0)
+        def step(state, slot_of_vocab, alias_prob, alias_idx,
+                 centers, contexts, ctx_mask, key):
+            return run(state, (slot_of_vocab, alias_prob, alias_idx),
+                       (centers, contexts, ctx_mask), key)
 
         return obs.costs.track("w2v_step", step)
 
@@ -699,54 +759,20 @@ class Word2Vec:
         n_hot = self.table.n_hot
         gfields = tuple(self.access.grad_fields)
 
-        if self.stencil:
-            @partial(jax.jit, donate_argnums=0)
-            def multi_st(state, slot_of_vocab, alias_prob, alias_idx,
-                         tokens_s, sids_s, cpos_s, half_s, key):
-                keys = jax.random.split(key, n_inner)
-                state0 = state
-
-                def body(state, xs):
-                    t, s, c, h, k = xs
-                    pushes, es, ec = grads_fn(
-                        state, slot_of_vocab, alias_prob, alias_idx,
-                        t, s, c, h, k)
-                    if num is None:
-                        return apply_fn(state, pushes), (es, ec)
-                    return apply_fn(state, pushes), (
-                        es, ec, obs_numerics.spec_stats(pushes, n_hot))
-
-                state, outs = jax.lax.scan(
-                    body, state, (tokens_s, sids_s, cpos_s, half_s, keys))
-                if num is None:
-                    es, ec = outs
-                else:
-                    es, ec, stats = outs
-                    obs_numerics.stage_step(
-                        num, state0, state,
-                        tuple(s.sum() for s in stats),
-                        es.sum(), ec.sum(), gfields)
-                return state, es.sum(), ec.sum()
-
-            return multi_st
-
-        @partial(jax.jit, donate_argnums=0)
-        def multi(state, slot_of_vocab, alias_prob, alias_idx,
-                  centers_s, contexts_s, masks_s, key):
+        def run(state, statics, batches, key, **shape):
             keys = jax.random.split(key, n_inner)
             state0 = state
 
             def body(state, xs):
-                c, x, m, k = xs
-                pushes, es, ec = grads_fn(
-                    state, slot_of_vocab, alias_prob, alias_idx, c, x, m, k)
+                *batch, k = xs
+                pushes, es, ec = grads_fn(state, *statics, *batch, k,
+                                          **shape)
                 if num is None:
                     return apply_fn(state, pushes), (es, ec)
                 return apply_fn(state, pushes), (
                     es, ec, obs_numerics.spec_stats(pushes, n_hot))
 
-            state, outs = jax.lax.scan(
-                body, state, (centers_s, contexts_s, masks_s, keys))
+            state, outs = jax.lax.scan(body, state, (*batches, keys))
             if num is None:
                 es, ec = outs
             else:
@@ -755,6 +781,21 @@ class Word2Vec:
                     num, state0, state, tuple(s.sum() for s in stats),
                     es.sum(), ec.sum(), gfields)
             return state, es.sum(), ec.sum()
+
+        if self.stencil:
+            @partial(jax.jit, donate_argnums=0, static_argnames="centers")
+            def multi_st(state, slot_of_vocab, alias_prob, alias_idx,
+                         spans_s, key, *, centers):
+                return run(state, (slot_of_vocab, alias_prob, alias_idx),
+                           (spans_s,), key, centers=centers)
+
+            return multi_st
+
+        @partial(jax.jit, donate_argnums=0)
+        def multi(state, slot_of_vocab, alias_prob, alias_idx,
+                  centers_s, contexts_s, masks_s, key):
+            return run(state, (slot_of_vocab, alias_prob, alias_idx),
+                       (centers_s, contexts_s, masks_s), key)
 
         return multi
 
@@ -782,7 +823,7 @@ class Word2Vec:
         n_hot = self.table.n_hot
         gfields = tuple(self.access.grad_fields)
 
-        def run_windows(state, statics, keys, xs_all):
+        def run_windows(state, statics, keys, xs_all, **shape):
             es_tot, ec_tot = jnp.float32(0), jnp.float32(0)
             state0 = state
             if num is not None:
@@ -793,7 +834,7 @@ class Word2Vec:
                 def body(carry, x):
                     # carry is the window-start state, returned untouched:
                     # every step in the window sees the same snapshot
-                    pushes, es, ec = grads_fn(carry, *statics, *x)
+                    pushes, es, ec = grads_fn(carry, *statics, *x, **shape)
                     return carry, (pushes, es, ec)
 
                 _, (pushes_s, es, ec) = jax.lax.scan(body, state, xs)
@@ -818,13 +859,13 @@ class Word2Vec:
             return state, es_tot, ec_tot
 
         if self.stencil:
-            @partial(jax.jit, donate_argnums=0)
+            @partial(jax.jit, donate_argnums=0, static_argnames="centers")
             def multi_st(state, slot_of_vocab, alias_prob, alias_idx,
-                         tokens_s, sids_s, cpos_s, half_s, key):
+                         spans_s, key, *, centers):
                 keys = jax.random.split(key, n_inner)
                 return run_windows(state,
                                    (slot_of_vocab, alias_prob, alias_idx),
-                                   keys, (tokens_s, sids_s, cpos_s, half_s))
+                                   keys, (spans_s,), centers=centers)
 
             return multi_st
 
@@ -1305,101 +1346,120 @@ class Word2Vec:
         return grads_fn
 
     def _build_grads_stencil(self, shared: bool):
-        """Positional-stencil rendering of the CBOW gradient phase
-        (opt-in, ``stencil: 1``): collapse the context gather to the
-        batch's UNIQUE stream-span rows.
+        """Positional-stencil rendering of the CBOW gradient phase: the
+        context side is computed over the batch's stream SPAN, position
+        by position, never over a ``(B, 2W)`` pair grid.
 
-        Consecutive centers in a sequential stream share context
-        tokens, so the per-pair rendering's (B, 2W) context gather
-        touches at most S = B + 2W unique rows — ~16.4K instead of
-        ~131K at bench shape, ~8x fewer HBM transactions against the
-        measured 28ns/row random-gather floor (docs/ROUND5_NOTES.md).
-        The batcher emits positions over the span (data/text.py
-        ``StencilBatch``; the native loader emits the identical wire
-        format) and the context sum becomes a fixed-stencil
-        sliding-window reduction:
+        Consecutive centers of a sentence share their windows, so every
+        stream position stands in up to 2W rows of the per-pair grid,
+        and 43 % of that grid is the dynamic window's padding
+        (``pair_fill_share``).  The batcher emits the span instead
+        (data/text.py ``StencilBatch``: ``S`` tokens and sentence ids,
+        ``B`` center positions and half windows, one packed buffer; the
+        native loader emits the identical wire format) and the step:
 
-          v_span  = pull span rows            — ONE ≤(B+2W)-row gather
-          ctx_idx = center_pos ± {1..W}       — static stencil offsets
-          masks   = in-span ∧ same-sentence ∧ |offset| ≤ half ∧ valid
-          neu1    = Σ_offsets v_span[ctx_idx]·mask   — gathered from
-                    the span ARRAY, not the capacity table
+          v_span   = pull the S span rows       — ONE pull, S rows
+          neu1_pos = Σ_{o=±1..W} v_span[p+o] · [|o| ≤ half_pos[p]]
+                     · [same sentence]           — 2W statically shifted
+                     slices of the (S, d) array: dense, no gather
+          neu1     = neu1_pos[center_pos]        — B rows of a small array
+          ... the target side (``h`` rows of the center and its
+              negatives), as the per-pair rendering has it ...
+          neu1e at its center's position, then
+          vg[p] = Σ_o neu1e_pos[p-o] · mask, vc[p] = Σ_o mask
+                     — the same 2W shifts, transposed
+          push S counted rows (``mean=True``, ``vc`` the multiplicity;
+          a position no window covers goes as slot -1)
 
-        The v-gradient inverts the same stencil: per-pair context
-        grads scatter onto SPAN positions (batch-local dense indices),
-        then one position-indexed push dedups duplicate tokens WITHOUT
-        the generic path's 151K-key sort (transfer/xla.py
-        ``push_span``).  Sentence boundaries and the reference's
-        dynamic window shrink (word2vec.h:556) are masks, equal by
-        construction to the per-pair batcher's expansion —
-        data/text.py ``stencil_to_cbow`` is the executable statement
-        of that equivalence and the parity tests pin it.
+        ``half_pos`` is ``half`` placed at the centers' positions and 0
+        elsewhere, so a position that is no center has an empty window.
+        Sentence boundaries and the reference's dynamic window shrink
+        (word2vec.h:556) are masks, equal by construction to the
+        per-pair batcher's expansion — data/text.py ``stencil_to_cbow``
+        is the executable statement of that equivalence and the parity
+        tests pin it.  The mathematics is the per-pair rendering's, f32
+        throughout, every valid pair summed: only the order of summation
+        differs.
 
         ``shared=False``: per-center K negatives drawn from the SAME
         sampling stream as the parity gather rendering — directly
         checkable against the numpy oracle.  ``shared=True``
         (``shared_negatives: 1``): the batch-shared pool of
-        ``_build_grads_shared`` on the h side — the 1M-vocab bench
-        cell's composition."""
+        ``_build_grads_shared`` on the h side."""
         access = self.access
         transfer = self.transfer
         W = self.window
         alpha = self.alpha
         d = self.row_width
         K = self.shared_pool if shared else self.negative
+        offsets = [o for o in range(-W, W + 1) if o]
 
-        offsets = jnp.concatenate(
-            [jnp.arange(-W, 0), jnp.arange(1, W + 1)])      # (2W,)
+        def shifted(x, o):
+            """``x[p + o]`` at every span position ``p``, of an ``x``
+            padded by ``W`` on both ends of its first axis."""
+            return jax.lax.slice_in_dim(x, W + o, W + o + x.shape[0] - 2 * W)
 
-        def stencil_parts(state, slot_of_vocab, tokens, sent_id,
-                          center_pos, half):
+        def pad(x, fill):
+            return jnp.pad(x, [(W, W)] + [(0, 0)] * (x.ndim - 1),
+                           constant_values=fill)
+
+        def span_parts(state, slot_of_vocab, span, centers):
+            tokens, sent_id, center_pos, half = unpack_span(span, centers)
             S = tokens.shape[0]
-            B = center_pos.shape[0]
             with obs.named_scope("sample"):
-                span_valid = sent_id >= 0
-                span_slots = jnp.where(span_valid, slot_of_vocab[tokens], -1)
+                span_slots = jnp.where(sent_id >= 0, slot_of_vocab[tokens],
+                                       -1)
                 row_valid = center_pos >= 0
                 cp = jnp.clip(center_pos, 0, S - 1)
-                centers = tokens[cp]                             # (B,) vocab
+                c_words = tokens[cp]                             # (B,) vocab
                 c_slots = jnp.where(row_valid, span_slots[cp], -1)
-                ctx_idx = cp[:, None] + offsets[None, :]         # (B, 2W)
-                ci = jnp.clip(ctx_idx, 0, S - 1)
-                ctx_mask = ((ctx_idx >= 0) & (ctx_idx < S)
-                            & (sent_id[ci] == sent_id[cp][:, None])
-                            & (jnp.abs(offsets)[None, :] <= half[:, None])
-                            & row_valid[:, None])
+                # centers stand at ascending, distinct positions; padded
+                # ones (the batch's tail) go past the end and drop
+                at = jnp.where(row_valid, center_pos, S)
+                half_pos = jnp.zeros((S,), jnp.int32).at[at].set(
+                    half, mode="drop", unique_indices=True,
+                    indices_are_sorted=True)
+                half_pad = pad(half_pos, 0)
+                # a padding id of its own: no window reaches past an end
+                sid_pad = pad(sent_id, -2)
+                # fwd[o][p]: position p + o is in center p's window;
+                # bwd[o][p]: p is in the window of the center at p - o
+                fwd = [(abs(o) <= half_pos)
+                       & (shifted(sid_pad, o) == sent_id) for o in offsets]
+                bwd = [(abs(o) <= shifted(half_pad, -o))
+                       & (shifted(sid_pad, -o) == sent_id) for o in offsets]
             with obs.named_scope("math"):
-                # THE gather this rendering exists for: ≤ B + 2W unique rows
-                v_span = transfer.pull(
+                # THE pull this rendering exists for: S rows, once
+                v_pad = pad(transfer.pull(
                     state, span_slots, access, fields=("v",)
-                )["v"].astype(jnp.float32)                       # (S, d)
-                v_ctx = v_span[ci]        # span-local gather, not HBM rows
-                neu1 = jnp.sum(v_ctx * ctx_mask[..., None], axis=1)
-            return span_slots, centers, c_slots, ci, ctx_mask, neu1
+                )["v"].astype(jnp.float32), 0.0)                 # (S+2W, d)
+                neu1_pos = sum(jnp.where(m[:, None], shifted(v_pad, o), 0.0)
+                               for o, m in zip(offsets, fwd))
+                neu1 = neu1_pos[cp]                              # (B, d)
+            return span_slots, c_words, c_slots, row_valid, at, bwd, neu1
 
-        def v_push(span_slots, ci, ctx_mask, neu1e, S):
+        def v_push(span_slots, at, bwd, neu1e):
+            S = span_slots.shape[0]
             with obs.named_scope("math"):
-                # invert the stencil: per-pair context grads land on SPAN
-                # positions (dense batch-local indices, not a capacity
-                # scatter); contribution counts ride along so push_span's
-                # mean normalization divides by the true pair count
-                contrib = jnp.where(ctx_mask[..., None],
-                                    neu1e[:, None, :], 0.0)
-                vg = jnp.zeros((S, d), jnp.float32).at[
-                    ci.reshape(-1)].add(contrib.reshape(-1, d))
-                vc = jnp.zeros((S,), jnp.float32).at[
-                    ci.reshape(-1)].add(
-                    ctx_mask.reshape(-1).astype(jnp.float32))
-                return PushSpec(span_slots, {"v": vg}, mean=True, counts=vc)
+                # invert the stencil by the transposed shifts: a position
+                # takes the gradient of every center whose window holds
+                # it, and their number rides along so the push's mean
+                # divides by the true pair count
+                e_pad = pad(jnp.zeros((S, d), jnp.float32).at[
+                    at].set(neu1e, mode="drop", unique_indices=True,
+                            indices_are_sorted=True), 0.0)
+                vg = sum(jnp.where(m[:, None], shifted(e_pad, -o), 0.0)
+                         for o, m in zip(offsets, bwd))
+                vc = sum(m.astype(jnp.float32) for m in bwd)
+                # a position no window covers pushes no row
+                return PushSpec(jnp.where(vc > 0, span_slots, -1),
+                                {"v": vg}, mean=True, counts=vc)
 
         def grads_fn(state, slot_of_vocab, alias_prob, alias_idx,
-                     tokens, sent_id, center_pos, half, key):
-            S = tokens.shape[0]
-            B = center_pos.shape[0]
-            (span_slots, centers, c_slots, ci, ctx_mask,
-             neu1) = stencil_parts(state, slot_of_vocab, tokens,
-                                   sent_id, center_pos, half)
-            row_valid = center_pos >= 0
+                     span, key, *, centers):
+            B = centers
+            (span_slots, c_words, c_slots, row_valid, at, bwd,
+             neu1) = span_parts(state, slot_of_vocab, span, centers)
             if shared:
                 with obs.named_scope("sample"):
                     negs = sample_alias(key, alias_prob, alias_idx, (K,))
@@ -1416,7 +1476,7 @@ class Word2Vec:
                         row_valid, (1.0 - sigmoid_clipped(f_pos)) * alpha,
                         0.0)
                     # negative == center skipped (word2vec.h:584-586)
-                    n_valid = (negs[None, :] != centers[:, None]) \
+                    n_valid = (negs[None, :] != c_words[:, None]) \
                         & row_valid[:, None]
                     g_neg = jnp.where(
                         n_valid, (0.0 - sigmoid_clipped(f_neg)) * alpha, 0.0)
@@ -1429,7 +1489,7 @@ class Word2Vec:
                     # normalization-collapse note in _build_grads_shared
                     pushes = (PushSpec(c_slots, {"h": gh_pos}, mean=True),
                               PushSpec(neg_slots, {"h": gh_neg}),
-                              v_push(span_slots, ci, ctx_mask, neu1e, S))
+                              v_push(span_slots, at, bwd, neu1e))
                     ratio = self.negative / K
                     err_sum = jnp.sum(1e4 * g_pos * g_pos) \
                         + ratio * jnp.sum(1e4 * g_neg * g_neg)
@@ -1443,7 +1503,7 @@ class Word2Vec:
                 t_slots = jnp.concatenate(
                     [c_slots[:, None], neg_slots], axis=1)       # (B, K+1)
                 t_valid = jnp.concatenate(
-                    [jnp.ones((B, 1), bool), negs != centers[:, None]],
+                    [jnp.ones((B, 1), bool), negs != c_words[:, None]],
                     axis=1)
                 t_valid = t_valid & row_valid[:, None]
                 t_slots = jnp.where(t_valid, t_slots, -1)
@@ -1458,10 +1518,12 @@ class Word2Vec:
                 g = jnp.where(t_valid, g, 0.0)                   # (B, K+1)
                 h_contrib = g[..., None] * neu1[:, None, :]      # (B,K+1,d)
                 neu1e = jnp.einsum("bk,bkd->bd", g, h_t)         # (B, d)
-                pushes = (PushSpec(t_slots.reshape(-1),
+                # v first, as _assemble_push has it: the order of the two
+                # pushes moves the step's peak memory, no value
+                pushes = (v_push(span_slots, at, bwd, neu1e),
+                          PushSpec(t_slots.reshape(-1),
                                    {"h": h_contrib.reshape(-1, d)},
-                                   mean=True),
-                          v_push(span_slots, ci, ctx_mask, neu1e, S))
+                                   mean=True))
                 err_sum = jnp.sum(1e4 * g * g)          # word2vec.h:593
                 err_cnt = t_valid.sum()
             return pushes, err_sum, err_cnt
@@ -1643,8 +1705,8 @@ class Word2Vec:
                     state.update(new_fields)
                 elif getattr(spec, "counts", None) is not None:
                     # position-indexed span family (stencil rendering):
-                    # rows are pre-summed with data counts — sort-free
-                    # dedup path
+                    # rows are pre-summed, their data counts the mean's
+                    # multiplicity
                     state = transfer.push_span(
                         state, spec.slots, spec.grads, spec.counts,
                         access, mean=spec.mean)
@@ -1674,14 +1736,23 @@ class Word2Vec:
         # the pipeline is on
         sketch = self._control_sketch
 
+        def observe(fields):
+            # a span batch is one packed buffer: its tokens lead it
+            if sketch is not None:
+                sketch.observe(unpack_span(fields[0], batch_size)[0]
+                               if stencil else fields[0])
+            if pairs is None:
+                return
+            if stencil:
+                pairs.observe_span(fields[0], batch_size, self.window)
+            else:
+                pairs.observe(fields[2])
+
         def group_item():
             n_words = [b.n_words for b in group]
             fields = (_stack_group_host_stencil(group) if stencil
                       else _stack_group_host(group))
-            if sketch is not None:
-                sketch.observe(fields[0])
-            if pairs is not None and not stencil:
-                pairs.observe(fields[2])
+            observe(fields)
             return ("group", fields, n_words)
 
         epoch_iter = (batcher.epoch_stencil(batch_size) if stencil
@@ -1701,15 +1772,11 @@ class Word2Vec:
                 yield group_item()
                 group = []
             if stencil:
-                fields = (batch.tokens, batch.sent_id,
-                          batch.center_pos, batch.half)
+                fields = (batch.pack(),)
             else:
                 fields = (batch.centers, batch.contexts,
                           batch.ctx_mask)
-            if sketch is not None:
-                sketch.observe(fields[0])
-            if pairs is not None and not stencil:
-                pairs.observe(fields[2])
+            observe(fields)
             yield ("single", fields, batch.n_words)
         if group:                  # leftover partial group
             yield group_item()
@@ -1776,7 +1843,7 @@ class Word2Vec:
                 "+0.02%% vs hogwild at realistic scale — see "
                 "docs/ARCHITECTURE.md)", self.local_steps)
             hogwild = False
-        stencil = bool(self.stencil)
+        stencil = self._settle_stencil(batcher)
         if stencil and hogwild:
             raise ValueError(
                 "async_mode=hogwild drives per-pair batches; the stencil "
@@ -1872,8 +1939,9 @@ class Word2Vec:
                 self._step = self._build_step()
             else:
                 self._step = (
-                    obs.costs.track("w2v_grads",
-                                    jax.jit(self._build_grads())),
+                    obs.costs.track("w2v_grads", jax.jit(
+                        self._build_grads(),
+                        static_argnames="centers" if stencil else None)),
                     obs.costs.track("w2v_apply",
                                     jax.jit(self._build_apply())))
         # -- input pipeline setup (tentpole: prefetch-rendered,
@@ -1906,6 +1974,9 @@ class Word2Vec:
             pipe_stats = {"produced": 0, "consumed": 0,
                           "peak_queue_depth": 0, "stall_s": 0.0,
                           "transfer_s": 0.0}
+        # a span step cuts its packed batch by the centers it holds
+        shape = {"centers": batch_size} if stencil else {}
+
         def end_setup():
             nonlocal setup_span
             if setup_span is not None:
@@ -1966,7 +2037,8 @@ class Word2Vec:
                     rows = ()
                     with obs.span("dispatch", steps=1, step=n):
                         if sync:
-                            state, es, ec, *rows = self._step(state, *args)
+                            state, es, ec, *rows = self._step(
+                                state, *args, **shape)
                         else:
                             # async/global variant, bounded-staleness
                             # flavor (word2vec_global.h:577-651): grads
@@ -1974,7 +2046,7 @@ class Word2Vec:
                             # land immediately; snapshot refreshes every
                             # local_steps batches => bounded staleness.
                             grads_fn, apply_fn = self._step
-                            pushes, es, ec = grads_fn(frozen, *args)
+                            pushes, es, ec = grads_fn(frozen, *args, **shape)
                             state = apply_fn(state, pushes)
                     with obs.span("step_book", step=n):
                         # the step donates (deletes) the input state
@@ -2031,7 +2103,7 @@ class Word2Vec:
                     with obs.span("dispatch", steps=L, step=n):
                         state, es, ec = fused(
                             state, self._slot_of_vocab, self._alias_prob,
-                            self._alias_idx, *fields, sub)
+                            self._alias_idx, *fields, sub, **shape)
                     with obs.span("step_book", step=n):
                         self.table.state = state
                         es_q.add(es)
@@ -2153,6 +2225,9 @@ class Word2Vec:
                     pairs.valid / pairs.steps
                 self.train_metrics["pair_fill_share"] = \
                     100.0 * pairs.valid / pairs.grid
+                if pairs.span_rows:
+                    self.train_metrics["span_rows_per_step"] = \
+                        pairs.span_rows / pairs.steps
             if pipe_stats is not None:
                 self.train_metrics["pipeline"] = dict(pipe_stats)
             if self.controller is not None:

@@ -283,8 +283,8 @@ class PushSpec:
     stencil w2v rendering): each row already carries the sum of its
     window-overlap contributions and ``counts[i]`` says how many, so
     ``mean`` normalization needs the data counts rather than
-    1-per-row, and the apply step routes through the sort-free
-    ``push_span`` dedup instead of the generic sorted push."""
+    1-per-row, and the apply step routes through ``push_span``: the
+    push with that multiplicity."""
 
     def __init__(self, slots, grads, mean: bool = False,
                  dense: bool = False, counts=None):
@@ -1262,8 +1262,8 @@ class Transfer:
         routes) or compacted unique rows (the eager oracle).
 
         Default: the single-device representative trick (sort-free
-        positional scatter-min over a (capacity+1,) plane — exactly the
-        ``XlaTransfer.push_span`` machinery), which any one-program
+        positional scatter-min over a (capacity+1,) plane), which any
+        one-program
         device backend can use as-is."""
         B = flat.shape[0]
         valid = flat >= 0

@@ -464,8 +464,8 @@ class TpuTransfer(Transfer):
             pos = jnp.arange(B, dtype=jnp.int32)
             safe = jnp.where(valid, slots_l, capacity)
             # rep[k] = first window position holding slot k — sort-free
-            # scatter-min into a (capacity+1,) plane, exactly the
-            # XlaTransfer.push_span representative trick
+            # scatter-min into a (capacity+1,) plane, the base class's
+            # representative trick (api.Transfer._prim_window_dedup)
             rep = jnp.full((capacity + 1,), B, jnp.int32).at[safe].min(
                 jnp.where(valid, pos, B), mode="drop")
             owner = jnp.where(valid, rep[safe], B)   # B == dropped

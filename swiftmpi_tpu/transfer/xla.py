@@ -330,34 +330,20 @@ class XlaTransfer(Transfer):
     # -- span push (stencil rendering; see models/word2vec.py) -------------
     def push_span(self, state, slots, grads, counts, access, mean=False,
                   _wire=None):
-        """Sort-free dedup push for POSITION-INDEXED span batches.
-
-        ``_push_sparse`` must sort the batch before it can dedup
-        (duplicate slots can sit anywhere in a gather-rendering push),
-        and at the 1M-vocab bench shape that argsort of ~151K keys is
-        the measured ~13ms push floor.  A stencil span batch has more
-        structure: rows are indexed by stream position over a span of
-        S = B + 2W tokens, every row already carries the SUM of its
-        window-overlap contributions (the model folded those in a dense
-        span-local scatter), and ``counts[i]`` says how many.  That
-        admits an O(S·d + capacity) dedup with no sort at all:
-
-          rep[k]   = min span position holding slot k — one scatter-min
-                     into a (capacity,) int32 plane (~5MB at 1.3M rows)
-          owner_i  = rep[slots_i]: every row learns its family head
-          combined = scatter-add of grads/counts INTO owner rows — a
-                     span-local (S, d) fold, not a capacity scatter
-          apply    = gather current rows at owners, one access-method
-                     update, scatter-set back (unique by construction)
-
-        ``counts`` carries the per-row contribution multiplicities for
-        ``mean=True``: the per-key divisor is the total pair count, the
-        same quantity the sorted path derives from its segment sums, so
-        normalization semantics match the generic push exactly.
-        """
+        """Push of POSITION-INDEXED span rows: every row already carries
+        the SUM of its window-overlap contributions (the model folded
+        those in by dense shifted sums) and ``counts[i]`` says how many,
+        so ``mean=True`` divides a key's summed gradient by its summed
+        data count — the per-pair push's divisor, the pairs that named
+        the key.  It is the sparse push with that multiplicity: sort the
+        ``S`` slots, sum duplicates, write the distinct rows at the head
+        back in the form every sparse push takes (`write_back_form`), at
+        the cost of the rows and not of the span.  Why sorted: a
+        sort-free dedup (scatter-min of positions into a ``(capacity,)``
+        plane, unsorted fold, every slot written row by row) measured
+        1.7 ms a step slower in cbow2m-b16k's step of 22,400 span slots
+        and ~13.7 K distinct rows (PERF.md section 6, PR 36)."""
         slots = jnp.asarray(slots, jnp.int32)
-        capacity = next(iter(state.values())).shape[0]
-        S = slots.shape[0]
         valid = slots >= 0
         if _wire is not None:
             # window path shipping a compressed representation: book the
@@ -367,41 +353,8 @@ class XlaTransfer(Transfer):
         else:
             self._record_exchange(jnp.sum(valid),
                                   grad_row_bytes(grads, with_counts=True))
-        with obs.named_scope("dedup"):
-            safe = jnp.where(valid, slots, 0)
-            pos = jnp.arange(S, dtype=jnp.int32)
-            rep = jnp.full((capacity,), S, jnp.int32).at[safe].min(
-                jnp.where(valid, pos, S))
-            owner = jnp.where(valid, rep[safe], S)       # (S,) in [0, S]
-            inv = None
-            if mean:
-                cnt = jnp.zeros((S,), jnp.float32).at[owner].add(
-                    jnp.asarray(counts, jnp.float32), mode="drop")
-                inv = (1.0 / jnp.maximum(cnt, 1.0))[:, None]
-            combined = {}
-            for f in grads:
-                g = jnp.asarray(grads[f])
-                acc = jnp.zeros((S, g.shape[1]), g.dtype).at[owner].add(
-                    g, mode="drop")
-                combined[f] = acc * inv if mean else acc
-            is_owner = valid & (owner == pos)
-        touched = access.touched_fields(grads)
-        self._count_rows_written(
-            lambda: jnp.sum(is_owner, dtype=jnp.int32), touched)
-        with obs.named_scope("apply"):
-            safe_own = jnp.where(is_owner, slots, 0)
-            current = {f: jnp.take(state[f], safe_own, axis=0)
-                       for f in touched}
-            updated = access.apply_push(current, combined)
-            out = dict(state)
-            tgt = jnp.where(is_owner, slots, capacity)
-            for f in updated:
-                # owner rows hold distinct slots by construction (one
-                # owner per table row); non-owners route OOB and drop.
-                # The span is position-ordered, not slot-ordered: no
-                # sweep, which needs ascending rows.
-                out[f] = _set_rows(state[f], tgt, updated[f], sweep=False)
-            return bump_row_versions(out, state, tgt)
+        return self._push_sparse(state, slots, grads, access, mean,
+                                 counts=jnp.asarray(counts, jnp.float32))
 
     # -- window-coalesced push ---------------------------------------------
     # No override: the base-class TrafficPlan interpreter
@@ -415,7 +368,10 @@ class XlaTransfer(Transfer):
     # reduce-scatter/allgather degenerates to on one program, so this
     # backend inherits it unchanged.
 
-    def _push_sparse(self, state, slots, grads, access, mean=False):
+    def _push_sparse(self, state, slots, grads, access, mean=False,
+                     counts=None):
+        """``counts``: a row's multiplicity under ``mean`` (a span row is
+        a sum of that many contributions, `push_span`); ``None``: one."""
         capacity = next(iter(state.values())).shape[0]
         B = slots.shape[0]
         if B == 0:
@@ -464,9 +420,10 @@ class XlaTransfer(Transfer):
                 # seg_ids ascend (cumsum of non-negatives): tell XLA so
                 # the scatter lowering can skip the general collision
                 # machinery
+                weight = valid[order].astype(jnp.float32) if counts is None \
+                    else jnp.where(valid, counts, 0.0)[order]
                 seg_counts = jnp.zeros((B,), jnp.float32).at[seg_ids].add(
-                    valid[order].astype(jnp.float32), mode="drop",
-                    indices_are_sorted=True)
+                    weight, mode="drop", indices_are_sorted=True)
                 inv = (1.0 / jnp.maximum(seg_counts, 1.0))[:, None]
             combined = {}
             for f in grads:

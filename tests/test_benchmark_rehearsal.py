@@ -97,19 +97,26 @@ V, WINDOW, NEGATIVE, LEN_VEC, MINIBATCH = 300, 3, 4, 16, 4096
 FIELDS = {"h", "v", "h2sum", "v2sum"}
 STEP_PARAMS = ("state", "slot_of_vocab", "alias_prob", "alias_idx",
                "centers", "contexts", "ctx_mask", "key")
+#: a CBOW model's step takes the span batch, one packed buffer, and is
+#: told the centers to cut it by
+SPAN_STEP_PARAMS = ("state", "slot_of_vocab", "alias_prob", "alias_idx",
+                    "span", "key", "centers")
 
 
 class TwoBatches:
     """The batcher ``train()`` is handed: the native batcher's first two
-    full batches of an epoch, and a note of every size asked for."""
+    full batches of an epoch, in the rendering the model asks for (as
+    the harness's ``ChunkBatcher`` forwards both), and a note of every
+    size asked for."""
 
     def __init__(self, inner):
         self.inner, self.vocab = inner, inner.vocab
-        self.asked, self.batches = [], []
+        self.asked, self.batches, self.kinds = [], [], []
 
-    def epoch(self, batch_size):
+    def _two_full(self, kind, batch_size):
         self.asked.append(batch_size)
-        gen = self.inner.epoch(batch_size)
+        self.kinds.append(kind)
+        gen = iter(getattr(self.inner, kind)(batch_size))
         try:
             for batch in gen:
                 if batch.n_words == batch_size:
@@ -119,6 +126,12 @@ class TwoBatches:
                     return
         finally:
             gen.close()           # stops the native prefetch thread
+
+    def epoch(self, batch_size):
+        return self._two_full("epoch", batch_size)
+
+    def epoch_stencil(self, batch_size):
+        return self._two_full("epoch_stencil", batch_size)
 
 
 @contextlib.contextmanager
@@ -189,6 +202,7 @@ def build_toy(sg, workdir):
                   dict(zip(keys[order].tolist(), range(V))))
     offsets = np.arange(0, len(tokens) + 1, 40, dtype=np.int64)
     model.build_from_vocab(vocab)
+    stencil_at_build = model.stencil
 
     assert native.available(), "the native loader did not build"
     batcher = TwoBatches(native.PrefetchingCBOWBatcher(
@@ -208,7 +222,8 @@ def build_toy(sg, workdir):
     spans = {k: n - before.get(k, 0) for k, n in span_counts().items()}
     return SimpleNamespace(model=model, vocab=vocab, batcher=batcher,
                            key_before=key_before, losses=losses,
-                           spans=spans, parents=parents)
+                           spans=spans, parents=parents,
+                           stencil_at_build=stencil_at_build)
 
 
 @pytest.fixture(scope="module")
@@ -272,23 +287,43 @@ def vocab_and_batcher(toy):
     assert isinstance(inner, native.PrefetchingCBOWBatcher)
     assert inner.vocab is t.vocab
     assert callable(inner.epoch) and callable(inner.epoch_stencil)
-    batch = t.batcher.batches[0]
-    B = t.batcher.asked[0]
+    # skip-gram is fed per-pair batches ...
+    batch = toy(1).batcher.batches[0]
+    B = toy(1).batcher.asked[0]
+    assert toy(1).batcher.kinds == ["epoch"]
     assert batch.centers.shape == (B,)
     assert batch.contexts.shape == batch.ctx_mask.shape == (B, 2 * WINDOW)
     assert batch.n_words == B
+    # ... a CBOW model spans: full batches of B centers under the
+    # cell's subsampling, in a span sized for them
+    assert t.batcher.kinds == ["epoch_stencil"]
+    from swiftmpi_tpu.data.text import span_positions
+    assert 0.1 < inner.keep_mean < 0.5       # the toy's gate bites hard
+    for batch in t.batcher.batches:
+        S = batch.span
+        assert S == span_positions(B, WINDOW, inner.keep_mean)
+        assert S % 128 == 0 and S >= B / inner.keep_mean + 2 * WINDOW
+        assert batch.tokens.shape == batch.sent_id.shape == (S,)
+        assert batch.center_pos.shape == batch.half.shape == (B,)
+        assert batch.n_words == B and (batch.center_pos >= 0).all()
+        assert batch.pack().shape == (2 * S + 2 * B,)
 
 
 @surface
 def train_call(toy):
     """``train(batcher=, niters=1)``: one loss an iteration, the batch
-    size it asks the batcher for, and ``.window .sample .stencil``."""
+    size it asks the batcher for, and ``.window .sample .stencil`` —
+    the last truthy from ``build_from_vocab`` on for a CBOW model (the
+    harness reads it before the first ``train()`` to pick the batches it
+    peeks), never for skip-gram."""
     t = toy()
     assert len(t.losses) == 1 and math.isfinite(float(t.losses[0]))
     assert t.batcher.asked == [max(256, MINIBATCH // (2 * WINDOW))]
     assert len(t.batcher.batches) == 2
     m = t.model
-    assert (m.window, m.sample, m.stencil) == (WINDOW, 0.0001, 0)
+    assert (m.window, m.sample, m.stencil) == (WINDOW, 0.0001, 1)
+    assert t.stencil_at_build == 1
+    assert (toy(1).model.stencil, toy(1).stencil_at_build) == (0, 0)
 
 
 @surface
@@ -318,7 +353,8 @@ def key_index_lookup(toy):
     free = np.ones(table.capacity, bool)
     free[slots] = False
     v2sum = np.asarray(table.state["v2sum"])
-    trained = t.batcher.batches[0].contexts[t.batcher.batches[0].ctx_mask]
+    first = t.batcher.batches[0]
+    trained = first.tokens[first.sent_id >= 0]
     assert (v2sum[slots[trained]] != v2sum[np.flatnonzero(free)[0]]).any()
 
 
@@ -377,25 +413,29 @@ def sampling_state(toy):
 
 @surface
 def build_step_signature(toy):
-    """``_build_step()``: the positional parameters the harness's
-    ``tools/compile_real_size.py`` lowers by, and ``.lower``."""
-    for sg in (0, 1):
+    """``_build_step()``: the parameters a step is lowered by, and
+    ``.lower``.  Skip-gram's are the per-pair positions the harness's
+    ``tools/compile_real_size.py`` passes; a CBOW model's step takes the
+    span (the tool still passes the per-pair ones: PERF.md section 7)."""
+    for sg, params in ((0, SPAN_STEP_PARAMS), (1, STEP_PARAMS)):
         step = toy(sg).model._build_step()
-        assert tuple(inspect.signature(step).parameters) == STEP_PARAMS
+        assert tuple(inspect.signature(step).parameters) == params
         assert callable(step.lower)
 
 
 @surface
 def resolved_rendering(toy):
     """``resolved_rendering`` under the two configurations' keys: the
-    per-center CBOW step and the per-pair skip-gram step."""
-    assert toy(0).model.resolved_rendering == "gather"
+    CBOW step by span position and the per-pair skip-gram step."""
+    assert toy(0).model.resolved_rendering == "stencil"
     assert toy(1).model.resolved_rendering == "sg"
 
 
 @surface
 def train_metrics(toy):
     """The ``train_metrics`` keys the ``train_metrics`` reader takes."""
+    from swiftmpi_tpu.data.text import stencil_to_cbow
+
     for sg in (0, 1):
         t = toy(sg)
         metrics = t.model.train_metrics
@@ -405,13 +445,22 @@ def train_metrics(toy):
             assert math.isfinite(metrics[key]), (sg, key)
         # at most every slot of the step's two pushes a new row, each
         # written to a parameter and its accumulator
-        slots = [b.ctx_mask.size + b.centers.size * (1 + t.model.negative)
+        masks = [b.ctx_mask if sg else
+                 stencil_to_cbow(b, t.model.window).ctx_mask
                  for b in t.batcher.batches]
-        assert 0 < metrics["rows_written_per_step"] <= 2 * max(slots) * (
+        B = len(masks[0])
+        slots = masks[0].size + B * (1 + t.model.negative)
+        assert 0 < metrics["rows_written_per_step"] <= 2 * slots * (
             2 * t.model.window if sg else 1)
-        valid = [b.ctx_mask.sum() for b in t.batcher.batches]
-        assert metrics["pairs_per_step"] == pytest.approx(np.mean(valid))
-        assert 0 < metrics["pair_fill_share"] <= 100
+        # a span batch's pairs are its expansion's, over the same grid
+        assert metrics["pairs_per_step"] == pytest.approx(
+            np.mean([m.sum() for m in masks]))
+        assert metrics["pair_fill_share"] == pytest.approx(
+            100 * np.mean([m.mean() for m in masks]))
+        assert ("span_rows_per_step" in metrics) == (not sg)
+    spans = [b.span for b in toy(0).batcher.batches]
+    assert toy(0).model.train_metrics["span_rows_per_step"] == \
+        np.mean(spans)
 
 
 def loop_spans_cover_a_call(spans, parents, steps):

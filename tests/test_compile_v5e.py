@@ -7,6 +7,7 @@ compiler, and the topology is described inside a fixture so that every
 worker collects the same tests and only the one given this file loads it.
 """
 
+import hashlib
 import json
 import os
 import re
@@ -82,6 +83,24 @@ def _load_conf(path, config, minibatch):
 HEAD_ROWS = dict.fromkeys(("h", "h2sum", "v", "v2sum"), "head_rows")
 
 
+def _span_positions(config, traffic, centers):
+    """The span the native batcher would cut for the cell's stream: its
+    own rule (``data/text.py::span_positions``) on the expected counts of
+    the traffic's key law (every key once, the rest Zipf or uniform)."""
+    import numpy as np
+
+    from swiftmpi_tpu.data.text import center_keep_mean, span_positions
+    from swiftmpi_tpu.ops.sampling import subsample_keep_prob
+
+    vocab, keys = int(config["vocab_size"]), traffic["keys"]
+    p = np.arange(1, vocab + 1, dtype=np.float64) ** -float(
+        keys.get("exponent", 0.0))
+    counts = 1.0 + (int(traffic["stream_tokens"]) - vocab) * p / p.sum()
+    keep = subsample_keep_prob(counts, float(config["word2vec"]["sample"]))
+    return span_positions(centers, int(config["word2vec"]["window"]),
+                          center_keep_mean(counts, keep))
+
+
 def _w2v_step(topo, tmp_path, monkeypatch, cell, chips=1):
     """(cluster, model, compiled train step) of a word2vec cell on
     ``chips`` described v5e chips, the model built by the calls the
@@ -107,33 +126,58 @@ def _w2v_step(topo, tmp_path, monkeypatch, cell, chips=1):
     # Word2Vec.build_from_vocab's capacity rule
     capacity = max(64, int(vocab * 1.3 / cluster.n_servers) + 1)
     model.table = cluster.create_table("w2v", model.access, capacity)
+    # ... and its rule for the context side's rendering
+    model._resolve_stencil()
     step = model._build_step()
     rep = NamedSharding(cluster.mesh, P())
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=rep)
 
+    if model.stencil:
+        # the span batch as the native batcher packs it
+        model.span = _span_positions(config, traffic, centers)
+        batch = (shape((2 * model.span + 2 * centers,), jnp.int32),)
+        statics = {"centers": centers}
+    else:
+        batch = (shape((centers,), jnp.int32),
+                 shape((centers, 2 * window), jnp.int32),
+                 shape((centers, 2 * window), jnp.bool_))
+        statics = {}
     key = jax.eval_shape(lambda: jax.random.key(0))
     compiled = step.lower(
         model.table.state, shape((vocab,), jnp.int32),
-        shape((vocab,), jnp.float32), shape((vocab,), jnp.int32),
-        shape((centers,), jnp.int32), shape((centers, 2 * window), jnp.int32),
-        shape((centers, 2 * window), jnp.bool_),
-        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=rep)).compile()
+        shape((vocab,), jnp.float32), shape((vocab,), jnp.int32), *batch,
+        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=rep),
+        **statics).compile()
     return cluster, model, compiled
 
 
-@pytest.mark.parametrize("cell, sweeps, temp_gib", [
-    # ~5,000-slot pushes: the shapes rule the sweep out, the head's chunks
-    # are all there is
-    ("cbow2m-demo", 0, 0.01),
-    # 180,224 / 163,840 slots: chunks or one sweep, by the count
-    ("cbow2m-b16k", 4, 1.0),
+def _pair_grid(model, traffic):
+    """Slots of the ``(B, 2W)`` context pair grid a per-pair step of the
+    cell gathers, sorts and pushes."""
+    minibatch = int(traffic.get("minibatch")
+                    or traffic["centers_per_step"] * 2 * model.window)
+    return max(256, minibatch // (2 * model.window)) * 2 * model.window
+
+
+@pytest.mark.parametrize("cell, sweeps, temp_gib, span", [
+    # 5,500 target slots and a span of 768: the shapes rule the sweep
+    # out, the head's chunks are all there is
+    ("cbow2m-demo", 0, 0.01, 768),
+    # 180,224 target slots: chunks or one sweep, by the count; the
+    # context push is the span's 22,528 slots (74.2 % of a Zipf stream's
+    # positions pass the center gate): chunks alone, where the per-pair
+    # grid's 163,840 slots had a conditional and a sweep of their own
+    ("cbow2m-b16k", 2, 1.0, 22_400),
+    # uniform keys: nothing is gated, the span is B + 2W in whole tiles
+    ("cbow2m-b16k-uniform", 2, 1.0, 16_512),
     # 122,880 target slots: either; 20,480 input slots: chunks alone
-    ("sg2m-b2k", 2, 0.7),
+    ("sg2m-b2k", 2, 0.7, None),
 ])
 def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
-                                  monkeypatch, cell, sweeps, temp_gib):
+                                  monkeypatch, cell, sweeps, temp_gib,
+                                  span):
     """A word2vec cell's train step at 2,340,001 rows on one v5e chip.
     The 300-wide rows are stored on 384 lanes (`access.stored_width`), so
     with no layout asked for the four fields come in and go out row-major
@@ -150,6 +194,36 @@ def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
     capacity = model.table.capacity
 
     assert (capacity, model.len_vec, model.row_width) == (2_340_001, 300, 384)
+    # a CBOW cell renders its contexts over the span: the step holds NO
+    # operation at the shape of the (B, 2W) pair grid (ISSUE 36; 15.1 ms
+    # of cbow2m-b16k's 70.7 were five of them); skip-gram is per-pair
+    grid = _pair_grid(model, _cell(cell)[1])
+    assert bool(model.stencil) == (span is not None) == (not model.sg)
+    if span is not None:
+        assert model.span == span
+        centers = grid // (2 * model.window)
+        assert f"s32[{2 * span + 2 * centers}]" in text    # one packed batch
+        assert re.findall(rf"f32\[{span},384\]", text)
+        # no row, gradient or mask of the grid.  What is left at its slot
+        # count is the sampler's: this configuration draws K = 2W = 10
+        # negatives a center, so the (B, K) draw has as many slots
+        assert model.negative == 2 * model.window
+        assert f"[{grid},384]" not in text
+        at_grid = re.findall(rf"= (\w+)\[{grid}[\],].*", text)
+        assert set(at_grid) <= {"s32", "u32"}
+        assert all("/sample/" in line for line in re.findall(
+            rf"= \w+\[{grid}[\],].*op_name=\"([^\"]*)\"", text))
+    else:
+        assert re.findall(rf"= f32\[{grid},384\]", text)
+        # ... and its step is PR 35's instruction for instruction (names,
+        # operands and layouts; the metadata's source lines aside): what
+        # ISSUE 36 changed for CBOW left skip-gram's program alone.  A PR
+        # that changes this step on purpose pins its own digest here.
+        lines = [re.sub(r", metadata=\{[^}]*\}", "", line.rstrip())
+                 for line in text.splitlines()
+                 if re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = ", line)]
+        digest = hashlib.sha256("\n".join(lines).encode()).hexdigest()
+        assert (len(lines), digest[:16]) == (2776, "d2d9fb458098049e")
     field = rf"f32\[{capacity},384\]"
     # the module's first line: aliasing and the entry's layouts
     params, results = re.search(r"entry_computation_layout=\{(.*)",
@@ -191,6 +265,14 @@ def test_w2v_x4_step_keeps_the_sweep(topo, no_compile_cache, tmp_path,
     scatters = re.findall(r"= f32\[\d+,384\]\S* scatter\(.*apply/", text)
     assert len(scatters) == 4
     assert all("indices_are_sorted=true" in s for s in scatters)
+    # a tenth of a Zipf stream's positions fail the center gate at sample
+    # 1e-3: the context side is the span's, and nothing in the step has
+    # the pair grid's 655,360 slots (~142 of the parent's 290.6 ms a step,
+    # a 1.0 GB all-reduce among them)
+    grid = _pair_grid(model, _cell("gnews3m-x4-b64k")[1])
+    assert (model.stencil, grid) == (1, 655_360)
+    assert model.span == 73_344
+    assert not re.findall(rf"\[{grid}[\],]", text)
 
 
 def test_table_is_built_within_its_own_size(topo, no_compile_cache,

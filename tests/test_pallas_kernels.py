@@ -61,6 +61,7 @@ def test_multi_step_scan_matches_single_steps(devices8):
     corpus = synthetic_corpus(20, vocab_size=40, length=12, seed=9)
     model = Word2Vec(config=cfg)
     model.build(corpus)
+    model.stencil = 0      # drives the per-pair builders itself
     batches = list(CBOWBatcher(corpus, model.vocab, 2).epoch(64))[:2]
     import jax.numpy as jnp
     centers = jnp.stack([jnp.asarray(b.centers) for b in batches])
@@ -191,6 +192,7 @@ def test_w2v_step_with_pallas_pull_matches_xla(monkeypatch, devices8):
         m = Word2Vec(config=cfg, cluster=Cluster(cfg).initialize())
         corpus = synthetic_corpus(20, 200, 40, seed=13)
         m.build(corpus)
+        m.stencil = 0      # drives the per-pair builders itself
         step = jax.jit(m._build_step())
         batcher = CBOWBatcher(corpus, m.vocab, m.window, m.sample, seed=5)
         b = next(iter(batcher.epoch(128)))
@@ -264,6 +266,7 @@ def test_w2v_step_with_pallas_scatter_matches_xla(monkeypatch, devices8):
         m = Word2Vec(config=cfg, cluster=Cluster(cfg).initialize())
         corpus = synthetic_corpus(10, 100, 30, seed=17)
         m.build(corpus)
+        m.stencil = 0      # drives the per-pair builders itself
         step = jax.jit(m._build_step())
         batcher = CBOWBatcher(corpus, m.vocab, m.window, m.sample, seed=5)
         b = next(iter(batcher.epoch(64)))

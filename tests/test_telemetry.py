@@ -662,20 +662,30 @@ def test_budget_gate_aggregates_fmt_cells(tmp_path):
 # -- pair counters (ISSUE 26) ----------------------------------------------
 
 class _RecordingBatcher:
-    """Forwards a CBOWBatcher's batches and keeps what was fed."""
+    """Forwards a CBOWBatcher's batches and keeps what was fed: of a
+    span batch (what a CBOW model asks for), the pairs of its per-pair
+    expansion and the span's positions."""
 
     def __init__(self, inner):
         self.inner, self.vocab = inner, inner.vocab
-        self.valid, self.grid = [], 0
+        self.valid, self.grid, self.span_rows = [], 0, []
+
+    def _fed(self, ctx_mask):
+        self.valid.append(int(np.asarray(ctx_mask).sum()))
+        self.grid += ctx_mask.size
 
     def epoch(self, batch_size):
         for batch in self.inner.epoch(batch_size):
-            self.valid.append(int(np.asarray(batch.ctx_mask).sum()))
-            self.grid += batch.ctx_mask.size
+            self._fed(batch.ctx_mask)
             yield batch
 
     def epoch_stencil(self, batch_size):
-        return self.inner.epoch_stencil(batch_size)
+        from swiftmpi_tpu.data.text import stencil_to_cbow
+
+        for batch in self.inner.epoch_stencil(batch_size):
+            self._fed(stencil_to_cbow(batch, self.inner.window).ctx_mask)
+            self.span_rows.append(batch.span)
+            yield batch
 
 
 def _pairs_run(sg, worker, telemetry, tmp_path, extra=None):
@@ -711,9 +721,17 @@ def test_pair_counters_equal_the_batches_fed(sg, worker, tmp_path,
                                              devices8):
     """``pairs_per_step`` / ``pair_fill_share`` and ``train/pairs`` are
     the host batches' ``ctx_mask.sum()`` against their ``(B, 2W)`` grid,
-    whichever way the loop feeds the step."""
+    whichever way the loop feeds the step — and whichever way the batch
+    is rendered: a CBOW model takes spans, whose pairs are counted from
+    the positions (the grid is never built) and equal the expansion's;
+    ``span_rows_per_step`` is what its step pulls and pushes instead."""
     model, fed = _pairs_run(sg, worker, 1, tmp_path)
     m = model.train_metrics
+    assert bool(model.stencil) == (not sg) == bool(fed.span_rows)
+    if sg:
+        assert "span_rows_per_step" not in m
+    else:
+        assert m["span_rows_per_step"] == np.mean(fed.span_rows) == 128
     assert len(fed.valid) > 4 and 0 < sum(fed.valid) < fed.grid
     assert m["pairs_per_step"] == pytest.approx(
         sum(fed.valid) / len(fed.valid), rel=1e-12)
@@ -736,15 +754,19 @@ def test_rows_written_counts_each_push_s_distinct_rows(sg, monkeypatch,
     from swiftmpi_tpu.transfer.xla import XlaTransfer
 
     seen = []
-    real = XlaTransfer.push
 
-    def spying(self, state, slots, grads, access, mean=False):
-        fields = len(access.touched_fields(grads))
-        jax.debug.callback(lambda s: seen.append(
-            fields * np.unique(s[s >= 0]).size), slots)
-        return real(self, state, slots, grads, access, mean)
+    def spy(name):
+        real = getattr(XlaTransfer, name)
 
-    monkeypatch.setattr(XlaTransfer, "push", spying)
+        def spying(self, state, slots, grads, *args, **kwargs):
+            fields = len(args[-1].touched_fields(grads))
+            jax.debug.callback(lambda s: seen.append(
+                fields * np.unique(s[s >= 0]).size), slots)
+            return real(self, state, slots, grads, *args, **kwargs)
+        monkeypatch.setattr(XlaTransfer, name, spying)
+
+    spy("push")           # (state, slots, grads, access)
+    spy("push_span")      # (state, slots, grads, counts, access): CBOW's v
     model, fed = _pairs_run(sg, {}, 1, tmp_path)
     jax.effects_barrier()
     steps = len(fed.valid)
@@ -761,10 +783,11 @@ def test_pair_counters_absent_with_telemetry_off(sg, monkeypatch, tmp_path,
                                                  devices8):
     from swiftmpi_tpu.models import word2vec
 
-    def no_sum(self, ctx_mask):
+    def no_sum(self, *batch):
         raise AssertionError("a pair sum was taken with telemetry off")
 
     monkeypatch.setattr(word2vec._PairCount, "observe", no_sum)
+    monkeypatch.setattr(word2vec._PairCount, "observe_span", no_sum)
     model, fed = _pairs_run(sg, {}, 0, tmp_path)
     assert not list(tmp_path.iterdir())
     assert fed.valid and not obs.get_registry().enabled
@@ -775,19 +798,31 @@ def test_pair_counters_absent_with_telemetry_off(sg, monkeypatch, tmp_path,
 
 
 def test_uncounted_batches_export_no_pair_series(tmp_path, devices8):
-    """Stencil batches carry no mask: nothing is counted, and neither the
-    ``train/pairs`` series nor the ``train_metrics`` keys exist, so a
-    series at 0 cannot be read as "no pairs"."""
-    cfg_stencil = {"word2vec": {"len_vec": 8, "window": 3, "negative": 2,
-                                "sg": 0, "stencil": 1, "sample": -1,
-                                "learning_rate": 0.05}}
-    model, _fed = _pairs_run(0, {}, 1, tmp_path, extra=cfg_stencil)
+    """Batches that are not host arrays (multi-process: already-placed
+    global arrays; here, spans handed over as device arrays) are not
+    counted: neither the ``train/pairs`` series nor the
+    ``train_metrics`` keys exist, so a series at 0 cannot be read as
+    "no pairs"."""
+    import jax.numpy as jnp
+
+    from swiftmpi_tpu.models import word2vec
+
+    class OnDevice(_RecordingBatcher):
+        def epoch_stencil(self, batch_size):
+            for batch in self.inner.epoch_stencil(batch_size):
+                batch.packed = jnp.asarray(batch.pack())
+                yield batch
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sys.modules[__name__], "_RecordingBatcher", OnDevice)
+        model, _fed = _pairs_run(0, {}, 1, tmp_path)
     assert model.resolved_rendering.startswith("stencil")
     assert obs.get_registry().enabled
     assert not [k for k in obs.get_registry().series_keys()
                 if k.startswith("train/pairs")]
-    assert "pairs_per_step" not in model.train_metrics
-    assert "pair_fill_share" not in model.train_metrics
+    for key in ("pairs_per_step", "pair_fill_share", "span_rows_per_step"):
+        assert key not in model.train_metrics
+    assert word2vec._PairCount(obs.get_registry()).steps == 0
 
 
 def test_pair_series_is_declared():

@@ -107,6 +107,7 @@ def test_w2v_cbow_grads_match_numpy(devices8):
     model = make_model()
     sents = corpus(seed=3)
     model.build(sents)
+    model.stencil = 0      # drives the per-pair builders itself
     state = model.table.state
     W2, K, B = 2 * model.window, model.negative, 24
 

@@ -3,11 +3,14 @@ Python/native batcher parity, the sort-free span push, and golden checks
 against both the numpy oracle and the reference-parity gather rendering.
 
 The stencil contract (data/text.py StencilBatch): a batch is a stream
-span of at most ``S = B + 2W`` unique tokens plus per-center positions
-into it, and ``stencil_to_cbow`` expansion reproduces the per-pair
-batcher's stream element for element at the same seed.  The device side
-(models/word2vec.py ``_build_grads_stencil``) gathers only the span
-rows and must match the per-pair math bit-tight.
+span of ``S = span_positions(B, W, keep_mean)`` positions (``B + 2W``
+rounded up to lane tiles without subsampling; enough for ``B`` centers
+under the center gate with it) plus per-center positions into it, and
+``stencil_to_cbow`` expansion reproduces the per-pair batcher's stream
+element for element at the same seed.  The device side
+(models/word2vec.py ``_build_grads_stencil``) pulls, window-sums and
+pushes each span position once, by dense shifted sums, and must match
+the per-pair math bit-tight.
 """
 
 import numpy as np
@@ -17,9 +20,10 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from swiftmpi_tpu.data import native  # noqa: E402
-from swiftmpi_tpu.data.text import (CBOWBatcher, build_vocab,  # noqa: E402
-                                    load_corpus, stencil_to_cbow,
-                                    synthetic_corpus)
+from swiftmpi_tpu.data.text import (CBOWBatcher, Vocab,  # noqa: E402
+                                    build_vocab, load_corpus,
+                                    span_positions, stencil_to_cbow,
+                                    synthetic_corpus, unpack_span)
 from swiftmpi_tpu.models.word2vec import Word2Vec  # noqa: E402
 from swiftmpi_tpu.ops.sampling import sample_alias  # noqa: E402
 from swiftmpi_tpu.testing import cbow_batch_grads  # noqa: E402
@@ -39,6 +43,22 @@ def make_model(stencil=1, **overrides):
         for k, v in kv.items():
             cfg.set(sec, k, v)
     return Word2Vec(config=cfg)
+
+
+def build_per_pair(model, sents):
+    """``model.build(sents)`` held to the per-pair rendering: a CBOW
+    model resolves to spans at build time, and a test that drives the
+    step builders itself (no ``train()``, no batcher to settle it
+    against) says here which one it wants."""
+    model.build(sents)
+    model.stencil = 0
+    return model
+
+
+def span_args(batch):
+    """What the span step takes of a batch: the packed buffer, and the
+    centers it is cut by."""
+    return (jnp.asarray(batch.pack()),), {"centers": len(batch.center_pos)}
 
 
 def corpus(n_sent=40, vocab=30, length=12, seed=0):
@@ -81,8 +101,8 @@ def test_stencil_stream_matches_pair_stream(sample):
 
 def test_stencil_working_set_bounded():
     """The acceptance bound this rendering exists for: every batch's
-    gather working set is at most B + 2W rows — vs B * 2W context
-    gathers in the per-pair layout."""
+    gather working set is at most B + 2W rows (in a span of whole lane
+    tiles) — vs B * 2W context gathers in the per-pair layout."""
     sents = corpus(n_sent=60, seed=7)
     vocab = build_vocab(sents)
     B, W = 32, 3
@@ -90,7 +110,7 @@ def test_stencil_working_set_bounded():
     n_batches = 0
     for b in batcher.epoch_stencil(B):
         n_batches += 1
-        assert b.span == B + 2 * W                  # fixed span capacity
+        assert b.span == span_positions(B, W) == 128  # fixed span capacity
         assert int(np.sum(b.sent_id >= 0)) <= B + 2 * W
         # and strictly below the per-pair working set at this shape
         assert b.span < B * 2 * W
@@ -168,12 +188,18 @@ def test_native_stencil_wire_format_matches_python(corpus_file):
         tokens, offsets, vocab_c, window=W, seed=5).epoch_stencil(B))
     pys = list(CBOWBatcher(load_corpus(corpus_file), vocab_py, W,
                            seed=5).epoch_stencil(B))
+    S = span_positions(B, W)
+    assert S == 128 >= B + 2 * W
     for b in nat + pys:
-        assert b.tokens.dtype == np.int32 and b.tokens.shape == (B + 2 * W,)
+        assert b.tokens.dtype == np.int32 and b.tokens.shape == (S,)
         assert b.sent_id.dtype == np.int32
         assert b.center_pos.dtype == np.int32
         assert b.half.dtype == np.int32
-        assert b.span == B + 2 * W
+        assert b.span == S
+        assert b.pack().dtype == np.int32
+        for got, want in zip(unpack_span(b.pack(), B),
+                             (b.tokens, b.sent_id, b.center_pos, b.half)):
+            np.testing.assert_array_equal(got, want)
         assert (b.center_pos[b.n_words:] == -1).all()
         assert (b.tokens[b.sent_id < 0] == 0).all()
     def coverage(batches):
@@ -296,10 +322,10 @@ def test_stencil_grads_match_numpy_oracle(devices8):
 
     grads_fn = model._build_grads()
     assert model.resolved_rendering == "stencil"
+    span, shape = span_args(batch)
     pushes, es, ec = grads_fn(
         state, model._slot_of_vocab, model._alias_prob, model._alias_idx,
-        jnp.asarray(batch.tokens), jnp.asarray(batch.sent_id),
-        jnp.asarray(batch.center_pos), jnp.asarray(batch.half), key)
+        *span, key, **shape)
     got_h, got_v = _dense_from_pushes(model, pushes)
 
     # identical randomness: the negatives the step drew, in key space
@@ -343,7 +369,7 @@ def test_stencil_step_matches_gather_step(devices8):
     m_st = make_model()
     m_ga = make_model(stencil=0)
     m_st.build(sents)
-    m_ga.build(sents)
+    build_per_pair(m_ga, sents)
     step_st = m_st._build_step()
     step_ga = m_ga._build_step()
     for B in (24, 512):                   # full batch / padded tail
@@ -354,12 +380,11 @@ def test_stencil_step_matches_gather_step(devices8):
         key = jax.random.key(11)
         # the jitted steps DONATE their state argument: hand each call
         # fresh copies so the models' live buffers survive both rounds
+        span, shape = span_args(batch)
         st1, es1, ec1 = step_st(
             {f: jnp.array(v) for f, v in m_st.table.state.items()},
             m_st._slot_of_vocab, m_st._alias_prob,
-            m_st._alias_idx, jnp.asarray(batch.tokens),
-            jnp.asarray(batch.sent_id), jnp.asarray(batch.center_pos),
-            jnp.asarray(batch.half), key)
+            m_st._alias_idx, *span, key, **shape)
         st2, es2, ec2 = step_ga(
             {f: jnp.array(v) for f, v in m_ga.table.state.items()},
             m_ga._slot_of_vocab, m_ga._alias_prob,
@@ -464,3 +489,236 @@ def test_stencil_rejects_multiprocess(monkeypatch):
     m = make_model()
     with pytest.raises(ValueError, match="single-process"):
         m.train(corpus(), niters=1, batch_size=64)
+
+
+# -- the default rendering (ISSUE 36): spans under subsampling --------------
+
+
+def zipf_stream(n_tokens=6000, vocab=200, sentence=40, seed=0):
+    """(vocab, tokens, offsets) of a Zipf-1.0 stream of 40-token
+    sentences with every key once: the benchmark traffic's shape, toy
+    size.  Frequent words fail the center gate at ``sample`` 1e-2."""
+    rng = np.random.default_rng(seed)
+    p = 1.0 / np.arange(1, vocab + 1)
+    ranks = np.concatenate([np.arange(vocab),
+                            rng.choice(vocab, n_tokens - vocab,
+                                       p=p / p.sum())])
+    rng.shuffle(ranks)
+    counts = np.bincount(ranks, minlength=vocab).astype(np.int64)
+    keys = np.arange(1, vocab + 1, dtype=np.uint64)
+    order = np.lexsort((keys, -counts))      # count desc, key asc
+    index_of = np.empty(vocab, np.int32)
+    index_of[order] = np.arange(vocab, dtype=np.int32)
+    voc = Vocab(keys[order], counts[order],
+                dict(zip(keys[order].tolist(), range(vocab))))
+    offsets = np.append(np.arange(0, n_tokens, sentence, dtype=np.int64),
+                        np.int64(n_tokens))
+    return voc, index_of[ranks].astype(np.int32), offsets
+
+
+class PerPairOnly:
+    """A batcher that renders no spans: what keeps a CBOW model on the
+    per-pair rendering."""
+
+    def __init__(self, inner):
+        self.inner, self.vocab = inner, inner.vocab
+
+    def epoch(self, batch_size):
+        return self.inner.epoch(batch_size)
+
+
+SAMPLE = 1e-2
+
+
+def default_model(**overrides):
+    """A CBOW model as a cell's conf leaves it: no ``stencil`` key."""
+    return make_model(stencil=0, word2vec={"sample": SAMPLE, "window": 3},
+                      **overrides)
+
+
+@needs_native
+def test_native_spans_hold_full_batches_under_subsampling():
+    """The native span batcher sizes its span from its own keep
+    probabilities, so under subsampling every batch but the epoch's last
+    closes at B centers, none on a full span — and the expanded stream
+    is ``epoch()``'s at the same seed, element for element."""
+    voc, tokens, offsets = zipf_stream()
+    B, W = 256, 3
+    sten = native.NativeCBOWBatcher(tokens, offsets, voc, W, SAMPLE, seed=7)
+    pair = native.NativeCBOWBatcher(tokens, offsets, voc, W, SAMPLE, seed=7)
+    assert 0.5 < sten.keep_mean < 0.9        # the gate bites
+    S = span_positions(B, W, sten.keep_mean)
+    assert S % 128 == 0 and S > B / sten.keep_mean + 2 * W
+    assert span_positions(B, W) == 384       # none gated: B + 2W, in tiles
+    batches = list(sten.epoch_stencil(B))
+    assert len(batches) > 8
+    assert [b.n_words for b in batches[:-1]] == [B] * (len(batches) - 1)
+    for b in batches:
+        assert b.span == S and b.packed.shape == (2 * S + 2 * B,)
+        assert np.shares_memory(b.tokens, b.packed)
+        assert int((b.sent_id >= 0).sum()) < S           # never full
+    want = _pair_stream(pair.epoch(B))
+    got = _pair_stream(stencil_to_cbow(b, W) for b in batches)
+    assert len(want) > 8 * B and got == want
+
+
+@needs_native
+@pytest.mark.parametrize("which", ["full", "padded_tail"])
+def test_span_step_equals_per_pair_step_row_for_row(which, devices8):
+    """The dense-shift rendering against the per-pair step on the
+    expansion of the same batch, same key: every touched row of ``h``,
+    ``v``, ``h2sum``, ``v2sum`` and the loss, on a Zipf stream under
+    ``sample`` > 0 — sentence boundaries every 40 tokens, shrunk
+    windows, gated centers, duplicate tokens all over the span, and (the
+    epoch's tail) padded centers."""
+    voc, tokens, offsets = zipf_stream()
+    m_sp, m_pp = default_model(), default_model()
+    m_sp.build_from_vocab(voc)
+    m_pp.build_from_vocab(voc)
+    assert m_sp.stencil == 1 and m_sp._build_grads() \
+        and m_sp.resolved_rendering == "stencil"
+    m_pp.stencil = 0
+    B, W = 256, m_sp.window
+    batches = list(native.NativeCBOWBatcher(
+        tokens, offsets, voc, W, SAMPLE, seed=3).epoch_stencil(B))
+    batch = batches[0] if which == "full" else batches[-1]
+    assert (batch.n_words == B) == (which == "full")
+    real = batch.tokens[batch.sent_id >= 0]
+    assert len(np.unique(real)) < 0.5 * len(real)        # duplicates
+    assert len(np.unique(batch.sent_id)) > 5             # boundaries
+    assert len(np.unique(batch.half[:batch.n_words])) == W    # shrunk
+    # gated centers: positions inside the span that are no center
+    assert batch.n_words < 0.9 * int((batch.sent_id >= 0).sum())
+    exp = stencil_to_cbow(batch, W)
+    key = jax.random.key(5)
+
+    def fresh(m):
+        return {f: jnp.array(v) for f, v in m.table.state.items()}
+
+    before = {f: np.asarray(v) for f, v in m_sp.table.state.items()}
+    span, shape = span_args(batch)
+    got, es1, ec1 = m_sp._build_step()(
+        fresh(m_sp), m_sp._slot_of_vocab, m_sp._alias_prob,
+        m_sp._alias_idx, *span, key, **shape)
+    want, es2, ec2 = m_pp._build_step()(
+        fresh(m_pp), m_pp._slot_of_vocab, m_pp._alias_prob,
+        m_pp._alias_idx, jnp.asarray(exp.centers),
+        jnp.asarray(exp.contexts), jnp.asarray(exp.ctx_mask), key)
+    assert int(ec1) == int(ec2) > 0
+    np.testing.assert_allclose(float(es1), float(es2), rtol=1e-5)
+    for f in ("h", "v", "h2sum", "v2sum"):
+        a, b = np.asarray(got[f]), np.asarray(want[f])
+        moved = np.any(b != before[f], axis=1)
+        assert moved.sum() > 20, f
+        # the same rows move, and to the same values
+        np.testing.assert_array_equal(np.any(a != before[f], axis=1), moved)
+        np.testing.assert_allclose(a[moved], b[moved], atol=1e-6, rtol=1e-5,
+                                   err_msg=f)
+
+
+def test_uncovered_span_position_writes_no_row(devices8):
+    """A span position no center's window covers is pushed as slot -1:
+    its word's ``v`` row (and accumulator) stays bit for bit, even where
+    the word is in the vocabulary and the span names it."""
+    from swiftmpi_tpu.data.text import StencilBatch
+
+    m = make_model(stencil=0, word2vec={"window": 3})
+    m.build(corpus(seed=3))
+    assert m.stencil == 1
+    V, S, B = len(m.vocab), 128, 8
+    tokens = np.zeros(S, np.int32)
+    sent = np.full(S, -1, np.int32)
+    # one sentence of 12 positions: words 1..12; centers at 2 and 3 with
+    # half 1 reach positions 1..4 only; a second sentence of 3 with its
+    # middle the center, half 3
+    tokens[:12], sent[:12] = np.arange(1, 13), 0
+    tokens[12:15], sent[12:15] = [13, 14, 15], 1
+    cpos = np.full(B, -1, np.int32)
+    half = np.zeros(B, np.int32)
+    cpos[:3], half[:3] = [2, 3, 13], [1, 1, 3]
+    batch = StencilBatch(tokens, sent, cpos, half, 3)
+    assert V > 16
+    span, shape = span_args(batch)
+    grads_fn = m._build_grads()
+    pushes, _es, _ec = grads_fn(
+        m.table.state, m._slot_of_vocab, m._alias_prob, m._alias_idx,
+        *span, jax.random.key(1), **shape)
+    v_push = next(p for p in pushes if "v" in p.grads)
+    covered = np.asarray(v_push.slots) >= 0
+    assert covered[[1, 2, 3, 4, 12, 14]].all()     # in a window
+    assert not covered[[0, 5, 11, 13]].any()       # in none (13: a center
+    assert covered.sum() == 6                      # alone is no context)
+    np.testing.assert_array_equal(
+        np.asarray(v_push.counts)[:6], [0, 1, 1, 1, 1, 0])
+    before = {f: np.asarray(v) for f, v in m.table.state.items()}
+    after, _es, _ec = m._build_step()(
+        {f: jnp.array(v) for f, v in m.table.state.items()},
+        m._slot_of_vocab, m._alias_prob, m._alias_idx, *span,
+        jax.random.key(1), **shape)
+    sov = np.asarray(m._slot_of_vocab)
+    for f in ("v", "v2sum"):
+        a = np.asarray(after[f])
+        moved = np.any(a != before[f], axis=1)
+        assert moved[sov[tokens[[1, 2, 3, 4, 12, 14]]]].all(), f
+        assert not moved[sov[tokens[[0, 5, 11, 13]]]].any(), f
+        assert moved.sum() == 6, f
+
+
+@needs_native
+@pytest.mark.parametrize("worker, w2v", [
+    ({}, {}), ({"inner_steps": 3}, {}), ({"pipeline": 2}, {}),
+    ({}, {"local_steps": 2})],
+    ids=["single", "fused", "pipelined", "snapshot"])
+def test_span_train_equals_per_pair_train(worker, w2v, devices8):
+    """Through ``train()``, every loop: a CBOW model handed the native
+    batcher resolves to spans on its own and walks the per-pair run's
+    trajectory (same stream, same keys) — and handed a batcher that
+    renders none it keeps the per-pair rendering, visibly."""
+    voc, tokens, offsets = zipf_stream()
+
+    def run(wrap):
+        m = default_model(worker=worker)
+        for k, v in w2v.items():
+            m.config.set("word2vec", k, v)
+            setattr(m, k, v)
+        m.build_from_vocab(voc)
+        assert m.stencil == 1                # at build time, before train
+        inner = native.NativeCBOWBatcher(tokens, offsets, voc, m.window,
+                                         SAMPLE, seed=11)
+        losses = m.train(batcher=wrap(inner), niters=2, batch_size=256)
+        return m, losses
+
+    m_sp, got = run(lambda b: b)
+    m_pp, want = run(PerPairOnly)
+    assert (m_sp.stencil, m_sp.resolved_rendering) == (1, "stencil")
+    assert (m_pp.stencil, m_pp.resolved_rendering) == (0, "gather")
+    assert got[-1] < got[0]
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    # a model that fell back takes spans again when it is handed them
+    inner = native.NativeCBOWBatcher(tokens, offsets, voc, m_pp.window,
+                                     SAMPLE, seed=12)
+    m_pp.train(batcher=inner, niters=1, batch_size=256)
+    assert (m_pp.stencil, m_pp.resolved_rendering) == (1, "stencil")
+
+
+@pytest.mark.parametrize("overrides, why", [
+    ({"word2vec": {"sg": 1}}, "skip-gram is per-pair by nature"),
+    ({"word2vec": {"async_mode": "hogwild"}}, "hogwild groups per-pair"),
+    ({"word2vec": {"dense_logits": 1}}, "another rendering was asked for"),
+    ({"cluster": {"transfer": "local"}}, "no counted push"),
+])
+def test_who_keeps_the_per_pair_rendering(overrides, why, devices8):
+    m = make_model(stencil=0, **overrides)
+    m.build(corpus(seed=3))
+    assert m.stencil == 0, why
+
+
+def test_python_batcher_of_train_keeps_per_pair(devices8):
+    """``train(sentences)`` makes the Python batcher, whose stream the
+    per-pair tests pin: the model settles on per-pair batches there."""
+    m = make_model(stencil=0)
+    sents = corpus(seed=3)
+    m.build(sents)
+    assert m.stencil == 1
+    m.train(sents, niters=1, batch_size=64)
+    assert (m.stencil, m.resolved_rendering) == (0, "gather")

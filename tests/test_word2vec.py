@@ -535,6 +535,7 @@ def test_w2v_shared_negatives_grads_match_numpy(devices8):
                                  "negative": 4, "len_vec": 8, "window": 2})
     corpus = synthetic_corpus(10, vocab_size=30, length=10, seed=5)
     model.build(corpus)
+    model.stencil = 0      # drives the per-pair builders itself
     B, W2 = 24, 4
     V = len(model.vocab)
     rng = np.random.default_rng(2)
@@ -640,6 +641,7 @@ def test_w2v_sg_shared_grads_match_numpy(devices8):
                                  "len_vec": 8, "window": 2})
     corpus = synthetic_corpus(10, vocab_size=30, length=10, seed=5)
     model.build(corpus)
+    model.stencil = 0      # drives the per-pair builders itself
     B, W2 = 24, 4
     V = len(model.vocab)
     rng = np.random.default_rng(2)
@@ -908,6 +910,7 @@ def test_w2v_dense_logits_matches_parity_step(devices8):
     def run(dense):
         m = make_model(word2vec={"dense_logits": int(dense)})
         m.build(corpus)
+        m.stencil = 0      # drives the per-pair builders itself
         step = jax.jit(m._build_step())
         batcher = CBOWBatcher(corpus, m.vocab, m.window, m.sample,
                               seed=5)
@@ -973,6 +976,11 @@ def test_w2v_dense_logits_auto_gate(monkeypatch, tmp_path, devices8):
     m = make_model()
     assert m.dense_logits is None          # the auto default
     m.build(corpus)
+    m._build_grads()
+    # a CBOW model renders its contexts by span position by default; the
+    # gate is the per-pair path's (a batcher that renders no spans)
+    assert m.resolved_rendering == "stencil"
+    m.stencil = 0
     m._build_grads()
     assert m.resolved_rendering == "gather"
 

@@ -150,7 +150,7 @@ def test_write_back_forms_match_oracle_bitwise(case, width, mean, sharded,
 
 # slots of a push, the field it writes (a 300-wide row is stored on 384
 # lanes), the table's shards, the devices' platform -> the form.  The
-# first six are the benchmark cells' own pushes (PERF.md section 4): on
+# first seven are the benchmark cells' own pushes (PERF.md section 4): on
 # the CPU the two forms the shapes choose between, on a TPU the head's
 # rows wherever the field is one `write_back_form` gives them for.
 CELLS = [
@@ -158,7 +158,8 @@ CELLS = [
     (5_500, 2_340_001, 384, 1, "per_row"),       # cbow2m-demo, targets
     (20_480, 2_340_001, 384, 1, "per_row"),      # sg2m-b2k, inputs
     (122_880, 2_340_001, 384, 1, "sweep"),       # sg2m-b2k, targets
-    (163_840, 2_340_001, 384, 1, "sweep"),       # cbow2m-b16k, contexts
+    (163_840, 2_340_001, 384, 1, "sweep"),       # cbow2m-b16k, the pair grid
+    (22_400, 2_340_001, 384, 1, "per_row"),      # ... its span (PR 36)
     (655_360, 3_900_004, 384, 4, "sweep"),       # gnews3m-x4-b64k
 ]
 
@@ -170,7 +171,7 @@ CELLS = [
     (1_000, 1 << 20, 1, 1, "cpu", "sweep"),          # d = 1: a cheap sweep
     (100, 1 << 20, 1, 1, "cpu", "per_row"),
     # one TPU, rows of whole 128-lane tiles: the head's rows, any size
-    *((*c[:4], "tpu", "head_rows") for c in CELLS[:5]),
+    *((*c[:4], "tpu", "head_rows") for c in CELLS[:6]),
     (100_000, 3_900_004, 128, 1, "tpu", "head_rows"),
     # a TPU, and everything else keeps the shapes' answer: four shards,
     # 300 wide (column-major by default), one wide
@@ -197,9 +198,13 @@ def test_write_back_form_wants_f32_rows_and_names_its_platform():
     assert XlaTransfer().platform == jax.devices()[0].platform
 
 
-def test_span_push_writes_row_by_row(monkeypatch):
-    """``push_span``'s owner rows are position-ordered, never ascending:
-    it shares the helper and always takes the per-row form."""
+@pytest.mark.parametrize("mean", [False, True])
+def test_span_push_is_the_sparse_push_with_counts(monkeypatch, mean):
+    """``push_span`` (position-ordered rows, each the sum of ``counts[i]``
+    contributions) sorts its slots like any sparse push and writes the
+    distinct rows back in the form the shapes give (PR 36; before it,
+    always row by row from unsorted owner positions): the oracle's rows,
+    ``mean`` dividing by the summed counts."""
     from swiftmpi_tpu.transfer import xla
     seen = []
     real = xla._set_rows
@@ -211,10 +216,17 @@ def test_span_push_writes_row_by_row(monkeypatch):
                                          capacity_per_shard=CAP_PER_SHARD))
     slots = np.array([5, 3, 5, -1, 9, 3], np.int32)
     grads = {"val": np.arange(6, dtype=np.float32)[:, None] / 8}
-    counts = np.ones(6, np.float32)
+    counts = np.array([2, 1, 3, 4, 1, 0], np.float32)
     state_np = {f: np.asarray(v) for f, v in table.state.items()}
-    want = LocalTransfer().push_span(state_np, slots, grads, counts, access)
-    got = XlaTransfer().push_span(table.state, slots, grads, counts, access)
-    assert seen == [False, False]     # val, grad2sum
+    want = LocalTransfer().push_span(state_np, slots, grads, counts, access,
+                                     mean=mean)
+    backend = XlaTransfer()
+    got = backend.push_span(table.state, slots, grads, counts, access,
+                            mean=mean)
+    form = backend.write_back_form(6, [table.state[f] for f in access.fields])
+    assert form in ("per_row", "sweep")
+    assert seen == [form == "sweep"] * 2          # val, grad2sum
+    assert backend.resolved_write_back == dict.fromkeys(access.fields, form)
     for f in access.fields:
-        np.testing.assert_array_equal(want[f], np.asarray(got[f]), err_msg=f)
+        np.testing.assert_allclose(want[f], np.asarray(got[f]), rtol=1e-6,
+                                   atol=1e-7, err_msg=f)
