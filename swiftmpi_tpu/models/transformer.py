@@ -21,13 +21,18 @@ the framework would compose them:
 
 Architecture: pre-RMSNorm, an output head that is the embedding's transpose
 (``tied_head``) or a matrix of its own, and a stack whose layers need not
-be alike: each layer has an *operator* (attention with RoPE, grouped KV
-heads of any ``d_head`` and optional per-head QK norm, or a gated short
-convolution) and an *FFN* (dense SiLU-gated, or the routed expert layer of
-parallel/moe.py).  ``layer_ops`` / ``layer_ffns`` name them per layer; runs
-of equal layers are stacked on a leading axis and scanned, so a stack
-compiles one body per run (left empty, every layer is attention +
-``n_experts``'s FFN: the homogeneous stack ``pipeline_apply`` wants).
+be alike: each layer has an *operator* (attention with grouped KV heads of
+any ``d_head``, optional per-head QK norm and an optional output gate — in
+one of the kinds of ``ATTENTION_OPS``: causal with RoPE, causal over a
+sliding ``window`` with RoPE, or causal with no position embedding — or a
+gated short convolution) and an *FFN* (dense SiLU-gated, or the routed
+expert layer of parallel/moe.py, beside shared experts every token runs
+through where ``n_shared_experts``).  ``layer_ops`` / ``layer_ffns`` name
+them per layer; runs of equal layers are stacked on a leading axis and
+scanned, so a stack compiles one body per run (left empty, every layer is
+attention + ``n_experts``'s FFN: the homogeneous stack ``pipeline_apply``
+wants).  ``sandwich_norm`` norms each half layer's update again before the
+residual takes it; ``embed_scale`` multiplies the embedding rows.
 
 Objective (``objective``): ``next_token`` — causal attention, cross entropy
 of the next token — or ``block_diffusion`` (models/diffusion.py): the trunk
@@ -57,14 +62,20 @@ from swiftmpi_tpu.parallel.moe import (MoEParams, MoEStats, expert_layer,
                                        init_moe_params, moe_ffn)
 from swiftmpi_tpu.parallel.pipeline import (pipeline_apply,
                                             stack_stage_params)
-from swiftmpi_tpu.parallel.ring_attention import (CAUSAL,
+from swiftmpi_tpu.parallel.ring_attention import (CAUSAL, WindowMask,
                                                   blockwise_attention,
                                                   full_attention,
                                                   ring_attention,
                                                   ulysses_attention)
 
 
-OPS = ("attention", "conv")
+#: attention operators -> (device scope, over ``cfg.window`` only, RoPE).
+#: ``full`` is what a stack that mixes it with ``sliding`` layers means by
+#: it: every earlier position, and no position embedding at all
+ATTENTION_OPS = {"attention": ("attention", False, True),
+                 "sliding": ("window_attention", True, True),
+                 "full": ("attention", False, False)}
+OPS = (*ATTENTION_OPS, "conv")
 FFNS = ("dense", "moe")
 OBJECTIVES = ("next_token", "block_diffusion")
 
@@ -111,6 +122,13 @@ class TransformerConfig:
     loss_chunk: int = 0              # tokens a head + loss chunk; 0 = all
     d_head: int = 0                  # a head's width; 0 => d_model / n_heads
     tied_head: bool = True           # the head is the embedding's transpose
+    window: int = 0                  # a "sliding" layer's window, in positions
+    attn_gate: bool = False          # o * sigmoid(h W_g) before W_o
+    sandwich_norm: bool = False      # x + RMSNorm(update), both half layers
+    embed_scale: float = 1.0         # x = embed[tok] * embed_scale
+    n_shared_experts: int = 0        # experts every token runs beside the
+                                     # routed ones, each an expert's width
+    route_scale: float = 1.0         # what a token's routing weights sum to
     # -- the objective ------------------------------------------------------
     objective: str = "next_token"    # of OBJECTIVES
     diffusion_block: int = 4         # block_diffusion: positions a block
@@ -126,6 +144,19 @@ class TransformerConfig:
             raise ValueError("objective 'block_diffusion' needs attention "
                              f"'blockwise', not {self.attention!r}: the "
                              "other variants are causal")
+        self.layer_kinds()           # unknown kinds and counts, by name
+        if "sliding" in self.layer_ops:
+            if self.window < 1:
+                raise ValueError("a 'sliding' layer needs window >= 1, not "
+                                 f"{self.window}")
+            if self.attention != "blockwise":
+                raise ValueError("a 'sliding' layer needs attention "
+                                 f"'blockwise', not {self.attention!r}: the "
+                                 "other variants see the whole prefix")
+            if self.objective != "next_token":
+                raise ValueError("a 'sliding' layer brings its own mask; "
+                                 f"objective {self.objective!r} brings one "
+                                 "for every layer")
 
     @property
     def head_dim(self) -> int:
@@ -183,12 +214,17 @@ def _init_block(k, cfg: TransformerConfig, op: str, ffn: str):
 
     blk = {"ln1": jnp.ones((d,), cfg.dtype),
            "ln2": jnp.ones((d,), cfg.dtype)}
-    if op == "attention":
+    if cfg.sandwich_norm:
+        blk.update(ln1_post=jnp.ones((d,), cfg.dtype),
+                   ln2_post=jnp.ones((d,), cfg.dtype))
+    if op in ATTENTION_OPS:
         blk.update(wq=mat(d, cfg.n_heads * Dh), wk=mat(d, cfg.kv_heads * Dh),
                    wv=mat(d, cfg.kv_heads * Dh), wo=mat(cfg.n_heads * Dh, d))
         if cfg.qk_norm:
             blk.update(q_norm=jnp.ones((Dh,), cfg.dtype),
                        k_norm=jnp.ones((Dh,), cfg.dtype))
+        if cfg.attn_gate:
+            blk.update(wg=mat(d, cfg.n_heads * Dh))
     else:
         blk.update(conv_in=mat(d, 3 * d), conv_out=mat(d, d),
                    conv_w=jax.random.normal(
@@ -196,10 +232,15 @@ def _init_block(k, cfg: TransformerConfig, op: str, ffn: str):
                    * (cfg.init_std or 1.0 / math.sqrt(cfg.conv_kernel)))
     if ffn == "moe":
         lo, hi = cfg.held
+        width = cfg.d_expert or cfg.d_ff
         blk["moe"] = init_moe_params(
-            next(ks), d, cfg.d_expert or cfg.d_ff, cfg.n_experts, cfg.dtype,
+            next(ks), d, width, cfg.n_experts, cfg.dtype,
             held=hi - lo, gated=cfg.expert_gated,
             bias=cfg.router == "sigmoid_bias", std=cfg.init_std)
+        if cfg.n_shared_experts:
+            h = cfg.n_shared_experts * width
+            blk.update(shared_gate=mat(d, h), shared_up=mat(d, h),
+                       shared_down=mat(h, d))
     else:
         h = cfg.d_ff
         blk.update(w_gate=mat(d, h), w_up=mat(d, h), w_down=mat(h, d))
@@ -271,9 +312,10 @@ def param_shardings(params, cfg: TransformerConfig, mesh: Mesh,
     del data_axis  # params are never dp-sharded; activations are
 
     def spec(path: str, leaf) -> P:
-        if path in ("wq", "wk", "wv", "w_gate", "w_up"):
+        if path in ("wq", "wk", "wv", "wg", "w_gate", "w_up", "shared_gate",
+                    "shared_up"):
             return P(None, None, model_axis)      # (L, d, d|dff) col-shard
-        if path in ("wo", "w_down"):
+        if path in ("wo", "w_down", "shared_down"):
             return P(None, model_axis, None)      # (L, dff|d, d) row-shard
         if path == "w_in":
             return P(None, None, None, model_axis)   # (L, E, d, dff)
@@ -328,13 +370,20 @@ def _rope(x, base: float, positions):
     return jnp.concatenate([rot1, rot2], -1).astype(x.dtype)
 
 
+def _post_norm(y, blk, gain: str, cfg: TransformerConfig):
+    """A half layer's update ``y``, normed again before the residual takes
+    it where the stack has sandwich norms (``blk`` holds ``gain``)."""
+    return _rms_norm(y, blk[gain], cfg.norm_eps) if gain in blk else y
+
+
 def _attention(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
-               seq_axis: str, positions=None, mask=CAUSAL):
+               seq_axis: str, positions=None, mask=CAUSAL, rotary=True):
     """Attention at the position ids ``positions`` ((S,) f32; default
-    ``0..S-1``) under ``mask`` (``blockwise_attention``'s contract;
-    default causal).  The layer does not know the objective: whoever
-    builds another input than a plain sequence says where its positions
-    stand and who sees whom (``diffusion.attention_inputs``)."""
+    ``0..S-1``; unused without ``rotary``) under ``mask``
+    (``blockwise_attention``'s contract; default causal).  The layer does
+    not know the objective: whoever builds another input than a plain
+    sequence says where its positions stand and who sees whom
+    (``diffusion.attention_inputs``)."""
     B, S, d = x.shape
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     if mask is not CAUSAL and cfg.attention != "blockwise":
@@ -347,10 +396,11 @@ def _attention(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
     if cfg.qk_norm:
         q = _rms_norm(q, blk["q_norm"], cfg.norm_eps)
         k = _rms_norm(k, blk["k_norm"], cfg.norm_eps)
-    if positions is None:
-        positions = jnp.arange(S, dtype=jnp.float32)
-    q = _rope(q, cfg.rope_base, positions)
-    k = _rope(k, cfg.rope_base, positions)
+    if rotary:
+        if positions is None:
+            positions = jnp.arange(S, dtype=jnp.float32)
+        q = _rope(q, cfg.rope_base, positions)
+        k = _rope(k, cfg.rope_base, positions)
     if cfg.matmul_dtype is not None:
         q, k, v = (t.astype(cfg.matmul_dtype) for t in (q, k, v))
     # like _ffn: the collective variants need their axis on the mesh;
@@ -367,7 +417,10 @@ def _attention(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
             o = ulysses_attention(q, k, v, mesh, axis=seq_axis, causal=True)
         else:
             o = full_attention(q, k, v, causal=True)
-    return x + _mm(o.reshape(B, S, H * Dh).astype(x.dtype), blk["wo"], cfg)
+    o = o.reshape(B, S, H * Dh).astype(x.dtype)
+    if cfg.attn_gate:
+        o = o * jax.nn.sigmoid(_mm(h, blk["wg"], cfg))
+    return x + _post_norm(_mm(o, blk["wo"], cfg), blk, "ln1_post", cfg)
 
 
 def _short_conv(blk, x, cfg: TransformerConfig):
@@ -380,11 +433,17 @@ def _short_conv(blk, x, cfg: TransformerConfig):
     b, c, u = jnp.split(_mm(h, blk["conv_in"], cfg), 3, axis=-1)
     bu = jnp.pad(b * u, ((0, 0), (K - 1, 0), (0, 0)))
     z = sum(blk["conv_w"][j] * bu[:, j:j + S] for j in range(K))
-    return x + _mm(c * z, blk["conv_out"], cfg)
+    return x + _post_norm(_mm(c * z, blk["conv_out"], cfg), blk, "ln1_post",
+                          cfg)
 
 
 def _no_stats() -> MoEStats:
     return MoEStats(*(jnp.float32(0.0),) * len(MoEStats._fields))
+
+
+def _swiglu(h, w_gate, w_up, w_down, cfg: TransformerConfig):
+    return _mm(jax.nn.silu(_mm(h, w_gate, cfg)) * _mm(h, w_up, cfg), w_down,
+               cfg)
 
 
 def _ffn(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
@@ -397,17 +456,29 @@ def _ffn(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
         if mesh is not None and expert_axis in mesh.axis_names:
             y, aux = moe_ffn(blk["moe"], tokens, mesh, axis=expert_axis,
                              k=cfg.moe_top_k, router=cfg.router,
-                             compute_dtype=cfg.matmul_dtype)
+                             compute_dtype=cfg.matmul_dtype,
+                             route_scale=cfg.route_scale)
             stats = _no_stats()
         else:
             y, aux, stats = expert_layer(
                 blk["moe"], tokens, k=cfg.moe_top_k, router=cfg.router,
-                held=cfg.held, compute_dtype=cfg.matmul_dtype)
-        return x + y.astype(x.dtype).reshape(B, S, d), aux, stats
+                held=cfg.held, compute_dtype=cfg.matmul_dtype,
+                route_scale=cfg.route_scale)
+        y = y.astype(x.dtype)
+        if cfg.n_shared_experts:
+            # every token, on every chip that shares the layer: a dense
+            # product outside the routed experts' sort and groups
+            with obs.named_scope("shared_expert"):
+                y = y + _swiglu(tokens, blk["shared_gate"], blk["shared_up"],
+                                blk["shared_down"], cfg)
+        y = y.reshape(B, S, d)
+        with obs.named_scope("route"):
+            y = _post_norm(y, blk, "ln2_post", cfg)
+        return x + y, aux, stats
     with obs.named_scope("dense_ffn"):
         h = _rms_norm(x, blk["ln2"], cfg.norm_eps)
-        y = _mm(jax.nn.silu(_mm(h, blk["w_gate"], cfg))
-                * _mm(h, blk["w_up"], cfg), blk["w_down"], cfg)
+        y = _post_norm(_swiglu(h, blk["w_gate"], blk["w_up"], blk["w_down"],
+                               cfg), blk, "ln2_post", cfg)
     return x + y, jnp.float32(0.0), _no_stats()
 
 
@@ -427,9 +498,13 @@ def _remat_policy(cfg: TransformerConfig):
 
 def _operator(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
               seq_axis: str, op: str, **attn):
-    if op == "attention":
-        with obs.named_scope("attention"):
-            return _attention(blk, x, cfg, mesh, seq_axis, **attn)
+    if op in ATTENTION_OPS:
+        scope, windowed, rotary = ATTENTION_OPS[op]
+        if windowed:
+            attn = {**attn, "mask": WindowMask(cfg.window)}
+        with obs.named_scope(scope):
+            return _attention(blk, x, cfg, mesh, seq_axis, rotary=rotary,
+                              **attn)
     with obs.named_scope("conv"):
         return _short_conv(blk, x, cfg)
 
@@ -443,6 +518,11 @@ def block_apply(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh] = None,
     op, ffn = kind or cfg.layer_kinds()[0]
     x = _operator(blk, x, cfg, mesh, seq_axis, op, **attn)
     return _ffn(blk, x, cfg, mesh, expert_axis, ffn)
+
+
+def _embed(params, tokens, cfg: TransformerConfig):
+    x = params["embed"][tokens]
+    return x if cfg.embed_scale == 1.0 else x * cfg.embed_scale
 
 
 def _groups(params, cfg: TransformerConfig):
@@ -461,7 +541,7 @@ def trunk(params, tokens, cfg: TransformerConfig,
     ``positions`` and ``mask``) says otherwise: the ``block_diffusion``
     loss runs ``[x_t ; x_0]`` (B, 2S) with ``diffusion.attention_inputs``."""
     with obs.named_scope("embed"):
-        x = params["embed"][tokens]
+        x = _embed(params, tokens, cfg)
     carry = (x, jnp.float32(0.0), _no_stats())
 
     # one compiled block body for each run of equal layers, whatever its
@@ -501,7 +581,7 @@ def hidden_states(params, tokens, cfg: TransformerConfig, **attn):
     layer ``i``'s operator, ``m_i`` the input of its FFN and ``x_L`` the
     last layer's output (for holding each operator and FFN to a reference
     on the program's own input).  ``attn`` as :func:`trunk` takes it."""
-    x = params["embed"][tokens]
+    x = _embed(params, tokens, cfg)
     out = [x]
     for (op, ffn), n, stacked in _groups(params, cfg):
         for i in range(n):
@@ -526,7 +606,7 @@ def forward_pipelined(params, tokens, cfg: TransformerConfig, mesh: Mesh,
     if cfg.n_experts or cfg.attention != "full" or cfg.heterogeneous:
         raise ValueError("pipelined trunk requires full attention and "
                          "dense FFN (nested shard_map is not supported)")
-    x = params["embed"][tokens]
+    x = _embed(params, tokens, cfg)
 
     def stage_fn(blk, act):
         return block_apply(blk, act, cfg, None)[0]

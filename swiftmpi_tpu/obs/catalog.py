@@ -154,6 +154,7 @@ DEVICE_SCOPES = {
     "embed": "embed",              # the embedding rows' gather
     "conv": "conv",                # gated short convolution operator
     "attention": "attention",      # QKV, QK norm, RoPE, softmax(QK)V, out
+    "window_attention": "window_attention",   # ... of a sliding layer
     "route": "route",              # router, top-k, sort, un-sort, weights
     "experts": "experts",          # the grouped products over held experts
     # jax.lax.ragged_dot as the TPU compiler renders it: a custom call
@@ -161,6 +162,7 @@ DEVICE_SCOPES = {
     # kernel's name, like pallas_ring_push; only the experts call it)
     "ragged-dot-none": "experts",
     "ragged-dot-metadata": "experts",
+    "shared_expert": "shared_expert",   # the experts every token runs
     "dense_ffn": "dense_ffn",      # the dense SwiGLU FFN
     "head": "head",                # final norm, head, cross entropy
     "optimizer": "optimizer",      # clip + AdamW + apply

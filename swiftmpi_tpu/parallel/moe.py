@@ -31,7 +31,8 @@ ever dropped**, whatever the routing.
 Routers: ``"softmax"`` (GShard/Switch: top-k of the softmax, renormalised,
 with the load-balance auxiliary loss) and ``"sigmoid_bias"`` (selection on
 ``sigmoid(logits) + bias`` with ``bias`` a fixed buffer, weights from the
-unbiased scores, renormalised over the picks; no auxiliary loss).
+unbiased scores, renormalised over the picks; no auxiliary loss); either
+may scale the renormalised weights (``route_scale``).
 Experts: SwiGLU (``w_gate`` given) or the ungated ReLU pair.
 """
 
@@ -106,9 +107,11 @@ def init_moe_params(key, d_model: int, d_ff: int, n_experts: int,
 
 # -- routing -----------------------------------------------------------------
 
-def route(x: jax.Array, router: jax.Array, bias, k: int, kind: str):
+def route(x: jax.Array, router: jax.Array, bias, k: int, kind: str,
+          scale: float = 1.0):
     """(T, d) tokens -> ``(sel (T, k) int32, gates (T, k) f32, density
-    (E,), density_proxy (E,))``.  Scores, top-k and weights are f32 with
+    (E,), density_proxy (E,))``; the normalised weights of a token's picks
+    sum to ``scale``.  Scores, top-k and weights are f32 with
     the router product at highest precision: a pick is a discrete choice,
     and an operand rounded to bf16 flips near ties.  The two densities
     are token means whose product is the GShard load-balance loss
@@ -129,6 +132,8 @@ def route(x: jax.Array, router: jax.Array, bias, k: int, kind: str):
         gates = top / (top.sum(-1, keepdims=True) + 1e-6)
     else:
         raise ValueError(f"unknown router {kind!r}; have {ROUTERS}")
+    if scale != 1.0:
+        gates = gates * scale
     density = jax.nn.one_hot(sel, E, dtype=jnp.float32).sum(1).mean(0)
     return sel.astype(jnp.int32), gates, density, scores.mean(0)
 
@@ -285,7 +290,8 @@ def _stats(n_picks: int, sizes, covered) -> MoEStats:
 def expert_layer(params: MoEParams, x: jax.Array, *, k: int = 2,
                  router: str = "softmax",
                  held: Optional[Tuple[int, int]] = None,
-                 compute_dtype=None, row_chunk: int = ROW_CHUNK):
+                 compute_dtype=None, row_chunk: int = ROW_CHUNK,
+                 route_scale: float = 1.0):
     """The expert layer on one device: ``(y (T, d) f32, aux, MoEStats)``.
 
     ``held = (lo, hi)`` is the range of expert ids whose weights
@@ -307,7 +313,7 @@ def expert_layer(params: MoEParams, x: jax.Array, *, k: int = 2,
         # part (the caller sums or withholds it); the tokens do not.
         x_route = x if hi - lo == E else lax.stop_gradient(x)
         sel, gates, dens, proxy = route(x_route, params.router, params.bias,
-                                        k, router)
+                                        k, router, route_scale)
         aux = (dens * proxy).sum() * E if router == "softmax" \
             else jnp.float32(0.0)
         e = sel.reshape(-1)
@@ -321,8 +327,8 @@ def expert_layer(params: MoEParams, x: jax.Array, *, k: int = 2,
 
 def moe_ffn(params: MoEParams, x: jax.Array, mesh: Mesh, *,
             axis: str = EXPERT_AXIS, k: int = 2, router: str = "softmax",
-            compute_dtype=None, row_chunk: int = ROW_CHUNK
-            ) -> Tuple[jax.Array, jax.Array]:
+            compute_dtype=None, row_chunk: int = ROW_CHUNK,
+            route_scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
     """The expert layer over a mesh axis.
 
     ``x``: global ``(T, d_model)`` tokens, sharded ``P(axis)`` on T (dp and
@@ -351,7 +357,8 @@ def moe_ffn(params: MoEParams, x: jax.Array, mesh: Mesh, *,
     @partial(jax.shard_map, mesh=mesh, in_specs=(p_spec, x_spec),
              out_specs=(x_spec, P()), check_vma=False)
     def _moe(p, xl):
-        sel, gates, dens, proxy = route(xl, p.router, p.bias, k, router)
+        sel, gates, dens, proxy = route(xl, p.router, p.bias, k, router,
+                                        route_scale)
         aux = (lax.pmean(dens, axis) * lax.pmean(proxy, axis)).sum() * E \
             if router == "softmax" else jnp.float32(0.0)
         n_picks = xl.shape[0] * k
@@ -383,12 +390,14 @@ def moe_ffn(params: MoEParams, x: jax.Array, mesh: Mesh, *,
 
 def moe_ffn_reference(params: MoEParams, x: jax.Array, *, k: int = 2,
                       router: str = "softmax",
-                      held: Optional[Tuple[int, int]] = None):
+                      held: Optional[Tuple[int, int]] = None,
+                      route_scale: float = 1.0):
     """Dense single-device golden: every held expert applied to every
     token and masked by the gate.  For tests."""
     E = params.router.shape[1]
     lo, hi = (0, E) if held is None else held
-    sel, g, dens, proxy = route(x, params.router, params.bias, k, router)
+    sel, g, dens, proxy = route(x, params.router, params.bias, k, router,
+                                route_scale)
     aux = (dens * proxy).sum() * E if router == "softmax" \
         else jnp.float32(0.0)
     gates = jnp.zeros((x.shape[0], E), jnp.float32).at[

@@ -14,8 +14,9 @@ mesh-native primitives:
   become head-sharded, full-sequence attention runs locally per head group,
   then all_to_all back.  Two collectives total; requires heads % n == 0.
 
-* ``blockwise_attention`` — one device, grouped KV heads, causal or under
-  any mask that names its tiles (``CausalMask`` is the contract): the
+* ``blockwise_attention`` — one device, grouped KV heads, causal
+  (``CausalMask``, the contract), over a sliding window (``WindowMask``) or
+  under any other mask that names its tiles: the
   same online-softmax recurrence walked over key/value blocks, so no
   ``(S, S)`` matrix of a head ever exists in HBM, forward or backward (the
   backward pass recomputes a block's probabilities from the saved
@@ -100,6 +101,33 @@ class CausalMask:
 
 
 CAUSAL = CausalMask()
+
+
+@dataclass(frozen=True)
+class WindowMask(CausalMask):
+    """Causal attention over a sliding window: query ``i`` sees the keys
+    ``j <= i`` with ``i - j < window`` (itself and the ``window - 1``
+    before it).  The tile lists are bands: a query tile starts at the tile
+    that holds its first query's earliest key, and a key tile ends at the
+    tile that holds its last key's latest query, so the tiles folded follow
+    ``S * window`` and not ``S^2 / 2``.  ``window`` need not be a multiple
+    of the tile."""
+    window: int
+
+    def __post_init__(self):
+        if self.window < 1:
+            raise ValueError(f"window {self.window}: a query sees itself")
+
+    def key_tiles(self, i, n, size):
+        first = jnp.maximum(i * size - (self.window - 1), 0) // size
+        return first, i + 1, lambda t: t
+
+    def query_tiles(self, j, n, size):
+        last = ((j + 1) * size + self.window - 2) // size
+        return j, jnp.minimum(last + 1, n), lambda t: t
+
+    def visible(self, qa, kc):
+        return (qa >= kc) & (qa - kc < self.window)
 
 
 def _block(x, i, size):

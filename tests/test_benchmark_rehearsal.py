@@ -774,6 +774,137 @@ def bdlm_phase_map_and_counters(toy):
         t.state.params["blocks"][0]["moe"].router))
 
 
+# -- the sliding-window family's surface (benchmark/families/swlm.py's head) ------
+
+_SW = {}
+
+
+def sw_toy():
+    """A toy stack of sliding and full attention layers over dense and
+    expert FFNs, one ``Trainer.run`` of two host batches with telemetry on,
+    by the family's call sequence."""
+    if _SW:
+        return _SW["toy"]
+    from swiftmpi_tpu import obs
+    from swiftmpi_tpu.models.trainer import Trainer
+    from swiftmpi_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=4, n_heads=8, n_kv_heads=1,
+        d_head=8, d_ff=48, d_expert=16, max_seq=32, attention="blockwise",
+        attn_block=8, loss_chunk=16, remat=True, remat_policy="full",
+        n_experts=128, moe_top_k=8, experts_held=(80, 88),
+        router="sigmoid_bias", route_scale=2.826, n_shared_experts=1,
+        expert_gated=True, qk_norm=True, attn_gate=True, sandwich_norm=True,
+        layer_ops=("sliding", "sliding", "full", "sliding"),
+        layer_ffns=("dense", "moe", "moe", "moe"), window=12,
+        embed_scale=32 ** 0.5, norm_eps=1e-5, rope_base=1e4, init_std=0.3,
+        tied_head=False)
+    was_on = obs.get_registry().enabled
+    obs.set_enabled(True)
+    trainer = Trainer(cfg, optimizer="adamw", aux_weight=0.0,
+                      learning_rate=3e-4, warmup_steps=2, decay_steps=100,
+                      weight_decay=0.1, grad_clip=1.0, b1=0.9, b2=0.95)
+    state0 = trainer.init_state(jax.random.key(3))
+    rng = np.random.default_rng(41)
+    batches = [rng.integers(0, 64, (1, 32)).astype(np.int32)
+               for _ in range(2)]
+    state, losses = trainer.run(state0, iter(batches))
+    phase_map = obs.costs.phase_map("trainer_step")
+    obs.set_enabled(was_on)
+    _SW["toy"] = SimpleNamespace(cfg=cfg, trainer=trainer, state=state,
+                                 losses=losses, batches=batches,
+                                 phase_map=phase_map)
+    return _SW["toy"]
+
+
+@surface
+def swlm_config_and_tree(toy):
+    """The ``TransformerConfig`` fields and operator kinds the family sets
+    beyond the other LM families', the parameter names it samples, and
+    ``hidden_states`` at every half layer."""
+    from swiftmpi_tpu.models.transformer import (ATTENTION_OPS, OPS,
+                                                 TransformerConfig,
+                                                 hidden_states)
+
+    fields = TransformerConfig.__dataclass_fields__
+    for name, default in [("window", 0), ("attn_gate", False),
+                          ("sandwich_norm", False), ("embed_scale", 1.0),
+                          ("n_shared_experts", 0), ("route_scale", 1.0)]:
+        assert fields[name].default == default, name
+    assert {"sliding", "full", "attention"} == set(ATTENTION_OPS) < set(OPS)
+    t = sw_toy()
+    assert [k for k, _n in t.cfg.layer_groups()] == [
+        ("sliding", "dense"), ("sliding", "moe"), ("full", "moe"),
+        ("sliding", "moe")]
+    params = t.state.params
+    assert set(params) == {"embed", "head", "blocks", "ln_f"}
+    attn = {"ln1", "ln1_post", "ln2", "ln2_post", "wq", "wk", "wv", "wo",
+            "wg", "q_norm", "k_norm"}
+    assert set(params["blocks"][0]) == attn | {"w_gate", "w_up", "w_down"}
+    assert set(params["blocks"][2]) == attn | {
+        "moe", "shared_gate", "shared_up", "shared_down"}
+    assert params["blocks"][1]["wg"].shape == (1, 32, 64)
+    assert params["blocks"][1]["shared_down"].shape == (1, 16, 32)
+    assert params["blocks"][1]["moe"].w_in.shape == (1, 8, 32, 16)
+    mu = t.state.opt_state[1][0].mu
+    assert jax.tree.structure(mu) == jax.tree.structure(params)
+    assert len(t.losses) == 2
+    assert all(math.isfinite(float(x)) for x in t.losses)
+    hs = hidden_states(params, t.batches[0], t.cfg)
+    assert len(hs) == 2 * t.cfg.n_layers + 1 and hs[0].shape == (1, 32, 32)
+
+
+@surface
+def swlm_masks_walked(toy):
+    """``WindowMask(window)`` and ``CAUSAL`` with ``key_tiles(i, n, size)
+    -> (lo, hi, tile)`` and ``visible(qa, kc)``, as the family walks them
+    for its pair-fill counters."""
+    from swiftmpi_tpu.parallel.ring_attention import CAUSAL, WindowMask
+
+    t = sw_toy()
+    size, n = t.cfg.attn_block, 32 // t.cfg.attn_block
+    pos = np.arange(size)
+    for mask, pairs in ((WindowMask(t.cfg.window), 12 * 13 // 2 + 20 * 12),
+                        (CAUSAL, 32 * 33 // 2)):
+        assert mask.tile(size, 32) == size
+        seen = 0
+        for i in range(n):
+            lo, hi, tile = mask.key_tiles(i, n, size)
+            for k in range(int(lo), int(hi)):
+                j = int(tile(k))
+                seen += int(np.asarray(mask.visible(
+                    (i * size + pos)[:, None], (j * size + pos)[None])).sum())
+        assert seen == pairs
+
+
+@surface
+def swlm_phase_map_and_counters(toy):
+    """The device scopes ``window_attention`` and ``shared_expert`` beside
+    the LM step's, the expert layers' counters, and a share's router and
+    selection bias left alone."""
+    from swiftmpi_tpu.obs.catalog import DEVICE_SCOPES
+
+    t = sw_toy()
+    want = {"embed", "window_attention", "attention", "route", "experts",
+            "shared_expert", "dense_ffn", "head", "optimizer"}
+    assert want <= set(DEVICE_SCOPES.values())
+    assert want <= set(t.phase_map["phase"].values())
+    assert t.phase_map["module"] == "jit_train_step"
+    m = t.trainer.train_metrics
+    assert m["steps"] == 2 and m["dropped_picks_per_step"] == 0.0
+    assert 0.0 < m["held_pick_share"] < 100.0
+    assert m["expert_load_max_over_mean"] >= 1.0
+    state0 = t.trainer.init_state(jax.random.key(3))
+    for before, after in zip(state0.params["blocks"][1:],
+                             t.state.params["blocks"][1:]):
+        for name in ("router", "bias"):
+            assert np.array_equal(np.asarray(getattr(before["moe"], name)),
+                                  np.asarray(getattr(after["moe"], name)))
+    assert not np.array_equal(np.asarray(state0.params["blocks"][1]["wg"]),
+                              np.asarray(t.state.params["blocks"][1]["wg"]))
+
+
 @pytest.mark.parametrize("name", sorted(SURFACE))
 def test_harness_surface(name, toy):
     SURFACE[name](toy)

@@ -439,3 +439,61 @@ def test_sdar_ep8_trainer_step_fits_one_chip(topo, no_compile_cache):
     for dims in set(re.findall(r"= \w+\[([\d,]+)\]", text)):
         big = [int(d) for d in dims.split(",") if int(d) >= S]
         assert len(big) < 2, f"[{dims}]"
+
+
+def test_trinity_ep16_trainer_step_fits_one_chip(topo, no_compile_cache):
+    """The real ``trainer_step`` of ``trinity-ep16-16k-t16k`` — 5 layers in
+    four scanned runs at the published widths, sliding layers under
+    ``WindowMask(2048)``, 8 of 128 experts beside the shared one, an untied
+    25,024-row head, one packed sequence of 16,384 — on one v5e chip: the
+    compiler's memory report fits 15.75 GiB with room (12.2 GiB), the
+    grouped products are the compiler's ``ragged-dot`` kernels — 15 a layer
+    body, the residual's second norm making the layer's recomputation run
+    the expert loop again (``benchmark/costs/swlm.py::RAGGED_FORWARD_RUNS``)
+    — and no buffer has the size of a head's ``(S, S)`` scores."""
+    import sys
+    sys.path.insert(0, REPO)
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.families import swlm as family
+    from swiftmpi_tpu.models.trainer import Trainer
+    from swiftmpi_tpu.obs.catalog import DEVICE_SCOPES
+
+    config, traffic = _cell("trinity-ep16-16k-t16k")
+    cfg = family.transformer_config(config, traffic)
+    assert [k for k, _n in cfg.layer_groups()] == [
+        ("sliding", "dense"), ("sliding", "moe"), ("full", "moe"),
+        ("sliding", "moe")]
+    assert (cfg.window, cfg.head_dim, cfg.held[1] - cfg.held[0]) == \
+        (2048, 128, 8)
+    one = SingleDeviceSharding(topo.devices[0])
+    trainer = Trainer(cfg, **family.trainer_kwargs(config))
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda k: trainer.init_state(k).tree(),
+                       jax.random.key(0)))
+    seqs, S = int(traffic["sequences_per_step"]), cfg.max_seq
+    tokens = jax.ShapeDtypeStruct((seqs, S), jnp.int32, sharding=one)
+    n_params = sum(a.size for a in jax.tree.leaves(state["params"]))
+    # attention 27,263,232 + four gains 8,192 a layer; dense MLP 37,748,736;
+    # router 262,144 + bias 128 + shared 6,291,456 + 8 x 6,291,456; embedding
+    # and head 2 x 51,249,152; final gain 2,048: 8.07 GB x 16 B
+    assert n_params == 504_147_712
+
+    compiled = trainer._build_step().lower(
+        state["params"], state["opt_state"], state["step"], tokens).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total <= 13.0 * GIB, f"{total / GIB:.2f} GiB"
+    assert mem.alias_size_in_bytes >= 0.99 * mem.output_size_in_bytes
+
+    text = compiled.as_text()
+    kernels = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                         r'op_name="([^"]*)"', text)
+    assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
+    assert set(kernels) <= set(DEVICE_SCOPES)
+    assert kernels.count("ragged-dot-none") == 3 * 15
+    for dims in set(re.findall(r"= \w+\[([\d,]+)\]", text)):
+        big = [int(d) for d in dims.split(",") if int(d) >= S]
+        assert len(big) < 2, f"[{dims}]"
