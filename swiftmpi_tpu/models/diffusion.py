@@ -110,12 +110,5 @@ class BlockDiffusionMask:
         return (0, jnp.where(noisy, i + 2, i - N + 1),
                 lambda t: jnp.where(noisy & (t == i + 1), i, N + t))
 
-    def query_tiles(self, j, n, size):
-        N = self._halves(n, size)
-        noisy, c = j < N, j - N            # clean key tile c of its half
-        return (0, jnp.where(noisy, 1, 2 * (N - c)),
-                lambda t: jnp.where(noisy, j, jnp.where(t < N - c, c + t,
-                                                        2 * c + t)))
-
     def visible(self, qa, kc):
         return visible(qa, kc, self.seq, self.block)

@@ -20,7 +20,8 @@ mesh-native primitives:
   same online-softmax recurrence walked over key/value blocks, so no
   ``(S, S)`` matrix of a head ever exists in HBM, forward or backward (the
   backward pass recomputes a block's probabilities from the saved
-  log-sum-exp, flash-attention style).
+  log-sum-exp, flash-attention style, and walks query blocks in turn as
+  the forward does: a block's ``dq`` stays in the fold's carry).
 
 All are numerically checked against ``full_attention`` in the test suite.
 Layout convention: ``(batch, seq, heads, head_dim)``.
@@ -77,9 +78,10 @@ class CausalMask:
     * ``key_tiles(i, n, size) -> (lo, hi, tile)``: query tile ``i`` folds
       the key tiles ``tile(t)`` for ``t`` in ``[lo, hi)`` — every tile that
       holds a key one of its queries sees, each once.  Tiles outside the
-      list are never multiplied;
-    * ``query_tiles(j, n, size)``: the same list transposed — the query
-      tiles that fold key tile ``j`` (the backward pass walks these);
+      list are never multiplied.  The forward and the hand-written backward
+      walk this one list, a query tile at a time: the forward carries the
+      tile's ``(m, l, o)`` through its fold, the backward the tile's ``dq``
+      (and adds a key tile's ``dk`` / ``dv`` to block-major sums);
     * ``visible(qa, kc)``: the element predicate, from absolute query
       positions ``(size, 1)`` and key positions ``(1, size)``;
     * ``tile(block, S) -> size``: the tile its lists are written for, at
@@ -93,9 +95,6 @@ class CausalMask:
     def key_tiles(self, i, n, size):
         return 0, i + 1, lambda t: t
 
-    def query_tiles(self, j, n, size):
-        return j, n, lambda t: t
-
     def visible(self, qa, kc):
         return qa >= kc
 
@@ -107,9 +106,8 @@ CAUSAL = CausalMask()
 class WindowMask(CausalMask):
     """Causal attention over a sliding window: query ``i`` sees the keys
     ``j <= i`` with ``i - j < window`` (itself and the ``window - 1``
-    before it).  The tile lists are bands: a query tile starts at the tile
-    that holds its first query's earliest key, and a key tile ends at the
-    tile that holds its last key's latest query, so the tiles folded follow
+    before it).  The tile list is a band: a query tile starts at the tile
+    that holds its first query's earliest key, so the tiles folded follow
     ``S * window`` and not ``S^2 / 2``.  ``window`` need not be a multiple
     of the tile."""
     window: int
@@ -122,10 +120,6 @@ class WindowMask(CausalMask):
         first = jnp.maximum(i * size - (self.window - 1), 0) // size
         return first, i + 1, lambda t: t
 
-    def query_tiles(self, j, n, size):
-        last = ((j + 1) * size + self.window - 2) // size
-        return j, jnp.minimum(last + 1, n), lambda t: t
-
     def visible(self, qa, kc):
         return (qa >= kc) & (qa - kc < self.window)
 
@@ -133,6 +127,11 @@ class WindowMask(CausalMask):
 def _block(x, i, size):
     """Block ``i`` of ``size`` positions along axis 1."""
     return lax.dynamic_slice_in_dim(x, i * size, size, axis=1)
+
+
+def _unblock(x, shape):
+    """Stacked blocks (n, B, size, ...) -> ``shape`` (B, S, ...)."""
+    return jnp.moveaxis(x, 0, 1).reshape(shape)
 
 
 def _block_scores(qi, kj, i, j, size, scale, mask):
@@ -181,9 +180,7 @@ def _blockwise_fwd(q, k, v, size, mask):
                 jnp.einsum("bhgq->bqhg", m + jnp.log(l)))
 
     o, lse = lax.map(q_block, jnp.arange(S // size))
-    # (blocks, B, size, ...) -> (B, S, ...)
-    return (jnp.moveaxis(o, 0, 1).reshape(q.shape),
-            jnp.moveaxis(lse, 0, 1).reshape(B, S, Hkv, G))
+    return _unblock(o, q.shape), _unblock(lse, (B, S, Hkv, G))
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -197,46 +194,67 @@ def _blockwise_vjp_fwd(q, k, v, size, mask):
 
 
 def _blockwise_vjp_bwd(size, mask, res, do):
+    """The forward's mirror: query blocks in turn, each folding the key
+    blocks its mask lists.  A query block's ``dq`` is summed in the fold's
+    carry (f32, in the product's own layout, as the forward's ``o``) and
+    written once, cast; its ``q``, ``do``, statistics and ``delta`` are
+    sliced once (a block is visited once, so ``delta`` needs no pass of
+    its own).  ``dk`` / ``dv`` are f32 and block-major, ``(n, B, size,
+    Hkv, D)``: a fold adds one key-sized block to each, one contiguous
+    slab, where a block of a ``(B, S, ...)`` buffer that the compiler lays
+    out sequence-minor is a thousand separate runs, written at a third of
+    the memory's rate.  With grouped query heads the query side is the
+    heavy one (``G`` times a key block), so it is the one kept still; at
+    ``G`` = 1 the two sides weigh the same and this order is still the
+    faster (PERF.md section 6, PR 39)."""
     q, k, v, o, lse = res
     B, S, Hkv, G, D = q.shape
     scale = 1.0 / math.sqrt(D)
     n = S // size
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
 
-    def kv_block(dq, j):
-        kj, vj = _block(k, j, size), _block(v, j, size)
-        lo, hi, tile = mask.query_tiles(j, n, size)
+    def add_block(x, j, update):
+        return lax.dynamic_update_index_in_dim(
+            x, lax.dynamic_index_in_dim(x, j, 0, keepdims=False) + update,
+            j, 0)
+
+    def q_block(i, grads):
+        dq, dk, dv = grads
+        qi, doi = _block(q, i, size), _block(do, i, size)
+        lse_i = jnp.einsum("bqhg->bhgq", _block(lse, i, size))
+        d_i = jnp.einsum("bqhg->bhgq", jnp.sum(
+            doi.astype(jnp.float32)
+            * _block(o, i, size).astype(jnp.float32), -1))
+        lo, hi, tile = mask.key_tiles(i, n, size)
 
         def fold(t, carry):
-            dq, dk, dv = carry
-            i = tile(t)
-            qi, doi = _block(q, i, size), _block(do, i, size)
+            dq_i, dk, dv = carry
+            j = tile(t)
+            kj, vj = _block(k, j, size), _block(v, j, size)
             s = _block_scores(qi, kj, i, j, size, scale, mask)
-            lse_i = jnp.einsum("bqhg->bhgq", _block(lse, i, size))
             p = jnp.exp(s - lse_i[..., None])        # hidden: exp(-1e30)
-            dv = dv + jnp.einsum("bhgqk,bqhgd->bkhd", p.astype(do.dtype),
-                                 doi, preferred_element_type=jnp.float32)
+            dv_j = jnp.einsum("bhgqk,bqhgd->bkhd", p.astype(do.dtype), doi,
+                              preferred_element_type=jnp.float32)
             dp = jnp.einsum("bqhgd,bkhd->bhgqk", doi, vj,
                             preferred_element_type=jnp.float32)
-            d_i = jnp.einsum("bqhg->bhgq", _block(delta, i, size))
             ds = (p * (dp - d_i[..., None]) * scale).astype(q.dtype)
-            dk = dk + jnp.einsum("bhgqk,bqhgd->bkhd", ds, qi,
-                                 preferred_element_type=jnp.float32)
-            dq_i = jnp.einsum("bhgqk,bkhd->bqhgd", ds, kj,
+            dk_j = jnp.einsum("bhgqk,bqhgd->bkhd", ds, qi,
                               preferred_element_type=jnp.float32)
-            dq = lax.dynamic_update_slice_in_dim(
-                dq, _block(dq, i, size) + dq_i, i * size, axis=1)
-            return dq, dk, dv
+            dq_i = dq_i + jnp.einsum("bhgqk,bkhd->bhgqd", ds, kj,
+                                     preferred_element_type=jnp.float32)
+            return dq_i, add_block(dk, j, dk_j), add_block(dv, j, dv_j)
 
-        zeros = jnp.zeros(kj.shape, jnp.float32)
-        dq, dk, dv = lax.fori_loop(lo, hi, fold, (dq, zeros, zeros))
-        return dq, (dk, dv)
+        dq_i, dk, dv = lax.fori_loop(
+            lo, hi, fold,
+            (jnp.zeros((B, Hkv, G, size, D), jnp.float32), dk, dv))
+        dq_i = jnp.einsum("bhgqd->bqhgd", dq_i.astype(q.dtype))
+        return (lax.dynamic_update_slice_in_dim(dq, dq_i, i * size, axis=1),
+                dk, dv)
 
-    dq, (dk, dv) = lax.scan(kv_block, jnp.zeros(q.shape, jnp.float32),
-                            jnp.arange(n))
-    unblock = lambda x: jnp.moveaxis(x, 0, 1).reshape(k.shape)
-    return (dq.astype(q.dtype), unblock(dk).astype(k.dtype),
-            unblock(dv).astype(v.dtype))
+    zeros = jnp.zeros((n, B, size, Hkv, D), jnp.float32)
+    dq, dk, dv = lax.fori_loop(0, n, q_block,
+                               (jnp.zeros_like(q), zeros, zeros))
+    return (dq, _unblock(dk.astype(k.dtype), k.shape),
+            _unblock(dv.astype(v.dtype), v.shape))
 
 
 _blockwise.defvjp(_blockwise_vjp_fwd, _blockwise_vjp_bwd)
@@ -250,7 +268,13 @@ def blockwise_attention(q, k, v, block: int = 512, mask=CAUSAL):
     multiple of ``Hkv`` (each KV head serves ``H / Hkv`` query heads in
     order).  Scores, softmax statistics and accumulators are f32; the two
     products take their operands in the inputs' dtype.  ``S`` must be a
-    multiple of ``block`` (a shorter sequence is one block)."""
+    multiple of ``block`` (a shorter sequence is one block).
+
+    Forward and backward both walk ``mask.key_tiles``: query blocks in
+    turn, each folding the key blocks listed for it.  The backward keeps a
+    query block's ``dq`` (f32) in its fold's carry and adds each key
+    block's ``dk`` / ``dv`` to f32 sums held a block at a time, so no f32
+    buffer of the query's size exists and none is rewritten a fold."""
     B, S, H, D = q.shape
     Hkv = k.shape[2]
     if H % Hkv:

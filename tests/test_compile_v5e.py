@@ -342,11 +342,35 @@ def test_default_layout_of_a_tall_field(topo, no_compile_cache, width,
     assert (temp < 0.01 * GIB) if row_major else (temp >= padded)
 
 
+def _backward_keeps_the_query_side_still(compiled, cfg, seqs, positions,
+                                         parent_peak_gib):
+    """Blockwise attention's backward sums a query tile's ``dq`` in its
+    fold's carry (PR 39): the compiled step holds no
+    ``dynamic-update-slice`` — bare, or the root of a fusion's computation —
+    into an f32 buffer of the query's ``(B, S, Hkv, G, D)`` (the parent
+    wrote a strided 16.8 MB tile of one back every fold: 1, 1 and 4 such
+    instructions in the three LM steps), and the step's peak is not above
+    the parent's.  The peak is the compiler's ``peak_memory_in_bytes``: it
+    moved with what the chip reserved for the step (``sdar-ep8-8k-t16k``:
+    7,482 -> 7,460 MB of ``peak_bytes_reserved``, PERF.md section 6) where
+    ``temp_size_in_bytes`` did not (10.118 -> 10.158 GiB there; 9.411 ->
+    9.407 and 6.524 -> 6.367 in the other two)."""
+    q = (f"f32[{seqs},{positions},{cfg.kv_heads},"
+         f"{cfg.n_heads // cfg.kv_heads},{cfg.head_dim}]")
+    text = compiled.as_text()
+    assert " dynamic-update-slice(" in text
+    assert not re.findall(rf"= {re.escape(q)}\S* dynamic-update-slice\(",
+                          text), q
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert peak <= parent_peak_gib * GIB, f"{peak / GIB:.4f} GiB"
+
+
 def test_lfm2_ep4_trainer_step_fits_one_chip(topo, no_compile_cache):
     """The real ``trainer_step`` of ``lfm2-ep4-8k-t32k`` — 5 layers at the
     published widths, 8 of 32 experts, 4 packed sequences of 8,192 — on one
     v5e chip: the compiler's memory report fits 15.75 GiB, and no buffer has
-    the size of a head's ``(S, S)`` scores or of a ``(T, E, C)`` dispatch."""
+    the size of a head's ``(S, S)`` scores or of a ``(T, E, C)`` dispatch.
+    Peak 13.4366 GiB (the parent of PR 39: 13.4366, 1 KiB less)."""
     import sys
     sys.path.insert(0, REPO)
     from jax.sharding import SingleDeviceSharding
@@ -378,6 +402,7 @@ def test_lfm2_ep4_trainer_step_fits_one_chip(topo, no_compile_cache):
 
     text = compiled.as_text()
     assert "ragged-dot" in text               # the compiler's grouped matmul
+    _backward_keeps_the_query_side_still(compiled, cfg, seqs, S, 13.437)
     # a head's (S, S) scores, or a (T, E, C) dispatch at C = T k / E x 2 =
     # 8,192, would be an array with two dims of at least S; the largest
     # things here have one (tokens x a width)
@@ -393,7 +418,8 @@ def test_sdar_ep8_trainer_step_fits_one_chip(topo, no_compile_cache):
     positions of ``[x_t ; x_0]`` — on one v5e chip: the compiler's memory
     report fits 15.75 GiB, the grouped products are the compiler's one
     ``ragged-dot`` kernel family, and no buffer has the size of a head's
-    scores over a whole sequence, noised + clean or either half."""
+    scores over a whole sequence, noised + clean or either half.
+    Peak 12.008 GiB (the parent of PR 39: 12.010)."""
     import sys
     sys.path.insert(0, REPO)
     from jax.sharding import SingleDeviceSharding
@@ -434,6 +460,7 @@ def test_sdar_ep8_trainer_step_fits_one_chip(topo, no_compile_cache):
     assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
     assert set(kernels) <= set(DEVICE_SCOPES)
     assert kernels.count("ragged-dot-none") == 12
+    _backward_keeps_the_query_side_still(compiled, cfg, seqs, 2 * S, 12.010)
     # (2S, 2S) or (S, S) scores of a head would be an array with two dims
     # of at least S; the largest things here have one (positions x a width)
     for dims in set(re.findall(r"= \w+\[([\d,]+)\]", text)):
@@ -450,7 +477,8 @@ def test_trinity_ep16_trainer_step_fits_one_chip(topo, no_compile_cache):
     grouped products are the compiler's ``ragged-dot`` kernels — 15 a layer
     body, the residual's second norm making the layer's recomputation run
     the expert loop again (``benchmark/costs/swlm.py::RAGGED_FORWARD_RUNS``)
-    — and no buffer has the size of a head's ``(S, S)`` scores."""
+    — and no buffer has the size of a head's ``(S, S)`` scores.
+    Peak 10.506 GiB (the parent of PR 39: 10.662)."""
     import sys
     sys.path.insert(0, REPO)
     from jax.sharding import SingleDeviceSharding
@@ -494,6 +522,7 @@ def test_trinity_ep16_trainer_step_fits_one_chip(topo, no_compile_cache):
     assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
     assert set(kernels) <= set(DEVICE_SCOPES)
     assert kernels.count("ragged-dot-none") == 3 * 15
+    _backward_keeps_the_query_side_still(compiled, cfg, seqs, S, 10.663)
     for dims in set(re.findall(r"= \w+\[([\d,]+)\]", text)):
         big = [int(d) for d in dims.split(",") if int(d) >= S]
         assert len(big) < 2, f"[{dims}]"
