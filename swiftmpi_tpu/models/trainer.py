@@ -94,6 +94,21 @@ def _expert_counters(stats) -> dict:
         "dropped_picks_per_step": total["dropped"] / len(stats)}
 
 
+def _loss_parts(parts, weight: float) -> dict:
+    """What a run's steps with a multi-token-prediction module returned
+    beside the loss (``lm_loss_and_stats``'s parts), as means over the
+    steps: the two losses, the module's weighted share of their sum in
+    percent, and the module's own expert counters under ``mtp_``."""
+    if not parts or not parts[0]:
+        return {}
+    main, mtp = (sum(float(p[k]) for p in parts) / len(parts)
+                 for k in ("main_loss", "mtp_loss"))
+    return {"main_loss": main, "mtp_loss": mtp,
+            "mtp_loss_share": 100.0 * weight * mtp / (main + weight * mtp),
+            **{"mtp_" + k: v for k, v in _expert_counters(
+                [p["mtp_stats"] for p in parts]).items()}}
+
+
 class Trainer:
     """Owns params + optimizer state and the jitted step.
 
@@ -123,9 +138,9 @@ class Trainer:
         self.pipeline_stats: dict = {}
         # what the last run() counted (the Word2Vec.train_metrics
         # analogue): the stall split, and with telemetry on the expert
-        # layers' counters, fetched with the loss
+        # layers' counters and the loss's parts, fetched with the loss
         self.train_metrics: dict = {}
-        self._stats = []          # MoEStats of the steps not yet fetched
+        self._stats = []          # (MoEStats, loss parts) not yet fetched
         # serving plane: attach a serve.SnapshotPublisher here and
         # step() publishes a params-only snapshot every K steps (dense
         # params carry no key map — readers use the pytree directly)
@@ -306,7 +321,8 @@ class Trainer:
         ``step_prep``, ``dispatch`` and ``step_book`` in ``step``,
         ``loss_fetch`` for the call's one blocking fetch (``loss_wait``
         for the last loss, then with telemetry on the read of the expert
-        layers' counters), ``train_finish`` from there to the return.
+        layers' counters and, with a multi-token-prediction module, of the
+        loss's two parts), ``train_finish`` from there to the return.
         ``self.train_metrics`` holds what the call counted.
         """
         setup_span = obs.span("train_setup")
@@ -367,7 +383,8 @@ class Trainer:
             self.train_metrics = {
                 "steps": steps, "host_stall_ms": stall,
                 "stall_ms_per_step": stall / steps if steps else 0.0,
-                **_expert_counters(stats)}
+                **_expert_counters([s for s, _parts in stats]),
+                **_loss_parts([p for _s, p in stats], self.cfg.mtp_weight)}
         return state, losses
 
     # -- checkpoints (multihost-safe, atomic, CRC-validated) ---------------

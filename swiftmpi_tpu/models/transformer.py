@@ -24,7 +24,10 @@ Architecture: pre-RMSNorm, an output head that is the embedding's transpose
 be alike: each layer has an *operator* (attention with grouped KV heads of
 any ``d_head``, optional per-head QK norm and an optional output gate — in
 one of the kinds of ``ATTENTION_OPS``: causal with RoPE, causal over a
-sliding ``window`` with RoPE, or causal with no position embedding — or a
+sliding ``window`` with RoPE, causal with no position embedding, or
+*latent* attention: queries through a low-rank pair, keys and values
+re-expanded per head from one compressed vector a position, RoPE on a
+decoupled part of the head whose key every head shares — or a
 gated short convolution) and an *FFN* (dense SiLU-gated, or the routed
 expert layer of parallel/moe.py, beside shared experts every token runs
 through where ``n_shared_experts``).  ``layer_ops`` / ``layer_ffns`` name
@@ -38,7 +41,11 @@ Objective (``objective``): ``next_token`` — causal attention, cross entropy
 of the next token — or ``block_diffusion`` (models/diffusion.py): the trunk
 runs ``[x_t ; x_0]``, a noised copy of each sequence before the clean one,
 under the block-diffusion attention mask, and the loss is the 1/t-weighted
-cross entropy of the masked positions' own tokens.
+cross entropy of the masked positions' own tokens.  ``mtp_layers`` 1 puts a
+multi-token-prediction module behind a ``next_token`` trunk (:func:`_mtp`):
+one more block over the trunk's last hidden state merged with the next
+token's embedding, the same head a second time, and ``mtp_weight`` times the
+cross entropy of the token after the next added to the loss.
 
 Precision: parameters, residual stream, norms, softmax, router and loss
 are ``dtype`` (f32); ``matmul_dtype`` (bf16 in a deployment) is what the
@@ -71,10 +78,12 @@ from swiftmpi_tpu.parallel.ring_attention import (CAUSAL, WindowMask,
 
 #: attention operators -> (device scope, over ``cfg.window`` only, RoPE).
 #: ``full`` is what a stack that mixes it with ``sliding`` layers means by
-#: it: every earlier position, and no position embedding at all
+#: it: every earlier position, and no position embedding at all; ``latent``
+#: (:func:`_latent_attention`) rotates ``qk_rope_dim`` of a head's dims
 ATTENTION_OPS = {"attention": ("attention", False, True),
                  "sliding": ("window_attention", True, True),
-                 "full": ("attention", False, False)}
+                 "full": ("attention", False, False),
+                 "latent": ("latent_attention", False, True)}
 OPS = (*ATTENTION_OPS, "conv")
 FFNS = ("dense", "moe")
 OBJECTIVES = ("next_token", "block_diffusion")
@@ -129,11 +138,21 @@ class TransformerConfig:
     n_shared_experts: int = 0        # experts every token runs beside the
                                      # routed ones, each an expert's width
     route_scale: float = 1.0         # what a token's routing weights sum to
+    # -- a "latent" layer (each 0 until a layer asks for them) -------------
+    q_lora_rank: int = 0             # the queries' compressed width
+    kv_lora_rank: int = 0            # the keys' and values' compressed width
+    qk_nope_dim: int = 0             # a q / k head's dims without position
+    qk_rope_dim: int = 0             # ... with RoPE; the key's part is one
+                                     # a position, shared by every head
+    v_head_dim: int = 0              # a value head's width
     # -- the objective ------------------------------------------------------
     objective: str = "next_token"    # of OBJECTIVES
     diffusion_block: int = 4         # block_diffusion: positions a block
     mask_token: int = 0              # ... the id a noised position reads
     noise_eps: float = 1e-3          # ... a block's mask rate t in [eps, 1]
+    mtp_layers: int = 0              # next_token: 0 | 1 multi-token-
+                                     # prediction modules behind the trunk
+    mtp_weight: float = 0.3          # ... its loss's weight in the sum
 
     def __post_init__(self):
         if self.objective not in OBJECTIVES:
@@ -157,6 +176,29 @@ class TransformerConfig:
                 raise ValueError("a 'sliding' layer brings its own mask; "
                                  f"objective {self.objective!r} brings one "
                                  "for every layer")
+        if "latent" in self.layer_ops:
+            missing = [f for f in ("q_lora_rank", "kv_lora_rank",
+                                   "qk_nope_dim", "qk_rope_dim", "v_head_dim")
+                       if getattr(self, f) < 1]
+            if missing:
+                raise ValueError("a 'latent' layer needs its ranks and head "
+                                 f"dims: {', '.join(missing)} not set")
+            if self.v_head_dim != self.qk_nope_dim + self.qk_rope_dim:
+                raise ValueError(
+                    f"a 'latent' layer needs v_head_dim ({self.v_head_dim}) "
+                    f"== qk_nope_dim + qk_rope_dim ({self.qk_nope_dim} + "
+                    f"{self.qk_rope_dim}): the attention variants take one "
+                    "width for keys and values")
+            if self.qk_rope_dim % 2:
+                raise ValueError("a 'latent' layer rotates pairs: "
+                                 f"qk_rope_dim {self.qk_rope_dim} is odd")
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(f"mtp_layers is 0 or 1, not {self.mtp_layers}: "
+                             "one module, one token further")
+        if self.mtp_layers and self.objective != "next_token":
+            raise ValueError("mtp_layers needs objective 'next_token', not "
+                             f"{self.objective!r}: the module predicts the "
+                             "token after the next")
 
     @property
     def head_dim(self) -> int:
@@ -206,7 +248,7 @@ class TransformerConfig:
 
 def _init_block(k, cfg: TransformerConfig, op: str, ffn: str):
     ks = iter(jax.random.split(k, 10))
-    d, Dh = cfg.d_model, cfg.head_dim
+    d = cfg.d_model
 
     def mat(rows, cols):
         s = cfg.init_std or 1.0 / math.sqrt(rows)
@@ -217,7 +259,19 @@ def _init_block(k, cfg: TransformerConfig, op: str, ffn: str):
     if cfg.sandwich_norm:
         blk.update(ln1_post=jnp.ones((d,), cfg.dtype),
                    ln2_post=jnp.ones((d,), cfg.dtype))
-    if op in ATTENTION_OPS:
+    if op == "latent":
+        H, r_q, r_kv = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+        blk.update(
+            wq_a=mat(d, r_q), q_a_norm=jnp.ones((r_q,), cfg.dtype),
+            wq_b=mat(r_q, H * (cfg.qk_nope_dim + cfg.qk_rope_dim)),
+            # the compressed keys / values and, beside them, the one rope
+            # key a position: no head has rope columns of its own
+            wkv_a=mat(d, r_kv + cfg.qk_rope_dim),
+            kv_a_norm=jnp.ones((r_kv,), cfg.dtype),
+            wkv_b=mat(r_kv, H * (cfg.qk_nope_dim + cfg.v_head_dim)),
+            wo=mat(H * cfg.v_head_dim, d))
+    elif op in ATTENTION_OPS:
+        Dh = cfg.head_dim
         blk.update(wq=mat(d, cfg.n_heads * Dh), wk=mat(d, cfg.kv_heads * Dh),
                    wv=mat(d, cfg.kv_heads * Dh), wo=mat(cfg.n_heads * Dh, d))
         if cfg.qk_norm:
@@ -271,6 +325,21 @@ def init_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
     }
     if not cfg.tied_head:      # (V, d) like the embedding; a key of its own
         params["head"] = vocab_matrix(jax.random.fold_in(k_emb, 1))
+    if cfg.mtp_layers:
+        # one block of the last layer's kind (a stack of one: the layers'
+        # sharding rules and scan read it as they read a run) between a
+        # merge of [hidden ; next token's embedding] and a gain of its own;
+        # embedding and head are the trunk's
+        k_eh, k_b = jax.random.split(jax.random.fold_in(k_blk, 1))
+        d = cfg.d_model
+        params["mtp"] = {
+            "hnorm": jnp.ones((d,), cfg.dtype),
+            "enorm": jnp.ones((d,), cfg.dtype),
+            "eh_proj": jax.random.normal(k_eh, (2 * d, d), cfg.dtype)
+            * (cfg.init_std or 1.0 / math.sqrt(2 * d)),
+            "block": stack_stage_params(
+                [_init_block(k_b, cfg, *cfg.layer_kinds()[-1])]),
+            "norm": jnp.ones((d,), cfg.dtype)}
     return params
 
 
@@ -313,8 +382,11 @@ def param_shardings(params, cfg: TransformerConfig, mesh: Mesh,
 
     def spec(path: str, leaf) -> P:
         if path in ("wq", "wk", "wv", "wg", "w_gate", "w_up", "shared_gate",
-                    "shared_up"):
-            return P(None, None, model_axis)      # (L, d, d|dff) col-shard
+                    "shared_up", "wq_b", "wkv_b"):
+            # (L, d, d|dff) col-shard; a latent layer's up-projections by
+            # head (its down-projections wq_a / wkv_a and the module's
+            # eh_proj are small and replicated: the rope key is every head's)
+            return P(None, None, model_axis)
         if path in ("wo", "w_down", "shared_down"):
             return P(None, model_axis, None)      # (L, dff|d, d) row-shard
         if path == "w_in":
@@ -376,6 +448,30 @@ def _post_norm(y, blk, gain: str, cfg: TransformerConfig):
     return _rms_norm(y, blk[gain], cfg.norm_eps) if gain in blk else y
 
 
+def _attend(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
+            seq_axis: str, mask):
+    """``softmax(q k^T / sqrt(D) + mask) v`` by ``cfg.attention``'s variant:
+    q (B, S, H, D), k and v (B, S, Hkv, D) -> (B, S, H, D)."""
+    H, Hkv = q.shape[2], k.shape[2]
+    if mask is not CAUSAL and cfg.attention != "blockwise":
+        raise ValueError(f"attention {cfg.attention!r} is causal; a mask "
+                         "needs 'blockwise'")
+    if cfg.matmul_dtype is not None:
+        q, k, v = (t.astype(cfg.matmul_dtype) for t in (q, k, v))
+    # like _ffn: the collective variants need their axis on the mesh;
+    # otherwise fall back to the numerically identical local computation
+    has_seq = mesh is not None and seq_axis in mesh.axis_names
+    if cfg.attention == "blockwise":
+        return blockwise_attention(q, k, v, block=cfg.attn_block, mask=mask)
+    if Hkv != H:      # the golden and the ring take one K/V per head
+        k, v = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
+    if cfg.attention == "ring" and has_seq:
+        return ring_attention(q, k, v, mesh, axis=seq_axis, causal=True)
+    if cfg.attention == "ulysses" and has_seq:
+        return ulysses_attention(q, k, v, mesh, axis=seq_axis, causal=True)
+    return full_attention(q, k, v, causal=True)
+
+
 def _attention(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
                seq_axis: str, positions=None, mask=CAUSAL, rotary=True):
     """Attention at the position ids ``positions`` ((S,) f32; default
@@ -386,9 +482,6 @@ def _attention(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
     (``diffusion.attention_inputs``)."""
     B, S, d = x.shape
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    if mask is not CAUSAL and cfg.attention != "blockwise":
-        raise ValueError(f"attention {cfg.attention!r} is causal; a mask "
-                         "needs 'blockwise'")
     h = _rms_norm(x, blk["ln1"], cfg.norm_eps)
     q = _mm(h, blk["wq"], cfg).reshape(B, S, H, Dh)
     k = _mm(h, blk["wk"], cfg).reshape(B, S, Hkv, Dh)
@@ -401,25 +494,45 @@ def _attention(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
             positions = jnp.arange(S, dtype=jnp.float32)
         q = _rope(q, cfg.rope_base, positions)
         k = _rope(k, cfg.rope_base, positions)
-    if cfg.matmul_dtype is not None:
-        q, k, v = (t.astype(cfg.matmul_dtype) for t in (q, k, v))
-    # like _ffn: the collective variants need their axis on the mesh;
-    # otherwise fall back to the numerically identical local computation
-    has_seq = mesh is not None and seq_axis in mesh.axis_names
-    if cfg.attention == "blockwise":
-        o = blockwise_attention(q, k, v, block=cfg.attn_block, mask=mask)
-    else:
-        if Hkv != H:      # the golden and the ring take one K/V per head
-            k, v = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
-        if cfg.attention == "ring" and has_seq:
-            o = ring_attention(q, k, v, mesh, axis=seq_axis, causal=True)
-        elif cfg.attention == "ulysses" and has_seq:
-            o = ulysses_attention(q, k, v, mesh, axis=seq_axis, causal=True)
-        else:
-            o = full_attention(q, k, v, causal=True)
+    o = _attend(q, k, v, cfg, mesh, seq_axis, mask)
     o = o.reshape(B, S, H * Dh).astype(x.dtype)
     if cfg.attn_gate:
         o = o * jax.nn.sigmoid(_mm(h, blk["wg"], cfg))
+    return x + _post_norm(_mm(o, blk["wo"], cfg), blk, "ln1_post", cfg)
+
+
+def _latent_attention(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
+                      seq_axis: str, positions=None, mask=CAUSAL):
+    """Multi-head latent attention, in the form training runs (keys and
+    values re-expanded; nothing attends over the compressed vector):
+
+        cq = RMSNorm(h Wq_a);  q = cq Wq_b             H x [nope ; rope]
+        [ckv ; kr] = h Wkv_a;  c = RMSNorm(ckv)
+        [k_nope ; v] = c Wkv_b                         H x [nope ; v]
+        k = [k_nope ; RoPE(kr)]     one rope key a position, every head's
+        o = softmax([q_nope ; RoPE(q_rope)] k^T / sqrt(nope + rope)) v
+
+    one KV head a query head, ``v_head_dim == qk_nope_dim + qk_rope_dim``
+    (``__post_init__``), ``W_o`` from ``H x v_head_dim``.  ``positions`` and
+    ``mask`` as :func:`_attention` takes them."""
+    B, S, _d = x.shape
+    H, dn, dr, dv = (cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim,
+                     cfg.v_head_dim)
+    if positions is None:
+        positions = jnp.arange(S, dtype=jnp.float32)
+    h = _rms_norm(x, blk["ln1"], cfg.norm_eps)
+    cq = _rms_norm(_mm(h, blk["wq_a"], cfg), blk["q_a_norm"], cfg.norm_eps)
+    q = _mm(cq, blk["wq_b"], cfg).reshape(B, S, H, dn + dr)
+    ckv, kr = jnp.split(_mm(h, blk["wkv_a"], cfg), [cfg.kv_lora_rank], -1)
+    c = _rms_norm(ckv, blk["kv_a_norm"], cfg.norm_eps)
+    k_nope, v = jnp.split(_mm(c, blk["wkv_b"], cfg).reshape(B, S, H, dn + dv),
+                          [dn], -1)
+    q = jnp.concatenate(
+        [q[..., :dn], _rope(q[..., dn:], cfg.rope_base, positions)], -1)
+    kr = _rope(kr[:, :, None, :], cfg.rope_base, positions)    # (B, S, 1, dr)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(kr, (B, S, H, dr))], -1)
+    o = _attend(q, k, v, cfg, mesh, seq_axis, mask)
+    o = o.reshape(B, S, H * dv).astype(x.dtype)
     return x + _post_norm(_mm(o, blk["wo"], cfg), blk, "ln1_post", cfg)
 
 
@@ -503,6 +616,8 @@ def _operator(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
         if windowed:
             attn = {**attn, "mask": WindowMask(cfg.window)}
         with obs.named_scope(scope):
+            if op == "latent":     # its rope part is not an option
+                return _latent_attention(blk, x, cfg, mesh, seq_axis, **attn)
             return _attention(blk, x, cfg, mesh, seq_axis, rotary=rotary,
                               **attn)
     with obs.named_scope("conv"):
@@ -532,36 +647,76 @@ def _groups(params, cfg: TransformerConfig):
             zip(cfg.layer_groups(), stacks)]
 
 
-def trunk(params, tokens, cfg: TransformerConfig,
-          mesh: Optional[Mesh] = None, *, seq_axis: str = "seq",
-          expert_axis: str = "expert", **attn):
-    """tokens (B, S) int32 -> (hidden (B, S, d) after the final norm, aux
-    loss, MoEStats summed over the expert layers).  A plain sequence
-    under causal attention unless ``attn`` (:func:`_attention`'s
-    ``positions`` and ``mask``) says otherwise: the ``block_diffusion``
-    loss runs ``[x_t ; x_0]`` (B, 2S) with ``diffusion.attention_inputs``."""
+def _run_layers(stacked, carry, cfg: TransformerConfig, mesh, kind,
+                **block_kwargs):
+    """``carry = (x, aux, MoEStats)`` through a run of equal layers: one
+    compiled block body whatever the run's length — a scan over the stacked
+    params instead of unrolled copies."""
+    def body(carry, blk):
+        x, aux, stats = carry
+        x, a, st = block_apply(blk, x, cfg, mesh, kind=kind, **block_kwargs)
+        return (x, aux + a, MoEStats(*(s + t for s, t in
+                                       zip(stats, st)))), None
+
+    if cfg.remat:
+        body = jax.checkpoint(body, policy=_remat_policy(cfg))
+    return jax.lax.scan(body, carry, stacked)[0]
+
+
+def _stack(params, tokens, cfg: TransformerConfig,
+           mesh: Optional[Mesh] = None, *, seq_axis: str = "seq",
+           expert_axis: str = "expert", **attn):
+    """:func:`trunk` up to the last layer's output, *before* the final
+    norm: what a multi-token-prediction module reads."""
     with obs.named_scope("embed"):
         x = _embed(params, tokens, cfg)
     carry = (x, jnp.float32(0.0), _no_stats())
-
-    # one compiled block body for each run of equal layers, whatever its
-    # length: scan over the stacked params instead of unrolling copies
     for kind, _n, stacked in _groups(params, cfg):
-        def body(carry, blk, kind=kind):
-            x, aux, stats = carry
-            x, a, st = block_apply(blk, x, cfg, mesh, seq_axis=seq_axis,
-                                   expert_axis=expert_axis, kind=kind,
-                                   **attn)
-            return (x, aux + a, MoEStats(*(s + t for s, t in
-                                           zip(stats, st)))), None
+        carry = _run_layers(stacked, carry, cfg, mesh, kind,
+                            seq_axis=seq_axis, expert_axis=expert_axis,
+                            **attn)
+    return carry
 
-        if cfg.remat:
-            body = jax.checkpoint(body, policy=_remat_policy(cfg))
-        carry, _ = jax.lax.scan(body, carry, stacked)
-    x, aux, stats = carry
+
+def trunk(params, tokens, cfg: TransformerConfig,
+          mesh: Optional[Mesh] = None, **kwargs):
+    """tokens (B, S) int32 -> (hidden (B, S, d) after the final norm, aux
+    loss, MoEStats summed over the expert layers).  A plain sequence
+    under causal attention unless ``kwargs`` (:func:`_attention`'s
+    ``positions`` and ``mask``; ``seq_axis``, ``expert_axis``) say
+    otherwise: the ``block_diffusion`` loss runs ``[x_t ; x_0]`` (B, 2S)
+    with ``diffusion.attention_inputs``."""
+    x, aux, stats = _stack(params, tokens, cfg, mesh, **kwargs)
     with obs.named_scope("head"):
         x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     return x, aux, stats
+
+
+def _mtp_merge(params, x_last, tokens, cfg: TransformerConfig):
+    """The module's input: ``[RMSNorm(x_last) ; RMSNorm(E[next token])]
+    W_eh`` at every position (the last one reads the sequence's first
+    token again; nothing earlier sees it and its loss is left out)."""
+    m = params["mtp"]
+    e = _embed(params, jnp.roll(tokens, -1, axis=1), cfg)
+    return _mm(jnp.concatenate(
+        [_rms_norm(x_last, m["hnorm"], cfg.norm_eps),
+         _rms_norm(e, m["enorm"], cfg.norm_eps)], -1), m["eh_proj"], cfg)
+
+
+def _mtp(params, x_last, tokens, cfg: TransformerConfig,
+         mesh: Optional[Mesh] = None, **block_kwargs):
+    """The multi-token-prediction module (depth 1): ``x_last`` (B, S, d),
+    the trunk's last hidden state before the final norm, merged with the
+    next token's embedding, through one block of the last layer's kind over
+    positions ``0..S-1`` (causal: the sequence keeps its length, so the
+    attention walks the tiles the trunk's layers walk) -> (hidden after the
+    module's own gain, aux, the module's MoEStats).  The embedding is the
+    trunk's, and so is the head the caller runs over the result."""
+    z = _mtp_merge(params, x_last, tokens, cfg)
+    z, aux, stats = _run_layers(
+        params["mtp"]["block"], (z, jnp.float32(0.0), _no_stats()), cfg,
+        mesh, cfg.layer_kinds()[-1], **block_kwargs)
+    return _rms_norm(z, params["mtp"]["norm"], cfg.norm_eps), aux, stats
 
 
 def forward(params, tokens, cfg: TransformerConfig,
@@ -580,15 +735,27 @@ def hidden_states(params, tokens, cfg: TransformerConfig, **attn):
     half layer: ``[x_0, m_0, x_1, m_1, ..., x_L]`` with ``x_i`` the input of
     layer ``i``'s operator, ``m_i`` the input of its FFN and ``x_L`` the
     last layer's output (for holding each operator and FFN to a reference
-    on the program's own input).  ``attn`` as :func:`trunk` takes it."""
+    on the program's own input).  With a multi-token-prediction module,
+    three more: the module's merged input, its FFN's input and its block's
+    output (before the module's gain).  ``attn`` as :func:`trunk` takes
+    it."""
     x = _embed(params, tokens, cfg)
     out = [x]
-    for (op, ffn), n, stacked in _groups(params, cfg):
-        for i in range(n):
+
+    def layers(stacked, kind, x):
+        op, ffn = kind
+        for i in range(jax.tree.leaves(stacked)[0].shape[0]):
             blk = jax.tree.map(lambda a: a[i], stacked)
             mid = _operator(blk, x, cfg, None, "seq", op, **attn)
             x = _ffn(blk, mid, cfg, None, "expert", ffn)[0]
-            out += [mid, x]
+            out.extend([mid, x])
+        return x
+
+    for kind, _n, stacked in _groups(params, cfg):
+        x = layers(stacked, kind, x)
+    if cfg.mtp_layers:
+        out.append(_mtp_merge(params, x, tokens, cfg))
+        layers(params["mtp"]["block"], cfg.layer_kinds()[-1], out[-1])
     return out
 
 
@@ -646,12 +813,18 @@ def _token_nll(x, head, targets, cfg: TransformerConfig):
 def lm_loss_and_stats(params, tokens, cfg: TransformerConfig,
                       mesh: Optional[Mesh] = None, aux_weight: float = 0.01,
                       noise_key=None, **fwd_kwargs):
-    """(``cfg.objective``'s loss + weighted MoE aux, MoEStats summed over
-    the expert layers).
+    """(``cfg.objective``'s loss + weighted MoE aux, (MoEStats summed over
+    the expert layers, the loss's parts)).
 
     ``next_token``: the whole sequence runs through the trunk (so a packed
     sequence keeps its length); a sequence's last position has no target
-    and carries no loss.  ``block_diffusion``: ``noise_key`` draws the
+    and carries no loss.  With ``mtp_layers`` the module (:func:`_mtp`)
+    runs behind the trunk and the same head a second time: ``loss =
+    main_loss + mtp_weight * mtp_loss``, the second the cross entropy of
+    the token after the next (a sequence's last two positions left out);
+    the parts are ``{"main_loss", "mtp_loss", "mtp_stats"}`` — the module's
+    own MoEStats, which the sum holds too — and ``{}`` without a module.
+    ``block_diffusion``: ``noise_key`` draws the
     step's noise (:func:`diffusion.block_noise`), the trunk runs
     ``[x_t ; x_0]``, and head and loss run over the noised half alone: a
     masked position predicts its own token, weighted 1/t."""
@@ -665,7 +838,22 @@ def lm_loss_and_stats(params, tokens, cfg: TransformerConfig,
             noisy, weights = diffusion.block_noise(noise_key, tokens, cfg)
             inputs = diffusion.trunk_input(noisy, tokens)
             attn = diffusion.attention_inputs(S, cfg)
-    x, aux, stats = trunk(params, inputs, cfg, mesh, **attn, **fwd_kwargs)
+    x, aux, stats = _stack(params, inputs, cfg, mesh, **attn, **fwd_kwargs)
+    parts = {}
+    if cfg.mtp_layers:
+        # everything the module adds books under `mtp`: its layers' own
+        # scopes by the prefix, its merge, gain, head pass and loss here
+        with obs.named_scope("mtp"), obs.scope_prefix("mtp_"):
+            z, aux_m, stats_m = _mtp(params, x, tokens, cfg, mesh,
+                                     **fwd_kwargs)
+            nll = _token_nll(z.reshape(B * S, -1), head_matrix(params, cfg),
+                             jnp.roll(tokens, -2, axis=1).reshape(B * S),
+                             cfg).reshape(B, S)
+            parts = {"mtp_loss": nll[:, :-2].mean(), "mtp_stats": stats_m}
+        aux = aux + aux_m
+        stats = MoEStats(*(s + t for s, t in zip(stats, stats_m)))
+    with obs.named_scope("head"):
+        x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     if diffuse:
         x = x[:, :S]
     with obs.named_scope("head"):
@@ -674,7 +862,10 @@ def lm_loss_and_stats(params, tokens, cfg: TransformerConfig,
                          targets.reshape(B * S), cfg).reshape(B, S)
         loss = diffusion.weighted_loss(nll, weights) if diffuse \
             else nll[:, :-1].mean()
-    return loss + aux_weight * aux, stats
+    if parts:
+        parts["main_loss"] = loss
+        loss = loss + cfg.mtp_weight * parts["mtp_loss"]
+    return loss + aux_weight * aux, (stats, parts)
 
 
 def lm_loss(params, tokens, cfg: TransformerConfig,

@@ -27,6 +27,8 @@ a cached reference) rather than caching it forever.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
 import time
 from typing import Optional
@@ -57,16 +59,43 @@ __all__ = [
     "stream_filename", "series_key", "parse_series_key",
     "quantile_from_buckets", "process_ident", "process_rank",
     "get_registry", "set_enabled", "reset_for_tests", "span",
-    "named_scope", "configure", "install_recorder", "uninstall_recorder",
+    "named_scope", "scope_prefix", "current_scope_prefix", "configure",
+    "install_recorder", "uninstall_recorder",
     "get_recorder", "record_step", "CostCatalog", "TrackedFn",
     "get_catalog", "get_profiler", "install_profiler",
     "uninstall_profiler", "get_tracer", "install_tracer",
     "uninstall_tracer",
 ]
 
-#: named scope for *compiled* code — same phase names as :func:`span`,
-#: rendered into the device trace by XLA instead of timed on the host.
-named_scope = jax.named_scope
+_SCOPE_PREFIX = contextvars.ContextVar("smtpu_scope_prefix", default="")
+
+
+def named_scope(name: str):
+    """Named scope for *compiled* code — same phase names as :func:`span`,
+    rendered into the device trace by XLA instead of timed on the host.
+    Under :func:`scope_prefix` the name is entered with that prefix."""
+    return jax.named_scope(_SCOPE_PREFIX.get() + name)
+
+
+@contextlib.contextmanager
+def scope_prefix(prefix: str):
+    """While tracing under this, every :func:`named_scope` enters
+    ``prefix + name``: a module built from layers that scope themselves
+    (``route``, ``experts``, ...) books its copies of them under names of
+    its own, which ``obs.catalog.DEVICE_SCOPES`` maps to the module's phase
+    — the phase map credits the *innermost* known scope, so an enclosing
+    scope alone would lose them.  Read with :func:`current_scope_prefix`
+    by code whose backward pass is traced later (a ``custom_vjp``)."""
+    token = _SCOPE_PREFIX.set(prefix)
+    try:
+        yield
+    finally:
+        _SCOPE_PREFIX.reset(token)
+
+
+def current_scope_prefix() -> str:
+    """The prefix :func:`scope_prefix` set for the code being traced."""
+    return _SCOPE_PREFIX.get()
 
 _REGISTRY = MetricsRegistry(enabled=False)
 _RECORDER: Optional[StepRecorder] = None

@@ -155,6 +155,9 @@ DEVICE_SCOPES = {
     "conv": "conv",                # gated short convolution operator
     "attention": "attention",      # QKV, QK norm, RoPE, softmax(QK)V, out
     "window_attention": "window_attention",   # ... of a sliding layer
+    # a latent layer: down- and up-projections, the two inner norms, RoPE
+    # and the rope key's broadcast, softmax(QK)V, out
+    "latent_attention": "latent_attention",
     "route": "route",              # router, top-k, sort, un-sort, weights
     "experts": "experts",          # the grouped products over held experts
     # jax.lax.ragged_dot as the TPU compiler renders it: a custom call
@@ -166,7 +169,21 @@ DEVICE_SCOPES = {
     "dense_ffn": "dense_ffn",      # the dense SwiGLU FFN
     "head": "head",                # final norm, head, cross entropy
     "optimizer": "optimizer",      # clip + AdamW + apply
+    # the multi-token-prediction module, everything it adds to a step:
+    # merge, gain, its head pass and loss directly under `mtp`, its block's
+    # layers under the scopes below (LAYER_SCOPES behind "mtp_")
+    "mtp": "mtp",
 }
+
+#: the scopes a layer of the LM stack enters.  The multi-token-prediction
+#: module traces its block under ``obs.scope_prefix("mtp_")``: the phase of
+#: an instruction is its *innermost* known scope, so the module's copies of
+#: these carry names of their own, all booked as ``mtp``.  (``ragged_dot``'s
+#: custom call keeps no path at all — see ``ragged-dot-none`` above — so the
+#: module's grouped products book as ``experts`` with the stack's.)
+LAYER_SCOPES = ("conv", "attention", "window_attention", "latent_attention",
+                "route", "experts", "shared_expert", "dense_ffn")
+DEVICE_SCOPES.update({"mtp_" + s: "mtp" for s in LAYER_SCOPES})
 
 #: device time inside a tracked program but under none of these scopes
 #: is reported under this name, never guessed from a shape.

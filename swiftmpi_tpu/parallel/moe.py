@@ -178,14 +178,16 @@ def _walk(body, carry, n_rows, rows: int):
                          lambda c, acc: body(c * rows, acc), carry)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+@partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
 def _grouped(w, src, weight, src_idx, out_idx, order, starts, ends,
-             n_out, compute_dtype, rows):
+             n_out, compute_dtype, rows, scope_prefix):
     """The walk over the sorted rows that hold a held pick: ``(out (n_out,
     d) f32, rows covered)``.  Its trip count follows the routing (a
     ``fori_loop`` to the last live chunk), which autodiff cannot transpose;
     the backward pass below walks the same chunks, recomputing each, so
-    neither time nor memory follows the static ``T * k`` bound."""
+    neither time nor memory follows the static ``T * k`` bound.
+    ``scope_prefix``: the ``obs.scope_prefix`` the layer was traced under,
+    for the backward pass, which is traced after that context has closed."""
     dtype = compute_dtype or src.dtype
     with obs.named_scope("experts"):
         wc = jax.tree.map(lambda a: a.astype(dtype), w)
@@ -205,13 +207,18 @@ def _grouped(w, src, weight, src_idx, out_idx, order, starts, ends,
 
 
 def _grouped_fwd(w, src, weight, src_idx, out_idx, order, starts, ends,
-                 n_out, compute_dtype, rows):
+                 n_out, compute_dtype, rows, scope_prefix):
     out = _grouped(w, src, weight, src_idx, out_idx, order, starts, ends,
-                   n_out, compute_dtype, rows)
+                   n_out, compute_dtype, rows, scope_prefix)
     return out, (w, src, weight, src_idx, out_idx, order, starts, ends)
 
 
-def _grouped_bwd(n_out, compute_dtype, rows, res, cot):
+def _grouped_bwd(n_out, compute_dtype, rows, scope_prefix, res, cot):
+    with obs.scope_prefix(scope_prefix):
+        return _grouped_bwd_walk(compute_dtype, rows, res, cot)
+
+
+def _grouped_bwd_walk(compute_dtype, rows, res, cot):
     w, src, weight, src_idx, out_idx, order, starts, ends = res
     dout = cot[0]
     dtype = compute_dtype or src.dtype
@@ -274,7 +281,8 @@ def _held_experts(p: MoEParams, src, src_idx, out_idx, local_e, weight,
         ends = jnp.cumsum(sizes)
     out, covered = _grouped((p.w_in, p.w_out, p.w_gate), src, weight,
                             src_idx, out_idx, order, ends - sizes, ends,
-                            n_out, compute_dtype, rows)
+                            n_out, compute_dtype, rows,
+                            obs.current_scope_prefix())
     return out, sizes, covered
 
 

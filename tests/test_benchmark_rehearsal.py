@@ -832,7 +832,7 @@ def swlm_config_and_tree(toy):
                           ("sandwich_norm", False), ("embed_scale", 1.0),
                           ("n_shared_experts", 0), ("route_scale", 1.0)]:
         assert fields[name].default == default, name
-    assert {"sliding", "full", "attention"} == set(ATTENTION_OPS) < set(OPS)
+    assert {"sliding", "full", "attention"} < set(ATTENTION_OPS) < set(OPS)
     t = sw_toy()
     assert [k for k, _n in t.cfg.layer_groups()] == [
         ("sliding", "dense"), ("sliding", "moe"), ("full", "moe"),
@@ -903,6 +903,132 @@ def swlm_phase_map_and_counters(toy):
                                   np.asarray(getattr(after["moe"], name)))
     assert not np.array_equal(np.asarray(state0.params["blocks"][1]["wg"]),
                               np.asarray(t.state.params["blocks"][1]["wg"]))
+
+
+# -- the latent-attention family's surface (benchmark/families/mlalm.py's head) ----
+
+_MLA = {}
+
+
+def mla_toy():
+    """A toy stack of latent attention layers over a dense and expert FFNs
+    with a multi-token-prediction module behind it, one ``Trainer.run`` of
+    two host batches with telemetry on, by the family's call sequence."""
+    if _MLA:
+        return _MLA["toy"]
+    from swiftmpi_tpu import obs
+    from swiftmpi_tpu.models.trainer import Trainer
+    from swiftmpi_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=3, n_heads=4, d_ff=160,
+        d_expert=24, max_seq=32, attention="blockwise", attn_block=8,
+        loss_chunk=16, remat=True, remat_policy="full", n_experts=64,
+        moe_top_k=4, experts_held=(8, 16), router="sigmoid_bias",
+        route_scale=1.8, n_shared_experts=1, expert_gated=True,
+        layer_ops=("latent",) * 3, layer_ffns=("dense", "moe", "moe"),
+        q_lora_rank=12, kv_lora_rank=8, qk_nope_dim=6, qk_rope_dim=2,
+        v_head_dim=8, norm_eps=1e-5, rope_base=1e6, init_std=0.3,
+        tied_head=False, mtp_layers=1, mtp_weight=0.3)
+    was_on = obs.get_registry().enabled
+    obs.set_enabled(True)
+    trainer = Trainer(cfg, optimizer="adamw", aux_weight=0.0,
+                      learning_rate=3e-4, warmup_steps=2, decay_steps=100,
+                      weight_decay=0.1, grad_clip=1.0, b1=0.9, b2=0.95)
+    state0 = trainer.init_state(jax.random.key(3))
+    rng = np.random.default_rng(42)
+    batches = [rng.integers(0, 64, (1, 32)).astype(np.int32)
+               for _ in range(2)]
+    state, losses = trainer.run(state0, iter(batches))
+    phase_map = obs.costs.phase_map("trainer_step")
+    obs.set_enabled(was_on)
+    _MLA["toy"] = SimpleNamespace(cfg=cfg, trainer=trainer, state=state,
+                                  losses=losses, batches=batches,
+                                  phase_map=phase_map)
+    return _MLA["toy"]
+
+
+@surface
+def mlalm_config_and_tree(toy):
+    """The ``TransformerConfig`` fields and the operator kind the family
+    sets beyond the other LM families', the parameter names it samples —
+    the latent layer's and ``params["mtp"]`` — and ``hidden_states`` at
+    every half layer and at the module's three states."""
+    from swiftmpi_tpu.models.transformer import (ATTENTION_OPS, OPS,
+                                                 TransformerConfig,
+                                                 hidden_states)
+
+    fields = TransformerConfig.__dataclass_fields__
+    for name, default in [("q_lora_rank", 0), ("kv_lora_rank", 0),
+                          ("qk_nope_dim", 0), ("qk_rope_dim", 0),
+                          ("v_head_dim", 0), ("mtp_layers", 0),
+                          ("mtp_weight", 0.3)]:
+        assert fields[name].default == default, name
+    assert "latent" in ATTENTION_OPS and set(ATTENTION_OPS) < set(OPS)
+    t = mla_toy()
+    assert t.cfg.layer_groups() == [(("latent", "dense"), 1),
+                                    (("latent", "moe"), 2)]
+    params = t.state.params
+    assert set(params) == {"embed", "head", "blocks", "ln_f", "mtp"}
+    latent = {"ln1", "ln2", "wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm",
+              "wkv_b", "wo"}
+    experts = {"moe", "shared_gate", "shared_up", "shared_down"}
+    assert set(params["blocks"][0]) == latent | {"w_gate", "w_up", "w_down"}
+    assert set(params["blocks"][1]) == latent | experts
+    assert params["blocks"][1]["wkv_a"].shape == (2, 32, 8 + 2)
+    assert params["blocks"][1]["wkv_b"].shape == (2, 8, 4 * (6 + 8))
+    assert params["blocks"][1]["wq_b"].shape == (2, 12, 4 * 8)
+    assert params["blocks"][1]["wo"].shape == (2, 4 * 8, 32)
+    mtp = params["mtp"]
+    assert set(mtp) == {"hnorm", "enorm", "eh_proj", "block", "norm"}
+    assert mtp["eh_proj"].shape == (64, 32)
+    assert set(mtp["block"]) == latent | experts
+    assert mtp["block"]["moe"].w_in.shape == (1, 8, 32, 24)
+    mu = t.state.opt_state[1][0].mu
+    assert jax.tree.structure(mu) == jax.tree.structure(params)
+    assert len(t.losses) == 2
+    assert all(math.isfinite(float(x)) for x in t.losses)
+    hs = hidden_states(params, t.batches[0], t.cfg)
+    assert len(hs) == 2 * t.cfg.n_layers + 1 + 3
+    assert all(h.shape == (1, 32, 32) for h in hs)
+
+
+@surface
+def mlalm_phase_map_and_counters(toy):
+    """The device scopes ``latent_attention`` and ``mtp`` beside the LM
+    step's, the loss's two parts and the module's expert counters in
+    ``train_metrics``, one launch a step, and a share's routers and
+    selection biases — the module's too — left alone."""
+    from swiftmpi_tpu.obs.catalog import DEVICE_SCOPES, LAYER_SCOPES
+
+    t = mla_toy()
+    want = {"embed", "latent_attention", "mtp", "route", "experts",
+            "shared_expert", "dense_ffn", "head", "optimizer"}
+    assert want <= set(DEVICE_SCOPES.values())
+    assert {DEVICE_SCOPES["mtp_" + s] for s in LAYER_SCOPES} == {"mtp"}
+    assert want <= set(t.phase_map["phase"].values())
+    assert not any(p.startswith("mtp_") for p in
+                   t.phase_map["phase"].values())
+    assert t.phase_map["module"] == "jit_train_step"
+    m = t.trainer.train_metrics
+    assert m["steps"] == 2 and m["dropped_picks_per_step"] == 0.0
+    assert m["mtp_dropped_picks_per_step"] == 0.0
+    assert 0.0 < m["held_pick_share"] < 100.0
+    assert 0.0 <= m["mtp_held_pick_share"] <= 100.0
+    mean = sum(float(x) for x in t.losses) / 2
+    assert abs(m["main_loss"] + 0.3 * m["mtp_loss"] - mean) < 1e-4 * mean
+    assert 0.0 < m["mtp_loss_share"] < 100.0
+    state0 = t.trainer.init_state(jax.random.key(3))
+    pairs = [(state0.params["blocks"][1], t.state.params["blocks"][1]),
+             (state0.params["mtp"]["block"], t.state.params["mtp"]["block"])]
+    for before, after in pairs:
+        for name in ("router", "bias"):
+            assert np.array_equal(np.asarray(getattr(before["moe"], name)),
+                                  np.asarray(getattr(after["moe"], name)))
+        assert not np.array_equal(np.asarray(before["wkv_a"]),
+                                  np.asarray(after["wkv_a"]))
+    assert not np.array_equal(np.asarray(state0.params["mtp"]["eh_proj"]),
+                              np.asarray(t.state.params["mtp"]["eh_proj"]))
 
 
 @pytest.mark.parametrize("name", sorted(SURFACE))

@@ -526,3 +526,82 @@ def test_trinity_ep16_trainer_step_fits_one_chip(topo, no_compile_cache):
     for dims in set(re.findall(r"= \w+\[([\d,]+)\]", text)):
         big = [int(d) for d in dims.split(",") if int(d) >= S]
         assert len(big) < 2, f"[{dims}]"
+
+
+def test_glm47f_ep8_trainer_step_fits_one_chip(topo, no_compile_cache):
+    """The real ``trainer_step`` of ``glm47f-ep8-8k-t8k`` — a dense layer and
+    a scanned run of four expert layers at the published widths, every
+    attention layer latent (ranks 768 / 512, 20 heads of 192 + 64 over values
+    of 256, ``G`` = 1), 8 of 64 experts beside the shared one, the
+    multi-token-prediction module behind the trunk, an untied 19,360-row head
+    run twice, one packed sequence of 8,192 — on one v5e chip: 706,518,848
+    parameters; the compiler's ``peak_memory_in_bytes`` (what the chip must
+    hold at once: 13.77 GiB) fits the 15.75 GiB the runtime gives, where the
+    sum of arguments and every temporary allocation (16.62 GiB) would not;
+    the grouped products are the compiler's ``ragged-dot`` kernels, 12 a
+    layer body in two bodies (the stack's scanned one and the module's); the
+    backward writes no strided ``dq`` tile; no buffer has the size of a
+    head's ``(S, S)`` scores; and the module's instructions carry its own
+    scopes."""
+    import sys
+    sys.path.insert(0, REPO)
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.costs import mlalm as costs
+    from benchmark.families import mlalm as family
+    from swiftmpi_tpu.models.trainer import Trainer
+    from swiftmpi_tpu.obs.catalog import DEVICE_SCOPES
+
+    config, traffic = _cell("glm47f-ep8-8k-t8k")
+    cfg = family.transformer_config(config, traffic)
+    assert cfg.layer_groups() == [(("latent", "dense"), 1),
+                                  (("latent", "moe"), 4)]
+    assert (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_dim,
+            cfg.qk_rope_dim, cfg.v_head_dim) == (768, 512, 20, 192, 64, 256)
+    assert (cfg.n_experts, cfg.moe_top_k, cfg.held[1] - cfg.held[0],
+            cfg.mtp_layers) == (64, 4, 8, 1)
+    one = SingleDeviceSharding(topo.devices[0])
+    trainer = Trainer(cfg, **family.trainer_kwargs(config))
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda k: trainer.init_state(k).tree(),
+                       jax.random.key(0)))
+    seqs, S = int(traffic["sequences_per_step"]), cfg.max_seq
+    assert (seqs, S) == (1, 8192)
+    tokens = jax.ShapeDtypeStruct((seqs, S), jnp.int32, sharding=one)
+    n_params = sum(a.size for a in jax.tree.leaves(state["params"]))
+    # latent attention 21,759,232 + two gains 4,096 a layer; dense FFN
+    # 62,914,560; router 131,072 + bias 64 + shared 9,437,184 + 8 x
+    # 9,437,184; embedding and head 2 x 39,649,280; final gain 2,048; the
+    # module 8,388,608 + three gains 6,144 + one expert layer: 11.30 GB x 16 B
+    assert n_params == 706_518_848
+    shape = {"kinds": [("latent", "dense")] + [("latent", "moe")] * 4,
+             "mtp": 1, "d_model": 2048, "heads": 20, "q_rank": 768,
+             "kv_rank": 512, "nope": 192, "rope": 64, "v_dim": 256,
+             "d_ff": 10240, "d_expert": 1536, "d_shared": 1536,
+             "experts": 64, "experts_held": 8, "vocab": 19360}
+    assert costs.parameters(shape) == n_params
+
+    compiled = trainer._build_step().lower(
+        state["params"], state["opt_state"], state["step"], tokens).compile()
+    mem = compiled.memory_analysis()
+    assert mem.peak_memory_in_bytes <= 14.0 * GIB, \
+        f"{mem.peak_memory_in_bytes / GIB:.3f} GiB"
+    assert mem.alias_size_in_bytes >= 0.99 * mem.output_size_in_bytes
+    assert mem.argument_size_in_bytes <= 7.9 * GIB       # 12 B a parameter
+
+    text = compiled.as_text()
+    kernels = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                         r'op_name="([^"]*)"', text)
+    assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
+    assert kernels.count("ragged-dot-none") == 2 * 12
+    assert " dynamic-update-slice(" in text
+    assert not re.findall(
+        rf"= f32\[{seqs},{S},20,1,256\]\S* dynamic-update-slice\(", text)
+    # (widths reach past S here: 8,960 = 20 x 448, 10,240, 19,360)
+    for dims in set(re.findall(r"= \w+\[([\d,]+)\]", text)):
+        assert dims.split(",").count(str(S)) < 2, f"[{dims}]"
+    scopes = set(re.findall(r"[/(](mtp_\w+|mtp|latent_attention)[/)]", text))
+    assert {"mtp", "mtp_latent_attention", "mtp_route", "mtp_experts",
+            "mtp_shared_expert", "latent_attention"} <= scopes
+    assert scopes <= set(DEVICE_SCOPES)
