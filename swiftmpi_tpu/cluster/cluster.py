@@ -111,7 +111,8 @@ class Cluster:
         kwargs = ({"mesh": self.mesh, "data_plane": self.data_plane}
                   if backend in ("tpu", "hybrid")
                   else {"shards": n_servers,
-                        "platform": devices[0].platform}
+                        "platform": devices[0].platform,
+                        "mesh": self.mesh, "axis": self.table_axis}
                   if backend == "xla" else {})
         self.transfer = get_transfer(backend, **kwargs)
         self._initialized = True

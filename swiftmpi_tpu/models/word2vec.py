@@ -39,12 +39,14 @@ hot loop was derived from.  Same batch layout; the pair axis is (B, 2W).
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from swiftmpi_tpu import obs
 from swiftmpi_tpu.cluster.cluster import Cluster
@@ -219,7 +221,8 @@ def _stack_group(batches):
     return tuple(jnp.asarray(f) for f in _stack_group_host(batches))
 
 
-def _negative_slots(key, alias_prob, alias_idx, slot_of_vocab, shape):
+def _negative_slots(key, alias_prob, alias_idx, slot_of_vocab, shape,
+                    take=None):
     """``sample_alias_slots`` for a train step.  While the step is
     traced (Python time: nothing enters the program) the gauge
     ``train/sampler_slot_lookups{mode=}`` takes the scalar slot lookups
@@ -229,7 +232,7 @@ def _negative_slots(key, alias_prob, alias_idx, slot_of_vocab, shape):
         mode, lookups = alias_slot_lookups(alias_prob.shape[0], shape)
         reg.gauge("train/sampler_slot_lookups", mode=mode).set(lookups)
     return sample_alias_slots(key, alias_prob, alias_idx, slot_of_vocab,
-                              shape)
+                              shape, take)
 
 
 def _cbow_targets(slot_of_vocab, alias_prob, alias_idx, centers,
@@ -663,11 +666,43 @@ class Word2Vec:
             draws * np.log1p(-np.minimum(head, 1.0)))))
         return min(touched / n_hot, 1.0)
 
+    def _step_split(self):
+        """``(mesh, axis, n)`` when the sync step can run SPLIT over the
+        table's axis — each of its ``n`` chips rendering, sampling for,
+        pulling for and pushing its own share of the batch, `_build_step`'s
+        ``run_split`` — else ``None``.  From what the model observes: the
+        span rendering with per-center negatives (the one rendering that
+        has a split form, `_build_grads_stencil`), on a transfer that
+        routes this table's rows to their owners
+        (`XlaTransfer.route_mode`), one process, no replicated hot head,
+        no numerics plane."""
+        tr = self.transfer
+        if (not self.stencil or self.sg or self.shared_negatives
+                or self._numerics is not None or self.table.n_hot
+                or jax.process_count() > 1
+                or getattr(tr, "route_mode", None) is None
+                or tr.route_mode(self.table.state) != "wrap"):
+            return None
+        return tr.mesh, tr.axis, tr.shards
+
+    def _splits(self, span, centers: int, n: int) -> bool:
+        """Whether a step of this packed ``span`` batch runs split ``n``
+        ways: the span's positions divide, a chip's share holds a window,
+        and neither push is one the transfer would apply densely (a
+        full-table sweep, weighed against the whole batch)."""
+        S = (span.shape[-1] - 2 * centers) // 2
+        return (S % n == 0 and S // n >= 2 * self.window
+                and not any(self.transfer.pushes_dense(
+                    slots, self.table.capacity)
+                    for slots in (S, centers * (self.negative + 1))))
+
     # -- the fused step ----------------------------------------------------
     def _build_step(self):
         """Sync step: grads against current state + immediate push.  The
         table state is donated — the update is in-place in HBM."""
+        split = self._step_split()
         grads_fn = self._build_grads()
+        split_grads_fn = self._build_grads(split) if split else None
         apply_fn = self._build_apply()
         # numerics plane: `num is None` (the default) leaves the traced
         # program untouched — the branches below are Python-time
@@ -678,26 +713,58 @@ class Word2Vec:
         # row-write counter (telemetry on only: a step built without it
         # returns no count, so the timed program carries none): the
         # step's sparse pushes' distinct valid rows x fields, a fourth
-        # result, fetched with the loss
+        # result, fetched with the loss — and, where the transfer routes
+        # rows to their owners, the rows it routed and the bucket slots
+        # it exchanged for them, a fifth and a sixth
+        telemetry = obs.get_registry().enabled
         count_rows = getattr(self.transfer, "count_rows_written", None) \
-            if obs.get_registry().enabled else None
+            if telemetry else None
+        count_routed = getattr(self.transfer, "count_routed", None) \
+            if telemetry else None
 
-        def apply_counted(state, pushes):
-            if count_rows is None:
-                return apply_fn(state, pushes), ()
-            with count_rows() as tape:
-                out = apply_fn(state, pushes)
-            return out, (sum(tape, jnp.int32(0)),)
+        def counted(fn, *args):
+            """``(fn(*args), counts)`` with the transfer's tapes held."""
+            with contextlib.ExitStack() as tapes:
+                rows = count_rows and tapes.enter_context(count_rows())
+                routed = count_routed and tapes.enter_context(count_routed())
+                out = fn(*args)
+            counts = () if rows is None else (sum(rows, jnp.int32(0)),)
+            if routed:
+                counts += tuple(sum(c, jnp.int32(0)) for c in zip(*routed))
+            return out, counts
 
-        def run(state, statics, batch, key, **shape):
-            pushes, es, ec = grads_fn(state, *statics, *batch, key, **shape)
-            out, rows = apply_counted(state, pushes)
+        def run(state, statics, batch, key, grads_fn=grads_fn, **shape):
+            def grads_and_apply():
+                pushes, es, ec = grads_fn(state, *statics, *batch, key,
+                                          **shape)
+                return apply_fn(state, pushes), pushes, es, ec
+            (out, pushes, es, ec), counts = counted(grads_and_apply)
             if num is not None:
                 obs_numerics.stage_step(
                     num, state, out,
                     obs_numerics.spec_stats(pushes, n_hot),
                     es, ec, gfields)
-            return (out, es, ec, *rows)
+            return (out, es, ec, *counts)
+
+        def run_split(state, statics, batch, key, **shape):
+            """`run` with every chip of the table's axis rendering,
+            sampling for, pulling for and pushing its own share of the
+            batch (`_build_grads_stencil`), the table's shard its state:
+            what leaves a chip is what `transfer/route.py` exchanges and
+            the sums of the loss and the counters."""
+            mesh, axis, _ = split
+
+            def body(state, statics, batch, key):
+                out, *sums = run(state, statics, batch, key,
+                                 grads_fn=split_grads_fn, **shape)
+                return (out, *(jax.lax.psum(x, axis) for x in sums))
+            rows = dict.fromkeys(state, P(axis))
+            # the loss's two sums, then the counters `counted` returns
+            n_sums = 2 + bool(count_rows) + 2 * bool(count_routed)
+            return jax.shard_map(
+                body, mesh=mesh, in_specs=(rows, P(), P(), P()),
+                out_specs=(rows, *[P()] * n_sums),
+                check_vma=False)(state, statics, batch, key)
 
         if self.stencil:
             # the batch is one packed buffer (StencilBatch.pack), cut
@@ -705,8 +772,10 @@ class Word2Vec:
             @partial(jax.jit, donate_argnums=0, static_argnames="centers")
             def step(state, slot_of_vocab, alias_prob, alias_idx,
                      span, key, *, centers):
-                return run(state, (slot_of_vocab, alias_prob, alias_idx),
-                           (span,), key, centers=centers)
+                fn = run_split if split and self._splits(
+                    span, centers, split[2]) else run
+                return fn(state, (slot_of_vocab, alias_prob, alias_idx),
+                          (span,), key, centers=centers)
 
             return obs.costs.track("w2v_step", step)
 
@@ -1039,11 +1108,12 @@ class Word2Vec:
         return obs.costs.track("w2v_hogwild", step,
                                steps_per_call=n_inner), n_workers
 
-    def _build_grads(self):
+    def _build_grads(self, split=None):
         """Gradient phase of the step: pull rows, CBOW- or skip-gram-NS
         math, per-key mean normalization — no push.  Split out so the async
         (``local_steps``) mode can compute grads against a *stale* state
-        snapshot while pushes land on the live state."""
+        snapshot while pushes land on the live state.  ``split``:
+        `_step_split`'s answer, for the one rendering it is given for."""
         if self.stencil:
             if self.sg:
                 raise ValueError(
@@ -1064,7 +1134,7 @@ class Word2Vec:
                 self.resolved_rendering = "stencil_shared"
                 return self._build_grads_stencil(shared=True)
             self.resolved_rendering = "stencil"
-            return self._build_grads_stencil(shared=False)
+            return self._build_grads_stencil(shared=False, split=split)
         if self.sg:
             if self.dense_logits:
                 raise ValueError(
@@ -1345,7 +1415,7 @@ class Word2Vec:
 
         return grads_fn
 
-    def _build_grads_stencil(self, shared: bool):
+    def _build_grads_stencil(self, shared: bool, split=None):
         """Positional-stencil rendering of the CBOW gradient phase: the
         context side is computed over the batch's stream SPAN, position
         by position, never over a ``(B, 2W)`` pair grid.
@@ -1385,7 +1455,23 @@ class Word2Vec:
         sampling stream as the parity gather rendering — directly
         checkable against the numpy oracle.  ``shared=True``
         (``shared_negatives: 1``): the batch-shared pool of
-        ``_build_grads_shared`` on the h side."""
+        ``_build_grads_shared`` on the h side.
+
+        ``split`` (`_step_split`: ``(mesh, axis, n)``): the rendering of
+        ONE chip's share, to be run under a ``shard_map`` over the table's
+        ``axis``.  The span is cut by position: chip ``i`` owns positions
+        ``[i S/n, (i+1) S/n)``, the centers standing there (a run of the
+        batch's ascending ``center_pos``), and ``W`` positions of halo on
+        either side, which it pulls itself.  The target side is rendered
+        by POSITION, not by center (a position that is no center is an
+        invalid row), so a center's window sum is the row at its own
+        position and nothing is gathered or scattered between the two
+        indexings.  A position in a halo takes gradient from the centers
+        of two chips; each pushes its own part with its own count and the
+        push's ``mean`` divides the owner's sum by the summed counts, as
+        it does for any key pushed twice.  The negatives are the global
+        ``(B, K)`` draw's rows (`_negative_slots` ``take``): the stream
+        is the unsplit step's, letter for letter."""
         access = self.access
         transfer = self.transfer
         W = self.window
@@ -1419,15 +1505,7 @@ class Word2Vec:
                 half_pos = jnp.zeros((S,), jnp.int32).at[at].set(
                     half, mode="drop", unique_indices=True,
                     indices_are_sorted=True)
-                half_pad = pad(half_pos, 0)
-                # a padding id of its own: no window reaches past an end
-                sid_pad = pad(sent_id, -2)
-                # fwd[o][p]: position p + o is in center p's window;
-                # bwd[o][p]: p is in the window of the center at p - o
-                fwd = [(abs(o) <= half_pos)
-                       & (shifted(sid_pad, o) == sent_id) for o in offsets]
-                bwd = [(abs(o) <= shifted(half_pad, -o))
-                       & (shifted(sid_pad, -o) == sent_id) for o in offsets]
+                fwd, bwd = windows(sent_id, half_pos)
             with obs.named_scope("math"):
                 # THE pull this rendering exists for: S rows, once
                 v_pad = pad(transfer.pull(
@@ -1438,16 +1516,75 @@ class Word2Vec:
                 neu1 = neu1_pos[cp]                              # (B, d)
             return span_slots, c_words, c_slots, row_valid, at, bwd, neu1
 
-        def v_push(span_slots, at, bwd, neu1e):
-            S = span_slots.shape[0]
+        def windows(sent_id, half_pos):
+            """fwd[o][p]: position p + o is in center p's window;
+            bwd[o][p]: p is in the window of the center at p - o."""
+            half_pad = pad(half_pos, 0)
+            # a padding id of its own: no window reaches past an end
+            sid_pad = pad(sent_id, -2)
+            fwd = [(abs(o) <= half_pos)
+                   & (shifted(sid_pad, o) == sent_id) for o in offsets]
+            bwd = [(abs(o) <= shifted(half_pad, -o))
+                   & (shifted(sid_pad, -o) == sent_id) for o in offsets]
+            return fwd, bwd
+
+        def span_parts_split(state, slot_of_vocab, span, centers):
+            """`span_parts` of this chip's share: its ``S / n`` positions
+            and ``W`` of halo either side, the centers as rows of its own
+            positions."""
+            _, axis, n = split
+            _, _, center_pos, half = unpack_span(span, centers)
+            B = centers
+            S = (span.shape[0] - 2 * B) // 2
+            own = S // n
+            lo = jax.lax.axis_index(axis) * own
+            core = slice(W, W + own)
+            with obs.named_scope("sample"):
+                # tokens and sentence ids of the chip's positions, read
+                # where `unpack_span` has them in the packed batch
+                pos = lo - W + jnp.arange(own + 2 * W, dtype=jnp.int32)
+                inside = (pos >= 0) & (pos < S)
+                pos = jnp.clip(pos, 0, S - 1)
+                tokens = span[pos]
+                sent_id = jnp.where(inside, span[S + pos], -2)
+                span_slots = jnp.where(sent_id >= 0, slot_of_vocab[tokens],
+                                       -1)
+                # the centers standing here: a run of the batch's
+                # ascending positions, from the first at or past `lo`
+                b = jnp.sum((center_pos >= 0) & (center_pos < lo),
+                            dtype=jnp.int32) + jnp.arange(own,
+                                                          dtype=jnp.int32)
+                rank = jnp.clip(b, 0, B - 1)
+                cp = center_pos[rank]
+                at = jnp.where((b < B) & (cp >= lo) & (cp < lo + own),
+                               cp - lo, own)
+                placed = dict(mode="drop", unique_indices=True,
+                              indices_are_sorted=True)
+                half_pos = pad(jnp.zeros((own,), jnp.int32).at[at].set(
+                    half[rank], **placed), 0)
+                # the center standing at each position, -1: none
+                rank = jnp.full((own,), -1, jnp.int32).at[at].set(
+                    rank, **placed)
+                row_valid = rank >= 0
+                c_words = tokens[core]
+                c_slots = jnp.where(row_valid, span_slots[core], -1)
+                fwd, bwd = windows(sent_id, half_pos)
+            with obs.named_scope("math"):
+                v_pad = pad(transfer.pull(
+                    state, span_slots, access, fields=("v",)
+                )["v"].astype(jnp.float32), 0.0)
+                neu1 = sum(jnp.where(m[:, None], shifted(v_pad, o), 0.0)
+                           for o, m in zip(offsets, fwd))[core]
+            return (span_slots, c_words, c_slots, row_valid,
+                    jnp.clip(rank, 0, B - 1), bwd, neu1)
+
+        def v_push(span_slots, bwd, at_positions, neu1e):
             with obs.named_scope("math"):
                 # invert the stencil by the transposed shifts: a position
                 # takes the gradient of every center whose window holds
                 # it, and their number rides along so the push's mean
                 # divides by the true pair count
-                e_pad = pad(jnp.zeros((S, d), jnp.float32).at[
-                    at].set(neu1e, mode="drop", unique_indices=True,
-                            indices_are_sorted=True), 0.0)
+                e_pad = pad(at_positions(neu1e), 0.0)
                 vg = sum(jnp.where(m[:, None], shifted(e_pad, -o), 0.0)
                          for o, m in zip(offsets, bwd))
                 vc = sum(m.astype(jnp.float32) for m in bwd)
@@ -1458,8 +1595,26 @@ class Word2Vec:
         def grads_fn(state, slot_of_vocab, alias_prob, alias_idx,
                      span, key, *, centers):
             B = centers
-            (span_slots, c_words, c_slots, row_valid, at, bwd,
-             neu1) = span_parts(state, slot_of_vocab, span, centers)
+            if split:
+                # a row a position of the chip's own: the centers stand
+                # where they stand, and draw `take`'s rows of the batch's
+                (span_slots, c_words, c_slots, row_valid, take, bwd,
+                 neu1) = span_parts_split(state, slot_of_vocab, span,
+                                          centers)
+                rows = neu1.shape[0]
+
+                def at_positions(neu1e):
+                    return pad(neu1e, 0.0)
+            else:
+                (span_slots, c_words, c_slots, row_valid, at, bwd,
+                 neu1) = span_parts(state, slot_of_vocab, span, centers)
+                rows, take = B, None
+
+                def at_positions(neu1e):
+                    return jnp.zeros((span_slots.shape[0], d),
+                                     jnp.float32).at[at].set(
+                        neu1e, mode="drop", unique_indices=True,
+                        indices_are_sorted=True)
             if shared:
                 with obs.named_scope("sample"):
                     negs = sample_alias(key, alias_prob, alias_idx, (K,))
@@ -1489,7 +1644,8 @@ class Word2Vec:
                     # normalization-collapse note in _build_grads_shared
                     pushes = (PushSpec(c_slots, {"h": gh_pos}, mean=True),
                               PushSpec(neg_slots, {"h": gh_neg}),
-                              v_push(span_slots, at, bwd, neu1e))
+                              v_push(span_slots, bwd, at_positions,
+                                     neu1e))
                     ratio = self.negative / K
                     err_sum = jnp.sum(1e4 * g_pos * g_pos) \
                         + ratio * jnp.sum(1e4 * g_neg * g_neg)
@@ -1499,28 +1655,28 @@ class Word2Vec:
                 # parity negatives: per-center draws from the SAME sampling
                 # stream as _cbow_targets — the oracle test's anchor
                 negs, neg_slots = _negative_slots(
-                    key, alias_prob, alias_idx, slot_of_vocab, (B, K))
+                    key, alias_prob, alias_idx, slot_of_vocab, (B, K), take)
                 t_slots = jnp.concatenate(
-                    [c_slots[:, None], neg_slots], axis=1)       # (B, K+1)
+                    [c_slots[:, None], neg_slots], axis=1)    # (rows, K+1)
                 t_valid = jnp.concatenate(
-                    [jnp.ones((B, 1), bool), negs != c_words[:, None]],
+                    [jnp.ones((rows, 1), bool), negs != c_words[:, None]],
                     axis=1)
                 t_valid = t_valid & row_valid[:, None]
                 t_slots = jnp.where(t_valid, t_slots, -1)
             with obs.named_scope("math"):
                 h_t = transfer.pull(
                     state, t_slots.reshape(-1), access, fields=("h",)
-                )["h"].reshape(B, K + 1, d).astype(jnp.float32)
+                )["h"].reshape(rows, K + 1, d).astype(jnp.float32)
                 f = jnp.einsum("bd,bkd->bk", neu1, h_t)
                 labels = jnp.concatenate(
-                    [jnp.ones((B, 1)), jnp.zeros((B, K))], axis=1)
+                    [jnp.ones((rows, 1)), jnp.zeros((rows, K))], axis=1)
                 g = (labels - sigmoid_clipped(f)) * alpha
                 g = jnp.where(t_valid, g, 0.0)                   # (B, K+1)
                 h_contrib = g[..., None] * neu1[:, None, :]      # (B,K+1,d)
                 neu1e = jnp.einsum("bk,bkd->bd", g, h_t)         # (B, d)
                 # v first, as _assemble_push has it: the order of the two
                 # pushes moves the step's peak memory, no value
-                pushes = (v_push(span_slots, at, bwd, neu1e),
+                pushes = (v_push(span_slots, bwd, at_positions, neu1e),
                           PushSpec(t_slots.reshape(-1),
                                    {"h": h_contrib.reshape(-1, d)},
                                    mean=True))
@@ -1887,6 +2043,7 @@ class Word2Vec:
         step_i = 0
         hogwild_dropped = 0
         rows_written, rows_steps = 0.0, 0   # the sync step's row writes
+        routed_rows, routed_slots, routed_steps = 0.0, 0.0, 0   # ... routed
         # telemetry plane ([worker] telemetry, obs/): reuse an outer
         # recorder (bench harness, trainer) or own one for this call.
         # The Throughput meter and transfer ledger keep their own
@@ -2023,6 +2180,7 @@ class Word2Vec:
                 # optimization targets.
                 es_q, ec_q = _LossAccum(dispatch_bound), _LossAccum(None)
                 rows_q = _LossAccum(None)
+                routed_q, offered_q = _LossAccum(None), _LossAccum(None)
 
                 # an item of the loop is input_wait, then four siblings:
                 # step_prep, h2d (to_device), dispatch, step_book
@@ -2056,6 +2214,9 @@ class Word2Vec:
                         self.table.state = state
                         if rows:
                             rows_q.add(rows[0])
+                        if len(rows) == 3:
+                            routed_q.add(rows[1])
+                            offered_q.add(rows[2])
                         if not sync:
                             step_i += 1
                             if step_i % self.local_steps == 0:
@@ -2170,6 +2331,9 @@ class Word2Vec:
                     err_cnt = int(round(ec_q.total()))
                     rows_written += rows_q.total()
                     rows_steps += rows_q.count
+                    routed_rows += routed_q.total()
+                    routed_slots += offered_q.total()
+                    routed_steps += routed_q.count
                     close_epoch(it, err_sum, err_cnt)
             if checkpoint_path and (it + 1) % checkpoint_every == 0:
                 self.table.state = state
@@ -2220,6 +2384,13 @@ class Word2Vec:
             if rows_steps:
                 self.train_metrics["rows_written_per_step"] = \
                     rows_written / rows_steps
+            if routed_steps:
+                # what the transfer sent to the rows' owners, and how much
+                # of the bucket slots it exchanged for that was rows
+                self.train_metrics["routed_rows_per_step"] = \
+                    routed_rows / routed_steps
+                self.train_metrics["route_fill_share"] = \
+                    100.0 * routed_rows / max(routed_slots, 1.0)
             if pairs is not None and pairs.steps:
                 self.train_metrics["pairs_per_step"] = \
                     pairs.valid / pairs.steps

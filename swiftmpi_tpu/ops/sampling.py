@@ -56,7 +56,7 @@ def build_unigram_alias(counts: np.ndarray, power: float = 0.75
     return prob.astype(np.float32), alias
 
 
-def _alias_draw_packed(key, prob, extra_cols, shape):
+def _alias_draw_packed(key, prob, extra_cols, shape, take=None):
     """Shared draw core: packs ``(prob_bits, *extra_cols)`` into one
     (V, 1+len(extra_cols)) int32 table and resolves each draw with ONE
     row gather.  A scalar gather on the chip is transaction-bound: 6-7
@@ -69,12 +69,19 @@ def _alias_draw_packed(key, prob, extra_cols, shape):
     negatives through ``sample_alias`` while training itself uses
     ``sample_alias_slots``.
 
+    ``take``: indices into the draw's first axis.  The draw is made at
+    ``shape`` whatever a caller keeps of it (the stream is the same
+    letter for letter); the table lookups, which are the cost, are made
+    for the rows kept.
+
     Returns ``(j, accept, rows)``: bucket draws, acceptance mask, and
     the gathered packed rows (prob bits in column 0)."""
     k1, k2 = jax.random.split(key)
     V = prob.shape[0]
     j = jax.random.randint(k1, shape, 0, V)
     u = jax.random.uniform(k2, shape)
+    if take is not None:
+        j, u = j[take], u[take]
     packed = jnp.stack(
         [jax.lax.bitcast_convert_type(prob, jnp.int32)] + extra_cols,
         axis=1)
@@ -108,8 +115,8 @@ def alias_slot_lookups(vocab_size: int, shape: Tuple[int, ...]
 
 
 def sample_alias_slots(key: jax.Array, prob: jax.Array, alias: jax.Array,
-                       slot_of_vocab: jax.Array, shape: Tuple[int, ...]
-                       ) -> Tuple[jax.Array, jax.Array]:
+                       slot_of_vocab: jax.Array, shape: Tuple[int, ...],
+                       take=None) -> Tuple[jax.Array, jax.Array]:
     """Alias draws fused with the vocab->slot mapping: returns
     ``(negs, neg_slots)`` with ``neg_slots == slot_of_vocab[negs]``, the
     draw stream bit-identical to ``sample_alias`` + that lookup.
@@ -130,13 +137,16 @@ def sample_alias_slots(key: jax.Array, prob: jax.Array, alias: jax.Array,
 
     The rule does not weigh a scan's trip count: this function cannot
     see it, and ``per_draw`` costs a trip at most what an unhoisted
-    ``per_vocab`` pack would."""
+    ``per_vocab`` pack would.
+
+    ``take``: rows of the ``shape`` draw to resolve (`_alias_draw_packed`);
+    the results have ``take``'s leading shape."""
     V = prob.shape[0]
     per_vocab = alias_slot_lookups(V, shape)[0] == "per_vocab"
     cols = [alias, slot_of_vocab[:V]]
     if per_vocab:
         cols.append(slot_of_vocab[alias])
-    j, accept, rows = _alias_draw_packed(key, prob, cols, shape)
+    j, accept, rows = _alias_draw_packed(key, prob, cols, shape, take)
     alias_j = rows[..., 1]
     alias_slots = rows[..., 3] if per_vocab else slot_of_vocab[alias_j]
     negs = jnp.where(accept, j, alias_j).astype(jnp.int32)

@@ -1,11 +1,13 @@
-"""``xla`` transfer backend: gather/scatter, compiler-chosen collectives.
+"""``xla`` transfer backend: gather/scatter on the rows a device holds.
 
 The idiomatic-JAX data plane: ``pull`` is a row gather, ``push`` is an
 in-batch segment-sum dedup followed by a one-shot access-method update and a
-row scatter.  Under ``jit`` over a mesh with the table row-sharded, XLA
-lowers the gather/scatter to the appropriate ICI collectives — the same
-traffic the explicit ``tpu`` backend spells out by hand, minus the manual
-bucketing.  Everything here is shape-static and traceable.
+row scatter.  On a table row-sharded over a mesh axis the same gather and
+the same sparse push run on each row's OWNER, reached through
+``transfer/route.py``'s ``all_to_all`` exchange (`XlaTransfer.route_mode`
+says when; a mesh the backend was not told of, or one with a ``data`` axis,
+leaves the exchange to the partitioner as before).  Everything here is
+shape-static and traceable.
 
 Dedup-without-unique trick (XLA has no dynamic ``unique``): sort the batch
 slots, segment-sum gradients into batch-local segments keyed by
@@ -25,9 +27,12 @@ import contextlib
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from swiftmpi_tpu import obs
 from swiftmpi_tpu.ops import calibration, pallas_gather, pallas_scatter
+from swiftmpi_tpu.parameter.sparse_table import is_hot_field
+from swiftmpi_tpu.transfer import route
 from swiftmpi_tpu.transfer.api import (Transfer, bump_row_versions,
                                        grad_row_bytes)
 
@@ -174,7 +179,8 @@ class XlaTransfer(Transfer):
     name = "xla"
 
     def __init__(self, dense_apply: bool | None = None, shards: int = 1,
-                 platform: str | None = None):
+                 platform: str | None = None, mesh=None,
+                 axis: str | None = None):
         """``dense_apply``: True forces the dense full-table push, False
         forces the sort-based sparse push, None (default) picks per call —
         dense when the push batch is at least half the table capacity.
@@ -191,10 +197,20 @@ class XlaTransfer(Transfer):
         ``platform``: of the devices the table lives on (``Cluster``
         passes its devices'; default: this process's first device's).
         The write-back's costs were measured on TPUs, so what they
-        select is selected there alone."""
+        select is selected there alone.
+
+        ``mesh``, ``axis``: the mesh the table lives on and the axis its
+        rows are split over (``Cluster`` passes its own).  With them, and
+        more than one shard, a pull and a sparse push are ROUTED to the
+        rows' owners (`route_mode`); without them the exchange is the
+        partitioner's."""
         self.dense_apply = dense_apply
         self.shards = max(1, int(shards))
         self.platform = platform or jax.devices()[0].platform
+        self.mesh, self.axis = mesh, axis
+        #: while a list (`count_routed`), every routed pull and push
+        #: traced appends ``(rows routed, bucket slots exchanged)``
+        self.routed: list | None = None
         #: field -> ``"per_row"`` | ``"sweep"`` | ``"head_rows"``: the
         #: form the write-back of that field's last traced sparse push took
         self.resolved_write_back: dict = {}
@@ -223,6 +239,75 @@ class XlaTransfer(Transfer):
         if self.rows_written is not None:
             self.rows_written.append(rows() * len(touched))
 
+    @contextlib.contextmanager
+    def count_routed(self):
+        """The list every routed pull and push traced inside the block
+        appends its ``(rows routed, bucket slots exchanged)`` to (traced
+        int32s): what a step built with telemetry on returns for
+        ``routed_rows_per_step`` and ``route_fill_share``."""
+        self.routed = tape = []
+        try:
+            yield tape
+        finally:
+            self.routed = None
+
+    def _count_routed(self, rows, offered) -> None:
+        if self.routed is not None:
+            self.routed.append((rows, offered))
+
+    # -- owner routing (transfer/route.py) ---------------------------------
+    def route_mode(self, state) -> str | None:
+        """How a pull or sparse push of ``state`` reaches the rows: from
+        what the call can observe, no option.
+
+        ``None``: directly — one shard, no mesh given, a mesh with a
+        second axis of more than one device (a ``data`` axis: each group
+        holds a replica, the partitioner reconciles them), a replicated
+        hot head in the state, or a call site already under a manual axis
+        that is not the table's (hogwild's ``worker`` axis: every worker
+        holds the whole table).  ``"inside"``: the caller is already
+        manual over the table's axis (a step split over it,
+        models/word2vec.py): ``state`` is the chip's shard, the slots its
+        share of the batch, and `route` runs as is.  ``"wrap"``: the
+        call is wrapped in a ``shard_map`` over the table's axis, which
+        hands each chip its shard and a 1/n slice of the batch."""
+        if self.mesh is None or self.shards == 1:
+            return None
+        ctx = jax.sharding.get_abstract_mesh()
+        manual = () if ctx.empty else ctx.manual_axes
+        if manual:
+            return "inside" if self.axis in manual else None
+        if any(size > 1 for name, size in self.mesh.shape.items()
+               if name != self.axis):
+            return None
+        if any(is_hot_field(f) or x.shape[0] % self.shards
+               for f, x in state.items()):
+            return None
+        return "wrap"
+
+    def _routed(self, fn, args, sharded_out, n_counts):
+        """``fn(*args)`` on every chip of the table's axis, every leaf of
+        ``args`` split along its first axis; ``fn`` returns ``(sharded
+        results, *counts)``, the counts summed over the chips."""
+        def body(*args):
+            out, *counts = fn(*args)
+            return (out, *(jax.lax.psum(c, self.axis) for c in counts))
+        row = P(self.axis)
+        return jax.shard_map(
+            body, mesh=self.mesh, in_specs=jax.tree.map(lambda _: row, args),
+            out_specs=(sharded_out(row), *[P()] * n_counts),
+            check_vma=False)(*args)
+
+    def _pad_to_shards(self, slots, *rows):
+        """A batch the axis does not divide gets ``-1`` slots, padding by
+        the transfer's contract, and zero rows (leaves may be ``None``)."""
+        pad = (-slots.shape[0]) % self.shards
+        if not pad:
+            return (slots, *rows)
+        return (jnp.pad(slots, (0, pad), constant_values=-1),
+                *jax.tree.map(lambda a: jnp.pad(
+                    a, [(0, pad)] + [(0, 0)] * (a.ndim - 1)), rows))
+
     def _membership_changed(self) -> None:
         """Elastic membership (api.py): XLA keeps no compiled caches
         here (jit re-specializes on its own), but the expected-unique
@@ -236,29 +321,55 @@ class XlaTransfer(Transfer):
         # structural gather only — the ledger/format/cache logic lives
         # in the base-class pull interpreter (api.Transfer.pull)
         slots = jnp.asarray(slots, jnp.int32)
-        valid = slots >= 0
-        return {f: _masked_gather(state[f], slots, valid)
-                for f in fields}
+        mode = self.route_mode(state) if slots.shape[0] else None
+        if mode is None:
+            valid = slots >= 0
+            return {f: _masked_gather(state[f], slots, valid)
+                    for f in fields}
+        fields = tuple(fields)
+
+        def fetch(shard, slots):
+            return route.pull(shard, slots, fields, self.axis, self.shards,
+                              _masked_gather)
+        shard = {f: state[f] for f in fields}
+        if mode == "inside":
+            out, *counts = fetch(shard, slots)
+        else:
+            n_req = slots.shape[0]
+            (slots,) = self._pad_to_shards(slots)
+            out, *counts = self._routed(
+                fetch, (shard, slots), lambda row: dict.fromkeys(fields, row),
+                2)
+            out = {f: x[:n_req] for f, x in out.items()}
+        self._count_routed(*counts)
+        return out
 
     # -- push (global_push_access.h:26-43 + server.h:159-176) --------------
     def push(self, state, slots, grads, access, mean=False):
         slots = jnp.asarray(slots, jnp.int32)
         capacity = next(iter(state.values())).shape[0]
-        dense = self.dense_apply
-        if dense is None:
-            # per-call compute crossover through the tunable decision
-            # hook: dense once the batch reaches capacity/ratio rows.
-            # The seed ratio 2.0 reproduces the measured
-            # ``>= capacity // 2`` rule exactly (int(cap / 2.0) ==
-            # cap // 2), keeping control-off trajectories bit-identical
-            dense = slots.shape[0] >= int(
-                capacity / self.wire_dense_ratio("push_apply"))
+        # a caller already split over the table's axis (`route_mode`)
+        # weighed the whole batch against the whole table before it split
+        dense = (self.pushes_dense(slots.shape[0], capacity)
+                 and self.route_mode(state) != "inside")
         if dense:
             self._record_exchange(
                 capacity, grad_row_bytes(grads, with_index=False))
             return self._push_dense(state, slots, grads, access, mean)
         self._record_exchange(jnp.sum(slots >= 0), grad_row_bytes(grads))
         return self._push_sparse(state, slots, grads, access, mean)
+
+    def pushes_dense(self, n_slots: int, capacity: int) -> bool:
+        """Whether a push of ``n_slots`` into ``capacity`` rows takes the
+        dense full-table form."""
+        if self.dense_apply is not None:
+            return self.dense_apply
+        # per-call compute crossover through the tunable decision
+        # hook: dense once the batch reaches capacity/ratio rows.
+        # The seed ratio 2.0 reproduces the measured
+        # ``>= capacity // 2`` rule exactly (int(cap / 2.0) ==
+        # cap // 2), keeping control-off trajectories bit-identical
+        return n_slots >= int(capacity / self.wire_dense_ratio("push_apply"))
 
     def _push_dense(self, state, slots, grads, access, mean=False):
         capacity = next(iter(state.values())).shape[0]
@@ -371,32 +482,66 @@ class XlaTransfer(Transfer):
     def _push_sparse(self, state, slots, grads, access, mean=False,
                      counts=None):
         """``counts``: a row's multiplicity under ``mean`` (a span row is
-        a sum of that many contributions, `push_span`); ``None``: one."""
-        capacity = next(iter(state.values())).shape[0]
-        B = slots.shape[0]
-        if B == 0:
+        a sum of that many contributions, `push_span`); ``None``: one.
+        One algorithm — sort, sum the duplicates, divide by the summed
+        counts, write the distinct rows back — reached directly when the
+        table's axis has one shard and through `route.push` when it has
+        more (`route_mode`), where it runs on each owner with the shard
+        as a table of its own."""
+        if slots.shape[0] == 0:
             return dict(state)
+        mode = self.route_mode(state)
+        if mode is None:
+            out, n_rows = self._push_rows(state, slots, grads, access, mean,
+                                          counts, self.shards)
+        else:
+            out, n_rows = self._push_routed(mode, state, slots, grads,
+                                            access, mean, counts)
+        self._count_rows_written(lambda: n_rows,
+                                 access.touched_fields(grads))
+        return out
+
+    def _push_routed(self, mode, state, slots, grads, access, mean, counts):
+        """`_push_sparse` through the owners.  A chip sums its own
+        duplicates (`_combine`), which also orders its distinct rows by
+        owner, and every owner pushes what it is sent into its own shard
+        (`_push_rows`, ``shards`` = 1: the write-back weighs the shard's
+        rows and takes ``head_rows`` where one chip would)."""
+        if counts is None and mean:
+            counts = (slots >= 0).astype(jnp.float32)
+
+        def combine(slots, grads, counts, capacity):
+            rows, _, _, weights, _, sums = self._combine(
+                slots, grads, capacity, mean, counts, scale=False)
+            return rows, sums, weights
+
+        def owner_push(shard, rows, grads, counts):
+            return self._push_rows(shard, rows, grads, access, mean, counts,
+                                   shards=1)
+
+        def send(shard, slots, grads, counts):
+            return route.push(shard, slots, grads, counts, self.axis,
+                              self.shards, combine, owner_push)
+        if mode == "inside":
+            out, n_rows, *tally = send(state, slots, grads, counts)
+        else:
+            out, n_rows, *tally = self._routed(
+                send, (dict(state), *self._pad_to_shards(
+                    slots, dict(grads), counts)),
+                lambda row: dict.fromkeys(state, row), 3)
+        self._count_routed(*tally)
+        return out, n_rows
+
+    def _combine(self, slots, grads, capacity, mean, counts, scale):
+        """The sparse push's duplicate reduction: ``slots`` sorted, the
+        ``grads`` of equal slots summed.  Returns ``(rep_slots, rep_valid,
+        safe_rep, seg_counts, inv, combined)``: the distinct slots
+        ascending at the head of ``rep_slots``, ``capacity`` behind them;
+        a segment's summed multiplicity and its reciprocal (``None``
+        unless ``mean``); the summed rows, times ``inv`` where ``scale``
+        asks for the mean here."""
+        B = slots.shape[0]
         valid = slots >= 0
-        # only the fields this push's grad families actually update are
-        # gathered and re-scattered (a partial push must not round-trip
-        # the untouched fields' rows through HBM for nothing)
-        touched = access.touched_fields(grads)
-        written = [state[f] for f in touched]
-        form = self.write_back_form(B, written)
-        self.resolved_write_back.update(dict.fromkeys(touched, form))
-        # the shapes say whether a head can be so long that one sweep is
-        # cheaper; whether it is, the count at run time
-        may_sweep = self._static_form(B, written) == "sweep"
-        if form == "head_rows" and may_sweep:
-            # A push that large sums its batch after the state it is given
-            # exists, i.e. after the push before it, whichever fields that
-            # wrote: a loop and a conditional bind the scheduler less than
-            # the sweep's one fusion did, and left to itself it sorts and
-            # sums one push's batch before another's gradients are
-            # computed, one (B, width) buffer more at the step's peak
-            # (0.25 GB on the chip in cbow2m-b16k, PERF.md section 6).
-            state, grads = jax.lax.optimization_barrier(
-                (dict(state), dict(grads)))
         with obs.named_scope("dedup"):
             # Sort so duplicates are adjacent; padding (-1 -> capacity)
             # sorts last and is dropped by OOB scatter below.
@@ -415,7 +560,7 @@ class XlaTransfer(Transfer):
             rep_valid = rep_slots < capacity
             safe_rep = jnp.where(rep_valid, rep_slots, 0)
 
-            inv = None
+            seg_counts = inv = None
             if mean:
                 # seg_ids ascend (cumsum of non-negatives): tell XLA so
                 # the scatter lowering can skip the general collision
@@ -432,11 +577,46 @@ class XlaTransfer(Transfer):
                 acc = jnp.zeros((B, width), g.dtype)
                 acc = acc.at[seg_ids].add(g, mode="drop",
                                           indices_are_sorted=True)
-                # `head_rows` multiplies where it reads the rows: as an
-                # operand of its loop and conditional the product would
-                # be a (B, width) buffer of its own
-                combined[f] = (acc * inv if mean and form != "head_rows"
-                               else acc)
+                combined[f] = acc * inv if mean and scale else acc
+        return rep_slots, rep_valid, safe_rep, seg_counts, inv, combined
+
+    def _push_rows(self, state, slots, grads, access, mean, counts, shards):
+        """The sparse push on the rows ``state`` holds, split over
+        ``shards`` devices by the partitioner (1: all of them here).
+        Returns the new state and the distinct valid rows written."""
+        capacity = next(iter(state.values())).shape[0]
+        B = slots.shape[0]
+        # only the fields this push's grad families actually update are
+        # gathered and re-scattered (a partial push must not round-trip
+        # the untouched fields' rows through HBM for nothing)
+        touched = access.touched_fields(grads)
+        written = [state[f] for f in touched]
+        # the form is weighed for the rows THIS call holds: an owner's
+        # shard is a table of its own, whatever the backend was built for
+        held, self.shards = self.shards, shards
+        try:
+            form = self.write_back_form(B, written)
+            # the shapes say whether a head can be so long that one sweep
+            # is cheaper; whether it is, the count at run time
+            may_sweep = self._static_form(B, written) == "sweep"
+        finally:
+            self.shards = held
+        self.resolved_write_back.update(dict.fromkeys(touched, form))
+        if form == "head_rows" and may_sweep:
+            # A push that large sums its batch after the state it is given
+            # exists, i.e. after the push before it, whichever fields that
+            # wrote: a loop and a conditional bind the scheduler less than
+            # the sweep's one fusion did, and left to itself it sorts and
+            # sums one push's batch before another's gradients are
+            # computed, one (B, width) buffer more at the step's peak
+            # (0.25 GB on the chip in cbow2m-b16k, PERF.md section 6).
+            state, grads = jax.lax.optimization_barrier(
+                (dict(state), dict(grads)))
+        # `head_rows` multiplies where it reads the rows: as an operand
+        # of its loop and conditional the product would be a (B, width)
+        # buffer of its own
+        rep_slots, rep_valid, safe_rep, _, inv, combined = self._combine(
+            slots, grads, capacity, mean, counts, scale=form != "head_rows")
 
         # Unused segments' representatives stay == capacity: OOB, dropped.
         # rep_slots are ascending AND one-per-segment by construction
@@ -444,7 +624,6 @@ class XlaTransfer(Transfer):
         # any form of the write-back may take them.
         out = dict(state)
         n_rows = jnp.sum(rep_valid, dtype=jnp.int32)
-        self._count_rows_written(lambda: n_rows, touched)
         if form != "per_row":
             fields = {f: state[f] for f in touched}
             with obs.named_scope("apply"):
@@ -455,7 +634,7 @@ class XlaTransfer(Transfer):
                     out.update(_rmw_head_rows(
                         fields, rep_slots, combined, access, n_rows,
                         may_sweep, inv=inv))
-                return bump_row_versions(out, state, rep_slots)
+                return bump_row_versions(out, state, rep_slots), n_rows
         # Per row.  Where a field is column-major in HBM (a tall array
         # whose stored width is no multiple of 128: `access.stored_width`
         # widens the rows that can afford it, and then there is nothing
@@ -482,7 +661,7 @@ class XlaTransfer(Transfer):
                 out[f] = done = _set_rows(field, rep_slots, updated[f],
                                           sweep=False)
         with obs.named_scope("apply"):
-            return bump_row_versions(out, state, rep_slots)
+            return bump_row_versions(out, state, rep_slots), n_rows
 
     def write_back_form(self, n: int, fields) -> str:
         """How to write ``n`` ascending slots, the distinct valid rows at

@@ -161,23 +161,33 @@ def _pair_grid(model, traffic):
     return max(256, minibatch // (2 * model.window)) * 2 * model.window
 
 
-@pytest.mark.parametrize("cell, sweeps, temp_gib, span", [
+def _instructions(text):
+    """(count, digest) of a compiled module's instructions: names,
+    operands and layouts, the metadata's source lines aside."""
+    lines = [re.sub(r", metadata=\{[^}]*\}", "", line.rstrip())
+             for line in text.splitlines()
+             if re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = ", line)]
+    return (len(lines),
+            hashlib.sha256("\n".join(lines).encode()).hexdigest()[:16])
+
+
+@pytest.mark.parametrize("cell, sweeps, temp_gib, span, program", [
     # 5,500 target slots and a span of 768: the shapes rule the sweep
     # out, the head's chunks are all there is
-    ("cbow2m-demo", 0, 0.01, 768),
+    ("cbow2m-demo", 0, 0.01, 768, (2911, "39a2f22493a1de65")),
     # 180,224 target slots: chunks or one sweep, by the count; the
     # context push is the span's 22,528 slots (74.2 % of a Zipf stream's
     # positions pass the center gate): chunks alone, where the per-pair
     # grid's 163,840 slots had a conditional and a sweep of their own
-    ("cbow2m-b16k", 2, 1.0, 22_400),
+    ("cbow2m-b16k", 2, 1.0, 22_400, (3189, "58360141e83e5b37")),
     # uniform keys: nothing is gated, the span is B + 2W in whole tiles
-    ("cbow2m-b16k-uniform", 2, 1.0, 16_512),
+    ("cbow2m-b16k-uniform", 2, 1.0, 16_512, (3191, "43086c84424a6621")),
     # 122,880 target slots: either; 20,480 input slots: chunks alone
-    ("sg2m-b2k", 2, 0.7, None),
+    ("sg2m-b2k", 2, 0.7, None, (2776, "d2d9fb458098049e")),
 ])
 def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
                                   monkeypatch, cell, sweeps, temp_gib,
-                                  span):
+                                  span, program):
     """A word2vec cell's train step at 2,340,001 rows on one v5e chip.
     The 300-wide rows are stored on 384 lanes (`access.stored_width`), so
     with no layout asked for the four fields come in and go out row-major
@@ -215,15 +225,12 @@ def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
             rf"= \w+\[{grid}[\],].*op_name=\"([^\"]*)\"", text))
     else:
         assert re.findall(rf"= f32\[{grid},384\]", text)
-        # ... and its step is PR 35's instruction for instruction (names,
-        # operands and layouts; the metadata's source lines aside): what
-        # ISSUE 36 changed for CBOW left skip-gram's program alone.  A PR
-        # that changes this step on purpose pins its own digest here.
-        lines = [re.sub(r", metadata=\{[^}]*\}", "", line.rstrip())
-                 for line in text.splitlines()
-                 if re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = ", line)]
-        digest = hashlib.sha256("\n".join(lines).encode()).hexdigest()
-        assert (len(lines), digest[:16]) == (2776, "d2d9fb458098049e")
+    # ... and the step is PR 42's instruction for instruction (skip-gram's
+    # is PR 35's still): a table on ONE shard is pulled and pushed
+    # directly, the owner routing of ISSUE 43 is bypassed and left these
+    # programs alone.  A PR that changes a step on purpose pins its own
+    # digest here.
+    assert _instructions(text) == program
     field = rf"f32\[{capacity},384\]"
     # the module's first line: aliasing and the entry's layouts
     params, results = re.search(r"entry_computation_layout=\{(.*)",
@@ -251,28 +258,70 @@ def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
     assert len(re.findall(r" conditional\(.*apply/", text)) == sweeps // 2
 
 
-def test_w2v_x4_step_keeps_the_sweep(topo, no_compile_cache, tmp_path,
-                                     monkeypatch):
-    """``gnews3m-x4-b64k``'s table is row-sharded over four chips: its
-    pushes keep the form the shapes choose, one sweep a field, no loop."""
-    cluster, model, compiled = _w2v_step(topo, tmp_path, monkeypatch,
-                                         "gnews3m-x4-b64k", chips=4)
-    assert cluster.transfer.shards == 4
-    assert cluster.transfer.resolved_write_back == dict.fromkeys(
-        HEAD_ROWS, "sweep")
-    text = compiled.as_text()
-    assert " while(" not in text and " conditional(" not in text
-    scatters = re.findall(r"= f32\[\d+,384\]\S* scatter\(.*apply/", text)
-    assert len(scatters) == 4
-    assert all("indices_are_sorted=true" in s for s in scatters)
-    # a tenth of a Zipf stream's positions fail the center gate at sample
-    # 1e-3: the context side is the span's, and nothing in the step has
-    # the pair grid's 655,360 slots (~142 of the parent's 290.6 ms a step,
-    # a 1.0 GB all-reduce among them)
-    grid = _pair_grid(model, _cell("gnews3m-x4-b64k")[1])
-    assert (model.stencil, grid) == (1, 655_360)
-    assert model.span == 73_344
-    assert not re.findall(rf"\[{grid}[\],]", text)
+@pytest.mark.parametrize("cell, rows, parent, sweeps", [
+    # 65,536 centers, a span of 73,344 positions: a chip renders 18,336
+    # positions (110,016 target slots), an owner is sent up to 4 x 34,384
+    # distinct target rows — a head that long may be cheaper swept, by the
+    # count — and 4 x 5,736 span rows
+    ("gnews3m-x4-b64k", (393_216, 73_344), (8_448_998_912, 2_426_327_040), 2),
+    # 16,384 centers, 18,432 positions: 4,608 a chip (27,648 target
+    # slots), 4 x 8,640 and 4 x 1,448 an owner: the chunks alone
+    ("gnews3m-x4-b16k", (98_304, 18_432), (6_632_095_232, 609_871_872), 0),
+])
+def test_w2v_x4_step_routes_rows_to_their_owners(
+        topo, no_compile_cache, tmp_path, monkeypatch, cell, rows, parent,
+        sweeps):
+    """The table of the two four-chip cells is row-sharded over the
+    ``model`` axis: the step runs split over it (ISSUE 43).  A chip holds
+    nothing of the global batch's size — no row, gradient or index at the
+    global target grid's or span's row count, no all-reduce of rows — what
+    crosses chips is ``all-to-all``, every owner writes the rows at its
+    head into its OWN shard (``head_rows``, chosen from the shard's rows,
+    as one chip would), no capacity-sized buffer beside the four aliased
+    fields, and the step's peak and temporaries (``parent``: bytes a
+    chip) are under the parent's (`92f1f4d`: three all-reduces of
+    ``(global slots, 384)`` f32 and four field sweeps)."""
+    cluster, model, compiled = _w2v_step(topo, tmp_path, monkeypatch, cell,
+                                         chips=4)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert cluster.transfer.shards == 4 and model._step_split()
+    assert cluster.transfer.resolved_write_back == HEAD_ROWS
+    traffic = _cell(cell)[1]
+    grid, span = rows
+    assert model.stencil and model.span == span
+    assert grid == traffic["centers_per_step"] * (model.negative + 1)
+    # nothing at the global batch's row counts, whatever the type
+    assert not re.findall(rf"\[(?:{grid}|{span})[\],]", text)
+    # the only sums over the axis are scalars and a bound an owner
+    reduced = re.findall(r"= (\S+) all-reduce(?:-start)?\(", text)
+    assert reduced and all(re.match(r"\(?[fs]32\[4?\]", r) for r in reduced)
+    assert len(re.findall(r" all-to-all\(", text)) >= 6
+    assert not re.findall(r" (?:all-gather|reduce-scatter)(?:-start)?\(",
+                          text)
+    # the shard's fields: in and out aliased, never copied, and nothing
+    # else of their size (a scatter-add into zeros, an accumulator)
+    capacity = model.table.capacity // 4
+    assert capacity == 975_001
+    field = rf"f32\[{capacity},384\]"
+    made = re.findall(rf"= {field}\S* ([\w\-]+)\(", text)
+    assert set(made) <= {"parameter", "get-tuple-element", "fusion",
+                         "scatter", "while", "conditional",
+                         "custom-call", "bitcast"}
+    assert not re.findall(rf"= {field}\S* (?:copy|broadcast|constant)\(",
+                          text)
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert {(int(o), int(i)) for o, i in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", aliased)} >= {(i, i) for i in range(4)}
+    # every write to a field is a scatter of rows into it, in place: the
+    # head's chunks and, where the shapes allow a longer head, one sweep
+    writes = [line for line in text.splitlines()
+              if re.search(rf"= {field}\S* fusion\(", line)]
+    assert writes and all("scatter" in w for w in writes)
+    scatters = re.findall(rf"= {field}\S* scatter\(.*apply/.*scatter", text)
+    swept = sum("indices_are_sorted=true" in s for s in scatters)
+    assert (len(scatters) - swept, swept) == (4, sweeps)
+    assert (mem.peak_memory_in_bytes, mem.temp_size_in_bytes) < parent
+    assert mem.temp_size_in_bytes < parent[1]
 
 
 def test_table_is_built_within_its_own_size(topo, no_compile_cache,

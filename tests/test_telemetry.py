@@ -748,7 +748,10 @@ def test_rows_written_counts_each_push_s_distinct_rows(sg, monkeypatch,
     """``rows_written_per_step``: every push of a step adds its
     distinct valid slots times the fields it touches (a parameter and its
     AdaGrad accumulator), counted on the device and fetched with the loss;
-    here against the slots each push was handed, taken out by a callback."""
+    here against the slots each push was handed, taken out by a callback.
+    A CBOW step on the 8-device mesh runs split over the table's axis
+    (ISSUE 43): every chip then makes the push with its share of the
+    slots, and the rows are the distinct slots of the shares together."""
     import jax
 
     from swiftmpi_tpu.transfer.xla import XlaTransfer
@@ -760,8 +763,13 @@ def test_rows_written_counts_each_push_s_distinct_rows(sg, monkeypatch,
 
         def spying(self, state, slots, grads, *args, **kwargs):
             fields = len(args[-1].touched_fields(grads))
+            whole, chips = slots, 1
+            if self.route_mode(state) == "inside":
+                # one call a chip: each reports an equal part of the whole
+                whole, chips = jax.lax.all_gather(slots, self.axis), \
+                    self.shards
             jax.debug.callback(lambda s: seen.append(
-                fields * np.unique(s[s >= 0]).size), slots)
+                fields * np.unique(s[s >= 0]).size / chips), whole)
             return real(self, state, slots, grads, *args, **kwargs)
         monkeypatch.setattr(XlaTransfer, name, spying)
 
@@ -771,8 +779,9 @@ def test_rows_written_counts_each_push_s_distinct_rows(sg, monkeypatch,
     jax.effects_barrier()
     steps = len(fed.valid)
     # two pushes a step, sparse or (at this toy capacity, skip-gram's
-    # target push) dense
-    assert steps > 4 and len(seen) == 2 * steps
+    # target push) dense; a split step makes each on all 8 chips
+    chips = 1 if sg else 8
+    assert steps > 4 and len(seen) == 2 * steps * chips
     assert model.train_metrics["rows_written_per_step"] == pytest.approx(
         sum(seen) / steps, rel=1e-6)
     assert min(seen) > 0

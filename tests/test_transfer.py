@@ -484,3 +484,247 @@ def test_tpu_backend_pull_with_pallas_shard_gather(monkeypatch,
     ref = LocalTransfer().pull(state_np, slots, access)
     for f in ref:
         np.testing.assert_allclose(np.asarray(got[f]), ref[f], rtol=1e-6)
+
+
+# -- owner routing of a row-sharded table (ISSUE 43, transfer/route.py) -------
+
+ROUTE_CAP = 64          # rows a shard
+ROUTE_CASES = {
+    # valid slots of every owner, some padding, duplicates
+    "mixed": lambda rng, n: rng.integers(-1, n * ROUTE_CAP, 203),
+    # every slot belongs to ONE owner: its bucket overflows, rounds > 1
+    "one_owner": lambda rng, n: rng.integers(ROUTE_CAP, 2 * ROUTE_CAP, 200),
+    # nothing to route at all
+    "all_padding": lambda rng, n: np.full(40, -1),
+    # one hot row among the rest; a length the axis does not divide
+    "hot_ragged": lambda rng, n: np.r_[np.full(150, 70),
+                                       rng.integers(0, n * ROUTE_CAP, 51)],
+}
+
+
+def _routed_setup(n, case):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:n]).reshape(1, n), ("data", "model"))
+    access = w2v_access(learning_rate=0.3, len_vec=8)
+    rng = np.random.default_rng(n)
+    state = {f: (np.abs if f.endswith("2sum") else np.asarray)(
+        rng.normal(size=(n * ROUTE_CAP, fs.dim))).astype(np.float32)
+        for f, fs in access.fields.items()}
+    row = NamedSharding(mesh, P("model"))
+    routed = XlaTransfer(shards=n, platform="cpu", mesh=mesh, axis="model")
+    slots = ROUTE_CASES[case](rng, n).astype(np.int32)
+    return (access, state, routed, slots, rng,
+            lambda: {f: jax.device_put(v, row) for f, v in state.items()})
+
+
+@pytest.mark.parametrize("case", list(ROUTE_CASES))
+@pytest.mark.parametrize("n", [4, 8])
+def test_routed_pull_equals_the_local_oracle(n, case, devices8):
+    """A pull of a table row-sharded ``n`` ways, routed to the rows'
+    owners, returns the oracle's rows bit for bit — whatever the skew: a
+    bucket that overflows takes further rounds, nothing is dropped."""
+    from swiftmpi_tpu.transfer import route
+
+    access, state, routed, slots, _, placed = _routed_setup(n, case)
+    assert routed.route_mode(placed()) == "wrap"
+    want = LocalTransfer().pull(state, slots, access)
+    def pull(st, s):
+        with routed.count_routed() as tape:
+            return routed.pull(st, s, access), tape
+    got, tape = jax.jit(pull)(placed(), slots)
+    for f in access.pull_fields:
+        np.testing.assert_array_equal(np.asarray(got[f]), want[f])
+    (rows, offered), = tape     # one routed pull: both pull fields at once
+    fields = len(access.pull_fields)
+    assert int(rows) == fields * int((slots >= 0).sum())
+    share = -(-len(slots) // n)             # a chip's padded share
+    # every chip offers every owner one bucket a round
+    bucket = route.bucket_slots(share, n)
+    rounds = int(offered) // (fields * n * n * bucket)
+    valid = int((slots >= 0).sum())
+    assert rounds == 0 if case == "all_padding" else rounds >= 1
+    if case == "one_owner":     # the fullest bucket: a chip's whole share
+        assert rounds == -(-share // bucket) > 1
+    assert int(rows) <= int(offered) or not valid
+
+
+@pytest.mark.parametrize("kind", ["sum", "mean", "span_mean", "span_sum"])
+@pytest.mark.parametrize("case", list(ROUTE_CASES))
+@pytest.mark.parametrize("n", [4, 8])
+def test_routed_push_equals_one_shard(n, case, kind, devices8):
+    """A sparse push routed to the owners — each chip's own duplicates
+    summed first, the owner's sparse push on what it is sent — leaves the
+    table `XlaTransfer` on ONE shard leaves, to f32 re-ordering: sums,
+    ``mean`` by the global count, span rows with their ``counts``; with
+    every slot on one owner (passes > 1), with nothing but padding, with a
+    batch length the axis does not divide."""
+    access, state, routed, slots, rng, placed = _routed_setup(n, case)
+    grads = {"h": rng.normal(size=(len(slots), 8)).astype(np.float32)}
+    counts = rng.integers(1, 5, len(slots)).astype(np.float32)
+    mean = kind.endswith("mean")
+
+    def push(backend, st):
+        if kind.startswith("span"):
+            return backend.push_span(st, slots, grads, counts, access,
+                                     mean=mean)
+        return backend.push(st, slots, grads, access, mean=mean)
+
+    one = XlaTransfer(dense_apply=False, platform="cpu")
+    routed.dense_apply = False
+    with one.count_rows_written() as want_rows:
+        want = push(one, {f: jnp.asarray(v) for f, v in state.items()})
+    def routed_push(st):
+        with routed.count_rows_written() as rows, \
+                routed.count_routed() as tape:
+            return push(routed, st), rows, tape
+    got, got_rows, tape = jax.jit(routed_push)(placed())
+    for f in access.fields:
+        np.testing.assert_allclose(np.asarray(got[f]), np.asarray(want[f]),
+                                   rtol=2e-5, atol=2e-6, err_msg=f)
+    # the distinct rows do not depend on who sorts them
+    assert int(got_rows[0]) == int(want_rows[0])
+    (rows, offered), = tape
+    assert 0 <= int(rows) <= int((slots >= 0).sum())
+    assert (int(offered) == 0) == (case == "all_padding")
+
+
+def test_routed_push_passes_cut_whole_rows(devices8):
+    """More distinct rows for one owner than a bucket holds: the push goes
+    in several passes, cut at row bounds every sender agrees on, so a row
+    pushed by several chips is still divided by its GLOBAL count."""
+    n = 4
+    access, state, routed, _, rng, placed = _routed_setup(n, "mixed")
+    # every chip pushes the same 40 rows of owner 1, twice each
+    slots = np.tile(np.r_[ROUTE_CAP + np.arange(40),
+                          ROUTE_CAP + np.arange(40)], n).astype(np.int32)
+    grads = {"h": rng.normal(size=(len(slots), 8)).astype(np.float32)}
+    routed.dense_apply = False
+    want = XlaTransfer(dense_apply=False, platform="cpu").push(
+        {f: jnp.asarray(v) for f, v in state.items()}, slots, grads, access,
+        mean=True)
+    def push(st):
+        with routed.count_routed() as tape:
+            return routed.push(st, slots, grads, access, mean=True), tape
+    got, tape = jax.jit(push)(placed())
+    for f in access.fields:
+        np.testing.assert_allclose(np.asarray(got[f]), np.asarray(want[f]),
+                                   rtol=2e-5, atol=2e-6, err_msg=f)
+    from swiftmpi_tpu.transfer import route
+    (rows, offered), = tape
+    assert int(rows) == n * 40              # a chip's own sum: 40 rows
+    bucket = route.bucket_slots(len(slots) // n, n)
+    assert bucket < 40 and int(offered) // (n * n * bucket) >= 2
+
+
+def _one_and_four_shard_models(tmp_path):
+    """A CBOW model on 1 device and one on 4, the second's table holding
+    the first's rows key by key."""
+    from swiftmpi_tpu.cluster.cluster import Cluster
+    from swiftmpi_tpu.data.text import build_vocab, synthetic_corpus
+    from swiftmpi_tpu.models.word2vec import Word2Vec
+    from swiftmpi_tpu.utils import ConfigParser
+
+    corpus = synthetic_corpus(60, vocab_size=300, length=30, seed=4)
+    vocab = build_vocab(corpus)
+    models = []
+    for n in (1, 4):
+        cfg = ConfigParser().update({
+            "word2vec": {"len_vec": 16, "window": 3, "negative": 4,
+                         "sg": 0, "sample": -1, "learning_rate": 0.05},
+            "server": {"initial_learning_rate": 0.3},
+            "worker": {"minibatch": 6 * 256}})
+        cluster = Cluster(cfg, devices=jax.devices()[:n]).initialize()
+        model = Word2Vec(config=cfg, cluster=cluster, seed=7)
+        model.build_from_vocab(vocab)
+        models.append(model)
+    one, four = models
+    rows = {f: np.asarray(v)[one.table.key_index.lookup(vocab.keys)]
+            for f, v in one.table.state.items()}
+    at = four.table.key_index.lookup(vocab.keys)
+    state = {}
+    for f, v in four.table.state.items():
+        full = np.array(v)
+        full[at] = rows[f]
+        state[f] = jax.device_put(full, v.sharding)
+    four.table.state = state
+    return corpus, vocab, one, four, rows
+
+
+def test_w2v_step_split_over_four_shards_equals_one_shard(tmp_path,
+                                                          devices8):
+    """A CBOW step on a table sharded four ways runs SPLIT over the
+    table's axis — a chip renders its own positions of the span, draws
+    its centers' rows of the one ``(B, K)`` draw, pulls and pushes through
+    the owners — and leaves the rows the one-shard step leaves on the
+    same table: the same negatives, the same first steps, the same loss."""
+    from swiftmpi_tpu.data.text import CBOWBatcher
+
+    corpus, vocab, one, four, before = _one_and_four_shard_models(tmp_path)
+    after, losses = [], []
+    for model in (one, four):
+        losses.append(model.train(
+            batcher=CBOWBatcher(corpus, vocab, model.window, seed=3),
+            niters=1))
+        slot = model.table.key_index.lookup(vocab.keys)
+        after.append({f: np.asarray(v)[slot]
+                      for f, v in model.table.state.items()})
+    assert one.stencil and four.stencil
+    assert one._step_split() is None
+    mesh, axis, n = four._step_split()
+    assert (axis, n) == (four.cluster.table_axis, 4)
+    np.testing.assert_allclose(losses[0], losses[1], rtol=1e-5)
+    for f in before:
+        assert np.abs(after[0][f] - before[f]).max() > 1e-3    # it trained
+        np.testing.assert_allclose(after[1][f], after[0][f], rtol=2e-4,
+                                   atol=2e-6, err_msg=f)
+
+
+def test_negative_draw_rows_are_the_whole_draw_s(devices8):
+    """`sample_alias_slots(take=)`: the rows a chip resolves are the rows
+    of the one ``(B, K)`` draw, letter for letter."""
+    from swiftmpi_tpu.ops.sampling import (build_unigram_alias,
+                                           sample_alias_slots)
+
+    prob, alias = build_unigram_alias(np.arange(1, 501, dtype=np.float64))
+    slot_of = jnp.asarray(np.random.default_rng(0).permutation(500),
+                          jnp.int32)
+    args = (jax.random.key(5), jnp.asarray(prob), jnp.asarray(alias),
+            slot_of, (64, 5))
+    negs, slots = sample_alias_slots(*args)
+    take = jnp.asarray([63, 0, 17, 17, 40])
+    negs_t, slots_t = sample_alias_slots(*args, take=take)
+    np.testing.assert_array_equal(np.asarray(negs)[np.asarray(take)], negs_t)
+    np.testing.assert_array_equal(np.asarray(slots)[np.asarray(take)],
+                                  slots_t)
+
+
+def test_hogwild_on_a_mesh_keeps_the_direct_path(devices8):
+    """Call sites under a manual axis that is not the table's keep the
+    direct gather and scatter: hogwild's workers each hold the whole table
+    (`XlaTransfer.route_mode`), and the step still builds and trains."""
+    from jax.sharding import PartitionSpec as P
+
+    from swiftmpi_tpu.data.text import synthetic_corpus
+    from swiftmpi_tpu.models.word2vec import Word2Vec
+    from swiftmpi_tpu.utils import ConfigParser
+
+    cfg = ConfigParser().update({
+        "word2vec": {"len_vec": 8, "window": 2, "negative": 2, "sample": -1,
+                     "async_mode": "hogwild", "local_steps": 2},
+        "worker": {"minibatch": 128}})
+    model = Word2Vec(config=cfg)
+    corpus = synthetic_corpus(120, vocab_size=60, length=12, seed=1)
+    model.build(corpus)
+    transfer = model.transfer
+    assert transfer.shards == 8 and transfer.mesh is not None
+    assert transfer.route_mode(model.table.state) == "wrap"
+    workers = jax.sharding.Mesh(model.cluster.mesh.devices.reshape(-1),
+                                ("worker",))
+    seen = []
+    jax.jit(jax.shard_map(
+        lambda x: (seen.append(transfer.route_mode(model.table.state)), x)[1],
+        mesh=workers, in_specs=P("worker"), out_specs=P("worker"),
+        check_vma=False))(jnp.zeros(8))
+    assert seen == [None]
+    assert np.isfinite(model.train(corpus, niters=1, batch_size=32)).all()
