@@ -77,62 +77,108 @@ def _dev(x):
     return x if isinstance(x, jax.Array) else jnp.asarray(x)
 
 
-class _LossAccum:
-    """Non-blocking loss accumulator: queues per-dispatch device scalars
-    and folds every 256 into ONE on-device scalar (a single stacked-sum
-    dispatch, no host sync), so an epoch holds O(1) buffers and the
-    epoch-end fetch is one round trip — not two per batch.  Folds in
-    float32: exact up to 2^24 per fold, and beyond that the loss
-    denominator's relative error is <1e-7, immaterial.
+class _Tally:
+    """The sums a train call reads once, carried by the step program.
 
-    ``bound`` feeds a utils.pipeline.DispatchWindow (default "auto":
-    bound the async pipeline only on the emulated cpu mesh, where
-    unbounded in-flight sharded programs CHECK-abort at collective
-    rendezvous — see that module's docstring for the failure mode).
+    One ``uint32[2, 6]`` device array.  Every step program takes it
+    (donated, like the table state) and returns it with its own sums
+    added, so a step is one launch and a call's end one wait and one
+    read: no per-step convert, no stack-and-sum at the fetch.  Columns:
 
-    ``fold`` is the retention bound: the queue never holds more than
-    ``fold`` device scalars (an epoch of 10k tiny batches retains at
-    most ``fold``, not 10k — ``peak_queued`` makes that checkable).
-    The drain itself is non-blocking: the stacked-sum is just another
-    async dispatch."""
+    - ``err``, ``pairs_weighted``: float sums, each the bits of an f32
+      Kahan pair (row 0 the running sum, row 1 its compensation) — the
+      error sum (word2vec.h:593), and the pair count of the renderings
+      that weigh a pooled negative as a fraction of a pair
+      (``shared_negatives``).  The compensation keeps an epoch-long
+      call's sum within a few ulps of the exact one.
+    - ``pairs``, ``rows``, ``routed``, ``offered``: counts, each a
+      uint32 (low, high) limb pair: row 0 wraps at 2^32 and carries into
+      row 1, so a count is EXACT to 2^64 — a bare int32 would wrap at
+      2.1e9 pairs, the corpus sizes a call an epoch long is for, and an
+      f32 is exact only to 2^24.  ``rows`` / ``routed`` / ``offered``
+      are what a step built with telemetry on counts
+      (`_build_step`'s ``counted``); they stay 0 otherwise.
+    """
 
-    _FOLD = 256
+    FLOATS = ("err", "pairs_weighted")
+    COUNTS = ("pairs", "rows", "routed", "offered")
 
-    def __init__(self, bound="auto", fold: int = _FOLD):
-        if fold < 2:
-            raise ValueError(f"_LossAccum fold must be >= 2, got {fold}")
-        self._q = []
-        self._fold = fold
-        self.count = 0            # scalars added
-        self.peak_queued = 0
-        self._window = DispatchWindow(bound)
+    @classmethod
+    def zeros(cls) -> np.ndarray:
+        return np.zeros((2, len(cls.FLOATS) + len(cls.COUNTS)), np.uint32)
 
-    def add(self, x) -> None:
-        x = jnp.asarray(x, jnp.float32)
-        self.count += 1
-        self._q.append(x)
-        self._window.push(x)
-        self.peak_queued = max(self.peak_queued, len(self._q))
-        if len(self._q) >= self._fold:
-            self._q = [jnp.stack(self._q).sum()]
+    @classmethod
+    def add(cls, tally, es, ec, *counts):
+        """``tally`` plus one dispatch's sums, inside the step program:
+        ``es`` the error sum, ``ec`` the pair count (an integer, or a
+        float where negatives are weighed), ``counts`` the telemetry
+        counters in ``COUNTS[1:]``'s order.
 
-    def wait(self) -> None:
-        """Block until the newest scalar is there: its completion implies
-        every queued step ran (the epoch's ``loss_wait``)."""
-        if self._q:
-            jax.block_until_ready(self._q[-1])
+        ``tally=None`` starts a fresh one in the program.  No loop of
+        this module passes it: it is there so a caller that lowers or
+        drives a step by hand with the arguments it had before the step
+        carried a tally — ``benchmark/tools/compile_real_size.py``, which
+        this module's PRs may not edit, and a few tests — still gets the
+        step's program."""
+        f32, u32 = jnp.float32, jnp.uint32
+        nf, n = len(cls.FLOATS), len(cls.FLOATS) + len(cls.COUNTS)
+        if tally is None:
+            tally = jnp.zeros((2, n), u32)
 
-    def total(self) -> float:
-        if not self._q:
-            return 0.0
-        # drain the dispatch pipeline before issuing the stack program
-        self.wait()
-        self._window.clear()
-        out = float(jnp.stack(self._q).sum())
-        # read: let go of the scalars here, inside the epoch's fetch, and
-        # not when the caller's frame is torn down outside every span
-        self._q = []
+        def bits(x, to=u32):
+            return jax.lax.bitcast_convert_type(x, to)
+
+        weighted = jnp.issubdtype(jnp.result_type(ec), jnp.floating)
+        x = [bits(jnp.asarray(es, f32)),
+             bits(jnp.asarray(ec if weighted else 0, f32)),
+             *(jnp.asarray(k).astype(u32)
+               for k in (0 if weighted else ec, *counts))]
+        x = jnp.stack(x + [u32(0)] * (n - len(x)))
+        lo, hi = tally
+        # every column both ways, in one pass over the six lanes, and
+        # each keeps its own: Kahan's sum and compensation ...
+        xf, s, c = bits(x, f32), bits(lo, f32), bits(hi, f32)
+        y = xf - c
+        t = s + y
+        # ... or the low limb's add and its carry
+        low = lo + x
+        is_float = jnp.arange(n) < nf
+        return jnp.stack([
+            jnp.where(is_float, bits(t), low),
+            jnp.where(is_float, bits((t - s) - y),
+                      hi + (low < lo).astype(u32))])
+
+    @classmethod
+    def read(cls, tally) -> dict:
+        """The call's one device-to-host read: ``{column: sum}``, floats
+        as Python floats, counts as Python ints — and ``pair_count``,
+        the loss's denominator: the pairs counted plus the weighted ones,
+        rounded."""
+        t = np.asarray(tally)
+        nf = len(cls.FLOATS)
+        s, c = t[:, :nf].view(np.float32).astype(np.float64)
+        out = dict(zip(cls.FLOATS, (s - c).tolist()))
+        lo, hi = t[:, nf:].tolist()
+        out.update(zip(cls.COUNTS, ((h << 32) | l for l, h in zip(lo, hi))))
+        out["pair_count"] = out["pairs"] + int(round(out["pairs_weighted"]))
         return out
+
+
+def _carried(key, tally, run):
+    """The bookkeeping a step program owns.  ``key`` is the MODEL's key:
+    the program splits it as ``train()`` used to on the host — the draws
+    are that sequence bit for bit — runs ``run(sub) -> (out, es, ec,
+    *counts)`` and returns ``(out, next key, tally, es)``: the next key as
+    its data (the caller wraps it, `Word2Vec._rekey`: a typed key's
+    stored sharding reads ``P()`` and ``P(None)`` by turns across a jit
+    boundary on a mesh, and every turn would be one more entry in the
+    step's dispatch cache), the tally with this dispatch's sums added
+    (`_Tally.add`), and ``es`` once more on its own, the one result that
+    is not donated into the next step: what a `DispatchWindow` waits on."""
+    key, sub = jax.random.split(key)
+    out, es, ec, *counts = run(sub)
+    return out, jax.random.key_data(key), _Tally.add(tally, es, ec,
+                                                     *counts), es
 
 
 class _PairCount:
@@ -499,7 +545,12 @@ class Word2Vec:
         #: train steps dispatched by this model, over all train() calls:
         #: the `dispatch` span's step= (a fused group carries steps=L)
         self._steps_dispatched = 0
+        #: the sampler's key: a step program takes it and returns the
+        #: next (`_carried`), so between steps it is a device value that
+        #: `train()` repoints like `table.state` — the array a caller read
+        #: before a step is donated into that step
         self._key = jax.random.key(seed ^ 0x5EED)
+        self._key_impl = jax.random.key_impl(self._key)
         # per-train() observability: hogwild tail-skip count, hybrid
         # transfer traffic counters — refreshed by every train() call
         self.train_metrics: dict = {}
@@ -697,9 +748,52 @@ class Word2Vec:
                     for slots in (S, centers * (self.negative + 1))))
 
     # -- the fused step ----------------------------------------------------
+    @property
+    def _rep(self):
+        """The sharding of a step program's carried values: replicated
+        over the mesh."""
+        return jax.sharding.NamedSharding(self.cluster.mesh, P())
+
+    def _carry(self, x: np.ndarray):
+        """A small host value as the steps carry it: committed and
+        replicated over the mesh (every process holds the same); data,
+        no program."""
+        return jax.make_array_from_process_local_data(self._rep, x)
+
+    def _carried_jit(self, donate=("state", "key", "tally"), **more):
+        """``jax.jit``'s arguments for a step program (`_carried`): the
+        state, the key and the tally donated; the key's data and the
+        tally returned replicated over the mesh, as `_carry` places
+        them, whatever the compiler would pick — a
+        carried value goes back in as it came out, and the step is
+        compiled and cached once."""
+        rep = self._rep
+        return dict(donate_argnames=donate,
+                    out_shardings=(None, rep, rep, None), **more)
+
+    def _rekey(self, key_data) -> None:
+        """Repoint ``_key`` at the key data a step returned (host only:
+        no program runs)."""
+        self._key = jax.random.wrap_key_data(key_data, impl=self._key_impl)
+
+    def _settle_key(self) -> None:
+        """``_key`` placed as the steps return it (`_carried_jit`): a new
+        model's key, or one a caller assigned, sits uncommitted on one
+        device, and the step would be entered twice in its dispatch
+        cache, once for that key and once for its own.  One read and one
+        put; nothing to do from the second ``train()`` on."""
+        data = jax.random.key_data(self._key)
+        if not (data.committed and data.sharding == self._rep):
+            self._rekey(self._carry(np.asarray(data)))
+
     def _build_step(self):
         """Sync step: grads against current state + immediate push.  The
-        table state is donated — the update is in-place in HBM."""
+        table state is donated — the update is in-place in HBM.
+
+        The program owns its bookkeeping (`_carried`): it takes the
+        model's key and the call's tally (both donated; ``tally=None``
+        starts one) and returns ``(state, next key's data, tally, es)``,
+        so ``train()`` launches nothing else for a step."""
         split = self._step_split()
         grads_fn = self._build_grads()
         split_grads_fn = self._build_grads(split) if split else None
@@ -769,23 +863,39 @@ class Word2Vec:
         if self.stencil:
             # the batch is one packed buffer (StencilBatch.pack), cut
             # into its four fields inside the program: one put a step
-            @partial(jax.jit, donate_argnums=0, static_argnames="centers")
+            @partial(jax.jit, **self._carried_jit(static_argnames="centers"))
             def step(state, slot_of_vocab, alias_prob, alias_idx,
-                     span, key, *, centers):
+                     span, key, tally=None, *, centers):
                 fn = run_split if split and self._splits(
                     span, centers, split[2]) else run
-                return fn(state, (slot_of_vocab, alias_prob, alias_idx),
-                          (span,), key, centers=centers)
+                return _carried(key, tally, lambda sub: fn(
+                    state, (slot_of_vocab, alias_prob, alias_idx),
+                    (span,), sub, centers=centers))
 
             return obs.costs.track("w2v_step", step)
 
-        @partial(jax.jit, donate_argnums=0)
+        @partial(jax.jit, **self._carried_jit())
         def step(state, slot_of_vocab, alias_prob, alias_idx,
-                 centers, contexts, ctx_mask, key):
-            return run(state, (slot_of_vocab, alias_prob, alias_idx),
-                       (centers, contexts, ctx_mask), key)
+                 centers, contexts, ctx_mask, key, tally=None):
+            return _carried(key, tally, lambda sub: run(
+                state, (slot_of_vocab, alias_prob, alias_idx),
+                (centers, contexts, ctx_mask), sub))
 
         return obs.costs.track("w2v_step", step)
+
+    def _make_step(self, hogwild: bool = False):
+        """The step program(s) ``train()`` drives in this model's mode,
+        all in the step programs' form (`_carried`): the hogwild group
+        step, the sync step (``local_steps <= 1``), or the async
+        ``(grads, apply)`` pair.  The one place a mode picks its
+        programs — ``train()`` and the control plane's safe-point
+        recompile (`_rebuild_step`) both come here."""
+        if hogwild:
+            return self._build_hogwild_step(max(self.local_steps, 1))
+        if self.local_steps <= 1:
+            return self._build_step()
+        return (obs.costs.track("w2v_grads", self._build_async_grads()),
+                obs.costs.track("w2v_apply", jax.jit(self._build_apply())))
 
     def _fused_for(self, n_inner: int):
         """Compiled fused scan of ``n_inner`` steps, cached per length.
@@ -852,19 +962,21 @@ class Word2Vec:
             return state, es.sum(), ec.sum()
 
         if self.stencil:
-            @partial(jax.jit, donate_argnums=0, static_argnames="centers")
+            @partial(jax.jit, **self._carried_jit(static_argnames="centers"))
             def multi_st(state, slot_of_vocab, alias_prob, alias_idx,
-                         spans_s, key, *, centers):
-                return run(state, (slot_of_vocab, alias_prob, alias_idx),
-                           (spans_s,), key, centers=centers)
+                         spans_s, key, tally=None, *, centers):
+                return _carried(key, tally, lambda sub: run(
+                    state, (slot_of_vocab, alias_prob, alias_idx),
+                    (spans_s,), sub, centers=centers))
 
             return multi_st
 
-        @partial(jax.jit, donate_argnums=0)
+        @partial(jax.jit, **self._carried_jit())
         def multi(state, slot_of_vocab, alias_prob, alias_idx,
-                  centers_s, contexts_s, masks_s, key):
-            return run(state, (slot_of_vocab, alias_prob, alias_idx),
-                       (centers_s, contexts_s, masks_s), key)
+                  centers_s, contexts_s, masks_s, key, tally=None):
+            return _carried(key, tally, lambda sub: run(
+                state, (slot_of_vocab, alias_prob, alias_idx),
+                (centers_s, contexts_s, masks_s), sub))
 
         return multi
 
@@ -928,23 +1040,23 @@ class Word2Vec:
             return state, es_tot, ec_tot
 
         if self.stencil:
-            @partial(jax.jit, donate_argnums=0, static_argnames="centers")
+            @partial(jax.jit, **self._carried_jit(static_argnames="centers"))
             def multi_st(state, slot_of_vocab, alias_prob, alias_idx,
-                         spans_s, key, *, centers):
-                keys = jax.random.split(key, n_inner)
-                return run_windows(state,
-                                   (slot_of_vocab, alias_prob, alias_idx),
-                                   keys, (spans_s,), centers=centers)
+                         spans_s, key, tally=None, *, centers):
+                return _carried(key, tally, lambda sub: run_windows(
+                    state, (slot_of_vocab, alias_prob, alias_idx),
+                    jax.random.split(sub, n_inner), (spans_s,),
+                    centers=centers))
 
             return multi_st
 
-        @partial(jax.jit, donate_argnums=0)
+        @partial(jax.jit, **self._carried_jit())
         def multi(state, slot_of_vocab, alias_prob, alias_idx,
-                  centers_s, contexts_s, masks_s, key):
-            keys = jax.random.split(key, n_inner)
-            return run_windows(state,
-                               (slot_of_vocab, alias_prob, alias_idx),
-                               keys, (centers_s, contexts_s, masks_s))
+                  centers_s, contexts_s, masks_s, key, tally=None):
+            return _carried(key, tally, lambda sub: run_windows(
+                state, (slot_of_vocab, alias_prob, alias_idx),
+                jax.random.split(sub, n_inner),
+                (centers_s, contexts_s, masks_s)))
 
         return multi
 
@@ -1099,11 +1211,12 @@ class Word2Vec:
             return new_state, jax.lax.psum(es.sum(), "worker"), \
                 jax.lax.psum(ec.sum(), "worker")
 
-        @partial(jax.jit, donate_argnums=0)
+        @partial(jax.jit, **self._carried_jit())
         def step(state, slot_of_vocab, alias_prob, alias_idx,
-                 centers_s, contexts_s, masks_s, key):
-            return _workers(state, slot_of_vocab, alias_prob, alias_idx,
-                            centers_s, contexts_s, masks_s, key)
+                 centers_s, contexts_s, masks_s, key, tally=None):
+            return _carried(key, tally, lambda sub: _workers(
+                state, slot_of_vocab, alias_prob, alias_idx,
+                centers_s, contexts_s, masks_s, sub))
 
         return obs.costs.track("w2v_hogwild", step,
                                steps_per_call=n_inner), n_workers
@@ -1845,6 +1958,22 @@ class Word2Vec:
 
         return grads_fn
 
+    def _build_async_grads(self):
+        """The gradient program of the async ``(grads, apply)`` pair, in
+        the step programs' form (`_carried`): pushes computed against a
+        state that is NOT donated (the stale snapshot), the key and the
+        tally taken and returned."""
+        grads_fn = self._build_grads()
+
+        @partial(jax.jit, **self._carried_jit(
+            donate=("key", "tally"),
+            static_argnames="centers" if self.stencil else None))
+        def grads(state, *args, key, tally=None, **shape):
+            return _carried(key, tally, lambda sub: grads_fn(
+                state, *args, sub, **shape))
+
+        return grads
+
     def _build_apply(self):
         access = self.access
         transfer = self.transfer
@@ -2042,8 +2171,8 @@ class Word2Vec:
         meter = Throughput()
         step_i = 0
         hogwild_dropped = 0
-        rows_written, rows_steps = 0.0, 0   # the sync step's row writes
-        routed_rows, routed_slots, routed_steps = 0.0, 0.0, 0   # ... routed
+        rows_written = rows_steps = 0     # the sync step's row writes
+        routed_rows = routed_slots = routed_steps = 0       # ... routed
         # telemetry plane ([worker] telemetry, obs/): reuse an outer
         # recorder (bench harness, trainer) or own one for this call.
         # The Throughput meter and transfer ledger keep their own
@@ -2084,23 +2213,13 @@ class Word2Vec:
         # second knob.
         if _tracer is not None and hasattr(self.transfer, "count_traffic"):
             self.transfer.count_traffic = True
+        self._settle_key()
         # step compile AFTER numerics arming: the builders close over
         # self._numerics at trace time, and a first-time arm drops any
         # step compiled without the bundle
         if self._step is None:
             self._fused_cache = {}
-            if hogwild:
-                self._step = self._build_hogwild_step(
-                    max(self.local_steps, 1))
-            elif sync:
-                self._step = self._build_step()
-            else:
-                self._step = (
-                    obs.costs.track("w2v_grads", jax.jit(
-                        self._build_grads(),
-                        static_argnames="centers" if stencil else None)),
-                    obs.costs.track("w2v_apply",
-                                    jax.jit(self._build_apply())))
+            self._step = self._make_step(hogwild)
         # -- input pipeline setup (tentpole: prefetch-rendered,
         # pre-transferred batches).  The producer is gated to paths
         # where it can own rendering wholesale: hogwild does its own
@@ -2171,32 +2290,32 @@ class Word2Vec:
                 self._serve_on_steps(1)
                 close_epoch(it, err_sum, err_cnt)
             else:
-                # Per-batch loss scalars are QUEUED as device arrays
-                # and fetched once at epoch end: a float(es) per batch
-                # is a blocking round trip that serializes dispatch.
-                # Summed host-side in Python ints at the end —
-                # an on-device int32 accumulator would wrap at ~2.1e9
-                # target pairs, i.e. exactly the corpus sizes this
-                # optimization targets.
-                es_q, ec_q = _LossAccum(dispatch_bound), _LossAccum(None)
-                rows_q = _LossAccum(None)
-                routed_q, offered_q = _LossAccum(None), _LossAccum(None)
+                # The epoch's sums ride in the step program (`_Tally`):
+                # every step takes the tally and returns it, and the
+                # epoch ends in one wait and one read — a float(es) per
+                # batch is a blocking round trip that serializes
+                # dispatch, and a queue of per-step scalars is a launch
+                # a scalar to convert and a stack-and-sum to fetch.
+                tally = self._carry(_Tally.zeros())
+                # the async pair is two programs a step
+                window = DispatchWindow(dispatch_bound,
+                                        programs=1 if sync else 2)
+                steps_counted = 0      # sync single steps: they count rows
 
                 # an item of the loop is input_wait, then four siblings:
                 # step_prep, h2d (to_device), dispatch, step_book
 
                 def run_single(fields, n_words):
-                    nonlocal state, frozen, step_i
+                    nonlocal state, frozen, step_i, tally, steps_counted
                     n = self._steps_dispatched
                     with obs.span("step_prep", step=n):
-                        self._key, sub = jax.random.split(self._key)
-                    args = (self._slot_of_vocab, self._alias_prob,
-                            self._alias_idx, *to_device(fields), sub)
-                    rows = ()
+                        statics = (self._slot_of_vocab, self._alias_prob,
+                                   self._alias_idx)
+                    args = (*statics, *to_device(fields))
                     with obs.span("dispatch", steps=1, step=n):
                         if sync:
-                            state, es, ec, *rows = self._step(
-                                state, *args, **shape)
+                            state, key, tally, es = self._step(
+                                state, *args, self._key, tally, **shape)
                         else:
                             # async/global variant, bounded-staleness
                             # flavor (word2vec_global.h:577-651): grads
@@ -2204,25 +2323,24 @@ class Word2Vec:
                             # land immediately; snapshot refreshes every
                             # local_steps batches => bounded staleness.
                             grads_fn, apply_fn = self._step
-                            pushes, es, ec = grads_fn(frozen, *args, **shape)
+                            pushes, key, tally, es = grads_fn(
+                                frozen, *args, key=self._key, tally=tally,
+                                **shape)
                             state = apply_fn(state, pushes)
                     with obs.span("step_book", step=n):
                         # the step donates (deletes) the input state
-                        # buffers; repoint the table at the live ones
-                        # immediately so an abnormal exit (raise, Ctrl-C)
-                        # never strands the model with deleted arrays
+                        # buffers, and the key's; repoint the table and
+                        # the key at the live ones immediately so an
+                        # abnormal exit (raise, Ctrl-C) never strands the
+                        # model with deleted arrays
                         self.table.state = state
-                        if rows:
-                            rows_q.add(rows[0])
-                        if len(rows) == 3:
-                            routed_q.add(rows[1])
-                            offered_q.add(rows[2])
+                        self._rekey(key)
+                        window.push(es)
                         if not sync:
                             step_i += 1
                             if step_i % self.local_steps == 0:
                                 frozen = state
-                        es_q.add(es)
-                        ec_q.add(ec)
+                        steps_counted += sync
                         self._steps_dispatched += 1
                         meter.record(n_words)
                         obs.record_step(1)
@@ -2237,7 +2355,7 @@ class Word2Vec:
                             frozen = state
                         # the step's inputs are let go inside the span,
                         # not with this frame after it
-                        del args, sub
+                        del args, key, es
 
                 def run_group(fields, n_words):
                     # update ORDER is preserved either way: a group runs
@@ -2248,7 +2366,7 @@ class Word2Vec:
                     # one-by-one pays the per-dispatch overhead each
                     # (not measured on this stack).  A lone batch uses the already-
                     # compiled single step.
-                    nonlocal state
+                    nonlocal state, tally
                     L = len(n_words)
                     if L == 1:
                         # lone batch: peel the stacked fields back
@@ -2259,16 +2377,16 @@ class Word2Vec:
                     n = self._steps_dispatched
                     with obs.span("step_prep", step=n):
                         fused = self._fused_for(L)
-                        self._key, sub = jax.random.split(self._key)
                     fields = to_device(fields)
                     with obs.span("dispatch", steps=L, step=n):
-                        state, es, ec = fused(
+                        state, key, tally, es = fused(
                             state, self._slot_of_vocab, self._alias_prob,
-                            self._alias_idx, *fields, sub, **shape)
+                            self._alias_idx, *fields, self._key, tally,
+                            **shape)
                     with obs.span("step_book", step=n):
                         self.table.state = state
-                        es_q.add(es)
-                        ec_q.add(ec)
+                        self._rekey(key)
+                        window.push(es)
                         self._steps_dispatched += L
                         # a fused group is ONE dispatch but L train steps;
                         # stall_ms_per_step stays per-step across fuse modes
@@ -2321,20 +2439,23 @@ class Word2Vec:
                                 pipe_stats[k] = max(pipe_stats[k], v)
                             elif k != "depth":
                                 pipe_stats[k] += v
-                # the epoch's one blocking fetch: loss_wait for every
-                # queued step, then the reads of the scalars and the
+                # the epoch's one blocking fetch: loss_wait for the
+                # newest tally (every step ran), its one read, and the
                 # epoch's line in the log
                 with obs.span("loss_fetch"):
                     with obs.span("loss_wait"):
-                        es_q.wait()
-                    err_sum = es_q.total()
-                    err_cnt = int(round(ec_q.total()))
-                    rows_written += rows_q.total()
-                    rows_steps += rows_q.count
-                    routed_rows += routed_q.total()
-                    routed_slots += offered_q.total()
-                    routed_steps += routed_q.count
-                    close_epoch(it, err_sum, err_cnt)
+                        jax.block_until_ready(tally)
+                    window.clear()
+                    sums = _Tally.read(tally)
+                    del tally
+                    if sums["rows"]:       # a step built to count them
+                        rows_written += sums["rows"]
+                        rows_steps += steps_counted
+                    if sums["offered"]:    # a routed step offers slots
+                        routed_rows += sums["routed"]
+                        routed_slots += sums["offered"]
+                        routed_steps += steps_counted
+                    close_epoch(it, sums["err"], sums["pair_count"])
             if checkpoint_path and (it + 1) % checkpoint_every == 0:
                 self.table.state = state
                 from swiftmpi_tpu.io.checkpoint import (npz_path,
@@ -2347,7 +2468,7 @@ class Word2Vec:
                     # baselines ride along so a resumed run scores its
                     # first windows against the learned regime instead
                     # of re-warming (and false-alarming) from scratch
-                    self._numerics.sync()
+                    self._numerics.sync(obs.get_registry())
                     ck_extra["numerics"] = \
                         self._numerics.detector.state_bytes()
                 save_checkpoint(
@@ -2390,7 +2511,7 @@ class Word2Vec:
                 self.train_metrics["routed_rows_per_step"] = \
                     routed_rows / routed_steps
                 self.train_metrics["route_fill_share"] = \
-                    100.0 * routed_rows / max(routed_slots, 1.0)
+                    100.0 * routed_rows / max(routed_slots, 1)
             if pairs is not None and pairs.steps:
                 self.train_metrics["pairs_per_step"] = \
                     pairs.valid / pairs.steps
@@ -2416,7 +2537,7 @@ class Word2Vec:
                 # numerics-off model training next in this process must
                 # trace (and book) nothing
                 from swiftmpi_tpu.transfer import api as transfer_api
-                self._numerics.sync()
+                self._numerics.sync(obs.get_registry())
                 transfer_api.clear_numerics_tap()
                 det = self._numerics.detector
                 self.train_metrics["numerics"] = {
@@ -2459,7 +2580,8 @@ class Word2Vec:
         step, n_workers = self._step
         group = n_workers * max(self.local_steps, 1)
         state = self.table.state
-        es_q, ec_q = _LossAccum(), _LossAccum(None)
+        tally = self._carry(_Tally.zeros())
+        window = DispatchWindow()
         buf = []
         dropped = 0
         for batch in batcher.epoch(batch_size):
@@ -2469,21 +2591,20 @@ class Word2Vec:
             buf.append(batch)
             if len(buf) < group:
                 continue
-            self._key, sub = jax.random.split(self._key)
             c, x, m = _stack_group(buf)
-            state, es, ec = step(state, self._slot_of_vocab,
-                                 self._alias_prob, self._alias_idx,
-                                 c, x, m, sub)
+            state, key, tally, es = step(
+                state, self._slot_of_vocab, self._alias_prob,
+                self._alias_idx, c, x, m, self._key, tally)
             self.table.state = state
-            es_q.add(es)
-            ec_q.add(ec)
+            self._rekey(key)
+            window.push(es)
             meter.record(sum(b.n_words for b in buf), steps=len(buf))
             obs.record_step(len(buf))
             buf = []
         if buf:
             dropped += sum(b.n_words for b in buf)
-        err_sum = es_q.total()
-        err_cnt = int(round(ec_q.total()))
+        sums = _Tally.read(tally)
+        err_sum, err_cnt = sums["err"], sums["pair_count"]
         if err_cnt == 0:
             raise RuntimeError(
                 f"hogwild epoch dispatched NO group: the corpus yielded "
@@ -2708,18 +2829,12 @@ class Word2Vec:
         time) — the ``grow()`` fixup contract, owned here for the
         control-plane appliers."""
         self._fused_cache = {}
-        if self.async_mode == "hogwild":
-            # control hooks never fire on the hogwild path; a stale
-            # step cannot be reached, but drop it anyway for symmetry
-            self._step = None
-        elif self.local_steps <= 1:
-            self._step = self._build_step()
-        else:
-            self._step = (
-                obs.costs.track("w2v_grads",
-                                jax.jit(self._build_grads())),
-                obs.costs.track("w2v_apply",
-                                jax.jit(self._build_apply())))
+        # control hooks never fire on the hogwild path; a stale step
+        # cannot be reached, but drop it anyway for symmetry (the next
+        # train() builds it).  A multi-process hogwild run trains in the
+        # snapshot mode (train()), and gets that mode's programs
+        hogwild = self.async_mode == "hogwild" and jax.process_count() == 1
+        self._step = None if hogwild else self._make_step()
         self._control_recompiles += 1
         self._control_dirty = True
 

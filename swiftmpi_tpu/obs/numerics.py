@@ -241,15 +241,22 @@ class NumericsCollector:
     ``jax.debug.callback`` whenever the runtime retires the dispatch —
     asynchronously, so the dispatch path never blocks on telemetry.
     ``sampler`` runs on the StepRecorder's record path and publishes
-    the latest bundle (plus cumulative nonfinite / quant-error totals)
-    as ``numerics/*`` series, then lets the detector score them.
+    the bundles that arrived since it last ran (plus cumulative
+    nonfinite / quant-error totals) as ``numerics/*`` series, letting
+    the detector score each ONCE, in order of arrival: a loop that runs
+    ahead of the device (the train loops launch one program a step and
+    wait for none) finds several bundles at one record and none at the
+    next, and a bundle scored again with every record it outlives would
+    walk the baseline's deviation to zero, so that the next fresh value
+    reads as an anomaly.  ``sync(reg)`` scores the stragglers at a safe
+    point.
     """
 
     def __init__(self, detector: Optional["AnomalyDetector"] = None):
         self.detector = detector
         self._lock = threading.Lock()
-        self._latest: Dict[str, float] = {}      # guarded-by: _lock
-        self._ef_mass: Dict[str, float] = {}     # guarded-by: _lock
+        #: (bundle, ef_mass) of each dispatch not yet published
+        self._pending: List[tuple] = []          # guarded-by: _lock
         self._nonfinite = 0.0                    # guarded-by: _lock
         self._quant_err = 0.0                    # guarded-by: _lock
         self._bundles = 0                        # guarded-by: _lock
@@ -264,8 +271,9 @@ class NumericsCollector:
 
     def _on_bundle(self, bundle, ef_mass) -> None:
         with self._lock:
-            self._latest = {k: float(v) for k, v in bundle.items()}
-            self._ef_mass = {k: float(v) for k, v in ef_mass.items()}
+            self._pending.append(
+                ({k: float(v) for k, v in bundle.items()},
+                 {k: float(v) for k, v in ef_mass.items()}))
             self._nonfinite += float(bundle.get("nonfinite", 0.0))
             self._bundles += 1
 
@@ -287,10 +295,13 @@ class NumericsCollector:
         with self._lock:
             self._quant_err += math.sqrt(max(v, 0.0))
 
-    def sync(self) -> None:
+    def sync(self, reg=None) -> None:
         """Drain in-flight debug callbacks (call at safe points — end
-        of train, before a final record — never per step)."""
+        of train, before a final record — never per step) and, given
+        the registry, publish and score the bundles they brought."""
         jax.effects_barrier()
+        if reg is not None:
+            self.sampler(reg)
 
     @property
     def bundles(self) -> int:
@@ -301,39 +312,45 @@ class NumericsCollector:
     # .. publishing ........................................................
 
     def sampler(self, reg) -> None:
-        """StepRecorder sampler: mirror the latest bundle as declared
-        series, then let the detector score the sample."""
+        """StepRecorder sampler: mirror each bundle that arrived since
+        the last call as declared series, and let the detector score
+        it — every bundle once, oldest first."""
         with self._lock:
-            latest = dict(self._latest)
-            ef_mass = dict(self._ef_mass)
+            pending, self._pending = self._pending, []
             nonfinite = self._nonfinite
             quant_err = self._quant_err
-        if not latest and not ef_mass and not nonfinite and not quant_err:
+        if not pending and not nonfinite and not quant_err:
             return
-        values: Dict[str, float] = {}
-        gsq = latest.get("gsq", 0.0)
-        gsq_hot = latest.get("gsq_hot", 0.0)
-        values["numerics/grad_norm"] = math.sqrt(max(gsq, 0.0))
-        values["numerics/grad_norm_hot"] = math.sqrt(max(gsq_hot, 0.0))
-        values["numerics/grad_norm_tail"] = math.sqrt(
-            max(gsq - gsq_hot, 0.0))
-        par_sq = latest.get("par_sq", 0.0)
-        if par_sq > 0.0:
-            values["numerics/update_ratio"] = math.sqrt(
-                max(latest.get("upd_sq", 0.0), 0.0) / par_sq)
-        loss_n = latest.get("loss_n", 0.0)
-        if loss_n > 0.0:
-            values["numerics/loss"] = latest.get("loss_sum", 0.0) / loss_n
-        for name, v in values.items():
-            reg.gauge(name).set(v)
-        for f, m in sorted(ef_mass.items()):
-            reg.gauge("numerics/ef_mass", field=f).set(m)
         reg.counter("numerics/nonfinite").set_total(nonfinite)
         reg.counter("numerics/quant_err").set_total(quant_err)
-        if self.detector is not None:
+        for latest, ef_mass in pending:
+            values: Dict[str, float] = {}
+            gsq = latest.get("gsq", 0.0)
+            gsq_hot = latest.get("gsq_hot", 0.0)
+            values["numerics/grad_norm"] = math.sqrt(max(gsq, 0.0))
+            values["numerics/grad_norm_hot"] = math.sqrt(max(gsq_hot, 0.0))
+            values["numerics/grad_norm_tail"] = math.sqrt(
+                max(gsq - gsq_hot, 0.0))
+            par_sq = latest.get("par_sq", 0.0)
+            if par_sq > 0.0:
+                values["numerics/update_ratio"] = math.sqrt(
+                    max(latest.get("upd_sq", 0.0), 0.0) / par_sq)
+            loss_n = latest.get("loss_n", 0.0)
+            if loss_n > 0.0:
+                values["numerics/loss"] = \
+                    latest.get("loss_sum", 0.0) / loss_n
+            for name, v in values.items():
+                reg.gauge(name).set(v)
             for f, m in sorted(ef_mass.items()):
-                values[f"numerics/ef_mass{{field={f}}}"] = m
-            self.detector.on_sample(reg, values, nonfinite)
+                reg.gauge("numerics/ef_mass", field=f).set(m)
+            if self.detector is not None:
+                for f, m in sorted(ef_mass.items()):
+                    values[f"numerics/ef_mass{{field={f}}}"] = m
+                self.detector.on_sample(reg, values, nonfinite)
+        if not pending and self.detector is not None:
+            # no bundle (the eager oracle's path stages none): the
+            # counter's forward motion is still the detector's to see
+            self.detector.on_sample(reg, {}, nonfinite)
 
 
 # -- rolling-baseline anomaly detector --------------------------------------

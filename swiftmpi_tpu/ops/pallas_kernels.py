@@ -12,7 +12,7 @@ shape — elementwise over a flat ``(rows, 128)`` lane-aligned view with one
 VMEM pass per block, and declares input/output aliasing for the pallas
 call.  Whether the aliasing actually elides the table copy depends on the
 caller: inside the framework's jitted training step the whole table state
-is donated (``_build_step``'s ``donate_argnums=0``), so XLA can satisfy
+is donated (``_build_step`` donates ``state``), so XLA can satisfy
 the alias in place; called standalone (as the tests do), the jit keeps its
 inputs valid and a copy is inserted.  (The flat view may also cost a
 relayout copy for widths that are not lane-aligned; for 128-multiple

@@ -10,10 +10,20 @@ past N tracked arrays, each push blocks on the OLDEST (its completion
 implies every earlier dependent dispatch ran, and ~N newer programs
 stay in flight, so there is no pipeline bubble).
 
+The bound is in PROGRAMS.  XLA:CPU admits 32 computations in flight a
+device and BLOCKS the dispatching thread at the 33rd — in the middle of
+a multi-device launch, some devices' parts enqueued and waiting at a
+rendezvous for the parts the blocked thread has yet to enqueue — and on
+an oversubscribed host nothing older retires to free a slot: a hang,
+then the abort above.  A loop whose step is more than one program (the
+word2vec async pair: gradients, then apply) says so (``programs``), and
+its window holds that many fewer steps: 16 programs either way.
+
 The ``"auto"`` policy applies the bound only on the cpu backend: a real
 TPU chip runs one program at a time and needs no bound.  Shared by
-``word2vec._LossAccum``, the LR train loop, and anything else that
-queues device results without fetching them.
+the word2vec train loops (which push the one result of a step that is
+not donated into the next: its own error sum), the LR train loop, and
+anything else that queues device results without fetching them.
 """
 
 from __future__ import annotations
@@ -26,10 +36,14 @@ AUTO_BOUND = 16
 
 
 class DispatchWindow:
-    def __init__(self, bound: Union[str, int, None] = "auto"):
+    def __init__(self, bound: Union[str, int, None] = "auto",
+                 programs: int = 1):
+        """``bound`` programs in flight (``"auto"``: the backend policy
+        above; ``None``: no bound), ``programs`` of them a pushed value."""
         if bound == "auto":
             bound = AUTO_BOUND if jax.default_backend() == "cpu" else None
-        self._bound: Optional[int] = bound
+        self._bound: Optional[int] = \
+            bound if bound is None else max(1, bound // programs)
         self._window: list = []
 
     def push(self, x) -> None:

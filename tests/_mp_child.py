@@ -54,7 +54,7 @@ def main():
     # every process, dp-sharded over the global data axis, table updates
     # through the jitted step (the reference's distributed SGD epoch body)
     from swiftmpi_tpu.data.text import CBOWBatcher, synthetic_corpus
-    from swiftmpi_tpu.models.word2vec import Word2Vec
+    from swiftmpi_tpu.models.word2vec import Word2Vec, _Tally
 
     cfg.update({"word2vec": {"len_vec": 8, "window": 2, "negative": 2,
                              "sample": -1, "learning_rate": 0.05},
@@ -73,7 +73,7 @@ def main():
             x.shape, NamedSharding(mesh, spec), lambda idx: x[idx])
 
     state = model.table.state
-    new_state, es, ec = step(
+    new_state, _key, tally, es = step(
         state, model._slot_of_vocab, model._alias_prob, model._alias_idx,
         global_put(batch.centers, P("data")),
         global_put(batch.contexts, P("data", None)),
@@ -81,7 +81,7 @@ def main():
         jax.random.key(1))
     jax.block_until_ready(new_state)
     model.table.state = new_state   # the step donated the old buffers
-    loss = float(es) / max(int(ec), 1)
+    loss = float(es) / max(_Tally.read(tally)["pair_count"], 1)
     assert np.isfinite(loss), f"non-finite loss {loss}"
 
     # full distributed epoch through the public API: train() shards the
@@ -138,13 +138,13 @@ def main():
     tb = next(CBOWBatcher(corpus, tmodel.vocab, tmodel.window).epoch(
         2 * n))
     tstep = tmodel._build_step()
-    tstate, tes, tec = tstep(
+    tstate, _key, ttally, tes = tstep(
         tmodel.table.state, tmodel._slot_of_vocab, tmodel._alias_prob,
         tmodel._alias_idx, jnp.asarray(tb.centers),
         jnp.asarray(tb.contexts), jnp.asarray(tb.ctx_mask),
         jax.random.key(5))
     tmodel.table.state = tstate
-    tloss = float(tes) / max(int(tec), 1)
+    tloss = float(tes) / max(_Tally.read(ttally)["pair_count"], 1)
     assert np.isfinite(tloss), f"tpu-transfer step loss {tloss}"
     changed = host_array(tstate["h"])
     assert np.abs(changed).sum() > 0

@@ -95,12 +95,14 @@ def test_run_without_tpu_prints_no_result(tmp_path):
 
 V, WINDOW, NEGATIVE, LEN_VEC, MINIBATCH = 300, 3, 4, 16, 4096
 FIELDS = {"h", "v", "h2sum", "v2sum"}
+#: ... and, since ISSUE 44, the call's tally behind the key: optional, so
+#: the positions the harness's tool passes (up to the key) still lower
 STEP_PARAMS = ("state", "slot_of_vocab", "alias_prob", "alias_idx",
-               "centers", "contexts", "ctx_mask", "key")
+               "centers", "contexts", "ctx_mask", "key", "tally")
 #: a CBOW model's step takes the span batch, one packed buffer, and is
 #: told the centers to cut it by
 SPAN_STEP_PARAMS = ("state", "slot_of_vocab", "alias_prob", "alias_idx",
-                    "span", "key", "centers")
+                    "span", "key", "tally", "centers")
 
 
 class TwoBatches:
@@ -372,9 +374,10 @@ def cluster_mesh(toy):
 
 @surface
 def sampler_privates(toy):
-    """``_key`` (``train()`` keeps ``split(key)[0]`` and hands the step
-    ``split(key)[1]``), ``_alias_prob`` / ``_alias_idx`` (unigram^0.75 of
-    the counts) and ``ops.sampling.sample_alias``."""
+    """``_key`` (a step keeps ``split(key)[0]`` for the next and draws
+    with ``split(key)[1]``, as ``train()`` did on the host before ISSUE
+    44), ``_alias_prob`` / ``_alias_idx`` (unigram^0.75 of the counts)
+    and ``ops.sampling.sample_alias``."""
     from swiftmpi_tpu.ops.sampling import sample_alias
 
     t = toy()
@@ -419,7 +422,9 @@ def build_step_signature(toy):
     span (the tool still passes the per-pair ones: PERF.md section 7)."""
     for sg, params in ((0, SPAN_STEP_PARAMS), (1, STEP_PARAMS)):
         step = toy(sg).model._build_step()
-        assert tuple(inspect.signature(step).parameters) == params
+        signature = inspect.signature(step).parameters
+        assert tuple(signature) == params
+        assert signature["tally"].default is None
         assert callable(step.lower)
 
 

@@ -145,12 +145,34 @@ def _w2v_step(topo, tmp_path, monkeypatch, cell, chips=1):
                  shape((centers, 2 * window), jnp.bool_))
         statics = {}
     key = jax.eval_shape(lambda: jax.random.key(0))
+    # the step's carried arguments (ISSUE 44): the model's key and the
+    # call's tally, donated like the state
+    from swiftmpi_tpu.models.word2vec import _Tally
+    tally = _Tally.zeros()
     compiled = step.lower(
         model.table.state, shape((vocab,), jnp.int32),
         shape((vocab,), jnp.float32), shape((vocab,), jnp.int32), *batch,
         jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=rep),
-        **statics).compile()
+        shape(tally.shape, tally.dtype), **statics).compile()
     return cluster, model, compiled
+
+
+def _aliased(text):
+    """{(output, parameter)} of a compiled module's input_output_alias."""
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    return {(int(o), int(i)) for o, i in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", aliased)}
+
+
+def _carried_aliased(text):
+    """The four fields, the key and the tally: each result written where
+    its argument was (results: fields 0-3, key data 4, tally 5, the
+    step's own error sum last; the key and the tally are the last two
+    parameters)."""
+    n_params = len(re.findall(r"parameter\((\d+)\)", re.search(
+        r"\nENTRY [^\n]*\{\n(.*?)\n\}", text, re.S).group(1)))
+    return _aliased(text) >= {(i, i) for i in range(4)} | {
+        (4, n_params - 2), (5, n_params - 1)}
 
 
 def _pair_grid(model, traffic):
@@ -174,16 +196,16 @@ def _instructions(text):
 @pytest.mark.parametrize("cell, sweeps, temp_gib, span, program", [
     # 5,500 target slots and a span of 768: the shapes rule the sweep
     # out, the head's chunks are all there is
-    ("cbow2m-demo", 0, 0.01, 768, (2911, "39a2f22493a1de65")),
+    ("cbow2m-demo", 0, 0.01, 768, (3277, "6b8d64c91e461d03")),
     # 180,224 target slots: chunks or one sweep, by the count; the
     # context push is the span's 22,528 slots (74.2 % of a Zipf stream's
     # positions pass the center gate): chunks alone, where the per-pair
     # grid's 163,840 slots had a conditional and a sweep of their own
-    ("cbow2m-b16k", 2, 1.0, 22_400, (3189, "58360141e83e5b37")),
+    ("cbow2m-b16k", 2, 1.0, 22_400, (3555, "a9dd094689dee825")),
     # uniform keys: nothing is gated, the span is B + 2W in whole tiles
-    ("cbow2m-b16k-uniform", 2, 1.0, 16_512, (3191, "43086c84424a6621")),
+    ("cbow2m-b16k-uniform", 2, 1.0, 16_512, (3557, "aabc09dbe853d6f4")),
     # 122,880 target slots: either; 20,480 input slots: chunks alone
-    ("sg2m-b2k", 2, 0.7, None, (2776, "d2d9fb458098049e")),
+    ("sg2m-b2k", 2, 0.7, None, (3142, "db2d8ee1f418259d")),
 ])
 def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
                                   monkeypatch, cell, sweeps, temp_gib,
@@ -225,11 +247,14 @@ def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
             rf"= \w+\[{grid}[\],].*op_name=\"([^\"]*)\"", text))
     else:
         assert re.findall(rf"= f32\[{grid},384\]", text)
-    # ... and the step is PR 42's instruction for instruction (skip-gram's
-    # is PR 35's still): a table on ONE shard is pulled and pushed
-    # directly, the owner routing of ISSUE 43 is bypassed and left these
-    # programs alone.  A PR that changes a step on purpose pins its own
-    # digest here.
+    # ... and the step is PR 44's instruction for instruction: PR 42's
+    # (skip-gram's: PR 35's), where a table on ONE shard is pulled and
+    # pushed directly and the owner routing of ISSUE 43 is bypassed, plus
+    # the bookkeeping ISSUE 44 moved into the program — the key's split
+    # (threefry, whose constants the sampler's now share) and the tally's
+    # adds: 366 instructions more, every one outside the pull, math,
+    # dedup and apply scopes, whose instructions count as they did.  A PR
+    # that changes a step on purpose pins its own digest here.
     assert _instructions(text) == program
     field = rf"f32\[{capacity},384\]"
     # the module's first line: aliasing and the entry's layouts
@@ -238,9 +263,7 @@ def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
     row_major = field + r"\{1,0:T\(8,128\)\}"
     assert len(re.findall(row_major, params)) == 4
     assert len(re.findall(row_major, results)) == 4
-    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
-    assert {(int(o), int(i)) for o, i in re.findall(
-        r"\{(\d+)\}: \((\d+), \{\}", aliased)} >= {(i, i) for i in range(4)}
+    assert _carried_aliased(text)
     assert not re.findall(rf"= {field}\S* copy\(", text)
     assert f"[{capacity},300]" not in text
     # a field is 3.35 GiB: the temporaries are a fraction of one
@@ -309,9 +332,7 @@ def test_w2v_x4_step_routes_rows_to_their_owners(
                          "custom-call", "bitcast"}
     assert not re.findall(rf"= {field}\S* (?:copy|broadcast|constant)\(",
                           text)
-    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
-    assert {(int(o), int(i)) for o, i in re.findall(
-        r"\{(\d+)\}: \((\d+), \{\}", aliased)} >= {(i, i) for i in range(4)}
+    assert _carried_aliased(text)
     # every write to a field is a scatter of rows into it, in place: the
     # head's chunks and, where the shapes allow a longer head, one sweep
     writes = [line for line in text.splitlines()

@@ -25,7 +25,7 @@ from swiftmpi_tpu.io.resilience import train_with_resume  # noqa: E402
 from swiftmpi_tpu.models.glove import GloVe  # noqa: E402
 from swiftmpi_tpu.models.trainer import Trainer  # noqa: E402
 from swiftmpi_tpu.models import transformer as tfm  # noqa: E402
-from swiftmpi_tpu.models.word2vec import Word2Vec, _LossAccum  # noqa: E402
+from swiftmpi_tpu.models.word2vec import Word2Vec  # noqa: E402
 from swiftmpi_tpu.testing import faults  # noqa: E402
 from swiftmpi_tpu.testing.faults import FaultPlan, InjectedFault  # noqa: E402
 from swiftmpi_tpu.utils import ConfigParser  # noqa: E402
@@ -158,20 +158,26 @@ def test_resolve_dispatch_bound():
     assert resolve_dispatch_bound(0, pipelined=True) is None
 
 
-def test_loss_accum_retention_bound(devices8):
-    """An epoch of 10k tiny batches retains at most ``fold`` queued
-    device scalars — the accumulator drains by folding, without a
-    blocking host sync per batch."""
-    acc = _LossAccum(bound=None, fold=64)
-    for _ in range(10_000):
-        acc.add(jnp.float32(0.001))
-    assert acc.peak_queued <= 64
-    assert acc.total() == pytest.approx(10.0, rel=1e-3)
+@pytest.mark.parametrize("programs, held", [(1, 16), (2, 8), (3, 5)])
+def test_dispatch_window_bounds_programs(programs, held, monkeypatch):
+    """The window's bound is in programs: a loop whose step is
+    ``programs`` of them keeps that many fewer steps in flight (XLA:CPU
+    blocks the dispatching thread at 32 computations a device — the
+    async pair's 16 steps)."""
+    from swiftmpi_tpu.utils import pipeline
+    from swiftmpi_tpu.utils.pipeline import DispatchWindow
 
-
-def test_loss_accum_fold_validation():
-    with pytest.raises(ValueError):
-        _LossAccum(bound=None, fold=1)
+    waited = []
+    monkeypatch.setattr(pipeline.jax, "block_until_ready", waited.append)
+    window = DispatchWindow(AUTO_BOUND, programs=programs)
+    for step in range(40):
+        window.push(step)
+        assert len(window._window) <= held
+    # oldest first, one a push once full
+    assert waited == list(range(40 - held))
+    unbounded = DispatchWindow(None, programs=programs)
+    unbounded.push(0)
+    assert unbounded._window == []
 
 
 def test_throughput_stall_split():

@@ -72,14 +72,16 @@ def test_multi_step_scan_matches_single_steps(devices8):
     key = jax.random.key(7)
     # deep-copy: multi donates its state argument
     state_copy = {f: jnp.array(v) for f, v in model.table.state.items()}
-    s_multi, es, ec = multi(
+    # the program splits the key it is given, then once a step
+    sub = jax.random.split(key)[1]
+    s_multi, *_sums = multi(
         state_copy, model._slot_of_vocab, model._alias_prob,
         model._alias_idx, centers, contexts, masks, key)
 
     grads_fn = jax.jit(model._build_grads())
     apply_fn = jax.jit(model._build_apply())
     s = dict(model.table.state)
-    keys = jax.random.split(key, 2)
+    keys = jax.random.split(sub, 2)
     for i in range(2):
         pushes, _, _ = grads_fn(
             s, model._slot_of_vocab, model._alias_prob, model._alias_idx,
@@ -197,7 +199,7 @@ def test_w2v_step_with_pallas_pull_matches_xla(monkeypatch, devices8):
         batcher = CBOWBatcher(corpus, m.vocab, m.window, m.sample, seed=5)
         b = next(iter(batcher.epoch(128)))
         state = dict(m.table.state)
-        state, es, ec = step(
+        state, _key, _tally, es = step(
             state, m._slot_of_vocab, m._alias_prob, m._alias_idx,
             jnp.asarray(b.centers), jnp.asarray(b.contexts),
             jnp.asarray(b.ctx_mask), jax.random.key(0))
@@ -271,7 +273,7 @@ def test_w2v_step_with_pallas_scatter_matches_xla(monkeypatch, devices8):
         batcher = CBOWBatcher(corpus, m.vocab, m.window, m.sample, seed=5)
         b = next(iter(batcher.epoch(64)))
         state = dict(m.table.state)
-        state, es, ec = step(
+        state, _key, _tally, es = step(
             state, m._slot_of_vocab, m._alias_prob, m._alias_idx,
             jnp.asarray(b.centers), jnp.asarray(b.contexts),
             jnp.asarray(b.ctx_mask), jax.random.key(0))

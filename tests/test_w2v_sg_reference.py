@@ -17,7 +17,7 @@ import pytest
 from benchmark.reference import w2v_sg as reference
 from swiftmpi_tpu.cluster.cluster import Cluster
 from swiftmpi_tpu.data.text import Vocab
-from swiftmpi_tpu.models.word2vec import Word2Vec
+from swiftmpi_tpu.models.word2vec import Word2Vec, _Tally
 from swiftmpi_tpu.ops.sampling import sample_alias
 from swiftmpi_tpu.utils import ConfigParser
 
@@ -85,13 +85,16 @@ def test_sg_step_matches_plain_reference(n_devices, case):
         pytest.skip(f"needs {n_devices} virtual devices")
     model, before, rng = build(n_devices)
     key = jax.random.key(2008)
-    centers, contexts, mask, negs = batch_for(case, model, rng, key)
+    # the step is given the model's key and draws with what it splits off
+    centers, contexts, mask, negs = batch_for(
+        case, model, rng, jax.random.split(key)[1])
     step = model._build_step()
     assert model.resolved_rendering == "sg"
-    state, err_sum, err_cnt = step(
+    state, _next_key, tally, err_sum = step(
         model.table.state, model._slot_of_vocab, model._alias_prob,
         model._alias_idx, jnp.asarray(centers), jnp.asarray(contexts),
         jnp.asarray(mask), key)
+    err_cnt = _Tally.read(tally)["pair_count"]
     after = {f: np.asarray(a) for f, a in state.items()}
 
     # the pair layout of reference/w2v_sg.py, over the whole (small) table

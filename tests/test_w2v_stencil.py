@@ -24,7 +24,7 @@ from swiftmpi_tpu.data.text import (CBOWBatcher, Vocab,  # noqa: E402
                                     build_vocab, load_corpus,
                                     span_positions, stencil_to_cbow,
                                     synthetic_corpus, unpack_span)
-from swiftmpi_tpu.models.word2vec import Word2Vec  # noqa: E402
+from swiftmpi_tpu.models.word2vec import Word2Vec, _Tally  # noqa: E402
 from swiftmpi_tpu.ops.sampling import sample_alias  # noqa: E402
 from swiftmpi_tpu.testing import cbow_batch_grads  # noqa: E402
 from swiftmpi_tpu.utils import ConfigParser  # noqa: E402
@@ -377,20 +377,21 @@ def test_stencil_step_matches_gather_step(devices8):
         if B == 512:
             assert batch.n_words < B
         exp = stencil_to_cbow(batch, m_st.window)
-        key = jax.random.key(11)
-        # the jitted steps DONATE their state argument: hand each call
-        # fresh copies so the models' live buffers survive both rounds
+        # the jitted steps DONATE their state and key arguments: hand
+        # each call fresh copies so the models' live buffers survive
+        # both rounds
         span, shape = span_args(batch)
-        st1, es1, ec1 = step_st(
+        st1, _k1, t1, es1 = step_st(
             {f: jnp.array(v) for f, v in m_st.table.state.items()},
             m_st._slot_of_vocab, m_st._alias_prob,
-            m_st._alias_idx, *span, key, **shape)
-        st2, es2, ec2 = step_ga(
+            m_st._alias_idx, *span, jax.random.key(11), **shape)
+        st2, _k2, t2, es2 = step_ga(
             {f: jnp.array(v) for f, v in m_ga.table.state.items()},
             m_ga._slot_of_vocab, m_ga._alias_prob,
             m_ga._alias_idx, jnp.asarray(exp.centers),
-            jnp.asarray(exp.contexts), jnp.asarray(exp.ctx_mask), key)
-        assert int(ec1) == int(ec2)
+            jnp.asarray(exp.contexts), jnp.asarray(exp.ctx_mask),
+            jax.random.key(11))
+        assert _Tally.read(t1)["pair_count"] == _Tally.read(t2)["pair_count"]
         np.testing.assert_allclose(float(es1), float(es2), rtol=1e-5)
         for f in st2:
             np.testing.assert_allclose(np.asarray(st1[f]),
@@ -590,21 +591,21 @@ def test_span_step_equals_per_pair_step_row_for_row(which, devices8):
     # gated centers: positions inside the span that are no center
     assert batch.n_words < 0.9 * int((batch.sent_id >= 0).sum())
     exp = stencil_to_cbow(batch, W)
-    key = jax.random.key(5)
 
     def fresh(m):
         return {f: jnp.array(v) for f, v in m.table.state.items()}
 
     before = {f: np.asarray(v) for f, v in m_sp.table.state.items()}
     span, shape = span_args(batch)
-    got, es1, ec1 = m_sp._build_step()(
+    got, _k1, t1, es1 = m_sp._build_step()(
         fresh(m_sp), m_sp._slot_of_vocab, m_sp._alias_prob,
-        m_sp._alias_idx, *span, key, **shape)
-    want, es2, ec2 = m_pp._build_step()(
+        m_sp._alias_idx, *span, jax.random.key(5), **shape)
+    want, _k2, t2, es2 = m_pp._build_step()(
         fresh(m_pp), m_pp._slot_of_vocab, m_pp._alias_prob,
         m_pp._alias_idx, jnp.asarray(exp.centers),
-        jnp.asarray(exp.contexts), jnp.asarray(exp.ctx_mask), key)
-    assert int(ec1) == int(ec2) > 0
+        jnp.asarray(exp.contexts), jnp.asarray(exp.ctx_mask),
+        jax.random.key(5))
+    assert _Tally.read(t1)["pair_count"] == _Tally.read(t2)["pair_count"] > 0
     np.testing.assert_allclose(float(es1), float(es2), rtol=1e-5)
     for f in ("h", "v", "h2sum", "v2sum"):
         a, b = np.asarray(got[f]), np.asarray(want[f])
@@ -651,7 +652,7 @@ def test_uncovered_span_position_writes_no_row(devices8):
     np.testing.assert_array_equal(
         np.asarray(v_push.counts)[:6], [0, 1, 1, 1, 1, 0])
     before = {f: np.asarray(v) for f, v in m.table.state.items()}
-    after, _es, _ec = m._build_step()(
+    after, *_sums = m._build_step()(
         {f: jnp.array(v) for f, v in m.table.state.items()},
         m._slot_of_vocab, m._alias_prob, m._alias_idx, *span,
         jax.random.key(1), **shape)

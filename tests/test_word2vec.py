@@ -7,7 +7,7 @@ import pytest
 
 from swiftmpi_tpu.data.text import (CBOWBatcher, build_vocab, load_corpus,
                                     synthetic_corpus, tokenize)
-from swiftmpi_tpu.models.word2vec import Word2Vec
+from swiftmpi_tpu.models.word2vec import Word2Vec, _Tally
 from swiftmpi_tpu.ops import (MAX_EXP, build_unigram_alias, sample_alias,
                               sigmoid_clipped, subsample_keep_prob)
 from swiftmpi_tpu.utils import ConfigParser
@@ -875,8 +875,9 @@ def test_w2v_hogwild_reconciliation_is_exact_worker_major_apply(devices8):
     apply_fn = m._build_apply()
     sov, ap, ai = m._slot_of_vocab, m._alias_prob, m._alias_idx
     all_pushes = []
+    sub = jax.random.split(key)[1]     # the step splits the key it is given
     for w in range(8):
-        keys = jax.random.split(jax.random.fold_in(key, w), n_inner)
+        keys = jax.random.split(jax.random.fold_in(sub, w), n_inner)
         local = {f: jnp.asarray(v) for f, v in base.items()}
         seq = []
         for s in range(n_inner):
@@ -891,8 +892,9 @@ def test_w2v_hogwild_reconciliation_is_exact_worker_major_apply(devices8):
         for s in range(n_inner):
             ref = apply_fn(ref, all_pushes[w][s])
 
-    got, es, ec = step({f: jnp.asarray(v) for f, v in base.items()},
-                       sov, ap, ai, c, x, mk, key)
+    got, _key, _tally, es = step(
+        {f: jnp.asarray(v) for f, v in base.items()},
+        sov, ap, ai, c, x, mk, key)
     for f in ref:
         # jit-fused vs eager replay differ only by float reassociation
         # (~1e-7); a wrong APPLY ORDER shows up at ~1e-2 (AdaGrad
@@ -916,11 +918,11 @@ def test_w2v_dense_logits_matches_parity_step(devices8):
                               seed=5)
         b = next(iter(batcher.epoch(128)))
         state = dict(m.table.state)
-        state, es, ec = step(
+        state, _key, tally, es = step(
             state, m._slot_of_vocab, m._alias_prob, m._alias_idx,
             jnp.asarray(b.centers), jnp.asarray(b.contexts),
             jnp.asarray(b.ctx_mask), jax.random.key(3))
-        return float(es), int(ec), \
+        return float(es), _Tally.read(tally)["pair_count"], \
             {f: np.asarray(v) for f, v in state.items()}
 
     es0, ec0, st0 = run(False)
