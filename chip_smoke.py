@@ -9,10 +9,7 @@ repo's large-vocabulary CBOW shape: the reference demo.conf
 hyperparameters over a 1,000,000-word vocabulary (1.3 M table rows x
 100 dims, ``h``/``v`` + their AdaGrad planes ~ 2.1 GB f32 in HBM), on
 however many chips it finds.  A second short run with ``[worker]
-inner_steps`` > 1 compiles the fused ``lax.scan`` program, and every
-Pallas kernel the tree keeps is compiled with ``interpret=False`` and
-compared with its XLA reference (the DMA ring exchange only where there
-is more than one chip).
+inner_steps`` > 1 compiles the fused ``lax.scan`` program.
 
 ``python3 chip_smoke.py`` demands the chip: it exits non-zero, with no
 result line, when JAX finds no TPU.  On success the last stdout line is
@@ -44,7 +41,6 @@ ZIPF_TOKENS = 1_600_000
 # cover a truncated stream, as tests/_scale_child.py does.
 TRAIN_TOKENS = 100_000       # 160 steps an iteration, three iterations
 FUSED_TOKENS = 25_000        # 40 steps an iteration = 10 scan groups of 4
-KERNEL_BATCH = 16_384        # cbow2m-b16k's center count per step
 
 
 def log(msg: str) -> None:
@@ -270,99 +266,12 @@ def peak_bytes(devices):
     return [int(s["peak_bytes_in_use"]) for s in stats]
 
 
-# -- Pallas kernels -----------------------------------------------------------
-
-def check_kernels(d: int = 100, batch: int = KERNEL_BATCH,
-                  small_rows: int = 17_314, interpret: bool = False):
-    """Compile every single-chip Pallas kernel the tree keeps at the
-    shape its call site uses (row width ``d``, one 16 K-center step's
-    rows, the demo.conf-scale 17 K-row table the VMEM-resident kernels
-    are gated to) and compare with the XLA reference.  Returns the
-    names."""
-    import jax
-    import jax.numpy as jnp
-
-    from swiftmpi_tpu.ops import (pallas_gather, pallas_kernels,
-                                  pallas_scatter)
-
-    rng = np.random.default_rng(0)
-    done = []
-
-    # server-side AdaGrad over one step's pushed rows
-    p, a, g = (jnp.asarray(rng.standard_normal((batch, d)), jnp.float32)
-               for _ in range(3))
-    a = jnp.abs(a)
-    po, ao = pallas_kernels.adagrad_update(p, a, g, lr=0.7, fudge=1e-6,
-                                           interpret=interpret)
-    a_ref = a + g * g
-    p_ref = p + 0.7 * g * jax.lax.rsqrt(a_ref + 1e-6)
-    require(np.allclose(np.asarray(ao), np.asarray(a_ref), rtol=1e-6)
-            and np.allclose(np.asarray(po), np.asarray(p_ref),
-                            rtol=1e-5, atol=1e-6),
-            "adagrad_update differs from the jnp rule")
-    done.append("adagrad_update")
-
-    # VMEM-resident gather / scatter: tables small enough to stage
-    small = jnp.asarray(rng.standard_normal((small_rows, d)), jnp.float32)
-    require(pallas_gather.fits_vmem(small),
-            "vmem_gather call-site table does not pass fits_vmem")
-    idx = jnp.asarray(rng.integers(0, small_rows, batch), jnp.int32)
-    valid = jnp.asarray(rng.random(batch) < 0.9)
-    got = pallas_gather.masked_vmem_gather(small, idx, valid)
-    want = jnp.where(valid[:, None], jnp.take(small, idx, axis=0), 0)
-    require(np.array_equal(np.asarray(got), np.asarray(want)),
-            "vmem_gather differs from jnp.take")
-    done.append("vmem_gather")
-
-    width = d + 1            # grads + the fused contribution-count column
-    require(pallas_scatter.fits_vmem(small_rows, width),
-            "vmem_scatter call-site shape does not pass fits_vmem")
-    grads = jnp.asarray(rng.standard_normal((batch, width)), jnp.float32)
-    got = pallas_scatter.masked_vmem_scatter_add(idx, valid, grads,
-                                                 small_rows)
-    want = jnp.zeros((small_rows, width), jnp.float32).at[
-        jnp.where(valid, idx, small_rows)].add(grads, mode="drop")
-    require(np.allclose(np.asarray(got), np.asarray(want),
-                        rtol=1e-4, atol=1e-4),
-            "vmem_scatter differs from .at[].add")
-    done.append("vmem_scatter")
-    return done
-
-
-def check_ring(mesh, axis: str, d: int = 100, bucket: int = 4096,
-               interpret: bool = False) -> None:
-    """DMA ring exchange at the two bucket shapes the tpu transfer's
-    push hands it — ``(n, C)`` int32 request ids and ``(n, C, d + 1)``
-    f32 grads + counts — vs the block transpose it must equal (needs
-    > 1 device on ``axis``)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from swiftmpi_tpu.ops.pallas_ring import ring_exchange
-
-    n = int(mesh.shape[axis])
-    rng = np.random.default_rng(0)
-    f = jax.jit(jax.shard_map(
-        lambda b: ring_exchange(b[0], axis, n, interpret=interpret)[None],
-        mesh=mesh, in_specs=P(axis), out_specs=P(axis), check_vma=False))
-    for shape, dtype in (((n, n, bucket), jnp.int32),
-                         ((n, n, bucket, d + 1), jnp.float32)):
-        x = jnp.asarray(rng.integers(0, 1000, shape), dtype)
-        perm = (1, 0) + tuple(range(2, len(shape)))
-        require(np.array_equal(np.asarray(f(x)),
-                               np.asarray(x).transpose(perm)),
-                f"ring_exchange differs from all_to_all at {shape}")
-
-
 # -- the run ------------------------------------------------------------------
 
 def run(out_dir: str, platform: str, vocab_size: int = VOCAB,
         zipf_tokens: int = ZIPF_TOKENS, max_tokens=TRAIN_TOKENS,
         fused_tokens: int = FUSED_TOKENS, transfer: str = "xla",
-        len_vec: int = 100, minibatch: int = 5000,
-        kernel_batch: int = KERNEL_BATCH, kernel_rows: int = 17_314,
-        interpret: bool = False) -> dict:
+        len_vec: int = 100, minibatch: int = 5000) -> dict:
     """All phases at the given size; raises SystemExit on any miss."""
     import jax
 
@@ -435,15 +344,6 @@ def run(out_dir: str, platform: str, vocab_size: int = VOCAB,
             f"non-finite fused loss {fused_losses}")
     check_placement(model, platform)
 
-    kernels = check_kernels(len_vec, kernel_batch, kernel_rows, interpret)
-    log("pallas kernels compiled and matched: " + ", ".join(kernels))
-
-    if len(devices) > 1:
-        from swiftmpi_tpu.cluster.mesh import ps_mesh
-        from swiftmpi_tpu.cluster import SHARD_AXIS
-        check_ring(ps_mesh(), SHARD_AXIS, len_vec, interpret=interpret)
-        log(f"pallas ring_exchange matched over {len(devices)} devices")
-
     peaks = peak_bytes(devices)
     if peaks is not None:
         log("peak_bytes_in_use per device: "
@@ -452,8 +352,18 @@ def run(out_dir: str, platform: str, vocab_size: int = VOCAB,
     log(f"compile cache: {entries1} entries after (+{entries1 - entries0})")
     return {"losses": losses, "fused_losses": fused_losses,
             "table_bytes": table_bytes, "peak_bytes": peaks,
-            "loader": loader, "kernels": kernels,
+            "loader": loader,
             "first_call_s": first_s, "steady_iter_s": steady_iter_s}
+
+
+def libtpu_version() -> str:
+    from importlib import metadata
+    for dist in ("libtpu", "libtpu-nightly"):
+        try:
+            return metadata.version(dist)
+        except metadata.PackageNotFoundError:
+            continue
+    return "none"
 
 
 def main(argv=None) -> int:
@@ -465,8 +375,6 @@ def main(argv=None) -> int:
     import jax
     import jaxlib
 
-    from swiftmpi_tpu.ops import calibration
-
     t0 = time.perf_counter()
     devices = jax.devices()
     dev = devices[0]
@@ -475,7 +383,7 @@ def main(argv=None) -> int:
                          f"{dev.platform!r} ({dev.device_kind})")
     log(f"device: platform={dev.platform} kind={dev.device_kind} "
         f"count={len(devices)}; jax {jax.__version__} jaxlib "
-        f"{jaxlib.__version__} libtpu {calibration.stack_key()['libtpu']}")
+        f"{jaxlib.__version__} libtpu {libtpu_version()}")
     out = run(os.path.join(REPO, "runs", "chip_smoke"), "tpu",
               transfer=args.transfer)
     require(out["peak_bytes"] is not None,

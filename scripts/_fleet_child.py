@@ -45,7 +45,10 @@ here), trains its owned rows, and publishes ``elastic/epoch`` /
 counters, so the FleetCollector's epoch/reconvergence/imbalance view
 works off the ordinary telemetry streams.  ``SMTPU_ELASTIC_SHARDS`` /
 ``_ROWS`` / ``_DIM`` / ``_DUMP_EVERY`` size the workload; a rank
-evicted by a rollback re-enters through ``boot()``.  Prints
+evicted by a rollback re-enters through ``boot()``.  A rank trains on
+past ``SMTPU_FLEET_STEPS`` while the world is not whole (a peer dead or
+mid-rejoin) or its committed epoch is below the one the fault plan ends
+on (two a planned kill), at most the join timeout longer.  Prints
 ``ELASTIC_CHILD_OK rank=<r> steps=<n> epoch=<e> loss=<l>`` on a clean
 finish; a stale-epoch rejection exits rc 3 (loud, never silent).
 
@@ -54,6 +57,7 @@ Prints ``FLEET_CHILD_OK rank=<r> steps=<n>`` on a clean finish.
 
 from __future__ import annotations
 
+import itertools
 import os
 import sys
 import time
@@ -74,7 +78,7 @@ def elastic_main(rec, reg, rank: int, steps: int, step_s: float,
     member table (see module docstring)."""
     from swiftmpi_tpu.cluster.bootstrap import ENV_NUM_PROCESSES
     from swiftmpi_tpu.cluster.elastic import ElasticWorker
-    from swiftmpi_tpu.cluster.membership import StaleEpochError
+    from swiftmpi_tpu.cluster.membership import COMMITTED, StaleEpochError
 
     world = int(os.environ.get(ENV_NUM_PROCESSES, "1"))
     worker = ElasticWorker(
@@ -86,6 +90,11 @@ def elastic_main(rec, reg, rank: int, steps: int, step_s: float,
         dump_every=int(os.environ.get("SMTPU_ELASTIC_DUMP_EVERY", "5")))
     join_timeout = float(os.environ.get("SMTPU_ELASTIC_JOIN_TIMEOUT_S",
                                         "30"))
+    # the epoch the drill's faults end on: each planned kill costs two,
+    # the death's repartition and the rejoin's commit
+    plan = faults.active()
+    leave_epoch = 2 * sum(f.kind == "kill" for f in plan.faults) \
+        if plan else 0
     row_bytes = 4 + worker.dim * 4
     booked_mig = 0
     loss = 0.0
@@ -94,7 +103,24 @@ def elastic_main(rec, reg, rank: int, steps: int, step_s: float,
             print(f"elastic_child: rank {rank} never admitted within "
                   f"{join_timeout}s", file=sys.stderr)
             return 4
-        for step in range(steps):
+        # a rank leaves a WHOLE world only, and not before the epoch the
+        # drill ends on: while a peer is still to be killed, or is on
+        # its way back (restart backoff + boot + two-phase handback),
+        # the others keep training past ``steps`` — a rank that left
+        # could never ack the rejoin's prepare, and how long a start or
+        # a restart takes is the host's business, not the drill's.  The
+        # ceiling is the join timeout, past which the peer was abandoned.
+        def whole() -> bool:
+            t = worker.member_table
+            return (t is not None and t.state == COMMITTED
+                    and len(t.live) == world and t.epoch >= leave_epoch)
+
+        leave_by = None
+        for step in itertools.count():
+            if step >= steps:
+                leave_by = leave_by or time.monotonic() + join_timeout
+                if whole() or time.monotonic() >= leave_by:
+                    break
             faults.step_event(step)       # kill/hang drills fire here
             events = worker.sync()        # the safe point
             if any(e.get("kind") == "evicted" for e in events):
@@ -124,7 +150,7 @@ def elastic_main(rec, reg, rank: int, steps: int, step_s: float,
         return 3
     worker.write_census()
     rec.close()
-    print(f"ELASTIC_CHILD_OK rank={rank} steps={steps} "
+    print(f"ELASTIC_CHILD_OK rank={rank} steps={step} "
           f"epoch={worker.epoch} loss={loss:.6f}")
     return 0
 

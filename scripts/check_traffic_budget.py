@@ -51,7 +51,7 @@ import sys
 #: ledger the same way wire_bytes_per_step budgets pushes.
 TRAFFIC_METRICS = ("wire_bytes_per_step", "dispatches_per_step",
                    "dispatches_per_window", "stall_ms_per_step",
-                   "kernel_ms", "serve_p99_ms", "serve_miss_ratio",
+                   "serve_p99_ms", "serve_miss_ratio",
                    "pull_bytes_per_step", "control_decisions_per_1k_steps",
                    "fleet_step_ms_skew_pct", "fleet_wire_bytes_imbalance",
                    "ef_mass_growth", "fleet_grad_norm_divergence",
@@ -98,13 +98,12 @@ DETAIL_METRICS = ("window_sparse", "window_dense", "window_fmt_dense",
                   "tail_bit_identical")
 #: absolute increase a metric must clear before it can regress: wall-
 #: clock metrics jitter run to run while the counter metrics are exact,
-#: so only the former get a floor (ms for the stall split; kernel_ms is
-#: a microbench mean over many reps, tighter than one stall sample;
+#: so only the former get a floor (ms for the stall split;
 #: serve_p99_ms is one tail sample under deliberate train/serve
 #: contention — the stall gate's 0.1ms convention applies; a
 #: miss-ratio wiggle under 1 point is query-stream sampling noise)
-ABS_NOISE_FLOOR = {"stall_ms_per_step": 0.1, "kernel_ms": 0.05,
-                   "serve_p99_ms": 0.1, "serve_miss_ratio": 0.01,
+ABS_NOISE_FLOOR = {"stall_ms_per_step": 0.1, "serve_p99_ms": 0.1,
+                   "serve_miss_ratio": 0.01,
                    # a quiet baseline (0 decisions) must tolerate the
                    # occasional legitimate retune; only a flapping tuner
                    # (> 2 decisions per 1k steps above baseline) fails
@@ -241,17 +240,7 @@ def load_telemetry_cells(path: str) -> dict:
         if total:
             cell[tkey.replace("/", "_")] = total
     run = str(doc["meta"].get("run", "telemetry"))
-    cells = {run: cell} if cell else {}
-    # kernel microbench streams (obs.micro.MicroTelemetry): every
-    # ``micro/<name>`` phase becomes its own cell keyed ``run/<name>``
-    # with the lower-is-better kernel_ms mean, so two microbench runs
-    # diff cell by cell like bench JSONs
-    for row in phase_table(doc):
-        phase = row["phase"]
-        if phase.startswith("micro/"):
-            cells[f"{run}/{phase[len('micro/'):]}"] = {
-                "kernel_ms": row["mean_ms"]}
-    return cells
+    return {run: cell} if cell else {}
 
 
 def load_fleet_cells(path: str) -> dict:

@@ -42,13 +42,9 @@ def _corpus():
 
 
 def _w2v_config(**overrides):
-    """The soak model hyperparameters (one source of truth).
-    ``SOAK_DENSE=1`` forces the dense-logits rendering so the parity
-    run checks THAT path against the oracle at soak scale."""
+    """The soak model hyperparameters (one source of truth)."""
     from swiftmpi_tpu.utils import ConfigParser
 
-    if os.environ.get("SOAK_DENSE"):
-        overrides.setdefault("dense_logits", 1)
     return ConfigParser().update({
         "cluster": {"server_num": overrides.pop("server_num", 1),
                     "transfer": "xla"},

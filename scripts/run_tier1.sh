@@ -153,8 +153,4 @@ if timeout -k 10 300 env JAX_PLATFORMS=cpu python "$(dirname "$0")/fleet_smoke.p
 else
   echo "serve smoke ADVISORY FAILURE (tier-1 verdict unchanged)"
 fi
-# Advisory calibration staleness check: verdicts recorded under another
-# jaxlib/libtpu stack no longer steer data-plane gates — say so next to
-# the verdict (exit code unchanged; the CLI always exits 0).
-timeout -k 5 60 env JAX_PLATFORMS=cpu python -m swiftmpi_tpu.ops.calibration --stale-check 2>/dev/null || true
 exit $rc

@@ -30,7 +30,6 @@ from swiftmpi_tpu.cluster.bootstrap import init_distributed
 from swiftmpi_tpu.cluster.hashfrag import HashFrag
 from swiftmpi_tpu.cluster.mesh import (MODEL_AXIS, SHARD_AXIS, MeshSpec,
                                        build_mesh, mesh_info, ps_mesh)
-from swiftmpi_tpu.ops import calibration
 from swiftmpi_tpu.parameter.access import AccessMethod
 from swiftmpi_tpu.parameter.key_index import KeyIndex
 from swiftmpi_tpu.parameter.sparse_table import SparseTable
@@ -98,17 +97,7 @@ class Cluster:
         frag_num = (self.config.get("server", "frag_num").to_int32()
                     if self.config.has("server", "frag_num") else None)
         self.hashfrag = HashFrag(n_servers, frag_num)
-        # [cluster] data_plane: pallas|xla|auto — steers the Pallas
-        # on-chip data plane (fused stencil gather, DMA ring push); the
-        # default "auto" defers to measured ops/calibration verdicts
-        self.data_plane = (
-            self.config.get("cluster", "data_plane").to_string()
-            if self.config.has("cluster", "data_plane") else "auto")
-        if self.data_plane not in calibration.DATA_PLANE_MODES:
-            raise ValueError(
-                f"[cluster] data_plane must be one of "
-                f"{calibration.DATA_PLANE_MODES}, got {self.data_plane!r}")
-        kwargs = ({"mesh": self.mesh, "data_plane": self.data_plane}
+        kwargs = ({"mesh": self.mesh}
                   if backend in ("tpu", "hybrid")
                   else {"shards": n_servers,
                         "platform": devices[0].platform,

@@ -284,10 +284,7 @@ def _negative_slots(key, alias_prob, alias_idx, slot_of_vocab, shape,
 def _cbow_targets(slot_of_vocab, alias_prob, alias_idx, centers,
                   contexts, ctx_mask, key, K):
     """Shared CBOW batch layout: draw the negatives and build the
-    target/context slot matrices + validity masks.  ONE copy used by
-    both the gather and dense renderings — their identical sampling
-    stream (the basis of the dense mode's parity guarantee) is
-    identical by construction, not by parallel maintenance."""
+    target/context slot matrices + validity masks."""
     with obs.named_scope("sample"):
         B = centers.shape[0]
         # fused draw: negatives and their table slots from ONE packed row
@@ -376,16 +373,6 @@ class Word2Vec:
         #: the rendering was resolved from what the model observes, not
         #: asked for: train() may still fall back to per-pair batches
         self._stencil_auto = False
-        # TPU-first opt-in with PARITY semantics: compute the NS phase
-        # through full (B, capacity) logits on the MXU instead of
-        # random row gathers (see _build_grads_dense) — same sampling
-        # stream, same math, different memory shape.  Default "auto":
-        # on a single TPU device with a recorded on-chip win for this
-        # rendering (ops/calibration, written by the chip session's
-        # step-level A/B) and a small table, use it; 0/1 force.
-        _dense_raw = g("word2vec", "dense_logits", "auto").to_string()
-        self.dense_logits = None if _dense_raw == "auto" \
-            else int(_dense_raw)
         self.alpha = g("word2vec", "learning_rate", 0.05).to_float()
         self.min_sentence_length = g(
             "word2vec", "min_sentence_length", 1).to_int32()
@@ -666,15 +653,14 @@ class Word2Vec:
         backend that pushes counted rows renders its contexts by span
         position (``stencil`` reads 1 from here on, visibly) — one
         algorithm, the context sum, rendered by position when the input
-        carries positions.  Skip-gram is per-pair by nature, multi-
-        process and hogwild batches are per-pair by construction, and
-        ``dense_logits: 1`` asks for another rendering.  ``train()``
-        settles it against the batcher it is handed (`_settle_stencil`)."""
+        carries positions.  Skip-gram is per-pair by nature, and multi-
+        process and hogwild batches are per-pair by construction.
+        ``train()`` settles it against the batcher it is handed
+        (`_settle_stencil`)."""
         if self.stencil:
             return
         self._stencil_auto = bool(
-            not self.sg and self.dense_logits != 1
-            and self.async_mode != "hogwild"
+            not self.sg and self.async_mode != "hogwild"
             and jax.process_count() == 1
             and getattr(self.transfer, "name", "") in ("xla", "hybrid"))
         self.stencil = int(self._stencil_auto)
@@ -1062,21 +1048,12 @@ class Word2Vec:
 
     def _build_apply_window(self):
         """Window analogue of :meth:`_build_apply`: each stacked (W, ...)
-        PushSpec family goes through ONE ``transfer.push_window`` call.
-        Dense (capacity-shaped) specs have no deferred-window semantics —
-        their grads are already normalized against live state — so
-        dense_logits mode is rejected at trace time rather than silently
-        de-coalesced."""
+        PushSpec family goes through ONE ``transfer.push_window`` call."""
         access = self.access
         transfer = self.transfer
 
         def apply_window(state, pushes):
             for spec in pushes:
-                if getattr(spec, "dense", False):
-                    raise ValueError(
-                        "[cluster] push_window > 1 cannot coalesce dense "
-                        "(capacity-shaped) pushes — disable [word2vec] "
-                        "dense_logits or set push_window: 1")
                 state = transfer.push_window(
                     state, spec.slots, spec.grads, access,
                     mean=spec.mean,
@@ -1233,10 +1210,6 @@ class Word2Vec:
                     "stencil is a CBOW-only rendering (span positions "
                     "index a center's context window); drop sg or "
                     "stencil")
-            if self.dense_logits:
-                raise ValueError(
-                    "dense_logits and stencil are two different "
-                    "renderings of the gather working set — pick one")
             if getattr(self.transfer, "name", "") not in ("xla", "hybrid"):
                 raise ValueError(
                     "the stencil rendering pushes its span family "
@@ -1249,43 +1222,15 @@ class Word2Vec:
             self.resolved_rendering = "stencil"
             return self._build_grads_stencil(shared=False, split=split)
         if self.sg:
-            if self.dense_logits:
-                raise ValueError(
-                    "dense_logits is a CBOW-only rendering; with sg: 1 "
-                    "the per-pair skip-gram phase would ignore it — "
-                    "drop one of the two flags")
             if self.shared_negatives:
                 self.resolved_rendering = "sg_shared"
                 return self._build_grads_sg_shared()
             self.resolved_rendering = "sg"
             return self._build_grads_sg()
-        if self.dense_logits and self.shared_negatives:
-            raise ValueError(
-                "dense_logits and shared_negatives are two different "
-                "renderings of the negative-sampling phase — pick one")
         if self.shared_negatives:
             self.resolved_rendering = "shared"
             return self._build_grads_shared()
-        dense = self.dense_logits
-        if dense is None:             # "auto": measurement-driven
-            from swiftmpi_tpu.ops import calibration
-
-            # the (B, capacity) buffers bound the regime; passed as the
-            # gate's `fits` so SMTPU_DENSE_LOGITS=1 force-on keeps the
-            # same semantics as the Pallas kernel gates (force beats
-            # every auto condition except fit)
-            fits = (self.table is not None
-                    and self.table.capacity <= 20_000)
-            dense = (getattr(self.transfer, "name", "")
-                     not in ("tpu", "hybrid")
-                     and calibration.gated("dense_logits",
-                                           "SMTPU_DENSE_LOGITS", fits))
-        # which rendering actually resolved — benches label their
-        # numbers with this so A/B verdicts can't compare mismatched
-        # baselines (the dense-promotion feedback-loop hazard)
-        self.resolved_rendering = "dense" if dense else "gather"
-        if dense:
-            return self._build_grads_dense()
+        self.resolved_rendering = "gather"
         access = self.access
         transfer = self.transfer
         K = self.negative
@@ -1330,95 +1275,6 @@ class Word2Vec:
                     h_contrib.reshape(-1, d), v_contrib.reshape(-1, d))
 
                 err_sum = jnp.sum(1e4 * g * g)          # word2vec.h:593
-                err_cnt = t_valid.sum()
-            return pushes, err_sum, err_cnt
-
-        return grads_fn
-
-    def _build_grads_dense(self):
-        """Dense-logits rendering of the parity CBOW-NS gradient phase.
-
-        SAME sampling stream, same clipped sigmoid, same mean-normalized
-        update semantics as ``_build_grads`` — only the memory shape of
-        the h (target) side changes.  The parity step is transaction-
-        bound on its B*(K+1) random row gather + scatter (measured
-        ~14ns/row, docs/ARCHITECTURE.md); with a small table
-        (demo.conf: 17K rows) the same math fits the MXU instead:
-
-            F      = neu1 @ h.T                  (B, cap) logits
-            f[b,k] = F[b, t[b,k]]                row-LOCAL pair gather
-            G      = scatter g into (B, cap)     row-local scalar scatter
-            h_grad = G.T @ neu1                  (cap, d) — ARRIVES DENSE
-            neu1e  = G @ h                       (B, d)
-
-        so the random-row traffic disappears entirely: the h push skips
-        the transfer scatter (PushSpec(dense=True)) and normalization
-        uses the scattered count plane.  Cost moves to O(B*cap) MXU
-        FLOPs + (B, cap) buffers, profitable exactly when cap is small
-        — the regime the reference's demo targets.  Decision data:
-        ``scripts/gather_micro.py --dense-only`` on chip.  Context
-        (v) side is unchanged — its B*2W gather is ~10x smaller.
-
-        Reference math being reproduced: word2vec.h:550-615 (the same
-        f/g/neu1e quantities, batched).
-        """
-        if getattr(self.transfer, "name", "") in ("tpu", "hybrid"):
-            raise ValueError(
-                "dense_logits computes the h-grad as a full-capacity "
-                "matmul and applies it directly — the 'tpu'/'hybrid' "
-                "backends' row-sharded routing doesn't apply (set "
-                "[cluster] transfer: xla)")
-        access = self.access
-        transfer = self.transfer
-        K = self.negative
-        alpha = self.alpha
-        d = self.row_width
-
-        def grads_fn(state, slot_of_vocab, alias_prob, alias_idx,
-                     centers, contexts, ctx_mask, key):
-            B, W2 = contexts.shape
-            cap = state["h"].shape[0]
-            t_slots, ctx_slots, t_valid = _cbow_targets(
-                slot_of_vocab, alias_prob, alias_idx, centers, contexts,
-                ctx_mask, key, K)
-            with obs.named_scope("sample"):
-                safe_t = jnp.clip(jnp.where(t_valid, t_slots, 0), 0,
-                                  cap - 1)
-
-            with obs.named_scope("math"):
-                v_ctx = transfer.pull(
-                    state, ctx_slots.reshape(-1), access, fields=("v",)
-                )["v"].reshape(B, W2, d).astype(jnp.float32)
-                neu1 = jnp.sum(v_ctx * ctx_mask[..., None], axis=1)  # (B, d)
-
-                h_all = state["h"].astype(jnp.float32)        # (cap, d)
-                F = neu1 @ h_all.T                            # (B, cap) MXU
-                f = jnp.take_along_axis(F, safe_t, axis=1)    # (B, K+1)
-                labels = jnp.concatenate(
-                    [jnp.ones((B, 1)), jnp.zeros((B, K))], axis=1)
-                g = (labels - sigmoid_clipped(f)) * alpha
-                g = jnp.where(t_valid, g, 0.0)
-
-                rows = jnp.arange(B)[:, None]
-                G = jnp.zeros((B, cap), jnp.float32).at[rows, safe_t].add(g)
-                # counts scatter straight to (cap,): 344K scalar adds are
-                # noise next to the three O(B*cap) matmuls, and a (B, cap)
-                # count plane would cost another ~1.1GB buffer at bench
-                # shape just to be row-summed away
-                counts = jnp.zeros((cap,), jnp.float32).at[
-                    safe_t.reshape(-1)].add(
-                    t_valid.reshape(-1).astype(jnp.float32), mode="drop")
-                h_grad = (G.T @ neu1) / jnp.maximum(counts, 1.0)[:, None]
-                neu1e = G @ h_all                             # (B, d)
-                v_contrib = jnp.where(ctx_mask[..., None],
-                                      neu1e[:, None, :], 0.0)
-
-                pushes = (PushSpec(None, {"h": h_grad}, dense=True),
-                          PushSpec(ctx_slots.reshape(-1),
-                                   {"v": v_contrib.reshape(-1, d)},
-                                   mean=True))
-
-                err_sum = jnp.sum(1e4 * g * g)
                 err_cnt = t_valid.sum()
             return pushes, err_sum, err_cnt
 
@@ -1980,15 +1836,7 @@ class Word2Vec:
 
         def apply_fn(state, pushes):
             for spec in pushes:
-                if getattr(spec, "dense", False):
-                    # capacity-shaped, pre-normalized grads (dense-logits
-                    # mode): apply the access rule directly — untouched
-                    # rows carry exact zero and are no-ops
-                    with obs.named_scope("apply"):
-                        new_fields = access.apply_push(state, spec.grads)
-                    state = dict(state)
-                    state.update(new_fields)
-                elif getattr(spec, "counts", None) is not None:
+                if getattr(spec, "counts", None) is not None:
                     # position-indexed span family (stencil rendering):
                     # rows are pre-summed, their data counts the mean's
                     # multiplicity

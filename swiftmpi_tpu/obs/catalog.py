@@ -16,9 +16,8 @@ Two declaration forms:
   the catalog pins the *name* half of the contract that
   docs/ARCHITECTURE.md "Telemetry plane" documents in prose.
 * :data:`PREFIXES` — dynamic families built with f-strings whose
-  stem is static (``control/<knob-name>`` gauges, the microbench
-  ``micro_<gauge>`` context scalars).  The lint rule checks an
-  f-string's leading literal chunk against these.
+  stem is static (``control/<knob-name>`` gauges).  The lint rule
+  checks an f-string's leading literal chunk against these.
 
 ``transfer/`` series are declared via :data:`TRANSFER_KEYS` (the bare
 ledger key, as passed to ``Transfer._obs_inc``) and expanded into
@@ -125,7 +124,6 @@ SERIES = frozenset({
 #: check when its leading literal chunk starts with one of these.
 PREFIXES = (
     "control/",     # per-knob gauges: control/<knob.name>
-    "micro_",       # microbench context gauges: micro_<key>{cell=}
 )
 
 
@@ -136,8 +134,7 @@ PREFIXES = (
 #: program (``obs.costs.phase_map``), the profiler-window reduction
 #: (``obs.profiler``) and the benchmark's ``trace_scope`` reader all read
 #: this dict and nothing else.  The tpu/hybrid backends' window dedup is
-#: the same phase as the xla backend's sort/segment-sum dedup, and the
-#: Pallas ring push is one rendering of the wire exchange.
+#: the same phase as the xla backend's sort/segment-sum dedup.
 DEVICE_SCOPES = {
     "sample": "sample",            # negative draw + slot lookups + masks
     "pull": "pull",                # Transfer.pull: the row gather
@@ -146,7 +143,6 @@ DEVICE_SCOPES = {
     "window_dedup": "dedup",       # transfer/tpu.py, hybrid.py
     "apply": "apply",              # row read-modify-write, AdaGrad
     "wire_exchange": "wire_exchange",
-    "pallas_ring_push": "wire_exchange",
     # the autodiff LM step (models/transformer.py, models/trainer.py): a
     # backward instruction carries its forward scope inside the
     # transform's name (transpose(jvp(experts))) and books there too
@@ -162,7 +158,7 @@ DEVICE_SCOPES = {
     "experts": "experts",          # the grouped products over held experts
     # jax.lax.ragged_dot as the TPU compiler renders it: a custom call
     # whose op_name the compiler's expansion replaces with its own (a
-    # kernel's name, like pallas_ring_push; only the experts call it)
+    # kernel's name; only the experts call it)
     "ragged-dot-none": "experts",
     "ragged-dot-metadata": "experts",
     "shared_expert": "shared_expert",   # the experts every token runs

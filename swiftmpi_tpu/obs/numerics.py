@@ -163,15 +163,12 @@ def state_stats(before: dict, after: dict, grad_fields):
 
 def spec_stats(pushes, n_hot: int):
     """Fold :func:`push_stats` over one step's PushSpec list (also
-    accepts scan-stacked specs — the reductions are shape-agnostic).
-    Dense capacity-shaped specs have no slot identity; they count as
-    all-tail."""
+    accepts scan-stacked specs — the reductions are shape-agnostic)."""
     sq = jnp.zeros((), jnp.float32)
     hot = jnp.zeros((), jnp.float32)
     nf = jnp.zeros((), jnp.int32)
     for spec in pushes:
-        slots = None if getattr(spec, "dense", False) else spec.slots
-        s, h, n = push_stats(slots, spec.grads, n_hot)
+        s, h, n = push_stats(spec.slots, spec.grads, n_hot)
         sq, hot, nf = sq + s, hot + h, nf + n
     return sq, hot, nf
 

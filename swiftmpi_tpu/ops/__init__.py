@@ -1,4 +1,4 @@
-"""Device-side ops: sampling, activation kernels, (later) Pallas kernels."""
+"""Device-side ops: sampling, activation kernels."""
 
 from swiftmpi_tpu.ops.sampling import (build_unigram_alias, sample_alias,
                                        subsample_keep_prob)

@@ -190,32 +190,6 @@ class AdaGradAccess(AccessMethod):
         return tuple(out)
 
 
-class PallasAdaGradAccess(AdaGradAccess):
-    """AdaGradAccess with the update rule executed by the fused Pallas TPU
-    kernel (ops/pallas_kernels.adagrad_update).  The kernel declares
-    input/output aliasing; the update is truly in-place when the enclosing
-    training step donates the table state (as ``Word2Vec._build_step``
-    does).  Numerics identical to the base rule; interpret mode keeps it
-    runnable on CPU."""
-
-    def apply_push(self, params, grads):
-        from swiftmpi_tpu.ops.pallas_kernels import (adagrad_update,
-                                                     default_interpret)
-        interpret = default_interpret()
-        out = {}
-        for r in self.rules:
-            if r.grad not in grads:
-                continue
-            g = grads[r.grad].astype(jnp.float32)
-            p2, a2 = adagrad_update(
-                params[r.param], params[r.accum], g,
-                lr=self.learning_rate, fudge=self.fudge_factor,
-                interpret=interpret)
-            out[r.param] = p2
-            out[r.accum] = a2
-        return out
-
-
 def lr_access(learning_rate: float) -> AdaGradAccess:
     """Logistic-regression row: scalar weight + grad²-sum
     (reference LRParam, lr.cpp:14-22,60-81)."""

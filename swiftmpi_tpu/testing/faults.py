@@ -43,6 +43,8 @@ from swiftmpi_tpu.utils.logger import get_logger
 log = get_logger(__name__)
 
 ENV_FAULT_PLAN = "SMTPU_FAULT_PLAN"
+# ceiling of a fault's wait for its ``after`` event: it then fires anyway
+_AFTER_TIMEOUT_S = 60.0
 
 
 def _obs_count(name: str, **labels) -> None:
@@ -75,6 +77,9 @@ class Fault:
     signum: int = int(signal.SIGKILL)   # kill: signal to self-deliver
     max_fires: int = 1              # in-process fire budget
     marker: Optional[str] = None    # cross-process once-only marker file
+    after: Optional[str] = None     # fire only once this file exists: the
+    #                                 step waits for it (an event another
+    #                                 rank's marker signals, not a clock)
     fires: int = 0                  # in-memory count (not serialised intent)
 
     def _armed(self) -> bool:
@@ -85,6 +90,18 @@ class Fault:
         if self.marker and os.path.exists(self.marker):
             return False
         return True
+
+    def _await(self) -> None:
+        if not self.after:
+            return
+        give_up = time.monotonic() + _AFTER_TIMEOUT_S
+        while not os.path.exists(self.after):
+            if time.monotonic() >= give_up:
+                log.warning("fault injection: %s never appeared in %.0fs; "
+                            "firing %s anyway", self.after,
+                            _AFTER_TIMEOUT_S, self.kind)
+                return
+            time.sleep(0.01)
 
     def _record_fire(self) -> None:
         self.fires += 1
@@ -155,11 +172,15 @@ class FaultPlan:
 
     def hang_at_step(self, step: int, seconds: float,
                      rank: Optional[int] = None,
-                     marker: Optional[str] = None) -> "FaultPlan":
+                     marker: Optional[str] = None,
+                     after: Optional[str] = None) -> "FaultPlan":
         """Stall ``seconds`` at the top of step ``step`` — the injectable
-        stand-in for a hung device / stuck collective."""
+        stand-in for a hung device / stuck collective.  ``after``: wait
+        there for that file first (another fault's ``marker``, which is
+        written as it fires), so drills order their faults across ranks
+        by event; ``seconds=0`` with a ``marker`` only signals one."""
         self.faults.append(Fault("hang", step=step, seconds=seconds,
-                                 rank=rank, marker=marker))
+                                 rank=rank, marker=marker, after=after))
         return self
 
     def corrupt_checkpoint(self, at_save: Optional[int] = None,
@@ -176,13 +197,15 @@ class FaultPlan:
 
     def kill_rank(self, rank: int, at_step: int,
                   signum: int = int(signal.SIGKILL),
-                  marker: Optional[str] = None) -> "FaultPlan":
+                  marker: Optional[str] = None,
+                  after: Optional[str] = None) -> "FaultPlan":
         """Self-deliver ``signum`` on rank ``rank`` at step ``at_step`` —
         the launcher-facing fault: no exception, no cleanup, the process
         is simply gone (pass a ``marker`` path so the supervised restart
-        does not re-fire it)."""
+        does not re-fire it; ``after`` as for :meth:`hang_at_step`)."""
         self.faults.append(Fault("kill", step=at_step, rank=rank,
-                                 signum=int(signum), marker=marker))
+                                 signum=int(signum), marker=marker,
+                                 after=after))
         return self
 
     def nan_at_step(self, step: int, rank: Optional[int] = None,
@@ -206,6 +229,7 @@ class FaultPlan:
                 continue
             if not f._armed():
                 continue
+            f._await()
             f._record_fire()
             _obs_count("faults/injected", kind=f.kind)
             if f.kind == "hang":

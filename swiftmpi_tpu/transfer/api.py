@@ -267,17 +267,11 @@ def quant_pull_row_bytes(state, fields, quant: str) -> int:
 class PushSpec:
     """One gradient-family push: ``(slots, grads, mean)``.
 
-    A pytree whose ``mean``/``dense`` flags are static aux data, so a
-    jitted function taking pushes as an argument (e.g. the async
-    snapshot mode's ``jit(apply_fn)(state, pushes)``) sees concrete
-    Python bools, not traced scalars.  Iterates like the plain 3-tuple
-    it replaces.
-
-    ``dense=True`` marks grads that are ALREADY capacity-shaped and
-    normalized (e.g. the dense-logits w2v mode computes the h-grad as
-    a (capacity, d) matmul output): the apply step feeds them straight
-    to the access method, skipping the transfer's scatter/dedup —
-    ``slots`` is unused and should be None.
+    A pytree whose ``mean`` flag is static aux data, so a jitted
+    function taking pushes as an argument (e.g. the async snapshot
+    mode's ``jit(apply_fn)(state, pushes)``) sees a concrete Python
+    bool, not a traced scalar.  Iterates like the plain 3-tuple it
+    replaces.
 
     ``counts`` (non-None) marks a POSITION-INDEXED span family (the
     stencil w2v rendering): each row already carries the sum of its
@@ -286,24 +280,21 @@ class PushSpec:
     1-per-row, and the apply step routes through ``push_span``: the
     push with that multiplicity."""
 
-    def __init__(self, slots, grads, mean: bool = False,
-                 dense: bool = False, counts=None):
+    def __init__(self, slots, grads, mean: bool = False, counts=None):
         self.slots = slots
         self.grads = grads
         self.mean = bool(mean)
-        self.dense = bool(dense)
         self.counts = counts
 
     def __iter__(self):
         return iter((self.slots, self.grads, self.mean))
 
     def tree_flatten(self):
-        return (self.slots, self.grads, self.counts), (self.mean, self.dense)
+        return (self.slots, self.grads, self.counts), self.mean
 
     @classmethod
-    def tree_unflatten(cls, aux, children):
-        mean, dense = aux
-        return cls(children[0], children[1], mean, dense, children[2])
+    def tree_unflatten(cls, mean, children):
+        return cls(children[0], children[1], mean, children[2])
 
 
 class Transfer:

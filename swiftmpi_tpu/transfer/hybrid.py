@@ -50,12 +50,10 @@ class HybridTransfer(Transfer):
 
     def __init__(self, mesh: Mesh, axis: str = SHARD_AXIS,
                  bucket_capacity: Optional[int] = None,
-                 debug_overflow: bool = False,
-                 data_plane: str = "auto"):
+                 debug_overflow: bool = False):
         self.mesh = mesh
         self.axis = axis
-        self.tail = TpuTransfer(mesh, axis, bucket_capacity, debug_overflow,
-                                data_plane=data_plane)
+        self.tail = TpuTransfer(mesh, axis, bucket_capacity, debug_overflow)
         self._hot_push_cache: Dict = {}
         self._hot_total = 0
         self._psum_bytes_total = 0

@@ -36,23 +36,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from swiftmpi_tpu.cluster.mesh import DATA_AXIS, SHARD_AXIS
 from swiftmpi_tpu.obs import costs as obs_costs
-from swiftmpi_tpu.ops import (calibration, pallas_gather, pallas_ring,
-                              pallas_scatter)
 from swiftmpi_tpu.parameter.sparse_table import ROWVER_KEY
 from swiftmpi_tpu.transfer.api import Transfer, grad_row_bytes
-
-
-def _shard_gather(arr: jax.Array, flat_idx: jax.Array) -> jax.Array:
-    """Per-shard row gather; routes through the VMEM-resident Pallas
-    kernel when the single-chip verdict says it wins.  ``manual=True``:
-    this is called inside ``shard_map``, where ``arr`` is the device-
-    local shard — no partitioner hazard, and the per-core shard is even
-    smaller than the single-chip table the verdict was measured on."""
-    if calibration.gated("vmem_gather", "SMTPU_PALLAS_GATHER",
-                         pallas_gather.fits_vmem(arr), manual=True):
-        return pallas_gather.masked_vmem_gather(
-            arr, flat_idx, jnp.ones(flat_idx.shape, bool))
-    return jnp.take(arr, flat_idx, axis=0)
 
 
 def _bucketize(slots_l: jax.Array, n: int, cap_per_shard: int, C: int):
@@ -84,8 +69,7 @@ class TpuTransfer(Transfer):
 
     def __init__(self, mesh: Mesh, axis: str = SHARD_AXIS,
                  bucket_capacity: Optional[int] = None,
-                 debug_overflow: bool = False,
-                 data_plane: str = "auto"):
+                 debug_overflow: bool = False):
         """``bucket_capacity``: per-destination request slots; defaults to
         the full local batch (no overflow possible).  Smaller values cut
         all_to_all volume ~proportionally but drop overflow requests —
@@ -97,21 +81,10 @@ class TpuTransfer(Transfer):
         readable via :meth:`overflow_count` (and mirrored into ``metrics``
         if one is attached).  With ``debug_overflow=True`` each call
         synchronously checks the count and raises — slow, but turns silent
-        training corruption into an immediate failure.
-
-        ``data_plane``: the ``[cluster] data_plane:`` knob (``auto`` /
-        ``pallas`` / ``xla``) steering the push wire exchange between
-        ``all_to_all`` and the Pallas DMA ring
-        (ops/pallas_ring.py) — resolved per measured calibration
-        verdict by :func:`pallas_ring.use_ring_push`."""
+        training corruption into an immediate failure."""
         self.mesh = mesh
         self.axis = axis
         self.n = int(mesh.shape[axis])
-        if data_plane not in calibration.DATA_PLANE_MODES:
-            raise ValueError(
-                f"data_plane must be one of "
-                f"{calibration.DATA_PLANE_MODES}, got {data_plane!r}")
-        self.data_plane = data_plane
         # hybrid multi-host mesh (ps_mesh(hybrid=True)): a leading data
         # axis across processes/DCN.  Each data group holds a full table
         # replica and routes requests over its own shard axis (ICI); the
@@ -334,7 +307,7 @@ class TpuTransfer(Transfer):
             safe = jnp.where(ok, got, 0)
             out = {}
             for f in fields:
-                rows = _shard_gather(state_l[f], safe.reshape(-1))
+                rows = jnp.take(state_l[f], safe.reshape(-1), axis=0)
                 rows = rows.reshape(self.n, C, -1) * ok[..., None]
                 resp = jax.lax.all_to_all(rows, self.axis, 0, 0, tiled=True)
                 vals = resp[jnp.clip(so, 0, self.n - 1),
@@ -531,16 +504,9 @@ class TpuTransfer(Transfer):
             for f in grad_fields:
                 g = jnp.asarray(grads_l[f])
                 width = g.shape[1]
-                if calibration.gated(
-                        "vmem_scatter", "SMTPU_PALLAS_SCATTER",
-                        pallas_scatter.fits_vmem(capacity, width),
-                        manual=True):
-                    acc = pallas_scatter.masked_vmem_scatter_add(
-                        slots_l, valid, g, capacity)
-                else:
-                    acc = jnp.zeros((capacity, width), g.dtype).at[
-                        safe].add(g * valid[:, None].astype(g.dtype),
-                                  mode="drop")
+                acc = jnp.zeros((capacity, width), g.dtype).at[
+                    safe].add(g * valid[:, None].astype(g.dtype),
+                              mode="drop")
                 # the ONE exchange of the window: tiled reduce-scatter
                 # lands each shard's summed slice on its owner directly
                 with jax.named_scope("wire_exchange"):
@@ -593,17 +559,8 @@ class TpuTransfer(Transfer):
         out_specs = (state_specs, P()) if counted else state_specs
 
         dp = int(self.mesh.shape[self.dp_axis]) if self.dp_axis else 1
-        # wire-exchange routing, resolved at trace time: the Pallas DMA
-        # ring replaces both all_to_all rounds when the data_plane knob
-        # / measured ring_push verdict says so (1-D mesh only — see
-        # ops/pallas_ring.py on LOGICAL device ids)
-        use_ring = pallas_ring.use_ring_push(
-            self.n, self.dp_axis is None, self.data_plane)
 
-        def _wire_exchange(x, ring=None):
-            if use_ring if ring is None else ring:
-                with jax.named_scope("pallas_ring_push"):
-                    return pallas_ring.ring_exchange(x, self.axis, self.n)
+        def _wire_exchange(x):
             with jax.named_scope("wire_exchange"):
                 return jax.lax.all_to_all(x, self.axis, 0, 0, tiled=True)
 
@@ -616,8 +573,7 @@ class TpuTransfer(Transfer):
             req, order, so, idx = _bucketize(
                 slots_l, self.n, cap_per_shard, C)
             # phase names match obs.span()/telemetry: the collectives are
-            # "wire_exchange" (or "pallas_ring_push" when the DMA ring
-            # is routed), the owner-side access update is "apply" —
+            # "wire_exchange", the owner-side access update is "apply" —
             # host timing is meaningless inside jit, so the device trace
             # carries the names instead (docs/ARCHITECTURE.md).
             got = _wire_exchange(req)
@@ -669,13 +625,7 @@ class TpuTransfer(Transfer):
                     col_idx = jnp.clip(idx, 0, C - 1)
                     bucket = bucket.at[row_idx, col_idx].set(
                         g[order], mode="drop")
-                    # the width-1 counts bucket always rides all_to_all: its
-                    # bytes are noise next to the d-wide grad buckets, and
-                    # inv-scaling ring-fed grad sums by a ring-fed counts
-                    # column trips an XLA reshape CHECK during the interpret
-                    # discharge (jaxlib 0.4.x, array.h new_num_elements)
-                    recv = _wire_exchange(
-                        bucket, ring=use_ring and f != "__counts__")
+                    recv = _wire_exchange(bucket)
                     if sparse_dcn:
                         # batch-proportional DCN traffic: every group's
                         # received pairs, applied by everyone identically

@@ -30,15 +30,10 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from swiftmpi_tpu import obs
-from swiftmpi_tpu.ops import calibration, pallas_gather, pallas_scatter
 from swiftmpi_tpu.parameter.sparse_table import is_hot_field
 from swiftmpi_tpu.transfer import route
 from swiftmpi_tpu.transfer.api import (Transfer, bump_row_versions,
                                        grad_row_bytes)
-
-# replica-spread scatter: cap the R-fold temporary at ~256MB so the
-# measured-win gate can never OOM a large table's push
-_REPLICA_BUDGET_BYTES = 256 << 20
 
 # Row write-back of the sparse push (`_set_rows`): XLA's TPU scatter has
 # two costs, and the `indices_are_sorted` hint alone picks between them
@@ -74,24 +69,8 @@ _ROW_WRITE_AS_SWEPT_BYTES = 31_000
 _HEAD_CHUNK = 2048
 
 
-def _replica_R(capacity: int, width: int) -> int:
-    """Recorded replica factor for this device kind, bounded by the
-    temporary-buffer budget; 0 = no win recorded (gate closed)."""
-    v = calibration.lookup("replica_scatter", calibration.device_key()) \
-        if calibration.on_tpu() else None
-    R = int((v or {}).get("R", 0)) if (v or {}).get("win") else 0
-    if R and R * capacity * width * 4 > _REPLICA_BUDGET_BYTES:
-        return 0
-    return R
-
-
 def _masked_gather(arr: jax.Array, slots: jax.Array,
                    valid: jax.Array) -> jax.Array:
-    # VMEM-resident Pallas gather when the on-chip A/B verdict says it
-    # beats XLA's transaction-bound HBM gather (ops/pallas_gather.py;
-    # absent a recorded win this branch never taken)
-    if pallas_gather.use_vmem_gather(arr):
-        return pallas_gather.masked_vmem_gather(arr, slots, valid)
     # clip: an out-of-range slot is a caller bug, but TPU OOB gather yields
     # garbage/NaN rather than trapping — clamp so it stays observable as a
     # wrong row, not as NaN contamination.
@@ -378,24 +357,6 @@ class XlaTransfer(Transfer):
         safe = jnp.where(valid, slots, capacity)
 
         def _scatter(g, width):
-            # VMEM-resident Pallas scatter when the on-chip A/B verdict
-            # says it beats XLA's (ops/pallas_scatter.py; never taken
-            # without a recorded win)
-            if pallas_scatter.use_vmem_scatter(capacity, width):
-                return pallas_scatter.masked_vmem_scatter_add(
-                    slots, valid, g, capacity)
-            # replica-spread when the on-chip A/B crowned it (round-3:
-            # the ~20x-duplicated w2v push serializes RMW chains; R
-            # replica tables shorten chains R-fold, one streaming sum
-            # folds them back; scripts/scatter_micro.py records the
-            # verdict, gate closed without a win or past the budget)
-            R = _replica_R(capacity, width)
-            if R:
-                lane = jax.lax.rem(
-                    jnp.arange(g.shape[0], dtype=jnp.int32), R)
-                acc = jnp.zeros((R, capacity, width), g.dtype).at[
-                    lane, safe].add(g, mode="drop")
-                return acc.sum(axis=0)
             acc = jnp.zeros((capacity, width), g.dtype)
             return acc.at[safe].add(g, mode="drop")
 

@@ -17,22 +17,18 @@ import chip_smoke  # noqa: E402
 
 def test_phases_run_at_toy_size(tmp_path, monkeypatch, devices8):
     """Every phase — corpus, conf, loader, build, train (single-step
-    and fused scan), row / placement checks, save + read-back, Pallas
-    kernels (interpret mode) and the ring — through the same ``run`` the
-    chip executes at full width."""
+    and fused scan), row / placement checks, save + read-back — through
+    the same ``run`` the chip executes at full width."""
     # an externally placed cache directory is left alone (and this test
     # must not arm the persistent cache for the rest of the session)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
     out = chip_smoke.run(
         str(tmp_path / "out"), "cpu", vocab_size=2000, zipf_tokens=20_000,
-        max_tokens=None, fused_tokens=6000, len_vec=16, kernel_batch=256,
-        kernel_rows=512, interpret=True)
+        max_tokens=None, fused_tokens=6000, len_vec=16)
     assert out["loader"] in ("native", "python")
     assert len(out["losses"]) == 3 and len(out["fused_losses"]) == 2
     assert np.isfinite(out["losses"]).all()
     assert out["losses"][-1] < out["losses"][0]
-    assert out["kernels"] == ["adagrad_update", "vmem_gather",
-                              "vmem_scatter"]
     # 4 fields x capacity x len_vec x f32, split over the 8-device mesh
     assert out["table_bytes"] % (4 * 16 * 4 * 8) == 0
     assert out["peak_bytes"] is None          # XLA:CPU reports none

@@ -345,14 +345,20 @@ def test_fleet_acceptance_stall_and_kill_drill(tmp_path):
     from swiftmpi_tpu.testing.faults import FaultPlan
 
     fleet = str(tmp_path / "fleet")
-    # Drill geometry: the hang (rank 1, 0.8s at step 5) ENDS well before
-    # rank 2's kill at step 55 (~1.1s+overhead in), so rank 1 has
-    # recorded the hang step — and a few after it — by the time the
-    # teardown SIGTERM arrives.  The hang step then dominates the
-    # common aligned range, making straggler attribution deterministic.
+    # Drill geometry, ordered by events (marker files), not by how long a
+    # rank takes to start on a busy host: rank 2's kill at step 55 waits
+    # until rank 1 has recorded its hang (0.8s at step 5) and a few steps
+    # after it — the hang step then dominates the common aligned range,
+    # making straggler attribution deterministic — and rank 1 waits at
+    # step 58 for the kill, then stalls until the teardown sweeps it.
+    recorded = str(tmp_path / "hang_recorded")
+    killed = str(tmp_path / "rank2_killed")
     plan = (FaultPlan()
             .hang_at_step(5, seconds=0.8, rank=1)
-            .kill_rank(2, at_step=55, signum=int(signal.SIGTERM)))
+            .hang_at_step(9, seconds=0.0, rank=1, marker=recorded)
+            .kill_rank(2, at_step=55, signum=int(signal.SIGTERM),
+                       marker=killed, after=recorded)
+            .hang_at_step(58, seconds=30.0, rank=1, after=killed))
     os_env = {
         "SMTPU_FAULT_PLAN": plan.to_json(),
         "SMTPU_FLEET_STEPS": "60", "SMTPU_FLEET_STEP_S": "0.02",
@@ -390,8 +396,8 @@ def test_fleet_acceptance_stall_and_kill_drill(tmp_path):
     assert exits2 and exits2[-1]["rc"] == 143
     assert exits2[-1]["by_supervisor"] is False
     # the launcher's teardown kills are attributed AS teardown kills —
-    # rank 1 is mid-recovery from the hang when rank 2 dies, so it is
-    # guaranteed to still be running when the teardown sweeps it
+    # rank 1 waits for rank 2's death, so it is still running when the
+    # teardown sweeps it
     assert any(e["by_supervisor"]
                for e in fc.members()["1"]["exits"])
     # every death is supervised -> the unnoticed-death gate stays quiet

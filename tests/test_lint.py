@@ -260,10 +260,9 @@ def test_telemetry_trips_on_undeclared_series(tmp_path):
 
 def test_telemetry_passes_on_declared_series_and_prefix(tmp_path):
     new = lint_src(tmp_path, "pkg/thing.py", """
-    def record(reg, knob, k):
+    def record(reg, knob):
         reg.histogram("phase_ms").observe(1.0)
         reg.gauge(f"control/{knob}").set(2)
-        reg.gauge(f"micro_{k}", cell="c").set(3)
     """)
     assert new == []
 

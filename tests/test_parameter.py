@@ -47,22 +47,31 @@ def test_key_index_capacity_error():
 
 # -- access methods -------------------------------------------------------
 
-def test_adagrad_matches_reference_math():
+@pytest.mark.parametrize("shape", [None, (64, 100), (1000, 100), (7, 3),
+                                   (513, 1)])
+def test_adagrad_matches_reference_math(shape):
     # Reference WPushAccessMethod (word2vec.h:177-185):
     #   h2sum += g^2 ; h += lr * g / sqrt(h2sum + 1e-6)
-    access = w2v_access(learning_rate=0.7, len_vec=3)
-    params = {
-        "h": np.array([[1.0, 2.0, 3.0]], np.float32),
-        "h2sum": np.array([[0.5, 0.5, 0.5]], np.float32),
-        "v": np.zeros((1, 3), np.float32),
-        "v2sum": np.zeros((1, 3), np.float32),
-    }
-    g = np.array([[0.1, -0.2, 0.3]], np.float32)
-    out = access.apply_push(params, {"h": g, "v": np.zeros((1, 3), np.float32)})
-    h2sum = 0.5 + g**2
-    expected_h = params["h"] + 0.7 * g / np.sqrt(h2sum + 1e-6)
+    # ``None``: one hand-written row; a shape: a batch of random rows of
+    # that width (the table's, a one-wide logistic row, a ragged count)
+    if shape is None:
+        h = np.array([[1.0, 2.0, 3.0]], np.float32)
+        h2sum0 = np.full((1, 3), 0.5, np.float32)
+        g = np.array([[0.1, -0.2, 0.3]], np.float32)
+    else:
+        rng = np.random.default_rng(1)
+        h = rng.normal(size=shape).astype(np.float32)
+        h2sum0 = np.abs(rng.normal(size=shape)).astype(np.float32)
+        g = rng.normal(size=shape).astype(np.float32)
+    access = w2v_access(learning_rate=0.7, len_vec=h.shape[1])
+    params = {"h": h, "h2sum": h2sum0,
+              "v": np.zeros_like(h), "v2sum": np.zeros_like(h)}
+    out = access.apply_push(params, {"h": g, "v": np.zeros_like(h)})
+    h2sum = h2sum0 + g**2
+    expected_h = h + 0.7 * g / np.sqrt(h2sum + 1e-6)
     np.testing.assert_allclose(np.asarray(out["h2sum"]), h2sum, rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(out["h"]), expected_h, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(out["h"]), expected_h,
+                               rtol=1e-5, atol=1e-6)
     # v got zero grad: exact no-op
     np.testing.assert_array_equal(np.asarray(out["v"]), params["v"])
     np.testing.assert_array_equal(np.asarray(out["v2sum"]), params["v2sum"])

@@ -96,32 +96,82 @@ def test_push_mean_equivalence(w2v_setup):
                 err_msg=f"{backend.name}:{f}")
 
 
-def test_push_replica_scatter_gate_matches_plain(w2v_setup, monkeypatch):
-    """With a (simulated) recorded replica_scatter win, the dense push
-    routes through R replica tables + a fold-back sum — results must be
-    bit-close to the ungated scatter; and the gate must stay closed on
-    budget overflow."""
-    from swiftmpi_tpu.ops import calibration
-    from swiftmpi_tpu.transfer import xla as xla_mod
+KERNEL_SHAPES = [(64, 100), (1000, 100), (7, 3), (513, 1)]
 
-    mesh, access, table, slots, grads, state_np = w2v_setup
-    want = XlaTransfer(dense_apply=True).push(
-        table.state, slots, grads, access, mean=True)
-    monkeypatch.setattr(calibration, "on_tpu", lambda: True)
-    monkeypatch.setattr(calibration, "device_key", lambda: "fake-tpu")
-    monkeypatch.setattr(
-        calibration, "lookup",
-        lambda name, key: {"win": True, "R": 4}
-        if name == "replica_scatter" else None)
-    assert xla_mod._replica_R(100, 10) == 4
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", KERNEL_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_masked_gather_contract(shape, dtype):
+    """The row gather under every pull: an invalid slot gives a zero row,
+    an out-of-range slot is clipped to a real row (a wrong row that can
+    be seen, never NaN), a repeated slot repeats its row."""
+    from swiftmpi_tpu.transfer.xla import _masked_gather
+
+    rows, width = shape
+    rng = np.random.default_rng(3)
+    arr = jnp.asarray(rng.normal(size=shape), dtype)
+    slots = rng.integers(0, rows, 96)
+    slots[10:20] = slots[0]                     # repeats
+    slots[20:24] = [rows, rows + 7, -5, 10 * rows]   # out of range
+    valid = rng.random(96) < 0.8
+    valid[:24] = True
+    valid[24:30] = False
+    got = np.asarray(_masked_gather(
+        arr, jnp.asarray(slots, jnp.int32), jnp.asarray(valid)
+    ).astype(jnp.float32))
+    table = np.asarray(arr.astype(jnp.float32))
+    want = np.where(valid[:, None],
+                    table[np.clip(slots, 0, rows - 1)], 0.0)
+    assert got.shape == (96, width)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("mean", [False, True], ids=["sum", "mean"])
+@pytest.mark.parametrize("shape", KERNEL_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_push_dense_matches_segment_sum(shape, mean):
+    """The dense push's one scatter: duplicates sum (or average), masked
+    slots drop, and the access rule sees the per-row result — a NumPy
+    segment sum followed by ``apply_push``."""
+    from swiftmpi_tpu.parameter.access import (AdaGradAccess, AdaGradRule,
+                                               FieldSpec, zeros_init)
+
+    rows, width = shape
+    rng = np.random.default_rng(4)
+    access = AdaGradAccess(
+        0.3, rules=(AdaGradRule("w", "w2sum", "w"),),
+        fields={"w": FieldSpec(width, zeros_init),
+                "w2sum": FieldSpec(width, zeros_init)},
+        pull_fields=("w",))
+    state = {"w": rng.normal(size=shape).astype(np.float32),
+             "w2sum": np.abs(rng.normal(size=shape)).astype(np.float32)}
+    slots = rng.integers(0, rows, 200)
+    slots[:40] = slots[40]                      # one heavy duplicate
+    slots[rng.random(200) < 0.15] = -1          # masked
+    grads = rng.normal(size=(200, width)).astype(np.float32)
+
+    summed = np.zeros(shape, np.float32)
+    counts = np.zeros(rows, np.float32)
+    np.add.at(summed, slots[slots >= 0], grads[slots >= 0])
+    np.add.at(counts, slots[slots >= 0], 1.0)
+    if mean:
+        summed = summed / np.maximum(counts, 1.0)[:, None]
+    want = dict(state)
+    want.update(access.apply_push(state, {"w": summed}))
+
     got = XlaTransfer(dense_apply=True).push(
-        table.state, slots, grads, access, mean=True)
-    for f in access.fields:
-        np.testing.assert_allclose(
-            np.asarray(want[f]), np.asarray(got[f]), rtol=1e-5,
-            atol=1e-6, err_msg=f)
-    # budget: R * capacity * width * 4 over ~256MB closes the gate
-    assert xla_mod._replica_R(1 << 20, 128) == 0
+        {f: jnp.asarray(v) for f, v in state.items()},
+        jnp.asarray(slots, jnp.int32), {"w": jnp.asarray(grads)}, access,
+        mean=mean)
+    for f in want:
+        np.testing.assert_allclose(np.asarray(got[f]), np.asarray(want[f]),
+                                   rtol=1e-5, atol=1e-5, err_msg=f)
+    # rows no valid slot named are bit-identical
+    untouched = counts == 0
+    np.testing.assert_array_equal(np.asarray(got["w"])[untouched],
+                                  state["w"][untouched])
 
 
 def test_tpu_backend_batch_not_a_multiple_of_devices(w2v_setup):
@@ -457,33 +507,6 @@ def test_tpu_backend_hybrid_sparse_dcn_push(devices8):
     assert re.search(r"all_gather[^\n]*tensor<2x32x", txt)
     n_ar_dense, _, _ = collectives(64)        # dense regime
     assert n_ar_dense > 0, "dense regime should still psum"
-
-
-def test_tpu_backend_pull_with_pallas_shard_gather(monkeypatch,
-                                                   devices8):
-    """The shard-local VMEM gather (forced on; interpret mode inside
-    shard_map) must reproduce the plain take-based pull exactly."""
-    from jax.sharding import Mesh
-
-    mesh = Mesh(np.asarray(jax.devices()[:4]), (SHARD_AXIS,))
-    access = w2v_access(learning_rate=0.3, len_vec=8)
-    ki = KeyIndex(num_shards=4, capacity_per_shard=64)
-    table = SparseTable(access, ki, mesh=mesh, axis=SHARD_AXIS)
-    slots = slots_with_padding(ki, 48)
-    state_np = {f: np.asarray(v) for f, v in table.state.items()}
-
-    monkeypatch.setenv("SMTPU_PALLAS_GATHER", "0")
-    want = TpuTransfer(mesh).pull(table.state, slots, access)
-    monkeypatch.setenv("SMTPU_PALLAS_GATHER", "1")
-    got = TpuTransfer(mesh).pull(table.state, slots, access)
-    for f in want:
-        np.testing.assert_allclose(np.asarray(got[f]),
-                                   np.asarray(want[f]), rtol=1e-6,
-                                   err_msg=f)
-    # and both match the oracle
-    ref = LocalTransfer().pull(state_np, slots, access)
-    for f in ref:
-        np.testing.assert_allclose(np.asarray(got[f]), ref[f], rtol=1e-6)
 
 
 # -- owner routing of a row-sharded table (ISSUE 43, transfer/route.py) -------

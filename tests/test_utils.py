@@ -210,8 +210,7 @@ def test_xla_env_import_is_jax_free():
     """utils/xla_env must be importable BEFORE jax initializes (its whole
     purpose is setting XLA_FLAGS pre-init) — so the package __init__
     chains it pulls in must never import jax at module level.  Pins the
-    contract tests/conftest.py, __graft_entry__.py, and
-    scripts/crossover.py rely on."""
+    contract tests/conftest.py and __graft_entry__.py rely on."""
     import subprocess
     import sys
 

@@ -431,13 +431,6 @@ def test_stencil_rejects_skipgram():
         m._build_grads()
 
 
-def test_stencil_rejects_dense_logits():
-    m = make_model(word2vec={"dense_logits": 1})
-    m.build(corpus())
-    with pytest.raises(ValueError, match="dense_logits"):
-        m._build_grads()
-
-
 def test_stencil_requires_xla_transfer():
     m = make_model(cluster={"transfer": "local"})
     m.build(corpus())
@@ -705,7 +698,6 @@ def test_span_train_equals_per_pair_train(worker, w2v, devices8):
 @pytest.mark.parametrize("overrides, why", [
     ({"word2vec": {"sg": 1}}, "skip-gram is per-pair by nature"),
     ({"word2vec": {"async_mode": "hogwild"}}, "hogwild groups per-pair"),
-    ({"word2vec": {"dense_logits": 1}}, "another rendering was asked for"),
     ({"cluster": {"transfer": "local"}}, "no counted push"),
 ])
 def test_who_keeps_the_per_pair_rendering(overrides, why, devices8):

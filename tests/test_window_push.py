@@ -383,20 +383,6 @@ def test_w2v_push_window_training_parity(devices8):
         assert abs(a - b) / b < 0.25, (win_losses, base_losses)
 
 
-def test_w2v_push_window_rejects_dense_logits(devices8):
-    """Dense (capacity-shaped) pushes have no deferred-window semantics;
-    the combination must fail loudly at trace time, not silently
-    de-coalesce."""
-    from swiftmpi_tpu.data.text import synthetic_corpus
-
-    corpus = synthetic_corpus(20, vocab_size=30, length=10, seed=9)
-    m = w2v_model(cluster={"transfer": "xla", "push_window": 2},
-                  worker={"inner_steps": 2},
-                  word2vec={"dense_logits": "1"})
-    with pytest.raises(ValueError, match="cannot coalesce dense"):
-        m.train(corpus, niters=1, batch_size=64)
-
-
 # -- 4-way wire compression (sparse_q / bitmap + error feedback) ----------
 
 def distinct_window(ki, rng, W=2, B=64):

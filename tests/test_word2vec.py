@@ -903,95 +903,74 @@ def test_w2v_hogwild_reconciliation_is_exact_worker_major_apply(devices8):
                                    rtol=1e-4, atol=1e-6, err_msg=f)
 
 
-def test_w2v_dense_logits_matches_parity_step(devices8):
-    """dense_logits: 1 — full-logits MXU rendering — must produce the
-    same loss and state as the gather-based parity step (same sampling
-    stream; differences bounded by matmul reassociation)."""
-    corpus = synthetic_corpus(40, vocab_size=120, length=20, seed=31)
+def test_multi_step_scan_matches_single_steps(devices8):
+    import jax
+    from swiftmpi_tpu.data.text import CBOWBatcher, synthetic_corpus
+    from swiftmpi_tpu.models import Word2Vec
+    from swiftmpi_tpu.utils import ConfigParser
 
-    def run(dense):
-        m = make_model(word2vec={"dense_logits": int(dense)})
-        m.build(corpus)
-        m.stencil = 0      # drives the per-pair builders itself
-        step = jax.jit(m._build_step())
-        batcher = CBOWBatcher(corpus, m.vocab, m.window, m.sample,
-                              seed=5)
-        b = next(iter(batcher.epoch(128)))
-        state = dict(m.table.state)
-        state, _key, tally, es = step(
-            state, m._slot_of_vocab, m._alias_prob, m._alias_idx,
-            jnp.asarray(b.centers), jnp.asarray(b.contexts),
-            jnp.asarray(b.ctx_mask), jax.random.key(3))
-        return float(es), _Tally.read(tally)["pair_count"], \
-            {f: np.asarray(v) for f, v in state.items()}
+    cfg = ConfigParser().update({
+        "cluster": {"transfer": "xla"},
+        "word2vec": {"len_vec": 8, "window": 2, "negative": 3,
+                     "sample": -1, "learning_rate": 0.05},
+        "server": {"initial_learning_rate": 0.3},
+        "worker": {"minibatch": 128},
+    })
+    corpus = synthetic_corpus(20, vocab_size=40, length=12, seed=9)
+    model = Word2Vec(config=cfg)
+    model.build(corpus)
+    model.stencil = 0      # drives the per-pair builders itself
+    batches = list(CBOWBatcher(corpus, model.vocab, 2).epoch(64))[:2]
+    import jax.numpy as jnp
+    centers = jnp.stack([jnp.asarray(b.centers) for b in batches])
+    contexts = jnp.stack([jnp.asarray(b.contexts) for b in batches])
+    masks = jnp.stack([jnp.asarray(b.ctx_mask) for b in batches])
 
-    es0, ec0, st0 = run(False)
-    es1, ec1, st1 = run(True)
-    assert ec0 == ec1
-    assert es0 == pytest.approx(es1, rel=1e-4)
-    for f in st0:
-        np.testing.assert_allclose(st1[f], st0[f], rtol=1e-3, atol=1e-5,
-                                   err_msg=f)
+    multi = model._build_multi_step(2)
+    key = jax.random.key(7)
+    # deep-copy: multi donates its state argument
+    state_copy = {f: jnp.array(v) for f, v in model.table.state.items()}
+    # the program splits the key it is given, then once a step
+    sub = jax.random.split(key)[1]
+    s_multi, *_sums = multi(
+        state_copy, model._slot_of_vocab, model._alias_prob,
+        model._alias_idx, centers, contexts, masks, key)
 
-
-def test_w2v_dense_logits_trains_and_guards(devices8):
-    """train() end-to-end in dense mode; invalid flag combinations and
-    the tpu-backend guard raise."""
-    corpus = synthetic_corpus(50, vocab_size=80, length=15, seed=33)
-    m = make_model(word2vec={"dense_logits": 1})
-    losses = m.train(corpus, niters=3, batch_size=64)
-    assert losses[-1] < losses[0], losses
-
-    with pytest.raises(ValueError, match="CBOW-only"):
-        make_model(word2vec={"dense_logits": 1, "sg": 1})._build_grads()
-    with pytest.raises(ValueError, match="pick one"):
-        make_model(word2vec={"dense_logits": 1,
-                             "shared_negatives": 1})._build_grads()
-    m3 = make_model(word2vec={"dense_logits": 1})
-    m3.transfer = type("FakeTpuTransfer", (), {"name": "tpu"})()
-    with pytest.raises(ValueError, match="transfer: xla"):
-        m3._build_grads()
+    grads_fn = jax.jit(model._build_grads())
+    apply_fn = jax.jit(model._build_apply())
+    s = dict(model.table.state)
+    keys = jax.random.split(sub, 2)
+    for i in range(2):
+        pushes, _, _ = grads_fn(
+            s, model._slot_of_vocab, model._alias_prob, model._alias_idx,
+            centers[i], contexts[i], masks[i], keys[i])
+        s = apply_fn(s, pushes)
+    for f in s:
+        np.testing.assert_allclose(np.asarray(s[f]),
+                                   np.asarray(s_multi[f]),
+                                   rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.slow
-def test_w2v_hogwild_with_dense_logits(devices8):
-    """The two opt-ins compose: hogwild workers each compute dense-mode
-    grads (capacity-shaped h push) and the ring reconciliation applies
-    them; loss must decrease."""
-    corpus = synthetic_corpus(150, vocab_size=50, length=12, seed=8)
-    m = make_model(word2vec={"async_mode": "hogwild",
-                             "dense_logits": 1, "local_steps": 2})
-    losses = m.train(corpus, niters=3, batch_size=16)
-    assert losses[-1] < losses[0], losses
+@pytest.mark.parametrize("section, key, value", [
+    ("cluster", "data_plane", "pallas"),
+    ("word2vec", "dense_logits", 1),
+], ids=["data_plane", "dense_logits"])
+def test_removed_keys_do_not_fork_the_step(section, key, value, devices8):
+    """A conf that still carries a key ISSUE 45 removed lowers the step
+    of a conf without it: nothing reads the key any more."""
+    from tests.test_program_choice import lowered_steps
 
+    def conf(**extra):
+        return ConfigParser().update({
+            "cluster": {"transfer": "xla"},
+            "word2vec": {"len_vec": 16, "window": 2, "negative": 5,
+                         "sample": -1, "learning_rate": 0.05},
+            "server": {"initial_learning_rate": 0.3},
+            "worker": {"minibatch": 512}, **extra})
 
-def test_w2v_dense_logits_auto_gate(monkeypatch, tmp_path, devices8):
-    """dense_logits defaults to 'auto': gather on CPU / without a
-    verdict; promoted to dense on a single TPU device with a recorded
-    chip win (same calibration policy as the Pallas kernels)."""
-    from swiftmpi_tpu.ops import calibration
-
-    monkeypatch.setenv("SMTPU_CALIBRATION", str(tmp_path / "c.json"))
-    monkeypatch.delenv("SMTPU_DENSE_LOGITS", raising=False)
-    calibration.reset_cache()
-    corpus = synthetic_corpus(20, vocab_size=50, length=10, seed=2)
-    m = make_model()
-    assert m.dense_logits is None          # the auto default
-    m.build(corpus)
-    m._build_grads()
-    # a CBOW model renders its contexts by span position by default; the
-    # gate is the per-pair path's (a batcher that renders no spans)
-    assert m.resolved_rendering == "stencil"
-    m.stencil = 0
-    m._build_grads()
-    assert m.resolved_rendering == "gather"
-
-    monkeypatch.setattr(calibration, "on_tpu", lambda: True)
-    import jax as _jax
-    monkeypatch.setattr(_jax, "device_count", lambda: 1)
-    monkeypatch.setattr(calibration, "device_key",
-                        lambda: "TPU v5 lite")
-    calibration.record("dense_logits", "TPU v5 lite", {"win": True})
-    m._build_grads()
-    assert m.resolved_rendering == "dense"
-    calibration.reset_cache()
+    base = conf()
+    carrying = conf()
+    carrying.set(section, key, value)
+    want, got = lowered_steps(base), lowered_steps(carrying)
+    assert sorted(got) == sorted(want) == ["pairs", "spans"]
+    assert got == want
