@@ -99,7 +99,7 @@ def _loss_parts(parts, weight: float) -> dict:
     beside the loss (``lm_loss_and_stats``'s parts), as means over the
     steps: the two losses, the module's weighted share of their sum in
     percent, and the module's own expert counters under ``mtp_``."""
-    if not parts or not parts[0]:
+    if not parts or "main_loss" not in parts[0]:
         return {}
     main, mtp = (sum(float(p[k]) for p in parts) / len(parts)
                  for k in ("main_loss", "mtp_loss"))
@@ -107,6 +107,16 @@ def _loss_parts(parts, weight: float) -> dict:
             "mtp_loss_share": 100.0 * weight * mtp / (main + weight * mtp),
             **{"mtp_" + k: v for k, v in _expert_counters(
                 [p["mtp_stats"] for p in parts]).items()}}
+
+
+def _scan_counters(parts) -> dict:
+    """``ssm_scan_chunks``: the chunks the state-space layers' scans walked
+    a step (``lm_loss_and_stats``'s part of that name), mean over the
+    run's steps; nothing for a stack without such layers."""
+    if not parts or "ssm_scan_chunks" not in parts[0]:
+        return {}
+    return {"ssm_scan_chunks": sum(float(p["ssm_scan_chunks"])
+                                   for p in parts) / len(parts)}
 
 
 class Trainer:
@@ -287,7 +297,8 @@ class Trainer:
             params, opt_state, step, loss, stats = self._step_fn(
                 state.params, state.opt_state, state.step, tokens)
         with obs.span("step_book", step=n):
-            if self.cfg.n_experts and obs.get_registry().enabled:
+            if obs.get_registry().enabled and (
+                    self.cfg.n_experts or "ssm" in self.cfg.layer_ops):
                 self._stats.append(stats)
             self.meter.record(int(np.prod(tokens.shape)))
             obs.record_step(1)
@@ -321,8 +332,9 @@ class Trainer:
         ``step_prep``, ``dispatch`` and ``step_book`` in ``step``,
         ``loss_fetch`` for the call's one blocking fetch (``loss_wait``
         for the last loss, then with telemetry on the read of the expert
-        layers' counters and, with a multi-token-prediction module, of the
-        loss's two parts), ``train_finish`` from there to the return.
+        layers' counters, with a multi-token-prediction module of the
+        loss's two parts, and with state-space layers of the chunks their
+        scans walked), ``train_finish`` from there to the return.
         ``self.train_metrics`` holds what the call counted.
         """
         setup_span = obs.span("train_setup")
@@ -383,8 +395,10 @@ class Trainer:
             self.train_metrics = {
                 "steps": steps, "host_stall_ms": stall,
                 "stall_ms_per_step": stall / steps if steps else 0.0,
-                **_expert_counters([s for s, _parts in stats]),
-                **_loss_parts([p for _s, p in stats], self.cfg.mtp_weight)}
+                **(_expert_counters([s for s, _parts in stats])
+                   if self.cfg.n_experts else {}),
+                **_loss_parts([p for _s, p in stats], self.cfg.mtp_weight),
+                **_scan_counters([p for _s, p in stats])}
         return state, losses
 
     # -- checkpoints (multihost-safe, atomic, CRC-validated) ---------------

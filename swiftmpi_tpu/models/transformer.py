@@ -27,15 +27,19 @@ one of the kinds of ``ATTENTION_OPS``: causal with RoPE, causal over a
 sliding ``window`` with RoPE, causal with no position embedding, or
 *latent* attention: queries through a low-rank pair, keys and values
 re-expanded per head from one compressed vector a position, RoPE on a
-decoupled part of the head whose key every head shares — or a
-gated short convolution) and an *FFN* (dense SiLU-gated, or the routed
-expert layer of parallel/moe.py, beside shared experts every token runs
-through where ``n_shared_experts``).  ``layer_ops`` / ``layer_ffns`` name
-them per layer; runs of equal layers are stacked on a leading axis and
-scanned, so a stack compiles one body per run (left empty, every layer is
-attention + ``n_experts``'s FFN: the homogeneous stack ``pipeline_apply``
-wants).  ``sandwich_norm`` norms each half layer's update again before the
-residual takes it; ``embed_scale`` multiplies the embedding rows.
+decoupled part of the head whose key every head shares — a gated short
+convolution, or a Mamba-2 state-space mixer, :func:`_ssm_mixer`, whose
+recurrence is parallel/ssm.py's chunked scan) and an *FFN* (dense
+SiLU-gated, or the routed expert layer of parallel/moe.py, beside shared
+experts every token runs through where ``n_shared_experts``).  Either may
+be ``none``: such a layer is its other half alone, one norm and one
+residual, with no parameter for the absent half.  ``layer_ops`` /
+``layer_ffns`` name them per layer; runs of equal layers are stacked on a
+leading axis and scanned, so a stack compiles one body per run (left empty,
+every layer is attention + ``n_experts``'s FFN: the homogeneous stack
+``pipeline_apply`` wants).  ``sandwich_norm`` norms each half layer's update
+again before the residual takes it; ``embed_scale`` multiplies the embedding
+rows.
 
 Objective (``objective``): ``next_token`` — causal attention, cross entropy
 of the next token — or ``block_diffusion`` (models/diffusion.py): the trunk
@@ -65,8 +69,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from swiftmpi_tpu import obs
 from swiftmpi_tpu.models import diffusion
-from swiftmpi_tpu.parallel.moe import (MoEParams, MoEStats, expert_layer,
-                                       init_moe_params, moe_ffn)
+from swiftmpi_tpu.parallel.moe import (ACTIVATIONS, MoEParams, MoEStats,
+                                       expert_layer, init_moe_params,
+                                       moe_ffn)
 from swiftmpi_tpu.parallel.pipeline import (pipeline_apply,
                                             stack_stage_params)
 from swiftmpi_tpu.parallel.ring_attention import (CAUSAL, WindowMask,
@@ -74,6 +79,7 @@ from swiftmpi_tpu.parallel.ring_attention import (CAUSAL, WindowMask,
                                                   full_attention,
                                                   ring_attention,
                                                   ulysses_attention)
+from swiftmpi_tpu.parallel.ssm import chunked_scan, n_chunks
 
 
 #: attention operators -> (device scope, over ``cfg.window`` only, RoPE).
@@ -84,8 +90,9 @@ ATTENTION_OPS = {"attention": ("attention", False, True),
                  "sliding": ("window_attention", True, True),
                  "full": ("attention", False, False),
                  "latent": ("latent_attention", False, True)}
-OPS = (*ATTENTION_OPS, "conv")
-FFNS = ("dense", "moe")
+#: ``none``: the layer is its other half alone
+OPS = (*ATTENTION_OPS, "conv", "ssm", "none")
+FFNS = ("dense", "moe", "none")
 OBJECTIVES = ("next_token", "block_diffusion")
 
 
@@ -121,7 +128,10 @@ class TransformerConfig:
     conv_kernel: int = 3             # the short convolution's taps
     d_expert: int = 0                # an expert's width; 0 => d_ff
     router: str = "softmax"          # parallel/moe.py ROUTERS
-    expert_gated: bool = False       # SwiGLU experts (else ReLU pair)
+    expert_gated: bool = False       # SwiGLU experts (else an ungated pair)
+    expert_act: str = "relu"         # ... an ungated pair's activation, of
+                                     # parallel/moe.py ACTIVATIONS; the
+                                     # shared experts take the experts' form
     experts_held: Tuple[int, int] = ()   # (lo, hi) expert ids held here;
                                          # () = all n_experts
     norm_eps: float = 1e-6
@@ -137,6 +147,7 @@ class TransformerConfig:
     embed_scale: float = 1.0         # x = embed[tok] * embed_scale
     n_shared_experts: int = 0        # experts every token runs beside the
                                      # routed ones, each an expert's width
+    d_shared_expert: int = 0         # ... or this width together, if set
     route_scale: float = 1.0         # what a token's routing weights sum to
     # -- a "latent" layer (each 0 until a layer asks for them) -------------
     q_lora_rank: int = 0             # the queries' compressed width
@@ -145,6 +156,13 @@ class TransformerConfig:
     qk_rope_dim: int = 0             # ... with RoPE; the key's part is one
                                      # a position, shared by every head
     v_head_dim: int = 0              # a value head's width
+    # -- an "ssm" layer (each 0 until a layer asks for them) ---------------
+    ssm_heads: int = 0               # heads of the recurrence
+    ssm_head_dim: int = 0            # a head's inputs (its state's rows)
+    ssm_state: int = 0               # a state's columns
+    ssm_groups: int = 0              # B / C groups; divides ssm_heads
+    ssm_conv: int = 4                # the causal convolution's taps
+    ssm_chunk: int = 128             # positions a chunk of the scan
     # -- the objective ------------------------------------------------------
     objective: str = "next_token"    # of OBJECTIVES
     diffusion_block: int = 4         # block_diffusion: positions a block
@@ -192,6 +210,28 @@ class TransformerConfig:
             if self.qk_rope_dim % 2:
                 raise ValueError("a 'latent' layer rotates pairs: "
                                  f"qk_rope_dim {self.qk_rope_dim} is odd")
+        if "ssm" in self.layer_ops:
+            missing = [f for f in ("ssm_heads", "ssm_head_dim", "ssm_state",
+                                   "ssm_groups") if getattr(self, f) < 1]
+            if missing:
+                raise ValueError("an 'ssm' layer needs its sizes: "
+                                 f"{', '.join(missing)} not set")
+            if self.ssm_heads % self.ssm_groups:
+                raise ValueError(
+                    f"an 'ssm' layer's heads ({self.ssm_heads}) must be a "
+                    f"multiple of its groups ({self.ssm_groups}): a group's "
+                    "B and C serve whole heads")
+            if self.ssm_conv < 1 or self.ssm_chunk < 1:
+                raise ValueError("an 'ssm' layer needs ssm_conv >= 1 and "
+                                 f"ssm_chunk >= 1, not {self.ssm_conv} and "
+                                 f"{self.ssm_chunk}")
+            if self.objective != "next_token":
+                raise ValueError("an 'ssm' layer's recurrence is causal; "
+                                 f"objective {self.objective!r} brings a "
+                                 "mask for every layer")
+        if self.expert_act not in ACTIVATIONS:
+            raise ValueError(f"unknown expert_act {self.expert_act!r}; have "
+                             f"{tuple(ACTIVATIONS)}")
         if self.mtp_layers not in (0, 1):
             raise ValueError(f"mtp_layers is 0 or 1, not {self.mtp_layers}: "
                              "one module, one token further")
@@ -223,6 +263,11 @@ class TransformerConfig:
             [x for x in ffns if x not in FFNS]
         if bad:
             raise ValueError(f"unknown layer kinds {bad}")
+        empty = [i for i, kind in enumerate(zip(ops, ffns))
+                 if kind == ("none", "none")]
+        if empty:
+            raise ValueError(f"layers {empty} have neither an operator nor "
+                             "an FFN")
         return tuple(zip(ops, ffns))
 
     def layer_groups(self):
@@ -243,6 +288,16 @@ class TransformerConfig:
     def held(self) -> Tuple[int, int]:
         return tuple(self.experts_held) or (0, self.n_experts)
 
+    @property
+    def shared_width(self) -> int:
+        """The shared experts' width together (0: none)."""
+        return self.d_shared_expert or \
+            self.n_shared_experts * (self.d_expert or self.d_ff)
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
 
 # -- params ----------------------------------------------------------------
 
@@ -254,12 +309,13 @@ def _init_block(k, cfg: TransformerConfig, op: str, ffn: str):
         s = cfg.init_std or 1.0 / math.sqrt(rows)
         return jax.random.normal(next(ks), (rows, cols), cfg.dtype) * s
 
-    blk = {"ln1": jnp.ones((d,), cfg.dtype),
-           "ln2": jnp.ones((d,), cfg.dtype)}
-    if cfg.sandwich_norm:
-        blk.update(ln1_post=jnp.ones((d,), cfg.dtype),
-                   ln2_post=jnp.ones((d,), cfg.dtype))
-    if op == "latent":
+    # a half's gains exist where the half does
+    blk = {f"ln{i}{post}": jnp.ones((d,), cfg.dtype)
+           for i, half in ((1, op), (2, ffn)) if half != "none"
+           for post in (("", "_post") if cfg.sandwich_norm else ("",))}
+    if op == "ssm":
+        blk.update(_init_ssm(mat, next(ks), cfg))
+    elif op == "latent":
         H, r_q, r_kv = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
         blk.update(
             wq_a=mat(d, r_q), q_a_norm=jnp.ones((r_q,), cfg.dtype),
@@ -279,7 +335,7 @@ def _init_block(k, cfg: TransformerConfig, op: str, ffn: str):
                        k_norm=jnp.ones((Dh,), cfg.dtype))
         if cfg.attn_gate:
             blk.update(wg=mat(d, cfg.n_heads * Dh))
-    else:
+    elif op == "conv":
         blk.update(conv_in=mat(d, 3 * d), conv_out=mat(d, d),
                    conv_w=jax.random.normal(
                        next(ks), (cfg.conv_kernel, d), cfg.dtype)
@@ -292,13 +348,44 @@ def _init_block(k, cfg: TransformerConfig, op: str, ffn: str):
             held=hi - lo, gated=cfg.expert_gated,
             bias=cfg.router == "sigmoid_bias", std=cfg.init_std)
         if cfg.n_shared_experts:
-            h = cfg.n_shared_experts * width
-            blk.update(shared_gate=mat(d, h), shared_up=mat(d, h),
-                       shared_down=mat(h, d))
-    else:
+            h = cfg.shared_width
+            if cfg.expert_gated:
+                blk.update(shared_gate=mat(d, h))
+            blk.update(shared_up=mat(d, h), shared_down=mat(h, d))
+    elif ffn == "dense":
         h = cfg.d_ff
         blk.update(w_gate=mat(d, h), w_up=mat(d, h), w_down=mat(h, d))
     return blk
+
+
+def _init_ssm(mat, key, cfg: TransformerConfig) -> dict:
+    """A Mamba-2 mixer's parameters at their published start: every head's
+    decay rate ``-exp(A_log)`` uniform in [-16, -1], its step
+    ``softplus(dt_bias)`` log-uniform in [1e-3, 1e-1] (floored at 1e-4),
+    its skip ``D`` 1, the gated norm's gain 1; the depthwise convolution's
+    taps and bias uniform in +-1/sqrt(taps), whatever ``init_std`` (a
+    channel's fan-in is its taps: with taps of ``init_std`` 0.02 the
+    recurrence's inputs are ~0.02 and the ``D`` skip is all the mixer
+    computes)."""
+    H, inner = cfg.ssm_heads, cfg.ssm_inner
+    conv = inner + 2 * cfg.ssm_groups * cfg.ssm_state
+    k_conv, k_bias, k_a, k_dt = jax.random.split(key, 4)
+    bound = 1.0 / math.sqrt(cfg.ssm_conv)
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(
+        k_dt, (H,), jnp.float32, math.log(1e-3), math.log(1e-1))), 1e-4)
+    return {
+        # -> [z | x B C | dt]
+        "ssm_in": mat(cfg.d_model, inner + conv + H),
+        "ssm_out": mat(inner, cfg.d_model),
+        "ssm_conv_w": jax.random.uniform(k_conv, (cfg.ssm_conv, conv),
+                                         cfg.dtype, -bound, bound),
+        "ssm_conv_b": jax.random.uniform(k_bias, (conv,), cfg.dtype, -bound,
+                                         bound),
+        "A_log": jnp.log(jax.random.uniform(k_a, (H,), jnp.float32, 1.0,
+                                            16.0)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),     # softplus^-1(dt)
+        "D": jnp.ones((H,), jnp.float32),
+        "ssm_norm": jnp.ones((inner,), cfg.dtype)}
 
 
 def init_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
@@ -550,6 +637,42 @@ def _short_conv(blk, x, cfg: TransformerConfig):
                           cfg)
 
 
+def _ssm_mixer(blk, x, cfg: TransformerConfig):
+    """A Mamba-2 mixer (arXiv 2405.21060, as ``nemotron_h`` lays it out)::
+
+        [z | xBC | dt] = h W_in                  inner | inner + 2 G N | H
+        xBC = silu(conv1d_causal(xBC; w, b))     depthwise, ssm_conv taps
+        [x | B | C] = xBC;   dt = softplus(dt + dt_bias);   A = -exp(A_log)
+        s_t = exp(dt_t A) s_{t-1} + dt_t x_t (x) B_t;   y_t = s_t C_t + D x_t
+        out = RMSNorm_groups(y * silu(z); gain) W_out
+
+    ``H`` heads of ``ssm_head_dim`` with a ``ssm_state``-wide state, ``B`` /
+    ``C`` shared by the ``H / G`` heads of a group, the norm over each of the
+    ``G`` groups of ``inner / G``.  ``dt``, ``A`` and the recurrence's decays
+    are f32 (``parallel/ssm.py::chunked_scan``, scope ``ssm_scan``)."""
+    B, S, _d = x.shape
+    H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+    inner, K = cfg.ssm_inner, cfg.ssm_conv
+    h = _rms_norm(x, blk["ln1"], cfg.norm_eps)
+    z, xbc, dt = jnp.split(_mm(h, blk["ssm_in"], cfg),
+                           [inner, 2 * inner + 2 * G * N], axis=-1)
+    # tap j reads position t - (K-1) + j; zeros left of the sequence
+    xbc = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+    xbc = jax.nn.silu(sum(blk["ssm_conv_w"][j] * xbc[:, j:j + S]
+                          for j in range(K)) + blk["ssm_conv_b"])
+    xs, b, c = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+    xs = xs.reshape(B, S, H, P)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + blk["dt_bias"])
+    with obs.named_scope("ssm_scan"):
+        y = chunked_scan(xs, dt, -jnp.exp(blk["A_log"].astype(jnp.float32)),
+                         b.reshape(B, S, G, N), c.reshape(B, S, G, N),
+                         chunk=cfg.ssm_chunk, compute_dtype=cfg.matmul_dtype)
+    y = (y + blk["D"][:, None] * xs).astype(x.dtype).reshape(B, S, inner)
+    y = _rms_norm((y * jax.nn.silu(z)).reshape(B, S, G, inner // G), 1.0,
+                  cfg.norm_eps).reshape(B, S, inner) * blk["ssm_norm"]
+    return x + _post_norm(_mm(y, blk["ssm_out"], cfg), blk, "ln1_post", cfg)
+
+
 def _no_stats() -> MoEStats:
     return MoEStats(*(jnp.float32(0.0),) * len(MoEStats._fields))
 
@@ -559,10 +682,22 @@ def _swiglu(h, w_gate, w_up, w_down, cfg: TransformerConfig):
                cfg)
 
 
+def _shared_expert(blk, tokens, cfg: TransformerConfig):
+    """The shared experts, in the routed experts' form."""
+    if "shared_gate" in blk:
+        return _swiglu(tokens, blk["shared_gate"], blk["shared_up"],
+                       blk["shared_down"], cfg)
+    return _mm(ACTIVATIONS[cfg.expert_act](_mm(tokens, blk["shared_up"],
+                                               cfg)), blk["shared_down"], cfg)
+
+
 def _ffn(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
          expert_axis: str, kind: str):
-    """-> (x + ffn, aux loss, MoEStats of this layer)."""
+    """-> (x + ffn, aux loss, MoEStats of this layer); ``x`` itself for
+    kind ``none``."""
     B, S, d = x.shape
+    if kind == "none":
+        return x, jnp.float32(0.0), _no_stats()
     if kind == "moe":
         with obs.named_scope("route"):
             tokens = _rms_norm(x, blk["ln2"], cfg.norm_eps).reshape(B * S, d)
@@ -570,20 +705,19 @@ def _ffn(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
             y, aux = moe_ffn(blk["moe"], tokens, mesh, axis=expert_axis,
                              k=cfg.moe_top_k, router=cfg.router,
                              compute_dtype=cfg.matmul_dtype,
-                             route_scale=cfg.route_scale)
+                             route_scale=cfg.route_scale, act=cfg.expert_act)
             stats = _no_stats()
         else:
             y, aux, stats = expert_layer(
                 blk["moe"], tokens, k=cfg.moe_top_k, router=cfg.router,
                 held=cfg.held, compute_dtype=cfg.matmul_dtype,
-                route_scale=cfg.route_scale)
+                route_scale=cfg.route_scale, act=cfg.expert_act)
         y = y.astype(x.dtype)
         if cfg.n_shared_experts:
             # every token, on every chip that shares the layer: a dense
             # product outside the routed experts' sort and groups
             with obs.named_scope("shared_expert"):
-                y = y + _swiglu(tokens, blk["shared_gate"], blk["shared_up"],
-                                blk["shared_down"], cfg)
+                y = y + _shared_expert(blk, tokens, cfg)
         y = y.reshape(B, S, d)
         with obs.named_scope("route"):
             y = _post_norm(y, blk, "ln2_post", cfg)
@@ -611,6 +745,14 @@ def _remat_policy(cfg: TransformerConfig):
 
 def _operator(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
               seq_axis: str, op: str, **attn):
+    if op == "none":
+        return x
+    if op == "ssm":
+        if attn.get("mask", CAUSAL) is not CAUSAL:
+            raise ValueError("an 'ssm' layer's recurrence is causal; it "
+                             "takes no mask")
+        with obs.named_scope("ssm_mixer"):
+            return _ssm_mixer(blk, x, cfg)
     if op in ATTENTION_OPS:
         scope, windowed, rotary = ATTENTION_OPS[op]
         if windowed:
@@ -823,7 +965,10 @@ def lm_loss_and_stats(params, tokens, cfg: TransformerConfig,
     main_loss + mtp_weight * mtp_loss``, the second the cross entropy of
     the token after the next (a sequence's last two positions left out);
     the parts are ``{"main_loss", "mtp_loss", "mtp_stats"}`` — the module's
-    own MoEStats, which the sum holds too — and ``{}`` without a module.
+    own MoEStats, which the sum holds too — and none of them without a
+    module.  Where the stack has state-space layers the parts hold
+    ``ssm_scan_chunks``: the chunks their scans walked this step (sequences
+    x chunks a sequence x such layers), from the shapes traced here.
     ``block_diffusion``: ``noise_key`` draws the
     step's noise (:func:`diffusion.block_noise`), the trunk runs
     ``[x_t ; x_0]``, and head and loss run over the noised half alone: a
@@ -840,6 +985,11 @@ def lm_loss_and_stats(params, tokens, cfg: TransformerConfig,
             attn = diffusion.attention_inputs(S, cfg)
     x, aux, stats = _stack(params, inputs, cfg, mesh, **attn, **fwd_kwargs)
     parts = {}
+    ops = [kind[0] for kind in cfg.layer_kinds()]
+    n_ssm = ops.count("ssm") + (cfg.mtp_layers and ops[-1] == "ssm")
+    if n_ssm:
+        parts["ssm_scan_chunks"] = jnp.float32(
+            B * n_chunks(S, cfg.ssm_chunk) * n_ssm)
     if cfg.mtp_layers:
         # everything the module adds books under `mtp`: its layers' own
         # scopes by the prefix, its merge, gain, head pass and loss here
@@ -849,7 +999,7 @@ def lm_loss_and_stats(params, tokens, cfg: TransformerConfig,
             nll = _token_nll(z.reshape(B * S, -1), head_matrix(params, cfg),
                              jnp.roll(tokens, -2, axis=1).reshape(B * S),
                              cfg).reshape(B, S)
-            parts = {"mtp_loss": nll[:, :-2].mean(), "mtp_stats": stats_m}
+            parts.update(mtp_loss=nll[:, :-2].mean(), mtp_stats=stats_m)
         aux = aux + aux_m
         stats = MoEStats(*(s + t for s, t in zip(stats, stats_m)))
     with obs.named_scope("head"):
@@ -862,7 +1012,7 @@ def lm_loss_and_stats(params, tokens, cfg: TransformerConfig,
                          targets.reshape(B * S), cfg).reshape(B, S)
         loss = diffusion.weighted_loss(nll, weights) if diffuse \
             else nll[:, :-1].mean()
-    if parts:
+    if cfg.mtp_layers:
         parts["main_loss"] = loss
         loss = loss + cfg.mtp_weight * parts["mtp_loss"]
     return loss + aux_weight * aux, (stats, parts)

@@ -154,6 +154,12 @@ DEVICE_SCOPES = {
     # a latent layer: down- and up-projections, the two inner norms, RoPE
     # and the rope key's broadcast, softmax(QK)V, out
     "latent_attention": "latent_attention",
+    # a Mamba-2 mixer: in / out projections, the causal convolution, the
+    # step sizes, the gated grouped norm ...
+    "ssm_mixer": "ssm_mixer",
+    # ... and, innermost, its chunked recurrence (parallel/ssm.py), forward
+    # and transposed
+    "ssm_scan": "ssm_scan",
     "route": "route",              # router, top-k, sort, un-sort, weights
     "experts": "experts",          # the grouped products over held experts
     # jax.lax.ragged_dot as the TPU compiler renders it: a custom call
@@ -178,7 +184,8 @@ DEVICE_SCOPES = {
 #: custom call keeps no path at all — see ``ragged-dot-none`` above — so the
 #: module's grouped products book as ``experts`` with the stack's.)
 LAYER_SCOPES = ("conv", "attention", "window_attention", "latent_attention",
-                "route", "experts", "shared_expert", "dense_ffn")
+                "ssm_mixer", "ssm_scan", "route", "experts", "shared_expert",
+                "dense_ffn")
 DEVICE_SCOPES.update({"mtp_" + s: "mtp" for s in LAYER_SCOPES})
 
 #: device time inside a tracked program but under none of these scopes

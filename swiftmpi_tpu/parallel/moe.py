@@ -33,7 +33,8 @@ with the load-balance auxiliary loss) and ``"sigmoid_bias"`` (selection on
 ``sigmoid(logits) + bias`` with ``bias`` a fixed buffer, weights from the
 unbiased scores, renormalised over the picks; no auxiliary loss); either
 may scale the renormalised weights (``route_scale``).
-Experts: SwiGLU (``w_gate`` given) or the ungated ReLU pair.
+Experts: SwiGLU (``w_gate`` given) or an ungated pair ``act(x W_in) W_out``
+with ``act`` of ``ACTIVATIONS`` (ReLU, or its square).
 """
 
 from __future__ import annotations
@@ -53,6 +54,9 @@ from swiftmpi_tpu.parallel.collectives import all_to_all
 
 EXPERT_AXIS = "expert"
 ROUTERS = ("softmax", "sigmoid_bias")
+#: what an ungated expert puts between its two matrices
+ACTIVATIONS = {"relu": jax.nn.relu,
+               "relu2": lambda v: jnp.square(jax.nn.relu(v))}
 #: rows of sorted picks one step of the expert loop computes; the loop
 #: ends at the last held pick, so the cost follows the picks
 ROW_CHUNK = 8192
@@ -69,7 +73,7 @@ class MoEParams(NamedTuple):
     router: jax.Array                     # (d_model, E)
     w_in: jax.Array                       # (held, d_model, d_ff)  up
     w_out: jax.Array                      # (held, d_ff, d_model)  down
-    w_gate: Optional[jax.Array] = None    # (held, d_model, d_ff); None: ReLU
+    w_gate: Optional[jax.Array] = None    # (held, d_model, d_ff) | ungated
     bias: Optional[jax.Array] = None      # (E,) selection bias, a buffer
 
 
@@ -140,12 +144,12 @@ def route(x: jax.Array, router: jax.Array, bias, k: int, kind: str,
 
 # -- the grouped products over the held experts ------------------------------
 
-def _chunk_ffn(wc, xs, wrow, live, gs):
+def _chunk_ffn(wc, xs, wrow, live, gs, act="relu"):
     """One row chunk through the held experts: xs (rows, d) sorted by
     expert, ``gs`` the experts' group sizes inside the chunk, ``wrow`` the
-    rows' weights -> (rows, d) f32.  Rows past the last group are the
-    grouped-matmul kernel's to leave unwritten: they are zeroed (``live``)
-    before anything reads them."""
+    rows' weights, ``act`` an ungated expert's activation -> (rows, d) f32.
+    Rows past the last group are the grouped-matmul kernel's to leave
+    unwritten: they are zeroed (``live``) before anything reads them."""
     w_in, w_out, w_gate = wc
 
     def grouped(a, w):
@@ -154,7 +158,7 @@ def _chunk_ffn(wc, xs, wrow, live, gs):
 
     with obs.named_scope("experts"):
         up = grouped(xs, w_in)
-        h = jax.nn.relu(up) if w_gate is None else \
+        h = ACTIVATIONS[act](up) if w_gate is None else \
             jax.nn.silu(grouped(xs, w_gate)) * up
         y = grouped(h.astype(xs.dtype), w_out)
     with obs.named_scope("route"):
@@ -178,9 +182,9 @@ def _walk(body, carry, n_rows, rows: int):
                          lambda c, acc: body(c * rows, acc), carry)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
+@partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11, 12))
 def _grouped(w, src, weight, src_idx, out_idx, order, starts, ends,
-             n_out, compute_dtype, rows, scope_prefix):
+             n_out, compute_dtype, rows, scope_prefix, act):
     """The walk over the sorted rows that hold a held pick: ``(out (n_out,
     d) f32, rows covered)``.  Its trip count follows the routing (a
     ``fori_loop`` to the last live chunk), which autodiff cannot transpose;
@@ -197,7 +201,7 @@ def _grouped(w, src, weight, src_idx, out_idx, order, starts, ends,
         with obs.named_scope("route"):
             idx, live, gs, xs, wrow = _chunk_inputs(
                 lo, rows, src, weight, src_idx, order, starts, ends, dtype)
-        y = _chunk_ffn(wc, xs, wrow, live, gs)
+        y = _chunk_ffn(wc, xs, wrow, live, gs, act)
         with obs.named_scope("route"):
             return out.at[out_idx[idx]].add(y), covered + gs.sum()
 
@@ -207,18 +211,18 @@ def _grouped(w, src, weight, src_idx, out_idx, order, starts, ends,
 
 
 def _grouped_fwd(w, src, weight, src_idx, out_idx, order, starts, ends,
-                 n_out, compute_dtype, rows, scope_prefix):
+                 n_out, compute_dtype, rows, scope_prefix, act):
     out = _grouped(w, src, weight, src_idx, out_idx, order, starts, ends,
-                   n_out, compute_dtype, rows, scope_prefix)
+                   n_out, compute_dtype, rows, scope_prefix, act)
     return out, (w, src, weight, src_idx, out_idx, order, starts, ends)
 
 
-def _grouped_bwd(n_out, compute_dtype, rows, scope_prefix, res, cot):
+def _grouped_bwd(n_out, compute_dtype, rows, scope_prefix, act, res, cot):
     with obs.scope_prefix(scope_prefix):
-        return _grouped_bwd_walk(compute_dtype, rows, res, cot)
+        return _grouped_bwd_walk(compute_dtype, rows, act, res, cot)
 
 
-def _grouped_bwd_walk(compute_dtype, rows, res, cot):
+def _grouped_bwd_walk(compute_dtype, rows, act, res, cot):
     w, src, weight, src_idx, out_idx, order, starts, ends = res
     dout = cot[0]
     dtype = compute_dtype or src.dtype
@@ -236,7 +240,7 @@ def _grouped_bwd_walk(compute_dtype, rows, res, cot):
                 lo, rows, src, weight, src_idx, order, starts, ends, dtype)
             dy = dout[out_idx[idx]]
         _, pull = jax.vjp(lambda wc, xs, wrow: _chunk_ffn(
-            wc, xs, wrow, live, gs), wc, xs, wrow)
+            wc, xs, wrow, live, gs, act), wc, xs, wrow)
         dwc, dxs, dwrow = pull(dy)
         with obs.named_scope("experts"):
             dw = jax.tree.map(lambda a, b: a + b.astype(a.dtype), dw, dwc)
@@ -258,7 +262,7 @@ _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 def _held_experts(p: MoEParams, src, src_idx, out_idx, local_e, weight,
                   n_out: int, compute_dtype=None,
-                  row_chunk: int = ROW_CHUNK):
+                  row_chunk: int = ROW_CHUNK, act: str = "relu"):
     """``out[out_idx[i]] += weight[i] * expert_{local_e[i]}(src[src_idx[i]])``
     over the picks ``i`` whose ``local_e`` names a held expert
     (``local_e == held`` marks a pick that is not this device's), as
@@ -282,7 +286,7 @@ def _held_experts(p: MoEParams, src, src_idx, out_idx, local_e, weight,
     out, covered = _grouped((p.w_in, p.w_out, p.w_gate), src, weight,
                             src_idx, out_idx, order, ends - sizes, ends,
                             n_out, compute_dtype, rows,
-                            obs.current_scope_prefix())
+                            obs.current_scope_prefix(), act)
     return out, sizes, covered
 
 
@@ -295,17 +299,25 @@ def _stats(n_picks: int, sizes, covered) -> MoEStats:
                     / mean, layers=jnp.float32(1.0))
 
 
+def _check_act(act: str) -> None:
+    if act not in ACTIVATIONS:
+        raise ValueError(f"unknown expert activation {act!r}; have "
+                         f"{tuple(ACTIVATIONS)}")
+
+
 def expert_layer(params: MoEParams, x: jax.Array, *, k: int = 2,
                  router: str = "softmax",
                  held: Optional[Tuple[int, int]] = None,
                  compute_dtype=None, row_chunk: int = ROW_CHUNK,
-                 route_scale: float = 1.0):
+                 route_scale: float = 1.0, act: str = "relu"):
     """The expert layer on one device: ``(y (T, d) f32, aux, MoEStats)``.
 
     ``held = (lo, hi)`` is the range of expert ids whose weights
     ``params`` stacks (default: all).  Every token is routed over all
     ``E`` experts; picks on experts outside the range contribute nothing
-    here — they are another device's part of the sum."""
+    here — they are another device's part of the sum.  ``act``: an ungated
+    expert's activation (``ACTIVATIONS``)."""
+    _check_act(act)
     E = params.router.shape[1]
     lo, hi = (0, E) if held is None else held
     if hi - lo != params.w_in.shape[0]:
@@ -329,14 +341,15 @@ def expert_layer(params: MoEParams, x: jax.Array, *, k: int = 2,
         token = jnp.arange(T * k, dtype=jnp.int32) // k
     y, sizes, covered = _held_experts(
         params, x, token, token, local_e, gates.reshape(-1), T,
-        compute_dtype, row_chunk)
+        compute_dtype, row_chunk, act)
     return y, aux, _stats(T * k, sizes, covered)
 
 
 def moe_ffn(params: MoEParams, x: jax.Array, mesh: Mesh, *,
             axis: str = EXPERT_AXIS, k: int = 2, router: str = "softmax",
             compute_dtype=None, row_chunk: int = ROW_CHUNK,
-            route_scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+            route_scale: float = 1.0, act: str = "relu"
+            ) -> Tuple[jax.Array, jax.Array]:
     """The expert layer over a mesh axis.
 
     ``x``: global ``(T, d_model)`` tokens, sharded ``P(axis)`` on T (dp and
@@ -348,6 +361,7 @@ def moe_ffn(params: MoEParams, x: jax.Array, mesh: Mesh, *,
     all of the sender's ``t * k`` picks, so none can overflow), computes
     the picks it received with the grouped products and sends the results
     back to be weighted and summed per token."""
+    _check_act(act)
     n = int(mesh.shape[axis])
     E = params.router.shape[1]
     if E % n:
@@ -386,7 +400,7 @@ def moe_ffn(params: MoEParams, x: jax.Array, mesh: Mesh, *,
         y, _sizes, _covered = _held_experts(
             p, recv_x.reshape(n * n_picks, d), rows, rows,
             recv_e.reshape(-1), jnp.ones((n * n_picks,), jnp.float32),
-            n * n_picks, compute_dtype, row_chunk)
+            n * n_picks, compute_dtype, row_chunk, act)
         back = all_to_all(y.reshape(n, n_picks, d), axis, split_axis=0,
                           concat_axis=0)
         picked = back[owner, slot] * gates.reshape(-1)[order][:, None]
@@ -399,7 +413,7 @@ def moe_ffn(params: MoEParams, x: jax.Array, mesh: Mesh, *,
 def moe_ffn_reference(params: MoEParams, x: jax.Array, *, k: int = 2,
                       router: str = "softmax",
                       held: Optional[Tuple[int, int]] = None,
-                      route_scale: float = 1.0):
+                      route_scale: float = 1.0, act: str = "relu"):
     """Dense single-device golden: every held expert applied to every
     token and masked by the gate.  For tests."""
     E = params.router.shape[1]
@@ -411,7 +425,7 @@ def moe_ffn_reference(params: MoEParams, x: jax.Array, *, k: int = 2,
     gates = jnp.zeros((x.shape[0], E), jnp.float32).at[
         jnp.arange(x.shape[0])[:, None], sel].set(g)[:, lo:hi]
     up = jnp.einsum("td,edf->tef", x, params.w_in)
-    h = jax.nn.relu(up) if params.w_gate is None else \
+    h = ACTIVATIONS[act](up) if params.w_gate is None else \
         jax.nn.silu(jnp.einsum("td,edf->tef", x, params.w_gate)) * up
     per_e = jnp.einsum("tef,efd->ted", h, params.w_out)
     return jnp.einsum("te,ted->td", gates, per_e), aux

@@ -1036,6 +1036,130 @@ def mlalm_phase_map_and_counters(toy):
                               np.asarray(t.state.params["mtp"]["eh_proj"]))
 
 
+# -- the state-space hybrid family's surface (benchmark/families/sslm.py's head) ---
+
+_SSL = {}
+
+
+def ssl_toy():
+    """A toy stack of half layers — state-space mixers, one attention layer
+    with no position embedding, ungated squared-ReLU expert layers beside a
+    wider shared expert — one ``Trainer.run`` of two host batches with
+    telemetry on, by the family's call sequence."""
+    if _SSL:
+        return _SSL["toy"]
+    from swiftmpi_tpu import obs
+    from swiftmpi_tpu.models.trainer import Trainer
+    from swiftmpi_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=5, n_heads=16, n_kv_heads=1,
+        d_head=4, d_expert=12, max_seq=32, attention="blockwise",
+        attn_block=8, loss_chunk=16, remat=True, remat_policy="full",
+        n_experts=128, moe_top_k=6, experts_held=(8, 16),
+        router="sigmoid_bias", route_scale=2.5, n_shared_experts=1,
+        d_shared_expert=24, expert_gated=False, expert_act="relu2",
+        layer_ops=("ssm", "none", "ssm", "full", "none"),
+        layer_ffns=("none", "moe", "none", "none", "moe"),
+        ssm_heads=8, ssm_head_dim=4, ssm_state=8, ssm_groups=1, ssm_conv=4,
+        ssm_chunk=8, norm_eps=1e-5, init_std=0.3, tied_head=False)
+    was_on = obs.get_registry().enabled
+    obs.set_enabled(True)
+    trainer = Trainer(cfg, optimizer="adamw", aux_weight=0.0,
+                      learning_rate=3e-4, warmup_steps=2, decay_steps=100,
+                      weight_decay=0.1, grad_clip=1.0, b1=0.9, b2=0.95)
+    state0 = trainer.init_state(jax.random.key(3))
+    rng = np.random.default_rng(46)
+    batches = [rng.integers(0, 64, (1, 32)).astype(np.int32)
+               for _ in range(2)]
+    state, losses = trainer.run(state0, iter(batches))
+    phase_map = obs.costs.phase_map("trainer_step")
+    obs.set_enabled(was_on)
+    _SSL["toy"] = SimpleNamespace(cfg=cfg, trainer=trainer, state=state,
+                                  losses=losses, batches=batches,
+                                  phase_map=phase_map)
+    return _SSL["toy"]
+
+
+@surface
+def sslm_config_and_tree(toy):
+    """The ``TransformerConfig`` fields and the kinds the family sets beyond
+    the other LM families', the parameter names it samples — a mixer's, and
+    an expert layer without gates — and ``hidden_states`` with an absent
+    half repeating its input."""
+    from swiftmpi_tpu.models.transformer import (FFNS, OPS,
+                                                 TransformerConfig,
+                                                 hidden_states)
+
+    fields = TransformerConfig.__dataclass_fields__
+    for name, default in [("ssm_heads", 0), ("ssm_head_dim", 0),
+                          ("ssm_state", 0), ("ssm_groups", 0),
+                          ("ssm_conv", 4), ("ssm_chunk", 128),
+                          ("expert_act", "relu"), ("d_shared_expert", 0)]:
+        assert fields[name].default == default, name
+    assert {"ssm", "none", "full"} <= set(OPS) and "none" in FFNS
+    t = ssl_toy()
+    assert t.cfg.layer_groups() == [
+        (("ssm", "none"), 1), (("none", "moe"), 1), (("ssm", "none"), 1),
+        (("full", "none"), 1), (("none", "moe"), 1)]
+    params = t.state.params
+    assert set(params) == {"embed", "head", "blocks", "ln_f"}
+    assert set(params["blocks"][0]) == {
+        "ln1", "ssm_in", "ssm_out", "ssm_conv_w", "ssm_conv_b", "A_log",
+        "dt_bias", "D", "ssm_norm"}
+    assert set(params["blocks"][1]) == {"ln2", "moe", "shared_up",
+                                        "shared_down"}
+    assert set(params["blocks"][3]) == {"ln1", "wq", "wk", "wv", "wo"}
+    assert params["blocks"][0]["ssm_in"].shape == (1, 32, 32 + 48 + 8)
+    assert params["blocks"][0]["ssm_conv_w"].shape == (1, 4, 48)
+    assert params["blocks"][0]["A_log"].shape == (1, 8)
+    moe = params["blocks"][1]["moe"]
+    assert moe.w_gate is None and moe.w_in.shape == (1, 8, 32, 12)
+    assert params["blocks"][1]["shared_up"].shape == (1, 32, 24)
+    mu = t.state.opt_state[1][0].mu
+    assert jax.tree.structure(mu) == jax.tree.structure(params)
+    assert len(t.losses) == 2
+    assert all(math.isfinite(float(x)) for x in t.losses)
+    hs = hidden_states(params, t.batches[0], t.cfg)
+    assert len(hs) == 2 * t.cfg.n_layers + 1
+    assert all(h.shape == (1, 32, 32) for h in hs)
+    assert np.array_equal(hs[1], hs[2]) and np.array_equal(hs[2], hs[3])
+
+
+@surface
+def sslm_phase_map_and_counters(toy):
+    """The device scopes ``ssm_mixer`` and ``ssm_scan`` beside the LM
+    step's, ``ssm_scan_chunks`` beside the expert counters in
+    ``train_metrics``, one program a step, and a share's routers and
+    selection biases left alone while the mixer's own vectors move."""
+    from swiftmpi_tpu.obs.catalog import DEVICE_SCOPES, LAYER_SCOPES
+
+    t = ssl_toy()
+    want = {"embed", "ssm_mixer", "ssm_scan", "attention", "route",
+            "experts", "shared_expert", "head", "optimizer"}
+    assert want <= set(DEVICE_SCOPES.values())
+    assert {"ssm_mixer", "ssm_scan"} <= set(LAYER_SCOPES)
+    assert want <= set(t.phase_map["phase"].values())
+    assert t.phase_map["module"] == "jit_train_step"
+    m = t.trainer.train_metrics
+    assert m["steps"] == 2 and m["dropped_picks_per_step"] == 0.0
+    # 1 sequence x 32 / 8 chunks x 2 state-space layers
+    assert m["ssm_scan_chunks"] == 8.0
+    assert 0.0 <= m["held_pick_share"] <= 100.0
+    assert "main_loss" not in m and "mtp_loss_share" not in m
+    state0 = t.trainer.init_state(jax.random.key(3))
+    for i in (1, 4):
+        before, after = state0.params["blocks"][i], t.state.params["blocks"][i]
+        for name in ("router", "bias"):
+            assert np.array_equal(np.asarray(getattr(before["moe"], name)),
+                                  np.asarray(getattr(after["moe"], name)))
+    for name in ("ssm_in", "ssm_conv_w", "ssm_conv_b", "A_log", "dt_bias",
+                 "D", "ssm_norm"):
+        assert not np.array_equal(
+            np.asarray(state0.params["blocks"][0][name]),
+            np.asarray(t.state.params["blocks"][0][name])), name
+
+
 @pytest.mark.parametrize("name", sorted(SURFACE))
 def test_harness_surface(name, toy):
     SURFACE[name](toy)

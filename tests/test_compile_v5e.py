@@ -9,6 +9,7 @@ worker collects the same tests and only the one given this file loads it.
 
 import hashlib
 import json
+import math
 import os
 import re
 
@@ -675,3 +676,84 @@ def test_glm47f_ep8_trainer_step_fits_one_chip(topo, no_compile_cache):
     assert {"mtp", "mtp_latent_attention", "mtp_route", "mtp_experts",
             "mtp_shared_expert", "latent_attention"} <= scopes
     assert scopes <= set(DEVICE_SCOPES)
+
+
+def test_nemotron3n_ep16_trainer_step_fits_one_chip(topo, no_compile_cache):
+    """The real ``trainer_step`` of ``nemotron3n-ep16-8k-t8k`` — nine runs of
+    one half layer each at the published widths (four Mamba-2 mixers of 64
+    heads of 64 with a 128-wide state and 8 groups, one attention layer of 32
+    query heads on 2 KV heads of 128, four expert layers of 8 of 128
+    ungated squared-ReLU experts beside a 3,712-wide shared one), an untied
+    16,384-row head, one packed sequence of 8,192 — on one v5e chip:
+    666,963,456 parameters; the compiler's ``peak_memory_in_bytes`` (11.55
+    GiB) fits the 15.75 GiB the runtime gives; the grouped products are the
+    compiler's ``ragged-dot`` kernels, 8 a layer body (two products an
+    expert: 2 forward + 6 backward); the scan keeps chunk states and the
+    chunks' ``(128, 128)`` forms, never a position's state; no buffer has
+    the size of a head's ``(S, S)`` scores; and the mixers' instructions
+    carry their two scopes."""
+    import sys
+    sys.path.insert(0, REPO)
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.costs import sslm as costs
+    from benchmark.families import sslm as family
+    from swiftmpi_tpu.models.trainer import Trainer
+    from swiftmpi_tpu.obs.catalog import DEVICE_SCOPES
+
+    config, traffic = _cell("nemotron3n-ep16-8k-t8k")
+    cfg = family.transformer_config(config, traffic)
+    kinds = [("ssm", "none"), ("none", "moe")] * 2 + [
+        ("ssm", "none"), ("full", "none"), ("none", "moe"), ("ssm", "none"),
+        ("none", "moe")]
+    assert cfg.layer_groups() == [(kind, 1) for kind in kinds]
+    assert (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups,
+            cfg.ssm_conv, cfg.ssm_chunk) == (64, 64, 128, 8, 4, 128)
+    assert (cfg.n_heads, cfg.kv_heads, cfg.head_dim) == (32, 2, 128)
+    assert (cfg.n_experts, cfg.moe_top_k, cfg.held[1] - cfg.held[0],
+            cfg.d_expert, cfg.shared_width, cfg.expert_act) == \
+        (128, 6, 8, 1856, 3712, "relu2")
+    one = SingleDeviceSharding(topo.devices[0])
+    trainer = Trainer(cfg, **family.trainer_kwargs(config))
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda k: trainer.init_state(k).tree(),
+                       jax.random.key(0)))
+    seqs, S = int(traffic["sequences_per_step"]), cfg.max_seq
+    assert (seqs, S) == (1, 8192)
+    tokens = jax.ShapeDtypeStruct((seqs, S), jnp.int32, sharding=one)
+    n_params = sum(a.size for a in jax.tree.leaves(state["params"]))
+    # a mixer 38,744,896; an expert layer 100,125,440; the attention layer
+    # 23,399,040; embedding and head 2 x 44,040,192; final gain 2,688:
+    # 10.67 GB x 16 B
+    assert n_params == 666_963_456
+    shape = {"kinds": kinds, "d_model": 2688, "heads": 32, "kv_heads": 2,
+             "d_head": 128, "ssm_heads": 64, "ssm_head_dim": 64,
+             "ssm_state": 128, "ssm_groups": 8, "kernel": 4,
+             "d_expert": 1856, "d_shared": 3712, "experts": 128,
+             "experts_held": 8, "vocab": 16384}
+    assert costs.parameters(shape) == n_params
+
+    compiled = trainer._build_step().lower(
+        state["params"], state["opt_state"], state["step"], tokens).compile()
+    mem = compiled.memory_analysis()
+    assert mem.peak_memory_in_bytes <= 11.8 * GIB, \
+        f"{mem.peak_memory_in_bytes / GIB:.3f} GiB"
+    assert mem.alias_size_in_bytes >= 0.99 * mem.output_size_in_bytes
+    assert mem.argument_size_in_bytes <= 7.5 * GIB       # 12 B a parameter
+
+    text = compiled.as_text()
+    kernels = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                         r'op_name="([^"]*)"', text)
+    assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
+    assert kernels.count("ragged-dot-none") == 4 * 8
+    # the scan: 64 chunk states of (8 groups x 8 heads, 64, 128) a sequence
+    # are there, a state a position is not, and neither is a (S, S) form
+    assert re.search(r"= f32\[64,1,8,8,64,128\]", text)
+    for dims in set(re.findall(r"= \w+\[([\d,]+)\]", text)):
+        sizes = [int(d) for d in dims.split(",")]
+        assert sizes.count(S) < 2, f"[{dims}]"
+        assert not (S in sizes and {64, 128} <= set(sizes)
+                    and math.prod(sizes) >= S * 64 * 64 * 128), f"[{dims}]"
+    scopes = set(re.findall(r"[/(](ssm_\w+)(?=[/)])", text))
+    assert scopes == {"ssm_mixer", "ssm_scan"} and scopes <= set(DEVICE_SCOPES)
