@@ -14,9 +14,13 @@ copies a whole field, ROADMAP S1 / D0 / D10).  ``--width 384 --cases
 head`` (PR 34) times the field as it is stored since PR 32, 384 lanes and
 row-major by default, at the cells' own pushes with their distinct valid
 rows at the head: the sweep and the per-row write beside the program's own
-``transfer/xla.py::_rmw_head_rows`` (which reads and updates the head alone
-too), without (``head``) and with its run-time choice of the sweep
-(``head_or_sweep``), and the read half for the floor.
+tile kernel (``transfer/tile_rmw.py``, PR 47: ``tiles`` on the one field,
+``tiles_x2`` on a parameter and its AdaGrad accumulator as a word2vec push
+has them; the ring depths and block sizes tried cost the same, PERF.md
+section 6, and left the script with the kernel's arguments), the read half for
+the floor, and two heads as long as a sparse push gets (a quarter and
+nearly half of the rows: beyond that a push is dense) for the sweep
+against the kernel where most tiles are named.
 
     python scripts/writeback_micro.py                # on the chip
     JAX_PLATFORMS=cpu python scripts/writeback_micro.py --compile-only DIR
@@ -42,7 +46,8 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax import lax  # noqa: E402
 
-from swiftmpi_tpu.transfer import xla as transfer_xla  # noqa: E402
+from swiftmpi_tpu.parameter import w2v_access  # noqa: E402
+from swiftmpi_tpu.transfer import tile_rmw  # noqa: E402
 
 CAP, D = 2340001, 300
 #: (valid rows, batch length): the tail is ``capacity`` padding, as the
@@ -58,17 +63,20 @@ SIZES = {"5k": (5000, 5500), "100k": (100000, 110000),
          # targets; the worst push that shape allows
          "3.5k_of_5.5k": (3500, 5500), "2k_of_20k": (2000, 20480),
          "60k_of_123k": (60000, 122880), "63k_of_164k": (63000, 163840),
-         "146k_of_180k": (146000, 180224), "180k_of_180k": (180224, 180224)}
+         "146k_of_180k": (146000, 180224), "180k_of_180k": (180224, 180224),
+         # a quarter of the rows, and the longest sparse push
+         # (`XlaTransfer.pushes_dense`: half the rows go dense)
+         "585k_of_585k": (585000, 585000), "1.1m_of_1.1m": (1100000, 1100000)}
 ISSUE_SIZES = ("5k", "100k")
 HEAD_SIZES = ("3.5k_of_5.5k", "2k_of_20k", "60k_of_123k", "63k_of_164k",
               "146k_of_180k", "180k_of_180k")
-HEAD_FORMS = ("a", "b", "head", "head_or_sweep", "gather_only")
+DENSE_SIZES = ("585k_of_585k", "1.1m_of_1.1m")
+HEAD_FORMS = ("a", "b", "gather_only", "tiles", "tiles_x2")
+DENSE_FORMS = ("a", "tiles", "tiles_x2")
 RUNS = 4
 #: ``layout.Format`` of the field in and out of every program, or ``None``
 #: for the compiler's default (set from ``--layout``)
 FIELD_FORMAT = None
-#: suffix of the result file (set from ``--head-chunk``)
-TAG = ""
 
 
 def _rmw(x, rep):
@@ -156,14 +164,16 @@ class _Access:
         return {"x": _apply(current["x"], grads["x"])}
 
 
-def form_head(x, rep, g, may_sweep=False):  # the program's loop over the head
-    return transfer_xla._rmw_head_rows(
-        {"x": x}, rep, {"x": g}, _Access,
-        jnp.sum(rep < CAP, dtype=jnp.int32), may_sweep)["x"]
+def form_tiles(x, rep, g):  # the program's tile kernel
+    return tile_rmw.rmw_tiles({"x": x}, rep, {"x": g}, _Access,
+                              jnp.sum(rep < CAP, dtype=jnp.int32))["x"]
 
 
-def form_head_or_sweep(x, rep, g):  # ... or one sweep, chosen at run time
-    return form_head(x, rep, g, may_sweep=True)
+def form_tiles_x2(xs, rep, g):  # ... on a word2vec push: AdaGrad, 2 fields
+    out = tile_rmw.rmw_tiles(dict(zip(("h", "h2sum"), xs)), rep, {"h": g},
+                             w2v_access(0.05, D),
+                             jnp.sum(rep < CAP, dtype=jnp.int32))
+    return out["h"], out["h2sum"]
 
 
 def form_gather_only(x, rep, g):  # the floor: the read half, no write
@@ -173,8 +183,8 @@ def form_gather_only(x, rep, g):  # the floor: the read half, no write
 
 FORMS = {"a": form_a, "b": form_b, "c": form_c, "d": form_d, "e": form_e,
          "f": form_f, "f_s": form_f_s, "g": form_g,
-         "head": form_head, "head_or_sweep": form_head_or_sweep,
-         "gather_only": form_gather_only}
+         "gather_only": form_gather_only, "tiles": form_tiles,
+         "tiles_x2": form_tiles_x2}
 
 
 def _jitted(form, size):
@@ -203,10 +213,12 @@ def _cases(which):
     if which == "head":
         yield from ((form, size) for size in HEAD_SIZES
                     for form in HEAD_FORMS)
+        yield from ((form, size) for size in DENSE_SIZES
+                    for form in DENSE_FORMS)
         return
     for size in SIZES:
         for form in FORMS:
-            if size in HEAD_SIZES or form.startswith("head"):
+            if size in HEAD_SIZES + DENSE_SIZES or form.startswith("tiles"):
                 continue
             if form == "g" and size != "5k":
                 continue
@@ -225,9 +237,10 @@ def compile_only(out_dir, layout, which):
     os.makedirs(out_dir, exist_ok=True)
     for form, size in _cases(which):
         B = SIZES[size][1]
+        field = jax.ShapeDtypeStruct((CAP, D), jnp.float32,
+                                     sharding=FIELD_FORMAT or dev)
         c = _jitted(form, size).lower(
-            jax.ShapeDtypeStruct((CAP, D), jnp.float32,
-                                 sharding=FIELD_FORMAT or dev),
+            (field, field) if form.endswith("_x2") else field,
             jax.ShapeDtypeStruct((B,), jnp.int32, sharding=dev),
             jax.ShapeDtypeStruct((B, D), jnp.float32, sharding=dev)).compile()
         mem = c.memory_analysis()
@@ -278,25 +291,32 @@ def measure(only, layout, which):
     inputs = {}
     for size, (n, B) in SIZES.items():
         rep = np.full((B,), CAP, np.int32)
-        rep[:n] = np.sort(rng.choice(CAP, n, replace=False))
+        # whole tiles only: the rows of the last, partial one are not
+        # the kernel's (`transfer/xla.py::_rmw_tiles`)
+        rep[:n] = np.sort(rng.choice(CAP - CAP % tile_rmw.TILE, n,
+                                     replace=False))
         inputs[size] = (jnp.asarray(rep), jax.random.normal(
             jax.random.key(1), (B, D), jnp.float32))
     result = {"device": dev.device_kind, "layout": layout,
               "capacity": CAP, "width": D,
               "sizes": SIZES, "runs": RUNS,
-              "head_chunk": transfer_xla._HEAD_CHUNK, "cases": {}}
+              "tile_block": tile_rmw.BLOCK, "tile_depth": tile_rmw.DEPTH,
+              "cases": {}}
     trace_dir = os.path.join("chiprun_out", "writeback_trace")
     digests = {}
     for form, size in _cases(which):
-        if only is not None and form not in only:
+        if only is not None and form not in only and size not in only:
             continue
         fn = _jitted(form, size)
         rep, g = inputs[size]
         x = init(jax.random.key(0))
+        if form.endswith("_x2"):            # the accumulator: positive
+            x = (x, jnp.square(init(jax.random.key(2))))
         out = fn(x, rep, g)                 # compiles; the digest's run
         keep = form != "gather_only"
         if keep:
-            digests[form, size] = [float(v) for v in digest(out, rep)]
+            digests[form, size] = [float(v) for v in digest(
+                out[0] if form.endswith("_x2") else out, rep)]
             x = out
         jax.block_until_ready((x, out))
         # a capture of its own: programs that compile to one executable
@@ -311,14 +331,19 @@ def measure(only, layout, which):
         ms, ops = _reduce(trace_dir)
         shutil.rmtree(trace_dir)            # too big to bring back
         del x, out
+        # the row sum and the field sum against form ``a``'s: equal, or
+        # apart by the rounding of another compiler's `rsqrt`
         same = (digests[form, size] == digests.get(("a", size))
-                if keep else None)
+                if keep and not form.endswith("_x2") else None)
+        if same is False and ("a", size) in digests:
+            same = max(abs(v - w) / abs(w) for v, w in zip(
+                digests[form, size], digests["a", size]))
         result["cases"][f"{form}.{size}"] = {
             "ms_per_run": ms, "ops": ops, "same_as_a": same}
         top = ", ".join(f"{k} {v:.3f}" for k, v in list(ops.items())[:6])
         print(f"{form:12s}{size:14s}{ms:9.3f} ms a run  same_as_a={same}  "
               f"[{top}]", flush=True)
-    with open(os.path.join("chiprun_out", f"writeback_micro{TAG}.json"),
+    with open(os.path.join("chiprun_out", "writeback_micro.json"),
               "w") as f:
         json.dump(result, f, indent=1)
 
@@ -326,7 +351,8 @@ def measure(only, layout, which):
 if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--compile-only", metavar="DIR", default=None)
-    ap.add_argument("--only", nargs="*", default=None)
+    ap.add_argument("--only", nargs="*", default=None,
+                    help="forms or sizes to run (default: all)")
     ap.add_argument("--layout", choices=("row_major", "default"),
                     default="default",
                     help="how the field is stored (default: as the table "
@@ -337,14 +363,8 @@ if __name__ == "__main__":
     ap.add_argument("--cases", choices=("forms", "head"), default="forms",
                     help="forms: PR 30's candidates at its sizes; head: "
                          "the cells' pushes, distinct rows at the head")
-    ap.add_argument("--head-chunk", type=int, default=None,
-                    help="slots a chunk of `_rmw_head_rows` (default: "
-                         "the program's)")
     args = ap.parse_args()
     D = args.width
-    if args.head_chunk:
-        transfer_xla._HEAD_CHUNK = args.head_chunk
-        TAG = f"_chunk{args.head_chunk}"
     if args.compile_only:
         compile_only(args.compile_only, args.layout, args.cases)
     else:

@@ -96,8 +96,9 @@ def push(state_l, slots, grads, counts, axis: str, n: int, combine,
     ``weights`` a row's summed multiplicity (``None``: no mean is taken).
     ``owner_push(state_l, rows, grads, counts)`` is the one-chip sparse
     push on the shard (rows local to it, ``-1`` padding); it returns the
-    new shard and the distinct rows it wrote.  Returns ``(shard, rows
-    written, rows routed, slots offered)``, the counts this chip's own."""
+    new shard and what it wrote, ``int32[2]`` (distinct rows, tiles moved
+    for them).  Returns ``(shard, written, rows routed, slots offered)``,
+    the counts this chip's own."""
     B = slots.shape[0]
     cap = next(iter(state_l.values())).shape[0]
     capacity = n * cap
@@ -140,13 +141,14 @@ def push(state_l, slots, grads, counts, axis: str, n: int, combine,
                    for f, g in sums.items()}
             got_counts = None if weights is None else _exchange(
                 jnp.where(own, cut(weights), 0.0), axis).reshape(-1)
-        state_l, n_rows = owner_push(state_l, got_rows, got, got_counts)
-        return (state_l, upto, left(upto), written + n_rows,
+        state_l, wrote = owner_push(state_l, got_rows, got, got_counts)
+        return (state_l, upto, left(upto), written + wrote,
                 routed + jnp.sum(upto - cursor) * len(sums),
                 offered + n * C * len(sums))
 
     zero = jnp.int32(0)
     state_l, _, _, written, routed, offered = jax.lax.while_loop(
         lambda carry: carry[2], one_pass,
-        (dict(state_l), first[:-1], left(first[:-1]), zero, zero, zero))
+        (dict(state_l), first[:-1], left(first[:-1]),
+         jnp.zeros((2,), jnp.int32), zero, zero))
     return state_l, written, routed, offered

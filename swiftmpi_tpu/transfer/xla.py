@@ -31,7 +31,7 @@ from jax.sharding import PartitionSpec as P
 
 from swiftmpi_tpu import obs
 from swiftmpi_tpu.parameter.sparse_table import is_hot_field
-from swiftmpi_tpu.transfer import route
+from swiftmpi_tpu.transfer import route, tile_rmw
 from swiftmpi_tpu.transfer.api import (Transfer, bump_row_versions,
                                        grad_row_bytes)
 
@@ -52,21 +52,43 @@ from swiftmpi_tpu.transfer.api import (Transfer, bump_row_versions,
 # 14.18 at 110,000).
 #
 # What the constant still decides (ROADMAP D10).  From static shapes, the
-# form of every push `write_back_form` does not answer ``head_rows`` for:
-# one-wide logistic rows, widths that keep the column-major default, a
-# row-sharded mesh, the CPU.  Where it does (PR 34) the rows written are
-# counted at run time, a `_HEAD_CHUNK` at a time, and the same constant
-# weighs them against one sweep (`_rmw_head_rows`; there a row's whole
-# read-modify-write costs ~107 ns and the crossover lies at ~132,000
-# rows, so the constant sweeps a little early).
+# form of every push `write_back_form` does not answer ``tiles`` for:
+# one-wide logistic rows, widths that keep the column-major default,
+# fields that are not f32, a table split by the partitioner, the CPU.
 _ROW_WRITE_AS_SWEPT_BYTES = 31_000
 
-# `_rmw_head_rows` updates the valid head of a push this many slots at a
-# time: a chunk is one gather, one unsorted scatter a field, so a push
-# costs the per-row price of its distinct rows rounded up to a chunk, not
-# of its slots (v5e micro, PERF.md section 6, PR 34: ~92 ns a slot written
-# at 2,048 as at 4,096, which rounds a push of 2,000 rows up to twice it)
-_HEAD_CHUNK = 2048
+# The same weighing for the tile kernel (`_rmw_tiles`, PR 47), which has
+# no price a slot, only one a distinct row: 58.5 ns a row of one field
+# where a push touches a parameter and its accumulator (4 copies a row,
+# whose issue rate bounds the kernel), 82 where it touches one field
+# (v5e micro, PERF.md section 6, PR 47: `scripts/writeback_micro.py
+# --width 384 --cases head`), against ~107 in the loop of gathers and
+# scatters it replaced, and the sweep's 11.5 ms a field + ~16 ns a slot.
+# The distinct rows show only at run time and the form is chosen from
+# shapes, so a slot is weighed as the cells' pushes fill theirs — 0.8
+# distinct rows a slot (146 K of 180 K, Zipf; 0.95 uniform; 0.57 of an
+# owner's buckets) — less what the sweep pays a slot itself: ~31 ns,
+# what sweeping this many bytes costs.  The kernel keeps every push of up
+# to ~378,000 slots on a 3.59 GB field, ~157,000 on an owner's 1.5 GB
+# shard (the cells' longest: 180,224 and 137,536); beyond, where most
+# tiles of a field are named, one sweep is cheaper (585,000 rows of
+# 585,000 slots: 68.4 ms the kernel, 2 x 21.0 the sweep of two fields).
+_TILE_SLOT_AS_SWEPT_BYTES = 9_500
+
+# Gradients of a push from which `_push_rows` orders the push behind the
+# state it is given (an `optimization_barrier`, for the step's peak memory
+# alone).  Below, a batch is too small to move the peak of a 16 GB chip —
+# and the barrier is an OPEN SAFETY ITEM (ROADMAP D10): with it,
+# cbow2m-demo's step (pushes of 8.4 and 1.2 MB) HANGS the v5e, with the
+# kernel or with `per_row`'s own barrier (`_after`) in its place, and runs
+# without (PERF.md section 6, PR 47; `scripts/barrier_hang_repro.py`
+# reproduces it: a barrier over the fields alone runs there, over the
+# gradients alone too, over both in one — the tie that orders — hangs).
+# The cells whose pushes carry 31 MB and more run with
+# it; nothing between 8.4 and 31 MB has run on the chip either way, and
+# the constant stands between the two for no better reason.  Which pushes
+# it orders: `tests/test_write_back.py`.
+_ORDERED_PUSH_BYTES = 16 << 20
 
 
 def _masked_gather(arr: jax.Array, slots: jax.Array,
@@ -107,51 +129,46 @@ def _rmw_rows(fields: dict, rows: jax.Array, grads: dict, access,
             for f, x in fields.items()}
 
 
-def _rmw_head_rows(fields: dict, rows: jax.Array, grads: dict, access,
-                   n: jax.Array, may_sweep: bool, inv=None) -> dict:
+def _rmw_tiles(fields: dict, rows: jax.Array, grads: dict, access,
+               n: jax.Array, inv=None) -> dict:
     """`_rmw_rows` for ascending ``rows`` whose ``n`` valid ones stand at
     the head, ``capacity`` behind them: the head alone is read, updated
-    and written, row by row, a `_HEAD_CHUNK` of slots at a time, so the
-    cost follows the distinct rows of the push and not its slots.  The
-    rows of two chunks are distinct, so a chunk reads what no other
-    wrote.  ``may_sweep``: the shapes allow a head so long that one sweep
-    of the fields is cheaper (`_ROW_WRITE_AS_SWEPT_BYTES`), and which it
-    is shows only at run time.  Same rows, same values either way."""
+    and written, by whole 8-row tiles (`tile_rmw.rmw_tiles`, one kernel
+    for all ``fields``), at the cost of the push's distinct rows and not
+    of its slots.  The tile of the last ``capacity % 8`` rows reaches past
+    the fields, so those rows, the last of the head, are written row by
+    row.  Same rows, same values as `_rmw_rows`."""
     B = rows.shape[0]
     capacity = next(iter(fields.values())).shape[0]
-    chunk = min(_HEAD_CHUNK, B)
-    n_chunks = (n + chunk - 1) // chunk
+    whole = capacity - capacity % tile_rmw.TILE
+    if whole == capacity:
+        return tile_rmw.rmw_tiles(fields, rows, grads, access, n, inv)
+    rest = min(capacity - whole, B)
+    n_whole = jnp.sum(rows < whole, dtype=jnp.int32)
+    fields = tile_rmw.rmw_tiles(fields, rows, grads, access, n_whole, inv)
+    # the ``rest`` slots from the last whole tile's on: a short batch's
+    # start early, and the slots in front are the kernel's
+    at = jnp.minimum(n_whole, B - rest)
 
-    def by_chunks(fields):
-        def body(i, fields):
-            # the last chunk of a ragged batch starts early: the slots
-            # it shares with the chunk before are that chunk's
-            at = jnp.minimum(i * chunk, B - chunk)
-
-            def cut(x):
-                return jax.lax.dynamic_slice_in_dim(x, at, chunk)
-            mine = at + jnp.arange(chunk, dtype=jnp.int32) >= i * chunk
-            return _rmw_rows(fields, jnp.where(mine, cut(rows), capacity),
-                             {f: cut(g) for f, g in grads.items()}, access,
-                             sweep=False,
-                             inv=None if inv is None else cut(inv))
-        return jax.lax.fori_loop(0, n_chunks, body, fields)
-
-    if not may_sweep:
-        return by_chunks(fields)
-    swept = max(x.shape[0] * x.shape[1] * x.dtype.itemsize
-                for x in fields.values())
-    return jax.lax.cond(
-        n_chunks <= swept // (chunk * _ROW_WRITE_AS_SWEPT_BYTES), by_chunks,
-        lambda fields: _rmw_rows(fields, rows, grads, access, sweep=True,
-                                 inv=inv),
-        fields)
+    def cut(x):
+        return jax.lax.dynamic_slice_in_dim(x, at, rest)
+    mine = at + jnp.arange(rest, dtype=jnp.int32) >= n_whole
+    return _rmw_rows(fields, jnp.where(mine, cut(rows), capacity),
+                     {f: cut(g) for f, g in grads.items()}, access,
+                     sweep=False, inv=None if inv is None else cut(inv))
 
 
 def _after(x, done):
     """``x``, not to be touched before ``done`` exists (``None``: no
     wait): orders two uses of whole fields that share no data."""
     return x if done is None else jax.lax.optimization_barrier((done, x))[1]
+
+
+def _ordered(grads: dict) -> bool:
+    """Whether `_push_rows` orders a ``tiles`` push of ``grads`` behind
+    the state it is given (`_ORDERED_PUSH_BYTES`)."""
+    return sum(g.size * g.dtype.itemsize
+               for g in grads.values()) >= _ORDERED_PUSH_BYTES
 
 
 class XlaTransfer(Transfer):
@@ -190,11 +207,13 @@ class XlaTransfer(Transfer):
         #: while a list (`count_routed`), every routed pull and push
         #: traced appends ``(rows routed, bucket slots exchanged)``
         self.routed: list | None = None
-        #: field -> ``"per_row"`` | ``"sweep"`` | ``"head_rows"``: the
-        #: form the write-back of that field's last traced sparse push took
+        #: field -> ``"per_row"`` | ``"sweep"`` | ``"tiles"``: the form
+        #: the write-back of that field's last traced sparse push took
         self.resolved_write_back: dict = {}
         #: while a list (`count_rows_written`), every push traced appends
-        #: its row writes: distinct valid rows x fields touched
+        #: its writes, an ``int32[2]``: distinct valid rows x fields
+        #: touched, and the distinct 8-row tiles those rows lie in x
+        #: fields where the tile kernel moves them (0 where it does not)
         self.rows_written: list | None = None
         # wire ledger (api.py): XLA chooses the actual collectives, so
         # wire_bytes counts the representation-level payload — sparse:
@@ -203,20 +222,21 @@ class XlaTransfer(Transfer):
 
     @contextlib.contextmanager
     def count_rows_written(self):
-        """The list every push traced inside the block appends its row
-        writes to (a traced int32 each): what a step built with telemetry
-        on returns beside its loss."""
+        """The list every push traced inside the block appends its
+        ``(rows, tiles)`` written to (a traced ``int32[2]`` each): what a
+        step built with telemetry on returns beside its loss."""
         self.rows_written = tape = []
         try:
             yield tape
         finally:
             self.rows_written = None
 
-    def _count_rows_written(self, rows, touched) -> None:
-        """A push's row writes onto the tape, if one is held: ``rows()``,
-        its distinct valid rows, times the fields it touches."""
+    def _count_rows_written(self, written, touched) -> None:
+        """A push's writes onto the tape, if one is held: ``written()``,
+        its distinct valid rows and the tiles moved for them, times the
+        fields it touches."""
         if self.rows_written is not None:
-            self.rows_written.append(rows() * len(touched))
+            self.rows_written.append(written() * len(touched))
 
     @contextlib.contextmanager
     def count_routed(self):
@@ -390,8 +410,8 @@ class XlaTransfer(Transfer):
                     acc = _scatter(g, width)
                     dense_grads[f] = acc * inv if mean else acc
         self._count_rows_written(
-            lambda: jnp.sum(jnp.zeros((capacity,), jnp.bool_).at[safe].set(
-                True, mode="drop"), dtype=jnp.int32),
+            lambda: jnp.stack([jnp.sum(jnp.zeros((capacity,), jnp.bool_).at[
+                safe].set(True, mode="drop"), dtype=jnp.int32), 0]),
             access.touched_fields(grads))
         with obs.named_scope("apply"):
             new_fields = access.apply_push(state, dense_grads)
@@ -453,12 +473,12 @@ class XlaTransfer(Transfer):
             return dict(state)
         mode = self.route_mode(state)
         if mode is None:
-            out, n_rows = self._push_rows(state, slots, grads, access, mean,
-                                          counts, self.shards)
+            out, written = self._push_rows(state, slots, grads, access,
+                                           mean, counts, self.shards)
         else:
-            out, n_rows = self._push_routed(mode, state, slots, grads,
-                                            access, mean, counts)
-        self._count_rows_written(lambda: n_rows,
+            out, written = self._push_routed(mode, state, slots, grads,
+                                             access, mean, counts)
+        self._count_rows_written(lambda: written,
                                  access.touched_fields(grads))
         return out
 
@@ -467,7 +487,7 @@ class XlaTransfer(Transfer):
         duplicates (`_combine`), which also orders its distinct rows by
         owner, and every owner pushes what it is sent into its own shard
         (`_push_rows`, ``shards`` = 1: the write-back weighs the shard's
-        rows and takes ``head_rows`` where one chip would)."""
+        rows and takes ``tiles`` where one chip would)."""
         if counts is None and mean:
             counts = (slots >= 0).astype(jnp.float32)
 
@@ -484,14 +504,14 @@ class XlaTransfer(Transfer):
             return route.push(shard, slots, grads, counts, self.axis,
                               self.shards, combine, owner_push)
         if mode == "inside":
-            out, n_rows, *tally = send(state, slots, grads, counts)
+            out, written, *tally = send(state, slots, grads, counts)
         else:
-            out, n_rows, *tally = self._routed(
+            out, written, *tally = self._routed(
                 send, (dict(state), *self._pad_to_shards(
                     slots, dict(grads), counts)),
                 lambda row: dict.fromkeys(state, row), 3)
         self._count_routed(*tally)
-        return out, n_rows
+        return out, written
 
     def _combine(self, slots, grads, capacity, mean, counts, scale):
         """The sparse push's duplicate reduction: ``slots`` sorted, the
@@ -544,7 +564,8 @@ class XlaTransfer(Transfer):
     def _push_rows(self, state, slots, grads, access, mean, counts, shards):
         """The sparse push on the rows ``state`` holds, split over
         ``shards`` devices by the partitioner (1: all of them here).
-        Returns the new state and the distinct valid rows written."""
+        Returns the new state and ``int32[2]``: the distinct valid rows
+        written and, where the tile kernel moved them, their tiles."""
         capacity = next(iter(state.values())).shape[0]
         B = slots.shape[0]
         # only the fields this push's grad families actually update are
@@ -557,27 +578,26 @@ class XlaTransfer(Transfer):
         held, self.shards = self.shards, shards
         try:
             form = self.write_back_form(B, written)
-            # the shapes say whether a head can be so long that one sweep
-            # is cheaper; whether it is, the count at run time
-            may_sweep = self._static_form(B, written) == "sweep"
         finally:
             self.shards = held
         self.resolved_write_back.update(dict.fromkeys(touched, form))
-        if form == "head_rows" and may_sweep:
-            # A push that large sums its batch after the state it is given
-            # exists, i.e. after the push before it, whichever fields that
-            # wrote: a loop and a conditional bind the scheduler less than
-            # the sweep's one fusion did, and left to itself it sorts and
-            # sums one push's batch before another's gradients are
-            # computed, one (B, width) buffer more at the step's peak
-            # (0.25 GB on the chip in cbow2m-b16k, PERF.md section 6).
+        if form == "tiles" and _ordered(grads):
+            # A push sums its batch after the state it is given exists,
+            # i.e. after the push before it, whichever fields that wrote:
+            # left to itself the scheduler sorts and sums one push's batch
+            # before another's gradients are computed, one (B, width)
+            # buffer more at the step's peak (0.5 GiB of temporaries in
+            # cbow2m-b16k's compiled step, PERF.md section 6, PR 34 and
+            # PR 47; its `peak_hbm_gb` is held to 1 %).  Every field of
+            # the state: a barrier over the touched ones alone, or over
+            # the gradients and one element of each field, leaves the
+            # step its 1.53 GB of temporaries (0.98 with this one).
             state, grads = jax.lax.optimization_barrier(
                 (dict(state), dict(grads)))
-        # `head_rows` multiplies where it reads the rows: as an operand
-        # of its loop and conditional the product would be a (B, width)
-        # buffer of its own
+        # the tile kernel multiplies where it reads a row: no (B, width)
+        # product stands between the sums and the write-back
         rep_slots, rep_valid, safe_rep, _, inv, combined = self._combine(
-            slots, grads, capacity, mean, counts, scale=form != "head_rows")
+            slots, grads, capacity, mean, counts, scale=form != "tiles")
 
         # Unused segments' representatives stay == capacity: OOB, dropped.
         # rep_slots are ascending AND one-per-segment by construction
@@ -585,6 +605,10 @@ class XlaTransfer(Transfer):
         # any form of the write-back may take them.
         out = dict(state)
         n_rows = jnp.sum(rep_valid, dtype=jnp.int32)
+        # ... and, where the kernel moves them, the tiles they lie in
+        n_tiles = jnp.sum(rep_valid & (jnp.diff(
+            rep_slots // tile_rmw.TILE, prepend=-1) != 0), dtype=jnp.int32)
+        written = jnp.stack([n_rows, n_tiles if form == "tiles" else 0])
         if form != "per_row":
             fields = {f: state[f] for f in touched}
             with obs.named_scope("apply"):
@@ -592,10 +616,9 @@ class XlaTransfer(Transfer):
                     out.update(_rmw_rows(fields, rep_slots, combined, access,
                                          sweep=True))
                 else:
-                    out.update(_rmw_head_rows(
-                        fields, rep_slots, combined, access, n_rows,
-                        may_sweep, inv=inv))
-                return bump_row_versions(out, state, rep_slots), n_rows
+                    out.update(_rmw_tiles(fields, rep_slots, combined, access,
+                                          n_rows, inv=inv))
+                return bump_row_versions(out, state, rep_slots), written
         # Per row.  Where a field is column-major in HBM (a tall array
         # whose stored width is no multiple of 128: `access.stored_width`
         # widens the rows that can afford it, and then there is nothing
@@ -622,25 +645,24 @@ class XlaTransfer(Transfer):
                 out[f] = done = _set_rows(field, rep_slots, updated[f],
                                           sweep=False)
         with obs.named_scope("apply"):
-            return bump_row_versions(out, state, rep_slots), n_rows
+            return bump_row_versions(out, state, rep_slots), written
 
     def write_back_form(self, n: int, fields) -> str:
         """How to write ``n`` ascending slots, the distinct valid rows at
-        their head, back into each of ``fields``: ``"head_rows"``
-        (`_rmw_head_rows`: the cost of the rows, counted at run time)
-        where every field is one it takes — f32 rows of whole 128-lane
-        tiles (row-major by the compiler's default, so a loop around the
-        scatter copies no field), local to one TPU; else the cheaper of
-        ``"per_row"`` and ``"sweep"`` for ``n`` slots, from static shapes
-        alone (`_ROW_WRITE_AS_SWEPT_BYTES` has the measurement)."""
+        their head, back into each of ``fields``: ``"tiles"``
+        (`_rmw_tiles`: one kernel a push, at the cost of the rows' 8-row
+        tiles) where every field is one it takes — f32 rows of whole
+        128-lane tiles (row-major by the compiler's default, a tile
+        contiguous), local to one TPU — and the push is not so long that one sweep of the fields is
+        cheaper (`_TILE_SLOT_AS_SWEPT_BYTES`); else the cheaper of
+        ``"per_row"`` and ``"sweep"`` for ``n`` slots
+        (`_ROW_WRITE_AS_SWEPT_BYTES`).  From static shapes alone."""
+        swept = sum((f.shape[0] // self.shards) * f.shape[1]
+                    * f.dtype.itemsize for f in fields)
         if (self.shards == 1 and self.platform == "tpu"
                 and all(f.shape[1] % 128 == 0 and f.dtype == jnp.float32
                         for f in fields)):
-            return "head_rows"
-        return self._static_form(n, fields)
-
-    def _static_form(self, n: int, fields) -> str:
-        swept = sum((f.shape[0] // self.shards) * f.shape[1]
-                    * f.dtype.itemsize for f in fields)
-        return ("per_row" if n * len(fields) * _ROW_WRITE_AS_SWEPT_BYTES
-                <= swept else "sweep")
+            rows, slot_bytes = "tiles", _TILE_SLOT_AS_SWEPT_BYTES
+        else:
+            rows, slot_bytes = "per_row", _ROW_WRITE_AS_SWEPT_BYTES
+        return rows if n * len(fields) * slot_bytes <= swept else "sweep"

@@ -42,9 +42,10 @@ def ensure_compile_cache() -> str:
 def compile_cache_entries(path: str) -> int:
     """Number of compiled programs stored under ``path`` (0 when the
     directory does not exist yet).  JAX keeps one ``<key>-cache`` file
-    per program next to its ``-atime`` bookkeeping file."""
+    per program next to its ``-atime`` bookkeeping file (and
+    ``transfer/tile_rmw.py`` Pallas' byte code under ``pycache/``)."""
     try:
-        return sum(1 for f in os.listdir(path) if not f.endswith("-atime"))
+        return sum(1 for f in os.listdir(path) if f.endswith("-cache"))
     except FileNotFoundError:
         return 0
 

@@ -81,7 +81,7 @@ def _load_conf(path, config, minibatch):
     return global_config().load_conf(str(path)).parse()
 
 
-HEAD_ROWS = dict.fromkeys(("h", "h2sum", "v", "v2sum"), "head_rows")
+TILES = dict.fromkeys(("h", "h2sum", "v", "v2sum"), "tiles")
 
 
 def _span_positions(config, traffic, centers):
@@ -186,42 +186,45 @@ def _pair_grid(model, traffic):
 
 def _instructions(text):
     """(count, digest) of a compiled module's instructions: names,
-    operands and layouts, the metadata's source lines aside."""
-    lines = [re.sub(r", metadata=\{[^}]*\}", "", line.rstrip())
+    operands and layouts, the metadata's source lines aside — and a
+    Pallas kernel's serialized body, which carries its own."""
+    lines = [re.sub(r", metadata=\{[^}]*\}", "", re.sub(
+                 r'"custom_call_config":\{"body":"[^"]*"', "", line.rstrip()))
              for line in text.splitlines()
              if re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = ", line)]
     return (len(lines),
             hashlib.sha256("\n".join(lines).encode()).hexdigest()[:16])
 
 
-@pytest.mark.parametrize("cell, sweeps, temp_gib, span, program", [
-    # 5,500 target slots and a span of 768: the shapes rule the sweep
-    # out, the head's chunks are all there is
-    ("cbow2m-demo", 0, 0.01, 768, (3277, "6b8d64c91e461d03")),
-    # 180,224 target slots: chunks or one sweep, by the count; the
+@pytest.mark.parametrize("cell, temp_gib, span, program", [
+    # 5,500 target slots and a span of 768: pushes too small to be
+    # ordered behind the state (`_ORDERED_PUSH_BYTES`; with the barrier
+    # this step hangs the chip, PERF.md section 6, PR 47)
+    ("cbow2m-demo", 0.01, 768, (3099, "2b186f6eb59c8646")),
+    # 180,224 target slots: the longest head a one-chip cell pushes, which
+    # the parent swept where more than ~116 K of them were distinct; the
     # context push is the span's 22,528 slots (74.2 % of a Zipf stream's
-    # positions pass the center gate): chunks alone, where the per-pair
-    # grid's 163,840 slots had a conditional and a sweep of their own
-    ("cbow2m-b16k", 2, 1.0, 22_400, (3555, "a9dd094689dee825")),
+    # positions pass the center gate)
+    ("cbow2m-b16k", 1.0, 22_400, (3240, "220bbd61affcd651")),
     # uniform keys: nothing is gated, the span is B + 2W in whole tiles
-    ("cbow2m-b16k-uniform", 2, 1.0, 16_512, (3557, "aabc09dbe853d6f4")),
-    # 122,880 target slots: either; 20,480 input slots: chunks alone
-    ("sg2m-b2k", 2, 0.7, None, (3142, "db2d8ee1f418259d")),
+    ("cbow2m-b16k-uniform", 1.0, 16_512, (3242, "3921e16067a08727")),
+    # 122,880 target slots, 20,480 input slots
+    ("sg2m-b2k", 0.7, None, (2841, "228f632c7e5727a3")),
 ])
 def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
-                                  monkeypatch, cell, sweeps, temp_gib,
-                                  span, program):
+                                  monkeypatch, cell, temp_gib, span,
+                                  program):
     """A word2vec cell's train step at 2,340,001 rows on one v5e chip.
     The 300-wide rows are stored on 384 lanes (`access.stored_width`), so
     with no layout asked for the four fields come in and go out row-major
     (``{1,0:T(8,128)}``, the compiler's default at that width) and
     aliased, NO whole field is copied (the 300-wide table: 11 copies a
     step, 3.37 GiB of temporaries = one padded row-major field, ~108 ms)
-    — not around the loop over the head's chunks and not around the
-    conditional either (PR 34) —, the step fits the chip, and every push
-    writes back the rows at its head (`XlaTransfer.write_back_form`):
-    row by row inside the loop, swept only in the branch the shapes
-    allow."""
+    — not around the tile kernel, whose fields are aliased in to out
+    (PR 47) —, the step fits the chip, and every push writes back the
+    rows at its head (`XlaTransfer.write_back_form`) by their 8-row tiles:
+    one kernel a push under the ``apply`` scope, no scatter, no sweep, no
+    loop and no conditional of XLA's around a field (PR 34's forms)."""
     cluster, model, compiled = _w2v_step(topo, tmp_path, monkeypatch, cell)
     text, mem = compiled.as_text(), compiled.memory_analysis()
     capacity = model.table.capacity
@@ -272,36 +275,43 @@ def test_w2v_step_copies_no_field(topo, no_compile_cache, tmp_path,
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert total <= 15.75 * GIB, f"{total / GIB:.2f} GiB"
-    assert cluster.transfer.resolved_write_back == HEAD_ROWS
-    # a scatter of each field in a loop body, row by row, and where the
-    # shapes allow it one more in the conditional's other branch, swept
-    scatters = re.findall(rf"= {field}\S* scatter\(.*apply/.*scatter", text)
-    swept = sum("indices_are_sorted=true" in s for s in scatters)
-    assert (len(scatters) - swept, swept) == (4, sweeps)
-    assert len(re.findall(r" while\(.*apply/", text)) == 2
-    assert len(re.findall(r" conditional\(.*apply/", text)) == sweeps // 2
+    assert cluster.transfer.resolved_write_back == TILES
+    # one kernel a push, both of its fields aliased through it, booked
+    # under ``apply`` (what `obs.costs.phase_map` credits its time to) ...
+    kernels = re.findall(r" custom-call\(.*tpu_custom_call.*", text)
+    assert len(kernels) == 2
+    assert all('op_name="jit(step)/apply/rmw_tiles/' in k
+               and "output_to_operand_aliasing={{0}: (4, {}), {1}: (5, {})}"
+               in k for k in kernels)
+    # ... and beside it the one row of the fields' last, partial tile
+    # (2,340,001 = 1 mod 8), written in place where a push names it
+    assert not re.findall(rf"= {field}\S* scatter\(", text)
+    assert len(re.findall(
+        rf"= {field}\S* dynamic-update-slice\(.*apply/", text)) == 4
+    assert not re.findall(r" (?:while|conditional)\(", text)
 
 
-@pytest.mark.parametrize("cell, rows, parent, sweeps", [
+@pytest.mark.parametrize("cell, rows, parent, owner_slots", [
     # 65,536 centers, a span of 73,344 positions: a chip renders 18,336
     # positions (110,016 target slots), an owner is sent up to 4 x 34,384
-    # distinct target rows — a head that long may be cheaper swept, by the
-    # count — and 4 x 5,736 span rows
-    ("gnews3m-x4-b64k", (393_216, 73_344), (8_448_998_912, 2_426_327_040), 2),
+    # distinct target rows and 4 x 5,736 span rows
+    ("gnews3m-x4-b64k", (393_216, 73_344), (8_448_998_912, 2_426_327_040),
+     (137_536, 22_944)),
     # 16,384 centers, 18,432 positions: 4,608 a chip (27,648 target
-    # slots), 4 x 8,640 and 4 x 1,448 an owner: the chunks alone
-    ("gnews3m-x4-b16k", (98_304, 18_432), (6_632_095_232, 609_871_872), 0),
+    # slots), 4 x 8,640 and 4 x 1,448 an owner
+    ("gnews3m-x4-b16k", (98_304, 18_432), (6_632_095_232, 609_871_872),
+     (34_560, 5_792)),
 ])
 def test_w2v_x4_step_routes_rows_to_their_owners(
         topo, no_compile_cache, tmp_path, monkeypatch, cell, rows, parent,
-        sweeps):
+        owner_slots):
     """The table of the two four-chip cells is row-sharded over the
     ``model`` axis: the step runs split over it (ISSUE 43).  A chip holds
     nothing of the global batch's size — no row, gradient or index at the
     global target grid's or span's row count, no all-reduce of rows — what
     crosses chips is ``all-to-all``, every owner writes the rows at its
-    head into its OWN shard (``head_rows``, chosen from the shard's rows,
-    as one chip would), no capacity-sized buffer beside the four aliased
+    head into its OWN shard (``tiles``, chosen from the shard's rows, as
+    one chip would), no capacity-sized buffer beside the four aliased
     fields, and the step's peak and temporaries (``parent``: bytes a
     chip) are under the parent's (`92f1f4d`: three all-reduces of
     ``(global slots, 384)`` f32 and four field sweeps)."""
@@ -309,7 +319,7 @@ def test_w2v_x4_step_routes_rows_to_their_owners(
                                          chips=4)
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert cluster.transfer.shards == 4 and model._step_split()
-    assert cluster.transfer.resolved_write_back == HEAD_ROWS
+    assert cluster.transfer.resolved_write_back == TILES
     traffic = _cell(cell)[1]
     grid, span = rows
     assert model.stencil and model.span == span
@@ -328,20 +338,21 @@ def test_w2v_x4_step_routes_rows_to_their_owners(
     assert capacity == 975_001
     field = rf"f32\[{capacity},384\]"
     made = re.findall(rf"= {field}\S* ([\w\-]+)\(", text)
-    assert set(made) <= {"parameter", "get-tuple-element", "fusion",
-                         "scatter", "while", "conditional",
-                         "custom-call", "bitcast"}
+    assert set(made) <= {"parameter", "get-tuple-element", "bitcast",
+                         "dynamic-update-slice"}
     assert not re.findall(rf"= {field}\S* (?:copy|broadcast|constant)\(",
                           text)
     assert _carried_aliased(text)
-    # every write to a field is a scatter of rows into it, in place: the
-    # head's chunks and, where the shapes allow a longer head, one sweep
-    writes = [line for line in text.splitlines()
-              if re.search(rf"= {field}\S* fusion\(", line)]
-    assert writes and all("scatter" in w for w in writes)
-    scatters = re.findall(rf"= {field}\S* scatter\(.*apply/.*scatter", text)
-    swept = sum("indices_are_sorted=true" in s for s in scatters)
-    assert (len(scatters) - swept, swept) == (4, sweeps)
+    # every write to a field is the tile kernel's, in place in the
+    # owner's shard, on the slots the owner was sent — and one row of the
+    # shard's last, partial tile (975,001 = 1 mod 8)
+    assert not re.findall(rf"= {field}\S* (?:fusion|scatter)\(", text)
+    kernels = re.findall(r" custom-call\(.*tpu_custom_call.*", text)
+    assert [int(n) for k in kernels for n in re.findall(
+        r"operand_layout_constraints=\{s32\[1\]\{0\}, s32\[\d+\]\{0\}, "
+        r"f32\[\d+\]\{0\}, f32\[(\d+),384\]", k)] == sorted(owner_slots)
+    assert all("/apply/rmw_tiles/" in k for k in kernels)
+    assert not re.findall(r" conditional\(", text)
     assert (mem.peak_memory_in_bytes, mem.temp_size_in_bytes) < parent
     assert mem.temp_size_in_bytes < parent[1]
 
@@ -757,3 +768,61 @@ def test_nemotron3n_ep16_trainer_step_fits_one_chip(topo, no_compile_cache):
                     and math.prod(sizes) >= S * 64 * 64 * 128), f"[{dims}]"
     scopes = set(re.findall(r"[/(](ssm_\w+)(?=[/)])", text))
     assert scopes == {"ssm_mixer", "ssm_scan"} and scopes <= set(DEVICE_SCOPES)
+
+
+def _push_access(width, dtype):
+    """AdaGrad on one ``(param, accumulator)`` pair stored ``width`` lanes
+    wide, as given (no padding to whole tiles)."""
+    from swiftmpi_tpu.parameter.access import (AdaGradAccess, AdaGradRule,
+                                               FieldSpec)
+    return AdaGradAccess(
+        0.3, rules=(AdaGradRule("h", "h2sum", "h"),),
+        fields={"h": FieldSpec(width, dtype=dtype), "h2sum": FieldSpec(width)},
+        pull_fields=("h",))
+
+
+@pytest.mark.parametrize("width, dtype, shards, platform, slots, form, "
+                         "program", [
+    # rows of whole 128-lane f32 tiles on one TPU: the kernel's
+    (384, jnp.float32, 1, "tpu", 20_480, "tiles", None),
+    # ... unless the push is so long that one sweep of the fields is cheaper
+    (384, jnp.float32, 1, "tpu", 400_000, "sweep", None),
+    # every other push keeps the parent's program (`358056b`), by digest:
+    # a width that stays column-major, a half-width parameter, a table
+    # split by the partitioner, a backend told its devices are no TPUs,
+    # one-wide logistic rows
+    (300, jnp.float32, 1, "tpu", 20_480, "per_row", (370, "ad7b519fc1b758c5")),
+    (384, jnp.bfloat16, 1, "tpu", 5_000, "per_row", (329, "7fc2be6cb32cf36f")),
+    (384, jnp.float32, 4, "tpu", 163_840, "sweep", (376, "82f00e0ee6496e2d")),
+    (384, jnp.float32, 1, "cpu", 5_000, "per_row", (325, "0bc0f70a87c3f24b")),
+    (1, jnp.float32, 1, "tpu", 1_000, "sweep", (337, "9d96a2859728e5b4")),
+])
+def test_push_outside_the_kernel_s_predicate_compiles_as_before(
+        topo, no_compile_cache, width, dtype, shards, platform, slots, form,
+        program):
+    """`XlaTransfer.write_back_form` hands the tile kernel the pushes its
+    predicate names (dtype, width, platform, the rows held) and no other:
+    what it does not take lowers to the parent's text."""
+    from jax.sharding import SingleDeviceSharding
+
+    from swiftmpi_tpu.transfer.xla import XlaTransfer
+
+    one = SingleDeviceSharding(topo.devices[0])
+    access = _push_access(width, dtype)
+    backend = XlaTransfer(dense_apply=False, shards=shards, platform=platform)
+    rows = 2_340_001
+
+    def push(state, idx, g):
+        return backend.push(state, idx, {"h": g}, access, mean=True)
+
+    compiled = jax.jit(push, donate_argnums=0).lower(
+        {f: jax.ShapeDtypeStruct((rows, width), spec.dtype, sharding=one)
+         for f, spec in access.fields.items()},
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((slots, width), jnp.float32,
+                             sharding=one)).compile()
+    text = compiled.as_text()
+    assert backend.resolved_write_back == dict.fromkeys(("h", "h2sum"), form)
+    assert ("tpu_custom_call" in text) == (form == "tiles")
+    if program is not None:
+        assert _instructions(text) == program

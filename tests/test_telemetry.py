@@ -788,6 +788,82 @@ def test_rows_written_counts_each_push_s_distinct_rows(sg, monkeypatch,
 
 
 @pytest.mark.parametrize("sg", [0, 1], ids=["cbow", "sg"])
+def test_tiles_written_counts_the_tiles_the_kernel_moves(sg, monkeypatch,
+                                                         tmp_path):
+    """``tiles_written_per_step``: where a push takes the tile kernel
+    (one TPU, f32 rows of whole 128-lane tiles: here one CPU device whose
+    transfer is told it is one, the kernel in Pallas' interpret mode), the
+    distinct 8-row tiles its distinct valid rows lie in, times the fields
+    it touches; against the slots each push was handed.  It reads 0 where
+    every push is written another way."""
+    import jax
+    from jax.experimental.pallas import tpu as pltpu
+
+    from swiftmpi_tpu.cluster.cluster import Cluster
+    from swiftmpi_tpu.data.text import (CBOWBatcher, build_vocab,
+                                        synthetic_corpus)
+    from swiftmpi_tpu.models.word2vec import Word2Vec
+    from swiftmpi_tpu.transfer.xla import XlaTransfer
+    from swiftmpi_tpu.utils import ConfigParser
+
+    seen = []
+
+    def spy(name):
+        real = getattr(XlaTransfer, name)
+
+        def spying(self, state, slots, grads, *args, **kwargs):
+            fields = len(args[-1].touched_fields(grads))
+            jax.debug.callback(lambda s: seen.append(
+                (fields * np.unique(s[s >= 0]).size,
+                 fields * np.unique(s[s >= 0] // 8).size)), slots)
+            return real(self, state, slots, grads, *args, **kwargs)
+        monkeypatch.setattr(XlaTransfer, name, spying)
+
+    spy("push")
+    spy("push_span")
+    # a table of 64 rows is cheaper swept than the push's slots are moved
+    # by tiles: weigh a slot as nothing, as a table of the cells' size does
+    from swiftmpi_tpu.transfer import xla
+    monkeypatch.setattr(xla, "_TILE_SLOT_AS_SWEPT_BYTES", 0)
+    cfg = ConfigParser().update({
+        "cluster": {"transfer": "xla"},
+        "word2vec": {"len_vec": 128, "window": 3, "negative": 2, "sg": sg,
+                     "sample": -1, "learning_rate": 0.05},
+        "server": {"initial_learning_rate": 0.3},
+        "worker": {"minibatch": 64, "telemetry": 1,
+                   "telemetry_path": str(tmp_path / "telemetry.jsonl")},
+    })
+    corpus = synthetic_corpus(12, vocab_size=60, length=12, seed=4)
+    metrics = {}
+    for platform in ("tpu", "cpu"):
+        del seen[:]
+        cluster = Cluster(cfg, devices=jax.devices()[:1]).initialize()
+        cluster.transfer.platform = platform
+        # every push sparse: the dense form sweeps the table, no tiles
+        cluster.transfer.dense_apply = False
+        model = Word2Vec(config=cfg, cluster=cluster)
+        model.build_from_vocab(build_vocab(corpus))
+        with pltpu.force_tpu_interpret_mode():
+            model.train(batcher=CBOWBatcher(corpus, model.vocab,
+                                            model.window),
+                        niters=1, batch_size=16)
+        jax.effects_barrier()
+        metrics[platform] = (model.train_metrics, list(seen), set(
+            cluster.transfer.resolved_write_back.values()))
+    m, pushes, forms = metrics["tpu"]
+    steps = len(pushes) // 2
+    assert steps > 4 and forms == {"tiles"}
+    rows, tiles = (sum(p[k] for p in pushes) / steps for k in (0, 1))
+    assert m["rows_written_per_step"] == pytest.approx(rows, rel=1e-6)
+    assert m["tiles_written_per_step"] == pytest.approx(tiles, rel=1e-6)
+    assert rows / 8 <= tiles < rows          # some rows share a tile
+    m, _, forms = metrics["cpu"]
+    assert forms <= {"per_row", "sweep"}
+    assert m["rows_written_per_step"] == pytest.approx(rows, rel=1e-6)
+    assert m["tiles_written_per_step"] == 0
+
+
+@pytest.mark.parametrize("sg", [0, 1], ids=["cbow", "sg"])
 def test_pair_counters_absent_with_telemetry_off(sg, monkeypatch, tmp_path,
                                                  devices8):
     from swiftmpi_tpu.models import word2vec
@@ -802,8 +878,9 @@ def test_pair_counters_absent_with_telemetry_off(sg, monkeypatch, tmp_path,
     assert fed.valid and not obs.get_registry().enabled
     assert "pairs_per_step" not in model.train_metrics
     assert "pair_fill_share" not in model.train_metrics
-    # ... and the step was built without the row-write counter
+    # ... and the step was built without the row-write counters
     assert "rows_written_per_step" not in model.train_metrics
+    assert "tiles_written_per_step" not in model.train_metrics
 
 
 def test_uncounted_batches_export_no_pair_series(tmp_path, devices8):

@@ -606,7 +606,7 @@ def test_routed_push_equals_one_shard(n, case, kind, devices8):
         np.testing.assert_allclose(np.asarray(got[f]), np.asarray(want[f]),
                                    rtol=2e-5, atol=2e-6, err_msg=f)
     # the distinct rows do not depend on who sorts them
-    assert int(got_rows[0]) == int(want_rows[0])
+    assert int(got_rows[0][0]) == int(want_rows[0][0])
     (rows, offered), = tape
     assert 0 <= int(rows) <= int((slots >= 0).sum())
     assert (int(offered) == 0) == (case == "all_padding")

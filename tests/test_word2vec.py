@@ -26,6 +26,42 @@ def test_alias_sampler_matches_unigram_075():
     np.testing.assert_allclose(freq, expect, atol=0.01)
 
 
+def _alias_on_numpy_scalars(counts, power=0.75):
+    """`build_unigram_alias` as it stood until PR 47: Vose's pairing on
+    numpy float64 scalars."""
+    w = np.asarray(counts, np.float64) ** power
+    p = w / w.sum() * len(w)
+    prob = np.ones(len(w), np.float64)
+    alias = np.arange(len(w), dtype=np.int32)
+    small = [i for i, x in enumerate(p) if x < 1.0]
+    large = [i for i, x in enumerate(p) if x >= 1.0]
+    while small and large:
+        s, l = small.pop(), large.pop()
+        prob[s] = p[s]
+        alias[s] = l
+        p[l] = p[l] - (1.0 - p[s])
+        (small if p[l] < 1.0 else large).append(l)
+    for i in small + large:
+        prob[i] = 1.0
+    return prob.astype(np.float32), alias
+
+
+@pytest.mark.parametrize("counts", [
+    np.array([7.0]), np.full(1000, 3.0), np.arange(1, 5001),
+    1e6 / np.arange(1, 20_001) ** 1.07,       # a Zipf stream's counts
+    np.random.default_rng(3).integers(1, 10_000, 30_001),
+], ids=["one", "ties", "ramp", "zipf", "random"])
+def test_alias_tables_are_the_numpy_pairing_s_bit_for_bit(counts):
+    """PR 47 pairs the buckets on Python floats and lists (half the time
+    at 1.8 M words, a set-up cost alone): the same IEEE doubles, so the
+    same ``prob`` and ``alias``, bit for bit, dtype for dtype."""
+    prob, alias = build_unigram_alias(counts)
+    want_prob, want_alias = _alias_on_numpy_scalars(counts)
+    assert (prob.dtype, alias.dtype) == (np.float32, np.int32)
+    np.testing.assert_array_equal(prob, want_prob)
+    np.testing.assert_array_equal(alias, want_alias)
+
+
 @pytest.mark.parametrize("shape,mode", [
     ((5,), "per_draw"),
     ((776,), "per_draw"),

@@ -3,27 +3,34 @@ writes the ``local`` oracle's rows, bit for bit.
 
 ``XlaTransfer.write_back_form`` chooses, from static shapes, between
 writing a push's rows one by one (``per_row``) and one sweep of the whole
-field (``sweep``) and, on the fields it can (PR 34: f32 rows of whole
-128-lane tiles, one TPU), writing the distinct rows at the head of the
-push alone, a chunk at a time, or sweeping, as their count says at run
-time (``head_rows``): a choice of device time (PERF.md section 6, PR 30,
-PR 34), never of values.  Every case here runs the push in EVERY form,
-twice: op by op, where the access rule's arithmetic is the oracle's own
-sequence of primitives and every field must equal the numpy oracle's bit
-for bit (``assert_array_equal``); and under ``jit``, as a train step runs
-it, where the forms must equal each other bit for bit and stay within
-one rounding of the oracle (XLA fuses the rule's arithmetic there; so it
-does op by op in ``head_rows``, whose loop body is one compiled program:
-held there as the jitted ones are).  The
-gradients are multiples of 1/64 and every slot repeats 1, 2 or 4 times,
-so the oracle's ``sum / count`` and the backend's ``sum * (1 / count)``
-are the same float.
+field (``sweep``) and, on the fields it can (f32 rows of whole 128-lane
+tiles, one TPU), moving the 8-row tiles of the distinct rows at the head
+of the push through one Pallas kernel (``tiles``, PR 47;
+``transfer/tile_rmw.py``): a choice of device time (PERF.md section 6,
+PR 30, PR 34, PR 47), never of values.  Every case here runs the push in
+EVERY form, twice: op by op, where the access rule's arithmetic is the
+oracle's own sequence of primitives and every field must equal the numpy
+oracle's bit for bit (``assert_array_equal``); and under ``jit``, as a
+train step runs it, where the forms must equal each other bit for bit
+and stay within one rounding of the oracle (XLA fuses the rule's
+arithmetic there; so it does op by op in ``tiles``, whose kernel body is
+one compiled program: held there to its own jitted run, bit for bit, a
+mean push's within one rounding).  The kernel
+runs in Pallas' TPU interpret mode here: its copies, semaphores and
+control flow as written, the chip's compiler aside
+(``tests/test_compile_v5e.py`` has that).  On the row-sharded table it
+runs where the program runs it there: on each owner's shard, the rows
+routed to their owners by a backend told of the mesh (the other forms go
+through the partitioner, as before).  The gradients are multiples of
+1/64 and every slot repeats 1, 2 or 4 times, so the oracle's ``sum /
+count`` and the backend's ``sum * (1 / count)`` are the same float.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from swiftmpi_tpu.cluster import SHARD_AXIS, ps_mesh
 from swiftmpi_tpu.parameter import KeyIndex, SparseTable, lr_access, w2v_access
@@ -31,16 +38,13 @@ from swiftmpi_tpu.transfer.local import LocalTransfer
 from swiftmpi_tpu.transfer.xla import XlaTransfer
 
 FORMS = ("per_row", "sweep")
-#: widths whose stored row is whole 128-lane tiles also take
-#: ``head_rows``: bare where the shapes rule the sweep out (the loop over
-#: the head's chunks alone), ``+`` / ``-`` where they do not and the count
-#: at run time picks the chunks / the sweep
-HEAD_FORMS = ("head_rows", "head_rows+", "head_rows-")
 SHARDS = 4
-CAP_PER_SHARD = 24
-#: slots a chunk of `_rmw_head_rows` here: the 12 distinct rows of a
-#: batch are two chunks, the second one ragged
-HEAD_CHUNK = 8
+#: 25 rows: the last one's tile reaches past the fields (`_rmw_tiles`
+#: writes it row by row)
+CAP_PER_SHARD = 25
+#: slots a grid step of the kernel here, and its ring's slots: the 12
+#: distinct rows of a batch are two steps, the ring is taken twice over
+BLOCK, DEPTH = 8, 4
 
 
 def _batch(case, table, width, seed):
@@ -73,12 +77,13 @@ def _batch(case, table, width, seed):
                                   "all_padding", "empty"])
 def test_write_back_forms_match_oracle_bitwise(case, width, mean, sharded,
                                                monkeypatch):
-    from swiftmpi_tpu.transfer import xla
+    from swiftmpi_tpu.transfer import tile_rmw
 
     if sharded and len(jax.devices()) < SHARDS:
         pytest.skip(f"needs {SHARDS} virtual devices")
     mesh = ps_mesh(n=SHARDS) if sharded else None
-    monkeypatch.setattr(xla, "_HEAD_CHUNK", HEAD_CHUNK)
+    monkeypatch.setattr(tile_rmw, "BLOCK", BLOCK)
+    monkeypatch.setattr(tile_rmw, "DEPTH", DEPTH)
     # d = 1: the logistic table; else word2vec's, pushing the ``h``
     # family alone, so ``v`` / ``v2sum`` must come back untouched
     access = lr_access(0.3) if width == 1 else w2v_access(0.3, width)
@@ -91,7 +96,11 @@ def test_write_back_forms_match_oracle_bitwise(case, width, mean, sharded,
     # beyond the vector: `access.stored_width`)
     stored = table.state[family].shape[1]
     assert stored == {1: 1, 300: 384, 128: 128, 384: 384}[width]
-    forms = FORMS + (HEAD_FORMS if stored % 128 == 0 else ())
+    # widths whose stored row is whole 128-lane tiles also take the
+    # kernel, on the rows one device holds: the whole table, or — the
+    # rows routed to their owners, as a backend told of the mesh does —
+    # an owner's shard
+    forms = FORMS + (("tiles",) if stored % 128 == 0 else ())
     slots, grad = _batch(case, table, stored, seed=width + 7 * mean)
     state_np = {f: np.asarray(v) for f, v in table.state.items()}
     want = LocalTransfer().push(state_np, slots, {family: grad}, access,
@@ -101,48 +110,48 @@ def test_write_back_forms_match_oracle_bitwise(case, width, mean, sharded,
         return backend.push(state, slots, {family: grad}, access, mean=mean)
 
     eager, jitted = {}, {}
-    for form_case in forms:
-        form = form_case.rstrip("+-")
-        backend = XlaTransfer(dense_apply=False,
-                              shards=SHARDS if sharded else 1)
+    for form in forms:
+        backend = XlaTransfer(
+            dense_apply=False, shards=SHARDS if sharded else 1,
+            **(dict(mesh=mesh, axis=SHARD_AXIS)
+               if sharded and form == "tiles" else {}))
         monkeypatch.setattr(backend, "write_back_form",
                             lambda n, fields, form=form: form)
-        if form == "head_rows":
-            # what the shapes say of the sweep, and what a row written
-            # weighs against it: here, the case's
-            monkeypatch.setattr(
-                backend, "_static_form", lambda n, fields, may=form_case
-                != form: "sweep" if may else "per_row")
-            monkeypatch.setattr(xla, "_ROW_WRITE_AS_SWEPT_BYTES",
-                                1 << 24 if form_case.endswith("-") else 1)
-        out = push(backend, table.state, jnp.asarray(slots),
-                   jnp.asarray(grad))
-        eager[form_case] = {f: np.asarray(v) for f, v in out.items()}
-        if len(slots):
-            touched = access.touched_fields([family])
-            assert backend.resolved_write_back == dict.fromkeys(touched, form)
-        out = jax.jit(push, static_argnums=0)(
-            backend, table.state, jnp.asarray(slots), jnp.asarray(grad))
+        with pltpu.force_tpu_interpret_mode():
+            out = push(backend, table.state, jnp.asarray(slots),
+                       jnp.asarray(grad))
+            eager[form] = {f: np.asarray(v) for f, v in out.items()}
+            if len(slots):
+                touched = access.touched_fields([family])
+                assert backend.resolved_write_back == dict.fromkeys(touched,
+                                                                    form)
+            out = jax.jit(push, static_argnums=0)(
+                backend, table.state, jnp.asarray(slots), jnp.asarray(grad))
         if sharded:
             assert out[family].sharding.is_equivalent_to(
                 table.state[family].sharding, 2)
-        jitted[form_case] = {f: np.asarray(v) for f, v in out.items()}
+        jitted[form] = {f: np.asarray(v) for f, v in out.items()}
         for f in access.fields:
-            if form == "head_rows":
-                np.testing.assert_array_equal(jitted[form_case][f],
-                                              eager[form_case][f],
-                                              err_msg=f"{form_case}:{f}")
+            if form == "tiles":
+                # one compiled program either way, fused as the compiler
+                # likes: held to the jitted one, as that is to the oracle
+                # — bit for bit, but for a mean push: jitted, interpret
+                # mode's kernel is inlined into the program that computes
+                # ``1 / count``, and the CPU's compiler rounds their
+                # product as it likes (one ulp in two of the 16 cases)
+                np.testing.assert_allclose(jitted[form][f], eager[form][f],
+                                           rtol=2e-7 if mean else 0, atol=0,
+                                           err_msg=f"{form}:{f}")
             else:
-                np.testing.assert_array_equal(want[f], eager[form_case][f],
-                                              err_msg=f"{form_case}:{f}")
-            np.testing.assert_allclose(want[f], jitted[form_case][f],
+                np.testing.assert_array_equal(want[f], eager[form][f],
+                                              err_msg=f"{form}:{f}")
+            np.testing.assert_allclose(want[f], jitted[form][f],
                                        rtol=1e-6, atol=1e-7,
-                                       err_msg=f"jit {form_case}:{f}")
-    for form_case in forms:
+                                       err_msg=f"jit {form}:{f}")
+    for form in forms:
         for f in access.fields:
-            np.testing.assert_array_equal(jitted["sweep"][f],
-                                          jitted[form_case][f],
-                                          err_msg=f"{form_case}:{f}")
+            np.testing.assert_array_equal(jitted["sweep"][f], jitted[form][f],
+                                          err_msg=f"forms {form}:{f}")
     if case in ("all_padding", "empty"):
         for f in access.fields:
             np.testing.assert_array_equal(state_np[f], jitted["sweep"][f])
@@ -170,9 +179,17 @@ CELLS = [
     (100_000, 3_900_004, 384, 4, "cpu", "sweep"),    # ... a shard is a quarter
     (1_000, 1 << 20, 1, 1, "cpu", "sweep"),          # d = 1: a cheap sweep
     (100, 1 << 20, 1, 1, "cpu", "per_row"),
-    # one TPU, rows of whole 128-lane tiles: the head's rows, any size
-    *((*c[:4], "tpu", "head_rows") for c in CELLS[:6]),
-    (100_000, 3_900_004, 128, 1, "tpu", "head_rows"),
+    # one TPU, rows of whole 128-lane tiles: the head's tiles ...
+    *((*c[:4], "tpu", "tiles") for c in CELLS[:6]),
+    (180_224, 2_340_001, 384, 1, "tpu", "tiles"),    # cbow2m-b16k, targets
+    (100_000, 3_900_004, 128, 1, "tpu", "tiles"),
+    # ... an owner's shard of gnews3m-x4-b64k and its longest push too ...
+    (137_536, 975_001, 384, 1, "tpu", "tiles"),
+    # ... until the push names most tiles of the fields: one sweep then
+    # (`_TILE_SLOT_AS_SWEPT_BYTES`: from 378,342 slots on a 3.59 GB field)
+    (378_000, 2_340_001, 384, 1, "tpu", "tiles"),
+    (400_000, 2_340_001, 384, 1, "tpu", "sweep"),
+    (200_000, 975_001, 384, 1, "tpu", "sweep"),
     # a TPU, and everything else keeps the shapes' answer: four shards,
     # 300 wide (column-major by default), one wide
     (655_360, 3_900_004, 384, 4, "tpu", "sweep"),
@@ -196,6 +213,34 @@ def test_write_back_form_wants_f32_rows_and_names_its_platform():
     assert XlaTransfer(platform="tpu").write_back_form(5_000, rows) \
         == "per_row"
     assert XlaTransfer().platform == jax.devices()[0].platform
+
+
+@pytest.mark.parametrize("slots, ordered", [
+    (768, False), (5_500, False),       # cbow2m-demo: 1.2 and 8.4 MB
+    (10_922, False), (10_923, True),    # 16 MiB less a row, and with it
+    (20_480, True), (22_400, True),     # sg2m-b2k's inputs, b16k's span
+    (180_224, True),                    # b16k's targets: 277 MB
+])
+def test_tiles_push_is_ordered_behind_its_state_from_16_mib(slots, ordered):
+    """Which ``tiles`` pushes `_push_rows` puts an `optimization_barrier`
+    before (`_ORDERED_PUSH_BYTES`, by the gradients' bytes): the one-chip
+    cells' but cbow2m-demo's, whose step hangs the v5e with it
+    (``scripts/barrier_hang_repro.py``; PERF.md section 6, PR 47)."""
+    access = w2v_access(0.3, 300)
+    backend = XlaTransfer(dense_apply=False, platform="tpu")
+    fields = {f: jax.ShapeDtypeStruct((2_340_001, 384), jnp.float32)
+              for f in access.fields}
+    jaxpr = jax.make_jaxpr(lambda state, idx, g: backend.push(
+        state, idx, {"h": g}, access, mean=True))(
+            fields, jax.ShapeDtypeStruct((slots,), jnp.int32),
+            jax.ShapeDtypeStruct((slots, 384), jnp.float32))
+    assert set(backend.resolved_write_back.values()) == {"tiles"}
+    barriers = [e for e in jaxpr.eqns
+                if e.primitive.name == "optimization_barrier"]
+    assert len(barriers) == ordered
+    if ordered:     # every field of the state and the gradients, no less
+        assert [v.aval.shape for v in barriers[0].invars] == [
+            (2_340_001, 384)] * 4 + [(slots, 384)]
 
 
 @pytest.mark.parametrize("mean", [False, True])
@@ -230,3 +275,109 @@ def test_span_push_is_the_sparse_push_with_counts(monkeypatch, mean):
     for f in access.fields:
         np.testing.assert_allclose(want[f], np.asarray(got[f]), rtol=1e-6,
                                    atol=1e-7, err_msg=f)
+
+
+def _tile_case(case, capacity, rng):
+    """``(rows at the head, batch length)`` of a kernel case over
+    ``capacity`` rows, `BLOCK` = 8 slots a grid step."""
+    whole = capacity - capacity % 8
+    if case.startswith("share_"):
+        # k rows of one tile, a lone row either side of them
+        k = int(case[6:])
+        return np.concatenate([[3], 16 + np.sort(rng.permutation(8)[:k]),
+                               [41]]), 16
+    if case == "block_edge":
+        # slots 6..9 lie in one tile: the run crosses the first step's end
+        return np.array([0, 9, 17, 25, 33, 41, 48, 49, 52, 55, 57, 70]), 24
+    if case == "n_0":
+        return np.zeros(0, np.int64), 16
+    if case == "n_B":
+        return np.sort(rng.permutation(whole)[:16]), 16
+    if case == "last_tile":
+        # every row of the partial tile at the fields' end, behind rows of
+        # whole ones: not the kernel's
+        return np.concatenate([[2, 11, whole - 1],
+                               np.arange(whole, capacity)]), 24
+    if case == "only_last_tile":
+        return np.arange(whole, capacity), 8
+    return np.sort(rng.permutation(whole)[:11]), 16   # mean_inv, sgd
+
+
+@pytest.mark.parametrize("width", [128, 384])
+@pytest.mark.parametrize("case, capacity", [
+    *((f"share_{k}", 96) for k in range(2, 9)), ("block_edge", 96),
+    ("n_0", 96), ("n_B", 96), ("last_tile", 97), ("last_tile", 103),
+    ("only_last_tile", 97), ("only_last_tile", 103), ("mean_inv", 96),
+    ("sgd", 96)])
+def test_tile_kernel_matches_the_row_by_row_write(case, capacity, width,
+                                                  monkeypatch):
+    """`_rmw_tiles` against `_rmw_rows` on the same ascending head: rows
+    that share a tile (read once, written once), a run across a grid
+    step's edge (the step drains its writes, the next reads the tile
+    again), an empty head and a full one, rows in the partial tile at the
+    fields' end (``capacity % 8`` 1 and 7: written row by row), a mean
+    push's ``1 / count``, a rule without an accumulator.  Rows the push
+    does not name come back bit for bit — the other seven of a named
+    row's tile among them — and the named ones equal `_rmw_rows`' bit for
+    bit, a mean push's too."""
+    from swiftmpi_tpu.parameter.access import FieldSpec, SGDAccess, zeros_init
+    from swiftmpi_tpu.transfer import tile_rmw, xla
+
+    monkeypatch.setattr(tile_rmw, "BLOCK", BLOCK)
+    monkeypatch.setattr(tile_rmw, "DEPTH", DEPTH)
+    rng = np.random.default_rng(len(case) + capacity + width)
+    head, B = _tile_case(case, capacity, rng)
+    rows = np.full(B, capacity, np.int32)
+    rows[:len(head)] = head
+    if case == "sgd":
+        access = SGDAccess(0.3, {"h": FieldSpec(width, zeros_init)}, ("h",),
+                           ("h",))
+        names = ("h",)
+    else:
+        access, names = w2v_access(0.3, width), ("h", "h2sum")
+    fields = {f: jnp.asarray(rng.random((capacity, width)) + 0.5,
+                             jnp.float32) for f in names}
+    grads = {"h": jnp.asarray(rng.normal(size=(B, width)), jnp.float32)}
+    inv = jnp.asarray(1.0 / rng.integers(1, 5, (B, 1)), jnp.float32) \
+        if case == "mean_inv" else None
+    n = jnp.int32(len(head))
+
+    want = jax.jit(lambda *a: xla._rmw_rows(*a, access, sweep=False,
+                                            inv=inv))(fields, rows, grads)
+    with pltpu.force_tpu_interpret_mode():
+        got = jax.jit(lambda *a: xla._rmw_tiles(*a, access, n, inv=inv))(
+            fields, rows, grads)
+    named = np.zeros(capacity, bool)
+    named[head] = True
+    for f in names:
+        w, g = np.asarray(want[f]), np.asarray(got[f])
+        np.testing.assert_array_equal(g[~named], np.asarray(fields[f])[~named],
+                                      err_msg=f)
+        np.testing.assert_array_equal(g, w, err_msg=f)
+        if len(head):
+            assert (g[named] != np.asarray(fields[f])[named]).any()
+
+
+def test_pallas_byte_code_is_kept_in_the_compile_cache(tmp_path):
+    """The kernel's one set-up cost that is not a compile: importing
+    Pallas where the installation keeps no byte code.  `tile_rmw._pallas`
+    keeps it in the persistent compile cache's directory, in a process
+    that has one, and leaves the interpreter's own settings as they
+    were."""
+    import os
+    import subprocess
+    import sys
+    code = (
+        "import sys, jax\n"
+        f"jax.config.update('jax_compilation_cache_dir', {str(tmp_path)!r})\n"
+        "from swiftmpi_tpu.transfer import tile_rmw\n"
+        "assert 'jax.experimental.pallas' not in sys.modules\n"
+        "held = sys.dont_write_bytecode, sys.pycache_prefix\n"
+        "pl, pltpu = tile_rmw._pallas()\n"
+        "assert (sys.dont_write_bytecode, sys.pycache_prefix) == held\n"
+        "assert pl.pallas_call and pltpu.make_async_copy\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120,
+                   env={**os.environ, "JAX_PLATFORMS": "cpu",
+                        "PYTHONDONTWRITEBYTECODE": "1"})
+    kept = list((tmp_path / "pycache").rglob("pallas_call.*.pyc"))
+    assert kept, sorted(p.name for p in tmp_path.iterdir())
