@@ -20,9 +20,18 @@ has them; the ring depths and block sizes tried cost the same, PERF.md
 section 6, and left the script with the kernel's arguments), the read half for
 the floor, and two heads as long as a sparse push gets (a quarter and
 nearly half of the rows: beyond that a push is dense) for the sweep
-against the kernel where most tiles are named.
+against the kernel where most tiles are named.  ``--width 384 --cases
+runs`` (PR 48) prices a COPY of the kernel by its length: heads of 98,304
+tiles, a row each, in runs of exactly 1, 2, 4 and 8 adjacent tiles, every
+run one copy each way (``tiles_x2@8``: `tile_rmw.RUN` = 8) — the same rows
+and bytes, an eighth of the copies; then the cells' own heads, drawn as
+the cells draw them (`_cell_head`: a row's slot is its frequency rank, so
+the head of the table is dense), at 1, 2, 4 and 8 tiles a copy at most,
+and the kernel without its copies, without its update and without both.
 
     python scripts/writeback_micro.py                # on the chip
+    python scripts/writeback_micro.py --tree .parent --out parent.json ...
+        # the same cases on another checkout's kernel, in the same call
     JAX_PLATFORMS=cpu python scripts/writeback_micro.py --compile-only DIR
         # no chip: compile every form for a described v5e, write the HLO
 
@@ -30,6 +39,7 @@ Writes ``chiprun_out/writeback_micro.json`` and prints the table.
 """
 
 import argparse
+import contextlib
 import glob
 import json
 import os
@@ -37,8 +47,13 @@ import re
 import shutil
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
-    __file__))))
+#: the checkout whose ``swiftmpi_tpu`` is timed: this one, or ``--tree DIR``
+#: (a parent's ``git archive``: its kernel in the same call, on the same
+#: heads)
+TREE = os.path.abspath(
+    sys.argv[sys.argv.index("--tree") + 1] if "--tree" in sys.argv
+    else os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, TREE)
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax  # noqa: E402
@@ -67,6 +82,22 @@ SIZES = {"5k": (5000, 5500), "100k": (100000, 110000),
          # a quarter of the rows, and the longest sparse push
          # (`XlaTransfer.pushes_dense`: half the rows go dense)
          "585k_of_585k": (585000, 585000), "1.1m_of_1.1m": (1100000, 1100000)}
+#: tiles of a `RUN_SIZES` head, a row each
+RUN_TILES = 98304
+#: heads whose named tiles stand in runs of exactly so many, an un-named
+#: tile between two runs: ``runs_of_<L>``
+RUN_SIZES = {f"runs_of_{L}": L for L in (1, 2, 4, 8)}
+#: the cells' pushes as the cells draw them: (slots, of them centers or
+#: positive contexts, the rest negatives, uniform keys) — cbow2m-b16k's
+#: targets, sg2m-b2k's, cbow2m-demo's, cbow2m-b16k-uniform's
+CELL_SIZES = {"zipf_b16k": (180224, 16384, False),
+              "zipf_sg": (122880, 20480, False),
+              "zipf_demo": (5500, 500, False),
+              "flat_b16k": (180224, 16384, True)}
+SIZES.update({size: (RUN_TILES, RUN_TILES) for size in RUN_SIZES})
+# a cell's head is as long as its draw makes it: `measure` writes it here
+SIZES.update({size: (slots, slots) for size, (slots, *_) in
+              CELL_SIZES.items()})
 ISSUE_SIZES = ("5k", "100k")
 HEAD_SIZES = ("3.5k_of_5.5k", "2k_of_20k", "60k_of_123k", "63k_of_164k",
               "146k_of_180k", "180k_of_180k")
@@ -164,16 +195,100 @@ class _Access:
         return {"x": _apply(current["x"], grads["x"])}
 
 
+def _fields(out):
+    """The fields `tile_rmw.rmw_tiles` returns (since PR 48 beside its
+    count of copies)."""
+    return out[0] if isinstance(out, tuple) else out
+
+
 def form_tiles(x, rep, g):  # the program's tile kernel
-    return tile_rmw.rmw_tiles({"x": x}, rep, {"x": g}, _Access,
-                              jnp.sum(rep < CAP, dtype=jnp.int32))["x"]
+    return _fields(tile_rmw.rmw_tiles(
+        {"x": x}, rep, {"x": g}, _Access,
+        jnp.sum(rep < CAP, dtype=jnp.int32)))["x"]
 
 
-def form_tiles_x2(xs, rep, g):  # ... on a word2vec push: AdaGrad, 2 fields
-    out = tile_rmw.rmw_tiles(dict(zip(("h", "h2sum"), xs)), rep, {"h": g},
-                             w2v_access(0.05, D),
-                             jnp.sum(rep < CAP, dtype=jnp.int32))
+def form_tiles_x2(xs, rep, g, access=None):  # ... AdaGrad, 2 fields
+    out = _fields(tile_rmw.rmw_tiles(
+        dict(zip(("h", "h2sum"), xs)), rep, {"h": g},
+        access or w2v_access(0.05, D), jnp.sum(rep < CAP, dtype=jnp.int32)))
     return out["h"], out["h2sum"]
+
+
+class _NoCopy:
+    """A DMA that is neither started nor awaited (the ablation)."""
+
+    def __init__(self, *_):
+        pass
+
+    start = wait = __init__
+
+
+class _SameRows:
+    """The access rule that leaves its rows as they are (the ablation:
+    the kernel's sublane loads and stores stay, the arithmetic goes)."""
+
+    @staticmethod
+    def apply_push(current, grads):
+        return dict(current)
+
+
+@contextlib.contextmanager
+def _kernel(run, depth, copies):
+    """`tile_rmw` with ``run`` tiles a copy at most and ``depth`` ring
+    slots (0: its own) and, for the ablation, without its copies, while a
+    form is traced."""
+    held = getattr(tile_rmw, "RUN", 1), tile_rmw.DEPTH, tile_rmw._pallas
+    pl, pltpu = held[2]()
+
+    class NoDma:
+        make_async_copy = _NoCopy
+
+        def __getattr__(self, name):
+            return getattr(pltpu, name)
+    tile_rmw.RUN = run or held[0]
+    tile_rmw.DEPTH = depth or held[1]
+    if not copies:
+        tile_rmw._pallas = lambda: (pl, NoDma())
+    try:
+        yield
+    finally:
+        tile_rmw.RUN, tile_rmw.DEPTH, tile_rmw._pallas = held
+
+
+def _run_of(form):
+    """``(C, ring slots)`` of ``tiles...[@C[xSLOTS]][-...]``; 0: the
+    kernel's own."""
+    run, _, depth = form.partition("-")[0].partition("@")[2].partition("x")
+    return int(run or 0), int(depth or 0)
+
+
+def _tiles_x2_as(form):
+    """``tiles_x2[@C[xSLOTS]][-copies|-update|-both]``: `form_tiles_x2` at
+    C tiles a copy at most (and so many ring slots), less what the
+    ablation takes out."""
+    less = form.partition("-")[2]
+    access = _SameRows if less in ("update", "both") else None
+
+    def form_x2(xs, rep, g):
+        with _kernel(*_run_of(form), copies=less not in ("copies", "both")):
+            return form_tiles_x2(xs, rep, g, access)
+    return form_x2
+
+
+def _copies(form, head):
+    """Copies ``form``'s kernel makes one way a field for the ascending
+    rows ``head``, and the tiles they move: a run of adjacent tiles is
+    cut every `tile_rmw.RUN` tiles and where a grid step ends."""
+    if not form.startswith("tiles"):
+        return None, None
+    run = _run_of(form)[0] or getattr(tile_rmw, "RUN", 1)
+    step, tile = np.arange(len(head)) // tile_rmw.BLOCK, head >> 3
+    new = np.r_[True, (tile[1:] != tile[:-1]) | (step[1:] != step[:-1])]
+    step, tile = step[new], tile[new]
+    first = np.flatnonzero(np.r_[True, (tile[1:] != tile[:-1] + 1)
+                                 | (step[1:] != step[:-1])])
+    return (int(np.sum(-(-np.diff(np.r_[first, len(tile)]) // run))),
+            len(tile))
 
 
 def form_gather_only(x, rep, g):  # the floor: the read half, no write
@@ -188,7 +303,7 @@ FORMS = {"a": form_a, "b": form_b, "c": form_c, "d": form_d, "e": form_e,
 
 
 def _jitted(form, size):
-    fn = FORMS[form]
+    fn = FORMS.get(form) or _tiles_x2_as(form)
 
     def wb(x, rep, g):
         return fn(x, rep, g)
@@ -210,6 +325,21 @@ def _set_layout(layout, sharding):
 
 
 def _cases(which):
+    if which == "runs":
+        # a copy by its length; a length-8 head tile by tile; the floor
+        yield from (("tiles_x2@8", size) for size in RUN_SIZES)
+        yield from (("tiles_x2@1", "runs_of_8"), ("tiles_x2@8-copies",
+                                                  "runs_of_8"))
+        # the cells' heads by the tiles a copy may take, and against XLA
+        yield from ((f"tiles_x2@{run}", size) for size in CELL_SIZES
+                    for run in (1, 2, 4, 8))
+        yield from ((form, "zipf_b16k") for form in (
+            "a", "tiles", "tiles_x2-copies", "tiles_x2-update",
+            "tiles_x2-both", "tiles_x2@8x16", "tiles_x2@8x64", "tiles_x2@16"))
+        # PR 47's uniform heads: the fewest neighbours a draw leaves
+        yield from (("tiles_x2", size) for size in (
+            "3.5k_of_5.5k", "60k_of_123k", "146k_of_180k"))
+        return
     if which == "head":
         yield from ((form, size) for size in HEAD_SIZES
                     for form in HEAD_FORMS)
@@ -218,7 +348,8 @@ def _cases(which):
         return
     for size in SIZES:
         for form in FORMS:
-            if size in HEAD_SIZES + DENSE_SIZES or form.startswith("tiles"):
+            if (size in HEAD_SIZES + DENSE_SIZES + tuple(RUN_SIZES)
+                    + tuple(CELL_SIZES) or form.startswith("tiles")):
                 continue
             if form == "g" and size != "5k":
                 continue
@@ -227,7 +358,7 @@ def _cases(which):
             yield form, size
 
 
-def compile_only(out_dir, layout, which):
+def compile_only(out_dir, layout, which, only):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     topo = topologies.get_topology_desc(platform="tpu",
@@ -236,11 +367,13 @@ def compile_only(out_dir, layout, which):
     _set_layout(layout, dev)
     os.makedirs(out_dir, exist_ok=True)
     for form, size in _cases(which):
+        if only is not None and form not in only and size not in only:
+            continue
         B = SIZES[size][1]
         field = jax.ShapeDtypeStruct((CAP, D), jnp.float32,
                                      sharding=FIELD_FORMAT or dev)
         c = _jitted(form, size).lower(
-            (field, field) if form.endswith("_x2") else field,
+            (field, field) if form.startswith("tiles_x2") else field,
             jax.ShapeDtypeStruct((B,), jnp.int32, sharding=dev),
             jax.ShapeDtypeStruct((B, D), jnp.float32, sharding=dev)).compile()
         mem = c.memory_analysis()
@@ -252,6 +385,37 @@ def compile_only(out_dir, layout, which):
               f"whole-field copies "
               f"{len(re.findall(rf'= f32.{CAP},{D}.[^ ]* copy', text))}",
               flush=True)
+
+
+def _cell_head(slots, positives, uniform, rng, vocab=1_800_000,
+               stream=8_000_000):
+    """The distinct rows of a target push as a word2vec cell of the
+    benchmark draws it (``benchmark/families/w2v.py``): every key once and
+    the rest of the stream Zipf (exponent 1; ``uniform``: flat), a row's
+    slot its rank by count, centers from the stream subsampled at 1e-4,
+    negatives from ``counts ** 0.75``."""
+    p = np.ones(vocab) if uniform else 1.0 / np.arange(1, vocab + 1)
+    counts = -np.sort(-(1 + np.bincount(rng.choice(
+        vocab, stream - vocab, p=p / p.sum()), minlength=vocab)))
+    f = counts / counts.sum()
+    kept = f * np.minimum(1.0, np.sqrt(1e-4 / f) + 1e-4 / f)
+    drawn = counts ** 0.75
+    return np.unique(np.concatenate([
+        rng.choice(vocab, positives, p=kept / kept.sum()),
+        rng.choice(vocab, slots - positives, p=drawn / drawn.sum())]))
+
+
+def _head(size, n, rng):
+    """The ``n`` ascending distinct rows of the head ``size``."""
+    if size in RUN_SIZES:
+        L = RUN_SIZES[size]
+        tile = np.arange(n)
+        return 8 * (tile + tile // L) + rng.integers(0, 8, n)
+    if size in CELL_SIZES:
+        return _cell_head(*CELL_SIZES[size], rng)[:n]
+    # whole tiles only: the rows of the last, partial one are not the
+    # kernel's (`transfer/xla.py::_rmw_tiles`)
+    return np.sort(rng.choice(CAP - CAP % tile_rmw.TILE, n, replace=False))
 
 
 def _reduce(trace_dir):
@@ -274,7 +438,7 @@ def _reduce(trace_dir):
     return sum(e - s for s, e, _ in runs) / 1e6 / RUNS, ops
 
 
-def measure(only, layout, which):
+def measure(only, layout, which, out_name):
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         raise SystemExit(f"needs the chip, found {dev.platform}")
@@ -289,18 +453,19 @@ def measure(only, layout, which):
 
     rng = np.random.default_rng(0)
     inputs = {}
-    for size, (n, B) in SIZES.items():
+    for size in dict.fromkeys(size for _, size in _cases(which)):
+        n, B = SIZES[size]
+        head = _head(size, n, rng)
         rep = np.full((B,), CAP, np.int32)
-        # whole tiles only: the rows of the last, partial one are not
-        # the kernel's (`transfer/xla.py::_rmw_tiles`)
-        rep[:n] = np.sort(rng.choice(CAP - CAP % tile_rmw.TILE, n,
-                                     replace=False))
+        rep[:len(head)] = head
+        SIZES[size] = (len(head), B)
         inputs[size] = (jnp.asarray(rep), jax.random.normal(
             jax.random.key(1), (B, D), jnp.float32))
-    result = {"device": dev.device_kind, "layout": layout,
+    result = {"device": dev.device_kind, "layout": layout, "tree": TREE,
               "capacity": CAP, "width": D,
               "sizes": SIZES, "runs": RUNS,
               "tile_block": tile_rmw.BLOCK, "tile_depth": tile_rmw.DEPTH,
+              "tile_run": getattr(tile_rmw, "RUN", 1),
               "cases": {}}
     trace_dir = os.path.join("chiprun_out", "writeback_trace")
     digests = {}
@@ -310,13 +475,13 @@ def measure(only, layout, which):
         fn = _jitted(form, size)
         rep, g = inputs[size]
         x = init(jax.random.key(0))
-        if form.endswith("_x2"):            # the accumulator: positive
+        if form.startswith("tiles_x2"):            # the accumulator: positive
             x = (x, jnp.square(init(jax.random.key(2))))
         out = fn(x, rep, g)                 # compiles; the digest's run
         keep = form != "gather_only"
         if keep:
             digests[form, size] = [float(v) for v in digest(
-                out[0] if form.endswith("_x2") else out, rep)]
+                out[0] if form.startswith("tiles_x2") else out, rep)]
             x = out
         jax.block_until_ready((x, out))
         # a capture of its own: programs that compile to one executable
@@ -334,17 +499,19 @@ def measure(only, layout, which):
         # the row sum and the field sum against form ``a``'s: equal, or
         # apart by the rounding of another compiler's `rsqrt`
         same = (digests[form, size] == digests.get(("a", size))
-                if keep and not form.endswith("_x2") else None)
+                if keep and not form.startswith("tiles_x2") else None)
         if same is False and ("a", size) in digests:
             same = max(abs(v - w) / abs(w) for v, w in zip(
                 digests[form, size], digests["a", size]))
+        copies, tiles = _copies(form, np.asarray(rep)[:SIZES[size][0]])
         result["cases"][f"{form}.{size}"] = {
-            "ms_per_run": ms, "ops": ops, "same_as_a": same}
+            "ms_per_run": ms, "rows": SIZES[size][0], "tiles": tiles,
+            "copies": copies, "ops": ops, "same_as_a": same}
         top = ", ".join(f"{k} {v:.3f}" for k, v in list(ops.items())[:6])
-        print(f"{form:12s}{size:14s}{ms:9.3f} ms a run  same_as_a={same}  "
-              f"[{top}]", flush=True)
-    with open(os.path.join("chiprun_out", "writeback_micro.json"),
-              "w") as f:
+        print(f"{form:18s}{size:14s}{ms:9.3f} ms a run  rows "
+              f"{SIZES[size][0]} tiles {tiles} copies {copies}  "
+              f"same_as_a={same}  [{top}]", flush=True)
+    with open(os.path.join("chiprun_out", out_name), "w") as f:
         json.dump(result, f, indent=1)
 
 
@@ -357,16 +524,22 @@ if __name__ == "__main__":
                     default="default",
                     help="how the field is stored (default: as the table "
                          "stores it, the compiler's choice)")
+    ap.add_argument("--tree", default=TREE,
+                    help="a checkout to take swiftmpi_tpu from")
+    ap.add_argument("--out", default="writeback_micro.json",
+                    help="the result's name under chiprun_out/")
     ap.add_argument("--width", type=int, default=D,
                     help="lanes of a stored row (384: the table's since "
                          "PR 32, row-major by default)")
-    ap.add_argument("--cases", choices=("forms", "head"), default="forms",
+    ap.add_argument("--cases", choices=("forms", "head", "runs"),
+                    default="forms",
                     help="forms: PR 30's candidates at its sizes; head: "
-                         "the cells' pushes, distinct rows at the head")
+                         "the cells' pushes, distinct rows at the head; "
+                         "runs: the tile kernel's copies by their length")
     args = ap.parse_args()
     D = args.width
     if args.compile_only:
-        compile_only(args.compile_only, args.layout, args.cases)
+        compile_only(args.compile_only, args.layout, args.cases, args.only)
     else:
         os.makedirs("chiprun_out", exist_ok=True)
-        measure(args.only, args.layout, args.cases)
+        measure(args.only, args.layout, args.cases, args.out)

@@ -80,7 +80,7 @@ def _dev(x):
 class _Tally:
     """The sums a train call reads once, carried by the step program.
 
-    One ``uint32[2, 7]`` device array.  Every step program takes it
+    One ``uint32[2, 8]`` device array.  Every step program takes it
     (donated, like the table state) and returns it with its own sums
     added, so a step is one launch and a call's end one wait and one
     read: no per-step convert, no stack-and-sum at the fetch.  Columns:
@@ -91,17 +91,18 @@ class _Tally:
       that weigh a pooled negative as a fraction of a pair
       (``shared_negatives``).  The compensation keeps an epoch-long
       call's sum within a few ulps of the exact one.
-    - ``pairs``, ``rows``, ``tiles``, ``routed``, ``offered``: counts, each a
-      uint32 (low, high) limb pair: row 0 wraps at 2^32 and carries into
-      row 1, so a count is EXACT to 2^64 — a bare int32 would wrap at
-      2.1e9 pairs, the corpus sizes a call an epoch long is for, and an
-      f32 is exact only to 2^24.  ``rows`` / ``tiles`` / ``routed`` /
-      ``offered`` are what a step built with telemetry on counts
+    - ``pairs``, ``rows``, ``tiles``, ``copies``, ``routed``, ``offered``:
+      counts, each a uint32 (low, high) limb pair: row 0 wraps at 2^32
+      and carries into row 1, so a count is EXACT to 2^64 — a bare int32
+      would wrap at 2.1e9 pairs, the corpus sizes a call an epoch long is
+      for, and an f32 is exact only to 2^24.  ``rows`` / ``tiles`` /
+      ``copies`` / ``routed`` / ``offered`` are what a step built with
+      telemetry on counts
       (`_build_step`'s ``counted``); they stay 0 otherwise.
     """
 
     FLOATS = ("err", "pairs_weighted")
-    COUNTS = ("pairs", "rows", "tiles", "routed", "offered")
+    COUNTS = ("pairs", "rows", "tiles", "copies", "routed", "offered")
 
     @classmethod
     def zeros(cls) -> np.ndarray:
@@ -135,7 +136,7 @@ class _Tally:
                for k in (0 if weighted else ec, *counts))]
         x = jnp.stack(x + [u32(0)] * (n - len(x)))
         lo, hi = tally
-        # every column both ways, in one pass over the six lanes, and
+        # every column both ways, in one pass over the eight lanes, and
         # each keeps its own: Kahan's sum and compensation ...
         xf, s, c = bits(x, f32), bits(lo, f32), bits(hi, f32)
         y = xf - c
@@ -792,9 +793,10 @@ class Word2Vec:
         gfields = tuple(self.access.grad_fields)
         # row-write counters (telemetry on only: a step built without
         # them returns no count, so the timed program carries none): the
-        # step's sparse pushes' distinct valid rows x fields and the
-        # 8-row tiles the tile kernel moved for them x fields, two more
-        # results, fetched with the loss — and, where the transfer routes
+        # step's sparse pushes' distinct valid rows x fields, the 8-row
+        # tiles the tile kernel moved for them x fields and the copies
+        # that moved those one way x fields, three more results, fetched
+        # with the loss — and, where the transfer routes
         # rows to their owners, the rows it routed and the bucket slots
         # it exchanged for them, two more again
         telemetry = obs.get_registry().enabled
@@ -810,7 +812,7 @@ class Word2Vec:
                 routed = count_routed and tapes.enter_context(count_routed())
                 out = fn(*args)
             counts = () if rows is None else tuple(
-                sum(rows, jnp.zeros((2,), jnp.int32)))
+                sum(rows, jnp.zeros((3,), jnp.int32)))
             if routed:
                 counts += tuple(sum(c, jnp.int32(0)) for c in zip(*routed))
             return out, counts
@@ -842,7 +844,7 @@ class Word2Vec:
                 return (out, *(jax.lax.psum(x, axis) for x in sums))
             rows = dict.fromkeys(state, P(axis))
             # the loss's two sums, then the counters `counted` returns
-            n_sums = 2 + 2 * bool(count_rows) + 2 * bool(count_routed)
+            n_sums = 2 + 3 * bool(count_rows) + 2 * bool(count_routed)
             return jax.shard_map(
                 body, mesh=mesh, in_specs=(rows, P(), P(), P()),
                 out_specs=(rows, *[P()] * n_sums),
@@ -2021,7 +2023,7 @@ class Word2Vec:
         meter = Throughput()
         step_i = 0
         hogwild_dropped = 0
-        rows_written = tiles_written = rows_steps = 0  # the sync step's
+        rows_written = tiles_written = tile_copies = rows_steps = 0  # sync
         routed_rows = routed_slots = routed_steps = 0       # ... routed
         # telemetry plane ([worker] telemetry, obs/): reuse an outer
         # recorder (bench harness, trainer) or own one for this call.
@@ -2301,6 +2303,7 @@ class Word2Vec:
                     if sums["rows"]:       # a step built to count them
                         rows_written += sums["rows"]
                         tiles_written += sums["tiles"]
+                        tile_copies += sums["copies"]
                         rows_steps += steps_counted
                     if sums["offered"]:    # a routed step offers slots
                         routed_rows += sums["routed"]
@@ -2359,6 +2362,10 @@ class Word2Vec:
                 # 0: no push of the step took the tile kernel
                 self.train_metrics["tiles_written_per_step"] = \
                     tiles_written / rows_steps
+                # ... and the copies that moved them one way: its runs of
+                # adjacent tiles (== the tiles where none has a neighbour)
+                self.train_metrics["tile_copies_per_step"] = \
+                    tile_copies / rows_steps
             if routed_steps:
                 # what the transfer sent to the rows' owners, and how much
                 # of the bucket slots it exchanged for that was rows

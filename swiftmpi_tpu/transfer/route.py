@@ -96,9 +96,9 @@ def push(state_l, slots, grads, counts, axis: str, n: int, combine,
     ``weights`` a row's summed multiplicity (``None``: no mean is taken).
     ``owner_push(state_l, rows, grads, counts)`` is the one-chip sparse
     push on the shard (rows local to it, ``-1`` padding); it returns the
-    new shard and what it wrote, ``int32[2]`` (distinct rows, tiles moved
-    for them).  Returns ``(shard, written, rows routed, slots offered)``,
-    the counts this chip's own."""
+    new shard and what it wrote, ``int32[3]`` (distinct rows, tiles moved
+    for them, copies that moved those).  Returns ``(shard, written, rows
+    routed, slots offered)``, the counts this chip's own."""
     B = slots.shape[0]
     cap = next(iter(state_l.values())).shape[0]
     capacity = n * cap
@@ -150,5 +150,5 @@ def push(state_l, slots, grads, counts, axis: str, n: int, combine,
     state_l, _, _, written, routed, offered = jax.lax.while_loop(
         lambda carry: carry[2], one_pass,
         (dict(state_l), first[:-1], left(first[:-1]),
-         jnp.zeros((2,), jnp.int32), zero, zero))
+         jnp.zeros((3,), jnp.int32), zero, zero))
     return state_l, written, routed, offered

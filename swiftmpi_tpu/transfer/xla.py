@@ -57,23 +57,25 @@ from swiftmpi_tpu.transfer.api import (Transfer, bump_row_versions,
 # fields that are not f32, a table split by the partitioner, the CPU.
 _ROW_WRITE_AS_SWEPT_BYTES = 31_000
 
-# The same weighing for the tile kernel (`_rmw_tiles`, PR 47), which has
-# no price a slot, only one a distinct row: 58.5 ns a row of one field
-# where a push touches a parameter and its accumulator (4 copies a row,
-# whose issue rate bounds the kernel), 82 where it touches one field
-# (v5e micro, PERF.md section 6, PR 47: `scripts/writeback_micro.py
-# --width 384 --cases head`), against ~107 in the loop of gathers and
-# scatters it replaced, and the sweep's 11.5 ms a field + ~16 ns a slot.
-# The distinct rows show only at run time and the form is chosen from
-# shapes, so a slot is weighed as the cells' pushes fill theirs — 0.8
-# distinct rows a slot (146 K of 180 K, Zipf; 0.95 uniform; 0.57 of an
-# owner's buckets) — less what the sweep pays a slot itself: ~31 ns,
-# what sweeping this many bytes costs.  The kernel keeps every push of up
-# to ~378,000 slots on a 3.59 GB field, ~157,000 on an owner's 1.5 GB
-# shard (the cells' longest: 180,224 and 137,536); beyond, where most
-# tiles of a field are named, one sweep is cheaper (585,000 rows of
-# 585,000 slots: 68.4 ms the kernel, 2 x 21.0 the sweep of two fields).
-_TILE_SLOT_AS_SWEPT_BYTES = 9_500
+# The same weighing for the tile kernel (`_rmw_tiles`, PR 47; a run of
+# adjacent named tiles one copy since PR 48), which has no price a slot:
+# ~35 ns a distinct row + ~83 ns a copy where a push touches a parameter
+# and its accumulator (v5e micro, PERF.md section 6, PR 48:
+# `scripts/writeback_micro.py --width 384 --cases runs`; 117 ns a row at
+# PR 47, ~2 x 107 in the loop of gathers and scatters before it), against
+# the sweep's 11.5 ms a field + ~16 ns a slot.  The cells' pushes cost a
+# fraction of their sweeps (a cbow2m-b16k target push, 145 K rows of
+# 180,224 slots: 9.3 ms, two sweeps 28.7).  The distinct rows show only
+# at run time and the form is chosen from shapes, so a slot is weighed at
+# the kernel's dearest — every slot a distinct row, most tiles named, one
+# field (two halve the control flow a row-field and double the sweep:
+# 1.1 M rows of 1.1 M slots 36.0 ms, two sweeps 58.5) — where the memory
+# binds and a row costs 28 ns (585,000 rows of one field 19.8 ms the
+# kernel, 21.0 the sweep; 1,100,000: 34.3 and 29.3): 12 ns over the
+# sweep's own 16 a slot, and the two cross at ~678,000 slots on a 3.59 GB
+# field, what sweeping this many bytes costs.  ~283,000 on an owner's
+# 1.5 GB shard (the cells' longest pushes: 180,224 and 137,536).
+_TILE_SLOT_AS_SWEPT_BYTES = 5_300
 
 # Gradients of a push from which `_push_rows` orders the push behind the
 # state it is given (an `optimization_barrier`, for the step's peak memory
@@ -130,14 +132,15 @@ def _rmw_rows(fields: dict, rows: jax.Array, grads: dict, access,
 
 
 def _rmw_tiles(fields: dict, rows: jax.Array, grads: dict, access,
-               n: jax.Array, inv=None) -> dict:
+               n: jax.Array, inv=None) -> tuple:
     """`_rmw_rows` for ascending ``rows`` whose ``n`` valid ones stand at
     the head, ``capacity`` behind them: the head alone is read, updated
     and written, by whole 8-row tiles (`tile_rmw.rmw_tiles`, one kernel
     for all ``fields``), at the cost of the push's distinct rows and not
     of its slots.  The tile of the last ``capacity % 8`` rows reaches past
     the fields, so those rows, the last of the head, are written row by
-    row.  Same rows, same values as `_rmw_rows`."""
+    row.  Same rows, same values as `_rmw_rows`; beside them, the copies
+    the kernel started one way a field."""
     B = rows.shape[0]
     capacity = next(iter(fields.values())).shape[0]
     whole = capacity - capacity % tile_rmw.TILE
@@ -145,7 +148,8 @@ def _rmw_tiles(fields: dict, rows: jax.Array, grads: dict, access,
         return tile_rmw.rmw_tiles(fields, rows, grads, access, n, inv)
     rest = min(capacity - whole, B)
     n_whole = jnp.sum(rows < whole, dtype=jnp.int32)
-    fields = tile_rmw.rmw_tiles(fields, rows, grads, access, n_whole, inv)
+    fields, copies = tile_rmw.rmw_tiles(fields, rows, grads, access, n_whole,
+                                        inv)
     # the ``rest`` slots from the last whole tile's on: a short batch's
     # start early, and the slots in front are the kernel's
     at = jnp.minimum(n_whole, B - rest)
@@ -155,7 +159,8 @@ def _rmw_tiles(fields: dict, rows: jax.Array, grads: dict, access,
     mine = at + jnp.arange(rest, dtype=jnp.int32) >= n_whole
     return _rmw_rows(fields, jnp.where(mine, cut(rows), capacity),
                      {f: cut(g) for f, g in grads.items()}, access,
-                     sweep=False, inv=None if inv is None else cut(inv))
+                     sweep=False,
+                     inv=None if inv is None else cut(inv)), copies
 
 
 def _after(x, done):
@@ -211,9 +216,10 @@ class XlaTransfer(Transfer):
         #: the write-back of that field's last traced sparse push took
         self.resolved_write_back: dict = {}
         #: while a list (`count_rows_written`), every push traced appends
-        #: its writes, an ``int32[2]``: distinct valid rows x fields
-        #: touched, and the distinct 8-row tiles those rows lie in x
-        #: fields where the tile kernel moves them (0 where it does not)
+        #: its writes, an ``int32[3]``: distinct valid rows x fields
+        #: touched, the distinct 8-row tiles those rows lie in x fields
+        #: where the tile kernel moves them (0 where it does not), and
+        #: the copies the kernel starts one way for them x fields
         self.rows_written: list | None = None
         # wire ledger (api.py): XLA chooses the actual collectives, so
         # wire_bytes counts the representation-level payload — sparse:
@@ -223,8 +229,9 @@ class XlaTransfer(Transfer):
     @contextlib.contextmanager
     def count_rows_written(self):
         """The list every push traced inside the block appends its
-        ``(rows, tiles)`` written to (a traced ``int32[2]`` each): what a
-        step built with telemetry on returns beside its loss."""
+        ``(rows, tiles, tile copies)`` written to (a traced ``int32[3]``
+        each): what a step built with telemetry on returns beside its
+        loss."""
         self.rows_written = tape = []
         try:
             yield tape
@@ -233,8 +240,8 @@ class XlaTransfer(Transfer):
 
     def _count_rows_written(self, written, touched) -> None:
         """A push's writes onto the tape, if one is held: ``written()``,
-        its distinct valid rows and the tiles moved for them, times the
-        fields it touches."""
+        its distinct valid rows, the tiles moved for them and the copies
+        that moved them one way, times the fields it touches."""
         if self.rows_written is not None:
             self.rows_written.append(written() * len(touched))
 
@@ -411,7 +418,7 @@ class XlaTransfer(Transfer):
                     dense_grads[f] = acc * inv if mean else acc
         self._count_rows_written(
             lambda: jnp.stack([jnp.sum(jnp.zeros((capacity,), jnp.bool_).at[
-                safe].set(True, mode="drop"), dtype=jnp.int32), 0]),
+                safe].set(True, mode="drop"), dtype=jnp.int32), 0, 0]),
             access.touched_fields(grads))
         with obs.named_scope("apply"):
             new_fields = access.apply_push(state, dense_grads)
@@ -564,8 +571,9 @@ class XlaTransfer(Transfer):
     def _push_rows(self, state, slots, grads, access, mean, counts, shards):
         """The sparse push on the rows ``state`` holds, split over
         ``shards`` devices by the partitioner (1: all of them here).
-        Returns the new state and ``int32[2]``: the distinct valid rows
-        written and, where the tile kernel moved them, their tiles."""
+        Returns the new state and ``int32[3]``: the distinct valid rows
+        written and, where the tile kernel moved them, their tiles and
+        the copies it started one way a field for those."""
         capacity = next(iter(state.values())).shape[0]
         B = slots.shape[0]
         # only the fields this push's grad families actually update are
@@ -608,7 +616,7 @@ class XlaTransfer(Transfer):
         # ... and, where the kernel moves them, the tiles they lie in
         n_tiles = jnp.sum(rep_valid & (jnp.diff(
             rep_slots // tile_rmw.TILE, prepend=-1) != 0), dtype=jnp.int32)
-        written = jnp.stack([n_rows, n_tiles if form == "tiles" else 0])
+        written = jnp.stack([n_rows, 0, 0])
         if form != "per_row":
             fields = {f: state[f] for f in touched}
             with obs.named_scope("apply"):
@@ -616,8 +624,12 @@ class XlaTransfer(Transfer):
                     out.update(_rmw_rows(fields, rep_slots, combined, access,
                                          sweep=True))
                 else:
-                    out.update(_rmw_tiles(fields, rep_slots, combined, access,
-                                          n_rows, inv=inv))
+                    # ... and the copies that moved those: the kernel's
+                    # own count of the runs of adjacent tiles it cut
+                    fields, n_copies = _rmw_tiles(
+                        fields, rep_slots, combined, access, n_rows, inv=inv)
+                    out.update(fields)
+                    written = jnp.stack([n_rows, n_tiles, n_copies])
                 return bump_row_versions(out, state, rep_slots), written
         # Per row.  Where a field is column-major in HBM (a tall array
         # whose stored width is no multiple of 128: `access.stored_width`

@@ -446,7 +446,7 @@ def train_metrics(toy):
         metrics = t.model.train_metrics
         for key in ("stall_ms_per_step", "pairs_per_step",
                     "pair_fill_share", "rows_written_per_step",
-                    "tiles_written_per_step"):
+                    "tiles_written_per_step", "tile_copies_per_step"):
             assert isinstance(metrics[key], (int, float)), (sg, key)
             assert math.isfinite(metrics[key]), (sg, key)
         # at most every slot of the step's two pushes a new row, each

@@ -230,7 +230,7 @@ def test_tally_counts_are_exact(count, steps):
     @partial(jax.jit, donate_argnums=0)
     def stub(tally, n):
         return _Tally.add(tally, jnp.float32(0.5), n, n - 1, n // 3,
-                          n // 2, jnp.int32(7))
+                          n // 5, n // 2, jnp.int32(7))
 
     tally = jnp.asarray(_Tally.zeros())
     for _ in range(steps):
@@ -239,6 +239,7 @@ def test_tally_counts_are_exact(count, steps):
     assert sums["pairs"] == steps * count
     assert sums["rows"] == steps * (count - 1)
     assert sums["tiles"] == steps * (count // 3)
+    assert sums["copies"] == steps * (count // 5)
     assert sums["routed"] == steps * (count // 2)
     assert sums["offered"] == steps * 7
     assert sums["pairs_weighted"] == 0.0

@@ -787,6 +787,80 @@ def test_rows_written_counts_each_push_s_distinct_rows(sg, monkeypatch,
     assert min(seen) > 0
 
 
+def _tile_copies(rows, block):
+    """Copies the tile kernel makes one way a field for the ascending
+    distinct ``rows``, a grid step ``block`` slots."""
+    from swiftmpi_tpu.transfer.tile_rmw import RUN
+    from tests.test_write_back import copies_walked
+
+    return np.count_nonzero(copies_walked(rows, len(rows), block, RUN))
+
+
+@pytest.mark.parametrize("case", ["apart", "adjacent", "every_row", "drawn",
+                                  "all_padding", "cpu"])
+def test_tile_copies_count_the_runs_the_kernel_cuts(case, monkeypatch):
+    """A push's third count (`XlaTransfer.count_rows_written`): the copies
+    the tile kernel started one way, times the fields touched — against a
+    numpy count of the runs of adjacent tiles, cut at `tile_rmw.RUN` and
+    at the grid step's edge, on crafted pushes: no two named tiles
+    adjacent (as many copies as tiles), all adjacent, every row of
+    adjacent tiles, a random draw with duplicates and padding, a push of
+    padding alone; never more than the tiles, and 0 with them where
+    another form writes."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    from swiftmpi_tpu.parameter import w2v_access
+    from swiftmpi_tpu.transfer import tile_rmw, xla
+
+    monkeypatch.setattr(xla, "_TILE_SLOT_AS_SWEPT_BYTES", 0)
+    monkeypatch.setattr(tile_rmw, "BLOCK", 16)
+    rng = np.random.default_rng(len(case))
+    capacity, width, B = 803, 128, 96
+    slots = {
+        "apart": 16 * rng.permutation(50)[:40] + rng.integers(0, 8, 40),
+        "adjacent": 8 * (10 + rng.permutation(40))
+        + rng.integers(0, 8, 40),
+        "every_row": rng.permutation(np.arange(80, 160)),
+        "all_padding": np.zeros(0, np.int64),
+    }.get(case, rng.integers(0, capacity, 70))
+    slots = np.concatenate([slots, rng.choice(slots, 8)])[:B] \
+        if len(slots) else slots
+    slots = rng.permutation(np.concatenate(
+        [slots, np.full(B - len(slots), -1)])).astype(np.int32)
+    access = w2v_access(0.3, width)
+    backend = xla.XlaTransfer(dense_apply=False,
+                              platform="cpu" if case == "cpu" else "tpu")
+    state = {f: jnp.asarray(rng.random((capacity, width)) + 0.5,
+                            jnp.float32) for f in access.fields}
+
+    def push(state, slots, g):
+        with backend.count_rows_written() as tape:
+            new = backend.push(state, slots, {"h": g}, access, mean=True)
+        return new, sum(tape)
+    with pltpu.force_tpu_interpret_mode():
+        _, counts = jax.jit(push)(state, slots, jnp.asarray(
+            rng.normal(size=(B, width)), jnp.float32))
+    rows = np.unique(slots[slots >= 0])
+    tiles = len(np.unique(rows >> 3))
+    # the partial tile at the fields' end is not the kernel's
+    copies = _tile_copies(rows[rows < capacity - capacity % 8], 16)
+    want = [2 * len(rows), 2 * tiles, 2 * copies]
+    if case == "cpu":
+        assert set(backend.resolved_write_back.values()) <= {"per_row",
+                                                             "sweep"}
+        want[1:] = 0, 0
+    assert counts.tolist() == want
+    assert copies <= tiles
+    if case == "apart":
+        assert copies == tiles == 40
+    elif case == "adjacent":        # 40 tiles over three grid steps
+        assert 40 // tile_rmw.RUN <= copies <= 40 // tile_rmw.RUN + 3
+    elif case == "every_row":
+        assert (tiles, copies) == (10, 5)   # two tiles fill a grid step
+
+
 @pytest.mark.parametrize("sg", [0, 1], ids=["cbow", "sg"])
 def test_tiles_written_counts_the_tiles_the_kernel_moves(sg, monkeypatch,
                                                          tmp_path):
@@ -795,7 +869,8 @@ def test_tiles_written_counts_the_tiles_the_kernel_moves(sg, monkeypatch,
     transfer is told it is one, the kernel in Pallas' interpret mode), the
     distinct 8-row tiles its distinct valid rows lie in, times the fields
     it touches; against the slots each push was handed.  It reads 0 where
-    every push is written another way."""
+    every push is written another way.  ``tile_copies_per_step`` beside
+    it: the copies that move those tiles one way, `_tile_copies`."""
     import jax
     from jax.experimental.pallas import tpu as pltpu
 
@@ -815,7 +890,8 @@ def test_tiles_written_counts_the_tiles_the_kernel_moves(sg, monkeypatch,
             fields = len(args[-1].touched_fields(grads))
             jax.debug.callback(lambda s: seen.append(
                 (fields * np.unique(s[s >= 0]).size,
-                 fields * np.unique(s[s >= 0] // 8).size)), slots)
+                 fields * np.unique(s[s >= 0] // 8).size,
+                 fields * _tile_copies(np.unique(s[s >= 0]), 8))), slots)
             return real(self, state, slots, grads, *args, **kwargs)
         monkeypatch.setattr(XlaTransfer, name, spying)
 
@@ -823,8 +899,9 @@ def test_tiles_written_counts_the_tiles_the_kernel_moves(sg, monkeypatch,
     spy("push_span")
     # a table of 64 rows is cheaper swept than the push's slots are moved
     # by tiles: weigh a slot as nothing, as a table of the cells' size does
-    from swiftmpi_tpu.transfer import xla
+    from swiftmpi_tpu.transfer import tile_rmw, xla
     monkeypatch.setattr(xla, "_TILE_SLOT_AS_SWEPT_BYTES", 0)
+    monkeypatch.setattr(tile_rmw, "BLOCK", 8)     # several grid steps a push
     cfg = ConfigParser().update({
         "cluster": {"transfer": "xla"},
         "word2vec": {"len_vec": 128, "window": 3, "negative": 2, "sg": sg,
@@ -853,14 +930,18 @@ def test_tiles_written_counts_the_tiles_the_kernel_moves(sg, monkeypatch,
     m, pushes, forms = metrics["tpu"]
     steps = len(pushes) // 2
     assert steps > 4 and forms == {"tiles"}
-    rows, tiles = (sum(p[k] for p in pushes) / steps for k in (0, 1))
+    rows, tiles, copies = (sum(p[k] for p in pushes) / steps
+                           for k in (0, 1, 2))
     assert m["rows_written_per_step"] == pytest.approx(rows, rel=1e-6)
     assert m["tiles_written_per_step"] == pytest.approx(tiles, rel=1e-6)
     assert rows / 8 <= tiles < rows          # some rows share a tile
+    # ... and some tiles a copy: 60 words fill 8 tiles
+    assert m["tile_copies_per_step"] == pytest.approx(copies, rel=1e-6)
+    assert tiles / tile_rmw.RUN <= copies < tiles
     m, _, forms = metrics["cpu"]
     assert forms <= {"per_row", "sweep"}
     assert m["rows_written_per_step"] == pytest.approx(rows, rel=1e-6)
-    assert m["tiles_written_per_step"] == 0
+    assert m["tiles_written_per_step"] == m["tile_copies_per_step"] == 0
 
 
 @pytest.mark.parametrize("sg", [0, 1], ids=["cbow", "sg"])
@@ -881,6 +962,7 @@ def test_pair_counters_absent_with_telemetry_off(sg, monkeypatch, tmp_path,
     # ... and the step was built without the row-write counters
     assert "rows_written_per_step" not in model.train_metrics
     assert "tiles_written_per_step" not in model.train_metrics
+    assert "tile_copies_per_step" not in model.train_metrics
 
 
 def test_uncounted_batches_export_no_pair_series(tmp_path, devices8):

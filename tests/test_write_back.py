@@ -186,10 +186,12 @@ CELLS = [
     # ... an owner's shard of gnews3m-x4-b64k and its longest push too ...
     (137_536, 975_001, 384, 1, "tpu", "tiles"),
     # ... until the push names most tiles of the fields: one sweep then
-    # (`_TILE_SLOT_AS_SWEPT_BYTES`: from 378,342 slots on a 3.59 GB field)
-    (378_000, 2_340_001, 384, 1, "tpu", "tiles"),
-    (400_000, 2_340_001, 384, 1, "tpu", "sweep"),
-    (200_000, 975_001, 384, 1, "tpu", "sweep"),
+    # (`_TILE_SLOT_AS_SWEPT_BYTES`: from 678,159 slots on a 3.59 GB field,
+    # 282,567 on a 1.5 GB shard)
+    (678_158, 2_340_001, 384, 1, "tpu", "tiles"),
+    (678_159, 2_340_001, 384, 1, "tpu", "sweep"),
+    (282_566, 975_001, 384, 1, "tpu", "tiles"),
+    (282_567, 975_001, 384, 1, "tpu", "sweep"),
     # a TPU, and everything else keeps the shapes' answer: four shards,
     # 300 wide (column-major by default), one wide
     (655_360, 3_900_004, 384, 4, "tpu", "sweep"),
@@ -300,7 +302,52 @@ def _tile_case(case, capacity, rng):
                                np.arange(whole, capacity)]), 24
     if case == "only_last_tile":
         return np.arange(whole, capacity), 8
+    if case.startswith("run_"):
+        return _run_case(case[4:], whole, rng)
     return np.sort(rng.permutation(whole)[:11]), 16   # mean_inv, sgd
+
+
+def _run_case(case, whole, rng):
+    """Heads whose named tiles are adjacent (`tile_rmw.RUN` = C tiles a
+    copy at most): ``(rows, batch length[, slots a grid step[, n[, tiles
+    a copy at most]]])``."""
+    from swiftmpi_tpu.transfer.tile_rmw import RUN as C
+
+    def one_each(tiles):            # a row of every tile, any sublane
+        tiles = np.asarray(tiles)
+        return 8 * tiles + rng.integers(0, 8, len(tiles))
+    lengths = {"1": 1, "2": 2, "C": C, "C+1": C + 1, "2C+1": 2 * C + 1}
+    if case in lengths:
+        # a lone tile, the run an un-named tile behind it, a lone tile an
+        # un-named tile behind the run; one grid step
+        k = lengths[case]
+        return one_each([0, *range(2, 2 + k), 3 + k]), 32, 32
+    if case == "block_edge":
+        # a run of six tiles from slot 5 on: cut where the first step ends
+        return one_each([0, 2, 4, 6, 8, *range(10, 16)]), 16
+    if case == "to_last_whole":
+        # the run ends in the last whole tile; the partial one behind it is
+        # named too, and not the kernel's
+        last = whole // 8 - 1
+        return np.concatenate([one_each([0, last - 2, last - 1, last]),
+                               np.arange(whole, whole + 1)]), 16, 16
+    if case == "gap_of_one":
+        return one_each([2, 3, 5, 6]), 8
+    if case == "every_row":
+        # every row of 2 C + 1 adjacent tiles, over several grid steps
+        return np.arange(8, 8 * (2 * C + 2)), 8 * (2 * C + 2), 32
+    if case == "n_0":
+        # adjacent tiles behind an empty head: never read
+        return one_each(range(2, 2 + C)), 16, 16, 0
+    if case.startswith("ring_"):
+        # more copies a grid step than the ring has slots, at 1, 2 and C
+        # tiles a copy: a slot's write is awaited where it is taken again
+        run = {"1": 1, "2": 2, "C": C}[case[5:]]
+        return np.sort(rng.permutation(whole)[:60]), 64, 64, None, run
+    assert case == "n_B", case
+    # two rows of each of eight adjacent tiles: the batch is all head
+    return 8 * np.repeat(np.arange(1, 9), 2) + np.concatenate(
+        [np.sort(rng.permutation(8)[:2]) for _ in range(8)]), 16, 16
 
 
 @pytest.mark.parametrize("width", [128, 384])
@@ -308,7 +355,12 @@ def _tile_case(case, capacity, rng):
     *((f"share_{k}", 96) for k in range(2, 9)), ("block_edge", 96),
     ("n_0", 96), ("n_B", 96), ("last_tile", 97), ("last_tile", 103),
     ("only_last_tile", 97), ("only_last_tile", 103), ("mean_inv", 96),
-    ("sgd", 96)])
+    ("sgd", 96),
+    *((f"run_{k}", 320) for k in ("1", "2", "C", "C+1", "2C+1")),
+    ("run_block_edge", 160), ("run_to_last_whole", 97),
+    ("run_to_last_whole", 103), ("run_gap_of_one", 96),
+    ("run_every_row", 320), ("run_n_0", 96), ("run_n_B", 96),
+    *((f"run_ring_{k}", 4000) for k in ("1", "2", "C"))])
 def test_tile_kernel_matches_the_row_by_row_write(case, capacity, width,
                                                   monkeypatch):
     """`_rmw_tiles` against `_rmw_rows` on the same ascending head: rows
@@ -316,19 +368,30 @@ def test_tile_kernel_matches_the_row_by_row_write(case, capacity, width,
     step's edge (the step drains its writes, the next reads the tile
     again), an empty head and a full one, rows in the partial tile at the
     fields' end (``capacity % 8`` 1 and 7: written row by row), a mean
-    push's ``1 / count``, a rule without an accumulator.  Rows the push
+    push's ``1 / count``, a rule without an accumulator; and named tiles
+    that are adjacent, which one copy moves (`_run_case`): runs of 1, 2,
+    C, C + 1 and 2 C + 1 tiles (C: `tile_rmw.RUN`), a run across a grid
+    step's edge, one that ends in the last whole tile, two an un-named
+    tile apart, every row of a long run, runs behind an empty head and in
+    a head that fills its batch, the ring taken twice over in a grid step.
+    Rows the push
     does not name come back bit for bit — the other seven of a named
     row's tile among them — and the named ones equal `_rmw_rows`' bit for
     bit, a mean push's too."""
     from swiftmpi_tpu.parameter.access import FieldSpec, SGDAccess, zeros_init
     from swiftmpi_tpu.transfer import tile_rmw, xla
 
-    monkeypatch.setattr(tile_rmw, "BLOCK", BLOCK)
-    monkeypatch.setattr(tile_rmw, "DEPTH", DEPTH)
     rng = np.random.default_rng(len(case) + capacity + width)
-    head, B = _tile_case(case, capacity, rng)
+    made = _tile_case(case, capacity, rng)
+    head, B, block, n, run = (
+        *made, *(BLOCK, None, tile_rmw.RUN)[len(made) - 2:])
+    monkeypatch.setattr(tile_rmw, "BLOCK", block)
+    monkeypatch.setattr(tile_rmw, "RUN", run)
+    monkeypatch.setattr(tile_rmw, "DEPTH", DEPTH)
     rows = np.full(B, capacity, np.int32)
     rows[:len(head)] = head
+    if n is not None:       # the rows behind ``n`` stand where they are
+        head = head[:n]
     if case == "sgd":
         access = SGDAccess(0.3, {"h": FieldSpec(width, zeros_init)}, ("h",),
                            ("h",))
@@ -343,10 +406,15 @@ def test_tile_kernel_matches_the_row_by_row_write(case, capacity, width,
     n = jnp.int32(len(head))
 
     want = jax.jit(lambda *a: xla._rmw_rows(*a, access, sweep=False,
-                                            inv=inv))(fields, rows, grads)
+                                            inv=inv))(
+        fields, np.where(np.arange(B) < len(head), rows, capacity), grads)
     with pltpu.force_tpu_interpret_mode():
-        got = jax.jit(lambda *a: xla._rmw_tiles(*a, access, n, inv=inv))(
-            fields, rows, grads)
+        got, copies = jax.jit(lambda *a: xla._rmw_tiles(
+            *a, access, n, inv=inv))(fields, rows, grads)
+    whole = capacity - capacity % 8
+    assert int(copies) == np.count_nonzero(copies_walked(
+        rows, int(np.sum(rows[:len(head)] < whole)), min(block, B),
+        tile_rmw.RUN))
     named = np.zeros(capacity, bool)
     named[head] = True
     for f in names:
@@ -356,6 +424,137 @@ def test_tile_kernel_matches_the_row_by_row_write(case, capacity, width,
         np.testing.assert_array_equal(g, w, err_msg=f)
         if len(head):
             assert (g[named] != np.asarray(fields[f])[named]).any()
+
+
+def copies_walked(rows, n, block, longest):
+    """The copies the kernel makes one way a field for the head
+    ``rows[:n]``, slot by slot: at the slot that opens a copy the tiles it
+    moves, 0 elsewhere.  A named tile joins the copy in front while it is
+    the next tile of the field, the copy has room (``longest`` tiles) and
+    the grid step (``block`` slots) has not ended."""
+    out = np.zeros(len(rows), np.int32)
+    at = None
+    for j in range(n):
+        tile = rows[j] >> 3
+        if j % block and tile == rows[j - 1] >> 3:
+            continue
+        if (j % block and tile == (rows[j - 1] >> 3) + 1
+                and out[at] < longest):
+            out[at] += 1
+        else:
+            at = j
+            out[at] = 1
+    return out
+
+
+@pytest.mark.parametrize("n, B, capacity", [
+    (0, 16, 96), (16, 16, 96), (1, 40, 96), (40, 40, 400), (300, 320, 800),
+    (200, 256, 4000), (250, 256, 256)])
+def test_tile_kernel_counts_the_copies_a_walk_of_the_head_makes(n, B, capacity,
+                                                                monkeypatch):
+    """The kernel's own count of the copies it starts one way a field
+    (``tile_copies_per_step`` sums it) against `copies_walked` on heads of
+    every density, over several grid steps; as many tiles as copies moved
+    them, a tile two grid steps share counted in both."""
+    from swiftmpi_tpu.transfer import tile_rmw
+
+    monkeypatch.setattr(tile_rmw, "BLOCK", 64)
+    rng = np.random.default_rng(n + capacity)
+    rows = np.full(B, capacity, np.int32)
+    rows[:n] = np.sort(rng.permutation(capacity)[:n])
+    access = w2v_access(0.3, 128)
+    fields = {f: jnp.asarray(rng.random((capacity, 128)) + 0.5, jnp.float32)
+              for f in ("h", "h2sum")}
+    grads = {"h": jnp.asarray(rng.normal(size=(B, 128)), jnp.float32)}
+    with pltpu.force_tpu_interpret_mode():
+        _, copies = jax.jit(lambda *a: tile_rmw.rmw_tiles(
+            *a, access, jnp.int32(n)))(fields, rows, grads)
+    want = copies_walked(rows, n, min(64, B), tile_rmw.RUN)
+    assert int(copies) == np.count_nonzero(want)
+    tiles = len(np.unique(rows[:n] >> 3))
+    assert want.sum() >= tiles
+    assert np.count_nonzero(want) <= tiles
+    if B <= 64:
+        assert want.sum() == tiles
+
+
+@pytest.mark.parametrize("run", [1, 2, 4])
+@pytest.mark.parametrize("n, capacity", [(40, 320), (48, 96), (48, 4000)],
+                         ids=["dense", "every_tile", "sparse"])
+def test_tile_kernel_awaits_every_copy_at_the_length_it_started(
+        n, capacity, run, monkeypatch):
+    """A DMA wait blocks until as many bytes as ITS descriptor names have
+    arrived: one that names another length than the copy's start hangs
+    the chip or runs ahead of the copy, and Pallas' interpret mode checks
+    neither.  So the kernel's copies are logged as they run (a callback
+    at every start and wait, by semaphore): on each semaphore starts and
+    waits alternate, a wait names its start's rows, and nothing is left
+    in flight when the kernel ends — over heads whose runs cross grid
+    steps and, the sparse one's, take the ring twice over."""
+    from swiftmpi_tpu.transfer import tile_rmw
+
+    log, real = [], tile_rmw._pallas()
+
+    class Logged:
+        def __init__(self, src, dst, sem):
+            self.copy = real[1].make_async_copy(src, dst, sem)
+            # the semaphore's field and ring slot, the rows copied
+            self.at = (*sem.transforms[-1].indices, dst.shape[0])
+            self.sem = id(sem.ref)
+
+        def _log(self, kind):
+            jax.debug.callback(
+                lambda field, slot, rows: log.append(
+                    (kind, self.sem, int(rows), int(field), int(slot))),
+                *map(jnp.int32, self.at))
+
+        def start(self):
+            self._log("start")
+            self.copy.start()
+
+        def wait(self):
+            self._log("wait")
+            self.copy.wait()
+
+    class Pltpu:
+        make_async_copy = Logged
+
+        def __getattr__(self, name):
+            return getattr(real[1], name)
+
+    monkeypatch.setattr(tile_rmw, "_pallas", lambda: (real[0], Pltpu()))
+    monkeypatch.setattr(tile_rmw, "BLOCK", 24)
+    monkeypatch.setattr(tile_rmw, "RUN", run)
+    monkeypatch.setattr(tile_rmw, "DEPTH", DEPTH)
+    rng = np.random.default_rng(n)
+    B, width = 48, 128
+    rows = np.full(B, capacity, np.int32)
+    rows[:n] = np.sort(rng.permutation(capacity)[:n])
+    access = w2v_access(0.3, width)
+    fields = {f: jnp.asarray(rng.random((capacity, width)) + 0.5,
+                             jnp.float32) for f in ("h", "h2sum")}
+    grads = {"h": jnp.asarray(rng.normal(size=(B, width)), jnp.float32)}
+    with pltpu.force_tpu_interpret_mode():
+        jax.block_until_ready(jax.jit(lambda *a: tile_rmw.rmw_tiles(
+            *a, access, jnp.int32(n)))(fields, rows, grads))
+    jax.effects_barrier()
+    in_flight = {}
+    for kind, sem, length, field, slot in log:
+        if kind == "start":
+            assert (sem, field, slot) not in in_flight
+            in_flight[sem, field, slot] = length
+        else:
+            assert in_flight.pop((sem, field, slot), None) == length
+    assert not in_flight
+    lengths = {length // 8 for _, _, length, _, _ in log}
+    assert lengths <= set(range(1, run + 1))
+    if capacity < 4000:
+        assert max(lengths) == run
+    # two fields a copy, each read and written once; and a grid step's
+    # reads run half a ring behind its last copy, for nothing
+    steps = -(-n // 24)
+    assert len(log) == 2 * 2 * (2 * np.count_nonzero(copies_walked(
+        rows, n, 24, run)) + steps * DEPTH // 2)
 
 
 def test_pallas_byte_code_is_kept_in_the_compile_cache(tmp_path):
