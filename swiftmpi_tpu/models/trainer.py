@@ -99,7 +99,7 @@ def _loss_parts(parts, weight: float) -> dict:
     beside the loss (``lm_loss_and_stats``'s parts), as means over the
     steps: the two losses, the module's weighted share of their sum in
     percent, and the module's own expert counters under ``mtp_``."""
-    if not parts or "main_loss" not in parts[0]:
+    if not parts or "mtp_loss" not in parts[0]:
         return {}
     main, mtp = (sum(float(p[k]) for p in parts) / len(parts)
                  for k in ("main_loss", "mtp_loss"))
@@ -109,14 +109,25 @@ def _loss_parts(parts, weight: float) -> dict:
                 [p["mtp_stats"] for p in parts]).items()}}
 
 
-def _scan_counters(parts) -> dict:
-    """``ssm_scan_chunks``: the chunks the state-space layers' scans walked
-    a step (``lm_loss_and_stats``'s part of that name), mean over the
-    run's steps; nothing for a stack without such layers."""
-    if not parts or "ssm_scan_chunks" not in parts[0]:
+#: ``lm_loss_and_stats``'s parts a stack with state-space layers returns:
+#: the chunks their scans walked a step
+SCAN_PARTS = ("ssm_scan_chunks",)
+#: ... and a stack with sparse layers: the layers' index losses summed, the
+#: objective's other part, the index loss a layer, and — counted on the
+#: device from the selections the attention used — the keys a query kept
+#: (mean over queries and layers) and their share of the causal pairs, in
+#: percent
+INDEX_PARTS = ("index_loss", "main_loss", "index_loss_per_layer",
+               "selected_keys_per_query", "selected_pair_share")
+
+
+def _part_means(parts, names) -> dict:
+    """Means over a run's steps of the parts ``names`` that its steps
+    returned beside the loss; nothing for a stack whose steps return none
+    (the first name tells)."""
+    if not parts or names[0] not in parts[0]:
         return {}
-    return {"ssm_scan_chunks": sum(float(p["ssm_scan_chunks"])
-                                   for p in parts) / len(parts)}
+    return {k: sum(float(p[k]) for p in parts) / len(parts) for k in names}
 
 
 class Trainer:
@@ -298,7 +309,8 @@ class Trainer:
                 state.params, state.opt_state, state.step, tokens)
         with obs.span("step_book", step=n):
             if obs.get_registry().enabled and (
-                    self.cfg.n_experts or "ssm" in self.cfg.layer_ops):
+                    self.cfg.n_experts or "ssm" in self.cfg.layer_ops
+                    or "sparse" in self.cfg.layer_ops):
                 self._stats.append(stats)
             self.meter.record(int(np.prod(tokens.shape)))
             obs.record_step(1)
@@ -333,8 +345,10 @@ class Trainer:
         ``loss_fetch`` for the call's one blocking fetch (``loss_wait``
         for the last loss, then with telemetry on the read of the expert
         layers' counters, with a multi-token-prediction module of the
-        loss's two parts, and with state-space layers of the chunks their
-        scans walked), ``train_finish`` from there to the return.
+        loss's two parts, with state-space layers of the chunks their
+        scans walked, and with sparse layers of the objective's two parts
+        and the selections' counters), ``train_finish`` from there to the
+        return.
         ``self.train_metrics`` holds what the call counted.
         """
         setup_span = obs.span("train_setup")
@@ -398,7 +412,8 @@ class Trainer:
                 **(_expert_counters([s for s, _parts in stats])
                    if self.cfg.n_experts else {}),
                 **_loss_parts([p for _s, p in stats], self.cfg.mtp_weight),
-                **_scan_counters([p for _s, p in stats])}
+                **_part_means([p for _s, p in stats], SCAN_PARTS),
+                **_part_means([p for _s, p in stats], INDEX_PARTS)}
         return state, losses
 
     # -- checkpoints (multihost-safe, atomic, CRC-validated) ---------------

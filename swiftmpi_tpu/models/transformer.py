@@ -24,10 +24,16 @@ Architecture: pre-RMSNorm, an output head that is the embedding's transpose
 be alike: each layer has an *operator* (attention with grouped KV heads of
 any ``d_head``, optional per-head QK norm and an optional output gate — in
 one of the kinds of ``ATTENTION_OPS``: causal with RoPE, causal over a
-sliding ``window`` with RoPE, causal with no position embedding, or
-*latent* attention: queries through a low-rank pair, keys and values
-re-expanded per head from one compressed vector a position, RoPE on a
-decoupled part of the head whose key every head shares — a gated short
+sliding ``window`` with RoPE, causal with no position embedding, *latent*
+attention: queries through a low-rank pair, keys and values re-expanded per
+head from one compressed vector a position, RoPE on a decoupled part of the
+head whose key every head shares, or learned *sparse* attention
+(:func:`_sparse_attention`, parallel/sparse_attention.py): an indexer of
+``index_heads`` small heads scores every earlier key, each query attends
+its ``index_topk`` best, and the layer's *index loss* — the KL divergence of
+the indexer's softmax over the selection from the attention's own
+head-averaged probabilities there — teaches the indexer, and it alone
+(its input is detached; the selection is a constant of the step) — a gated short
 convolution, or a Mamba-2 state-space mixer, :func:`_ssm_mixer`, whose
 recurrence is parallel/ssm.py's chunked scan) and an *FFN* (dense
 SiLU-gated, or the routed expert layer of parallel/moe.py, beside shared
@@ -61,7 +67,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -79,17 +85,21 @@ from swiftmpi_tpu.parallel.ring_attention import (CAUSAL, WindowMask,
                                                   full_attention,
                                                   ring_attention,
                                                   ulysses_attention)
+from swiftmpi_tpu.parallel.sparse_attention import (sparse_attention,
+                                                    sparse_attention_dense)
 from swiftmpi_tpu.parallel.ssm import chunked_scan, n_chunks
 
 
 #: attention operators -> (device scope, over ``cfg.window`` only, RoPE).
 #: ``full`` is what a stack that mixes it with ``sliding`` layers means by
 #: it: every earlier position, and no position embedding at all; ``latent``
-#: (:func:`_latent_attention`) rotates ``qk_rope_dim`` of a head's dims
+#: (:func:`_latent_attention`) rotates ``qk_rope_dim`` of a head's dims;
+#: ``sparse`` (:func:`_sparse_attention`) sees the keys its indexer selects
 ATTENTION_OPS = {"attention": ("attention", False, True),
                  "sliding": ("window_attention", True, True),
                  "full": ("attention", False, False),
-                 "latent": ("latent_attention", False, True)}
+                 "latent": ("latent_attention", False, True),
+                 "sparse": ("sparse_attention", False, True)}
 #: ``none``: the layer is its other half alone
 OPS = (*ATTENTION_OPS, "conv", "ssm", "none")
 FFNS = ("dense", "moe", "none")
@@ -156,6 +166,13 @@ class TransformerConfig:
     qk_rope_dim: int = 0             # ... with RoPE; the key's part is one
                                      # a position, shared by every head
     v_head_dim: int = 0              # a value head's width
+    # -- a "sparse" layer (each 0 until a layer asks for them).  It needs
+    # attention "blockwise" (or "full": every (S, S) array whole) and the
+    # objective "next_token", and there is no multi-token-prediction module
+    # of its kind: __post_init__ refuses the rest, naming the field
+    index_heads: int = 0             # the indexer's heads
+    index_head_dim: int = 0          # ... their width (RoPE over all of it)
+    index_topk: int = 0              # keys a query keeps of the earlier ones
     # -- an "ssm" layer (each 0 until a layer asks for them) ---------------
     ssm_heads: int = 0               # heads of the recurrence
     ssm_head_dim: int = 0            # a head's inputs (its state's rows)
@@ -210,6 +227,28 @@ class TransformerConfig:
             if self.qk_rope_dim % 2:
                 raise ValueError("a 'latent' layer rotates pairs: "
                                  f"qk_rope_dim {self.qk_rope_dim} is odd")
+        if "sparse" in self.layer_ops:
+            missing = [f for f in ("index_heads", "index_head_dim",
+                                   "index_topk") if getattr(self, f) < 1]
+            if missing:
+                raise ValueError("a 'sparse' layer needs its indexer's "
+                                 f"sizes: {', '.join(missing)} not set")
+            if self.index_head_dim % 2:
+                raise ValueError("a 'sparse' layer's indexer rotates pairs: "
+                                 f"index_head_dim {self.index_head_dim} is "
+                                 "odd")
+            if self.attention not in ("blockwise", "full"):
+                raise ValueError("a 'sparse' layer needs attention "
+                                 f"'blockwise' or 'full', not "
+                                 f"{self.attention!r}: the selection is a "
+                                 "mask, and the other variants take none")
+            if self.objective != "next_token":
+                raise ValueError("a 'sparse' layer selects among the earlier "
+                                 f"keys; objective {self.objective!r} brings "
+                                 "a mask it cannot compose with yet")
+            if self.mtp_layers and self.layer_kinds()[-1][0] == "sparse":
+                raise ValueError("mtp_layers with a last layer that is "
+                                 "'sparse': the module carries no index loss")
         if "ssm" in self.layer_ops:
             missing = [f for f in ("ssm_heads", "ssm_head_dim", "ssm_state",
                                    "ssm_groups") if getattr(self, f) < 1]
@@ -335,6 +374,8 @@ def _init_block(k, cfg: TransformerConfig, op: str, ffn: str):
                        k_norm=jnp.ones((Dh,), cfg.dtype))
         if cfg.attn_gate:
             blk.update(wg=mat(d, cfg.n_heads * Dh))
+        if op == "sparse":
+            blk.update(_init_indexer(jax.random.fold_in(k, 1), cfg))
     elif op == "conv":
         blk.update(conv_in=mat(d, 3 * d), conv_out=mat(d, d),
                    conv_w=jax.random.normal(
@@ -356,6 +397,21 @@ def _init_block(k, cfg: TransformerConfig, op: str, ffn: str):
         h = cfg.d_ff
         blk.update(w_gate=mat(d, h), w_up=mat(d, h), w_down=mat(h, d))
     return blk
+
+
+def _init_indexer(key, cfg: TransformerConfig) -> dict:
+    """A sparse layer's indexer: queries for ``index_heads`` heads, one key
+    a position with its LayerNorm, and a weight a head, all from the layer's
+    normed input.  Drawn from a key of its own (the layer's other matrices
+    draw as every attention layer's do)."""
+    d, HI, dI = cfg.d_model, cfg.index_heads, cfg.index_head_dim
+    std = cfg.init_std or 1.0 / math.sqrt(d)
+    kq, kk, kw = jax.random.split(key, 3)
+    return {"wq_idx": jax.random.normal(kq, (d, HI * dI), cfg.dtype) * std,
+            "wk_idx": jax.random.normal(kk, (d, dI), cfg.dtype) * std,
+            "w_idx": jax.random.normal(kw, (d, HI), cfg.dtype) * std,
+            "idx_ln_g": jnp.ones((dI,), cfg.dtype),
+            "idx_ln_b": jnp.zeros((dI,), cfg.dtype)}
 
 
 def _init_ssm(mat, key, cfg: TransformerConfig) -> dict:
@@ -559,17 +615,12 @@ def _attend(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
     return full_attention(q, k, v, causal=True)
 
 
-def _attention(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
-               seq_axis: str, positions=None, mask=CAUSAL, rotary=True):
-    """Attention at the position ids ``positions`` ((S,) f32; default
-    ``0..S-1``; unused without ``rotary``) under ``mask``
-    (``blockwise_attention``'s contract; default causal).  The layer does
-    not know the objective: whoever builds another input than a plain
-    sequence says where its positions stand and who sees whom
-    (``diffusion.attention_inputs``)."""
-    B, S, d = x.shape
+def _qkv(blk, h, cfg: TransformerConfig, positions, rotary: bool):
+    """The normed input ``h`` (B, S, d) -> q (B, S, H, Dh), k and v (B, S,
+    Hkv, Dh): projections, QK norm, RoPE at ``positions`` ((S,) f32;
+    ``None``: ``0..S-1``) where ``rotary``."""
+    B, S, _d = h.shape
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    h = _rms_norm(x, blk["ln1"], cfg.norm_eps)
     q = _mm(h, blk["wq"], cfg).reshape(B, S, H, Dh)
     k = _mm(h, blk["wk"], cfg).reshape(B, S, Hkv, Dh)
     v = _mm(h, blk["wv"], cfg).reshape(B, S, Hkv, Dh)
@@ -581,11 +632,104 @@ def _attention(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
             positions = jnp.arange(S, dtype=jnp.float32)
         q = _rope(q, cfg.rope_base, positions)
         k = _rope(k, cfg.rope_base, positions)
-    o = _attend(q, k, v, cfg, mesh, seq_axis, mask)
-    o = o.reshape(B, S, H * Dh).astype(x.dtype)
+    return q, k, v
+
+
+def _attn_out(blk, x, h, o, cfg: TransformerConfig):
+    """The heads' outputs ``o`` (B, S, H, Dh) -> the layer's output: the
+    gate where the stack has one, ``W_o``, the residual."""
+    B, S, _d = x.shape
+    o = o.reshape(B, S, -1).astype(x.dtype)
     if cfg.attn_gate:
         o = o * jax.nn.sigmoid(_mm(h, blk["wg"], cfg))
     return x + _post_norm(_mm(o, blk["wo"], cfg), blk, "ln1_post", cfg)
+
+
+def _attention(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
+               seq_axis: str, positions=None, mask=CAUSAL, rotary=True):
+    """Attention at the position ids ``positions`` ((S,) f32; default
+    ``0..S-1``; unused without ``rotary``) under ``mask``
+    (``blockwise_attention``'s contract; default causal).  The layer does
+    not know the objective: whoever builds another input than a plain
+    sequence says where its positions stand and who sees whom
+    (``diffusion.attention_inputs``)."""
+    h = _rms_norm(x, blk["ln1"], cfg.norm_eps)
+    q, k, v = _qkv(blk, h, cfg, positions, rotary)
+    return _attn_out(blk, x, h, _attend(q, k, v, cfg, mesh, seq_axis, mask),
+                     cfg)
+
+
+class IndexStats(NamedTuple):
+    """What the sparse layers of a step add beside their outputs, summed
+    over them."""
+    loss: jax.Array        # their index losses, f32
+    kept: jax.Array        # (query, key) pairs their selections kept, int32
+
+
+def _no_index_stats() -> IndexStats:
+    return IndexStats(jnp.float32(0.0), jnp.int32(0))
+
+
+def _layer_norm(x, g, b, eps):
+    x32 = x.astype(jnp.float32)
+    mu = jnp.mean(x32, -1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mu), -1, keepdims=True)
+    return ((x32 - mu) * jax.lax.rsqrt(var + eps)).astype(x.dtype) * g + b
+
+
+def _indexer(blk, h, cfg: TransformerConfig, positions):
+    """The indexer's three inputs to the index scores, from the *detached*
+    normed input ``h`` (B, S, d): ``qi = RoPE(h W_qI)`` (B, S, HI, dI), the
+    weights ``w = h W_w / sqrt(HI) / sqrt(dI)`` (B, S, HI) — the scores' two
+    scales folded in — and ``ki = RoPE(LayerNorm(h W_kI))`` (B, S, dI), one
+    key a position for every head.  RoPE rotates all ``dI`` dims."""
+    B, S, _d = h.shape
+    HI, dI = cfg.index_heads, cfg.index_head_dim
+    h = jax.lax.stop_gradient(h)
+    qi = _rope(_mm(h, blk["wq_idx"], cfg).reshape(B, S, HI, dI),
+               cfg.rope_base, positions)
+    ki = _layer_norm(_mm(h, blk["wk_idx"], cfg), blk["idx_ln_g"],
+                     blk["idx_ln_b"], cfg.norm_eps)
+    ki = _rope(ki[:, :, None], cfg.rope_base, positions)[:, :, 0]
+    w = _mm(h, blk["w_idx"], cfg).astype(jnp.float32) / math.sqrt(HI * dI)
+    return qi, w, ki
+
+
+def _sparse_attention(blk, x, cfg: TransformerConfig, positions=None,
+                      mask=CAUSAL):
+    """Learned sparse attention (parallel/sparse_attention.py has the
+    equations) -> (the layer's output, :class:`IndexStats` of this layer,
+    what the selection was made from and the selection: the indexer's
+    ``qi``, ``w``, ``ki`` as the index scores' product takes them and the
+    packed ``bits``, for :func:`sparse_probe`).
+    Queries, keys and values as :func:`_attention` makes them; the indexer
+    (:func:`_indexer`) reads the normed input detached, so the language-model
+    loss trains everything but the indexer and the index loss the indexer
+    alone.  Device scopes: ``indexer`` (its projections, every walk of the
+    index scores, the KL), inside it ``index_select`` (the top
+    ``index_topk``), the rest under the layer's ``sparse_attention``."""
+    if mask is not CAUSAL:
+        raise ValueError("a 'sparse' layer selects among the earlier keys; "
+                         "it composes with no other mask")
+    if positions is None:
+        positions = jnp.arange(x.shape[1], dtype=jnp.float32)
+    h = _rms_norm(x, blk["ln1"], cfg.norm_eps)
+    q, k, v = _qkv(blk, h, cfg, positions, True)
+    with obs.named_scope("indexer"):
+        qi, w, ki = _indexer(blk, h, cfg, positions)
+    if cfg.matmul_dtype is not None:
+        q, k, v, qi, ki = (t.astype(cfg.matmul_dtype)
+                           for t in (q, k, v, qi, ki))
+    if cfg.attention == "blockwise":
+        o, loss, kept, bits = sparse_attention(q, k, v, qi, w, ki,
+                                               topk=cfg.index_topk,
+                                               block=cfg.attn_block)
+    else:
+        o, loss, kept = sparse_attention_dense(q, k, v, qi, w, ki,
+                                               topk=cfg.index_topk)
+        bits = None
+    return (_attn_out(blk, x, h, o, cfg), IndexStats(loss, kept),
+            {"qi": qi, "w": w, "ki": ki, "bits": bits})
 
 
 def _latent_attention(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
@@ -743,38 +887,56 @@ def _remat_policy(cfg: TransformerConfig):
     raise ValueError(f"unknown remat_policy: {cfg.remat_policy!r}")
 
 
-def _operator(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
-              seq_axis: str, op: str, **attn):
+def _operator_stats(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
+                    seq_axis: str, op: str, **attn):
+    """A layer's operator -> (x, a sparse layer's :class:`IndexStats`, else
+    ``None``)."""
     if op == "none":
-        return x
+        return x, None
     if op == "ssm":
         if attn.get("mask", CAUSAL) is not CAUSAL:
             raise ValueError("an 'ssm' layer's recurrence is causal; it "
                              "takes no mask")
         with obs.named_scope("ssm_mixer"):
-            return _ssm_mixer(blk, x, cfg)
+            return _ssm_mixer(blk, x, cfg), None
     if op in ATTENTION_OPS:
         scope, windowed, rotary = ATTENTION_OPS[op]
         if windowed:
             attn = {**attn, "mask": WindowMask(cfg.window)}
         with obs.named_scope(scope):
+            if op == "sparse":
+                return _sparse_attention(blk, x, cfg, **attn)[:2]
             if op == "latent":     # its rope part is not an option
-                return _latent_attention(blk, x, cfg, mesh, seq_axis, **attn)
+                return _latent_attention(blk, x, cfg, mesh, seq_axis,
+                                         **attn), None
             return _attention(blk, x, cfg, mesh, seq_axis, rotary=rotary,
-                              **attn)
+                              **attn), None
     with obs.named_scope("conv"):
-        return _short_conv(blk, x, cfg)
+        return _short_conv(blk, x, cfg), None
+
+
+def _operator(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh],
+              seq_axis: str, op: str, **attn):
+    return _operator_stats(blk, x, cfg, mesh, seq_axis, op, **attn)[0]
+
+
+def _layer(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh], kind,
+           *, seq_axis: str = "seq", expert_axis: str = "expert", **attn):
+    """One layer of ``kind`` -> (x, aux loss, MoEStats, a sparse layer's
+    :class:`IndexStats` or ``None``)."""
+    op, ffn = kind
+    x, index = _operator_stats(blk, x, cfg, mesh, seq_axis, op, **attn)
+    return (*_ffn(blk, x, cfg, mesh, expert_axis, ffn), index)
 
 
 def block_apply(blk, x, cfg: TransformerConfig, mesh: Optional[Mesh] = None,
-                *, seq_axis: str = "seq", expert_axis: str = "expert",
-                kind: Optional[Tuple[str, str]] = None, **attn):
+                *, kind: Optional[Tuple[str, str]] = None, **kwargs):
     """One layer of ``kind`` = (operator, FFN) (default: layer 0's)
-    -> (x, aux loss, MoEStats).  ``attn``: :func:`_attention`'s
-    ``positions`` and ``mask``."""
-    op, ffn = kind or cfg.layer_kinds()[0]
-    x = _operator(blk, x, cfg, mesh, seq_axis, op, **attn)
-    return _ffn(blk, x, cfg, mesh, expert_axis, ffn)
+    -> (x, aux loss, MoEStats); a sparse layer's index loss is
+    :func:`_layer`'s fourth.  ``kwargs``: ``seq_axis``, ``expert_axis`` and
+    :func:`_attention`'s ``positions`` and ``mask``."""
+    return _layer(blk, x, cfg, mesh, kind or cfg.layer_kinds()[0],
+                  **kwargs)[:3]
 
 
 def _embed(params, tokens, cfg: TransformerConfig):
@@ -791,14 +953,16 @@ def _groups(params, cfg: TransformerConfig):
 
 def _run_layers(stacked, carry, cfg: TransformerConfig, mesh, kind,
                 **block_kwargs):
-    """``carry = (x, aux, MoEStats)`` through a run of equal layers: one
-    compiled block body whatever the run's length — a scan over the stacked
-    params instead of unrolled copies."""
+    """``carry = (x, aux, MoEStats, IndexStats or None)`` through a run of
+    equal layers: one compiled block body whatever the run's length — a scan
+    over the stacked params instead of unrolled copies."""
     def body(carry, blk):
-        x, aux, stats = carry
-        x, a, st = block_apply(blk, x, cfg, mesh, kind=kind, **block_kwargs)
-        return (x, aux + a, MoEStats(*(s + t for s, t in
-                                       zip(stats, st)))), None
+        x, aux, stats, index = carry
+        x, a, st, ix = _layer(blk, x, cfg, mesh, kind, **block_kwargs)
+        if ix is not None:
+            index = IndexStats(*(s + t for s, t in zip(index, ix)))
+        return (x, aux + a, MoEStats(*(s + t for s, t in zip(stats, st))),
+                index), None
 
     if cfg.remat:
         body = jax.checkpoint(body, policy=_remat_policy(cfg))
@@ -809,10 +973,13 @@ def _stack(params, tokens, cfg: TransformerConfig,
            mesh: Optional[Mesh] = None, *, seq_axis: str = "seq",
            expert_axis: str = "expert", **attn):
     """:func:`trunk` up to the last layer's output, *before* the final
-    norm: what a multi-token-prediction module reads."""
+    norm: what a multi-token-prediction module reads -> (x, aux, MoEStats,
+    the sparse layers' :class:`IndexStats`, ``None`` for a stack without
+    them)."""
     with obs.named_scope("embed"):
         x = _embed(params, tokens, cfg)
-    carry = (x, jnp.float32(0.0), _no_stats())
+    carry = (x, jnp.float32(0.0), _no_stats(),
+             _no_index_stats() if "sparse" in cfg.layer_ops else None)
     for kind, _n, stacked in _groups(params, cfg):
         carry = _run_layers(stacked, carry, cfg, mesh, kind,
                             seq_axis=seq_axis, expert_axis=expert_axis,
@@ -828,7 +995,7 @@ def trunk(params, tokens, cfg: TransformerConfig,
     ``positions`` and ``mask``; ``seq_axis``, ``expert_axis``) say
     otherwise: the ``block_diffusion`` loss runs ``[x_t ; x_0]`` (B, 2S)
     with ``diffusion.attention_inputs``."""
-    x, aux, stats = _stack(params, tokens, cfg, mesh, **kwargs)
+    x, aux, stats, _index = _stack(params, tokens, cfg, mesh, **kwargs)
     with obs.named_scope("head"):
         x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     return x, aux, stats
@@ -855,9 +1022,9 @@ def _mtp(params, x_last, tokens, cfg: TransformerConfig,
     module's own gain, aux, the module's MoEStats).  The embedding is the
     trunk's, and so is the head the caller runs over the result."""
     z = _mtp_merge(params, x_last, tokens, cfg)
-    z, aux, stats = _run_layers(
-        params["mtp"]["block"], (z, jnp.float32(0.0), _no_stats()), cfg,
-        mesh, cfg.layer_kinds()[-1], **block_kwargs)
+    z, aux, stats, _index = _run_layers(
+        params["mtp"]["block"], (z, jnp.float32(0.0), _no_stats(), None),
+        cfg, mesh, cfg.layer_kinds()[-1], **block_kwargs)
     return _rms_norm(z, params["mtp"]["norm"], cfg.norm_eps), aux, stats
 
 
@@ -899,6 +1066,19 @@ def hidden_states(params, tokens, cfg: TransformerConfig, **attn):
         out.append(_mtp_merge(params, x, tokens, cfg))
         layers(params["mtp"]["block"], cfg.layer_kinds()[-1], out[-1])
     return out
+
+
+def sparse_probe(blk, x, cfg: TransformerConfig) -> dict:
+    """What one sparse layer makes of the input ``x`` (B, S, d) beside its
+    output, for holding it to a reference on the program's own input (as
+    :func:`hidden_states` gives it): the indexer's ``qi``, ``w``, ``ki`` as
+    the index scores' product takes them
+    (``parallel/sparse_attention.py::index_tile``), the selection the
+    attention used as packed ``bits`` in tiles of ``min(attn_block, S)``
+    (``unpack`` there; ``None`` under ``attention="full"``), the layer's
+    ``index_loss`` and the pairs ``kept``."""
+    _out, stats, probe = _sparse_attention(blk, x, cfg)
+    return {**probe, "index_loss": stats.loss, "kept": stats.kept}
 
 
 def forward_pipelined(params, tokens, cfg: TransformerConfig, mesh: Mesh,
@@ -966,7 +1146,13 @@ def lm_loss_and_stats(params, tokens, cfg: TransformerConfig,
     the token after the next (a sequence's last two positions left out);
     the parts are ``{"main_loss", "mtp_loss", "mtp_stats"}`` — the module's
     own MoEStats, which the sum holds too — and none of them without a
-    module.  Where the stack has state-space layers the parts hold
+    module.  Where the stack has sparse layers, ``loss = main_loss +
+    index_loss`` (the layers' index losses summed, weight 1: the two train
+    disjoint parameters) and the parts hold both, with
+    ``index_loss_per_layer`` and, counted from the selections the attention
+    used, ``selected_keys_per_query`` (mean over queries and layers) and
+    ``selected_pair_share`` (percent of the causal pairs kept).  Where the
+    stack has state-space layers the parts hold
     ``ssm_scan_chunks``: the chunks their scans walked this step (sequences
     x chunks a sequence x such layers), from the shapes traced here.
     ``block_diffusion``: ``noise_key`` draws the
@@ -983,7 +1169,8 @@ def lm_loss_and_stats(params, tokens, cfg: TransformerConfig,
             noisy, weights = diffusion.block_noise(noise_key, tokens, cfg)
             inputs = diffusion.trunk_input(noisy, tokens)
             attn = diffusion.attention_inputs(S, cfg)
-    x, aux, stats = _stack(params, inputs, cfg, mesh, **attn, **fwd_kwargs)
+    x, aux, stats, index = _stack(params, inputs, cfg, mesh, **attn,
+                                  **fwd_kwargs)
     parts = {}
     ops = [kind[0] for kind in cfg.layer_kinds()]
     n_ssm = ops.count("ssm") + (cfg.mtp_layers and ops[-1] == "ssm")
@@ -1015,6 +1202,15 @@ def lm_loss_and_stats(params, tokens, cfg: TransformerConfig,
     if cfg.mtp_layers:
         parts["main_loss"] = loss
         loss = loss + cfg.mtp_weight * parts["mtp_loss"]
+    if index is not None:
+        n, queries = ops.count("sparse"), B * S
+        kept = index.kept.astype(jnp.float32)
+        parts.update(
+            main_loss=loss, index_loss=index.loss,
+            index_loss_per_layer=index.loss / n,
+            selected_keys_per_query=kept / (n * queries),
+            selected_pair_share=100.0 * kept / (n * queries * (S + 1) / 2))
+        loss = loss + index.loss
     return loss + aux_weight * aux, (stats, parts)
 
 

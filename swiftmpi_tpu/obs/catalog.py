@@ -154,6 +154,16 @@ DEVICE_SCOPES = {
     # a latent layer: down- and up-projections, the two inner norms, RoPE
     # and the rope key's broadcast, softmax(QK)V, out
     "latent_attention": "latent_attention",
+    # a sparse layer (learned sparse attention): QKV, QK norm, RoPE, the
+    # masked tile walk both ways, out ...
+    "sparse_attention": "sparse_attention",
+    # ... inside it, the indexer: its projections, LayerNorm and RoPE, every
+    # walk of the index scores and the index loss (the KL and the indexer's
+    # gradients) ...
+    "indexer": "indexer",
+    # ... and, innermost, the selection: the exact top-k of every query's
+    # row of index scores, packed into bits
+    "index_select": "index_select",
     # a Mamba-2 mixer: in / out projections, the causal convolution, the
     # step sizes, the gated grouped norm ...
     "ssm_mixer": "ssm_mixer",
@@ -184,6 +194,7 @@ DEVICE_SCOPES = {
 #: custom call keeps no path at all — see ``ragged-dot-none`` above — so the
 #: module's grouped products book as ``experts`` with the stack's.)
 LAYER_SCOPES = ("conv", "attention", "window_attention", "latent_attention",
+                "sparse_attention", "indexer", "index_select",
                 "ssm_mixer", "ssm_scan", "route", "experts", "shared_expert",
                 "dense_ffn")
 DEVICE_SCOPES.update({"mtp_" + s: "mtp" for s in LAYER_SCOPES})

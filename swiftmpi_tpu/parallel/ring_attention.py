@@ -87,7 +87,19 @@ class CausalMask:
     * ``tile(block, S) -> size``: the tile its lists are written for, at
       most ``block`` positions of the ``S`` (it must divide ``S``).
 
-    Every query must see a key in at least one tile of its list."""
+    Every query must see a key in at least one tile of its list.
+
+    A mask made of positions alone is all of the above.  One that reads
+    **data** (``parallel/sparse_attention.py::SelectedMask``: which keys a
+    query keeps was decided by a learned module) is still hashable and
+    static; its arrays travel beside it as ``blockwise_attention``'s
+    ``mask_data``, an operand of the forward and the backward walk that
+    takes no gradient, and it gives two things more:
+
+    * ``tile_data(data, i, j, size)``: what ``visible`` needs of ``data``
+      for query tile ``i`` against key tile ``j``;
+    * ``visible(qa, kc, tile_data)``: the predicate with that third
+      argument, broadcastable to the scores ``(B, Hkv, G, size, size)``."""
 
     def tile(self, block, S):
         return min(block, S)
@@ -134,18 +146,26 @@ def _unblock(x, shape):
     return jnp.moveaxis(x, 0, 1).reshape(shape)
 
 
-def _block_scores(qi, kj, i, j, size, scale, mask):
+def tile_visible(mask, data, i, j, size):
+    """``mask.visible`` of query tile ``i`` against key tile ``j``:
+    ``(size, size)`` from positions alone, or with ``data`` whatever shape
+    the mask gives that broadcasts to the scores."""
+    pos = jnp.arange(size)
+    qa, kc = (i * size + pos)[:, None], (j * size + pos)[None, :]
+    if data is None:
+        return mask.visible(qa, kc)
+    return mask.visible(qa, kc, mask.tile_data(data, i, j, size))
+
+
+def _block_scores(qi, kj, i, j, size, scale, mask, data=None):
     """f32 scores (B, Hkv, G, bq, bk) of query block ``i`` against key
     block ``j``, positions the mask hides at ``_NEG``."""
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qi, kj,
                    preferred_element_type=jnp.float32) * scale
-    pos = jnp.arange(size)
-    visible = mask.visible((i * size + pos)[:, None],
-                           (j * size + pos)[None, :])
-    return jnp.where(visible, s, _NEG)
+    return jnp.where(tile_visible(mask, data, i, j, size), s, _NEG)
 
 
-def _blockwise_fwd(q, k, v, size, mask):
+def _blockwise_fwd(q, k, v, data, size, mask):
     """q (B, S, Hkv, G, D), k / v (B, S, Hkv, D) -> (o like q, lse
     (B, S, Hkv, G) f32).  Query blocks in turn; each folds the key
     blocks its mask lists (causal: those up to its own; later ones are
@@ -161,7 +181,7 @@ def _blockwise_fwd(q, k, v, size, mask):
             m, l, o = carry
             j = tile(t)
             s = _block_scores(qi, _block(k, j, size), i, j, size, scale,
-                              mask)
+                              mask, data)
             m_new = jnp.maximum(m, s.max(axis=-1))
             p = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m - m_new)
@@ -183,17 +203,21 @@ def _blockwise_fwd(q, k, v, size, mask):
     return _unblock(o, q.shape), _unblock(lse, (B, S, Hkv, G))
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _blockwise(q, k, v, size, mask):
-    return _blockwise_fwd(q, k, v, size, mask)[0]
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _blockwise(q, k, v, data, size, mask):
+    """-> (o, lse).  ``lse``, the log-sum-exp of every query's visible
+    scores, is a statistic: no gradient flows through it (whoever reads it
+    reads it under ``stop_gradient``; the backward walk drops its
+    cotangent)."""
+    return _blockwise_fwd(q, k, v, data, size, mask)
 
 
-def _blockwise_vjp_fwd(q, k, v, size, mask):
-    o, lse = _blockwise_fwd(q, k, v, size, mask)
-    return o, (q, k, v, o, lse)
+def _blockwise_vjp_fwd(q, k, v, data, size, mask):
+    o, lse = _blockwise_fwd(q, k, v, data, size, mask)
+    return (o, lse), (q, k, v, data, o, lse)
 
 
-def _blockwise_vjp_bwd(size, mask, res, do):
+def _blockwise_vjp_bwd(size, mask, res, cts):
     """The forward's mirror: query blocks in turn, each folding the key
     blocks its mask lists.  A query block's ``dq`` is summed in the fold's
     carry (f32, in the product's own layout, as the forward's ``o``) and
@@ -207,7 +231,8 @@ def _blockwise_vjp_bwd(size, mask, res, do):
     heavy one (``G`` times a key block), so it is the one kept still; at
     ``G`` = 1 the two sides weigh the same and this order is still the
     faster (PERF.md section 6, PR 39)."""
-    q, k, v, o, lse = res
+    q, k, v, data, o, lse = res
+    do = cts[0]
     B, S, Hkv, G, D = q.shape
     scale = 1.0 / math.sqrt(D)
     n = S // size
@@ -230,7 +255,7 @@ def _blockwise_vjp_bwd(size, mask, res, do):
             dq_i, dk, dv = carry
             j = tile(t)
             kj, vj = _block(k, j, size), _block(v, j, size)
-            s = _block_scores(qi, kj, i, j, size, scale, mask)
+            s = _block_scores(qi, kj, i, j, size, scale, mask, data)
             p = jnp.exp(s - lse_i[..., None])        # hidden: exp(-1e30)
             dv_j = jnp.einsum("bhgqk,bqhgd->bkhd", p.astype(do.dtype), doi,
                               preferred_element_type=jnp.float32)
@@ -254,15 +279,19 @@ def _blockwise_vjp_bwd(size, mask, res, do):
     dq, dk, dv = lax.fori_loop(0, n, q_block,
                                (jnp.zeros_like(q), zeros, zeros))
     return (dq, _unblock(dk.astype(k.dtype), k.shape),
-            _unblock(dv.astype(v.dtype), v.shape))
+            _unblock(dv.astype(v.dtype), v.shape), None)
 
 
 _blockwise.defvjp(_blockwise_vjp_fwd, _blockwise_vjp_bwd)
 
 
-def blockwise_attention(q, k, v, block: int = 512, mask=CAUSAL):
+def blockwise_attention(q, k, v, block: int = 512, mask=CAUSAL,
+                        mask_data=None, with_lse: bool = False):
     """Masked attention on one device without an ``(S, S)`` matrix; causal
-    unless ``mask`` says otherwise (:class:`CausalMask` is the contract).
+    unless ``mask`` says otherwise (:class:`CausalMask` is the contract;
+    ``mask_data``: the arrays of a mask that reads data).  ``with_lse``
+    returns ``(o, lse)``, ``lse`` (B, S, H) f32 the log-sum-exp of each
+    query head's visible scores, a statistic that carries no gradient.
 
     ``q``: (B, S, H, D); ``k``, ``v``: (B, S, Hkv, D) with ``H`` a
     multiple of ``Hkv`` (each KV head serves ``H / Hkv`` query heads in
@@ -282,8 +311,10 @@ def blockwise_attention(q, k, v, block: int = 512, mask=CAUSAL):
     size = mask.tile(block, S)
     if S % size:
         raise ValueError(f"sequence {S} is no multiple of block {size}")
-    o = _blockwise(q.reshape(B, S, Hkv, H // Hkv, D), k, v, size, mask)
-    return o.reshape(B, S, H, D)
+    o, lse = _blockwise(q.reshape(B, S, Hkv, H // Hkv, D), k, v, mask_data,
+                        size, mask)
+    o = o.reshape(B, S, H, D)
+    return (o, lse.reshape(B, S, H)) if with_lse else o
 
 
 def _fold_block(q, k, v, m, l, o, scale, mask):

@@ -1161,6 +1161,147 @@ def sslm_phase_map_and_counters(toy):
             np.asarray(t.state.params["blocks"][0][name])), name
 
 
+# -- the sparse-attention family's surface (benchmark/families/salm.py's head) ---
+
+_SAL = {}
+
+
+def sal_toy():
+    """A toy stack of sparse-attention expert layers — an indexer of 2 heads
+    of 4, top 8 of 32 positions — one ``Trainer.run`` of two host batches
+    with telemetry on, by the family's call sequence."""
+    if _SAL:
+        return _SAL["toy"]
+    from swiftmpi_tpu import obs
+    from swiftmpi_tpu.models.trainer import Trainer
+    from swiftmpi_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=8, n_kv_heads=1,
+        d_head=4, d_expert=12, max_seq=32, attention="blockwise",
+        attn_block=8, loss_chunk=16, remat=True, remat_policy="full",
+        n_experts=128, moe_top_k=8, experts_held=(112, 128),
+        router="softmax", expert_gated=True, qk_norm=True,
+        layer_ops=("sparse",) * 2, layer_ffns=("moe",) * 2,
+        index_heads=2, index_head_dim=4, index_topk=8,
+        norm_eps=1e-6, rope_base=1e7, init_std=0.3, tied_head=False)
+    was_on = obs.get_registry().enabled
+    obs.set_enabled(True)
+    trainer = Trainer(cfg, optimizer="adamw", aux_weight=0.0,
+                      learning_rate=3e-4, warmup_steps=2, decay_steps=100,
+                      weight_decay=0.1, grad_clip=1.0, b1=0.9, b2=0.95)
+    state0 = trainer.init_state(jax.random.key(3))
+    rng = np.random.default_rng(49)
+    batches = [rng.integers(0, 64, (1, 32)).astype(np.int32)
+               for _ in range(2)]
+    state, losses = trainer.run(state0, iter(batches))
+    phase_map = obs.costs.phase_map("trainer_step")
+    obs.set_enabled(was_on)
+    _SAL["toy"] = SimpleNamespace(cfg=cfg, trainer=trainer, state=state,
+                                  losses=losses, batches=batches,
+                                  phase_map=phase_map)
+    return _SAL["toy"]
+
+
+@surface
+def salm_config_and_tree(toy):
+    """The ``TransformerConfig`` fields and the kind the family sets beyond
+    the other LM families', the parameter names it samples, and
+    ``sparse_probe`` with ``index_tile`` / ``unpack``: what the selection
+    check reads."""
+    from swiftmpi_tpu.models.transformer import (OPS, TransformerConfig,
+                                                 hidden_states, sparse_probe)
+    from swiftmpi_tpu.parallel.sparse_attention import index_tile, unpack
+
+    fields = TransformerConfig.__dataclass_fields__
+    for name in ("index_heads", "index_head_dim", "index_topk"):
+        assert fields[name].default == 0, name
+    assert "sparse" in OPS
+    t = sal_toy()
+    assert t.cfg.layer_groups() == [(("sparse", "moe"), 2)]
+    params = t.state.params
+    assert set(params) == {"embed", "head", "blocks", "ln_f"}
+    assert set(params["blocks"][0]) == {
+        "ln1", "ln2", "wq", "wk", "wv", "wo", "q_norm", "k_norm", "wq_idx",
+        "wk_idx", "w_idx", "idx_ln_g", "idx_ln_b", "moe"}
+    blk = jax.tree.map(lambda a: a[0], params["blocks"][0])
+    assert blk["wq_idx"].shape == (32, 8) and blk["wk_idx"].shape == (32, 4)
+    assert blk["w_idx"].shape == (32, 2) and blk["idx_ln_b"].shape == (4,)
+    mu = t.state.opt_state[1][0].mu
+    assert jax.tree.structure(mu) == jax.tree.structure(params)
+    hs = hidden_states(params, t.batches[0], t.cfg)
+    assert len(hs) == 5 and all(h.shape == (1, 32, 32) for h in hs)
+    probe = sparse_probe(blk, hs[0], t.cfg)
+    assert set(probe) == {"qi", "w", "ki", "bits", "index_loss", "kept"}
+    assert probe["qi"].shape == (1, 32, 2, 4)
+    assert probe["w"].shape == (1, 32, 2) and probe["ki"].shape == (1, 32, 4)
+    keep = unpack(probe["bits"], 8)
+    assert keep.shape == (1, 32, 32) and keep.dtype == bool
+    # sum_t min(t + 1, 8) over 32 positions
+    assert int(probe["kept"]) == int(keep.sum()) == 36 + 24 * 8
+    scores = index_tile(probe["qi"], probe["w"], probe["ki"])
+    assert scores.shape == (1, 32, 32) and scores.dtype == np.float32
+
+
+@surface
+def salm_phase_map_and_counters(toy):
+    """The device scopes ``sparse_attention``, ``indexer`` and
+    ``index_select`` beside the LM step's, the objective's two parts and the
+    selection's counters beside the expert counters in ``train_metrics``,
+    and a share's routers left alone while the indexer moves."""
+    from swiftmpi_tpu.obs.catalog import DEVICE_SCOPES, LAYER_SCOPES
+
+    t = sal_toy()
+    want = {"embed", "sparse_attention", "indexer", "index_select", "route",
+            "experts", "head", "optimizer"}
+    assert want <= set(DEVICE_SCOPES.values())
+    assert {"sparse_attention", "indexer", "index_select"} \
+        <= set(LAYER_SCOPES)
+    assert want <= set(t.phase_map["phase"].values())
+    assert t.phase_map["module"] == "jit_train_step"
+    m = t.trainer.train_metrics
+    assert m["steps"] == 2 and m["dropped_picks_per_step"] == 0.0
+    assert m["selected_keys_per_query"] == (36 + 24 * 8) / 32
+    assert math.isclose(m["selected_pair_share"],
+                        100 * (36 + 24 * 8) / (32 * 33 / 2), rel_tol=1e-6)
+    assert math.isclose(m["index_loss_per_layer"], m["index_loss"] / 2,
+                        rel_tol=1e-6)
+    losses = [float(x) for x in t.losses]
+    assert math.isclose(m["main_loss"] + m["index_loss"],
+                        sum(losses) / 2, rel_tol=1e-5)
+    assert "mtp_loss_share" not in m and "ssm_scan_chunks" not in m
+    before = t.trainer.init_state(jax.random.key(3)).params["blocks"][0]
+    after = t.state.params["blocks"][0]
+    assert np.array_equal(np.asarray(before["moe"].router),
+                          np.asarray(after["moe"].router))
+    for name in ("wq_idx", "wk_idx", "w_idx", "idx_ln_g", "idx_ln_b", "wq"):
+        assert not np.array_equal(np.asarray(before[name]),
+                                  np.asarray(after[name])), name
+
+
 @pytest.mark.parametrize("name", sorted(SURFACE))
 def test_harness_surface(name, toy):
     SURFACE[name](toy)
+
+
+@pytest.mark.parametrize("name, reads, err, ok", [
+    # the objective the timed step returned has a limit of its own again
+    ("loss", "loss", 5.9e-5, True),
+    ("loss", "loss", 3.25e-4, False),       # a bf16 head softmax and loss
+    # the step's own index loss reads the per-layer index loss's limit
+    ("step.index_loss", "index_loss", 7.15e-4, True),
+    ("step.index_loss", "index_loss", 0.37, False),      # float8 operands
+    ("grad.wk_idx", "grad", 9.0e-3, True),
+    ("grad.wk_idx", "grad", 0.62, False),                # float8 operands
+    ("flips", "flips", float("nan"), False),
+])
+def test_salm_verdict_holds_a_reading_to_its_limit(name, reads, err, ok):
+    """``families/salm.py::verdict`` — what ``first_step_check`` and
+    ``tools/salm_lower_precision.py`` both decide by — with the chip's two
+    readings of each limit (PERF.md section 6, PR 49) on their sides."""
+    sys.path.insert(0, REPO)
+    from benchmark.families import salm
+
+    field = salm.verdict({name: err}, salm.LIMITS)[name]
+    assert field["limit"] == salm.LIMITS[reads]
+    assert field["ok"] is ok and (field["max_err"] == err or err != err)

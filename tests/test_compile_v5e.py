@@ -483,6 +483,10 @@ def test_lfm2_ep4_trainer_step_fits_one_chip(topo, no_compile_cache):
     assert mem.alias_size_in_bytes >= 0.99 * mem.output_size_in_bytes
 
     text = compiled.as_text()
+    # the step is its program at `a87c672`, instruction for instruction
+    # (PR 49 gave `blockwise_attention`'s mask contract a data operand and
+    # its `custom_vjp` a second output; a positional mask lowers as before)
+    assert _instructions(text) == (9004, "101eb67644aa170c")
     assert "ragged-dot" in text               # the compiler's grouped matmul
     _backward_keeps_the_query_side_still(compiled, cfg, seqs, S, 13.437)
     # a head's (S, S) scores, or a (T, E, C) dispatch at C = T k / E x 2 =
@@ -534,6 +538,10 @@ def test_sdar_ep8_trainer_step_fits_one_chip(topo, no_compile_cache):
     assert mem.alias_size_in_bytes >= 0.99 * mem.output_size_in_bytes
 
     text = compiled.as_text()
+    # the step is its program at `a87c672`, instruction for instruction
+    # (PR 49 gave `blockwise_attention`'s mask contract a data operand and
+    # its `custom_vjp` a second output; a positional mask lowers as before)
+    assert _instructions(text) == (6836, "3235fd1e4d452f7e")
     # every kernel the compiler brings is a ragged-dot one, under the names
     # the catalog books as `experts` and `^ragged-dot` matches: 3 products
     # forward and 9 backward in the one scanned layer body
@@ -599,6 +607,10 @@ def test_trinity_ep16_trainer_step_fits_one_chip(topo, no_compile_cache):
     assert mem.alias_size_in_bytes >= 0.99 * mem.output_size_in_bytes
 
     text = compiled.as_text()
+    # the step is its program at `a87c672`, instruction for instruction
+    # (PR 49 gave `blockwise_attention`'s mask contract a data operand and
+    # its `custom_vjp` a second output; a positional mask lowers as before)
+    assert _instructions(text) == (21591, "0c870f916dddfcfb")
     kernels = re.findall(r'custom_call_target="tpu_custom_call".*?'
                          r'op_name="([^"]*)"', text)
     assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
@@ -673,6 +685,10 @@ def test_glm47f_ep8_trainer_step_fits_one_chip(topo, no_compile_cache):
     assert mem.argument_size_in_bytes <= 7.9 * GIB       # 12 B a parameter
 
     text = compiled.as_text()
+    # the step is its program at `a87c672`, instruction for instruction
+    # (PR 49 gave `blockwise_attention`'s mask contract a data operand and
+    # its `custom_vjp` a second output; a positional mask lowers as before)
+    assert _instructions(text) == (15269, "8d7de46ce30c12ba")
     kernels = re.findall(r'custom_call_target="tpu_custom_call".*?'
                          r'op_name="([^"]*)"', text)
     assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
@@ -754,6 +770,10 @@ def test_nemotron3n_ep16_trainer_step_fits_one_chip(topo, no_compile_cache):
     assert mem.argument_size_in_bytes <= 7.5 * GIB       # 12 B a parameter
 
     text = compiled.as_text()
+    # the step is its program at `a87c672`, instruction for instruction
+    # (PR 49 gave `blockwise_attention`'s mask contract a data operand and
+    # its `custom_vjp` a second output; a positional mask lowers as before)
+    assert _instructions(text) == (21049, "9095011c6e5d4ffe")
     kernels = re.findall(r'custom_call_target="tpu_custom_call".*?'
                          r'op_name="([^"]*)"', text)
     assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}

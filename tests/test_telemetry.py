@@ -997,3 +997,91 @@ def test_pair_series_is_declared():
     from swiftmpi_tpu.obs import catalog
     assert "train/pairs" in catalog.SERIES
     assert catalog.declared("train/pairs")
+
+
+# -- the sparse layers' scopes and counters (models/trainer.py) ---------------
+
+def _sparse_trainer(seq, topk, layers=2, seqs=1):
+    import jax
+
+    from swiftmpi_tpu.models.trainer import Trainer
+    from swiftmpi_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=32, d_model=16, n_layers=layers, n_heads=2, d_head=4,
+        d_ff=16, max_seq=seq, attention="blockwise", attn_block=8,
+        remat=True, remat_policy="full", qk_norm=True,
+        layer_ops=("sparse",) * layers, layer_ffns=("dense",) * layers,
+        index_heads=2, index_head_dim=4, index_topk=topk, init_std=0.3)
+    trainer = Trainer(cfg, aux_weight=0.0, warmup_steps=1, decay_steps=10)
+    rng = np.random.default_rng(49)
+    batches = [rng.integers(0, 32, (seqs, seq)).astype(np.int32)
+               for _ in range(2)]
+    return trainer, trainer.init_state(jax.random.key(0)), batches
+
+
+@pytest.mark.parametrize("seq,topk,layers,seqs", [
+    (32, 8, 2, 1), (24, 5, 1, 2), (16, 64, 3, 1)])
+def test_selection_counters_equal_the_closed_forms(seq, topk, layers, seqs):
+    """``selected_keys_per_query`` and ``selected_pair_share`` are counted on
+    the device from the selection the attention used: ``sum_t min(t + 1, k)``
+    keys over a sequence's queries, whatever the layers and the batch (a top
+    k past the sequence keeps the causal triangle: 100 %), and the objective
+    the step returned is its two parts."""
+    from swiftmpi_tpu import obs
+
+    obs.reset_for_tests()
+    obs.set_enabled(True)
+    try:
+        trainer, state, batches = _sparse_trainer(seq, topk, layers, seqs)
+        _state, losses = trainer.run(state, iter(batches))
+        m = trainer.train_metrics
+    finally:
+        obs.reset_for_tests()
+    kept = sum(min(t + 1, topk) for t in range(seq))
+    assert m["selected_keys_per_query"] == pytest.approx(kept / seq, rel=1e-6)
+    assert m["selected_pair_share"] == pytest.approx(
+        100.0 * kept / (seq * (seq + 1) / 2), rel=1e-6)
+    assert m["index_loss_per_layer"] == pytest.approx(
+        m["index_loss"] / layers, rel=1e-6)
+    assert m["index_loss"] > 0.0
+    assert m["main_loss"] + m["index_loss"] == pytest.approx(
+        float(np.mean([float(x) for x in losses])), rel=1e-5)
+
+
+def test_selection_counters_absent_with_telemetry_off():
+    from swiftmpi_tpu import obs
+
+    obs.reset_for_tests()
+    trainer, state, batches = _sparse_trainer(16, 4)
+    trainer.run(state, iter(batches))
+    assert "selected_keys_per_query" not in trainer.train_metrics
+    assert "index_loss" not in trainer.train_metrics
+
+
+def test_sparse_scopes_are_in_the_compiled_step():
+    """``sparse_attention``, ``indexer`` and ``index_select`` are declared
+    (``DEVICE_SCOPES``, ``LAYER_SCOPES``) and every one names instructions of
+    the compiled step, forward and transposed: the innermost known scope of
+    an ``op_name`` is its phase."""
+    from swiftmpi_tpu import obs
+    from swiftmpi_tpu.obs import catalog, costs
+
+    scopes = ("sparse_attention", "indexer", "index_select")
+    assert all(catalog.DEVICE_SCOPES[s] == s for s in scopes)
+    assert set(scopes) <= set(catalog.LAYER_SCOPES)
+    assert costs.phase_of(
+        "jit(train_step)/sparse_attention/indexer/index_select/while") \
+        == "index_select"
+    assert costs.phase_of(
+        "jit(train_step)/transpose(jvp(sparse_attention))/indexer/dot") \
+        == "indexer"
+    obs.reset_for_tests()
+    obs.set_enabled(True)
+    try:
+        trainer, state, batches = _sparse_trainer(16, 4)
+        trainer.run(state, iter(batches))
+        phases = set(costs.phase_map("trainer_step")["phase"].values())
+    finally:
+        obs.reset_for_tests()
+    assert set(scopes) <= phases
