@@ -80,8 +80,10 @@ def visible(qa, kc, S: int, Lb: int):
     q_noisy, k_noisy = qa < S, kc < S
     bq = jnp.where(q_noisy, qa, qa - S) // Lb
     bk = jnp.where(k_noisy, kc, kc - S) // Lb
-    return jnp.where(k_noisy, q_noisy & (bq == bk),
-                     jnp.where(q_noisy, bk < bq, bk <= bq))
+    # written with and / or alone: Mosaic selects no booleans, and the
+    # attention kernel evaluates this inside itself
+    return ((k_noisy & q_noisy & (bq == bk))
+            | (~k_noisy & ((bk < bq) | (~q_noisy & (bk == bq)))))
 
 
 @dataclass(frozen=True)

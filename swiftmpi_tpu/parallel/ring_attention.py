@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from swiftmpi_tpu.parallel import attention_kernel
 from swiftmpi_tpu.parallel.collectives import all_to_all, ring_permute
 
 SEQ_AXIS = "seq"
@@ -83,7 +84,10 @@ class CausalMask:
       tile's ``(m, l, o)`` through its fold, the backward the tile's ``dq``
       (and adds a key tile's ``dk`` / ``dv`` to block-major sums);
     * ``visible(qa, kc)``: the element predicate, from absolute query
-      positions ``(size, 1)`` and key positions ``(1, size)``;
+      positions ``(size, 1)`` and key positions ``(1, size)`` — or the
+      other way round, ``(1, size)`` and ``(size, 1)``: it is elementwise
+      and broadcasts one against the other (the forward's kernel holds its
+      scores keys down, queries across);
     * ``tile(block, S) -> size``: the tile its lists are written for, at
       most ``block`` positions of the ``S`` (it must divide ``S``).
 
@@ -99,7 +103,22 @@ class CausalMask:
     * ``tile_data(data, i, j, size)``: what ``visible`` needs of ``data``
       for query tile ``i`` against key tile ``j``;
     * ``visible(qa, kc, tile_data)``: the predicate with that third
-      argument, broadcastable to the scores ``(B, Hkv, G, size, size)``."""
+      argument, broadcastable to the scores ``(B, Hkv, G, size, size)``;
+
+    and, for the forward's kernel (``parallel/attention_kernel.py``), which
+    holds one sequence's pair of tiles at a time and is handed its part of
+    the data as a block of the ordinary pipeline:
+
+    * ``block_words(data, size)``: ``data`` as one array ``(B, n rows, n
+      cols)`` of 32-bit words of which tile pair ``(i, j)``'s part is block
+      ``(i, j)`` of ``(rows, cols)``;
+    * ``block_visible(qa, kc, block)``: the predicate ``(size, size)`` of
+      one sequence from that block.
+
+    The kernel evaluates ``visible`` / ``block_visible`` inside itself:
+    they are written with comparisons, integer arithmetic and ``&`` / ``|``
+    / ``~`` (Mosaic selects no booleans: no ``jnp.where`` between
+    predicates)."""
 
     def tile(self, block, S):
         return min(block, S)
@@ -203,17 +222,33 @@ def _blockwise_fwd(q, k, v, data, size, mask):
     return _unblock(o, q.shape), _unblock(lse, (B, S, Hkv, G))
 
 
+def _forward(q, k, v, data, size, mask):
+    """``_blockwise_fwd``'s ``(o, lse)`` by one of its two renderings: one
+    Pallas kernel a call (``parallel/attention_kernel.py``: a query tile's
+    scores, statistics and output stay in VMEM through its fold) where the
+    program is lowered for a TPU and the kernel takes the dtype and the
+    static shapes, the XLA walk everywhere else.  Nothing else is asked: a
+    step compiled for a described chip holds the chip's walk."""
+    xla = partial(_blockwise_fwd, size=size, mask=mask)
+    if not (k.dtype == v.dtype == q.dtype
+            and attention_kernel.takes(q.dtype, size, *q.shape[2:])):
+        return xla(q, k, v, data)
+    return lax.platform_dependent(
+        q, k, v, data, default=xla,
+        tpu=partial(attention_kernel.attn_fwd_tiles, size=size, mask=mask))
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _blockwise(q, k, v, data, size, mask):
     """-> (o, lse).  ``lse``, the log-sum-exp of every query's visible
     scores, is a statistic: no gradient flows through it (whoever reads it
     reads it under ``stop_gradient``; the backward walk drops its
     cotangent)."""
-    return _blockwise_fwd(q, k, v, data, size, mask)
+    return _forward(q, k, v, data, size, mask)
 
 
 def _blockwise_vjp_fwd(q, k, v, data, size, mask):
-    o, lse = _blockwise_fwd(q, k, v, data, size, mask)
+    o, lse = _forward(q, k, v, data, size, mask)
     return (o, lse), (q, k, v, data, o, lse)
 
 

@@ -86,6 +86,16 @@ class SelectedMask(CausalMask):
     def visible(self, qa, kc, selected):
         return ((qa >= kc) & selected)[:, None, None]
 
+    def block_words(self, bits, size):
+        return lax.bitcast_convert_type(bits, jnp.int32)
+
+    def block_visible(self, qa, kc, words):
+        g = _group(qa.shape[0])
+        rows, S = words.shape
+        words = jnp.broadcast_to(words[:, None, :], (rows, g, S)).reshape(
+            rows * g, S)
+        return (qa >= kc) & (lax.shift_right_logical(words, qa % g) & 1 == 1)
+
 
 SELECTED = SelectedMask()
 

@@ -21,11 +21,11 @@ out: nothing but the named tiles moves.
 from __future__ import annotations
 
 import functools
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
+
+from swiftmpi_tpu.utils.xla_env import pallas as _pallas
 
 #: rows of a tile: the sublanes of an f32 vector register
 TILE = 8
@@ -53,28 +53,6 @@ DEPTH = 32
 RUN = 8
 #: what the kernel may ask of VMEM for its ring and its gradient blocks
 _VMEM_LIMIT = 100 << 20
-
-
-def _pallas():
-    """Pallas and its TPU dialect, imported by the process whose push
-    first comes here and by no other.  The import is ~1 s of compiling
-    Python sources (Mosaic's dialects, the GPU back end beside them) where
-    the installation keeps no byte code (``PYTHONDONTWRITEBYTECODE``), ~2 s
-    on the benchmark's host and most of what the kernel costs a run's
-    set-up: where a persistent compile cache is configured the byte code
-    is kept in it too, beside the compiled programs, and read back by the
-    next process as they are."""
-    cache = jax.config.jax_compilation_cache_dir
-    held = sys.dont_write_bytecode, sys.pycache_prefix
-    if cache and sys.pycache_prefix is None:
-        sys.dont_write_bytecode = False
-        sys.pycache_prefix = os.path.join(cache, "pycache")
-    try:
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
-    finally:
-        sys.dont_write_bytecode, sys.pycache_prefix = held
-    return pl, pltpu
 
 
 def rmw_tiles(fields: dict, rows: jax.Array, grads: dict, access,

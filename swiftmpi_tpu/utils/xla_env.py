@@ -5,7 +5,8 @@ conftests and entry scripts can import it first thing:
 ``ensure_cpu_mesh_flags`` must run BEFORE the first jax import in the
 process (env-var flags are read at backend init);
 ``ensure_compile_cache`` imports jax itself and must run before the
-first compile.
+first compile; ``pallas`` imports jax and Pallas when a kernel is first
+traced.
 """
 
 from __future__ import annotations
@@ -37,6 +38,33 @@ def ensure_compile_cache() -> str:
     path = os.path.join(REPO_ROOT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+def pallas():
+    """Pallas and its TPU dialect, imported by the process whose first
+    kernel (``transfer/tile_rmw.py``'s push, ``parallel/attention_kernel.py``'s
+    forward walk) comes here and by no other.  The import is ~1 s of
+    compiling Python sources (Mosaic's dialects, the GPU back end beside
+    them) where the installation keeps no byte code
+    (``PYTHONDONTWRITEBYTECODE``), ~2 s on the benchmark's host and most of
+    what a kernel costs a run's set-up: where a persistent compile cache is
+    configured the byte code is kept in it too, beside the compiled
+    programs, and read back by the next process as they are."""
+    import sys
+
+    import jax
+
+    cache = jax.config.jax_compilation_cache_dir
+    held = sys.dont_write_bytecode, sys.pycache_prefix
+    if cache and sys.pycache_prefix is None:
+        sys.dont_write_bytecode = False
+        sys.pycache_prefix = os.path.join(cache, "pycache")
+    try:
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+    finally:
+        sys.dont_write_bytecode, sys.pycache_prefix = held
+    return pl, pltpu
 
 
 def compile_cache_entries(path: str) -> int:

@@ -1,5 +1,6 @@
 """Context-parallel attention vs full-attention golden on an 8-device mesh."""
 
+import dataclasses
 import importlib
 
 import jax
@@ -168,3 +169,172 @@ def test_blockwise_backward_at_the_lists_edges(kind, tile, los, folds):
     assert [int(lo) for lo, _hi, _t in lists] == los
     assert [int(hi) - int(lo) for lo, hi, _t in lists] == folds
     _grads_agree(kind, 4, tile, jnp.float32, 1e-5, S=S)
+
+
+# -- the forward walk's kernel (parallel/attention_kernel.py), interpreted -----
+
+ak = importlib.import_module("swiftmpi_tpu.parallel.attention_kernel")
+
+
+@dataclasses.dataclass(frozen=True)
+class _SkipsTile(ra.CausalMask):
+    """Causal, but nobody sees the keys of tile ``skip``: the tile is in no
+    list (a list that is not a range: ``tile(t)`` jumps over it)."""
+    size: int
+    skip: int = 1
+
+    def key_tiles(self, i, n, size):
+        assert size == self.size
+        hi = jnp.where(i >= self.skip, jnp.maximum(i, 1), i + 1)
+        return 0, hi, lambda t: jnp.where(t >= self.skip, t + 1, t)
+
+    def visible(self, qa, kc):
+        lo = self.skip * self.size
+        return (qa >= kc) & ((kc < lo) | (kc >= lo + self.size))
+
+
+def _kernel_case(kind, S, tile, B=1):
+    """(mask, its data or None, the boolean matrix (B, S, S) it stands
+    for)."""
+    from swiftmpi_tpu.parallel import sparse_attention as sa
+    if kind == "selected":
+        # a random selection of the earlier keys; query 3 keeps one key
+        keep = jax.random.bernoulli(jax.random.key(S), 0.3, (B, S, S))
+        keep = (keep | jnp.eye(S, dtype=bool)) & jnp.tril(
+            jnp.ones((S, S), bool))
+        keep = keep.at[:, 3].set(jnp.arange(S) == 1)
+        g = sa._group(tile)
+        bits = (keep.reshape(B, S // g, g, S).astype(jnp.uint32)
+                << sa._shifts(g)).sum(axis=2, dtype=jnp.uint32)
+        assert bool((sa.unpack(bits, tile) == keep).all())
+        return sa.SELECTED, bits, keep
+    if kind == "skips_tile":
+        mask = _SkipsTile(tile)
+        pos = jnp.arange(S)
+        see = mask.visible(pos[:, None], pos[None, :])
+    else:
+        mask, see = _mask_and_matrix(kind, S)
+    return mask, None, jnp.broadcast_to(see, (B, S, S))
+
+
+def _kernel_inputs(G, D, dtype, S, B=1):
+    Hkv = 2 if D < 128 else 1           # two 64-wide heads fill a block
+    kq, kk, kv = jax.random.split(jax.random.key(G + D + S), 3)
+    return (jax.random.normal(kq, (B, S, Hkv, G, D)).astype(dtype),
+            jax.random.normal(kk, (B, S, Hkv, D)).astype(dtype),
+            jax.random.normal(kv, (B, S, Hkv, D)).astype(dtype))
+
+
+def _interpreted(q, k, v, data, tile, mask):
+    from jax.experimental.pallas import tpu as pltpu
+    with pltpu.force_tpu_interpret_mode():
+        return jax.jit(lambda q, k, v, data: ak.attn_fwd_tiles(
+            q, k, v, data, tile, mask))(q, k, v, data)
+
+
+@pytest.mark.parametrize("kind,G,D,dtype", [
+    ("causal", 1, 64, jnp.float32), ("causal", 4, 128, jnp.bfloat16),
+    ("causal", 16, 256, jnp.float32),
+    ("window19", 4, 64, jnp.bfloat16), ("window19", 16, 128, jnp.float32),
+    ("window19", 1, 256, jnp.bfloat16),
+    ("block_diffusion", 4, 64, jnp.float32),
+    ("block_diffusion", 1, 128, jnp.bfloat16),
+    ("block_diffusion", 4, 256, jnp.float32),
+    ("selected", 4, 64, jnp.float32), ("selected", 1, 128, jnp.bfloat16),
+    ("selected", 16, 256, jnp.float32),
+], ids=lambda x: getattr(x, "__name__", str(x)))
+def test_forward_kernel_against_the_xla_walk_and_the_explicit_mask(
+        kind, G, D, dtype):
+    """``attn_fwd_tiles`` under Pallas' interpreter: ``o`` and ``lse`` are
+    the XLA walk's (the same products in the same precision: f32 to
+    rounding, bf16 to a step of the output) and plain attention's under
+    the mask written out."""
+    S, tile = 32, 8
+    mask, data, see = _kernel_case(kind, S, tile)
+    q, k, v = _kernel_inputs(G, D, dtype, S)
+    o, lse = _interpreted(q, k, v, data, tile, mask)
+    o_x, lse_x = ra._blockwise_fwd(q, k, v, data, tile, mask)
+    assert o.dtype == dtype and o.shape == q.shape
+    assert lse.dtype == jnp.float32 and lse.shape == q.shape[:-1]
+    tol = 2e-6 if dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(o_x, np.float32), atol=tol)
+    np.testing.assert_allclose(lse, lse_x, atol=2e-5, rtol=1e-6)
+    B, _S, Hkv, _G, _D = q.shape
+    want = _masked_attention(q.reshape(B, S, Hkv * G, D), k, v, see[:, None])
+    np.testing.assert_allclose(
+        np.asarray(o, np.float32).reshape(want.shape), want,
+        atol=1e-5 if dtype == jnp.float32 else 2e-2)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) / np.sqrt(D)
+    want_lse = jax.nn.logsumexp(jnp.where(see[:, None, None], s, -jnp.inf),
+                                axis=-1)
+    np.testing.assert_allclose(lse, jnp.einsum("bhgq->bqhg", want_lse),
+                               atol=1e-4, rtol=1e-5)
+
+
+@pytest.mark.parametrize("kind", ["causal", "window19", "block_diffusion",
+                                  "selected"])
+def test_gradients_through_the_kernel_are_the_xla_walk_s(kind, monkeypatch):
+    """The hand-written backward takes the kernel's residuals as it takes
+    the XLA walk's: gradients of a loss on ``o`` agree."""
+    S, tile, G, D = 32, 8, 4, 64
+    mask, data, _see = _kernel_case(kind, S, tile)
+    q, k, v = _kernel_inputs(G, D, jnp.float32, S)
+    w = jax.random.normal(jax.random.key(7), q.shape)
+
+    def grads():
+        return jax.grad(lambda q, k, v: (ra._blockwise(
+            q, k, v, data, tile, mask)[0] * w).sum(), (0, 1, 2))(q, k, v)
+
+    want = grads()
+    monkeypatch.setattr(ra, "_forward", lambda q, k, v, data, size, mask:
+                        _interpreted(q, k, v, data, size, mask))
+    for a, b in zip(grads(), want):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("walk", ["xla", "tiles"])
+def test_a_tile_in_no_list_is_never_multiplied(walk):
+    """A key tile of NaNs that no list names changes nothing, in either
+    walk: a product with it would poison every query that took it."""
+    S, tile, G, D = 32, 8, 4, 64
+    mask, data, _see = _kernel_case("skips_tile", S, tile)
+    pairs = ak.tile_pairs(mask, S // tile, tile)
+    assert 1 not in pairs[1] and sorted(set(pairs[0])) == [0, 1, 2, 3]
+    q, k, v = _kernel_inputs(G, D, jnp.float32, S)
+    poison = jnp.zeros(S).at[tile:2 * tile].set(jnp.nan)[None, :, None, None]
+    fwd = _interpreted if walk == "tiles" else ra._blockwise_fwd
+    o, lse = fwd(q, k, v, data, tile, mask)
+    o_nan, lse_nan = fwd(q, k + poison, v + poison, data, tile, mask)
+    assert bool(jnp.isfinite(o_nan).all() & jnp.isfinite(lse_nan).all())
+    np.testing.assert_array_equal(o, o_nan)
+    np.testing.assert_array_equal(lse, lse_nan)
+
+
+@pytest.mark.parametrize("kind,S,tile,pairs", [
+    ("causal", 64, 8, 36),             # the diagonal and everything under it
+    ("window19", 64, 8, 26),           # a band: 1, 2, 3, 4, 4, 4, 4, 4 tiles
+    ("window1", 64, 8, 8),
+    ("block_diffusion", 64, 8, 24),    # N^2 + 2N of the 4 N^2, N = 4
+    ("skips_tile", 32, 8, 7),
+    ("selected", 64, 32, 3),
+])
+def test_tile_pairs_are_the_mask_s_lists_in_order(kind, S, tile, pairs):
+    """``tile_pairs`` flattens ``mask.key_tiles``: every query tile's list
+    whole, together and in its own order, opened and closed once."""
+    mask, _data, _see = _kernel_case(kind, S, tile)
+    n = S // tile
+    q_of, k_of, flags = ak.tile_pairs(mask, n, tile)
+    assert len(q_of) == pairs
+    at = 0
+    for i in range(n):
+        lo, hi, tile_of = mask.key_tiles(i, n, tile)
+        want = [int(tile_of(t)) for t in range(int(lo), int(hi))]
+        assert list(k_of[at:at + len(want)]) == want
+        assert list(q_of[at:at + len(want)]) == [i] * len(want)
+        assert flags[at] & ak.FIRST and flags[at + len(want) - 1] & ak.LAST
+        assert not any(flags[at + 1:at + len(want)] & ak.FIRST)
+        assert not any(flags[at:at + len(want) - 1] & ak.LAST)
+        at += len(want)
+    assert at == len(q_of)

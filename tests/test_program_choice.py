@@ -181,3 +181,83 @@ def test_step_text_ignores_files_and_environment(
                 monkeypatch.setenv(var, value)
         got = step_texts(configuration)
     assert got == want
+
+
+# -- blockwise attention's forward walk (ISSUE 50) ----------------------------
+
+@pytest.mark.parametrize("dtype,size,Hkv,G,D,kernel", [
+    # the six LM cells' layers: the kernel takes them
+    (jnp.bfloat16, 512, 4, 8, 128, True),       # sdar, trinity, keye2
+    (jnp.bfloat16, 512, 8, 4, 64, True),        # lfm2: two heads a block
+    (jnp.bfloat16, 512, 20, 1, 256, True),      # glm47f
+    (jnp.bfloat16, 512, 2, 16, 128, True),      # nemotron3n
+    (jnp.float32, 512, 4, 8, 128, True),
+    (jnp.float32, 128, 8, 4, 64, True),         # the smallest tile
+    (jnp.bfloat16, 256, 1, 1, 256, True),       # one head in all
+    # what it does not take: the XLA walk on a TPU too
+    (jnp.float16, 512, 4, 8, 128, False),       # dtype
+    (jnp.bfloat16, 8, 4, 8, 128, False),        # a tile of 8 positions
+    (jnp.bfloat16, 512, 4, 8, 96, False),       # no whole or half block
+    (jnp.bfloat16, 512, 3, 4, 64, False),       # an odd head left over
+    (jnp.bfloat16, 512, 1, 128, 128, False),    # more than VMEM holds
+], ids=lambda x: getattr(x, "__name__", str(x)))
+def test_attention_kernel_takes_by_dtype_and_shape(
+        dtype, size, Hkv, G, D, kernel, monkeypatch):
+    """``attention_kernel.takes`` — what ``ring_attention._forward`` asks
+    before it offers the kernel to a TPU's lowering — is a function of its
+    arguments: nothing in the environment moves it.  (The platform's half
+    of the choice is ``lax.platform_dependent``'s: the next test.)"""
+    import importlib
+    ak = importlib.import_module("swiftmpi_tpu.parallel.attention_kernel")
+    assert ak.takes(dtype, size, Hkv, G, D) == kernel
+    for var in ("SMTPU_PALLAS_ATTENTION", "SMTPU_ATTENTION_KERNEL",
+                "SMTPU_NO_PALLAS"):
+        monkeypatch.setenv(var, "0" if kernel else "1")
+    assert ak.takes(dtype, size, Hkv, G, D) == kernel
+
+
+@pytest.mark.parametrize("platform,kernels", [("cpu", 0), ("tpu", 1)])
+def test_attention_lowers_the_walk_of_the_platform_it_is_lowered_for(
+        platform, kernels):
+    """One traced call, two lowerings: the text for a TPU holds the
+    kernel's custom call and no forward ``while``, the text for a CPU the
+    XLA walk — whatever platform this process runs on."""
+    import importlib
+    ra = importlib.import_module("swiftmpi_tpu.parallel.ring_attention")
+    q = jax.ShapeDtypeStruct((1, 256, 8, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
+    text = jax.jit(lambda q, k, v: ra.blockwise_attention(
+        q, k, v, block=128)).trace(q, k, k).lower(
+            lowering_platforms=(platform,)).as_text()
+    assert text.count("tpu_custom_call") == kernels
+    assert ("attn_fwd_tiles" in text) == bool(kernels)
+    assert ("stablehlo.while" in text) != bool(kernels)
+
+
+def test_attention_kernel_is_traced_once_for_a_pass_and_its_recomputation(
+        monkeypatch):
+    """A rematerialised layer's forward pass and its recomputation reach
+    ``attn_fwd_tiles`` under tracing contexts JAX tells apart (no abstract
+    mesh, an empty one): the kernel's body — seconds of a run's set-up at
+    the cells' sizes — is traced once for both all the same."""
+    import importlib
+    ra = importlib.import_module("swiftmpi_tpu.parallel.ring_attention")
+    ak = importlib.import_module("swiftmpi_tpu.parallel.attention_kernel")
+    traced = []
+    pairs = ak.tile_pairs
+    monkeypatch.setattr(ak, "tile_pairs",
+                        lambda *a: traced.append(a) or pairs(*a))
+    q = jax.ShapeDtypeStruct((1, 384, 8, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 384, 2, 128), jnp.bfloat16)
+
+    @jax.checkpoint
+    def layer(q, k, v):
+        return ra.blockwise_attention(q, k, v, block=128)
+
+    def loss(q, k, v):
+        return layer(layer(q, k, v), k, v).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).trace(q, k, k).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert len(traced) == 1, traced

@@ -474,6 +474,14 @@ def test_keye2_ep8_trainer_step_fits_one_chip(topo):
     text = compiled.as_text()
     kernels = re.findall(r'custom_call_target="tpu_custom_call".*?'
                          r'op_name="([^"]*)"', text)
+    # the attention's forward walk under the data mask is the kernel (PR
+    # 50): the scanned layer's body, forward and recomputed, under the
+    # layer's scope and none of the indexer's
+    walks = [n for n in kernels if n.endswith("/attn_fwd_tiles/pallas_call")]
+    assert len(walks) == 2
+    assert all("/sparse_attention/" in n and "indexer" not in n
+               for n in walks)
+    kernels = [n for n in kernels if n not in walks]
     assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
     for dtype, dims in set(re.findall(r"= (\w+)\[([\d,]+)\]", text)):
         big = [int(d) for d in dims.split(",") if int(d) >= S_]
