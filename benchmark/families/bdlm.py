@@ -19,9 +19,7 @@ not the arithmetic under test; ``hidden_states(params, z, cfg, positions=,
 mask=)`` on ``z = [noisy ; tokens]``; ``params["head"]`` (the untied head);
 the device scope ``noise``.
 
-The all-cell metrics read here as in ``families/lm.py``: the six phases of
-program ``w2v_step`` through ``obs.costs.alias`` (the five word2vec phases 0
-ms, ``unscoped`` = ``bd.unscoped_ms_per_step`` again); a "pair" is a masked
+The all-cell metrics read here as in ``families/lm.py``: a "pair" is a masked
 position, ``pair_fill_share`` their share of the ``B x S`` token grid (about
 half: the mean of ``t``), counted in a traced run from the noise drawn
 again.  A word is a token trained (``B S`` a step, not a trunk position).
@@ -193,7 +191,6 @@ class Family(lm.Family):
             obs.set_enabled(True)
         self.cfg = transformer_config(self.config, self.traffic)
         self.trainer = Trainer(self.cfg, **trainer_kwargs(self.config))
-        obs.costs.alias("w2v_step", "trainer_step")
         self.state = self.trainer.init_state(jax.random.key(WEIGHTS_KEY))
         self.fixed = self._fixed()
         self.ref = reference.Reference(self.dims)

@@ -15,16 +15,11 @@ weight_decay=, grad_clip=, b1=, b2=)`` with ``.init_state(key)``,
 ``held_pick_share``, ``expert_load_max_over_mean``,
 ``dropped_picks_per_step``); ``TrainState.params`` / ``.opt_state`` (optax's
 ``chain(clip, adamw)``: ``opt_state[1][0].mu``); ``obs.set_enabled``;
-``obs.costs.phase_map("trainer_step")``, ``obs.costs.alias``;
+``obs.costs.phase_map("trainer_step")``;
 ``utils.xla_env.ensure_compile_cache``.
 
-Eight accepted per-layer metrics have no list of cells and a file each that
-is not this family's to edit (``spec.check()`` holds ``cells`` and
-``workloads`` equal), so this cell reports them too, in the harness's words:
-the six phases of program ``w2v_step`` read the Trainer's step under that
-second name (``obs.costs.alias``: the word2vec phases ``sample pull math
-dedup apply`` take 0 ms of it and ``unscoped`` is ``lm.unscoped_ms_per_step``
-again), and a "pair" is a position with a next token to predict (``S - 1`` a
+The per-layer metrics with no list of cells read here too, in the harness's
+words: a "pair" is a position with a next token to predict (``S - 1`` a
 packed sequence, ``pair_fill_share`` their share of the token grid), counted
 from the host batches as the word2vec counters are.
 """
@@ -179,8 +174,6 @@ class Family:
             obs.set_enabled(True)     # spans, counters, phase_map signatures
         self.cfg = transformer_config(self.config, self.traffic)
         self.trainer = Trainer(self.cfg, **trainer_kwargs(self.config))
-        # the all-cell phase metrics know a cell's step program as w2v_step
-        obs.costs.alias("w2v_step", "trainer_step")
         self.state = self.trainer.init_state(jax.random.key(WEIGHTS_KEY))
         self.fixed = self._fixed()
         self.ref = reference.Reference(self.dims)

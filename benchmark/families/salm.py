@@ -21,12 +21,8 @@ tile)``; ``Trainer.train_metrics``'s ``main_loss``, ``index_loss``,
 its one step whatever the run); the device scopes ``sparse_attention``, ``indexer``,
 ``index_select``.
 
-The all-cell metrics read here as in ``families/lm.py``: the six phases of
-program ``w2v_step`` through ``obs.costs.alias`` (the five word2vec phases 0
-ms; ``step.unscoped_ms_per_step`` is the step's instructions under none of
-the device scopes, and with the cell's seven ``sa.*_ms_per_step`` it adds up
-to ``step.device_busy_ms`` less the scope ``embed``, which no metric of this
-cell reports); a "pair" is a position with a next token.
+The all-cell metrics read here as in ``families/lm.py``: a "pair" is a
+position with a next token.
 """
 
 from __future__ import annotations
@@ -239,7 +235,6 @@ class Family(lm.Family):
             obs.set_enabled(True)
         self.cfg = transformer_config(self.config, self.traffic)
         self.trainer = Trainer(self.cfg, **trainer_kwargs(self.config))
-        obs.costs.alias("w2v_step", "trainer_step")
         self.state = self.trainer.init_state(jax.random.key(WEIGHTS_KEY))
         self.fixed = self._fixed()
         self.ref = reference.Reference(self.dims)

@@ -23,9 +23,7 @@ absent half repeating its input; ``Trainer.train_metrics``'s
 ``ssm_scan_chunks`` (telemetry on); the device scopes ``ssm_mixer`` and
 ``ssm_scan``.
 
-The all-cell metrics read here as in ``families/lm.py``: the six phases of
-program ``w2v_step`` through ``obs.costs.alias`` (the five word2vec phases 0
-ms, ``unscoped`` = ``ssm.unscoped_ms_per_step`` again); a "pair" is a
+The all-cell metrics read here as in ``families/lm.py``: a "pair" is a
 position with a next token.
 """
 
@@ -194,7 +192,6 @@ class Family(lm.Family):
             obs.set_enabled(True)
         self.cfg = transformer_config(self.config, self.traffic)
         self.trainer = Trainer(self.cfg, **trainer_kwargs(self.config))
-        obs.costs.alias("w2v_step", "trainer_step")
         self.state = self.trainer.init_state(jax.random.key(WEIGHTS_KEY))
         self.fixed = self._fixed()
         self.ref = reference.Reference(self.dims)

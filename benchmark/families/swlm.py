@@ -20,9 +20,7 @@ head lists (``tests/test_benchmark_rehearsal.py::test_harness_surface
 pair-fill counters, never used to compute); the device scopes
 ``window_attention`` and ``shared_expert``.
 
-The all-cell metrics read here as in ``families/lm.py``: the six phases of
-program ``w2v_step`` through ``obs.costs.alias`` (the five word2vec phases 0
-ms, ``unscoped`` = ``sw.unscoped_ms_per_step`` again); a "pair" is a
+The all-cell metrics read here as in ``families/lm.py``: a "pair" is a
 position with a next token.
 """
 
@@ -175,7 +173,6 @@ class Family(lm.Family):
             obs.set_enabled(True)
         self.cfg = transformer_config(self.config, self.traffic)
         self.trainer = Trainer(self.cfg, **trainer_kwargs(self.config))
-        obs.costs.alias("w2v_step", "trainer_step")
         self.state = self.trainer.init_state(jax.random.key(WEIGHTS_KEY))
         self.fixed = self._fixed()
         self.ref = reference.Reference(self.dims)

@@ -9,6 +9,7 @@ on a shared machine) moves nothing.
 
 from __future__ import annotations
 
+import gc
 import glob
 import math
 import os
@@ -122,6 +123,11 @@ def traced(family, steps: int, chunks: int, counter: CompileCounter,
     opts.host_tracer_level = 2
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
     try:
+        # the harness's garbage (set-up's arrays, the reference's) is freed
+        # at the runtime's next call: make that call here, not the window's
+        # first train() (PythonRefManager::CollectGarbage, 4-8 ms of it)
+        gc.collect()
+        jax.block_until_ready(jax.jit(lambda x: x + 1)(0))
         with annotate("bench/window"):
             win = measure(family, steps, counter,
                           lambda w: len(w.chunks) >= chunks)

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 from dataclasses import dataclass
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -17,6 +18,25 @@ ROOT = os.path.dirname(BENCH_DIR)
 
 E2E_SOURCES = ("host_clock", "device_trace")
 SOURCES = E2E_SOURCES + ("program_span", "program_counter")
+
+# the driver's limits on BENCHMARK.json: a file outside any of them is
+# refused before a single run, so a builder meets them here, by name
+KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
+        "end_to_end", "per_layer"}
+MAX_BYTES = 64 * 1024
+COUNTS = {"paths": (1, 16), "command": (1, 32), "configs": (1, 24),
+          "workloads": (2, 24), "end_to_end": (1, 16), "per_layer": (1, 128)}
+MAX_RUN_SECONDS = 51
+MAX_REDUCED = 16
+MAX_TEXT = 200                 # a why, a layer, a source, a word of command
+NAME = re.compile(r"^[A-Za-z0-9][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
+# a key 'reduced' may never name: a width.  The guard is PR 22's, wide on
+# purpose (any 'head', any 'state': a head count changes a projection's
+# width), with one exemption: 'num_hidden_layers' is a depth.
+WIDTH = re.compile(r"(hidden(?!_layers)|intermediate|latent|state|proj|head|"
+                   r"expan|len_vec|_dim$|_rank$|experts_per)")
 
 
 class SpecError(Exception):
@@ -96,13 +116,105 @@ def load_cell(name: str, rehearse: bool = False) -> Cell:
     return Cell(name, int(entry["chips"]), config, traffic, e2e, layer, band)
 
 
+def four_chip_quota(cells: int) -> int:
+    """Cells that may ask for 4 chips: a quarter, rounded down, or one."""
+    return max(1, cells // 4)
+
+
+def _text(where: str, key: str, value) -> list:
+    if isinstance(value, str) and 1 <= len(value) <= MAX_TEXT \
+            and "\n" not in value and "\t" not in value:
+        return []
+    return [f"{where}: {key!r} must be 1 to {MAX_TEXT} characters on one "
+            f"line, has {len(value) if isinstance(value, str) else value!r}"]
+
+
+def check_limits(bench: dict) -> list:
+    """The driver's limits on ``BENCHMARK.json`` itself, each by name."""
+    bad = []
+    if set(bench) != KEYS:
+        bad.append(f"BENCHMARK.json has the keys {sorted(bench)}, the "
+                   f"contract takes exactly {sorted(KEYS)}")
+        return bad
+    size = len(json.dumps(bench, indent=2, ensure_ascii=False).encode())
+    if size > MAX_BYTES:
+        bad.append(f"BENCHMARK.json is {size} bytes, over the {MAX_BYTES} "
+                   "the contract takes")
+    for key, (lo, hi) in COUNTS.items():
+        if not lo <= len(bench[key]) <= hi:
+            bad.append(f"{key} has {len(bench[key])} entries, the contract "
+                       f"takes {lo} to {hi}"
+                       + ("; fold entries that share a reader "
+                          "(benchmark/README.md)" if key == "per_layer"
+                          else ""))
+    secs = bench["run_seconds"]
+    if not isinstance(secs, int) or not 1 <= secs <= MAX_RUN_SECONDS:
+        bad.append(f"run_seconds is {secs!r}, not a whole number from 1 to "
+                   f"{MAX_RUN_SECONDS}")
+    for p in bench["paths"]:
+        if not PATH.match(p) or p.startswith("/") or ".." in p.split("/"):
+            bad.append(f"paths: {p!r} is no relative path of the repo")
+    for word in bench["command"]:
+        bad += _text("command", "word", word)
+    names = []
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        for x in bench[key]:
+            where = f"{key} {x.get('name')!r}"
+            names.append(x.get("name"))
+            if not isinstance(x.get("name"), str) \
+                    or not NAME.match(x["name"]):
+                bad.append(f"{where}: a name is 1 to 64 letters, digits, "
+                           "'_', '.' and '-', the first a letter or a digit")
+            for k in ("why", "layer", "source"):
+                if k in x and not (k == "source" and key != "configs"):
+                    bad += _text(where, k, x[k])
+            if "unit" in x and not UNIT.match(str(x["unit"])):
+                bad.append(f"{where}: unit {x['unit']!r} is not 1 to 16 "
+                           "letters, digits, '_', '/', '%', '.' and '-'")
+            if key in ("end_to_end", "per_layer") \
+                    and x.get("better") not in ("lower", "higher"):
+                bad.append(f"{where}: better is {x.get('better')!r}, not "
+                           "'lower' or 'higher'")
+    for name in sorted({n for n in names if names.count(n) > 1}):
+        bad.append(f"the name {name!r} is used twice")
+    files = [c["file"] for c in bench["configs"]]
+    for c in bench["configs"]:
+        where = f"configs {c['name']!r}"
+        if files.count(c["file"]) > 1 or not PATH.match(c["file"]) or not \
+                any(c["file"].startswith(p + "/") for p in bench["paths"]):
+            bad.append(f"{where}: file {c['file']!r} must be its own, "
+                       "under 'paths'")
+        if len(c["reduced"]) > MAX_REDUCED:
+            bad.append(f"{where}: 'reduced' has {len(c['reduced'])} keys, "
+                       f"over {MAX_REDUCED}")
+        for k in c["reduced"]:
+            if not NAME.match(k) or WIDTH.search(k):
+                bad.append(f"{where}: 'reduced' names {k!r}: a width may "
+                           "never be reduced")
+    pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
+    for pair in sorted({p for p in pairs if pairs.count(p) > 1}):
+        bad.append(f"config {pair[0]!r} under traffic {pair[1]!r} appears "
+                   "twice")
+    for w in bench["workloads"]:
+        if not NAME.match(str(w["traffic"])):
+            bad.append(f"workloads {w['name']!r}: traffic {w['traffic']!r} "
+                       "is no name")
+    for m in bench["end_to_end"]:
+        if not 0.01 <= m.get("bound", 0) <= 0.1:
+            bad.append(f"end_to_end {m['name']!r}: bound {m.get('bound')!r} "
+                       "is not within 0.01 and 0.1")
+    return bad
+
+
 def check() -> list:
     """Every problem found, as text; empty when the benchmark is whole."""
-    bad = []
     try:
         bench = load_benchmark()
     except SpecError as e:
         return [str(e)]
+    bad = check_limits(bench)
+    if set(bench) != KEYS:
+        return bad
     cells = {w["name"] for w in bench["workloads"]}
     configs = {c["name"]: c for c in bench["configs"]}
     e2e = {m["name"] for m in bench["end_to_end"]}
@@ -136,7 +248,7 @@ def check() -> list:
     for name in set(configs) - used:
         bad.append(f"config {name!r} is used by no workload")
     four = sum(1 for w in bench["workloads"] if w["chips"] == 4)
-    quota = max(1, len(bench["workloads"]) // 4)
+    quota = four_chip_quota(len(bench["workloads"]))
     if four > quota:
         bad.append(f"{four} cells ask for 4 chips; a quarter of "
                    f"{len(bench['workloads'])} cells, rounded down, or one "
@@ -147,7 +259,7 @@ def check() -> list:
                        f"is not one of {E2E_SOURCES}")
     if "setup_s" not in e2e:
         bad.append("end_to_end has no setup_s")
-    declared = set()
+    declared, readers = set(), {}
     for m in bench["per_layer"]:
         where = f"per_layer {m['name']!r}"
         declared.add(m["name"])
@@ -177,6 +289,16 @@ def check() -> list:
                                                      f"{kind}.py")):
             bad.append(f"{where}: reader kind {kind!r} has no "
                        f"benchmark/readers/{kind}.py")
+        # one entry a reader: a second entry may read the same thing only
+        # in other cells (and then it should join the first one's list)
+        same = readers.setdefault(json.dumps(f.get("reader"), sort_keys=True),
+                                  {})
+        for c in metric_cells(m, bench):
+            if c in same:
+                bad.append(f"{where}: reads cell {c!r} with the reader "
+                           f"{same[c]!r} already reads it with; add the "
+                           "cell to that entry's list instead")
+            same.setdefault(c, m["name"])
     for fn in sorted(os.listdir(bench_path("layer_metrics"))):
         if fn.endswith(".json") and fn[:-5] not in declared:
             bad.append(f"layer_metrics/{fn} is not declared under per_layer "

@@ -28,9 +28,18 @@ DEVICE_PLANE = r"^/device:TPU:\d+$"
 OP_LINE = r"^XLA Ops$"
 MODULE_LINE = r"^XLA Modules$"
 HOST_PLANE = r"^/host:CPU$"
-COLLECTIVE_OP = (r"^(all-to-all|all-reduce|all-gather|reduce-scatter|"
-                 r"collective-permute)")
+# XLA's opcode spellings and the jax.lax primitives' (an instruction lowered
+# from ``lax.all_to_all`` is named ``all_to_all.<n>``, not ``all-to-all.<n>``)
+COLLECTIVE_OP = (r"^(all-to-all|all_to_all|all-reduce|all_reduce|psum|pmin|"
+                 r"pmax|all-gather|all_gather|reduce-scatter|reduce_scatter|"
+                 r"collective-permute|ppermute)")
 NO_SPAN = "(no span)"
+# A label of the profiler's own where it lost an op's name: no instruction of
+# a compiled program is called ``region.<n>`` (its computations are
+# ``region_<n>.<m>``).  One plane of a four-chip trace, another from run to
+# run, carries a stretch of every step under such labels (PERF.md section 6)
+UNNAMED_OP = r"^region\.\d+( |$)"
+UNNAMED_SHARE = 0.01                 # of a plane's busy time in the window
 
 
 # -- intervals ----------------------------------------------------------------
@@ -157,6 +166,7 @@ class Device:
 class Trace:
     devices: list
     host_lines: dict    # thread (line) name -> events
+    named: dict = field(default_factory=dict)      # named_devices by window
 
     def window(self, pattern: str):
         """(start, end) spanned by the host events whose name matches
@@ -267,6 +277,32 @@ def matching_seconds(dev: Device, window, pattern: str):
     hit = [s for s in segs if rx.search(s[2])]
     other = [s for s in segs if not rx.search(s[2])]
     return total(hit) / 1e9, total(subtract(hit, other)) / 1e9
+
+
+def named_devices(trace: Trace, window):
+    """``(planes to read op names from, [(plane, share)] left out)``: the ONE
+    decision for every reader that goes by an op's name (``trace_scope``,
+    ``trace_ops``, the breakdown's ``device_ops``).  A plane with more than
+    ``UNNAMED_SHARE`` of its busy time under ``UNNAMED_OP`` labels says
+    nothing of what ran in that time — a scope would lose it to ``unscoped``,
+    a kernel's mean would count the plane as 0 — so it is left out of the
+    mean over the planes, unless every plane is like that (then all are read
+    as they are).  Busy and idle time need no name: they read every plane."""
+    if window not in trace.named:
+        share = [(d, matching_seconds(d, window, UNNAMED_OP)[0]
+                  / max(busy_seconds(d, window), 1e-12))
+                 for d in trace.devices]
+        good = [d for d, s in share if s <= UNNAMED_SHARE]
+        trace.named[window] = (good, [(d.name, s) for d, s in share
+                                      if s > UNNAMED_SHARE]) \
+            if good else (list(trace.devices), [])
+    return trace.named[window]
+
+
+def left_out_text(left_out) -> str:
+    return "".join(f"; left out of the means by op name: {name}, {share:.1%} "
+                   "of its busy time under region.<n> labels"
+                   for name, share in left_out)
 
 
 def launches(dev: Device, window) -> int:

@@ -48,6 +48,11 @@ def program_spans() -> tuple:
     return tuple(HOST_SPANS)
 
 
+def span_pattern(names, also: str = "") -> str:
+    """A regex for exactly these span names (and ``also``, a regex)."""
+    return "^(" + also + "|".join(re.escape(n) for n in names) + ")$"
+
+
 def anchor_line(trace) -> list:
     """Events of the host thread that carries the harness's window."""
     rx = re.compile(ANCHOR)
@@ -75,8 +80,7 @@ def read(params: dict, ctx: dict):
             / ctx["steps"]
     if report == "idle_ms_per_step":
         idle = xplane.idle_gaps_by_span(
-            trace, trace.devices[0], (lo, hi), ANCHOR,
-            "^(" + "|".join(re.escape(n) for n in names) + ")$")
+            trace, trace.devices[0], (lo, hi), ANCHOR, span_pattern(names))
         if params.get("span") is None:
             return 1e3 * idle.get(xplane.NO_SPAN, 0.0) / ctx["steps"]
         rx = re.compile(params["span"])

@@ -22,6 +22,15 @@ neither does this reader.  ``"phase": "unscoped"`` reads that remainder (an
 executed instruction the map does not know counts there too), so the phases
 of one program sum to the device time spent inside it.
 
+A device plane whose ops the profiler labelled by something else than the
+instruction's name (``region.<n>``: one plane of a four-chip trace, a
+different one from run to run) tells nothing about phases: all of it would
+count as ``unscoped`` and the mean over the planes would take that much from
+every scope.  ``xplane.named_devices`` decides once, for every reader that
+goes by an op's name, which planes are read; the planes it leaves out are
+printed here.  A name the map does not know on a plane that is read (a stale
+map) counts as ``unscoped``, as it always did; the line says how much.
+
 ``None`` (the metric is left out of the line) when there is no device plane,
 the program has no ``phase_map`` (a commit that predates it) or no map (no
 handle ran with telemetry on; an executable served from a compile cache
@@ -50,11 +59,15 @@ def program_phase_map(program: str):
 
 
 def phase_ms_per_step(trace, window, steps: int, pm: dict):
-    """``({phase: ms a step, mean over the devices}, events the map does
-    not know)``, or ``None`` when ``pm["module"]`` never ran in the window."""
+    """``({phase: ms a step, mean over the planes read}, unknown)``, or
+    ``None`` when ``pm["module"]`` never ran in the window.  ``unknown`` has
+    the op segments the map does not know and their time (``"segments"``,
+    ``"ms_per_step"``, on the planes read) and the planes
+    ``xplane.named_devices`` left out (``"left_out"``)."""
     lo, hi = window
-    per_device, unknown = [], 0
-    for dev in trace.devices:
+    devices, left_out = xplane.named_devices(trace, window)
+    per_device, segments, unknown_ns = [], 0, 0.0
+    for dev in devices:
         runs = sorted((s, e) for s, e, name in xplane.clip(dev.modules, lo, hi)
                       if _RUN.match(name).group(1) == pm["module"])
         if not runs:
@@ -68,15 +81,19 @@ def phase_ms_per_step(trace, window, steps: int, pm: dict):
             # "%slice-start.1 = ((s32[...": a label op_label left as text
             phase = pm["phase"].get(label.split(" ", 1)[0].lstrip("%"))
             if phase is None:
-                unknown += 1
+                segments += 1
+                unknown_ns += e - s
                 phase = UNSCOPED
             acc[phase] = acc.get(phase, 0.0) + (e - s)
         per_device.append(acc)
     if not per_device:
         return None
     phases = {p for acc in per_device for p in acc}
-    return {p: statistics.fmean(acc.get(p, 0.0) for acc in per_device)
-            / 1e6 / steps for p in phases}, unknown
+    by_phase = {p: statistics.fmean(acc.get(p, 0.0) for acc in per_device)
+                / 1e6 / steps for p in phases}
+    return by_phase, {"segments": segments, "left_out": left_out,
+                      "ms_per_step": unknown_ns / len(per_device) / 1e6
+                      / steps}
 
 
 def read(params: dict, ctx: dict):
@@ -87,17 +104,21 @@ def read(params: dict, ctx: dict):
     cache = ctx.setdefault("_trace_scope", {})
     program = params["program"]
     if program not in cache:
-        pm = program_phase_map(program)
+        # a kept trace brings the maps its run had (tools/read_kept_trace.py)
+        maps = ctx.get("phase_maps")
+        pm = program_phase_map(program) if maps is None else maps.get(program)
         cache[program] = None if pm is None else \
             phase_ms_per_step(trace, window, ctx["steps"], pm)
         if cache[program] is not None:
             by_phase, unknown = cache[program]
             print(f"[bench] trace_scope: {program} = {pm['module']}, "
                   f"{pm['instructions']} instructions, {pm['unscoped']} "
-                  f"under no scope; {unknown} traced op segments not in "
-                  "the map; ms a step by phase: "
+                  f"under no scope; {unknown['segments']} traced op "
+                  f"segments not in the map ({unknown['ms_per_step']:.3f} ms "
+                  "a step, read as unscoped); ms a step by phase: "
                   + " ".join(f"{p}={v:.3f}" for p, v in
-                             sorted(by_phase.items())), flush=True)
+                             sorted(by_phase.items()))
+                  + xplane.left_out_text(unknown["left_out"]), flush=True)
     if cache[program] is None:
         return None
     return cache[program][0].get(params["phase"], 0.0)

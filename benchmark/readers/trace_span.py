@@ -6,9 +6,9 @@ is the time, in milliseconds a step, the harness's thread (the one that
 carries ``bench/window``) spent inside the ``obs.span``s whose name matches
 ``span``, within the traced window (their union, should two nest).
 ``"report": "idle_ms_per_step"`` is device 0's idle time credited to them
-instead: ``xplane.idle_gaps_by_span`` with the harness's anchor over the
-harness's span list plus the program's call-level spans, so each idle gap goes
-to the innermost span open at the time, and the matching names are summed.
+instead: ``xplane.idle_gaps_by_span`` with the harness's anchor over
+``SPANS`` below, so each idle gap goes to the innermost of those spans open at
+the time, and the matching names are summed.
 
 The contract with the program: ``obs.span(name)`` is a
 ``jax.profiler.TraceAnnotation`` of that name when ``[worker] telemetry`` is
@@ -26,7 +26,12 @@ import re
 from . import traced
 from ..lib import xplane
 
-# benchmark/run.py's, plus the program's call-level spans
+# The harness's annotations, the loop's spans and the CALL-LEVEL spans, on
+# purpose not ``obs.catalog.HOST_SPANS``: a span the program opens inside a
+# call-level one (``loss_wait`` in ``loss_fetch``) leaves its idle time with
+# its parent here, which is what ``device.idle_in_call_overhead_ms_per_step``
+# reads and ``tests/test_trace_host.py`` (tier-1) holds; ``trace_host`` reads
+# by the program's own list.
 ANCHOR = r"^bench/window$"
 SPANS = (r"^(bench/|render$|h2d$|input_wait$|dispatch$|train_setup$|"
          r"loss_fetch$|train_finish$)")

@@ -6,7 +6,8 @@
 The process holds the chip(s) itself and starts nothing that imports JAX.
 The last line of standard output is one JSON object with the keys
 ``correct``, ``attempted``, ``failed``, ``metrics`` and ``device`` (and
-``breakdown`` in a traced run); everything else goes on earlier lines.
+``breakdown`` in a traced run), then ``compared``: each number ``correct``
+compared beside its limit; everything else goes on earlier lines.
 Without a TPU, or with another device count than the cell's ``chips``, it
 exits non-zero and prints no result line; ``--rehearse-cpu`` runs the cell
 at its toy size on the CPU backend and prints ``platform: cpu`` and no time,
@@ -33,9 +34,6 @@ sys.path.insert(0, ROOT)
 from benchmark.lib import device as devlib   # noqa: E402
 from benchmark.lib import loop, spec, xplane  # noqa: E402
 
-# host spans a device idle gap can be credited to: the harness's own and the
-# program's (obs.span names, TraceAnnotations when [worker] telemetry: 1)
-SPANS = r"^(bench/|render$|h2d$|input_wait$|dispatch$)"
 ANCHOR = r"^bench/window$"
 
 
@@ -54,7 +52,8 @@ def parse_args(argv=None):
                     help="toy size on the CPU backend: counts and "
                          "correctness, no time or device metric")
     ap.add_argument("--keep-trace", default=None, metavar="DIR",
-                    help="copy the traced run's .xplane.pb into DIR")
+                    help="copy the traced run's .xplane.pb into DIR, with "
+                         "what tools/read_kept_trace.py needs beside it")
     return ap.parse_args(argv)
 
 
@@ -84,8 +83,70 @@ def layer_metrics(cell, ctx: dict) -> dict:
     return out
 
 
+def keep_context(dirname: str, cell, ctx: dict) -> None:
+    """What the readers had beside the kept ``.xplane.pb``: with it
+    ``tools/read_kept_trace.py`` reads the cell's per-layer metrics off the
+    same trace again, with this tree's readers or another's."""
+    from benchmark.readers import trace_scope
+
+    programs = {meta["reader"]["program"] for _e, meta in cell.per_layer
+                if "program" in meta["reader"]}
+    kept = {k: ctx[k] for k in ("harness", "counters", "steps", "memory",
+                                "floor")}
+    kept.update(cell=cell.name, phase_maps={
+        p: trace_scope.program_phase_map(p) for p in sorted(programs)})
+    with open(os.path.join(dirname, "context.json"), "w") as f:
+        json.dump(kept, f, default=float)
+
+
+def compared_numbers(cell, first, rows, warm, win, loss_fixed, band, places,
+                     peaks) -> dict:
+    """``{name: [number, limit]}``: every number ``correct`` compares, each
+    beside its limit.  ``checks`` is computed from this dict (``within``), so
+    what a run prints is what decided it.  A limit is an upper one, or a
+    ``[low, high]`` band; a condition is the count of its breaches beside 0.
+    ``train_loss_fixed`` has a band on the chip only (none is compared in a
+    CPU rehearsal, and no entry is made)."""
+    rtol = getattr(importlib.import_module(
+        f"benchmark.reference.{cell.family}"), "RTOL", None)
+    out = {f"first_step.{k}": [float(v["max_err"]), v.get("limit", rtol)]
+           for k, v in first["fields"].items()}
+    # the family's own verdict: the fields above, and what it holds beside
+    # them without a number (an update that is not zero, rows kept)
+    out["first_step.not_ok"] = [int(not first["ok"]), 0]
+    for k, v in rows.items():
+        if isinstance(v, str):               # "moved/sampled"
+            done, of = (int(x) for x in v.split("/"))
+            out[f"rows.not_{k}"] = [of - done, 0]
+        else:                                # a condition, "ok" with them
+            out[f"rows.not_{k}"] = [int(not v), 0]
+    losses = [first["loss"], loss_fixed] + [c.loss for c in warm + win.chunks]
+    out["losses.not_finite"] = [sum(not math.isfinite(x) for x in losses), 0]
+    if band is not None:
+        out["train_loss_fixed"] = [loss_fixed, list(band)]
+    out["window.programs_lowered"] = [win.compiles["lowered"], 0]
+    out["table.fields_misplaced"] = [sum(len(p["why"]) for p in places), 0]
+    if peaks is not None:
+        out["table.bytes_over_peaks"] = [
+            max(0, places[0]["table_bytes"] - sum(peaks)), 0]
+    out["steps.failed"] = [win.failed + sum(c.failed for c in warm), 0]
+    return out
+
+
+def within(compared: dict, prefix: str) -> bool:
+    """Every compared number under ``prefix`` is finite and in its limit."""
+    ok = True
+    for name, (value, limit) in compared.items():
+        if name.startswith(prefix):
+            lo, hi = limit if isinstance(limit, list) else (-math.inf, limit)
+            ok = ok and math.isfinite(value) and lo <= value <= hi
+    return ok
+
+
 def reduce_trace(path: str, steps: int):
     """(Trace, window, device block, breakdown) of a traced window."""
+    from benchmark.readers import trace_host
+
     trace = xplane.load(path)
     window = trace.window(ANCHOR)
     if not trace.devices or window is None:
@@ -93,18 +154,23 @@ def reduce_trace(path: str, steps: int):
     lo, hi = window
     busy = [xplane.busy_seconds(d, window) for d in trace.devices]
     ops = {}
-    for d in trace.devices:
+    named, left_out = xplane.named_devices(trace, window)
+    for d in named:
         for name, s in xplane.op_seconds(d, window,
                                          xplane.op_group).items():
-            ops[name] = ops.get(name, 0.0) + s / len(trace.devices)
+            ops[name] = ops.get(name, 0.0) + s / len(named)
+    # host spans an idle gap can be credited to: the harness's own and the
+    # program's (obs.span names, TraceAnnotations when [worker] telemetry: 1)
+    spans = trace_host.span_pattern(trace_host.program_spans(),
+                                    also="bench/.*|")
     idle = xplane.idle_gaps_by_span(trace, trace.devices[0], window,
-                                    ANCHOR, SPANS)
+                                    ANCHOR, spans)
     block = {"busy_s": sum(busy) / len(busy), "window_s": (hi - lo) / 1e9}
     breakdown = {"device_ops": xplane.top(ops), "idle_gaps": xplane.top(idle)}
     log(f"trace: {len(trace.devices)} device plane(s), window "
         f"{block['window_s']:.3f}s, busy " + " ".join(f"{b:.3f}s"
                                                       for b in busy)
-        + f", {steps} steps")
+        + f", {steps} steps" + xplane.left_out_text(left_out))
     return trace, window, block, breakdown
 
 
@@ -221,16 +287,16 @@ def run_cell(args, cell, family, counter, devices, seconds, workdir,
     peaks = devlib.peak_bytes(devices)
     log(f"memory_stats of device 0: {devices[0].memory_stats()}")
     band = cell.band.get("train_loss_fixed") if device_run else None
+    compared = compared_numbers(cell, first, rows, warm, win, loss_fixed,
+                                band, (place, place_end), peaks)
     checks = {
-        "first_step_matches_reference": first["ok"],
-        "rows": rows["ok"],
-        "losses_finite": all(math.isfinite(c.loss) for c in warm + win.chunks)
-        and math.isfinite(loss_fixed),
-        "loss_in_band": band is None or band[0] <= loss_fixed <= band[1],
-        "no_compilation_in_window": win.compiles["lowered"] == 0,
-        "table_resident": place["ok"] and place_end["ok"] and (
-            peaks is None or sum(peaks) >= place["table_bytes"]),
-        "no_failed_step": win.failed == 0 and not any(c.failed for c in warm),
+        "first_step_matches_reference": within(compared, "first_step."),
+        "rows": within(compared, "rows."),
+        "losses_finite": within(compared, "losses."),
+        "loss_in_band": within(compared, "train_loss_fixed"),
+        "no_compilation_in_window": within(compared, "window."),
+        "table_resident": within(compared, "table."),
+        "no_failed_step": within(compared, "steps."),
     }
     if device_run and not cell.band:
         log("no benchmark/bands file for this cell: loss band not checked")
@@ -253,6 +319,8 @@ def run_cell(args, cell, family, counter, devices, seconds, workdir,
                    devlib.peaks_for(dev.device_kind))
                if device_run else None}
         metrics = layer_metrics(cell, ctx)
+        if args.keep_trace:
+            keep_context(args.keep_trace, cell, ctx)
         if not device_run:
             log(f"rehearsal readings, not metrics: {metrics}")
             metrics = {}
@@ -268,6 +336,12 @@ def run_cell(args, cell, family, counter, devices, seconds, workdir,
               "failed": win.failed, "metrics": metrics, "device": device}
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # the contract: each number compared beside its limit, as the result's
+    # last key and the run's last lines on standard error
+    result["compared"] = compared
+    for name, (value, limit) in compared.items():
+        print(f"[bench] compared {name}: {value!r} limit {limit!r}",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -52,7 +52,18 @@ def test_cpu_rehearsal_end_to_end(cell, trace, devices):
     assert "platform: cpu" in lines[0]
     result = json.loads(lines[-1])
     assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+                           "device", "compared"}
+    assert list(result)[-1] == "compared"       # the numbers beside limits
+    compared = result["compared"]
+    for name, (value, limit) in compared.items():
+        assert limit is not None and 0.0 <= value <= limit, (name, value,
+                                                             limit)
+    # one number at least behind each check a CPU run makes; no band there
+    assert {n.split(".")[0] for n in compared} == {
+        "first_step", "rows", "losses", "window", "table", "steps"}
+    assert p.stderr.rstrip().splitlines()[-len(compared):] == [
+        f"[bench] compared {name}: {value!r} limit {limit!r}"
+        for name, (value, limit) in compared.items()]
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 0
     assert result["device"]["platform"] == "cpu"
@@ -159,3 +170,66 @@ def test_a_failing_chunk_fails_all_its_steps_and_ends_the_window():
     win = loop.measure(fam, 4, Counter(), lambda w: w.seconds >= 60.0)
     assert [c.failed for c in win.chunks] == [False, True, True]
     assert win.attempted == 12 and win.failed == 8 and fam.calls == 3
+
+
+def _sound_run():
+    """The arguments of ``run.compared_numbers`` for a run with no fault."""
+    from types import SimpleNamespace as NS
+
+    cell = NS(family="w2v")
+    first = {"ok": True, "loss": 0.7, "fields": {
+        "h": {"max_err": 5e-6, "ok": True},
+        "v": {"max_err": 2e-3, "limit": 1e-2, "ok": True}}}
+    rows = {"ok": True, "unoccupied_unchanged": True,
+            "live_rows_moved": "4/4", "finite": True}
+    warm = [loop.Chunk(4, 40, 1.0, 0.69)]
+    win = loop.Window([loop.Chunk(4, 40, 1.0, 0.68)], 1.0, {"lowered": 0})
+    places = ({"why": [], "table_bytes": 100}, {"why": [], "table_bytes": 100})
+    return dict(cell=cell, first=first, rows=rows, warm=warm, win=win,
+                loss_fixed=0.5, band=[0.4, 0.6], places=places, peaks=[60, 60])
+
+
+@pytest.mark.parametrize("fault, name, check", [
+    (None, None, None),
+    (lambda a: a["first"]["fields"]["h"].update(max_err=2e-4),
+     "first_step.h", "first_step."),
+    (lambda a: a["first"]["fields"]["v"].update(max_err=float("nan")),
+     "first_step.v", "first_step."),
+    (lambda a: a["first"].update(ok=False), "first_step.not_ok",
+     "first_step."),
+    (lambda a: a["rows"].update(live_rows_moved="3/4"),
+     "rows.not_live_rows_moved", "rows."),
+    (lambda a: a["rows"].update(unoccupied_unchanged=False),
+     "rows.not_unoccupied_unchanged", "rows."),
+    (lambda a: a["rows"].update(ok=False), "rows.not_ok", "rows."),
+    (lambda a: a["warm"].append(loop.Chunk(4, 40, 1.0, float("inf"))),
+     "losses.not_finite", "losses."),
+    (lambda a: a.update(loss_fixed=0.61), "train_loss_fixed",
+     "train_loss_fixed"),
+    (lambda a: a["win"].compiles.update(lowered=1),
+     "window.programs_lowered", "window."),
+    (lambda a: a["places"][1]["why"].append("field 'h' lives on ['cpu']"),
+     "table.fields_misplaced", "table."),
+    (lambda a: a.update(peaks=[40, 40]), "table.bytes_over_peaks", "table."),
+    (lambda a: a["win"].chunks.append(loop.Chunk(4, 40, 1.0, 0.6, True)),
+     "steps.failed", "steps."),
+])
+def test_each_number_compared_decides_its_check(fault, name, check):
+    """``compared`` is what ``checks`` is computed from: a sound run is
+    within every limit, and each breach shows in its own number and fails
+    the check of its prefix, no other."""
+    from benchmark import run as harness
+
+    args = _sound_run()
+    if fault:
+        fault(args)
+    compared = harness.compared_numbers(**args)
+    assert compared["first_step.h"][1] == 1e-4       # the reference's RTOL
+    assert all(limit is not None for _v, limit in compared.values())
+    prefixes = ("first_step.", "rows.", "losses.", "train_loss_fixed",
+                "window.", "table.", "steps.")
+    failed = [p for p in prefixes if not harness.within(compared, p)]
+    assert failed == ([check] if fault else [])
+    if fault:
+        value, limit = compared[name]
+        assert not harness.within({name: [value, limit]}, name)

@@ -36,7 +36,7 @@ def test_sg2m_b2k_cpu_rehearsal_end_to_end(trace):
     assert "platform: cpu" in lines[0] and "config w2v-sg-2m-300" in lines[0]
     result = json.loads(lines[-1])
     assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+                           "device", "compared"}
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 0
     assert result["device"]["platform"] == "cpu"
