@@ -114,13 +114,18 @@ class Cluster:
                      partition=None) -> SparseTable:
         """``partition``: optional ``HotColdPartition`` reserving a
         replicated hot head in the table (hybrid transfer); tail keys
-        keep the hashfrag-sharded layout."""
+        keep the hashfrag-sharded layout.  The set-up span
+        ``table_create``: the key index's frame and the table's jitted
+        ``init_all`` (``SparseTable._init_state``)."""
+        from swiftmpi_tpu import obs      # obs imports this package
+
         if not self._initialized:
             raise RuntimeError("Cluster.initialize() first")
-        ki = KeyIndex(self.n_servers, capacity_per_shard,
-                      hashfrag=self.hashfrag, partition=partition)
-        table = SparseTable(access, ki, mesh=self.mesh,
-                            axis=self.table_axis, seed=seed)
+        with obs.setup_span("table_create"):
+            ki = KeyIndex(self.n_servers, capacity_per_shard,
+                          hashfrag=self.hashfrag, partition=partition)
+            table = SparseTable(access, ki, mesh=self.mesh,
+                                axis=self.table_axis, seed=seed)
         self.tables[name] = table
         return table
 

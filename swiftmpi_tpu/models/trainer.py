@@ -178,6 +178,12 @@ class Trainer:
 
     # -- state ------------------------------------------------------------
     def init_state(self, key) -> TrainState:
+        """Parameters, their placement and the optimizer's state: the
+        set-up span ``state_init`` (``obs.catalog.SETUP_SPANS``)."""
+        with obs.setup_span("state_init"):
+            return self._init_state(key)
+
+    def _init_state(self, key) -> TrainState:
         params = init_params(key, self.cfg)
         if self.mesh is not None:
             shardings = param_shardings(params, self.cfg, self.mesh)
@@ -275,14 +281,18 @@ class Trainer:
         """One train step.  Host spans, siblings in this order:
         ``step_prep`` (the step function and sharding checks), ``h2d``
         (host tokens only), ``dispatch``, ``step_book`` (counters, meter,
-        recorder, publisher, controller, and ``run``'s own ``_book``)."""
+        recorder, publisher, controller, and ``run``'s own ``_book``).
+        The step program is built in the set-up span ``step_build`` and
+        its one call after that runs in ``first_step``
+        (``obs.costs.TrackedFn``)."""
         n = self._host_steps
         reshard = False
         with obs.span("step_prep", step=n):
             faults.step_event(n)
             self._host_steps += 1
             if self._step_fn is None:
-                self._step_fn = self._build_step()
+                with obs.setup_span("step_build"):
+                    self._step_fn = self._build_step()
             if self.mesh is not None:
                 want = NamedSharding(self.mesh, P("data", None))
                 reshard = not (isinstance(tokens, jax.Array)
@@ -414,6 +424,7 @@ class Trainer:
                 **_loss_parts([p for _s, p in stats], self.cfg.mtp_weight),
                 **_part_means([p for _s, p in stats], SCAN_PARTS),
                 **_part_means([p for _s, p in stats], INDEX_PARTS)}
+        obs.log_setup_once()     # start-up, said once a process
         return state, losses
 
     # -- checkpoints (multihost-safe, atomic, CRC-validated) ---------------

@@ -549,7 +549,14 @@ class Word2Vec:
 
     def build_from_vocab(self, vocab: Vocab) -> "Word2Vec":
         """Bring up table + sampler from a prebuilt vocab (e.g. the native
-        C++ loader's) without a python counting pass."""
+        C++ loader's) without a python counting pass.  Set-up spans
+        (``obs.catalog.SETUP_SPANS``): ``model_build`` over the whole of
+        it, with the children ``table_create`` (``Cluster.create_table``),
+        ``key_index`` and ``sampler_build``."""
+        with obs.setup_span("model_build"):
+            return self._bring_up(vocab)
+
+    def _bring_up(self, vocab: Vocab) -> "Word2Vec":
         self.vocab = vocab
         V = len(self.vocab)
         if V == 0:
@@ -578,8 +585,9 @@ class Word2Vec:
                     V - partition.n_hot)
             self.table = self.cluster.create_table(
                 "w2v", self.access, cap, partition=partition)
-        slots = self.table.key_index.lookup(self.vocab.keys)
-        self._slot_of_vocab = jnp.asarray(slots, jnp.int32)
+        with obs.setup_span("key_index"):
+            slots = self.table.key_index.lookup(self.vocab.keys)
+            self._slot_of_vocab = jnp.asarray(slots, jnp.int32)
         if self.push_window_size > 1 and hasattr(
                 self.transfer, "window_expected_unique"):
             # sharpen the per-window sparse/dense wire-format crossover
@@ -638,9 +646,10 @@ class Word2Vec:
             self.table.ensure_row_versions()
             log.info("[cluster] pull_cache: %d lines armed",
                      self.pull_cache)
-        prob, alias = build_unigram_alias(self.vocab.counts)
-        self._alias_prob = jnp.asarray(prob)
-        self._alias_idx = jnp.asarray(alias)
+        with obs.setup_span("sampler_build"):
+            prob, alias = build_unigram_alias(self.vocab.counts)
+            self._alias_prob = jnp.asarray(prob)
+            self._alias_idx = jnp.asarray(alias)
         self._resolve_stencil()
         if self.control_settings.enabled:
             self._arm_control()
@@ -879,13 +888,17 @@ class Word2Vec:
         step, the sync step (``local_steps <= 1``), or the async
         ``(grads, apply)`` pair.  The one place a mode picks its
         programs — ``train()`` and the control plane's safe-point
-        recompile (`_rebuild_step`) both come here."""
-        if hogwild:
-            return self._build_hogwild_step(max(self.local_steps, 1))
-        if self.local_steps <= 1:
-            return self._build_step()
-        return (obs.costs.track("w2v_grads", self._build_async_grads()),
-                obs.costs.track("w2v_apply", jax.jit(self._build_apply())))
+        recompile (`_rebuild_step`) both come here.  The building is the
+        set-up span ``step_build``; a program's first call after it is
+        its ``first_step`` (``obs.costs.TrackedFn``)."""
+        with obs.setup_span("step_build"):
+            if hogwild:
+                return self._build_hogwild_step(max(self.local_steps, 1))
+            if self.local_steps <= 1:
+                return self._build_step()
+            return (obs.costs.track("w2v_grads", self._build_async_grads()),
+                    obs.costs.track("w2v_apply",
+                                    jax.jit(self._build_apply())))
 
     def _fused_for(self, n_inner: int):
         """Compiled fused scan of ``n_inner`` steps, cached per length.
@@ -906,9 +919,10 @@ class Word2Vec:
             # cost-catalog funnel (ISSUE 14): one name covers every
             # fused length — each length is its own handle, so a new
             # tail length books a compile, never a retrace
-            fn = self._fused_cache[n_inner] = obs.costs.track(
-                "w2v_multi", self._build_multi_step(n_inner),
-                steps_per_call=n_inner)
+            with obs.setup_span("step_build"):
+                fn = self._fused_cache[n_inner] = obs.costs.track(
+                    "w2v_multi", self._build_multi_step(n_inner),
+                    steps_per_call=n_inner)
         return fn
 
     def _build_multi_step(self, n_inner: int):
@@ -2410,6 +2424,7 @@ class Word2Vec:
                 # its ring of step records goes here, inside the span,
                 # not with the frame after it
                 tel_rec = None
+        obs.log_setup_once()     # start-up, said once a process
         return losses
 
     def sampling_state(self):

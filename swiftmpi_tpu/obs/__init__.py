@@ -59,6 +59,7 @@ __all__ = [
     "stream_filename", "series_key", "parse_series_key",
     "quantile_from_buckets", "process_ident", "process_rank",
     "get_registry", "set_enabled", "reset_for_tests", "span",
+    "setup_span", "setup_report", "log_setup_once",
     "named_scope", "scope_prefix", "current_scope_prefix", "configure",
     "install_recorder", "uninstall_recorder",
     "get_recorder", "record_step", "CostCatalog", "TrackedFn",
@@ -200,6 +201,13 @@ def span(name: str, **attrs):
     if not reg.enabled:
         return _NULL_SPAN
     return _Span(reg, name, attrs)
+
+
+# -- set-up spans (the start-up ledger, obs/costs.py) ------------------------
+
+setup_span = costs.setup_span
+setup_report = costs.setup_report
+log_setup_once = costs.log_setup_once
 
 
 # -- recorder install point -------------------------------------------------

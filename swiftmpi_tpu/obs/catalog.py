@@ -219,6 +219,25 @@ HOST_SPANS = (
     "step_prep", "step_book", "loss_wait",
 )
 
+#: ``obs.setup_span`` names (host side, start-up: a TraceAnnotation and a
+#: record of the start-up ledger, ``obs.setup_report``, telemetry on or
+#: off).  A list of its own: the loop-coverage guard above reads
+#: ``HOST_SPANS`` and nothing here is a loop's span.  ``model_build`` is
+#: ``Word2Vec.build_from_vocab`` with its children ``table_create``
+#: (``Cluster.create_table``: the key index's frame and the table's
+#: jitted ``init_all``), ``key_index`` (the vocabulary's slot lookup,
+#: ``KeyIndex._create``) and ``sampler_build`` (the alias tables and
+#: their placement); ``state_init`` is ``Trainer.init_state``;
+#: ``step_build`` the Python that builds a step program; ``first_step``
+#: the one call of a tracked program that follows its build
+#: (``obs.costs.TrackedFn``); ``kernel_import`` the import of Pallas and its
+#: TPU dialect (``utils.xla_env.pallas``), inside whichever span traces
+#: the first kernel.
+SETUP_SPANS = (
+    "model_build", "table_create", "key_index", "sampler_build",
+    "state_init", "step_build", "first_step", "kernel_import",
+)
+
 
 def declared(name: str) -> bool:
     """True when ``name`` is a declared series (exact or prefix)."""

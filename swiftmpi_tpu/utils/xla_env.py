@@ -49,21 +49,27 @@ def pallas():
     (``PYTHONDONTWRITEBYTECODE``), ~2 s on the benchmark's host and most of
     what a kernel costs a run's set-up: where a persistent compile cache is
     configured the byte code is kept in it too, beside the compiled
-    programs, and read back by the next process as they are."""
+    programs, and read back by the next process as they are.  That first
+    import is the set-up span ``kernel_import``."""
     import sys
 
-    import jax
+    if "jax.experimental.pallas.tpu" not in sys.modules:
+        import jax
 
-    cache = jax.config.jax_compilation_cache_dir
-    held = sys.dont_write_bytecode, sys.pycache_prefix
-    if cache and sys.pycache_prefix is None:
-        sys.dont_write_bytecode = False
-        sys.pycache_prefix = os.path.join(cache, "pycache")
-    try:
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
-    finally:
-        sys.dont_write_bytecode, sys.pycache_prefix = held
+        from swiftmpi_tpu import obs
+
+        cache = jax.config.jax_compilation_cache_dir
+        held = sys.dont_write_bytecode, sys.pycache_prefix
+        if cache and sys.pycache_prefix is None:
+            sys.dont_write_bytecode = False
+            sys.pycache_prefix = os.path.join(cache, "pycache")
+        try:
+            with obs.setup_span("kernel_import"):
+                import jax.experimental.pallas.tpu  # noqa: F401
+        finally:
+            sys.dont_write_bytecode, sys.pycache_prefix = held
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
     return pl, pltpu
 
 

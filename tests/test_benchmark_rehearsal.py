@@ -21,6 +21,7 @@ chip; nothing else under ``tests/`` runs it.  Two guards:
   renames one of these learns it here, not from the driver's chip run.
 """
 
+import ast
 import contextlib
 import inspect
 import json
@@ -68,6 +69,56 @@ def test_cell_rehearses_on_cpu(cell, tmp_path):
     # a CPU run may say what the loss is, never how fast or how full
     assert set(result["metrics"]) == {"train_loss_fixed"}
     assert math.isfinite(result["metrics"]["train_loss_fixed"]["value"])
+
+
+def _one_cell_a_family() -> dict:
+    """``{family: its first cell}``, read from the configurations' files."""
+    out = {}
+    files = {c["name"]: c["file"] for c in BENCHMARK["configs"]}
+    for w in BENCHMARK["workloads"]:
+        with open(os.path.join(REPO, files[w["config"]])) as f:
+            out.setdefault(json.load(f)["family"], w["name"])
+    return out
+
+
+def _setup_ledger_entries(cell) -> set:
+    """The ``per_layer`` entries the ``setup_ledger`` reader reads in
+    ``cell`` (ISSUE 52's ``entry.*``), from the metric files."""
+    out = set()
+    for m in BENCHMARK["per_layer"]:
+        with open(os.path.join(REPO, "benchmark", "layer_metrics",
+                               m["name"] + ".json")) as f:
+            kind = json.load(f)["reader"]["kind"]
+        if kind == "setup_ledger" and cell in m.get("workloads", [cell]):
+            out.add(m["name"])
+    return out
+
+
+@pytest.mark.parametrize("family, cell", sorted(_one_cell_a_family().items()))
+def test_traced_rehearsal_reads_the_setup_ledger(family, cell, tmp_path):
+    """Each family's ``--rehearse-cpu --trace 1`` run shows a reading for
+    every ``setup_ledger`` entry on its cell's list: the program keeps its
+    start-up ledger in the harness's process, spans and compile events
+    both, whichever model the family builds.  (The kernel's import reads
+    0 seconds: a CPU process lowers no Pallas kernel.)"""
+    res = run_child(RUN + ["--workload", cell, "--seed", "11", "--seconds",
+                           "2", "--trace", "1", "--rehearse-cpu"], tmp_path)
+    assert res.returncode == 0, res.stderr[-3000:]
+    line = next(ln for ln in res.stdout.splitlines()
+                if ln.startswith("[bench] rehearsal readings, not metrics: "))
+    readings = ast.literal_eval(line.split("not metrics: ", 1)[1])
+    want = _setup_ledger_entries(cell)
+    assert len(want) == 6 and want <= set(readings), want - set(readings)
+    got = {k: readings[k]["value"] for k in want}
+    assert all(math.isfinite(v) and v >= 0.0 for v in got.values()), got
+    assert got["entry.kernel_import_s"] == 0.0
+    stages = [got[f"entry.step_{s}_s"] for s in ("trace", "lower")]
+    assert stages[0] > 0.0 and stages[1] > 0.0
+    assert sum(stages) + got["entry.step_compile_s"] + \
+        got["entry.small_programs_s"] < got["entry.time_to_first_step_s"]
+    assert got["entry.small_programs_s"] > 0.0
+    # the run says the ledger on one line of the program's log
+    assert sum(" start-up: " in ln for ln in res.stderr.splitlines()) == 1
 
 
 def test_run_without_tpu_prints_no_result(tmp_path):
@@ -576,14 +627,11 @@ def lm_toy():
         state, losses = trainer.run(state0, iter(batches))
     spans = {k: n - before.get(k, 0) for k, n in span_counts().items()}
     phase_map = obs.costs.phase_map("trainer_step")
-    obs.costs.alias("lm_toy_step", "trainer_step")
-    aliased = obs.costs.phase_map("lm_toy_step")
     obs.set_enabled(was_on)
     _LM["toy"] = SimpleNamespace(cfg=cfg, trainer=trainer, state=state,
                                  losses=losses, spans=spans,
                                  parents=parents, bias0=bias0,
-                                 batches=batches, phase_map=phase_map,
-                                 aliased=aliased)
+                                 batches=batches, phase_map=phase_map)
     return _LM["toy"]
 
 
@@ -639,12 +687,14 @@ def lm_counters(toy):
 @surface
 def lm_phase_map(toy):
     """``obs.costs.phase_map("trainer_step")``: the compiled step's
-    instructions under the scopes the model enters; ``obs.costs.alias``
-    gives the same map a second name (the family's: ``w2v_step``)."""
+    instructions under the scopes the model enters, under the program's
+    own name (every LM family's metric files say ``trainer_step``; a name
+    no program of the process was tracked under has no map)."""
+    from swiftmpi_tpu import obs
     from swiftmpi_tpu.obs.catalog import DEVICE_SCOPES
 
     pm = lm_toy().phase_map
-    assert lm_toy().aliased is pm
+    assert obs.costs.phase_map("w2v_step_of_no_model") is None
     assert pm["module"] == "jit_train_step"
     want = {"embed", "conv", "attention", "route", "experts", "dense_ffn",
             "head", "optimizer"}

@@ -214,25 +214,6 @@ def test_tracked_call_under_a_trace_records_nothing():
     assert inner._sig is not None
 
 
-def test_alias_answers_only_while_the_name_has_no_program_of_its_own():
-    obs.set_enabled(True)
-    one = obs_costs.track("one_fn", jax.jit(lambda x: x * 2.0))
-    one(jnp.ones((4,), jnp.float32))
-    cat = obs_costs.get_catalog()
-    assert cat.handle("step_fn") is None
-    obs_costs.alias("step_fn", "one_fn")
-    assert cat.handle("step_fn") is one
-    assert cat.handle("one_fn") is one
-    own = obs_costs.track("step_fn", jax.jit(lambda x: x + 1.0))
-    own(jnp.ones((4,), jnp.float32))
-    assert cat.handle("step_fn") is own       # its own program wins
-    del own
-    assert cat.handle("step_fn") is one
-    for name, target in (("one_fn", "one_fn"), ("other", "step_fn")):
-        with pytest.raises(ValueError):
-            obs_costs.alias(name, target)     # no chains, so no cycles
-
-
 # -- host spans of the whole call ---------------------------------------------
 
 @pytest.mark.parametrize("worker", [{}, {"pipeline": 2}],

@@ -71,6 +71,28 @@ def test_spans_declared_and_read_from_the_catalog():
     assert trace_host.program_spans() == tuple(catalog.HOST_SPANS)
 
 
+def test_setup_spans_are_a_list_of_their_own_and_cover_nothing():
+    """``SETUP_SPANS`` (ISSUE 52) shares no name with ``HOST_SPANS``, the
+    reader's list holds none of them, and a set-up span on the train
+    loop's thread — a ``step_build`` in a bare stretch, a ``first_step``
+    inside ``dispatch`` — moves no reading: the coverage guard and the
+    idle partition read what they read."""
+    assert not set(catalog.HOST_SPANS) & set(catalog.SETUP_SPANS)
+    assert not set(trace_host.program_spans()) & set(catalog.SETUP_SPANS)
+    assert len(set(catalog.SETUP_SPANS)) == len(catalog.SETUP_SPANS)
+    with_setup = dict(HOST, python3=HOST["python3"] + [
+        (120, 122, "step_build"), (87, 98, "first_step"),
+        (88, 90, "kernel_import"), (900, 910, "model_build")])
+    reports = [("unspanned_ms_per_step", {}), ("fixed_ms_per_call", {}),
+               ("idle_ms_per_step", {"span": LOOP}),
+               ("idle_ms_per_step", {"span": CALL}),
+               ("idle_ms_per_step", {"span": None})]
+    for report, params in reports:
+        assert host(report, ctx(host=with_setup), **params) ==             host(report, ctx(), **params), report
+    assert host("unspanned_ms_per_step", ctx(host=with_setup)) \
+        == pytest.approx((2 + 10 + 10) / PER)
+
+
 def test_unspanned_is_the_call_less_the_spans():
     # bench/train_call is [10,960]; bare: [120,122] after step 0's book,
     # [900,910] before train_finish, [950,960] after it
