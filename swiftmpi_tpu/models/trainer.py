@@ -80,15 +80,18 @@ def make_optimizer(name: str = "adamw", learning_rate: float = 3e-4,
 def _expert_counters(stats) -> dict:
     """The expert layers' counters of a run, from the ``MoEStats`` its
     steps returned (summed over a step's expert layers): the share of the
-    router's picks that landed on held experts, the held experts' largest
-    group over their mean (mean over layers and steps) and the held picks
-    a step left uncomputed (0, always: nothing is dropped)."""
+    router's picks that landed on held experts, the share of the rows the
+    expert loops walked that held such a pick (the rest are the last
+    chunks' dead rows), the held experts' largest group over their mean
+    (mean over layers and steps) and the held picks a step left uncomputed
+    (0, always: nothing is dropped)."""
     if not stats:
         return {}
     total = {f: sum(float(getattr(s, f)) for s in stats)
              for f in stats[0]._fields}
     return {
         "held_pick_share": 100.0 * total["held"] / max(total["picks"], 1.0),
+        "walk_fill_share": 100.0 * total["held"] / max(total["walked"], 1.0),
         "expert_load_max_over_mean":
             total["load_max_over_mean"] / max(total["layers"], 1.0),
         "dropped_picks_per_step": total["dropped"] / len(stats)}

@@ -24,7 +24,10 @@ products over the held experts' ragged groups (``jax.lax.ragged_dot``;
 on a TPU the compiler's own grouped-matmul kernel).  There is no
 ``(T, E, C)`` dispatch tensor and no capacity: the static bound is the
 ``T * k`` picks themselves, walked in row chunks up to the last one that
-holds a held pick, so the cost follows the picks that land
+holds a held pick — whole chunks of ``ROW_CHUNK`` rows, then the last one
+at the smallest rung of a short ladder of row counts (``_rungs``: a
+half, the whole) that holds what is left — so the cost follows
+the picks that land
 here (``T * k * held / E`` in the mean) and **no pick on a held expert is
 ever dropped**, whatever the routing.
 
@@ -58,7 +61,8 @@ ROUTERS = ("softmax", "sigmoid_bias")
 ACTIVATIONS = {"relu": jax.nn.relu,
                "relu2": lambda v: jnp.square(jax.nn.relu(v))}
 #: rows of sorted picks one step of the expert loop computes; the loop
-#: ends at the last held pick, so the cost follows the picks
+#: ends at the last held pick and its last step is cut to the smallest
+#: rung (``_rungs``) that holds what is left, so the cost follows the picks
 ROW_CHUNK = 8192
 
 
@@ -82,6 +86,7 @@ class MoEStats(NamedTuple):
     picks: jax.Array        # T * k
     held: jax.Array         # picks that landed on a held expert
     dropped: jax.Array      # held picks no grouped product covered: 0
+    walked: jax.Array       # sorted rows the expert loop computed
     load_max_over_mean: jax.Array   # over the held experts' group sizes
     layers: jax.Array       # expert layers summed into this record
 
@@ -175,19 +180,50 @@ def _chunk_inputs(lo, rows, src, weight, src_idx, order, starts, ends, dtype):
     return idx, live, gs, xs, jnp.where(live, weight[idx], 0.0)
 
 
+def _rungs(rows: int) -> Tuple[int, ...]:
+    """The row counts the walk's last chunk may take, in rising order up to
+    ``rows``: a half and the whole of it (one rung where it cannot be
+    halved).  Made from ``rows`` alone: one ladder for every layer that
+    walks.  A rung below the top is one more copy of the chunk's forward,
+    recomputation and backward for every expert layer's body to trace,
+    lower and read back from the compile cache — the half alone is 3-4 s
+    of the ~57 s of warm set-up in the cell with four such bodies, a
+    quarter below it about one more, against a bound of a tenth — which is
+    what left the quarters and the eighths out, and not the device, where
+    a finer ladder is faster (``scripts/expert_walk_micro.py``; PERF.md
+    section 6, PR 53)."""
+    return (rows // 2, rows) if rows % 2 == 0 else (rows,)
+
+
 def _walk(body, carry, n_rows, rows: int):
-    """``carry = body(lo, carry)`` over the chunks of ``rows`` sorted rows
-    that start below ``n_rows``: the trip count follows the routing."""
-    return lax.fori_loop(0, (n_rows + rows - 1) // rows,
-                         lambda c, acc: body(c * rows, acc), carry)
+    """``carry = body(lo, size, carry)`` over the sorted rows below
+    ``n_rows``: chunks of ``rows`` (a trip count that follows the routing),
+    then the rows that are left as one chunk of the smallest rung that
+    holds them.  A rest that needs the top rung is one more turn of the
+    loop, so the loop's body is the only copy of a whole chunk."""
+    low = _rungs(rows)[:-1]          # the top rung is the loop's own body
+    full = n_rows // rows
+    left = n_rows - full * rows
+    whole = left > (low[-1] if low else 0)
+    carry = lax.fori_loop(0, full + whole,
+                          lambda c, acc: body(c * rows, rows, acc), carry)
+    if not low:
+        return carry
+    # branch 0 leaves the carry as it is; branch i walks low[i - 1] rows
+    fits = jnp.asarray((0,) + low[:-1], jnp.int32)
+    return lax.switch(
+        jnp.where(whole, 0, (left > fits).sum()),
+        [lambda acc: acc] + [partial(body, full * rows, r) for r in low],
+        carry)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11, 12))
 def _grouped(w, src, weight, src_idx, out_idx, order, starts, ends,
              n_out, compute_dtype, rows, scope_prefix, act):
     """The walk over the sorted rows that hold a held pick: ``(out (n_out,
-    d) f32, rows covered)``.  Its trip count follows the routing (a
-    ``fori_loop`` to the last live chunk), which autodiff cannot transpose;
+    d) f32, rows covered, rows walked)``.  Its trip count and its last
+    chunk's size follow the routing (:func:`_walk`), which autodiff cannot
+    transpose;
     the backward pass below walks the same chunks, recomputing each, so
     neither time nor memory follows the static ``T * k`` bound.
     ``scope_prefix``: the ``obs.scope_prefix`` the layer was traced under,
@@ -196,18 +232,19 @@ def _grouped(w, src, weight, src_idx, out_idx, order, starts, ends,
     with obs.named_scope("experts"):
         wc = jax.tree.map(lambda a: a.astype(dtype), w)
 
-    def body(lo, acc):
-        out, covered = acc
+    def body(lo, size, acc):
+        out, covered, walked = acc
         with obs.named_scope("route"):
             idx, live, gs, xs, wrow = _chunk_inputs(
-                lo, rows, src, weight, src_idx, order, starts, ends, dtype)
+                lo, size, src, weight, src_idx, order, starts, ends, dtype)
         y = _chunk_ffn(wc, xs, wrow, live, gs, act)
         with obs.named_scope("route"):
-            return out.at[out_idx[idx]].add(y), covered + gs.sum()
+            return (out.at[out_idx[idx]].add(y), covered + gs.sum(),
+                    walked + size)
 
     with obs.named_scope("route"):
         out = jnp.zeros((n_out, src.shape[-1]), jnp.float32)
-    return _walk(body, (out, jnp.int32(0)), ends[-1], rows)
+    return _walk(body, (out, jnp.int32(0), jnp.int32(0)), ends[-1], rows)
 
 
 def _grouped_fwd(w, src, weight, src_idx, out_idx, order, starts, ends,
@@ -233,11 +270,11 @@ def _grouped_bwd_walk(compute_dtype, rows, act, res, cot):
         dsrc = jnp.zeros(src.shape, jnp.float32)
         dweight = jnp.zeros(weight.shape, jnp.float32)
 
-    def body(lo, carry):
+    def body(lo, size, carry):
         dw, dsrc, dweight = carry
         with obs.named_scope("route"):
             idx, live, gs, xs, wrow = _chunk_inputs(
-                lo, rows, src, weight, src_idx, order, starts, ends, dtype)
+                lo, size, src, weight, src_idx, order, starts, ends, dtype)
             dy = dout[out_idx[idx]]
         _, pull = jax.vjp(lambda wc, xs, wrow: _chunk_ffn(
             wc, xs, wrow, live, gs, act), wc, xs, wrow)
@@ -266,10 +303,11 @@ def _held_experts(p: MoEParams, src, src_idx, out_idx, local_e, weight,
     """``out[out_idx[i]] += weight[i] * expert_{local_e[i]}(src[src_idx[i]])``
     over the picks ``i`` whose ``local_e`` names a held expert
     (``local_e == held`` marks a pick that is not this device's), as
-    ``(out (n_out, d) f32, group sizes (held,), rows covered)``.
+    ``(out (n_out, d) f32, group sizes (held,), rows covered, rows walked)``.
 
     Picks are sorted by expert, held ones first; the sorted rows are
-    walked in chunks of ``row_chunk``: a chunk gathers its rows, runs the
+    walked in chunks of ``row_chunk``, the last one at the smallest rung
+    that holds what is left: a chunk gathers its rows, runs the
     grouped products over the parts of the experts' groups that fall in
     it, weights and scatters the result back (:func:`_grouped`)."""
     n_picks = local_e.shape[0]
@@ -283,18 +321,19 @@ def _held_experts(p: MoEParams, src, src_idx, out_idx, local_e, weight,
         sizes = (local_e[:, None] == jnp.arange(n_held, dtype=jnp.int32)
                  ).sum(0, dtype=jnp.int32)
         ends = jnp.cumsum(sizes)
-    out, covered = _grouped((p.w_in, p.w_out, p.w_gate), src, weight,
-                            src_idx, out_idx, order, ends - sizes, ends,
-                            n_out, compute_dtype, rows,
-                            obs.current_scope_prefix(), act)
-    return out, sizes, covered
+    out, covered, walked = _grouped(
+        (p.w_in, p.w_out, p.w_gate), src, weight, src_idx, out_idx, order,
+        ends - sizes, ends, n_out, compute_dtype, rows,
+        obs.current_scope_prefix(), act)
+    return out, sizes, covered, walked
 
 
-def _stats(n_picks: int, sizes, covered) -> MoEStats:
+def _stats(n_picks: int, sizes, covered, walked) -> MoEStats:
     held = sizes.sum().astype(jnp.float32)
     mean = jnp.maximum(held / sizes.shape[0], 1e-9)
     return MoEStats(picks=jnp.float32(n_picks), held=held,
                     dropped=held - covered.astype(jnp.float32),
+                    walked=walked.astype(jnp.float32),
                     load_max_over_mean=sizes.max().astype(jnp.float32)
                     / mean, layers=jnp.float32(1.0))
 
@@ -339,10 +378,10 @@ def expert_layer(params: MoEParams, x: jax.Array, *, k: int = 2,
         e = sel.reshape(-1)
         local_e = jnp.where((e >= lo) & (e < hi), e - lo, hi - lo)
         token = jnp.arange(T * k, dtype=jnp.int32) // k
-    y, sizes, covered = _held_experts(
+    y, *counts = _held_experts(
         params, x, token, token, local_e, gates.reshape(-1), T,
         compute_dtype, row_chunk, act)
-    return y, aux, _stats(T * k, sizes, covered)
+    return y, aux, _stats(T * k, *counts)
 
 
 def moe_ffn(params: MoEParams, x: jax.Array, mesh: Mesh, *,
@@ -397,7 +436,7 @@ def moe_ffn(params: MoEParams, x: jax.Array, mesh: Mesh, *,
         recv_x = all_to_all(send_x, axis, split_axis=0, concat_axis=0)
         recv_e = all_to_all(send_e, axis, split_axis=0, concat_axis=0)
         rows = jnp.arange(n * n_picks, dtype=jnp.int32)
-        y, _sizes, _covered = _held_experts(
+        y, *_counts = _held_experts(
             p, recv_x.reshape(n * n_picks, d), rows, rows,
             recv_e.reshape(-1), jnp.ones((n * n_picks,), jnp.float32),
             n * n_picks, compute_dtype, row_chunk, act)

@@ -15,6 +15,12 @@ from tests.test_compile_v5e import (  # noqa: F401  (fixtures)
     GIB, REPO, _cell, _instructions, no_compile_cache, topo)
 
 
+#: copies of a chunk's grouped products an expert layer's body holds since
+#: PR 53: the loop's body (a whole chunk, the ladder's top rung) and the
+#: last chunk at the one rung below it, a half (``parallel/moe.py::_walk``)
+WALK_BODIES = 1 + 1
+
+
 def _scope(op_name):
     """The innermost ``obs.named_scope`` of the catalog in an ``op_name``
     path, by its own name (``obs.costs.phase_of`` gives its phase)."""
@@ -107,10 +113,10 @@ def test_lfm2_ep4_trainer_step_fits_one_chip(topo, no_compile_cache):
     assert mem.alias_size_in_bytes >= 0.99 * mem.output_size_in_bytes
 
     text = compiled.as_text()
-    # pinned anew by PR 50: the attention forward walk is one Pallas kernel
-    # a call (`parallel/attention_kernel.py`), its `while` loops, stacked
-    # outputs and transposes gone
-    assert _instructions(text) == (8630, "e15f9cef4973019f")
+    # pinned anew by PR 53: the expert walk's last chunk is a `switch` to
+    # the ladder's lower rung (`parallel/moe.py::_walk`), one more copy of
+    # the chunk's body, forward, recomputed and backward
+    assert _instructions(text) == (10083, "09003f0ace4ec410")
     assert "ragged-dot" in text               # the compiler's grouped matmul
     # the forward walk is the kernel: the scanned attention layer's body,
     # forward and recomputed
@@ -165,17 +171,17 @@ def test_sdar_ep8_trainer_step_fits_one_chip(topo, no_compile_cache):
     assert mem.alias_size_in_bytes >= 0.99 * mem.output_size_in_bytes
 
     text = compiled.as_text()
-    # pinned anew by PR 50: the attention forward walk is one Pallas kernel
-    # a call (`parallel/attention_kernel.py`), its `while` loops, stacked
-    # outputs and transposes gone
-    assert _instructions(text) == (6184, "057642588b1b97a0")
+    # pinned anew by PR 53: the expert walk's last chunk is a `switch` to
+    # the ladder's lower rung (`parallel/moe.py::_walk`), one more copy of
+    # the chunk's body, forward, recomputed and backward
+    assert _instructions(text) == (6902, "4a00fd4b7fa60a31")
     # every kernel the compiler brings is a ragged-dot one, under the names
     # the catalog books as `experts` and `^ragged-dot` matches: 3 products
-    # forward and 9 backward in the one scanned layer body
+    # forward and 9 backward a chunk body in the one scanned layer body
     kernels = _kernels(text, {"attention": 2})
     assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
     assert set(kernels) <= set(DEVICE_SCOPES)
-    assert kernels.count("ragged-dot-none") == 12
+    assert kernels.count("ragged-dot-none") == WALK_BODIES * 12
     _backward_keeps_the_query_side_still(compiled, cfg, seqs, 2 * S, 12.010)
     # (2S, 2S) or (S, S) scores of a head would be an array with two dims
     # of at least S; the largest things here have one (positions x a width)
@@ -233,15 +239,15 @@ def test_trinity_ep16_trainer_step_fits_one_chip(topo, no_compile_cache):
     assert mem.alias_size_in_bytes >= 0.99 * mem.output_size_in_bytes
 
     text = compiled.as_text()
-    # pinned anew by PR 50: the attention forward walk is one Pallas kernel
-    # a call (`parallel/attention_kernel.py`), its `while` loops, stacked
-    # outputs and transposes gone
-    assert _instructions(text) == (19672, "2271e79631e56f0f")
+    # pinned anew by PR 53: the expert walk's last chunk is a `switch` to
+    # the ladder's lower rung (`parallel/moe.py::_walk`), one more copy of
+    # the chunk's body, forward, recomputed and backward
+    assert _instructions(text) == (22763, "eb3b026201f18308")
     # three sliding bodies and the full one, forward and recomputed
     kernels = _kernels(text, {"window_attention": 6, "attention": 2})
     assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
     assert set(kernels) <= set(DEVICE_SCOPES)
-    assert kernels.count("ragged-dot-none") == 3 * 15
+    assert kernels.count("ragged-dot-none") == 3 * WALK_BODIES * 15
     _backward_keeps_the_query_side_still(compiled, cfg, seqs, S, 10.663)
     for dims in set(re.findall(r"= \w+\[([\d,]+)\]", text)):
         big = [int(d) for d in dims.split(",") if int(d) >= S]
@@ -311,14 +317,14 @@ def test_glm47f_ep8_trainer_step_fits_one_chip(topo, no_compile_cache):
     assert mem.argument_size_in_bytes <= 7.9 * GIB       # 12 B a parameter
 
     text = compiled.as_text()
-    # pinned anew by PR 50: the attention forward walk is one Pallas kernel
-    # a call (`parallel/attention_kernel.py`), its `while` loops, stacked
-    # outputs and transposes gone
-    assert _instructions(text) == (14111, "6f6e8f4f7e31a3c1")
+    # pinned anew by PR 53: the expert walk's last chunk is a `switch` to
+    # the ladder's lower rung (`parallel/moe.py::_walk`), one more copy of
+    # the chunk's body, forward, recomputed and backward
+    assert _instructions(text) == (15736, "67018f26670975fa")
     kernels = _kernels(
         text, {"latent_attention": 4, "mtp_latent_attention": 2})
     assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
-    assert kernels.count("ragged-dot-none") == 2 * 12
+    assert kernels.count("ragged-dot-none") == 2 * WALK_BODIES * 12
     assert " dynamic-update-slice(" in text
     assert not re.findall(
         rf"= f32\[{seqs},{S},20,1,256\]\S* dynamic-update-slice\(", text)
@@ -396,13 +402,13 @@ def test_nemotron3n_ep16_trainer_step_fits_one_chip(topo, no_compile_cache):
     assert mem.argument_size_in_bytes <= 7.5 * GIB       # 12 B a parameter
 
     text = compiled.as_text()
-    # pinned anew by PR 50: the attention forward walk is one Pallas kernel
-    # a call (`parallel/attention_kernel.py`), its `while` loops, stacked
-    # outputs and transposes gone
-    assert _instructions(text) == (20577, "39ea52286dee765b")
+    # pinned anew by PR 53: the expert walk's last chunk is a `switch` to
+    # the ladder's lower rung (`parallel/moe.py::_walk`), one more copy of
+    # the chunk's body, forward, recomputed and backward
+    assert _instructions(text) == (23257, "21b8d941c3f98a64")
     kernels = _kernels(text, {"attention": 2})    # the one `full` layer
     assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
-    assert kernels.count("ragged-dot-none") == 4 * 8
+    assert kernels.count("ragged-dot-none") == 4 * WALK_BODIES * 8
     # the scan: 64 chunk states of (8 groups x 8 heads, 64, 128) a sequence
     # are there, a state a position is not, and neither is a (S, S) form
     assert re.search(r"= f32\[64,1,8,8,64,128\]", text)

@@ -483,6 +483,9 @@ def test_keye2_ep8_trainer_step_fits_one_chip(topo):
                for n in walks)
     kernels = [n for n in kernels if n not in walks]
     assert set(kernels) == {"ragged-dot-none", "ragged-dot-metadata"}
+    # 3 products forward and 9 backward a chunk body; the one scanned layer
+    # body holds the loop's (a whole chunk) and the half rung's (PR 53)
+    assert kernels.count("ragged-dot-none") == (1 + 1) * 12
     for dtype, dims in set(re.findall(r"= (\w+)\[([\d,]+)\]", text)):
         big = [int(d) for d in dims.split(",") if int(d) >= S_]
         assert len(big) < 2 or dtype == "u32", f"{dtype}[{dims}]"
