@@ -62,7 +62,8 @@ def load_benchmark() -> dict:
 
 
 def metric_cells(metric: dict, bench: dict) -> list:
-    """Cells a metric is reported in: its ``workloads`` list, or all."""
+    """Cells a metric is reported in: its ``workloads`` list in
+    ``BENCHMARK.json`` — the one place the list lives — or all."""
     return list(metric.get("workloads")
                 or [w["name"] for w in bench["workloads"]])
 
@@ -269,9 +270,12 @@ def check() -> list:
         if m["moves"] not in e2e:
             bad.append(f"{where}: moves {m['moves']!r}, which is no "
                        "end_to_end metric")
-        for c in m.get("workloads", []):
+        listed = m.get("workloads", [])
+        for c in listed:
             if c not in cells:
                 bad.append(f"{where}: cell {c!r} is no workload")
+        for c in sorted({c for c in listed if listed.count(c) > 1}):
+            bad.append(f"{where}: lists cell {c!r} twice")
         try:
             f = load_json(bench_path("layer_metrics", m["name"] + ".json"))
         except SpecError as e:
@@ -281,24 +285,23 @@ def check() -> list:
             if f.get(key) != m[key]:
                 bad.append(f"{where}: {key} is {m[key]!r} in BENCHMARK.json "
                            f"and {f.get(key)!r} in its file")
-        if sorted(f.get("cells", [])) != sorted(m.get("workloads", [])):
-            bad.append(f"{where}: cells differ between BENCHMARK.json "
-                       "('workloads') and its file ('cells')")
+        if "cells" in f:
+            bad.append(f"{where}: layer_metrics/{m['name']}.json carries "
+                       "'cells'; the list lives in BENCHMARK.json, as the "
+                       "entry's 'workloads'")
         kind = f.get("reader", {}).get("kind")
         if not kind or not os.path.exists(bench_path("readers",
                                                      f"{kind}.py")):
             bad.append(f"{where}: reader kind {kind!r} has no "
                        f"benchmark/readers/{kind}.py")
-        # one entry a reader: a second entry may read the same thing only
-        # in other cells (and then it should join the first one's list)
-        same = readers.setdefault(json.dumps(f.get("reader"), sort_keys=True),
-                                  {})
-        for c in metric_cells(m, bench):
-            if c in same:
-                bad.append(f"{where}: reads cell {c!r} with the reader "
-                           f"{same[c]!r} already reads it with; add the "
-                           "cell to that entry's list instead")
-            same.setdefault(c, m["name"])
+        # one entry a reader: a cell joins the entry that has the reader,
+        # by its name in that entry's 'workloads'; no second entry
+        block = json.dumps(f.get("reader"), sort_keys=True)
+        if block in readers:
+            bad.append(f"{where}: shares its reader with {readers[block]!r}; "
+                       f"add {listed or 'its cells'} to that entry's "
+                       "'workloads' in BENCHMARK.json instead")
+        readers.setdefault(block, m["name"])
     for fn in sorted(os.listdir(bench_path("layer_metrics"))):
         if fn.endswith(".json") and fn[:-5] not in declared:
             bad.append(f"layer_metrics/{fn} is not declared under per_layer "

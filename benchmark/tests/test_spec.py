@@ -1,9 +1,11 @@
 """BENCHMARK.json against the driver's contract, and the start-up check."""
 
 import copy
+import hashlib
 import json
 import os
 import re
+import shutil
 
 import pytest
 
@@ -25,8 +27,121 @@ def is_width(key: str) -> bool:
     return key != "num_hidden_layers" and bool(WIDTH.search(key))
 
 
-def test_benchmark_resolves():
+ADDED = "added-cell"
+JOINED = ("lm.head_ms_per_step", "lm.optimizer_ms_per_step")
+
+
+def _digests(root):
+    out = {}
+    for base, _dirs, fnames in os.walk(os.path.join(root, "benchmark")):
+        for fn in fnames:
+            path = os.path.join(base, fn)
+            with open(path, "rb") as f:
+                out[os.path.relpath(path, root)] = hashlib.sha256(
+                    f.read()).hexdigest()
+    return out
+
+
+def _write(path, obj):
+    with open(path, "w") as f:
+        json.dump(obj, f, indent=2)
+
+
+def _add_a_cell(root):
+    """A thirteenth cell the way a ``model_config`` PR brings one: new files
+    under ``benchmark/`` (a configuration of an accepted family, a traffic
+    mix, a band, one per-layer metric with a reader block no file has),
+    entries appended to ``BENCHMARK.json``, and the cell's name appended to
+    the ``workloads`` of two accepted listed entries.  No file that was
+    there is opened for writing."""
+    bench = spec.load_json(os.path.join(root, "BENCHMARK.json"))
+    like = next(w for w in bench["workloads"]
+                if w["name"] == "trinity-ep16-16k-t16k")
+    like_cfg = next(c for c in bench["configs"] if c["name"] == like["config"])
+    sub = os.path.join(root, "benchmark")
+    config = spec.load_json(os.path.join(root, like_cfg["file"]))
+    _write(os.path.join(sub, "configs", "added-config.json"),
+           dict(config, name="added-config"))
+    traffic = spec.load_json(os.path.join(sub, "traffic",
+                                          like["traffic"] + ".json"))
+    _write(os.path.join(sub, "traffic", "added-mix.json"),
+           dict(traffic, name="added-mix"))
+    _write(os.path.join(sub, "bands", ADDED + ".json"),
+           {"train_loss_fixed": [8.0, 9.0], "measured": "a test's"})
+    entry = {"name": "added.counter_per_step", "unit": "rows",
+             "better": "lower", "source": "program_counter",
+             "layer": "experts", "moves": "words_per_s"}
+    _write(os.path.join(sub, "layer_metrics", entry["name"] + ".json"),
+           dict(entry, what="a counter only the added cell's program has",
+                reader={"kind": "train_metrics", "key": "added_counter"}))
+    bench["configs"].append(dict(like_cfg, name="added-config",
+                                 file="benchmark/configs/added-config.json"))
+    bench["workloads"].append(dict(like, name=ADDED, config="added-config",
+                                   traffic="added-mix"))
+    bench["per_layer"].append(dict(entry, workloads=[ADDED]))
+    for m in bench["per_layer"]:
+        if m["name"] in JOINED:
+            m["workloads"].append(ADDED)
+    _write(os.path.join(root, "BENCHMARK.json"), bench)
+    return entry["name"]
+
+
+TREES = pytest.mark.parametrize("tree", ["committed", "a-cell-added"])
+
+
+def _tree(which, monkeypatch, tmp_path):
+    """``None`` for the benchmark as committed; for ``a-cell-added``, points
+    ``spec`` at a temporary copy of it to which a cell has been added by
+    new files and ``BENCHMARK.json`` entries alone, and returns what the
+    addition has to have left as it was.  (No fixture: tier-1 collects
+    these cases by importing the tests' names alone.)"""
+    if which == "committed":
+        return None
+    root = str(tmp_path)
+    shutil.copy(os.path.join(spec.ROOT, "BENCHMARK.json"), root)
+    shutil.copytree(spec.BENCH_DIR, os.path.join(root, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {"digests": _digests(root), "per_layer": {
+        w["name"]: [m["name"] for m, _f in spec.load_cell(w["name"]).per_layer]
+        for w in spec.load_benchmark()["workloads"]}}
+    before["added_metric"] = _add_a_cell(root)
+    monkeypatch.setattr(spec, "ROOT", root)
+    monkeypatch.setattr(spec, "BENCH_DIR", os.path.join(root, "benchmark"))
+    return before
+
+
+@TREES
+def test_benchmark_resolves(tree, monkeypatch, tmp_path):
+    """The committed benchmark resolves, and so does one a cell was added
+    to: an addition opens no accepted file under ``benchmark/``; a cell
+    joins a metric by its name in that entry's ``workloads``."""
+    before = _tree(tree, monkeypatch, tmp_path)
     assert spec.check() == []
+    if before is None:
+        return
+    bench = spec.load_benchmark()
+    assert len(bench["workloads"]) == len(before["per_layer"]) + 1
+    listless = {m["name"] for m in bench["per_layer"] if "workloads" not in m}
+    assert {m["name"] for m, _f in spec.load_cell(ADDED).per_layer} == \
+        listless | set(JOINED) | {before["added_metric"]}
+    for name, had in before["per_layer"].items():
+        assert [m["name"] for m, _f in spec.load_cell(name).per_layer] == had
+    now = _digests(spec.ROOT)
+    assert {k: now[k] for k in before["digests"]} == before["digests"]
+    assert len(now) == len(before["digests"]) + 4   # config, mix, band, metric
+    # the other route is shut, by name: a second entry of an accepted reader
+    # block for the new cell alone is sent to the first entry's list
+    twin = next(m for m in bench["per_layer"]
+                if m["name"] == "lm.embed_ms_per_step")
+    assert ADDED not in twin["workloads"]
+    shutil.copy(spec.bench_path("layer_metrics", twin["name"] + ".json"),
+                spec.bench_path("layer_metrics", "twin.metric.json"))
+    bench["per_layer"].append(dict(twin, name="twin.metric",
+                                   workloads=[ADDED]))
+    _write(os.path.join(spec.ROOT, "BENCHMARK.json"), bench)
+    assert [p for p in spec.check() if "per_layer 'twin.metric': shares its "
+            "reader with 'lm.embed_ms_per_step'" in p
+            and f"add {[ADDED]} to that entry's 'workloads'" in p]
 
 
 def test_contract_limits():
@@ -96,29 +211,46 @@ def test_a_name_as_the_contract_had_it(name, ok):
     assert bool(spec.NAME.match(name)) == ok == bool(NAME.match(name))
 
 
-def test_one_entry_a_reader_and_lists_that_agree():
-    """No two ``per_layer`` entries whose files share a ``reader`` block
-    list the same cell (since PR 51 none share one at all), and every
-    file's ``cells`` equals its entry's ``workloads``."""
+@TREES
+def test_one_entry_a_reader_and_lists_that_agree(tree, monkeypatch, tmp_path):
+    """No two ``per_layer`` entries' files share a ``reader`` block, in the
+    committed benchmark or after an addition, and an entry's ``workloads``
+    is the one list of its cells: no file carries ``cells``.  The only cap
+    on the count is the contract's (``test_contract_limits``)."""
+    _tree(tree, monkeypatch, tmp_path)
     b = spec.load_benchmark()
-    all_cells = [w["name"] for w in b["workloads"]]
     seen = {}
     for m in b["per_layer"]:
         f = spec.load_json(spec.bench_path("layer_metrics",
                                            m["name"] + ".json"))
-        assert f["cells"] == m.get("workloads", []), m["name"]
+        assert "cells" not in f, m["name"]
         assert f["name"] == m["name"]
+        assert len(set(m.get("workloads", []))) == len(m.get("workloads", []))
         key = json.dumps(f["reader"], sort_keys=True)
-        for c in m.get("workloads") or all_cells:
-            assert (key, c) not in seen, (m["name"], seen[key, c], c)
-            seen[key, c] = m["name"]
-    assert len({k for k, _c in seen}) == len(b["per_layer"]) <= 80
+        assert key not in seen, (m["name"], seen[key])
+        seen[key] = m["name"]
+    assert len(seen) == len(b["per_layer"])
 
 
-def _with(monkeypatch, change):
+def _with(monkeypatch, change, files=None):
+    """``spec.check()`` on a changed copy of ``BENCHMARK.json``; ``files``
+    gives ``layer_metrics`` files by entry name, each a function of the
+    committed file of the name it stands in for."""
     bench = copy.deepcopy(spec.load_benchmark())
     change(bench)
     monkeypatch.setattr(spec, "load_benchmark", lambda: bench)
+    real = spec.load_json
+
+    def load_json(path):
+        name = os.path.basename(path)[:-len(".json")]
+        if os.path.basename(os.path.dirname(path)) == "layer_metrics" \
+                and name in (files or {}):
+            stands_for, made = files[name]
+            return made(real(spec.bench_path("layer_metrics",
+                                             stands_for + ".json")))
+        return real(path)
+
+    monkeypatch.setattr(spec, "load_json", load_json)
     return spec.check()
 
 
@@ -138,7 +270,11 @@ def _entry(name):
             "source": "device_trace", "layer": "step", "moves": "words_per_s"}
 
 
-@pytest.mark.parametrize("change, said", [
+def _listed(b, name="lm.head_ms_per_step"):
+    return next(m for m in b["per_layer"] if m["name"] == name)
+
+
+BENCH_CASES = [
     (_four_chip_cells_over_quota, "cells ask for 4 chips"),
     (lambda b: b["per_layer"].extend(
         _entry(f"filler.{i}") for i in range(129 - len(b["per_layer"]))),
@@ -180,9 +316,25 @@ def _entry(name):
      "source 'program_counter'"),
     (lambda b: b["workloads"][2].update(chips=1),
      "asks for 1 chips"),
-])
-def test_check_fails_fast_by_name(monkeypatch, change, said):
-    problems = _with(monkeypatch, change)
+    (lambda b: _listed(b)["workloads"].append(_listed(b)["workloads"][0]),
+     "lists cell 'lfm2-ep4-8k-t32k' twice"),
+]
+# ... and a metric's file that disagrees with its entry
+FILE_CASES = [
+    (lambda b: None, "layer_metrics/lm.head_ms_per_step.json carries 'cells'; "
+     "the list lives in BENCHMARK.json",
+     {"lm.head_ms_per_step": ("lm.head_ms_per_step",
+                              lambda f: dict(f, cells=[]))}),
+    (lambda b: None, "unit is 'ms' in BENCHMARK.json and 's' in its file",
+     {"lm.head_ms_per_step": ("lm.head_ms_per_step",
+                              lambda f: dict(f, unit="s"))}),
+]
+
+
+@pytest.mark.parametrize("change, said, files", [
+    (change, said, None) for change, said in BENCH_CASES] + FILE_CASES)
+def test_check_fails_fast_by_name(monkeypatch, change, said, files):
+    problems = _with(monkeypatch, change, files)
     assert any(said in p for p in problems), problems
 
 
@@ -200,22 +352,20 @@ def test_rehearsal_overlays_toy_sizes_only_when_asked():
     assert toy.config["word2vec"]["window"] == 5      # the rest is kept
 
 
+@pytest.mark.parametrize("cells", [
+    ["lfm2-ep4-8k-t32k"],            # a cell the first entry reads
+    ["cbow2m-demo"]])                # a cell it does not: no second route
 def test_a_second_entry_of_one_reader_is_sent_to_the_first(monkeypatch,
-                                                           tmp_path):
-    """The rule of PR 51's fold at start-up: an entry whose file repeats
-    another's ``reader`` block in a cell that one already reads is refused,
-    and told where the cell belongs."""
-    first = spec.load_benchmark()["per_layer"][0]
-    real = spec.load_json
-
-    def load_json(path):
-        if os.path.basename(path) == "twin.metric.json":
-            path = spec.bench_path("layer_metrics", first["name"] + ".json")
-        return real(path)
-
-    monkeypatch.setattr(spec, "load_json", load_json)
-    problems = _with(monkeypatch, lambda b: b["per_layer"].append(
-        dict(first, name="twin.metric")))
-    assert any("per_layer 'twin.metric': reads cell" in p
-               and f"{first['name']!r} already reads it" in p
+                                                           cells):
+    """One entry a reader, at start-up: an entry whose file repeats an
+    accepted entry's ``reader`` block is refused whatever cells it lists,
+    and told where they belong."""
+    first = _listed(spec.load_benchmark())
+    problems = _with(
+        monkeypatch, lambda b: b["per_layer"].append(
+            dict(first, name="twin.metric", workloads=cells)),
+        {"twin.metric": (first["name"], lambda f: f)})
+    assert any("per_layer 'twin.metric': shares its reader with "
+               f"{first['name']!r}" in p
+               and f"add {cells} to that entry's 'workloads'" in p
                for p in problems), problems

@@ -4,13 +4,17 @@ described, not attached (no chip time; says nothing about results or times):
 
     JAX_PLATFORMS=cpu python3 benchmark/tools/compile_lm_step.py <cell> [--text FILE]
 
-Prints one JSON line: the parameter count, the compiler's memory report
-(arguments + outputs - aliased + temporaries, against the 15.75 GiB the
-runtime gives), and the ragged-dot kernel calls in the compiled text.
+Prints one JSON line: the parameter count, the compiler's memory report —
+its own peak (``peak_memory_in_bytes``: what the chip must hold at once, and
+the number to size a sequence by against the 15.75 GiB the runtime gives)
+beside the sum arguments + outputs - aliased + temporaries, which counts
+allocations that are never live together and so reads over 15.75 GiB for
+steps the chip loads — and the ragged-dot kernel calls in the compiled text.
 ``--text`` writes ``as_text()`` there — for comparing a cell's step between
 two trees: the same command in each, then ``diff`` (or the ``sha256`` this
 prints).  Any family whose module has ``transformer_config(config, traffic)``
-and ``trainer_kwargs`` (``lm``, ``bdlm``, ``swlm``).
+and ``trainer_kwargs``: ``lm``, ``bdlm``, ``swlm``, ``mlalm``, ``sslm`` and
+``salm`` today.
 """
 
 from __future__ import annotations
@@ -78,7 +82,9 @@ def main(argv=None) -> int:
         "arguments_gib": mem.argument_size_in_bytes / GIB,
         "temporaries_gib": mem.temp_size_in_bytes / GIB,
         "aliased_gib": mem.alias_size_in_bytes / GIB,
-        "total_gib": total / GIB, "fits_15_75_gib": total <= 15.75 * GIB,
+        "total_gib": total / GIB,
+        "peak_gib": mem.peak_memory_in_bytes / GIB,
+        "fits_15_75_gib": mem.peak_memory_in_bytes <= 15.75 * GIB,
         "ragged_dot_calls": len(re.findall(
             r'custom_call_target="tpu_custom_call".*?'
             r'op_name="ragged-dot-none"', text)),
